@@ -29,8 +29,6 @@ Architecture (vs. the reference's layer map, SURVEY.md §1):
   SingleProcessDummyCommunicator pattern, for tests and 1-device runs).
 """
 
-from dgraph_tpu import compat as _compat  # installs jax API shims; keep first
-
 from dgraph_tpu.version import __version__
 from dgraph_tpu import partition
 from dgraph_tpu.plan import (

@@ -6,12 +6,10 @@ it proves the *traced* program emits the collective schedule
 ``obs.footprint`` prices. But the artifact XLA compiles is one level
 lower, and two things can change between jaxpr and StableHLO:
 
-- **XLA-materialized collectives.** ``pallas_p2p`` programs relax the
-  jax-0.4.x shard_map replication checker (``compat.RELAXED_CHECKS``), so
-  a wrong out-spec can make the partitioner insert a full ``all_gather``
-  that no jaxpr-level check sees — the exact hazard the relaxation
-  re-opened (GC3 in PAPERS.md treats the compiled collective schedule as
-  an artifact to verify, not hope about).
+- **XLA-materialized collectives.** A wrong out-spec can make the
+  partitioner insert a full ``all_gather`` that no jaxpr-level check sees
+  (GC3 in PAPERS.md treats the compiled collective schedule as an
+  artifact to verify, not hope about).
 - **Donation.** ``donate_argnums`` is jit metadata at the jaxpr level;
   whether it survives is decided at lowering, where each honored donation
   becomes a ``jax.buffer_donor`` / ``tf.aliasing_output`` entry on a
@@ -93,7 +91,7 @@ _MLIR_DTYPES = {
 }
 
 # interpret-mode DMA discharge artifact shape: per remote put, the
-# compat discharge rule all-gathers the tile payload once and two i32
+# interpreter's discharge rule all-gathers the tile payload once and two i32
 # scalars (the raveled device id and the landing-row index) — anything
 # gathered beyond this budget per put was NOT scheduled by the plan
 _DMA_ARTIFACT_INT_GATHERS_PER_PUT = 2
@@ -180,7 +178,7 @@ def collect_stablehlo(lowered) -> dict:
                 shape, elt, np_dtype, nbytes = tensor_info(
                     op.operands[0].type
                 )
-                attrs = {a.name: a.attr for a in op.attributes}
+                attrs = {name: op.attributes[name] for name in op.attributes}
                 out[kind].append({
                     "op": kind,
                     "shape": shape,

@@ -164,35 +164,18 @@ def shard_map_checks(
     """THE one source of ``jax.shard_map`` check kwargs — every call site
     in the tree routes through here (enforced by the
     ``no-unchecked-shard-map`` lint rule), so which programs run with the
-    replication checker relaxed is a single greppable decision, not a
-    sprinkle of raw ``check_vma=False``.
+    vma checker relaxed is a single greppable decision, not a sprinkle of
+    raw ``check_vma=False``.
 
-    Three spellings:
-
-    - ``shard_map_checks(plan, axis_name)`` — resolve the halo lowering
-      once (same place the lowering itself resolves) and relax ONLY for
-      ``pallas_p2p`` programs: their ``pallas_call`` has no replication
-      rule under jax 0.4.x's rep checker (``compat.RELAXED_CHECKS`` — a
-      no-op on jax >= 0.6). Every other lowering keeps the checker on.
-    - ``shard_map_checks(impl="pallas_p2p")`` — plan-less call sites that
-      already KNOW their lowering (kernel selftests, audit scaffolding).
-    - ``shard_map_checks(relax="<why>")`` — the documented escape for
-      bodies the 0.4.x checker false-positives on regardless of lowering
-      (replicated-by-construction init outputs, ring attention's causal
-      ``lax.cond`` under AD). The reason string is mandatory and exists
-      to be read in the caller — an un-explained relaxation is exactly
-      what the lint rule forbids.
+    The decision today: none. Every program — every halo lowering, the
+    Pallas kernels (their ``out_shape`` declares its ``vma``), init,
+    ring attention — traces with the checker ON, so this returns ``{}``
+    for each spelling. The arguments say what a call site knows (its
+    ``plan``/``axis_name``, a fixed ``impl``, or the ``relax="<why>"``
+    reason it once needed an exemption); a relaxation, should one ever be
+    needed again, is added here and nowhere else.
     """
-    from dgraph_tpu import compat as _compat
-
-    if relax is not None:
-        return dict(_compat.RELAXED_CHECKS)
-    if impl is None:
-        if plan is None or axis_name is None:
-            return {}
-        impl = resolve_plan_impl(plan, axis_name)
-    if impl == "pallas_p2p":
-        return dict(_compat.RELAXED_CHECKS)
+    del plan, axis_name, impl, relax
     return {}
 
 

@@ -14,7 +14,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 GRAPH_AXIS = "graph"
@@ -26,23 +26,40 @@ def make_graph_mesh(
     num_replicas: int = 1,
     devices=None,
 ) -> Mesh:
-    """Build a ``('replica', 'graph')`` mesh.
+    """Build a ``('replica', 'graph')`` mesh over the first
+    ``num_replicas * ranks_per_graph`` of ``devices`` (a sub-mesh of the
+    host when that is fewer than all of them).
 
     ``ranks_per_graph`` defaults to (num_devices / num_replicas) — the
     reference's ``ranks_per_graph`` knob (``NCCLBackendEngine.py:56-64``).
+    Both axes are ``Auto``: every program here places its data with
+    explicit ``shard_map`` specs and indexes global arrays freely outside
+    it, which ``jax.make_mesh``'s default ``Explicit`` axes reject.
     """
     devices = devices if devices is not None else jax.devices()
     n = len(devices)
     if ranks_per_graph is None:
         ranks_per_graph = n // num_replicas
-    if ranks_per_graph * num_replicas != n:
+    need = ranks_per_graph * num_replicas
+    if not 0 < need <= n:
         raise ValueError(
             f"ranks_per_graph ({ranks_per_graph}) x num_replicas ({num_replicas})"
-            f" != device count ({n})"
+            f" needs {need} devices; have {n}"
         )
     return jax.make_mesh(
-        (num_replicas, ranks_per_graph), (REPLICA_AXIS, GRAPH_AXIS), devices=devices
+        (num_replicas, ranks_per_graph), (REPLICA_AXIS, GRAPH_AXIS),
+        (AxisType.Auto, AxisType.Auto), devices=devices[:need],
     )
+
+
+def put_on_graph_axis(tree, mesh: Mesh):
+    """Place a pytree of ``[W, ...]`` leaves (a stacked plan or batch) on
+    the mesh with ``NamedSharding(mesh, P('graph'))``: device ``r`` of the
+    graph axis holds row ``r`` and nothing else. (``jnp.asarray`` would
+    put the whole stack on the default device and leave every step to
+    re-shard from it.) Streamed feature tables too large to stack on the
+    host use :func:`dgraph_tpu.data.memmap.shard_rows_to_device`."""
+    return jax.device_put(tree, NamedSharding(mesh, P(GRAPH_AXIS)))
 
 
 def plan_in_specs(plan) -> object:

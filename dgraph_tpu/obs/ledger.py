@@ -313,7 +313,8 @@ def _norm_bench_round(obj: dict, source: str, round_n=None) -> tuple:
     attached fallback tier / lineage record as its own entries."""
     entries, skips = [], []
     rh = obj.get("run_health") or {}
-    child = rh.get("child") or rh.get("supervisor") or {}
+    child = (rh.get("bench") or rh.get("child") or rh.get("supervisor")
+             or {})
     git_rev = obj.get("git_rev") or child.get("git_rev")
     recorded = child.get("started_at") or obj.get("recorded")
     metrics = {
@@ -733,9 +734,8 @@ _BACKFILL_GLOBS = (
 
 def backfill(root: str, directory: str) -> dict:
     """Ingest the repo's historical artifact corpus (``BENCH_*.json``,
-    ``MULTICHIP_r*.json``, ``BASELINE.json``) so the 456.9 ms round-1
-    baseline and the wedge history become the ledger's first entries.
-    Idempotent: re-running dedups by entry_id."""
+    ``MULTICHIP_r*.json``, ``BASELINE.json``, whichever exist) as the
+    ledger's first entries. Idempotent: re-running dedups by entry_id."""
     report = {"kind": "ledger_backfill", "root": os.path.abspath(root),
               "dir": directory, "files": 0, "appended": 0, "deduped": 0,
               "skipped": []}

@@ -63,20 +63,23 @@ FUSED_MASK_VMEM_BUDGET = 4 * 1024 * 1024
 P2P_COLLECTIVE_ID = 7
 
 
-def p2p_interpret_mode() -> bool:
-    """True when the p2p kernels must run under the Pallas interpreter
-    (any non-TPU backend — the tier-1/CPU path)."""
-    return jax.default_backend() != "tpu"
+def p2p_interpret_mode():
+    """Falsy on a TPU (compile with Mosaic); elsewhere — the tier-1/CPU
+    path — the Pallas TPU interpreter's params. (``interpret=True``, the
+    generic interpreter, can discharge neither a remote put on a
+    multi-axis mesh nor its own index arithmetic under the shard_map vma
+    checker.)"""
+    if jax.default_backend() == "tpu":
+        return False
+    return pltpu.InterpretParams()
 
 
 def _logical_device_ids(axis_name, graph_ids):
     """Raveled LOGICAL device ids over the FULL axis env (row-major in
     env order) with the ``axis_name`` component replaced by ``graph_ids``
     — a ``('replica', 'graph')`` mesh must target
-    ``replica_idx * W + graph_rank``, not the bare graph rank (both real
-    Mosaic lowerings and the interpret discharge shim in
-    :func:`dgraph_tpu.compat.install_multiaxis_remote_dma` number devices
-    this way)."""
+    ``replica_idx * W + graph_rank``, not the bare graph rank (the way
+    Mosaic numbers LOGICAL device ids)."""
     try:
         from jax._src import core as jax_core
 
@@ -167,8 +170,7 @@ def _transport_kernel(
 
 
 @functools.lru_cache(maxsize=None)
-def _make_transport(n, W, S, F, dtype_name, fused_mask, interpret):
-    ANY = pltpu.TPUMemorySpace.ANY
+def _make_transport(n, W, S, F, dtype_name, fused_mask, interpret, vma):
     dtype = jnp.dtype(dtype_name)
     kern = functools.partial(
         _transport_kernel, n=n, S=S, fused_mask=fused_mask,
@@ -176,16 +178,14 @@ def _make_transport(n, W, S, F, dtype_name, fused_mask, interpret):
     )
     return pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((W * S, F), dtype),
+        out_shape=jax.ShapeDtypeStruct((W * S, F), dtype, vma=vma),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-            pl.BlockSpec(
-                memory_space=pltpu.TPUMemorySpace.VMEM if fused_mask else ANY
-            ),
-            pl.BlockSpec(memory_space=ANY),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM if fused_mask else pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(memory_space=ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             # the two-slot staging buffer exists only on the fused-mask
             # path; the non-fused path (reverse legs, over-budget stacks)
@@ -199,7 +199,7 @@ def _make_transport(n, W, S, F, dtype_name, fused_mask, interpret):
         # before the kernel (and so before any peer's put) — rows no put
         # covers stay exactly 0, matching the round lowerings
         input_output_aliases={3: 0},
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             collective_id=P2P_COLLECTIVE_ID
         ),
         interpret=interpret,
@@ -239,10 +239,6 @@ def p2p_transport(
     n = len(deltas)
     F = blocks.shape[-1]
     interpret = p2p_interpret_mode()
-    if interpret:
-        from dgraph_tpu.compat import install_multiaxis_remote_dma
-
-        install_multiaxis_remote_dma()
     fused = mask is not None and transport_fused_mask(blocks, S, F, blocks.dtype)
     if mask is not None and not fused:
         blocks = blocks * mask[..., None].astype(blocks.dtype)
@@ -259,9 +255,17 @@ def p2p_transport(
         sources,
         (me * S)[None],
     ]).astype(jnp.int32)
+    # the output varies over the manual axes its DATA operands vary over
+    # (``meta`` is addressing: it names a different device per replica,
+    # but every replica group moves the same values); the shard_map vma
+    # checker wants that declared on out_shape AND on the landing buffer
+    # the output aliases (an aliased output takes its input's type)
+    vma = jax.typeof(mask).vma | jax.typeof(blocks).vma
     zeros = jnp.zeros((W * S, F), blocks.dtype)
+    if vma:
+        zeros = lax.pcast(zeros, tuple(vma), to="varying")
     fn = _make_transport(
-        n, W, S, F, jnp.dtype(blocks.dtype).name, fused, interpret
+        n, W, S, F, jnp.dtype(blocks.dtype).name, fused, interpret, vma
     )
     return fn(meta, mask, blocks, zeros)
 
@@ -277,8 +281,6 @@ def _selftest_failures(seed: int = 0) -> list:
     put directions, fused and pre-masked. Tiny CPU compiles only."""
     import numpy as np
     from jax.sharding import PartitionSpec as P
-
-    from dgraph_tpu import compat as _compat  # noqa: F401  jax.shard_map
 
     failures = []
     if jax.default_backend() == "tpu":
@@ -311,8 +313,6 @@ def _selftest_failures(seed: int = 0) -> list:
             f = jax.shard_map(
                 body, mesh=mesh, in_specs=(P("x"), P("x")),
                 out_specs=P("x"),
-                # both smoke bodies (p2p and its all_to_all oracle) share
-                # this runner, and the p2p one needs the 0.4.x relaxation
                 **shard_map_checks(impl="pallas_p2p"),
             )
             return np.asarray(jax.jit(f)(xj, mj))

@@ -149,6 +149,15 @@ def _precision(precision: str):
     )
 
 
+def _out_struct(shape, dtype, *operands):
+    """``out_shape`` entry for a ``pallas_call`` made from ``operands``:
+    inside ``jax.shard_map`` with ``check_vma`` on, the output must declare
+    which manual axes it varies over — the union of its operands' (empty
+    outside shard_map)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 def _sorted_segment_sum_impl(
     data, segment_ids, num_segments, *, max_chunks_per_block, block_e, block_n,
     interpret, input_op, precision,
@@ -176,15 +185,16 @@ def _sorted_segment_sum_impl(
     # semantics anyway — so the VMEM-resident output block is ALWAYS f32
     # (bf16 inputs still ride the fast bf16 MXU passes under
     # precision='default'); cast back to the input dtype on the way out.
+    operands = (sched.chunk_start, sched.chunk_counts, sched.ids3d, data3d)
     out = pl.pallas_call(
         functools.partial(
             _kernel, block_n=block_n, block_e=block_e, input_op=input_op,
             precision=_precision(precision),
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((sched.N_pad, F), jnp.float32),
+        out_shape=_out_struct((sched.N_pad, F), jnp.float32, *operands),
         interpret=interpret,
-    )(sched.chunk_start, sched.chunk_counts, sched.ids3d, data3d)
+    )(*operands)
     return out[:num_segments].astype(data.dtype)
 
 
@@ -371,6 +381,7 @@ def _make_ssbr(num_segments, max_chunks_per_block, block_e, block_n, interpret,
             in_specs=in_specs,
             out_specs=sched.block_spec(F),
         )
+        call_args = (sched.chunk_start, sched.chunk_counts, *operands)
         out = pl.pallas_call(
             functools.partial(
                 _kernel_bias_relu, block_n=block_n, block_e=block_e,
@@ -378,9 +389,9 @@ def _make_ssbr(num_segments, max_chunks_per_block, block_e, block_n, interpret,
                 epilogue=epilogue,
             ),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((sched.N_pad, F), jnp.float32),
+            out_shape=_out_struct((sched.N_pad, F), jnp.float32, *call_args),
             interpret=interpret,
-        )(sched.chunk_start, sched.chunk_counts, *operands)
+        )(*call_args)
         if epilogue != "relu":
             # the act-count reduction is bwd-internal and vertex-sized —
             # keep the f32 accumulator precision (a bf16 count saturates)
@@ -636,15 +647,16 @@ def _make_srg(num_rows, max_vblocks, block_e, block_n, interpret, precision,
             in_specs=[vs.ids_spec(), vs.vtx_spec(F)],
             out_specs=vs.out_spec(F),
         )
+        operands = (vs.vb_start, vs.vb_counts, vs.ids3d, vs.pad_vertices(x))
         out = pl.pallas_call(
             functools.partial(
                 _gather_kernel, block_n=block_n, block_e=block_e,
                 precision=_precision(precision),
             ),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((vs.E_pad, F), jnp.float32),
+            out_shape=_out_struct((vs.E_pad, F), jnp.float32, *operands),
             interpret=interpret,
-        )(vs.vb_start, vs.vb_counts, vs.ids3d, vs.pad_vertices(x))
+        )(*operands)
         return out[:E].astype(x.dtype)
 
     @jax.custom_vjp
@@ -755,16 +767,17 @@ def _make_fused_bwd(num_rows, max_vblocks, block_e, block_n, interpret,
                 pltpu.VMEM((block_e, F), jnp.float32),  # bias-rows acc
             ],
         )
+        operands = (vs.vb_start, vs.vb_counts, vs.ids3d, data3d,
+                    vs.pad_vertices(g), vs.pad_vertices(bias))
         out = pl.pallas_call(
             functools.partial(
                 _fused_bwd_kernel, block_n=block_n, block_e=block_e,
                 precision=_precision(precision),
             ),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((vs.E_pad, F), data.dtype),
+            out_shape=_out_struct((vs.E_pad, F), data.dtype, *operands),
             interpret=interpret,
-        )(vs.vb_start, vs.vb_counts, vs.ids3d, data3d,
-          vs.pad_vertices(g), vs.pad_vertices(bias))
+        )(*operands)
         return out[:E]
 
     return impl

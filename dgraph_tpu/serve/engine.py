@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dgraph_tpu import compat as _compat  # noqa: F401  (jax.shard_map on 0.4.x)
 from dgraph_tpu.comm.mesh import GRAPH_AXIS, plan_in_specs, squeeze_plan
 from dgraph_tpu.obs import spans
 from dgraph_tpu.obs.metrics import Metrics, default_registry
@@ -226,8 +225,6 @@ class ServeEngine:
                 mesh=mesh,
                 in_specs=(P(), batch_specs, plan_specs),
                 out_specs=P(GRAPH_AXIS),
-                # pallas_p2p forwards relax the 0.4.x rep checker
-                # (pallas_call has no replication rule there)
                 **shard_map_checks(plan, GRAPH_AXIS),
             )(params, batch, plan)
 

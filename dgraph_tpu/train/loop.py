@@ -20,8 +20,13 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from dgraph_tpu import compat as _compat
-from dgraph_tpu.comm.mesh import GRAPH_AXIS, REPLICA_AXIS, plan_in_specs, squeeze_plan
+from dgraph_tpu.comm.mesh import (
+    GRAPH_AXIS,
+    REPLICA_AXIS,
+    plan_in_specs,
+    put_on_graph_axis,
+    squeeze_plan,
+)
 from dgraph_tpu.obs.metrics import StepMetrics
 from dgraph_tpu.plan import EdgePlan
 
@@ -53,12 +58,18 @@ def init_params(model, mesh, plan: EdgePlan, batch: dict, seed: int = 0,
         mesh=mesh,
         in_specs=(batch_specs, plan_in_specs(plan)),
         out_specs=P(),
-        # params ARE replicated (same key, shape-only init) but the 0.4.x
-        # rep checker cannot prove it through model.init's per-shard data
         **shard_map_checks(relax="init outputs replicated by construction"),
     )
     with jax.set_mesh(mesh):
         return jax.jit(fn)(batch, plan)
+
+
+def init_opt_state(optimizer: optax.GradientTransformation, params, mesh):
+    """``optimizer.init(params)`` under the mesh, so its step counter gets
+    the same (mesh-typed) aval the train step returns — initialised outside
+    it, step 1 sees a new input type, retraces and compiles again."""
+    with jax.set_mesh(mesh):
+        return optimizer.init(params)
 
 
 def masked_cross_entropy(logits, labels, mask, axis_name):
@@ -194,21 +205,14 @@ def make_train_step(
             return loss / num_replicas, (loss, correct)
 
         (_, (loss, correct)), grads = jax.value_and_grad(lf, has_aux=True)(params)
-        # NO explicit grad psum on jax >= 0.6: params enter replicated
-        # (in_specs P()), and shard_map's vma tracking makes
-        # grad-of-replicated-input insert the cross-shard psum
-        # automatically (the transpose of the replicated broadcast) — an
-        # extra lax.psum there would double-count by W. On jax 0.4.x no
-        # such rewrite exists, so compat inserts the psum explicitly over
-        # exactly the axes the batch is sharded on. Pinned either way by
-        # tests/test_models.py::test_distributed_gradients_match_single_
+        # NO explicit grad psum: params enter replicated (in_specs P()),
+        # and shard_map's vma tracking makes grad-of-replicated-input
+        # insert the cross-shard psum automatically (the transpose of the
+        # replicated broadcast) over BOTH axes — an extra lax.psum here
+        # would double-count by W. With the loss pre-scaled by
+        # 1/num_replicas the replica psum is exactly the DDP mean. Pinned
+        # by tests/test_models.py::test_distributed_gradients_match_single_
         # device.
-        # BOTH axes unconditionally: params are replicated over replica
-        # too, and with the loss pre-scaled by 1/num_replicas the replica
-        # psum is exactly the DDP mean (with per_replica_batch=False the
-        # replica grads are identical, so sum/R reproduces them; a
-        # graph-only psum would leave grads scaled 1/R when R > 1)
-        grads = _compat.sync_inbody_grads(grads, (REPLICA_AXIS, GRAPH_AXIS))
         loss = lax.psum(loss, GRAPH_AXIS)
         mask_count = lax.psum(b["mask"].sum(), GRAPH_AXIS)
         acc = lax.psum(correct, GRAPH_AXIS) / jnp.maximum(mask_count, 1.0)
@@ -233,8 +237,6 @@ def make_train_step(
             mesh=mesh,
             in_specs=(P(), batch_specs, plan_in_specs(plan)),
             out_specs=(P(), P()),
-            # pallas_p2p programs relax the 0.4.x rep checker (pallas_call
-            # has no replication rule there); all other lowerings keep it
             **shard_map_checks(plan, GRAPH_AXIS),
         )(params, batch, plan)
         if nonfinite_guard:
@@ -339,12 +341,12 @@ def fit(
     # otherwise — the default builder ignores unknown keys)
     batch_tr = dict(graph.batch("train"), y=graph.labels, vmask=graph.vertex_mask)
     batch_va = dict(graph.batch("val"), y=graph.labels, vmask=graph.vertex_mask)
-    batch_tr = jax.tree.map(jnp.asarray, batch_tr)
-    batch_va = jax.tree.map(jnp.asarray, batch_va)
-    plan = jax.tree.map(jnp.asarray, graph.plan)
+    batch_tr = put_on_graph_axis(batch_tr, mesh)
+    batch_va = put_on_graph_axis(batch_va, mesh)
+    plan = put_on_graph_axis(graph.plan, mesh)
 
     params = init_params(model, mesh, plan, batch_tr, seed, batch_args=batch_args)
-    opt_state = optimizer.init(params)
+    opt_state = init_opt_state(optimizer, params, mesh)
     train_step = make_train_step(
         model, optimizer, mesh, plan, loss_fn=loss_fn, batch_args=batch_args,
         nonfinite_guard=nonfinite_guard,
@@ -358,7 +360,8 @@ def fit(
             if chaos.fire("grads", index=epoch):
                 # host-side poison of this epoch's features only — same
                 # shapes, same executable, one step's grads go non-finite
-                bt = dict(batch_tr, x=jnp.asarray(chaos.poison_array(batch_tr["x"])))
+                bt = dict(batch_tr, x=put_on_graph_axis(
+                    chaos.poison_array(batch_tr["x"]), mesh))
             # host-boundary span (never inside the jitted step): one attr
             # read when tracing is off
             with spans.span("train.epoch", epoch=epoch):

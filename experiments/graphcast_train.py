@@ -52,7 +52,6 @@ def main(cfg: Config):
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from dgraph_tpu import compat as _compat
     from dgraph_tpu.comm import Communicator, make_graph_mesh
     from dgraph_tpu.comm.mesh import GRAPH_AXIS, plan_in_specs, squeeze_plan
     from dgraph_tpu.data.weather import SyntheticWeatherDataset
@@ -175,9 +174,6 @@ def main(cfg: Config):
             return se.sum() / jnp.maximum(cnt, 1.0)
 
         loss, grads = jax.value_and_grad(lf)(params)
-        # jax<0.6: in-body grads of replicated params need the explicit
-        # graph-axis psum (no-op on 0.6+, where vma tracking inserts it)
-        grads = _compat.sync_inbody_grads(grads, (GRAPH_AXIS,))
         return jax.lax.psum(loss, GRAPH_AXIS), grads
 
     body = jax.shard_map(

@@ -116,13 +116,14 @@ def load_data(cfg: DataConfig):
 
 def main(cfg: Config):
     import jax
-    import jax.numpy as jnp
     import optax
 
     from dgraph_tpu.comm import Communicator, make_graph_mesh
+    from dgraph_tpu.comm.mesh import put_on_graph_axis
     from dgraph_tpu.data import DistributedGraph
     from dgraph_tpu.models import GAT, GCN, GraphSAGE, GraphTransformer
     from dgraph_tpu.train.loop import (
+        init_opt_state,
         init_params,
         make_eval_step,
         make_train_step,
@@ -172,11 +173,11 @@ def main(cfg: Config):
         raise SystemExit(f"unknown model {cfg.model}")
     bargs = vmask_batch_args if cfg.model in ("gt", "graph_transformer") else None
 
-    plan = jax.tree.map(jnp.asarray, g.plan)
+    plan = put_on_graph_axis(g.plan, mesh)
 
     def _batch(split):
-        return jax.tree.map(
-            jnp.asarray, dict(g.batch(split), y=g.labels, vmask=g.vertex_mask)
+        return put_on_graph_axis(
+            dict(g.batch(split), y=g.labels, vmask=g.vertex_mask), mesh
         )
 
     batch_tr = _batch("train")
@@ -184,7 +185,7 @@ def main(cfg: Config):
 
     params = init_params(model, mesh, plan, batch_tr, batch_args=bargs)
     optimizer = optax.adam(cfg.lr)
-    opt_state = optimizer.init(params)
+    opt_state = init_opt_state(optimizer, params, mesh)
     loss_fn = (
         masked_bce_multilabel if np.asarray(g.labels).ndim > 2 else masked_cross_entropy
     )

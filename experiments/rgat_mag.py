@@ -49,7 +49,6 @@ def main(cfg: Config):
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from dgraph_tpu import compat as _compat
     from dgraph_tpu.comm import Communicator, make_graph_mesh
     from dgraph_tpu.comm.mesh import GRAPH_AXIS, plan_in_specs, squeeze_plan
     from dgraph_tpu.data.hetero import DistributedHeteroGraph, synthetic_mag
@@ -161,9 +160,6 @@ def main(cfg: Config):
             return loss, (mut.get("batch_stats", {}), correct, cnt)
 
         (loss, (new_bs, correct, cnt)), grads = jax.value_and_grad(lf, has_aux=True)(params)
-        # jax<0.6: in-body grads of replicated params need the explicit
-        # graph-axis psum (no-op on 0.6+, where vma tracking inserts it)
-        grads = _compat.sync_inbody_grads(grads, (GRAPH_AXIS,))
         acc = jax.lax.psum(correct, GRAPH_AXIS) / jnp.maximum(cnt, 1.0)
         return jax.lax.psum(loss, GRAPH_AXIS), acc, grads, new_bs
 

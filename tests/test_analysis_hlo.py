@@ -256,10 +256,10 @@ def test_raw_shard_map_site_flagged():
     )
     bad_splat = (
         "import jax\n"
-        "from dgraph_tpu import compat as _compat\n"
+        "RELAXED_CHECKS = {'check_vma': False}\n"
         "def build(body, mesh, specs):\n"
         "    return jax.shard_map(body, mesh=mesh, in_specs=specs,\n"
-        "                         out_specs=specs, **_compat.RELAXED_CHECKS)\n"
+        "                         out_specs=specs, **RELAXED_CHECKS)\n"
     )
     good = (
         "import jax\n"

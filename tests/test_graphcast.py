@@ -203,9 +203,6 @@ def test_graphcast_trains(mesh8, graphs8):
             return se.sum() / jnp.maximum(cnt, 1.0)
 
         loss, grads = jax.value_and_grad(lf)(params)
-        from dgraph_tpu import compat as _compat
-
-        grads = _compat.sync_inbody_grads(grads, (GRAPH_AXIS,))
         return jax.lax.psum(loss, GRAPH_AXIS), grads
 
     body = jax.shard_map(

@@ -145,9 +145,6 @@ def test_rgat_trains(mesh8, mag):
             return -(ll * m_).sum() / jnp.maximum(cnt, 1.0)
 
         loss, grads = jax.value_and_grad(lf)(params)
-        from dgraph_tpu import compat as _compat
-
-        grads = _compat.sync_inbody_grads(grads, (GRAPH_AXIS,))
         return jax.lax.psum(loss, GRAPH_AXIS), grads
 
     in_specs = (P(),) + hetero_in_specs(g8) + (P(GRAPH_AXIS), P(GRAPH_AXIS))

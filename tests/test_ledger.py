@@ -307,25 +307,22 @@ def test_seeded_drift_selftests_pass():
 def test_backfill_real_corpus_and_report(tmp_path):
     d = str(tmp_path)
     rep = backfill(REPO, d)
-    assert rep["files"] >= 11  # BASELINE + BENCH_r* + MULTICHIP_r*
-    assert rep["appended"] >= 10
+    assert rep["files"] >= 6  # BASELINE + MULTICHIP_r* (the BENCH_* rounds
+    # of the removed installation are deleted; their shapes stay covered
+    # by the normalize_record fixtures above)
+    assert rep["appended"] >= 6
     s = summarize(d)
-    # the wedge history (r01-r05) and the round-1 number are BOTH there
-    assert s["by_kind"]["probe_wedge"] >= 4
-    assert s["by_kind"]["bench_round"] >= 1
+    assert s["by_kind"]["multichip_dryrun"] >= 5
+    assert s["by_kind"]["reference_note"] == 1
     entries, _ = read_ledger(d)
-    baseline = next(e for e in entries if e["kind"] == "bench_round")
-    assert baseline["metrics"]["epoch_time_ms"] == pytest.approx(
-        456.898, abs=0.01)
     # idempotent: a second run appends nothing
     rep2 = backfill(REPO, d)
     assert rep2["appended"] == 0 and rep2["deduped"] == rep["appended"]
     # the real corpus gates GREEN (no synthetic drift in history)
     assert regress.check_ledger(d)["ok"]
-    # and the trajectory renders the north-star number
+    # and the trajectory renders the corpus
     md = report.render_trajectory(entries, directory=d)
-    assert "## Bench rounds" in md and "456.9" in md
-    assert "WEDGED" in md  # the wedge history is visible, not elided
+    assert "## multichip_dryrun" in md and "n_families" in md
 
 
 # ---------------------------------------------------------------------------
@@ -346,7 +343,7 @@ def test_cli_backfill_regress_report_roundtrip(tmp_path):
     d = str(tmp_path / "ledger")
     p = _run_cli(["dgraph_tpu.obs.ledger", "--backfill", REPO, "--dir", d])
     assert p.returncode == 0, p.stderr
-    assert json.loads(p.stdout.splitlines()[-1])["appended"] >= 10
+    assert json.loads(p.stdout.splitlines()[-1])["appended"] >= 6
 
     log = str(tmp_path / "regress.jsonl")
     p = _run_cli(["dgraph_tpu.obs.regress", "--dir", d,
@@ -361,7 +358,7 @@ def test_cli_backfill_regress_report_roundtrip(tmp_path):
     md_path = str(tmp_path / "TRAJECTORY.md")
     p = _run_cli(["dgraph_tpu.obs.report", "--dir", d, "--out", md_path])
     assert p.returncode == 0, p.stderr
-    assert "456.9" in open(md_path).read()
+    assert "## multichip_dryrun" in open(md_path).read()
 
 
 def test_cli_regress_exits_nonzero_on_red(tmp_path):

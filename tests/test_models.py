@@ -176,11 +176,8 @@ def test_distributed_gradients_match_single_device(mesh8, sbm):
             logits = m8.apply(p, b["x"], plan_s, b["edge_weight"])
             return masked_cross_entropy(logits, b["y"], b["mask"], GRAPH_AXIS)
 
-        # grad w.r.t. replicated params auto-psums across shards on jax
-        # 0.6+ (vma); compat inserts the explicit psum on 0.4.x
-        from dgraph_tpu import compat as _compat
-
-        return _compat.sync_inbody_grads(jax.grad(lf)(params), (GRAPH_AXIS,))
+        # grad w.r.t. replicated params auto-psums across shards (vma)
+        return jax.grad(lf)(params)
 
     batch_specs = jax.tree.map(lambda _: P(GRAPH_AXIS), batch)
     with jax.set_mesh(mesh8):

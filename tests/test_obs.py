@@ -534,13 +534,16 @@ def test_startup_record_has_backend_snapshot():
     assert rec2["backend"] is None
 
 
-def test_bench_failure_json_embeds_run_health():
-    """bench.py's one failure-path schema must carry the RunHealth record
-    (the acceptance pin for 'a null benchmark is diagnosable from the
-    artifact alone') — exercised in-process, no subprocess needed."""
+def test_bench_fails_instead_of_falling_back(monkeypatch, capsys):
+    """bench.py measures the chip or fails: no TPU (and no explicit smoke
+    mode) -> non-zero and no JSON line; a device without published peaks
+    is an error; a kernel self-check that disagrees raises — exercised
+    in-process, no subprocess and no compile needed."""
     import importlib.util
     import os
     import sys
+
+    import numpy as np
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     spec = importlib.util.spec_from_file_location(
@@ -550,18 +553,16 @@ def test_bench_failure_json_embeds_run_health():
     sys.modules["_bench_under_test"] = bench
     spec.loader.exec_module(bench)
     try:
-        h = bench._health_mod().RunHealth.begin("bench.supervisor")
-        h.record_probe(1, 12.0, "hang", "probe hung (wedged lease)")
-        bench._HEALTH = h
-        out, rc = bench._failure_json(
-            "backend never initialized within 1 probes; wedged TPU lease",
-            {}, bench.EXIT_EMPTY,
-        )
-        assert rc == bench.EXIT_EMPTY
-        parsed = json.loads(json.dumps(out))
-        rh = parsed["run_health"]["supervisor"]
-        assert rh["wedge"] == "init_wedge" and rh["probes"]
-        assert parsed["value"] is None and "error" in parsed
+        monkeypatch.delenv("DGRAPH_BENCH_SMOKE", raising=False)
+        assert bench.main() == 1  # the test backend is the CPU
+        captured = capsys.readouterr()
+        assert captured.out == "" and "no TPU" in captured.err
+        assert bench.device_peaks("TPU v5 lite")["hbm_gbps"] == 819.0
+        with pytest.raises(KeyError, match="no published peaks"):
+            bench.device_peaks("cpu")
+        ref = np.zeros(4, np.float32)
+        bench._check_one("same", lambda: ref, ref, 1e-4)
+        with pytest.raises(AssertionError, match="exceeds tol"):
+            bench._check_one("off", lambda: ref + 1.0, ref, 1e-4)
     finally:
-        bench._HEALTH = None
         sys.modules.pop("_bench_under_test", None)
