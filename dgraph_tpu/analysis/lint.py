@@ -178,7 +178,7 @@ JAX_FREE_TARGETS = (
     # imported by every module above, so it must never pull jax in
     "dgraph_tpu/utils/env.py",
     # the package __init__ the env import pays on the way in: its heavy
-    # exports (TimingReport, ExperimentLog) are PEP 562-lazy precisely so
+    # exports (ExperimentLog, the split helpers) are PEP 562-lazy precisely so
     # this file stays jax-free at module level — enforcing it here means
     # a restored eager import turns every target above RED instead of
     # silently re-poisoning them
@@ -449,21 +449,18 @@ def check_config_read_in_trace(relpath: str, tree: ast.AST, lines: list):
 # no-span-in-trace
 # ---------------------------------------------------------------------------
 
-# host-side span/timer entry points (obs.spans / utils.timing) that must
-# never execute inside a traced body: a host clock read there measures
-# TRACING (once), not execution (every step), and a span id would freeze
-# into the cached executable — both silently wrong, never crashing
-SPAN_CALLS = frozenset({"span", "start_span"})
-TIMER_CALLS = frozenset({"start", "stop", "time", "add_time"})
-PROFILER_CALLS = frozenset({"trace_to"})
+# host-side span entry points (obs.spans: span, its always-on twin stage)
+# that must never execute inside a traced body: a host clock read there
+# measures TRACING (once), not execution (every step), and a span id would
+# freeze into the cached executable — both silently wrong, never crashing
+SPAN_CALLS = frozenset({"span", "start_span", "stage", "record_span"})
 
 
 @rule(
     "no-span-in-trace",
-    "no obs.spans span / TimingReport timer / profiler call lexically "
-    "inside a function passed to jit/shard_map/scan/... (host timing in a "
-    "traced body measures tracing, not execution; spans stay at host "
-    "boundaries)",
+    "no obs.spans span / stage call lexically inside a function passed to "
+    "jit/shard_map/scan/... (host timing in a traced body measures "
+    "tracing, not execution; spans stay at host boundaries)",
     path_matcher("dgraph_tpu/"),
     scope="dgraph_tpu/",
 )
@@ -485,10 +482,6 @@ def check_span_in_trace(relpath: str, tree: ast.AST, lines: list):
                 ) or bool(node.keywords)
                 if named:
                     bad = f"span call '{dotted or last}'"
-            elif dotted.startswith("TimingReport.") and last in TIMER_CALLS:
-                bad = f"host timer call '{dotted}'"
-            elif last in PROFILER_CALLS:
-                bad = f"profiler context '{dotted or last}'"
             if bad:
                 findings.append(Finding(
                     "no-span-in-trace", relpath, node.lineno,

@@ -37,7 +37,7 @@ from dgraph_tpu.comm.mesh import GRAPH_AXIS, REPLICA_AXIS
 from dgraph_tpu.plan import EdgePlan, HaloSpec
 
 # Every collective issued through the facade carries a named region so
-# Perfetto traces (utils.timing.trace_to) attribute wire time to the API
+# Perfetto traces (jax.profiler.trace) attribute wire time to the API
 # call that caused it (collectives.py annotates the primitive layer the
 # same way).
 from dgraph_tpu.utils.timing import named_scope as _scoped

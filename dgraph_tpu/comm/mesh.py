@@ -58,8 +58,23 @@ def put_on_graph_axis(tree, mesh: Mesh):
     graph axis holds row ``r`` and nothing else. (``jnp.asarray`` would
     put the whole stack on the default device and leave every step to
     re-shard from it.) Streamed feature tables too large to stack on the
-    host use :func:`dgraph_tpu.data.memmap.shard_rows_to_device`."""
-    return jax.device_put(tree, NamedSharding(mesh, P(GRAPH_AXIS)))
+    host use :func:`dgraph_tpu.data.memmap.shard_rows_to_device`.
+
+    The always-on stage ``setup.place`` (:func:`dgraph_tpu.obs.spans.stage`)
+    times the host's part only: ``device_put`` returns once the copies are
+    issued, and this function does not wait for them."""
+    from dgraph_tpu.obs import spans
+
+    with spans.stage("setup.place", **tree_size(tree)):
+        return jax.device_put(tree, NamedSharding(mesh, P(GRAPH_AXIS)))
+
+
+def tree_size(tree) -> dict:
+    """``{"leaves", "bytes"}`` of a pytree of arrays: the attributes that
+    size a set-up stage (:func:`dgraph_tpu.obs.spans.stage`) over it."""
+    leaves = jax.tree.leaves(tree)
+    return {"leaves": len(leaves),
+            "bytes": sum(int(getattr(leaf, "nbytes", 0)) for leaf in leaves)}
 
 
 def plan_in_specs(plan) -> object:

@@ -142,10 +142,19 @@ class DistributedGraph:
             part_kwargs["sample_frac"] = sample_frac
         if edge_balance is not None:
             part_kwargs["edge_balance"] = edge_balance
-        new_edges, ren = pt.partition_graph(
-            edge_index, num_nodes, world_size, method=partition_method,
-            seed=seed, **part_kwargs,
-        )
+        from dgraph_tpu.obs import spans
+
+        # the three always-on set-up stages of this call (obs.spans.stage):
+        # setup.partition here, setup.plan inside plan.build_edge_plan,
+        # setup.shard below
+        sizes = dict(num_nodes=int(num_nodes),
+                     num_edges=int(edge_index.shape[1]),
+                     world_size=world_size, method=partition_method)
+        with spans.stage("setup.partition", **sizes):
+            new_edges, ren = pt.partition_graph(
+                edge_index, num_nodes, world_size, method=partition_method,
+                seed=seed, **part_kwargs,
+            )
         # the on-disk plan cache (train/checkpoint.cached_edge_plan) resolves
         # a falsy dir to a plain build, so this is the one call site either way
         from dgraph_tpu.train.checkpoint import cached_edge_plan
@@ -175,36 +184,37 @@ class DistributedGraph:
             overlap=overlap,
             key_extra=key_extra,
         )
-        n_pad = plan.n_src_pad
-        feats = shard_vertex_data(
-            np.asarray(features)[ren.inv], ren.counts, n_pad
-        ).astype(np.float32)
-        if labels is not None:
-            lab_arr = np.asarray(labels)
-            # integer class ids -> int32; float arrays (e.g. ogbn-proteins'
-            # [V, 112] multi-label targets) keep float32 for BCE losses
-            lab_dtype = (
-                np.float32 if np.issubdtype(lab_arr.dtype, np.floating) else np.int32
-            )
-            lab = shard_vertex_data(
-                lab_arr[ren.inv].astype(lab_dtype), ren.counts, n_pad
-            )
-        else:
-            lab = None
-        m = {}
-        if masks:
-            for k, v in masks.items():
-                m[k] = shard_vertex_data(
-                    np.asarray(v).astype(np.float32)[ren.inv], ren.counts, n_pad
+        with spans.stage("setup.shard", **sizes):
+            n_pad = plan.n_src_pad
+            feats = shard_vertex_data(
+                np.asarray(features)[ren.inv], ren.counts, n_pad
+            ).astype(np.float32)
+            if labels is not None:
+                lab_arr = np.asarray(labels)
+                # integer class ids -> int32; float arrays (e.g. ogbn-proteins'
+                # [V, 112] multi-label targets) keep float32 for BCE losses
+                lab_dtype = (
+                    np.float32 if np.issubdtype(lab_arr.dtype, np.floating) else np.int32
                 )
-        vmask = shard_vertex_data(
-            np.ones(num_nodes, np.float32), ren.counts, n_pad
-        )
-        ew = None
-        if add_symmetric_norm:
-            ew = shard_edge_data(
-                symmetric_norm_weights(new_edges, num_nodes), layout, plan.e_pad
+                lab = shard_vertex_data(
+                    lab_arr[ren.inv].astype(lab_dtype), ren.counts, n_pad
+                )
+            else:
+                lab = None
+            m = {}
+            if masks:
+                for k, v in masks.items():
+                    m[k] = shard_vertex_data(
+                        np.asarray(v).astype(np.float32)[ren.inv], ren.counts, n_pad
+                    )
+            vmask = shard_vertex_data(
+                np.ones(num_nodes, np.float32), ren.counts, n_pad
             )
+            ew = None
+            if add_symmetric_norm:
+                ew = shard_edge_data(
+                    symmetric_norm_weights(new_edges, num_nodes), layout, plan.e_pad
+                )
         return cls(
             num_nodes=num_nodes,
             num_edges=edge_index.shape[1],

@@ -101,37 +101,48 @@ def build_graphcast_graphs(
     # at W=4 — halo volume scales with cut
     pad_multiple: int = 8,
 ) -> GraphCastGraphs:
-    mm = mesh_lib.build_multimesh(mesh_level)
-    grid_latlon, grid_xyz = mesh_lib.latlon_grid(num_lat, num_lon)
-    g2m = mesh_lib.grid2mesh_edges(grid_xyz, mm)
-    m2g = mesh_lib.mesh2grid_edges(grid_xyz, mm)
-    num_grid, num_mesh = len(grid_xyz), len(mm.vertices)
+    from dgraph_tpu.obs import spans
 
-    # --- partitions ---
-    if world_size == 1:
-        mesh_part = np.zeros(num_mesh, np.int32)
-    elif mesh_partition_method == "rcm":
-        mesh_part = pt.rcm_partition(mm.edges, num_mesh, world_size)
-    elif mesh_partition_method in ("multilevel", "metis"):
-        # the reference partitions its mesh with METIS
-        # (GraphCast/data_utils/preprocess.py:14-31); the native multilevel
-        # partitioner is its stand-in here
-        mesh_part = pt.multilevel_partition(mm.edges, num_mesh, world_size)
-    else:
-        mesh_part = pt.greedy_bfs_partition(mm.edges, num_mesh, world_size)
-    mesh_ren = pt.renumber_contiguous(mesh_part, world_size)
-    grid_part = pt.block_partition(num_grid, world_size)  # latitude bands
-    grid_ren = pt.renumber_contiguous(grid_part, world_size)
+    # the always-on set-up stages of data/graph.py's from_global, by the same
+    # names, plus setup.graph_gen for what only this model has; the three
+    # plans time themselves (setup.plan, inside build_edge_plan)
+    with spans.stage("setup.graph_gen", mesh_level=mesh_level,
+                     num_lat=num_lat, num_lon=num_lon) as st:
+        mm = mesh_lib.build_multimesh(mesh_level)
+        grid_latlon, grid_xyz = mesh_lib.latlon_grid(num_lat, num_lon)
+        g2m = mesh_lib.grid2mesh_edges(grid_xyz, mm)
+        m2g = mesh_lib.mesh2grid_edges(grid_xyz, mm)
+        num_grid, num_mesh = len(grid_xyz), len(mm.vertices)
+        st.annotate(num_nodes=num_grid + num_mesh,
+                    num_edges=mm.edges.shape[1] + g2m.shape[1] + m2g.shape[1])
 
-    n_mesh_pad = _pad_to(int(mesh_ren.counts.max(initial=1)), pad_multiple)
-    n_grid_pad = _pad_to(int(grid_ren.counts.max(initial=1)), pad_multiple)
+    with spans.stage("setup.partition", num_nodes=num_grid + num_mesh,
+                     world_size=world_size, method=mesh_partition_method):
+        # --- partitions ---
+        if world_size == 1:
+            mesh_part = np.zeros(num_mesh, np.int32)
+        elif mesh_partition_method == "rcm":
+            mesh_part = pt.rcm_partition(mm.edges, num_mesh, world_size)
+        elif mesh_partition_method in ("multilevel", "metis"):
+            # the reference partitions its mesh with METIS
+            # (GraphCast/data_utils/preprocess.py:14-31); the native multilevel
+            # partitioner is its stand-in here
+            mesh_part = pt.multilevel_partition(mm.edges, num_mesh, world_size)
+        else:
+            mesh_part = pt.greedy_bfs_partition(mm.edges, num_mesh, world_size)
+        mesh_ren = pt.renumber_contiguous(mesh_part, world_size)
+        grid_part = pt.block_partition(num_grid, world_size)  # latitude bands
+        grid_ren = pt.renumber_contiguous(grid_part, world_size)
 
-    def remap(edges, src_ren, dst_ren):
-        return np.stack([src_ren.perm[edges[0]], dst_ren.perm[edges[1]]])
+        n_mesh_pad = _pad_to(int(mesh_ren.counts.max(initial=1)), pad_multiple)
+        n_grid_pad = _pad_to(int(grid_ren.counts.max(initial=1)), pad_multiple)
 
-    mesh_edges_r = remap(mm.edges, mesh_ren, mesh_ren)
-    g2m_r = remap(g2m, grid_ren, mesh_ren)
-    m2g_r = remap(m2g, mesh_ren, grid_ren)
+        def remap(edges, src_ren, dst_ren):
+            return np.stack([src_ren.perm[edges[0]], dst_ren.perm[edges[1]]])
+
+        mesh_edges_r = remap(mm.edges, mesh_ren, mesh_ren)
+        g2m_r = remap(g2m, grid_ren, mesh_ren)
+        m2g_r = remap(m2g, mesh_ren, grid_ren)
 
     mesh_plan, mesh_layout = build_edge_plan(
         mesh_edges_r, mesh_ren.partition, world_size=world_size, edge_owner="dst",
@@ -148,30 +159,32 @@ def build_graphcast_graphs(
         pad_multiple=pad_multiple,
     )
 
-    # --- static features (renumbered order!) ---
-    mesh_xyz_r = mm.vertices[mesh_ren.inv]
-    grid_xyz_r = grid_xyz[grid_ren.inv]
-    grid_latlon_r = grid_latlon[grid_ren.inv]
-    mesh_latlon_r = xyz_to_latlon(mesh_xyz_r)
+    with spans.stage("setup.shard", num_nodes=num_grid + num_mesh,
+                     world_size=world_size):
+        # --- static features (renumbered order!) ---
+        mesh_xyz_r = mm.vertices[mesh_ren.inv]
+        grid_xyz_r = grid_xyz[grid_ren.inv]
+        grid_latlon_r = grid_latlon[grid_ren.inv]
+        mesh_latlon_r = xyz_to_latlon(mesh_xyz_r)
 
-    grid_node_static = shard_vertex_data(
-        node_static_features(grid_xyz_r, grid_latlon_r), grid_ren.counts, n_grid_pad
-    )
-    mesh_node_static = shard_vertex_data(
-        node_static_features(mesh_xyz_r, mesh_latlon_r), mesh_ren.counts, n_mesh_pad
-    )
-    mesh_edge_static = shard_edge_data(
-        edge_static_features(mesh_xyz_r, mesh_xyz_r, mesh_edges_r),
-        mesh_layout, mesh_plan.e_pad,
-    )
-    g2m_edge_static = shard_edge_data(
-        edge_static_features(grid_xyz_r, mesh_xyz_r, g2m_r), g2m_layout, g2m_plan.e_pad
-    )
-    m2g_edge_static = shard_edge_data(
-        edge_static_features(mesh_xyz_r, grid_xyz_r, m2g_r), m2g_layout, m2g_plan.e_pad
-    )
-    grid_mask = shard_vertex_data(np.ones(num_grid, np.float32), grid_ren.counts, n_grid_pad)
-    mesh_mask = shard_vertex_data(np.ones(num_mesh, np.float32), mesh_ren.counts, n_mesh_pad)
+        grid_node_static = shard_vertex_data(
+            node_static_features(grid_xyz_r, grid_latlon_r), grid_ren.counts, n_grid_pad
+        )
+        mesh_node_static = shard_vertex_data(
+            node_static_features(mesh_xyz_r, mesh_latlon_r), mesh_ren.counts, n_mesh_pad
+        )
+        mesh_edge_static = shard_edge_data(
+            edge_static_features(mesh_xyz_r, mesh_xyz_r, mesh_edges_r),
+            mesh_layout, mesh_plan.e_pad,
+        )
+        g2m_edge_static = shard_edge_data(
+            edge_static_features(grid_xyz_r, mesh_xyz_r, g2m_r), g2m_layout, g2m_plan.e_pad
+        )
+        m2g_edge_static = shard_edge_data(
+            edge_static_features(mesh_xyz_r, grid_xyz_r, m2g_r), m2g_layout, m2g_plan.e_pad
+        )
+        grid_mask = shard_vertex_data(np.ones(num_grid, np.float32), grid_ren.counts, n_grid_pad)
+        mesh_mask = shard_vertex_data(np.ones(num_mesh, np.float32), mesh_ren.counts, n_mesh_pad)
 
     return GraphCastGraphs(
         world_size=world_size,

@@ -20,7 +20,32 @@ Three pillars, one import:
   host-side spans with trace/span/parent ids shared across train, serve,
   and bench (and across process restarts), JSONL records, and a Perfetto
   (Chrome trace) exporter. One attribute read when disabled; never inside
-  traced code (lint-enforced).
+  traced code (lint-enforced). Enabled context-managed spans also hold a
+  ``jax.profiler.TraceAnnotation``, so they share the device trace's
+  clock. Names the program records (docs/tracing.md has who reads each):
+
+  ===============================================  ========  ================
+  name                                             kind      read by
+  ===============================================  ========  ================
+  setup.partition / setup.plan / setup.shard       stage     partition_s,
+                                                             plan_s, shard_s
+  setup.place; setup.graph_gen (GraphCast's)       stage     operators
+  setup.init_params / setup.init_opt_state         stage     init_s
+  train.step, train.eval > step_dispatch, block    span      span_cost.py,
+                                                             xtrace's names
+  train.recompile; compile.trace/.lower/.backend   span      operators
+  compile.count                                    counter   train.recompile
+  compile.trace_s/.lower_s/.backend_s/             counter   the experiments'
+  .cache_hits/.cache_misses                                  "compiles" log
+  plan.segsum_grid_steps / plan.segsum_used_chunks counter   segsum_grid_
+  (also per route)                                           fill_pct.*
+  plan.halo_wire_rows / plan.halo_real_rows        counter   halo_wire_
+                                                             fill_pct.train
+  ===============================================  ========  ================
+
+  Stages (``spans.stage``) are always on: their totals are in
+  ``spans.stage_totals()`` with tracing off. Counters live in
+  :data:`~dgraph_tpu.obs.metrics.default_registry`.
 - :mod:`dgraph_tpu.obs.attribution` — CPU scan-delta step-time
   attribution: per-phase ``{interior, exchange, optimizer, other}``
   timing per halo lowering on the virtual-CPU backend — bench.py's

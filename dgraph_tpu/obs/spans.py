@@ -24,6 +24,20 @@ Design rules (the :mod:`dgraph_tpu.obs.metrics` discipline):
   lease); module level is pure stdlib, enforced by the ``jax-free-module``
   lint rule.
 
+- **On the profiler's clock.** An enabled, context-managed span also
+  holds a ``jax.profiler.TraceAnnotation`` of its own name from enter to
+  exit, so under ``jax.profiler.trace`` the program's spans lie on the
+  host line of the same trace as the device operations and an idle gap
+  can be put down to one of them. The class is taken from
+  ``sys.modules`` once jax is loaded (never imported here); a span that
+  is ended by hand, possibly on another thread, gets no annotation.
+- **Stages are always on.** :func:`stage` is the entry point for work
+  done once per launch (partition, plan, shard, place, init): it keeps
+  (count, total, max, last) seconds per name in an in-process table
+  (:func:`stage_totals`) whether or not the tracer is enabled, and is a
+  normal span besides when it is. Per-step and per-request spans stay
+  opt-in through :func:`span`.
+
 One finished span -> one JSONL record (``kind="span"``), written through
 any sink with a ``write(dict)`` method (:class:`~dgraph_tpu.utils.logging.
 ExperimentLog` works as-is) or a plain path.  ``python -m
@@ -43,6 +57,7 @@ import contextvars
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Optional
@@ -64,6 +79,17 @@ _CURRENT: contextvars.ContextVar = contextvars.ContextVar(
 
 def _new_id(nbytes: int) -> str:
     return os.urandom(nbytes).hex()
+
+
+def _trace_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation(name)``, or None while jax
+    is not loaded in this process (this module never imports it)."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 class _FileSink:
@@ -111,6 +137,30 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _span_record(trace_id, span_id, parent_id, name: str, start_unix: float,
+                 dur_s: float, error: Optional[str], attrs: dict) -> dict:
+    """The one JSONL shape of a finished span."""
+    rec = {
+        "kind": "span",
+        "schema": SPAN_SCHEMA_VERSION,
+        "trace": trace_id,
+        "span": span_id,
+        "parent": parent_id,
+        "name": name,
+        "ts_unix": round(start_unix, 6),
+        "dur_ms": round(dur_s * 1e3, 3),
+        "status": "error" if error else "ok",
+        "pid": os.getpid(),
+        "tid": threading.get_ident() & 0x7FFFFFFF,
+        "thread": threading.current_thread().name,
+    }
+    if error:
+        rec["error"] = str(error)[:500]
+    if attrs:
+        rec["attrs"] = attrs
+    return rec
+
+
 class Span:
     """One timed operation: started at construction, sealed by :meth:`end`
     (or context-manager exit, which also maintains the ambient
@@ -124,7 +174,7 @@ class Span:
 
     __slots__ = (
         "_tracer", "name", "trace_id", "span_id", "parent_id", "attrs",
-        "_t0_wall", "_t0", "_token", "_done",
+        "_t0_wall", "_t0", "_token", "_done", "_annotation",
     )
 
     def __init__(self, tracer: "Tracer", name: str,
@@ -139,6 +189,7 @@ class Span:
         self._t0 = time.perf_counter()
         self._token = None
         self._done = False
+        self._annotation = None
 
     def annotate(self, **attrs) -> None:
         """Attach attributes after construction (stage timings, outcomes)."""
@@ -153,32 +204,20 @@ class Span:
         self._done = True
         if attrs:
             self.attrs.update(attrs)
-        dur_ms = (time.perf_counter() - self._t0) * 1e3
-        rec = {
-            "kind": "span",
-            "schema": SPAN_SCHEMA_VERSION,
-            "trace": self.trace_id,
-            "span": self.span_id,
-            "parent": self.parent_id,
-            "name": self.name,
-            "ts_unix": round(self._t0_wall, 6),
-            "dur_ms": round(dur_ms, 3),
-            "status": "error" if error else "ok",
-            "pid": os.getpid(),
-            "tid": threading.get_ident() & 0x7FFFFFFF,
-            "thread": threading.current_thread().name,
-        }
-        if error:
-            rec["error"] = str(error)[:500]
-        if self.attrs:
-            rec["attrs"] = self.attrs
-        self._tracer._write(rec)
+        self._tracer._write(_span_record(
+            self.trace_id, self.span_id, self.parent_id, self.name,
+            self._t0_wall, time.perf_counter() - self._t0, error, self.attrs,
+        ))
 
     def __enter__(self) -> "Span":
         self._token = _CURRENT.set(self)
+        self._annotation = _trace_annotation(self.name)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         if self._token is not None:
             _CURRENT.reset(self._token)
             self._token = None
@@ -284,6 +323,20 @@ class Tracer:
             parent_id = getattr(parent, "span_id", None)
         return Span(self, name, parent_id, dict(attrs))
 
+    def record_span(self, name: str, start_unix: float, end_unix: float,
+                    **attrs) -> None:
+        """Write a span that someone else timed (JAX's compile events
+        arrive finished, with their own start and end): parented like
+        :meth:`span`, no annotation. Disabled: nothing."""
+        if not self._enabled:
+            return
+        cur = _CURRENT.get()
+        self._write(_span_record(
+            self.trace_id, _new_id(4),
+            cur.span_id if cur is not None else self._root_parent,
+            name, start_unix, end_unix - start_unix, None, attrs,
+        ))
+
     def _write(self, rec: dict) -> None:
         sink = self._sink
         if sink is None:
@@ -327,6 +380,68 @@ def span(name: str, parent=None, **attrs):
     """Module-level :meth:`Tracer.span` on the default tracer (the form
     call sites use; one attr read when disabled)."""
     return default_tracer.span(name, parent=parent, **attrs)
+
+
+def record_span(name: str, start_unix: float, end_unix: float,
+                **attrs) -> None:
+    """Module-level :meth:`Tracer.record_span` on the default tracer."""
+    default_tracer.record_span(name, start_unix, end_unix, **attrs)
+
+
+# name -> [count, total seconds, max seconds, last seconds]: the always-on
+# table of once-per-launch stages. (obs.metrics' registry would hold it, but
+# that module imports dgraph_tpu.plan, hence jax; this one may not.)
+_STAGES: dict = {}
+_STAGES_LOCK = threading.Lock()
+
+
+class _Stage:
+    """A once-per-launch stage: its seconds always go to the table; the
+    span underneath is the tracer's (the no-op when tracing is off)."""
+
+    __slots__ = ("name", "_span", "_t0")
+
+    def __init__(self, name: str, span):
+        self.name = name
+        self._span = span
+
+    def annotate(self, **attrs) -> None:
+        self._span.annotate(**attrs)
+
+    def __enter__(self) -> "_Stage":
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        dt = time.perf_counter() - self._t0
+        with _STAGES_LOCK:
+            row = _STAGES.setdefault(self.name, [0, 0.0, 0.0, 0.0])
+            row[0] += 1
+            row[1] += dt
+            row[2] = max(row[2], dt)
+            row[3] = dt
+        return self._span.__exit__(exc_type, exc, tb)
+
+
+def stage(name: str, **attrs) -> _Stage:
+    """Context manager around work done once per launch. Always on: on
+    exit its seconds are added under ``name`` to the in-process table
+    :func:`stage_totals` reads. With the tracer enabled it is a normal
+    span too (record + profiler annotation) carrying ``attrs``, the
+    numbers that size the work. Never for per-step or per-request work
+    (that is :func:`span`, opt-in), never inside traced code."""
+    return _Stage(name, default_tracer.span(name, **attrs))
+
+
+def stage_totals() -> dict:
+    """A copy of the stage table: ``{name: {"count", "total_s", "max_s",
+    "last_s"}}`` for every stage this process has finished."""
+    with _STAGES_LOCK:
+        return {
+            name: {"count": c, "total_s": t, "max_s": m, "last_s": last}
+            for name, (c, t, m, last) in _STAGES.items()
+        }
 
 
 def enable(sink=None, trace_id: Optional[str] = None,

@@ -521,19 +521,29 @@ def sorted_segment_sum_bias_relu(
     return fn(data, segment_ids, bias, edge_weight)
 
 
-def max_chunks_hint(
+def block_chunk_counts(
     segment_ids, num_segments: int, block_e: int = 512, block_n: int = 256
-) -> int:
-    """Host-side (concrete ids) bound for ``max_chunks_per_block``."""
+):
+    """Host-side (concrete sorted ids): for each ``block_n``-row vertex
+    block, the number of ``block_e`` edge chunks its edges touch — what
+    :class:`_SortedSchedule` computes in-jit as ``chunk_counts`` before the
+    clamp. The kernels' grid is (blocks, the maximum of these), so their
+    sum over the grid's size is the share of grid steps that do work."""
     import numpy as np
 
     ids = np.asarray(segment_ids)
     nb = -(-num_segments // block_n)
     starts = np.searchsorted(ids, np.arange(nb) * block_n)
     ends = np.searchsorted(ids, np.arange(1, nb + 1) * block_n, side="left")
-    cs = starts // block_e
-    ce = -(-ends // block_e)
-    return max(1, int((ce - cs).max(initial=1)))
+    return -(-ends // block_e) - starts // block_e
+
+
+def max_chunks_hint(
+    segment_ids, num_segments: int, block_e: int = 512, block_n: int = 256
+) -> int:
+    """Host-side (concrete ids) bound for ``max_chunks_per_block``."""
+    counts = block_chunk_counts(segment_ids, num_segments, block_e, block_n)
+    return max(1, int(counts.max(initial=1)))
 
 
 # --- sorted row gather: the transpose kernel -------------------------------
@@ -812,20 +822,29 @@ def sorted_row_gather(
     )(x, ids)
 
 
+def chunk_vblock_spans(
+    segment_ids, num_rows: int, block_e: int = 512, block_n: int = 256
+):
+    """Host-side (concrete sorted ids): for each ``block_e`` edge chunk, the
+    number of ``block_n``-row vertex blocks it spans (the grid of
+    :func:`sorted_row_gather` is (chunks, the maximum of these))."""
+    import numpy as np
+
+    ids = np.clip(np.asarray(segment_ids), 0, max(num_rows - 1, 0))
+    E = ids.shape[0]
+    if E == 0:
+        return np.zeros(0, np.int64)
+    E_pad = -(-E // block_e) * block_e
+    ids_p = np.pad(ids, (0, E_pad - E), constant_values=ids[-1])
+    chunks = ids_p.reshape(-1, block_e)
+    return chunks[:, -1] // block_n - chunks[:, 0] // block_n + 1
+
+
 def max_vblocks_hint(
     segment_ids, num_rows: int, block_e: int = 512, block_n: int = 256
 ) -> int:
     """Host-side (concrete sorted ids) bound for
     :func:`sorted_row_gather`'s ``max_vblocks``: the max number of
     ``block_n``-row vertex blocks any ``block_e`` edge chunk spans."""
-    import numpy as np
-
-    ids = np.clip(np.asarray(segment_ids), 0, max(num_rows - 1, 0))
-    E = ids.shape[0]
-    if E == 0:
-        return 1
-    E_pad = -(-E // block_e) * block_e
-    ids_p = np.pad(ids, (0, E_pad - E), constant_values=ids[-1])
-    chunks = ids_p.reshape(-1, block_e)
-    span = chunks[:, -1] // block_n - chunks[:, 0] // block_n + 1
-    return max(1, int(span.max(initial=1)))
+    spans = chunk_vblock_spans(segment_ids, num_rows, block_e, block_n)
+    return max(1, int(spans.max(initial=1)))

@@ -1129,72 +1129,78 @@ def build_edge_plan(
 
     Returns (plan, layout).
     """
-    pro = _plan_build_prologue(
-        edge_index, src_partition, dst_partition, edge_owner=edge_owner,
-        sort_edges=sort_edges, sort_route=sort_route, overlap=overlap,
-        pad_multiple=pad_multiple, e_pad=e_pad, s_pad=s_pad,
-        world_size=world_size,
-    )
-    src, dst, E = pro.src, pro.dst, pro.E
-    src_partition, dst_partition = pro.src_partition, pro.dst_partition
-    homogeneous = pro.homogeneous
-    src_counts, dst_counts = pro.src_counts, pro.dst_counts
-    src_offsets, dst_offsets = pro.src_offsets, pro.dst_offsets
-    sort_route, overlap = pro.sort_route, pro.overlap
-    W = world_size
-    from dgraph_tpu import native as _native
+    from dgraph_tpu.obs import spans
 
-    if use_native is None:
-        use_native = sort_edges and _native.available() and E >= NATIVE_PLAN_MIN_EDGES
-    if use_native:
-        if not sort_edges:
-            raise ValueError("native plan core always owner-sorts (sort_edges=True)")
-        return _build_edge_plan_native(
+    with spans.stage(
+        "setup.plan", num_edges=int(np.shape(edge_index)[1]),
+        world_size=world_size,
+    ):
+        pro = _plan_build_prologue(
+            edge_index, src_partition, dst_partition, edge_owner=edge_owner,
+            sort_edges=sort_edges, sort_route=sort_route, overlap=overlap,
+            pad_multiple=pad_multiple, e_pad=e_pad, s_pad=s_pad,
+            world_size=world_size,
+        )
+        src, dst, E = pro.src, pro.dst, pro.E
+        src_partition, dst_partition = pro.src_partition, pro.dst_partition
+        homogeneous = pro.homogeneous
+        src_counts, dst_counts = pro.src_counts, pro.dst_counts
+        src_offsets, dst_offsets = pro.src_offsets, pro.dst_offsets
+        sort_route, overlap = pro.sort_route, pro.overlap
+        W = world_size
+        from dgraph_tpu import native as _native
+
+        if use_native is None:
+            use_native = sort_edges and _native.available() and E >= NATIVE_PLAN_MIN_EDGES
+        if use_native:
+            if not sort_edges:
+                raise ValueError("native plan core always owner-sorts (sort_edges=True)")
+            return _build_edge_plan_native(
+                src, dst, src_partition, dst_partition, src_offsets, dst_offsets,
+                src_counts, dst_counts, W, edge_owner, homogeneous,
+                n_src_pad, n_dst_pad, e_pad, s_pad, pad_multiple,
+                sort_route=sort_route, overlap=overlap,
+            )
+
+        prep = _numpy_plan_prep(
             src, dst, src_partition, dst_partition, src_offsets, dst_offsets,
-            src_counts, dst_counts, W, edge_owner, homogeneous,
+            src_counts, dst_counts, W, edge_owner, sort_edges,
             n_src_pad, n_dst_pad, e_pad, s_pad, pad_multiple,
-            sort_route=sort_route, overlap=overlap,
         )
 
-    prep = _numpy_plan_prep(
-        src, dst, src_partition, dst_partition, src_offsets, dst_offsets,
-        src_counts, dst_counts, W, edge_owner, sort_edges,
-        n_src_pad, n_dst_pad, e_pad, s_pad, pad_multiple,
-    )
+        # --- scatter into padded [W, E_pad] layout ---
+        def to_padded(vals, dtype, fill=0):
+            out = np.full((W, prep.e_pad), fill, dtype=dtype)
+            out[prep.edge_rank, prep.edge_slot] = vals
+            return out
 
-    # --- scatter into padded [W, E_pad] layout ---
-    def to_padded(vals, dtype, fill=0):
-        out = np.full((W, prep.e_pad), fill, dtype=dtype)
-        out[prep.edge_rank, prep.edge_slot] = vals
-        return out
+        edge_mask = np.zeros((W, prep.e_pad), dtype=np.float32)
+        edge_mask[prep.edge_rank, prep.edge_slot] = 1.0
+        # owner-side padding = n_pad: keeps sorted order monotone through the
+        # padded tail and is dropped by segment reductions
+        if prep.halo_side == "src":
+            src_idx_arr = to_padded(prep.halo_side_local_idx.astype(np.int32), np.int32)
+            dst_idx_arr = to_padded(
+                prep.own_local.astype(np.int32), np.int32, fill=prep.n_owner_pad)
+        else:
+            src_idx_arr = to_padded(
+                prep.own_local.astype(np.int32), np.int32, fill=prep.n_owner_pad)
+            dst_idx_arr = to_padded(prep.halo_side_local_idx.astype(np.int32), np.int32)
 
-    edge_mask = np.zeros((W, prep.e_pad), dtype=np.float32)
-    edge_mask[prep.edge_rank, prep.edge_slot] = 1.0
-    # owner-side padding = n_pad: keeps sorted order monotone through the
-    # padded tail and is dropped by segment reductions
-    if prep.halo_side == "src":
-        src_idx_arr = to_padded(prep.halo_side_local_idx.astype(np.int32), np.int32)
-        dst_idx_arr = to_padded(
-            prep.own_local.astype(np.int32), np.int32, fill=prep.n_owner_pad)
-    else:
-        src_idx_arr = to_padded(
-            prep.own_local.astype(np.int32), np.int32, fill=prep.n_owner_pad)
-        dst_idx_arr = to_padded(prep.halo_side_local_idx.astype(np.int32), np.int32)
-
-    return _finalize_plan(
-        src_idx_arr=src_idx_arr, dst_idx_arr=dst_idx_arr, edge_mask=edge_mask,
-        src_counts=src_counts, dst_counts=dst_counts, e_counts=prep.e_counts,
-        send_idx=prep.send_idx, send_mask=prep.send_mask,
-        s_pad_val=prep.s_pad, W=W, E=E,
-        n_src_pad_val=prep.n_src_pad, n_dst_pad_val=prep.n_dst_pad,
-        e_pad_val=prep.e_pad,
-        halo_side=prep.halo_side, homogeneous=homogeneous,
-        edge_owner=edge_owner, owner_sorted=sort_edges,
-        halo_deltas=prep.halo_deltas,
-        edge_rank=prep.edge_rank, edge_slot=prep.edge_slot,
-        halo_counts=prep.halo_counts,
-        tag="", sort_route=sort_route, overlap=overlap,
-    )
+        return _finalize_plan(
+            src_idx_arr=src_idx_arr, dst_idx_arr=dst_idx_arr, edge_mask=edge_mask,
+            src_counts=src_counts, dst_counts=dst_counts, e_counts=prep.e_counts,
+            send_idx=prep.send_idx, send_mask=prep.send_mask,
+            s_pad_val=prep.s_pad, W=W, E=E,
+            n_src_pad_val=prep.n_src_pad, n_dst_pad_val=prep.n_dst_pad,
+            e_pad_val=prep.e_pad,
+            halo_side=prep.halo_side, homogeneous=homogeneous,
+            edge_owner=edge_owner, owner_sorted=sort_edges,
+            halo_deltas=prep.halo_deltas,
+            edge_rank=prep.edge_rank, edge_slot=prep.edge_slot,
+            halo_counts=prep.halo_counts,
+            tag="", sort_route=sort_route, overlap=overlap,
+        )
 
 
 def _plan_build_prologue(
@@ -1374,6 +1380,60 @@ def _numpy_plan_prep(
     )
 
 
+# route of a sorted-id kernel -> the static hint that is its grid's width
+GRID_ROUTES = {
+    "scatter": "scatter_mc", "gather_mv": "gather_mv",
+    "halo_sort": "halo_sort_mc", "interior": "interior_mc",
+    "boundary": "boundary_mc",
+}
+
+
+def _count_grid(
+    route: str, per_rank_counts, width: Optional[int] = None
+) -> int:
+    """How full one sorted-id kernel's grid is, counted where its hint is
+    made. The grid is (rows, width): rows are a rank's vertex blocks (its
+    edge chunks, for ``gather_mv``) and width is the plan's static hint,
+    the most steps ANY row of ANY rank needs; every other row pays for it
+    in steps its ``pl.when`` guard skips. ``per_rank_counts`` holds one
+    array a rank of per-row step counts
+    (``ops.pallas_segment.block_chunk_counts`` / ``chunk_vblock_spans``);
+    ``width`` defaults to their maximum (a resumed sharded build passes
+    the plan's, which shards it did not rebuild may have set). Adds the
+    registry counters ``plan.segsum_grid_steps`` (rows x width) and
+    ``plan.segsum_used_chunks`` (the counts' sum), each also under
+    ``.<route>``, and returns the width. Host numbers only: nothing here
+    reaches the plan."""
+    from dgraph_tpu.obs.metrics import default_registry
+
+    if width is None:
+        width = max(
+            [1] + [int(c.max(initial=1)) for c in per_rank_counts])
+    rows = sum(len(c) for c in per_rank_counts)
+    used = sum(int(c.sum()) for c in per_rank_counts)
+    for suffix in ("", "." + route):
+        default_registry.counter(
+            "plan.segsum_grid_steps" + suffix, rows * width)
+        default_registry.counter("plan.segsum_used_chunks" + suffix, used)
+    return width
+
+
+def halo_wire_rows(plan: "EdgePlan", impl: str) -> int:
+    """Rows one halo exchange puts on the wire, over all ranks, under the
+    lowering ``impl``, padding included (``obs.footprint`` prices the same
+    rows in bytes): all_to_all moves every remote peer block at ``s_pad``;
+    the round lowerings move one ``s_pad`` block a live delta; a compiled
+    schedule moves its rounds' padded widths."""
+    W, S = plan.world_size, plan.halo.s_pad
+    if impl == "all_to_all":
+        return W * (W - 1) * S
+    if impl in ("ppermute", "overlap", "pallas_p2p"):
+        return len(plan.halo_deltas) * W * S
+    if impl == "sched" and plan.halo_schedule is not None:
+        return W * sum(plan.halo_schedule.round_rows())
+    return 0
+
+
 def _finalize_plan(
     *, src_idx_arr, dst_idx_arr, edge_mask, src_counts, dst_counts, e_counts,
     send_idx, send_mask, s_pad_val, W, E, n_src_pad_val, n_dst_pad_val,
@@ -1390,24 +1450,24 @@ def _finalize_plan(
     scatter_block_e, scatter_block_n = SCATTER_BLOCK_E, SCATTER_BLOCK_N
     if owner_sorted:
         from dgraph_tpu.ops.pallas_segment import (
-            max_chunks_hint,
-            max_vblocks_hint,
+            block_chunk_counts,
+            chunk_vblock_spans,
         )
 
-        scatter_mc = max(
-            max_chunks_hint(
+        scatter_mc = _count_grid("scatter", [
+            block_chunk_counts(
                 owner_idx_arr[r], n_owner_pad,
                 block_e=scatter_block_e, block_n=scatter_block_n,
             )
             for r in range(W)
-        )
-        gather_mv = max(
-            max_vblocks_hint(
+        ])
+        gather_mv = _count_grid("gather_mv", [
+            chunk_vblock_spans(
                 owner_idx_arr[r], n_owner_pad,
                 block_e=scatter_block_e, block_n=scatter_block_n,
             )
             for r in range(W)
-        )
+        ])
     else:
         scatter_mc = 1
         gather_mv = 0
@@ -1416,7 +1476,7 @@ def _finalize_plan(
     halo_sort_perm = halo_sorted_ids = None
     halo_sort_mc = 1
     if sort_route:
-        from dgraph_tpu.ops.pallas_segment import max_chunks_hint
+        from dgraph_tpu.ops.pallas_segment import block_chunk_counts
 
         halo_idx_arr = src_idx_arr if halo_side == "src" else dst_idx_arr
         n_halo_rows = (
@@ -1426,13 +1486,13 @@ def _finalize_plan(
             np.int32
         )
         halo_sorted_ids = np.take_along_axis(halo_idx_arr, halo_sort_perm, axis=1)
-        halo_sort_mc = max(
-            max_chunks_hint(
+        halo_sort_mc = _count_grid("halo_sort", [
+            block_chunk_counts(
                 halo_sorted_ids[r], n_halo_rows,
                 block_e=scatter_block_e, block_n=scatter_block_n,
             )
             for r in range(W)
-        )
+        ])
 
     overlap_spec = None
     if overlap:
@@ -1486,6 +1546,14 @@ def _finalize_plan(
         dst_counts=dst_counts,
     )
     eff = plan_efficiency(plan, layout)
+    # the exchange's fill under the lowering the run will execute: the
+    # quantities behind eff's halo_wire_fill_*, as registry counters
+    from dgraph_tpu.obs.metrics import default_registry
+
+    default_registry.counter(
+        "plan.halo_wire_rows", halo_wire_rows(plan, eff["halo_impl"]))
+    default_registry.counter(
+        "plan.halo_real_rows", int(np.asarray(halo_counts).sum()))
     _logger.info(
         "EdgePlan built%s: W=%d E=%d e_pad=%d (fill %.3f) s_pad=%d "
         "halo_fill_active=%.3f wire_fill[a2a=%.3f pp=%.3f] deltas=%d -> %s",
@@ -1501,7 +1569,9 @@ def _overlap_rows_for_rank(
     s_pad, W, e_pad, e_int_pad, e_bnd_pad, owner_sorted,
     scatter_block_e, scatter_block_n,
 ):
-    """ONE rank's interior/boundary split rows + Pallas hints — the single
+    """ONE rank's interior/boundary split rows + the per-block chunk counts
+    its Pallas hints are the maxima of (route -> counts, see
+    :func:`_count_grid`; empty unless ``owner_sorted``) — the single
     per-rank core behind both build modes: the monolithic
     :func:`_build_overlap_spec` stacks these rows into an
     :class:`OverlapSpec`, and the streaming shard assembler
@@ -1543,17 +1613,17 @@ def _overlap_rows_for_rank(
         bnd_src = rebased
     else:
         bnd_dst = rebased
-    interior_mc = boundary_mc = 1
+    counts = {}
     if owner_sorted:
-        from dgraph_tpu.ops.pallas_segment import max_chunks_hint
+        from dgraph_tpu.ops.pallas_segment import block_chunk_counts
 
         int_owner = int_dst if halo_side == "src" else int_src
         bnd_owner = bnd_dst if halo_side == "src" else bnd_src
-        interior_mc = max_chunks_hint(
+        counts["interior"] = block_chunk_counts(
             int_owner, n_owner_pad,
             block_e=scatter_block_e, block_n=scatter_block_n,
         )
-        boundary_mc = max_chunks_hint(
+        counts["boundary"] = block_chunk_counts(
             bnd_owner, n_owner_pad,
             block_e=scatter_block_e, block_n=scatter_block_n,
         )
@@ -1565,7 +1635,7 @@ def _overlap_rows_for_rank(
         "num_interior": int(is_int.sum()),
         "num_boundary": int(is_bnd.sum()),
     }
-    return rows, interior_mc, boundary_mc
+    return rows, counts
 
 
 def _build_overlap_spec(
@@ -1604,6 +1674,12 @@ def _build_overlap_spec(
         for r in range(W)
     ]
     rows = [p[0] for p in per_rank]
+    interior_mc = boundary_mc = 1
+    if owner_sorted:
+        interior_mc = _count_grid(
+            "interior", [p[1]["interior"] for p in per_rank])
+        boundary_mc = _count_grid(
+            "boundary", [p[1]["boundary"] for p in per_rank])
 
     def stack(key):
         return np.stack([row[key] for row in rows])
@@ -1616,8 +1692,7 @@ def _build_overlap_spec(
         num_interior=n_int.astype(np.int32),
         num_boundary=n_bnd.astype(np.int32),
         e_int_pad=e_int_pad, e_bnd_pad=e_bnd_pad,
-        interior_mc=max(p[1] for p in per_rank),
-        boundary_mc=max(p[2] for p in per_rank),
+        interior_mc=interior_mc, boundary_mc=boundary_mc,
     )
 
 
@@ -1751,10 +1826,11 @@ def shard_nbytes_estimate(statics: dict) -> int:
 def _assemble_shard_payload(prep, r: int, *, sort_edges: bool,
                             sort_route: bool, overlap: bool,
                             overlap_pads: tuple = (None, None)):
-    """One rank's plan arrays + Pallas hints, assembled from the shared
-    numpy skeleton. Row-for-row identical to what the monolithic path's
-    ``[W, E_pad]`` stack holds at index ``r`` (the property the
-    kill-and-resume bit-parity pin rides on)."""
+    """One rank's plan arrays + Pallas hints + the per-row step counts the
+    hints are the maxima of (route -> counts, for :func:`_count_grid`),
+    assembled from the shared numpy skeleton. Row-for-row identical to
+    what the monolithic path's ``[W, E_pad]`` stack holds at index ``r``
+    (the property the kill-and-resume bit-parity pin rides on)."""
     W, E_pad = prep.W, prep.e_pad
     sel = prep.edge_rank == r
     slots = prep.edge_slot[sel]
@@ -1771,29 +1847,30 @@ def _assemble_shard_payload(prep, r: int, *, sort_edges: bool,
 
     hints = {"scatter_mc": 1, "gather_mv": 0, "halo_sort_mc": 1,
              "interior_mc": 1, "boundary_mc": 1}
+    counts = {}
     if sort_edges:
         from dgraph_tpu.ops.pallas_segment import (
-            max_chunks_hint,
-            max_vblocks_hint,
+            block_chunk_counts,
+            chunk_vblock_spans,
         )
 
-        hints["scatter_mc"] = max_chunks_hint(
+        counts["scatter"] = block_chunk_counts(
             own_row, prep.n_owner_pad,
             block_e=SCATTER_BLOCK_E, block_n=SCATTER_BLOCK_N,
         )
-        hints["gather_mv"] = max_vblocks_hint(
+        counts["gather_mv"] = chunk_vblock_spans(
             own_row, prep.n_owner_pad,
             block_e=SCATTER_BLOCK_E, block_n=SCATTER_BLOCK_N,
         )
 
     perm = sorted_ids = None
     if sort_route:
-        from dgraph_tpu.ops.pallas_segment import max_chunks_hint
+        from dgraph_tpu.ops.pallas_segment import block_chunk_counts
 
         n_halo_rows = prep.n_halo_pad + W * prep.s_pad
         perm = np.argsort(halo_row, kind="stable").astype(np.int32)
         sorted_ids = halo_row[perm]
-        hints["halo_sort_mc"] = max_chunks_hint(
+        counts["halo_sort"] = block_chunk_counts(
             sorted_ids, n_halo_rows,
             block_e=SCATTER_BLOCK_E, block_n=SCATTER_BLOCK_N,
         )
@@ -1812,12 +1889,14 @@ def _assemble_shard_payload(prep, r: int, *, sort_edges: bool,
         "overlap": None,
     }
     if overlap:
-        payload["overlap"], ov_hints = _assemble_overlap_rows(
+        payload["overlap"], ov_counts = _assemble_overlap_rows(
             prep, src_row, dst_row, mask_row, sort_edges,
             e_int_pad=overlap_pads[0], e_bnd_pad=overlap_pads[1],
         )
-        hints.update(ov_hints)
-    return payload, hints
+        counts.update(ov_counts)
+    for route, c in counts.items():
+        hints[GRID_ROUTES[route]] = max(1, int(c.max(initial=1)))
+    return payload, hints, counts
 
 
 def _assemble_overlap_rows(prep, src_row, dst_row, mask_row,
@@ -1829,7 +1908,7 @@ def _assemble_overlap_rows(prep, src_row, dst_row, mask_row,
     monolithic splits are structurally identical). The subset pads are
     the global maxima the manifest statics record
     (:func:`_shard_statics`)."""
-    rows, interior_mc, boundary_mc = _overlap_rows_for_rank(
+    return _overlap_rows_for_rank(
         src_row, dst_row, mask_row,
         halo_side=prep.halo_side, n_halo_pad=prep.n_halo_pad,
         n_owner_pad=prep.n_owner_pad, s_pad=prep.s_pad, W=prep.W,
@@ -1837,7 +1916,6 @@ def _assemble_overlap_rows(prep, src_row, dst_row, mask_row,
         owner_sorted=sort_edges,
         scatter_block_e=SCATTER_BLOCK_E, scatter_block_n=SCATTER_BLOCK_N,
     )
-    return rows, {"interior_mc": interior_mc, "boundary_mc": boundary_mc}
 
 
 def _content_fingerprint(edge_index, src_partition, dst_partition) -> str:
@@ -1921,102 +1999,113 @@ def build_plan_shards(
     is already content-derived — a constant label would let a resumed
     build adopt shards from different inputs with coinciding statics.
     """
-    from dgraph_tpu import chaos
-    from dgraph_tpu import plan_shards as ps
+    from dgraph_tpu.obs import spans
 
-    if use_native:
-        raise ValueError(
-            "build_plan_shards streams through the numpy per-rank "
-            "core; use_native=True would materialize the full [W, E_pad] "
-            "stack this mode exists to avoid"
-        )
-    if not fingerprint:
-        # an un-keyed manifest must still be bound to the build INPUTS:
-        # statics (counts, pads) can coincide between two different edge
-        # lists, and a resumed build that adopts shards from the other
-        # one is a silently wrong comm plan
-        fingerprint = _content_fingerprint(
-            edge_index, src_partition, dst_partition
-        )
-    pro = _plan_build_prologue(
-        edge_index, src_partition, dst_partition, edge_owner=edge_owner,
-        sort_edges=sort_edges, sort_route=sort_route, overlap=overlap,
-        pad_multiple=pad_multiple, e_pad=e_pad, s_pad=s_pad,
-        world_size=world_size,
-    )
-    homogeneous, E, W = pro.homogeneous, pro.E, world_size
-    src_counts, dst_counts = pro.src_counts, pro.dst_counts
-    sort_route, overlap = pro.sort_route, pro.overlap
+    with spans.stage(
+        "setup.plan", num_edges=int(np.shape(edge_index)[1]),
+        world_size=world_size, sharded=True,
+    ):
+        from dgraph_tpu import chaos
+        from dgraph_tpu import plan_shards as ps
 
-    prep = _numpy_plan_prep(
-        pro.src, pro.dst, pro.src_partition, pro.dst_partition,
-        pro.src_offsets, pro.dst_offsets,
-        src_counts, dst_counts, W, edge_owner, sort_edges,
-        n_src_pad, n_dst_pad, e_pad, s_pad, pad_multiple,
-    )
-    statics = _shard_statics(
-        prep, homogeneous=homogeneous, edge_owner=edge_owner,
-        sort_edges=sort_edges, sort_route=sort_route, overlap=overlap,
-    )
-    writer = ps.PlanShardWriter(
-        out_dir,
-        fingerprint=fingerprint,
-        world_size=W,
-        statics=statics,
-        build_kwargs={
-            "edge_owner": edge_owner, "pad_multiple": pad_multiple,
-            "sort_edges": sort_edges, "sort_route": bool(sort_route),
-            "overlap": bool(overlap), "num_edges": E,
-        },
-        memory_budget_bytes=memory_budget_bytes,
-        resume=resume,
-        rebuild_ranks=rebuild_ranks,
-    )
-    # fail BEFORE assembling anything when even one shard cannot fit
-    writer.check_budget(shard_nbytes_estimate(statics))
-    built = 0
-    for r in range(W):
-        if writer.done(r):
-            continue
-        chaos.fire("plan.build_shard", index=r)
-        payload, hints = _assemble_shard_payload(
-            prep, r, sort_edges=sort_edges, sort_route=sort_route,
-            overlap=overlap,
-            overlap_pads=(statics.get("e_int_pad"), statics.get("e_bnd_pad")),
+        if use_native:
+            raise ValueError(
+                "build_plan_shards streams through the numpy per-rank "
+                "core; use_native=True would materialize the full [W, E_pad] "
+                "stack this mode exists to avoid"
+            )
+        if not fingerprint:
+            # an un-keyed manifest must still be bound to the build INPUTS:
+            # statics (counts, pads) can coincide between two different edge
+            # lists, and a resumed build that adopts shards from the other
+            # one is a silently wrong comm plan
+            fingerprint = _content_fingerprint(
+                edge_index, src_partition, dst_partition
+            )
+        pro = _plan_build_prologue(
+            edge_index, src_partition, dst_partition, edge_owner=edge_owner,
+            sort_edges=sort_edges, sort_route=sort_route, overlap=overlap,
+            pad_multiple=pad_multiple, e_pad=e_pad, s_pad=s_pad,
+            world_size=world_size,
         )
-        writer.write(r, payload, hints=hints)
-        built += 1
-    # plan-level Pallas hints are maxima over the per-shard values the
-    # manifest recorded — identical whether the shards were built in one
-    # pass or across resumed processes
-    entries = writer.manifest["shards"]
-    hint_names = ("scatter_mc", "gather_mv", "halo_sort_mc",
-                  "interior_mc", "boundary_mc")
-    hints_max = {
-        name: max(int(entries[str(r)].get("hints", {}).get(name, 0))
-                  for r in range(W))
-        for name in hint_names
-    }
-    # the layout sidecar is O(E) (edge_rank/edge_slot): at papers100M
-    # scale it pickles to tens of GB, and atomic_pickle_dump transiently
-    # doubles that on disk — callers that never consume it (the p100m
-    # plan stage, per-host shard loading) opt out with write_layout=False
-    layout_payload = None
-    if write_layout:
-        layout_payload = {
-            "edge_rank": prep.edge_rank,
-            "edge_slot": prep.edge_slot,
-            "halo_counts": prep.halo_counts,
-            "src_counts": src_counts,
-            "dst_counts": dst_counts,
+        homogeneous, E, W = pro.homogeneous, pro.E, world_size
+        src_counts, dst_counts = pro.src_counts, pro.dst_counts
+        sort_route, overlap = pro.sort_route, pro.overlap
+
+        prep = _numpy_plan_prep(
+            pro.src, pro.dst, pro.src_partition, pro.dst_partition,
+            pro.src_offsets, pro.dst_offsets,
+            src_counts, dst_counts, W, edge_owner, sort_edges,
+            n_src_pad, n_dst_pad, e_pad, s_pad, pad_multiple,
+        )
+        statics = _shard_statics(
+            prep, homogeneous=homogeneous, edge_owner=edge_owner,
+            sort_edges=sort_edges, sort_route=sort_route, overlap=overlap,
+        )
+        writer = ps.PlanShardWriter(
+            out_dir,
+            fingerprint=fingerprint,
+            world_size=W,
+            statics=statics,
+            build_kwargs={
+                "edge_owner": edge_owner, "pad_multiple": pad_multiple,
+                "sort_edges": sort_edges, "sort_route": bool(sort_route),
+                "overlap": bool(overlap), "num_edges": E,
+            },
+            memory_budget_bytes=memory_budget_bytes,
+            resume=resume,
+            rebuild_ranks=rebuild_ranks,
+        )
+        # fail BEFORE assembling anything when even one shard cannot fit
+        writer.check_budget(shard_nbytes_estimate(statics))
+        built = 0
+        grid_counts: dict = {}  # of the shards assembled in this process
+        for r in range(W):
+            if writer.done(r):
+                continue
+            chaos.fire("plan.build_shard", index=r)
+            payload, hints, counts = _assemble_shard_payload(
+                prep, r, sort_edges=sort_edges, sort_route=sort_route,
+                overlap=overlap,
+                overlap_pads=(statics.get("e_int_pad"), statics.get("e_bnd_pad")),
+            )
+            for route, c in counts.items():
+                grid_counts.setdefault(route, []).append(c)
+            writer.write(r, payload, hints=hints)
+            built += 1
+        # plan-level Pallas hints are maxima over the per-shard values the
+        # manifest recorded — identical whether the shards were built in one
+        # pass or across resumed processes
+        entries = writer.manifest["shards"]
+        hint_names = ("scatter_mc", "gather_mv", "halo_sort_mc",
+                      "interior_mc", "boundary_mc")
+        hints_max = {
+            name: max(int(entries[str(r)].get("hints", {}).get(name, 0))
+                      for r in range(W))
+            for name in hint_names
         }
-    manifest = writer.finalize(layout_payload, statics_update=hints_max)
-    _logger.info(
-        "sharded EdgePlan built in %s: W=%d E=%d e_pad=%d s_pad=%d "
-        "(%d shard(s) assembled this run, %d resumed)",
-        out_dir, W, E, prep.e_pad, prep.s_pad, built, W - built,
-    )
-    return manifest
+        for route, per_rank in grid_counts.items():
+            _count_grid(route, per_rank, width=hints_max[GRID_ROUTES[route]])
+        # the layout sidecar is O(E) (edge_rank/edge_slot): at papers100M
+        # scale it pickles to tens of GB, and atomic_pickle_dump transiently
+        # doubles that on disk — callers that never consume it (the p100m
+        # plan stage, per-host shard loading) opt out with write_layout=False
+        layout_payload = None
+        if write_layout:
+            layout_payload = {
+                "edge_rank": prep.edge_rank,
+                "edge_slot": prep.edge_slot,
+                "halo_counts": prep.halo_counts,
+                "src_counts": src_counts,
+                "dst_counts": dst_counts,
+            }
+        manifest = writer.finalize(layout_payload, statics_update=hints_max)
+        _logger.info(
+            "sharded EdgePlan built in %s: W=%d E=%d e_pad=%d s_pad=%d "
+            "(%d shard(s) assembled this run, %d resumed)",
+            out_dir, W, E, prep.e_pad, prep.s_pad, built, W - built,
+        )
+        return manifest
 
 
 def build_edge_plan_sharded(

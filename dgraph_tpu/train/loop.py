@@ -26,6 +26,7 @@ from dgraph_tpu.comm.mesh import (
     plan_in_specs,
     put_on_graph_axis,
     squeeze_plan,
+    tree_size,
 )
 from dgraph_tpu.obs.metrics import StepMetrics
 from dgraph_tpu.plan import EdgePlan
@@ -60,15 +61,24 @@ def init_params(model, mesh, plan: EdgePlan, batch: dict, seed: int = 0,
         out_specs=P(),
         **shard_map_checks(relax="init outputs replicated by construction"),
     )
-    with jax.set_mesh(mesh):
-        return jax.jit(fn)(batch, plan)
+    from dgraph_tpu.obs import spans
+
+    # always-on stage: what the host does inside the call (trace, compile,
+    # issue); the device's part is not waited for
+    with spans.stage("setup.init_params") as st, jax.set_mesh(mesh):
+        params = jax.jit(fn)(batch, plan)
+        st.annotate(**tree_size(params))
+        return params
 
 
 def init_opt_state(optimizer: optax.GradientTransformation, params, mesh):
     """``optimizer.init(params)`` under the mesh, so its step counter gets
     the same (mesh-typed) aval the train step returns — initialised outside
     it, step 1 sees a new input type, retraces and compiles again."""
-    with jax.set_mesh(mesh):
+    from dgraph_tpu.obs import spans
+
+    with spans.stage("setup.init_opt_state", **tree_size(params)), \
+            jax.set_mesh(mesh):
         return optimizer.init(params)
 
 
@@ -335,6 +345,7 @@ def fit(
 
     from dgraph_tpu import chaos
     from dgraph_tpu.obs import spans
+    from dgraph_tpu.utils.compile_cache import compile_totals
 
     optimizer = optimizer or optax.adam(1e-2)
     # vmask rides along for models whose batch_args want it (harmless
@@ -353,6 +364,10 @@ def fit(
     )
     eval_step = make_eval_step(model, mesh, loss_fn=loss_fn, batch_args=batch_args)
 
+    def compiles() -> float:
+        # 0 where enable_compile_cache was never called: nothing is reported
+        return compile_totals().get("compile.count", 0.0)
+
     history = []
     with jax.set_mesh(mesh):
         for epoch in range(num_epochs):
@@ -360,17 +375,33 @@ def fit(
             if chaos.fire("grads", index=epoch):
                 # host-side poison of this epoch's features only — same
                 # shapes, same executable, one step's grads go non-finite
-                bt = dict(batch_tr, x=put_on_graph_axis(
-                    chaos.poison_array(batch_tr["x"]), mesh))
-            # host-boundary span (never inside the jitted step): one attr
-            # read when tracing is off
-            with spans.span("train.epoch", epoch=epoch):
-                params, opt_state, m = train_step(params, opt_state, bt, plan)
-            rec = {"epoch": epoch, "loss": float(m["loss"]), "acc": float(m["accuracy"])}
+                # (a plain device_put: setup.place is once a launch)
+                bt = dict(batch_tr, x=jax.device_put(
+                    chaos.poison_array(batch_tr["x"]), batch_tr["x"].sharding))
+            if epoch == 2:
+                compiled = compiles()
+            # host-boundary spans (never inside the jitted step), one attr
+            # read each when tracing is off: train.step ends once the loss
+            # is on the host; its children are the dispatch and the wait
+            with spans.span("train.step", epoch=epoch):
+                with spans.span("step_dispatch"):
+                    params, opt_state, m = train_step(
+                        params, opt_state, bt, plan)
+                with spans.span("block"):
+                    rec = {"epoch": epoch, "loss": float(m["loss"]),
+                           "acc": float(m["accuracy"])}
             if log_every and epoch % log_every == 0:
-                ev = eval_step(params, batch_va, plan)
-                rec["val_loss"] = float(ev["loss"])
-                rec["val_acc"] = float(ev["accuracy"])
+                with spans.span("train.eval", epoch=epoch):
+                    with spans.span("step_dispatch"):
+                        ev = eval_step(params, batch_va, plan)
+                    with spans.span("block"):
+                        rec["val_loss"] = float(ev["loss"])
+                        rec["val_acc"] = float(ev["accuracy"])
                 print(rec)
             history.append(rec)
+        # every shape was seen by the end of the second epoch (train and,
+        # at epoch 0, eval): a compile after it is a retrace
+        if num_epochs > 2 and compiles() > compiled:
+            spans.span("train.recompile", compiles=compiles() - compiled,
+                       after_epoch=2).end()
     return params, history

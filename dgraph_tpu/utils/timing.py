@@ -1,98 +1,17 @@
-"""Phase timing / profiling.
+"""Device-timing helpers for single-op measurements.
 
-Reference parity: ``DGraph/utils/TimingReport.py:19-84`` (static timer
-registry; start/stop wrap CUDA events with communicator barriers; context
-manager form; add_time; JSON-able report) and the module-global TIMINGS dict
-(``NCCLBackendEngine.py:32``).
-
-TPU-first: there are no CUDA events; accurate device timing comes from
-``jax.block_until_ready`` around host timers (what ``stop`` does here), and
-deep profiling from ``jax.profiler.trace`` (Perfetto), which
-:func:`trace_to` wraps. ``jax.named_scope`` replaces nvtx.annotate
-(``microbenchmark_graphcast.py:126``).
+The reference's static phase-timer registry (``DGraph/utils``) has no twin
+here: once-per-launch phases are :func:`dgraph_tpu.obs.spans.
+stage` (always-on totals, ``spans.stage_totals()``), per-step spans are
+:func:`dgraph_tpu.obs.spans.span`, and both land in a ``jax.profiler.trace``
+on the profiler's clock. ``jax.named_scope`` replaces nvtx.annotate
+(``microbenchmark_graphcast.py:126``); what is left here is the scan-delta
+protocol for timing one op on the device.
 """
 
 from __future__ import annotations
 
-import contextlib
-import json
-import time
-from collections import defaultdict
-from typing import Optional
-
 import jax
-
-
-class TimingReport:
-    """Static-registry phase timer (same surface as the reference's)."""
-
-    _starts: dict = {}
-    _times: dict = defaultdict(list)
-
-    @classmethod
-    def start(cls, name: str) -> None:
-        cls._starts[name] = time.perf_counter()
-
-    @classmethod
-    def stop(cls, name: str, sync: Optional[object] = None) -> float:
-        """Stop the timer; if ``sync`` is a jax array (or pytree), blocks on
-        it first so the interval covers device execution (the CUDA-event
-        synchronize analogue)."""
-        if sync is not None:
-            jax.block_until_ready(sync)
-        dt = (time.perf_counter() - cls._starts.pop(name)) * 1000.0
-        cls._times[name].append(dt)
-        return dt
-
-    @classmethod
-    @contextlib.contextmanager
-    def time(cls, name: str):
-        """Context form; set ``result["sync"]`` to a jax value to make the
-        stop block on device completion."""
-        cls.start(name)
-        result = {}
-        try:
-            yield result
-        finally:
-            cls.stop(name, sync=result.get("sync"))
-
-    @classmethod
-    def add_time(cls, name: str, ms: float) -> None:
-        cls._times[name].append(ms)
-
-    @classmethod
-    def report(cls) -> dict:
-        """name -> {mean, std, count, total} in ms."""
-        import numpy as np
-
-        out = {}
-        for k, v in cls._times.items():
-            a = np.asarray(v)
-            out[k] = {
-                "mean_ms": float(a.mean()),
-                "std_ms": float(a.std()),
-                "count": len(v),
-                "total_ms": float(a.sum()),
-            }
-        return out
-
-    @classmethod
-    def dump_json(cls, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(cls.report(), f, indent=2)
-
-    @classmethod
-    def reset(cls) -> None:
-        cls._starts.clear()
-        cls._times.clear()
-
-
-@contextlib.contextmanager
-def trace_to(logdir: str):
-    """Perfetto/TensorBoard trace of the enclosed block (the torch.profiler
-    analogue, ``train_graphcast.py:161-169``)."""
-    with jax.profiler.trace(logdir):
-        yield
 
 
 named_scope = jax.named_scope

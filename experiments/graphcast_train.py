@@ -62,7 +62,9 @@ def main(cfg: Config):
         checkpoint_keys, restore_checkpoint, save_checkpoint)
     from dgraph_tpu.train.ema import ema_init, ema_update
     from dgraph_tpu.train.schedules import graphcast_three_phase
-    from dgraph_tpu.utils import ExperimentLog, TimingReport
+    from dgraph_tpu.obs import spans
+    from dgraph_tpu.utils.compile_cache import compile_totals
+    from dgraph_tpu.utils import ExperimentLog
 
     world = cfg.world_size or len(jax.devices())
     mesh = make_graph_mesh(ranks_per_graph=world)
@@ -70,9 +72,7 @@ def main(cfg: Config):
     log = ExperimentLog(cfg.log_path)
     log.write(startup_record("experiments.graphcast_train"))
 
-    TimingReport.start("graph_build")
     graphs = build_graphcast_graphs(cfg.mesh_level, cfg.num_lat, cfg.num_lon, world)
-    TimingReport.stop("graph_build")
     ds = SyntheticWeatherDataset(graphs, cfg.num_lat, cfg.num_lon, cfg.channels)
 
     model = GraphCast(
@@ -278,7 +278,7 @@ def main(cfg: Config):
                     "rollout_eval": label, "steps": cfg.eval_rollout,
                     "rmse_per_step": [round(float(r), 5) for r in rmse],
                 })
-    log.write({"timing": TimingReport.report()})
+    log.write({"stages": spans.stage_totals(), "compiles": compile_totals()})
 
 
 def _microbenchmark(model, params, statics, plans, mesh, comm, ds, log):

@@ -1,7 +1,7 @@
 """Full-graph node classification (the reference's ``experiments/OGB/main.py``).
 
 Trains GCN / GraphSAGE / GAT on a partitioned graph over a TPU mesh, with
-per-epoch timing, accuracy logs, and TimingReport phase breakdown. Data: a
+per-epoch timing, accuracy logs, and the set-up stage totals. Data: a
 synthetic SBM graph by default (this environment has no ogb package / no
 egress), or any ``.npz`` with edge_index/features/labels/train_mask/... via
 ``--data.path`` — the `ogbn-*` datasets exported to npz load unchanged.
@@ -133,7 +133,9 @@ def main(cfg: Config):
     )
     from dgraph_tpu.obs import plan_footprint, startup_record
     from dgraph_tpu.obs.metrics import step_record
-    from dgraph_tpu.utils import ExperimentLog, TimingReport
+    from dgraph_tpu.obs import spans
+    from dgraph_tpu.utils.compile_cache import compile_totals
+    from dgraph_tpu.utils import ExperimentLog
 
     world = cfg.world_size or len(jax.devices())
     mesh = make_graph_mesh(ranks_per_graph=world)
@@ -142,7 +144,6 @@ def main(cfg: Config):
     log.write(startup_record("experiments.ogb_gcn"))
     data = load_data(cfg.data)
 
-    TimingReport.start("partition+plan")
     g = DistributedGraph.from_global(
         data["edge_index"],
         data["features"],
@@ -152,7 +153,6 @@ def main(cfg: Config):
         partition_method=cfg.data.partition,
         add_symmetric_norm=cfg.model == "gcn",
     )
-    TimingReport.stop("partition+plan")
     # static comm accounting BEFORE any device step: what will this plan
     # move per halo exchange, and how imbalanced is it?
     log.write({
@@ -223,7 +223,8 @@ def main(cfg: Config):
     log.write(
         {
             "avg_epoch_ms_excl_first": round(float(np.mean(epoch_times[1:])), 2),
-            "timing": TimingReport.report(),
+            "stages": spans.stage_totals(),
+            "compiles": compile_totals(),
         }
     )
 

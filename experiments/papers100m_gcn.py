@@ -181,7 +181,9 @@ def main(cfg: Config):
     from dgraph_tpu.train.checkpoint import cached_edge_plan
     from dgraph_tpu.models import GCN
     from dgraph_tpu.train.loop import init_params, make_train_step
-    from dgraph_tpu.utils import ExperimentLog, TimingReport
+    from dgraph_tpu.obs import spans
+    from dgraph_tpu.utils.compile_cache import compile_totals
+    from dgraph_tpu.utils import ExperimentLog
 
     if cfg.plan_only:
         # host-only flow: never touch the accelerator backend (an offline
@@ -238,18 +240,19 @@ def main(cfg: Config):
         log.write({"synthetic_nodes": V, "edges": int(edge_index.shape[1])})
 
     V = feats.shape[0]
-    TimingReport.start("partition")
-    new_edges, ren = pt.partition_graph(
-        edge_index, V, world, method=cfg.partition_method
-    )
-    TimingReport.stop("partition")
+    # the stage names DistributedGraph.from_global uses (this script streams
+    # its features instead of calling it); setup.plan is inside the builder
+    sizes = dict(num_nodes=int(V), num_edges=int(edge_index.shape[1]),
+                 world_size=world, method=cfg.partition_method)
+    with spans.stage("setup.partition", **sizes):
+        new_edges, ren = pt.partition_graph(
+            edge_index, V, world, method=cfg.partition_method
+        )
 
-    TimingReport.start("plan_build")
     plan_np, layout = cached_edge_plan(
         cfg.plan_cache, new_edges, ren.partition, world_size=world,
         pad_multiple=cfg.pad_multiple,
     )
-    TimingReport.stop("plan_build")
     n_pad = plan_np.n_src_pad
     # static comm accounting at the training dtype/width before sharding
     log.write({
@@ -261,22 +264,21 @@ def main(cfg: Config):
         ),
     })
 
-    TimingReport.start("shard_data")
     # blocks stream from the (possibly memmapped) source straight onto the
     # mesh, one device's rows at a time — neither feats[ren.inv] nor the
     # stacked [W, n_pad, F] copy ever exists host-side (~57 GB at real
     # papers100M scale); multi-controller hosts materialize only their own
     # devices' blocks
-    x = mm.shard_rows_to_device(
-        feats, ren.inv, ren.offsets, n_pad, mesh, dtype=np.float32
-    )
-    y = mm.shard_rows_to_device(
-        labels, ren.inv, ren.offsets, n_pad, mesh, dtype=np.int32
-    )
-    m = mm.shard_rows_to_device(
-        train_mask, ren.inv, ren.offsets, n_pad, mesh, dtype=np.float32
-    )
-    TimingReport.stop("shard_data")
+    with spans.stage("setup.shard", **sizes):
+        x = mm.shard_rows_to_device(
+            feats, ren.inv, ren.offsets, n_pad, mesh, dtype=np.float32
+        )
+        y = mm.shard_rows_to_device(
+            labels, ren.inv, ren.offsets, n_pad, mesh, dtype=np.int32
+        )
+        m = mm.shard_rows_to_device(
+            train_mask, ren.inv, ren.offsets, n_pad, mesh, dtype=np.float32
+        )
 
     dtype = jnp.bfloat16 if cfg.bfloat16 else None
     if cfg.remat:
@@ -310,7 +312,8 @@ def main(cfg: Config):
     log.write(
         {
             "avg_epoch_s_excl_first": round(float(np.mean(times[1:])), 3) if len(times) > 1 else None,
-            "timing": TimingReport.report(),
+            "stages": spans.stage_totals(),
+            "compiles": compile_totals(),
         }
     )
 

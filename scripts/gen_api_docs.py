@@ -33,7 +33,7 @@ SECTIONS = [
      ["CommPattern", "EdgePlan", "OverlapSpec", "build_edge_plan",
       "build_comm_pattern", "compute_comm_map", "validate_plan",
       "plan_memory_usage", "interior_boundary_edge_counts",
-      "pick_halo_impl", "resolve_halo_impl"]),
+      "pick_halo_impl", "resolve_halo_impl", "halo_wire_rows"]),
     ("Sharded plan builds (cache format v8)", "dgraph_tpu.plan",
      ["build_plan_shards", "build_edge_plan_sharded", "load_sharded_plan",
       "assemble_plan", "shard_nbytes_estimate", "reshard_vertex_data"]),
@@ -59,7 +59,8 @@ SECTIONS = [
     ("Rank-local ops", "dgraph_tpu.ops.local", None),
     ("Pallas kernels", "dgraph_tpu.ops.pallas_segment",
      ["sorted_segment_sum", "sorted_segment_sum_bias_relu",
-      "sorted_row_gather", "max_chunks_hint", "max_vblocks_hint"]),
+      "sorted_row_gather", "max_chunks_hint", "max_vblocks_hint",
+      "block_chunk_counts", "chunk_vblock_spans"]),
     ("Pallas one-sided halo transport", "dgraph_tpu.ops.pallas_p2p",
      ["p2p_transport", "p2p_interpret_mode", "transport_fused_mask",
       "FUSED_MASK_VMEM_BUDGET", "P2P_COLLECTIVE_ID"]),
@@ -104,9 +105,9 @@ SECTIONS = [
      ["init_world", "append_delta", "replan", "load_generation",
       "build_engine", "read_world", "write_world", "assign_new_vertices",
       "staged_delta_paths", "DeltaError"]),
-    ("Timing & tracing", "dgraph_tpu.utils.timing", None),
+    ("Device timing of single ops", "dgraph_tpu.utils.timing", None),
     ("Compile cache", "dgraph_tpu.utils.compile_cache",
-     ["enable_compile_cache"]),
+     ["enable_compile_cache", "compile_totals"]),
     ("Observability: comm footprint", "dgraph_tpu.obs.footprint",
      ["plan_footprint", "dtype_bytes"]),
     ("Observability: step metrics", "dgraph_tpu.obs.metrics",
@@ -114,8 +115,8 @@ SECTIONS = [
     ("Observability: run health", "dgraph_tpu.obs.health",
      ["RunHealth", "classify_wedge", "startup_record"]),
     ("Observability: span tracing", "dgraph_tpu.obs.spans",
-     ["Tracer", "Span", "span", "enable", "disable", "enabled",
-      "current_span", "current_trace_id", "child_env", "read_spans",
+     ["Tracer", "Span", "span", "stage", "stage_totals", "record_span",
+      "enable", "disable", "enabled", "current_span", "current_trace_id", "child_env", "read_spans",
       "export_perfetto"]),
     ("Observability: step-time attribution", "dgraph_tpu.obs.attribution",
      ["scan_delta_attribution", "multichip_family_table"]),

@@ -1,5 +1,5 @@
 """Smaller parity pieces: bf16 compute path, MessagePassing wrapper,
-Communicator facade surface, TimingReport, LR schedule, utils."""
+Communicator facade surface, stage totals, LR schedule, utils."""
 
 import numpy as np
 import pytest
@@ -78,18 +78,23 @@ def test_communicator_facade_surface():
         Communicator.init_process_group("tpu")  # missing world_size
 
 
-def test_timing_report():
-    from dgraph_tpu.utils import TimingReport
+def test_stage_totals_hold_the_phases():
+    """The phases the static phase timer held, through the recorder's
+    always-on table: one entry a finished stage, counted and summed."""
+    from dgraph_tpu.obs import spans
 
-    TimingReport.reset()
-    TimingReport.start("phase")
-    x = jnp.ones((100, 100)) @ jnp.ones((100, 100))
-    TimingReport.stop("phase", sync=x)
-    TimingReport.add_time("manual", 5.0)
-    rep = TimingReport.report()
-    assert rep["phase"]["count"] == 1 and rep["phase"]["mean_ms"] > 0
-    assert rep["manual"]["mean_ms"] == 5.0
-    TimingReport.reset()
+    before = spans.stage_totals().get("test.phase", {"count": 0, "total_s": 0.0})
+    for _ in range(2):
+        with spans.stage("test.phase", rows=100):
+            x = jnp.ones((100, 100)) @ jnp.ones((100, 100))
+            jax.block_until_ready(x)  # the stage covers the device's part
+    got = spans.stage_totals()["test.phase"]
+    assert got["count"] == before["count"] + 2
+    assert got["total_s"] > before["total_s"]
+    assert 0 < got["last_s"] <= got["max_s"] <= got["total_s"]
+    # a copy: editing it does not reach the table
+    got["count"] = -1
+    assert spans.stage_totals()["test.phase"]["count"] == before["count"] + 2
 
 
 def test_three_phase_schedule():

@@ -49,6 +49,66 @@ def test_disabled_is_one_attr_read_noop(tmp_path):
     assert not (tmp_path / "spans.jsonl").exists()
 
 
+def test_stage_totals_are_kept_with_the_tracer_off():
+    """stage() is the always-on entry point: its seconds reach the table
+    with tracing off, while span() stays the shared no-op; with tracing on
+    the same stage is also one normal span record with its attributes."""
+    name = "test.stage_off"
+    assert not spans.enabled()
+    with spans.stage(name, rows=7) as st:
+        st.annotate(more=1)  # inert while tracing is off
+        assert spans.span("anything") is spans.NOOP_SPAN
+        assert spans.current_span() is None  # no span was opened
+    row = spans.stage_totals()[name]
+    assert row["count"] == 1 and row["total_s"] == row["last_s"] == row["max_s"] > 0
+
+    recs = []
+    spans.enable(sink=recs.append)
+    with pytest.raises(RuntimeError):
+        with spans.stage(name, rows=9):
+            raise RuntimeError("a failed stage still counts its time")
+    spans.disable()
+    row2 = spans.stage_totals()[name]
+    assert row2["count"] == 2 and row2["total_s"] > row["total_s"]
+    assert [(r["name"], r["status"], r["attrs"]) for r in recs] == [
+        (name, "error", {"rows": 9})]
+
+
+def test_enabled_span_is_a_host_event_of_the_profiler_trace(tmp_path):
+    """On the profiler's clock: under jax.profiler.trace an enabled span is
+    a host event of the same name, a child inside its parent's interval; a
+    span that is never entered (ended by hand) leaves none."""
+    import glob
+    import gzip
+
+    import jax
+
+    spans.enable(sink=lambda rec: None)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with spans.span("test.parent"):
+            with spans.span("test.child"):
+                jax.block_until_ready(jax.numpy.ones(8) + 1)
+            with spans.stage("test.stage_child"):
+                pass
+            spans.span("test.by_hand").end()
+    finally:
+        jax.profiler.stop_trace()
+        spans.disable()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.trace.json.gz"))
+    with gzip.open(path) as f:
+        events = json.load(f)["traceEvents"]
+    found = {e["name"]: e for e in events
+             if e.get("ph") == "X" and e["name"].startswith("test.")}
+    assert set(found) == {"test.parent", "test.child", "test.stage_child"}
+    parent = found["test.parent"]
+    for name in ("test.child", "test.stage_child"):
+        child = found[name]
+        assert (child["pid"], child["tid"]) == (parent["pid"], parent["tid"])
+        assert parent["ts"] <= child["ts"]
+        assert child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+
 def test_spans_module_is_jax_free_static_pin():
     """The supervisor and bench's standalone loader import spans.py on
     machines where any jax call can hang — pin (statically, so the pin
