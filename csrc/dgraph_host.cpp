@@ -1253,7 +1253,9 @@ void plan_core_fill(void* ctx_, const int64_t* src, const int64_t* dst,
 
   // padding conventions (plan.py build_edge_plan): owner-side padded slots
   // carry n_owner_pad (monotone tail, dropped by segment reductions);
-  // halo-side and send arrays carry 0 with mask 0
+  // halo-side and send arrays carry 0 with mask 0 (the halo-SORTED route
+  // does not sort that 0 into block 0: plan.py halo_sort_route keys masked
+  // edges past the last vertex block, see EdgePlan.halo_sorted_ids)
   std::fill(owner_index, owner_index + static_cast<size_t>(W) * e_pad,
             static_cast<int32_t>(n_owner_pad));
   std::memset(halo_index, 0, static_cast<size_t>(W) * e_pad * sizeof(int32_t));

@@ -424,6 +424,13 @@ class EdgePlan:
     # ~2x slower at arxiv scale, ops/local.py). None on plans built with
     # sort_route=False (e.g. billion-edge plans where the extra 2x[W,E]
     # int32 isn't worth host RAM).
+    # PADDING CONVENTION of the route (halo_sort_route; the index arrays
+    # themselves keep 0 for a padded edge): a masked edge sorts after every
+    # real edge and its sorted id is the sentinel halo_sort_sentinel(), the
+    # first id past the kernel's last vertex block, so no block's chunk
+    # range holds it and halo_sort_mc is the widest block of REAL edges.
+    # The rows it no longer reaches are exact zeros (gather and scatter
+    # multiply by edge_mask before the route).
     halo_sort_perm: Any = None  # i32[W, E] or None
     halo_sorted_ids: Any = None  # i32[W, E] or None
     halo_sort_mc: int = 1  # static; max_chunks hint for the sorted route
@@ -872,7 +879,10 @@ def validate_plan(plan: EdgePlan) -> None:
         errors.append("num_edges exceeds e_pad")
     if plan.halo_sort_perm is not None:
         # sorted route: perm must be a permutation of [0, e_pad) per shard
-        # and the recorded sorted ids must equal halo_idx[perm], monotone.
+        # and the recorded sorted ids monotone and equal to the key
+        # halo_sort_route sorts by, permuted: halo_idx for a real edge, the
+        # sentinel past the last vertex block for a masked one (monotone,
+        # so the masked edges are the tail).
         # Vectorized WITHIN each rank (no O(E log E) sort — the old check's
         # dominant cost at billion-edge scale, VERDICT r2 #8) but looped
         # over ranks: all-at-once [W, e_pad] temporaries would multiply
@@ -880,6 +890,9 @@ def validate_plan(plan: EdgePlan) -> None:
         perm = np_.asarray(plan.halo_sort_perm)
         sids = np_.asarray(plan.halo_sorted_ids)
         halo_idx = src if plan.halo_side == "src" else dst
+        sentinel = halo_sort_sentinel(
+            src_hi if plan.halo_side == "src" else dst_hi,
+            plan.scatter_block_n)
         seen = np_.empty(plan.e_pad, bool)
         for r in range(W):
             pr = perm[r]
@@ -892,8 +905,16 @@ def validate_plan(plan: EdgePlan) -> None:
             if (np_.diff(sids[r]) < 0).any():
                 errors.append(f"halo_sorted_ids[{r}] not monotone")
                 break
-            if not np_.array_equal(halo_idx[r][pr], sids[r]):
-                errors.append(f"halo_sorted_ids[{r}] != halo_index[perm]")
+            real = mask[r][pr]
+            want = np_.where(real, halo_idx[r][pr], sentinel)
+            bad = np_.flatnonzero(want != sids[r])
+            if bad.size:
+                i = int(bad[0])
+                errors.append(
+                    f"halo_sorted_ids[{r}][{i}] = {int(sids[r][i])} != "
+                    f"{int(want[i])}: a real edge carries halo_index[perm], "
+                    f"a masked edge the sentinel {sentinel} (this one is "
+                    f"{'real' if real[i] else 'masked'})")
                 break
     ov = plan.overlap
     if ov is not None:
@@ -1418,6 +1439,40 @@ def _count_grid(
     return width
 
 
+def halo_sort_sentinel(n_halo_rows: int, block_n: int) -> int:
+    """Sorted id of a masked edge on the halo-sorted route: the first id
+    past the last ``block_n``-row vertex block of the kernel's grid. The
+    kernel rounds its rows up to whole blocks
+    (``ops.pallas_segment._ChunkSchedule.N_pad``, ``block_chunk_counts``
+    likewise), so ``n_halo_rows`` itself would lie INSIDE the last block
+    whenever it is not a multiple of ``block_n``."""
+    return _pad_to(n_halo_rows, block_n)
+
+
+def halo_sort_route(halo_idx, edge_mask, n_halo_rows: int):
+    """The halo-sorted route ``(halo_sort_perm, halo_sorted_ids)`` of one
+    rank's halo-side index row (or of the ``[W, e_pad]`` stack of them) and
+    its ``edge_mask``: a stable sort by ``where(mask > 0, idx, sentinel)``,
+    so real edges keep the order a sort by index gives them and every
+    masked edge follows at :func:`halo_sort_sentinel`, outside every vertex
+    block. The ONE place the route is made (monolithic, native and streamed
+    builds call it); a row with no padding gets what a plain argsort gives.
+
+    The ids stay monotone through the kernel's own tail pad
+    (``num_segments + 1``) when that pad is empty, i.e. ``e_pad`` is a
+    multiple of ``SCATTER_BLOCK_E`` (every kernel-scale plan since format
+    v6). On a sub-block plan the tail is lower than the sentinel; both are
+    dropped (one-hot guard, ``out[:num_segments]``) and only the LAST
+    block's end is searched past them, so at worst that block runs its
+    full hint width over chunks of zeros."""
+    key = np.where(
+        np.asarray(edge_mask) > 0, halo_idx,
+        np.int32(halo_sort_sentinel(n_halo_rows, SCATTER_BLOCK_N)),
+    ).astype(np.int32, copy=False)
+    perm = np.argsort(key, axis=-1, kind="stable").astype(np.int32)
+    return perm, np.take_along_axis(key, perm, axis=-1)
+
+
 def halo_wire_rows(plan: "EdgePlan", impl: str) -> int:
     """Rows one halo exchange puts on the wire, over all ranks, under the
     lowering ``impl``, padding included (``obs.footprint`` prices the same
@@ -1482,10 +1537,8 @@ def _finalize_plan(
         n_halo_rows = (
             n_src_pad_val if halo_side == "src" else n_dst_pad_val
         ) + W * s_pad_val
-        halo_sort_perm = np.argsort(halo_idx_arr, axis=1, kind="stable").astype(
-            np.int32
-        )
-        halo_sorted_ids = np.take_along_axis(halo_idx_arr, halo_sort_perm, axis=1)
+        halo_sort_perm, halo_sorted_ids = halo_sort_route(
+            halo_idx_arr, edge_mask, n_halo_rows)
         halo_sort_mc = _count_grid("halo_sort", [
             block_chunk_counts(
                 halo_sorted_ids[r], n_halo_rows,
@@ -1868,8 +1921,7 @@ def _assemble_shard_payload(prep, r: int, *, sort_edges: bool,
         from dgraph_tpu.ops.pallas_segment import block_chunk_counts
 
         n_halo_rows = prep.n_halo_pad + W * prep.s_pad
-        perm = np.argsort(halo_row, kind="stable").astype(np.int32)
-        sorted_ids = halo_row[perm]
+        perm, sorted_ids = halo_sort_route(halo_row, mask_row, n_halo_rows)
         counts["halo_sort"] = block_chunk_counts(
             sorted_ids, n_halo_rows,
             block_e=SCATTER_BLOCK_E, block_n=SCATTER_BLOCK_N,

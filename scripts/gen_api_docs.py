@@ -33,7 +33,8 @@ SECTIONS = [
      ["CommPattern", "EdgePlan", "OverlapSpec", "build_edge_plan",
       "build_comm_pattern", "compute_comm_map", "validate_plan",
       "plan_memory_usage", "interior_boundary_edge_counts",
-      "pick_halo_impl", "resolve_halo_impl", "halo_wire_rows"]),
+      "pick_halo_impl", "resolve_halo_impl", "halo_wire_rows",
+      "halo_sort_route", "halo_sort_sentinel"]),
     ("Sharded plan builds (cache format v8)", "dgraph_tpu.plan",
      ["build_plan_shards", "build_edge_plan_sharded", "load_sharded_plan",
       "assemble_plan", "shard_nbytes_estimate", "reshard_vertex_data"]),

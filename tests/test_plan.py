@@ -285,7 +285,16 @@ class TestHaloSortRoute:
             assert sorted(p.tolist()) == list(range(plan.e_pad))  # permutation
             si = np.asarray(plan.halo_sorted_ids[r])
             assert (np.diff(si) >= 0).all()  # monotone
-            np.testing.assert_array_equal(np.asarray(plan.src_index[r])[p], si)
+            # real edges first, at their index; padding after, at the
+            # sentinel past the last vertex block
+            n = int(plan.num_edges[r])
+            assert (np.asarray(plan.edge_mask[r])[p[:n]] > 0).all()
+            np.testing.assert_array_equal(
+                np.asarray(plan.src_index[r])[p[:n]], si[:n])
+            n_full = plan.n_src_pad + W * plan.halo.s_pad
+            sentinel = pl.halo_sort_sentinel(n_full, plan.scatter_block_n)
+            assert sentinel % plan.scatter_block_n == 0 and sentinel >= n_full
+            assert (si[n:] == sentinel).all() and n < plan.e_pad
         assert plan.halo_sort_mc >= 1
 
     def test_route_equals_generic(self):
@@ -319,9 +328,11 @@ class TestHaloSortRoute:
 
     def test_pallas_kernel_on_route_inputs(self):
         """The Pallas kernel (interpret mode) must agree with numpy on the
-        ACTUAL route inputs — per-shard halo_sorted_ids with padded id-0
-        edges and the plan-computed halo_sort_mc hint — not just on the
-        dense valid ids the bench self-check uses."""
+        ACTUAL route inputs — per-shard halo_sorted_ids whose padded edges
+        carry the out-of-range sentinel and the plan-computed halo_sort_mc
+        hint — not just on the dense valid ids the bench self-check uses.
+        The oracle sums the real ids only: the kernel drops the sentinel,
+        and ``np.add.at`` cannot index it."""
         import jax.numpy as jnp
 
         from dgraph_tpu.ops.pallas_segment import sorted_segment_sum
@@ -334,7 +345,9 @@ class TestHaloSortRoute:
             si = np.asarray(plan.halo_sorted_ids[r])
             data = rng.standard_normal((plan.e_pad, 8)).astype(np.float32)
             want = np.zeros((n_full, 8), np.float32)
-            np.add.at(want, si, data)
+            real = si < n_full
+            assert real.sum() == int(plan.num_edges[r])
+            np.add.at(want, si[real], data[real])
             got = np.asarray(
                 sorted_segment_sum(
                     jnp.asarray(data), jnp.asarray(si), n_full,
@@ -344,6 +357,108 @@ class TestHaloSortRoute:
                 )
             )
             np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _imbalanced_four_rank_plan(use_native, e_pad=None):
+    """Four ranks of 160 vertices; rank r owns (dst) 6 - 1.5 r edges a
+    vertex, so ranks 1-3 end in 240 / 480 / 720 padded edges of e_pad 960
+    (sub-block: the kernel pads each call to 1024 itself). Sources are
+    uniform, so every rank needs halo rows: 160 + 4 * s_pad halo-side rows,
+    which is no multiple of the 256-row block."""
+    rng = np.random.default_rng(27)
+    V, W = 640, 4
+    dst = np.concatenate([
+        np.repeat(np.arange(r * 160, (r + 1) * 160), 6)[: 960 - 240 * r]
+        for r in range(W)])
+    src = rng.integers(0, V, dst.size)
+    plan, _ = pl.build_edge_plan(
+        np.stack([src, dst]), np.repeat(np.arange(W), 160).astype(np.int32),
+        world_size=W, edge_owner="dst", pad_multiple=8, e_pad=e_pad,
+        use_native=use_native)
+    return plan
+
+
+@pytest.mark.parametrize("use_native", [False, True])
+def test_padding_stays_out_of_the_halo_sorted_routes_blocks(
+        use_native, monkeypatch):
+    """ISSUE 27. The route's width is the widest block of REAL edges, no
+    matter how many padded edges a rank's row ends in, and both route ops
+    equal a plain segment-sum over the unsorted ids bit for bit (the data
+    are small integers, so every order of summation is exact)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dgraph_tpu import native
+    from dgraph_tpu.ops import local as local_ops
+    from dgraph_tpu.ops.pallas_segment import (
+        block_chunk_counts, sorted_segment_sum)
+
+    if use_native and not native.available():
+        pytest.skip("native host library unavailable")
+    plan = _imbalanced_four_rank_plan(use_native)
+    W, be, bn = 4, plan.scatter_block_e, plan.scatter_block_n
+    n_full = plan.n_src_pad + W * plan.halo.s_pad
+    assert plan.halo_side == "src" and n_full % bn != 0
+    assert [int(n) for n in plan.num_edges] == [960, 720, 480, 240]
+    pl.validate_plan(plan)
+
+    # (a) the hint equals a count over the real edges alone
+    def real_width(p):
+        widest = 1
+        for r in range(W):
+            ids = np.asarray(p.src_index[r])[np.asarray(p.edge_mask[r]) > 0]
+            widest = max(widest, int(block_chunk_counts(
+                np.sort(ids), n_full, be, bn).max()))
+        return widest
+
+    assert plan.halo_sort_mc == real_width(plan)
+    # (b) and more padding does not move it (under the old rule rank 3's
+    # 3 856 padded edges in block 0 would have made it 4)
+    wide = _imbalanced_four_rank_plan(use_native, e_pad=4096)
+    assert wide.e_pad == 4096 and wide.halo.s_pad == plan.halo.s_pad
+    assert wide.halo_sort_mc == real_width(wide) == plan.halo_sort_mc
+
+    # (c) the route ops on the lightest rank, kernel in interpret mode:
+    # on the sub-block plan (the kernel's own tail pad, n_full + 1, lies
+    # below the sentinel there) and on the four-chunk one
+    def kernel(data, sorted_ids, n_rows, be_, bn_, mc, gather_mv=0):
+        return sorted_segment_sum(
+            data, sorted_ids, n_rows, max_chunks_per_block=mc, block_e=be_,
+            block_n=bn_, interpret=True)
+
+    # the route ops resolve the dispatch point when they are traced
+    monkeypatch.setattr(local_ops, "sorted_segment_sum_any", kernel)
+    r = 3
+    for p in (plan, wide):
+        idx, perm, sids, mask = (
+            jnp.asarray(np.asarray(a[r])) for a in (
+                p.src_index, p.halo_sort_perm, p.halo_sorted_ids,
+                p.edge_mask))
+        rng = np.random.default_rng(r)
+        hints = (be, bn, p.halo_sort_mc)
+        # scatter_sum and local_take multiply by the mask before the route
+        edata = jnp.asarray(
+            rng.integers(-8, 9, (p.e_pad, 8)), jnp.float32) * mask[:, None]
+        x = jnp.asarray(rng.integers(-8, 9, (n_full, 8)), jnp.float32)
+        want = np.asarray(jax.ops.segment_sum(edata, idx, num_segments=n_full))
+        fwd = local_ops.segment_sum_sort_route(
+            edata, idx, perm, sids, n_full, pallas_hints=hints)
+        _, vjp = jax.vjp(
+            lambda x_: local_ops.take_rows_sort_route(
+                x_, idx, perm, sids, pallas_hints=hints), x)
+        (dx,) = vjp(edata)
+        np.testing.assert_array_equal(np.asarray(fwd), want)
+        np.testing.assert_array_equal(np.asarray(dx), want)
+        assert np.abs(want).sum() > 0
+        # and what the route gave before this rule (padding keyed 0, so
+        # sorted into block 0, whose count was every block's width)
+        old_perm = np.argsort(np.asarray(idx), kind="stable").astype(np.int32)
+        old_ids = np.asarray(idx)[old_perm]
+        old_mc = int(block_chunk_counts(old_ids, n_full, be, bn).max())
+        old = local_ops.segment_sum_sort_route(
+            edata, idx, jnp.asarray(old_perm), jnp.asarray(old_ids), n_full,
+            pallas_hints=(be, bn, old_mc))
+        np.testing.assert_array_equal(np.asarray(old), np.asarray(fwd))
 
 
 class TestResolveHaloImplLadder:

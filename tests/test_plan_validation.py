@@ -61,10 +61,12 @@ def test_scale_plan_build_5m_edges(rng):
 
 
 class TestSortRouteValidation:
-    """validate_plan's halo-sort-route checks: a valid plan passes; each
-    corruption class (non-permutation, non-monotone, ids mismatch) is
-    rejected — stale/corrupt cached plans must rebuild, not silently feed
-    the Pallas sorted kernels."""
+    """validate_plan's halo-sort-route checks: a valid plan passes (real
+    edges at their index, padded edges after them at the sentinel past the
+    last vertex block); each corruption class (non-permutation,
+    non-monotone, ids mismatch, a padded edge inside a block, a real edge
+    at the sentinel) is rejected — stale/corrupt cached plans must
+    rebuild, not silently feed the Pallas sorted kernels."""
 
     def _plan(self):
         rng = np.random.default_rng(3)
@@ -74,7 +76,47 @@ class TestSortRouteValidation:
         return pl.build_edge_plan(edges, part, world_size=W, edge_owner="dst")[0]
 
     def test_valid_plan_passes(self):
-        validate_plan(self._plan())
+        plan = self._plan()
+        validate_plan(plan)
+        # every rank's row ends in padded edges: the rule is exercised
+        assert (np.asarray(plan.num_edges) < plan.e_pad).all()
+
+    @staticmethod
+    def _sentinel(plan):
+        n_rows = plan.n_src_pad + plan.world_size * plan.halo.s_pad
+        return pl.halo_sort_sentinel(n_rows, plan.scatter_block_n)
+
+    def test_masked_edge_inside_a_block_rejected(self):
+        """The rule before format v11: a padded edge at sorted id 0."""
+        import dataclasses
+
+        plan = self._plan()
+        halo_idx = np.asarray(plan.src_index)
+        perm = np.argsort(halo_idx, axis=1, kind="stable").astype(np.int32)
+        old_rule = dataclasses.replace(
+            plan, halo_sort_perm=perm,
+            halo_sorted_ids=np.take_along_axis(halo_idx, perm, axis=1))
+        with pytest.raises(ValueError, match="this one is masked"):
+            validate_plan(old_rule)
+        # at the tail, monotone, but inside the last block: n_rows is no
+        # multiple of the block, so n_rows itself is not past it
+        n_rows = plan.n_src_pad + plan.world_size * plan.halo.s_pad
+        assert n_rows < self._sentinel(plan)
+        sids = np.asarray(plan.halo_sorted_ids).copy()
+        sids[sids == self._sentinel(plan)] = n_rows
+        bad = dataclasses.replace(plan, halo_sorted_ids=sids)
+        with pytest.raises(ValueError, match="this one is masked"):
+            validate_plan(bad)
+
+    def test_real_edge_at_the_sentinel_rejected(self):
+        import dataclasses
+
+        plan = self._plan()
+        sids = np.asarray(plan.halo_sorted_ids).copy()
+        sids[0, int(plan.num_edges[0]) - 1] = self._sentinel(plan)
+        bad = dataclasses.replace(plan, halo_sorted_ids=sids)
+        with pytest.raises(ValueError, match="this one is real"):
+            validate_plan(bad)
 
     def test_non_monotone_sorted_ids_rejected(self):
         import dataclasses
@@ -102,8 +144,41 @@ class TestSortRouteValidation:
 
         plan = self._plan()
         sids = np.asarray(plan.halo_sorted_ids).copy()
-        # keep monotone but break the halo_index[perm] == sorted_ids tie
-        sids[0] = np.clip(sids[0] + 1, 0, None)
+        # keep monotone (and the padded tail at its sentinel) but break the
+        # real edges' halo_index[perm] == sorted_ids tie
+        sids[0, : int(plan.num_edges[0])] += 1
         bad = dataclasses.replace(plan, halo_sorted_ids=sids)
-        with pytest.raises(ValueError, match="!= halo_index"):
+        with pytest.raises(
+                ValueError, match=r"halo_index\[perm\].*this one is real"):
             validate_plan(bad)
+
+
+def test_a_plan_cached_under_format_v10_rebuilds(tmp_path, monkeypatch):
+    """Format v10 keyed a padded edge 0 on the halo-sorted route. Its cached
+    plans no longer pass validate_plan, so the version in the cache key has
+    to send the same call to a fresh build, not to the stale artifact."""
+    from dgraph_tpu.train import checkpoint as ck
+
+    rng = np.random.default_rng(3)
+    edges = np.stack([rng.integers(0, 64, 400), rng.integers(0, 64, 400)])
+    part = np.sort(rng.integers(0, 4, 64)).astype(np.int32)
+
+    def cached():
+        return ck.cached_edge_plan(
+            str(tmp_path), edges, part, world_size=4, edge_owner="dst")[0]
+
+    def v10_route(halo_idx, edge_mask, n_halo_rows):
+        perm = np.argsort(halo_idx, axis=-1, kind="stable").astype(np.int32)
+        return perm, np.take_along_axis(halo_idx, perm, axis=-1)
+
+    with monkeypatch.context() as m:
+        m.setattr(ck, "PLAN_FORMAT_VERSION", 10)
+        m.setattr(pl, "halo_sort_route", v10_route)
+        stale = cached()
+    with pytest.raises(ValueError, match="this one is masked"):
+        validate_plan(stale)
+    assert ck.PLAN_FORMAT_VERSION >= 11
+    fresh = cached()
+    validate_plan(fresh)
+    assert len([d for d in tmp_path.iterdir() if d.name.startswith("plan_")]) == 2
+    validate_plan(cached())  # and the warm hit is the fresh artifact

@@ -246,22 +246,26 @@ def two_rank_edges(per_vertex_rank1: int):
 
 
 # hand counts at the plan's tiles (1024-edge chunks, 256-vertex blocks,
-# 512 + 2 x 8 halo-side rows = 3 blocks a rank). Rank 0, every route: each
-# block of 256 vertices holds 2048 edges = 2 chunks. Rank 1 with k edges a
-# vertex has 4096 - 512 k padded edges, indexed 0 on the halo side and
-# n_pad on the owner side:
-#   halo_sort: the padded edges sort into block 0. k=4: 2048 + 1024 edges
-#     under id 256 = 3 chunks, block 1 the last chunk: [3, 1, 0], width 3,
-#     6 rows x 3 = 18 steps for 4 + 4 used. k=2: 3072 + 512 = 3584 edges =
-#     4 chunks, block 1 shares the last: [4, 1, 0], 24 steps for 4 + 5.
+# 512 + 2 x 8 = 528 halo-side rows = 3 blocks a rank). Rank 0, every route:
+# each block of 256 vertices holds 2048 edges = 2 chunks. Rank 1 with k
+# edges a vertex has 4096 - 512 k padded edges, indexed n_pad on the owner
+# side and, on the halo-sorted route, keyed 768: the first id past the
+# three blocks (528 itself lies in the third), so they sort last and into
+# no block:
+#   halo_sort: only real edges count. k=4: 1024 edges a block, block 0 is
+#     chunk 0, block 1 chunk 1, block 2 (the halo rows) empty: [1, 1, 0].
+#     k=2: 512 edges a block, both in chunk 0: [1, 1, 0]. Width 2 from
+#     rank 0's [2, 2, 0] whatever k is: 6 rows x 2 = 12 steps for 4 + 2.
+#     (Until format v11 the padding was keyed 0 and sorted into block 0:
+#     [3, 1, 0] and [4, 1, 0], widths 3 and 4, 18 and 24 steps.)
 #   scatter: the padded edges fall past the last block: k=4 [1, 1], k=2
 #     [1, 1] (both blocks in chunk 0), width 2 from rank 0: 8 steps, 4 + 2.
 #   gather_mv (4 chunks a rank x the widest span): k=2 puts all 512
 #     vertices into chunk 0, which then spans 2 blocks: 16 steps, 4 + 5.
 HAND_COUNTS = {
     8: {"halo_sort": (12, 8), "scatter": (8, 8), "gather_mv": (8, 8)},
-    4: {"halo_sort": (18, 8), "scatter": (8, 6), "gather_mv": (8, 8)},
-    2: {"halo_sort": (24, 9), "scatter": (8, 6), "gather_mv": (16, 9)},
+    4: {"halo_sort": (12, 6), "scatter": (8, 6), "gather_mv": (8, 8)},
+    2: {"halo_sort": (12, 6), "scatter": (8, 6), "gather_mv": (16, 9)},
 }
 
 
@@ -285,17 +289,21 @@ def test_grid_counters_equal_a_hand_count(per_vertex):
     assert plan.gather_mv * 8 == want["gather_mv"][0]
 
 
-def test_grid_fill_falls_as_the_edge_imbalance_grows():
-    """The padded edges land in block 0 of the halo-sorted route, so the
-    fuller the pad, the wider every block's grid and the lower the fill."""
+def test_padding_does_not_set_the_halo_sorted_routes_width():
+    """The padded edges sort past the last block of the halo-sorted route,
+    so its width is the widest block of real edges (rank 0's, the same for
+    every k) and its fill falls only by the work the lighter rank lacks."""
+    widths = {k: HAND_COUNTS[k]["halo_sort"][0] // 6 for k in HAND_COUNTS}
+    assert widths == {8: 2, 4: 2, 2: 2}
+    # the owner side always padded past its last block: same steps there
+    assert {HAND_COUNTS[k]["scatter"][0] for k in HAND_COUNTS} == {8}
+
     def fill(route, c):
         steps, used = HAND_COUNTS[c][route]
         return used / steps
 
-    assert fill("halo_sort", 8) > fill("halo_sort", 4) > fill("halo_sort", 2)
-    totals = [sum(u for _, u in HAND_COUNTS[c].values())
-              / sum(s for s, _ in HAND_COUNTS[c].values()) for c in (8, 4, 2)]
-    assert totals[0] > totals[1] > totals[2]
+    assert fill("halo_sort", 8) == 8 / 12
+    assert fill("halo_sort", 4) == fill("halo_sort", 2) == 6 / 12
 
 
 def test_sharded_build_counts_the_same_grid():
@@ -331,8 +339,10 @@ def test_a_repaired_shard_is_counted_at_the_plans_width():
             edges, part, out_dir=tmp, world_size=2, rebuild_ranks=(0,)),
             "plan.segsum")
     # rank 0 alone: 3 halo-side blocks, 2 owner blocks, 4 chunks, each at
-    # the widths rank 1 set (4, 2, 2); its own counts are 2 a block
-    assert got["plan.segsum_grid_steps.halo_sort"] == 3 * 4
+    # the plan's widths (2, 2, 2: rank 1's chunk 0 spans both owner blocks,
+    # which sets gather_mv; its padding sets nothing); its own counts are
+    # 2 a block
+    assert got["plan.segsum_grid_steps.halo_sort"] == 3 * 2
     assert got["plan.segsum_used_chunks.halo_sort"] == 4
     assert got["plan.segsum_grid_steps.scatter"] == 2 * 2
     assert got["plan.segsum_grid_steps.gather_mv"] == 4 * 2
