@@ -1,0 +1,207 @@
+"""Looped decoder LM over a sequence-sharded mesh axis.
+
+One stack of ``num_layers`` decoder layers, its parameters held once (stacked
+on a leading axis, applied under ``nn.scan``), is passed over ``loop_steps``
+times with the same parameters (a second ``nn.scan`` with the parameters
+broadcast): the looped language model of "Scaling Latent Reasoning via Looped
+Language Models" (ByteDance, 2025; Ouro). ``loop_steps=1`` with
+``exit_gate=False`` is a plain decoder with sandwich norms, so the loop is a
+parameter of one model and the parameter tree does not depend on it.
+
+Equations (d hidden, H heads of ``head_dim``, F intermediate, no bias):
+
+- layer: ``a = Attn(RMSNorm1(h))``, ``h <- h + RMSNorm2(a)``,
+  ``m = W_down(silu(W_gate u) * W_up u)`` with ``u = RMSNorm3(h)``,
+  ``h <- h + RMSNorm4(m)``;
+- ``Attn``: ``q, k, v = W_q x, W_k x, W_v x`` as ``[T, H, head_dim]``, rotary
+  embedding on q and k at the token's GLOBAL position (rotate-half pairs
+  ``(i, i + head_dim/2)``), exact causal softmax(``q k^T / sqrt(head_dim)``)
+  ``v`` through the comm facade's ``seq_attention`` (dense or the Mosaic
+  flash kernel on one device, ring or Ulysses over the graph axis), ``W_o``;
+- the loop: ``h0 = E[tokens]``; for t = 1..R: ``h_t = RMSNorm_f(Stack(h_{t-1}))``;
+  ``logits_t = W_head h_t``; exit gate ``lambda_t = sigmoid(w_g . h_t + b_g)``.
+
+Everything but attention is token-local, so the attention collective is the
+only communication. Parameters are float32; matmuls run in
+``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
+logits are float32. The exit-distribution loss over the passes lives with the
+trainer (:mod:`dgraph_tpu.train.lm`), which applies :meth:`LoopLM.logits`
+in blocks of positions under recomputation.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from dgraph_tpu.models.norm import RMSNorm
+
+# matmul in the compute dtype, result in float32 (the logits and the gate
+# feed a softmax / sigmoid whose statistics are float32)
+_dot_f32_out = functools.partial(
+    jax.lax.dot_general, preferred_element_type=jnp.float32)
+
+
+def rotary_tables(positions: jax.Array, head_dim: int, theta: float):
+    """(cos, sin), each ``[T, head_dim / 2]`` float32, of the angles
+    ``position * theta^(-2i / head_dim)``. ``positions`` are GLOBAL token
+    positions, so a sequence shard passes ``rank * T_loc + arange(T_loc)``."""
+    half = head_dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+@jax.named_scope("dgraph.lm.rotary")
+def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate-half rotary embedding of ``x`` ``[T, H, D]``: the pairs are
+    ``(i, i + D/2)``. Float32 arithmetic, result in ``x``'s dtype."""
+    half = x.shape[-1] // 2
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    c, s = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate(
+        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+
+
+class LoopLMLayer(nn.Module):
+    """One decoder layer with sandwich norms; ``(h, rope) -> (h, None)``, the
+    signature ``nn.scan`` wants of a body."""
+
+    hidden: int
+    num_heads: int
+    head_dim: int
+    intermediate: int
+    comm: Any  # _BaseComm: seq_attention routes dense/flash/ring/ulysses
+    num_kv_heads: Optional[int] = None  # None = num_heads
+    rms_eps: float = 1e-6
+    dtype: Any = None
+    attn_impl: str = "ring"
+
+    @nn.compact
+    def __call__(self, h, rope):  # [T_loc, hidden], (cos, sin)
+        from dgraph_tpu import config as _cfg
+
+        dt = _cfg.resolve_compute_dtype(self.dtype)
+        H, D = self.num_heads, self.head_dim
+        Hkv = self.num_kv_heads or H
+        if H % Hkv:
+            raise ValueError(f"heads {H} not divisible by kv heads {Hkv}")
+        n = h.shape[0]
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt)
+        norm = functools.partial(RMSNorm, epsilon=self.rms_eps, dtype=dt)
+
+        x = norm(name="norm_attn_in")(h)
+        q = dense(H * D, name="q_proj")(x).reshape(n, H, D)
+        k = dense(Hkv * D, name="k_proj")(x).reshape(n, Hkv, D)
+        v = dense(Hkv * D, name="v_proj")(x).reshape(n, Hkv, D)
+        q, k = apply_rotary(q, *rope), apply_rotary(k, *rope)
+        if Hkv != H:  # grouped-query: each kv head serves H / Hkv query heads
+            k, v = (jnp.repeat(t, H // Hkv, axis=1) for t in (k, v))
+        a = self.comm.seq_attention(q, k, v, causal=True, impl=self.attn_impl)
+        a = dense(self.hidden, name="o_proj")(a.reshape(n, H * D))
+        h = h + norm(name="norm_attn_out")(a)
+
+        u = norm(name="norm_mlp_in")(h)
+        m = nn.silu(dense(self.intermediate, name="gate_proj")(u)) \
+            * dense(self.intermediate, name="up_proj")(u)
+        m = dense(self.hidden, name="down_proj")(m)
+        return h + norm(name="norm_mlp_out")(m), None
+
+
+class LoopPass(nn.Module):
+    """One pass over the stack: ``num_layers`` layers under ``nn.scan``
+    (parameters stacked on axis 0, each application rematerialised where
+    ``remat``), then the final norm. ``(h, rope) -> (h_t, h_t)``: the carry
+    of the loop and the pass's exit state."""
+
+    num_layers: int
+    layer: dict  # LoopLMLayer's fields
+    remat: bool = True
+
+    @nn.compact
+    def __call__(self, h, rope):
+        with jax.named_scope("dgraph.lm.loop_pass"):
+            cls = LoopLMLayer
+            if self.remat:
+                cls = nn.remat(cls, prevent_cse=False)  # inside a scan
+            stack = nn.scan(
+                cls, variable_axes={"params": 0}, split_rngs={"params": True},
+                in_axes=nn.broadcast, length=self.num_layers)
+            h, _ = stack(**self.layer, name="layers")(h, rope)
+            # rematerialised too: its float32 internals would otherwise be
+            # saved once a pass
+            norm_f = nn.remat(RMSNorm) if self.remat else RMSNorm
+            h = norm_f(epsilon=self.layer["rms_eps"], dtype=h.dtype,
+                       name="norm_f")(h)
+        return h, h
+
+
+class LoopLM(nn.Module):
+    """Token ids in, the exit state of every pass out.
+
+    ``hidden(tokens, positions) -> [loop_steps, T_loc, hidden]``;
+    ``logits(h) -> [..., vocab]`` float32 (the untied head);
+    ``gate_logit(h) -> [...]`` float32 (``exit_gate`` only). Calling the
+    module gives ``(logits of every pass, gate logits or None)`` whole: for
+    ``init`` and for small sequences; a trainer applies the head in blocks.
+    """
+
+    vocab: int
+    hidden_size: int
+    num_layers: int
+    num_heads: int
+    head_dim: int
+    intermediate: int
+    comm: Any = None
+    num_kv_heads: Optional[int] = None
+    loop_steps: int = 1
+    exit_gate: bool = False
+    rms_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    dtype: Any = None
+    attn_impl: str = "ring"
+    remat: bool = True
+
+    def setup(self):
+        from dgraph_tpu import config as _cfg
+
+        dt = _cfg.resolve_compute_dtype(self.dtype)
+        self.embed = nn.Embed(self.vocab, self.hidden_size, dtype=dt)
+        layer = dict(
+            hidden=self.hidden_size, num_heads=self.num_heads,
+            head_dim=self.head_dim, intermediate=self.intermediate,
+            comm=self.comm, num_kv_heads=self.num_kv_heads,
+            rms_eps=self.rms_eps, dtype=self.dtype, attn_impl=self.attn_impl)
+        # the same parameters every pass: broadcast, not split
+        loop = nn.scan(
+            LoopPass, variable_broadcast="params",
+            split_rngs={"params": False}, in_axes=nn.broadcast,
+            length=self.loop_steps)
+        self.stack = loop(self.num_layers, layer, self.remat)
+        self.head = nn.Dense(self.vocab, use_bias=False, dtype=dt,
+                             dot_general=_dot_f32_out)
+        if self.exit_gate:
+            self.gate = nn.Dense(1, dtype=dt, dot_general=_dot_f32_out)
+
+    def hidden(self, tokens, positions):  # [T_loc] int32 each
+        rope = rotary_tables(positions, self.head_dim, self.rope_theta)
+        _, hs = self.stack(self.embed(tokens), rope)
+        return hs  # [loop_steps, T_loc, hidden]
+
+    def logits(self, h):
+        with jax.named_scope("dgraph.lm.head"):
+            return self.head(h).astype(jnp.float32)
+
+    def gate_logit(self, h):
+        with jax.named_scope("dgraph.lm.exit_gate"):
+            return self.gate(h).astype(jnp.float32)[..., 0]
+
+    def __call__(self, tokens, positions):
+        hs = self.hidden(tokens, positions)
+        return self.logits(hs), (
+            self.gate_logit(hs) if self.exit_gate else None)

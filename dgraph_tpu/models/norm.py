@@ -1,4 +1,5 @@
-"""Distributed BatchNorm over vertex-sharded activations.
+"""Normalisation layers: distributed BatchNorm over vertex-sharded
+activations, and the token-local RMSNorm of the sequence models.
 
 Reference parity: ``experiments/OGB-LSC/distributed_layers.py:22-207``
 (DistributedBatchNorm1D): mean/var all-reduced across ranks with a custom
@@ -79,3 +80,22 @@ class DistributedBatchNorm(nn.Module):
             _normalize = jax.checkpoint(
                 _normalize, policy=jax.checkpoint_policies.nothing_saveable)
         return _normalize(x, mean, var, scale, bias)
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the last axis (Zhang &
+    Sennrich 2019), no bias and no mean subtraction. Token-local, so it
+    needs no communicator. The statistic and the scaling are computed in
+    float32 whatever ``dtype`` is; the result is cast to ``dtype`` (None:
+    the input's)."""
+
+    epsilon: float = 1e-6
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        y = xf * jax.lax.rsqrt(var + self.epsilon) * scale.astype(jnp.float32)
+        return y.astype(self.dtype or x.dtype)

@@ -132,8 +132,11 @@ class SeqTransformerLM(nn.Module):
                 moe_capacity_factor=self.moe_capacity_factor,
                 name=f"block_{i}",
             )(h)
-        h = nn.LayerNorm(name="ln_out")(h)
-        return nn.Dense(self.vocab, name="head")(h).astype(jnp.float32)
+        from dgraph_tpu import config as _cfg
+
+        dt = _cfg.resolve_compute_dtype(self.dtype)
+        h = nn.LayerNorm(dtype=dt, name="ln_out")(h)
+        return nn.Dense(self.vocab, dtype=dt, name="head")(h).astype(jnp.float32)
 
 
 def moe_param_specs(params_or_shapes, axis_name: str = "graph"):

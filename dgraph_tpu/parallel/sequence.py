@@ -300,7 +300,7 @@ def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
         seg = fa.SegmentIds(q=ids, kv=ids)
     out = fa.flash_attention(
         to_k(qh), to_k(kh), to_k(vh), segment_ids=seg, causal=causal,
-        sm_scale=float(scale),
+        sm_scale=float(scale), block_sizes=_flash_block_sizes(fa, T),
     )
     res = out[0].transpose(1, 0, 2)
     if kv_mask is not None:
@@ -308,6 +308,25 @@ def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
         # SEGMENT while the dense oracle's attend real keys
         res = _zero_padded_rows(res, kv_mask)
     return res.astype(qh.dtype)
+
+
+# Largest tile edge of the flash kernels (forward, dq and dkv alike). The
+# library's default of 128 everywhere leaves the MXU waiting on grid steps
+# at long T; FLASH_BLOCK is what the chip measured fastest at T = 8192,
+# H = 16, D = 128 (PERF.md, section 6, PR 28).
+FLASH_BLOCK = 1024
+
+
+def _flash_block_sizes(fa, T: int):
+    """One tile edge for every block of the kernels: the largest power of
+    two <= FLASH_BLOCK that divides T (T is a multiple of 128 here)."""
+    b = FLASH_BLOCK
+    while T % b:
+        b //= 2
+    return fa.BlockSizes(
+        block_q=b, block_k_major=b, block_k=b, block_b=1,
+        block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b,
+        block_q_dkv=b, block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
 
 
 # Auto-mode flash engages only after flash_attention_selfcheck() passes
@@ -333,17 +352,39 @@ def flash_attention_selfcheck() -> bool:
         for _ in range(3)
     )
     mask = jnp.asarray((np.arange(T) < T - 32).astype(np.float32))
+    close = lambda a, b: np.allclose(
+        np.asarray(a, np.float32), np.asarray(b, np.float32),
+        rtol=5e-2, atol=5e-2)
     try:
         for causal in (False, True):
             got = _flash_dense(q, k, v, causal=causal, scale=None,
                                kv_mask=mask)
             want = dense_attention(q, k, v, causal=causal, kv_mask=mask)
             real = np.asarray(mask) > 0
-            if not np.allclose(
-                np.asarray(got, np.float32)[real],
-                np.asarray(want, np.float32)[real], rtol=5e-2, atol=5e-2,
-            ):
+            if not close(np.asarray(got, np.float32)[real],
+                         np.asarray(want, np.float32)[real]):
                 return False
+        # what a trainer runs: causal, no mask, T past one tile of
+        # FLASH_BLOCK, and the backward kernels (dq, dk, dv) as well
+        T = 2 * FLASH_BLOCK
+        q, k, v, w = (
+            jnp.asarray(rng.standard_normal((T, H, D)), jnp.bfloat16)
+            for _ in range(4)
+        )
+
+        def grads(attend):
+            return jax.grad(
+                lambda q_, k_, v_: (attend(q_, k_, v_).astype(jnp.float32)
+                                    * w.astype(jnp.float32)).sum(),
+                argnums=(0, 1, 2))(q, k, v)
+
+        flash = lambda q_, k_, v_: _flash_dense(
+            q_, k_, v_, causal=True, scale=None, kv_mask=None)
+        dense = lambda q_, k_, v_: dense_attention(q_, k_, v_, causal=True)
+        if not close(flash(q, k, v), dense(q, k, v)):
+            return False
+        if not all(close(a, b) for a, b in zip(grads(flash), grads(dense))):
+            return False
     except Exception:
         return False
     _flash_verified = True

@@ -1,0 +1,457 @@
+"""Trainer for causal sequence LMs over a sequence-sharded mesh axis.
+
+The sequence counterpart of :mod:`dgraph_tpu.train.loop`: set-up
+(:func:`lm_setup`: mesh-placed parameters, optimizer state, the attention
+implementation chosen after the chip's self-check), one jitted train step and
+one eval step (:func:`make_lm_train_step`, :func:`make_lm_eval_step`), and
+the host-fed loop (:class:`LMTrainer`, :func:`fit_lm`): one host batch of
+token ids goes to the device per step, the step is dispatched, the host
+blocks on the loss.
+
+One packed sequence of ``seq_len`` tokens is a step. It is sharded over the
+graph axis in contiguous blocks (rank ``r`` holds positions
+``[r T/W, (r+1) T/W)``); the trainer owns the global positions, and every one
+of the ``T - 1`` next-token predictions is scored for any world size: a
+shard's last position predicts its right neighbour's first token, fetched by
+``ppermute``.
+
+The loss is the exit-distribution objective of a looped LM
+(:class:`~dgraph_tpu.models.looplm.LoopLM`): with pass ``t``'s logits and
+exit gate ``lambda_t``,
+
+    p_t = lambda_t prod_{j<t} (1 - lambda_j)   (t < R),
+    p_R = prod_{j<R} (1 - lambda_j),
+    loss = mean over positions of  sum_t p_t CE(logits_t, next) - beta H(p).
+
+With one pass, or the gate off (then the last pass exits), it is the plain
+next-token cross-entropy. The head is applied per exit step in blocks of
+positions under recomputation, so no ``[R, T, vocab]`` tensor is ever live. A
+model that returns logits directly
+(:class:`~dgraph_tpu.models.transformer.SeqTransformerLM`, whose MoE blocks
+also sow an auxiliary loss) goes through the same step as one pass.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable, Iterable, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from dgraph_tpu.comm.mesh import GRAPH_AXIS, make_graph_mesh, tree_size
+from dgraph_tpu.obs.metrics import StepMetrics, default_registry
+from dgraph_tpu.train.loop import init_opt_state
+
+# the dense oracle materialises [H, T, T] float32 logits; past this size the
+# trainer refuses it instead of falling back to it in silence
+DENSE_LOGITS_LIMIT_BYTES = 1 << 30
+# one block of float32 logits [block, vocab] the exit loss holds at a time
+LOSS_BLOCK_BYTES = 1 << 28
+# tokens a shard of model.init's probe sequence (no shape depends on it)
+INIT_PROBE_TOKENS = 128
+
+
+def lm_comm(world_size: int):
+    """The communicator of a sequence LM: ``single`` on one device (its
+    ``seq_attention`` is the dense oracle or the flash kernel), ``tpu`` over
+    the graph axis otherwise (ring or Ulysses)."""
+    from dgraph_tpu.comm import Communicator
+
+    if world_size == 1:
+        return Communicator.init_process_group("single")
+    return Communicator.init_process_group("tpu", world_size=world_size)
+
+
+def lm_mesh(world_size: int, devices=None):
+    return make_graph_mesh(ranks_per_graph=world_size, devices=devices)
+
+
+def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
+                      head_dim: int) -> str:
+    """Decide, before anything is traced, which attention implementation
+    ``comm.seq_attention`` will run, and say which: 'flash', 'dense', 'ring',
+    'ulysses+flash' or 'ulysses+dense'.
+
+    Wherever a device holds a full-sequence view the Mosaic flash kernel is
+    engaged only after ``flash_attention_selfcheck()`` passed on this chip
+    (the flag is then pinned, which is what the single-comm site asks for).
+    A dense path whose ``[H, T, T]`` float32 logits would pass
+    ``DENSE_LOGITS_LIMIT_BYTES`` raises: at such a size the oracle is not a
+    fall-back."""
+    from dgraph_tpu import config as cfg
+    from dgraph_tpu.parallel import sequence as seq
+
+    world = comm.get_world_size()
+    if comm.graph_axis is not None and attn_impl == "ring":
+        return "ring"
+    if cfg.flash_attention_enabled():
+        cfg.set_flags(use_flash_attention=seq.flash_attention_selfcheck())
+    if comm.graph_axis is None:
+        t_full, heads, prefix = t_local, num_heads, ""
+    else:  # ulysses: the full sequence, a share of the heads
+        t_full, heads, prefix = t_local * world, num_heads // world, "ulysses+"
+    view = jax.ShapeDtypeStruct((t_full, heads, head_dim), jnp.float32)
+    if seq._flash_applicable(view, require_pinned=comm.graph_axis is None):
+        return prefix + "flash"
+    if heads * t_full * t_full * 4 > DENSE_LOGITS_LIMIT_BYTES:
+        raise RuntimeError(
+            f"attention over T={t_full} with {heads} heads would materialise "
+            f"{heads * t_full * t_full * 4 / 1e9:.1f} GB of logits in the dense "
+            f"oracle, and the flash kernel is not engaged (backend "
+            f"{jax.default_backend()!r}, use_flash_attention="
+            f"{cfg.use_flash_attention!r}, self-check latched="
+            f"{seq._flash_verified}); shard the sequence (ring) or repair the "
+            f"kernel")
+    return prefix + "dense"
+
+
+# --- the loss -----------------------------------------------------------------
+
+def next_token_targets(tokens: jax.Array, comm, seq_len: int):
+    """(targets, valid) for this shard's tokens ``[T_loc]``: position ``i``
+    predicts token ``i + 1``; the shard's last position takes the right
+    neighbour's first token, and the globally last position is not scored."""
+    t_loc = tokens.shape[0]
+    if comm.graph_axis is None:
+        nxt, offset = tokens[:1], 0
+    else:
+        world = comm.get_world_size()
+        left = [(i, (i - 1) % world) for i in range(world)]
+        nxt = lax.ppermute(tokens[:1], comm.graph_axis, left)
+        offset = lax.axis_index(comm.graph_axis) * t_loc
+    targets = jnp.concatenate([tokens[1:], nxt])
+    valid = offset + jnp.arange(t_loc) < seq_len - 1
+    return targets, valid
+
+
+def exit_distribution(gate_logits: jax.Array) -> jax.Array:
+    """log p ``[R, ...]`` of the exit step, from the gate logits of the first
+    ``R - 1`` passes (``[R - 1, ...]``): ``log p_t = log lambda_t +
+    sum_{j<t} log(1 - lambda_j)``, and the last pass takes what is left."""
+    g = gate_logits.astype(jnp.float32)
+    log_stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)  # sum_{j<=t}
+    before = jnp.concatenate([jnp.zeros_like(g[:1]), log_stay[:-1]], axis=0)
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(g) + before, log_stay[-1:]], axis=0)
+
+
+def exit_loss(ce: jax.Array, gate_logits: jax.Array,
+              beta: float) -> jax.Array:
+    """Per position ``[...]``: ``sum_t p_t ce_t - beta H(p)`` for ``ce``
+    ``[R, ...]`` and the gate logits of the first ``R - 1`` passes (``H`` the
+    entropy of the exit distribution)."""
+    logp = exit_distribution(gate_logits)
+    p = jnp.exp(logp)
+    return (p * ce).sum(0) + beta * (p * logp).sum(0)
+
+
+def loss_block_size(t_local: int, vocab: int) -> int:
+    """The largest divisor of ``t_local`` whose float32 logits block
+    ``[block, vocab]`` stays within ``LOSS_BLOCK_BYTES``."""
+    most = max(1, LOSS_BLOCK_BYTES // (4 * vocab))
+    return max(b for b in range(1, t_local + 1)
+               if t_local % b == 0 and b <= most)
+
+
+def blockwise_cross_entropy(logits_fn: Callable, hs: jax.Array,
+                            targets: jax.Array, block: int) -> jax.Array:
+    """``ce[t, i] = logsumexp(logits_fn(hs[t, i])) - logits_fn(hs[t, i])[
+    targets[i]]`` for ``hs`` ``[R, T, d]``, one ``[block, vocab]`` logits
+    tensor at a time (``lax.map`` over the R x T/block blocks), each block
+    recomputed in the backward pass. Equal to the direct computation."""
+    R, T, d = hs.shape
+    nb = T // block
+
+    @jax.checkpoint
+    def one(args):
+        h, tgt = args
+        logits = logits_fn(h)
+        with jax.named_scope("dgraph.lm.cross_entropy"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            hit = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+            return lse - hit
+
+    ce = lax.map(one, (hs.reshape(R * nb, block, d),
+                       jnp.tile(targets.reshape(nb, block), (R, 1))))
+    return ce.reshape(R, T)
+
+
+def _is_looped(model) -> bool:
+    return hasattr(model, "hidden") and hasattr(model, "loop_steps")
+
+
+def local_loss_sum(model, params, tokens, comm, *, seq_len: int,
+                   beta: float = 0.0, loss_block: Optional[int] = None):
+    """(sum over this shard's scored positions of the per-position loss,
+    the model's own auxiliary loss): per shard, inside ``shard_map`` where
+    the communicator has an axis."""
+    t_loc = tokens.shape[0]
+    rank = 0 if comm.graph_axis is None else lax.axis_index(comm.graph_axis)
+    positions = rank * t_loc + jnp.arange(t_loc, dtype=jnp.int32)
+    targets, valid = next_token_targets(tokens, comm, seq_len)
+    aux = 0.0
+    if _is_looped(model):
+        hs = model.apply(params, tokens, positions, method="hidden")
+        gated = model.exit_gate and model.loop_steps > 1
+        if not gated:
+            hs = hs[-1:]  # the last pass exits with certainty
+        with jax.named_scope("dgraph.lm.exit_loss"):
+            block = loss_block or loss_block_size(t_loc, model.vocab)
+            ce = blockwise_cross_entropy(
+                lambda h: model.apply(params, h, method="logits"),
+                hs, targets, block)
+            if gated:
+                gates = model.apply(params, hs[:-1], method="gate_logit")
+                per_pos = exit_loss(ce, gates, beta)
+            else:
+                per_pos = ce[0]
+    else:
+        if getattr(model, "moe_k", 0) > 0:
+            logits, mut = model.apply(params, tokens, positions,
+                                      mutable=["losses"])
+            aux = sum(jnp.sum(v) for v in jax.tree.leaves(mut))
+        else:
+            logits = model.apply(params, tokens, positions)
+        with jax.named_scope("dgraph.lm.exit_loss"):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            per_pos = -jnp.take_along_axis(
+                logp, targets[:, None], axis=1)[:, 0]
+    return jnp.where(valid, per_pos, 0.0).sum(), aux
+
+
+def make_lm_loss(model, mesh, comm, *, seq_len: int, beta: float = 0.0,
+                 loss_block: Optional[int] = None, aux_weight: float = 0.0,
+                 param_specs=None):
+    """``(params, tokens [T]) -> loss``: the mean over the ``T - 1`` scored
+    positions, under ``shard_map`` where the sequence is sharded."""
+
+    def body(params, tokens):
+        total, aux = local_loss_sum(
+            model, params, tokens, comm, seq_len=seq_len, beta=beta,
+            loss_block=loss_block)
+        if comm.graph_axis is not None:
+            total = lax.psum(total, comm.graph_axis)
+        return total / (seq_len - 1) + aux_weight * aux
+
+    if comm.graph_axis is None:
+        return body
+    from dgraph_tpu.comm.collectives import shard_map_checks
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P() if param_specs is None else param_specs,
+                  P(comm.graph_axis)),
+        out_specs=P(),
+        **shard_map_checks(relax="the neighbour-token ppermute and the MoE "
+                                 "all_to_alls are replicated by construction"),
+    )
+
+
+def make_lm_train_step(model, optimizer: optax.GradientTransformation, mesh,
+                       comm, *, seq_len: int, beta: float = 0.0,
+                       loss_block: Optional[int] = None,
+                       aux_weight: float = 0.0, param_specs=None,
+                       donate: bool = True, step_metrics: bool = False):
+    """Jitted ``(params, opt_state, tokens [T]) -> (params, opt_state,
+    StepMetrics)``. ``step_metrics`` (a build-time constant) adds the global
+    gradient norm."""
+    loss_fn = make_lm_loss(
+        model, mesh, comm, seq_len=seq_len, beta=beta, loss_block=loss_block,
+        aux_weight=aux_weight, param_specs=param_specs)
+
+    def lm_train_step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
+        gn = optax.global_norm(grads) if step_metrics else None
+        with jax.named_scope("dgraph.lm.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, StepMetrics(loss=loss, grad_norm=gn)
+
+    return jax.jit(lm_train_step, donate_argnums=(0, 1) if donate else ())
+
+
+def make_lm_eval_step(model, mesh, comm, *, seq_len: int, beta: float = 0.0,
+                      loss_block: Optional[int] = None, param_specs=None):
+    """Jitted ``(params, tokens [T]) -> loss``: the forward pass and the
+    training objective, no gradient."""
+    return jax.jit(make_lm_loss(
+        model, mesh, comm, seq_len=seq_len, beta=beta, loss_block=loss_block,
+        param_specs=param_specs))
+
+
+# --- set-up -------------------------------------------------------------------
+
+def init_lm_params(model, mesh, comm, seed: int = 0, *,
+                   param_specs_fn: Optional[Callable] = None):
+    """``model.init`` on a short probe sequence (no parameter's shape
+    depends on the sequence length), under ``shard_map`` where the
+    communicator has an axis. Returns (params, param_specs or None):
+    ``param_specs_fn(shapes)`` derives the per-leaf specs of leaves that are
+    sharded over the graph axis (MoE experts)."""
+    from dgraph_tpu.obs import spans
+
+    world = comm.get_world_size()
+
+    def init(tokens):
+        t_loc = tokens.shape[0]
+        return model.init(jax.random.key(seed), tokens,
+                          jnp.arange(t_loc, dtype=jnp.int32))
+
+    probe = jnp.zeros((INIT_PROBE_TOKENS * world,), jnp.int32)
+    specs = None
+    with spans.stage("setup.init_params") as st, jax.set_mesh(mesh):
+        if comm.graph_axis is None:
+            params = jax.jit(init)(probe)
+        else:
+            from dgraph_tpu.comm.collectives import shard_map_checks
+
+            def sharded(out_specs):
+                return jax.shard_map(
+                    init, mesh=mesh, in_specs=P(comm.graph_axis),
+                    out_specs=out_specs,
+                    **shard_map_checks(relax="init outputs replicated (or "
+                                             "per-expert) by construction"))
+
+            if param_specs_fn is not None:
+                specs = param_specs_fn(jax.eval_shape(sharded(P()), probe))
+            params = jax.jit(sharded(P() if specs is None else specs))(probe)
+        st.annotate(**tree_size(params))
+    return params, specs
+
+
+@dataclasses.dataclass
+class LMTrainer:
+    """What one launch holds, and the loop's step: host batch -> device,
+    the jitted train step, the host blocks on the loss."""
+
+    model: Any
+    mesh: Any
+    comm: Any
+    seq_len: int
+    params: Any
+    opt_state: Any
+    train_step: Callable
+    eval_step: Callable
+    startup: dict  # what ran: attention implementation, sizes
+    steps_done: int = 0
+
+    def feed(self, tokens: np.ndarray) -> jax.Array:
+        """One host batch ``[T]`` of token ids onto the mesh, sharded over
+        the graph axis."""
+        return jax.device_put(
+            tokens, NamedSharding(self.mesh, P(GRAPH_AXIS)))
+
+    def step(self, tokens: np.ndarray) -> StepMetrics:
+        """One step of the loop. Host-boundary spans (never inside the jitted
+        step), one attribute read each while tracing is off."""
+        from dgraph_tpu.obs import spans
+
+        with spans.span("train.step", step=self.steps_done):
+            with spans.span("host_feed"):
+                toks = self.feed(tokens)
+            with spans.span("step_dispatch"):
+                self.params, self.opt_state, sm = self.train_step(
+                    self.params, self.opt_state, toks)
+            with spans.span("block"):
+                jax.block_until_ready(sm.loss)
+        self.steps_done += 1
+        return sm
+
+    def evaluate(self, tokens: np.ndarray) -> jax.Array:
+        """The training objective on one batch, forward only."""
+        from dgraph_tpu.obs import spans
+
+        with spans.span("train.eval", step=self.steps_done):
+            with spans.span("host_feed"):
+                toks = self.feed(tokens)
+            with spans.span("step_dispatch"):
+                loss = self.eval_step(self.params, toks)
+            with spans.span("block"):
+                jax.block_until_ready(loss)
+        return loss
+
+
+def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
+             seq_len: int, seed: int = 0, beta: float = 0.0,
+             loss_block: Optional[int] = None, aux_weight: float = 0.0,
+             param_specs_fn: Optional[Callable] = None, donate: bool = True,
+             step_metrics: bool = False, params=None) -> LMTrainer:
+    """Everything a launch does once: choose the attention implementation
+    (after the chip's self-check), initialise the parameters (``params``
+    given: those instead, placed on the mesh) and the optimizer state on the
+    mesh, build the steps. Stages ``setup.init_params`` (or ``setup.place``)
+    and ``setup.init_opt_state``; counters ``lm.*``."""
+    world = comm.get_world_size()
+    if seq_len % world:
+        raise ValueError(f"seq_len {seq_len} does not divide by world {world}")
+    heads = getattr(model, "num_heads", 1)
+    head_dim = getattr(model, "head_dim", None) or model.latent // heads
+    attention = resolve_attention(
+        comm, model.attn_impl, seq_len // world, heads, head_dim)
+    specs = None
+    if params is None:
+        params, specs = init_lm_params(
+            model, mesh, comm, seed, param_specs_fn=param_specs_fn)
+    else:  # a caller's own (a checkpoint's): onto the mesh, replicated
+        from dgraph_tpu.obs import spans
+
+        with spans.stage("setup.place", **tree_size(params)):
+            params = jax.device_put(params, NamedSharding(mesh, P()))
+    opt_state = init_opt_state(optimizer, params, mesh)
+    loops = getattr(model, "loop_steps", 1)
+    startup = {
+        "attention": attention, "world_size": world, "seq_len": seq_len,
+        "layers_held": model.num_layers, "loop_steps": loops,
+        "layer_applications": model.num_layers * loops,
+        "parameters": sum(int(np.prod(a.shape))
+                          for a in jax.tree.leaves(params)),
+    }
+    for name in ("layers_held", "loop_steps", "layer_applications"):
+        default_registry.counter(f"lm.{name}", startup[name])
+    default_registry.counter("lm.tokens_per_step", seq_len)
+    default_registry.counter(f"lm.attention.{attention}")
+    kw = dict(seq_len=seq_len, beta=beta, loss_block=loss_block,
+              param_specs=specs)
+    return LMTrainer(
+        model=model, mesh=mesh, comm=comm, seq_len=seq_len, params=params,
+        opt_state=opt_state, startup=startup,
+        train_step=make_lm_train_step(
+            model, optimizer, mesh, comm, aux_weight=aux_weight,
+            donate=donate, step_metrics=step_metrics, **kw),
+        eval_step=make_lm_eval_step(model, mesh, comm, **kw))
+
+
+def fit_lm(model, optimizer: optax.GradientTransformation,
+           batches: Iterable[np.ndarray], *, seq_len: int,
+           world_size: int = 1, steps: int, seed: int = 0,
+           log: Optional[Callable[[dict], None]] = None, log_every: int = 0,
+           **setup):
+    """The training driver: ``lm_setup`` then ``steps`` steps of
+    :meth:`LMTrainer.step` over ``batches`` (host arrays ``[seq_len]`` of
+    token ids). ``model`` was built with ``lm_comm(world_size)``. Returns
+    (trainer, history of per-step records)."""
+    import time
+
+    mesh = lm_mesh(world_size)
+    trainer = lm_setup(model, optimizer, mesh, model.comm, seq_len=seq_len,
+                       seed=seed, **setup)
+    if log is not None:
+        log({"kind": "lm_startup", **trainer.startup})
+    history = []
+    t0 = time.perf_counter()
+    with jax.set_mesh(mesh):
+        for i, tokens in zip(range(steps), batches):
+            sm = trainer.step(tokens)
+            rec = sm.record(
+                step=i, seq_len=seq_len, world=world_size,
+                ms_per_step=(time.perf_counter() - t0) / (i + 1) * 1e3)
+            history.append(rec)
+            if log is not None and log_every and (
+                    i % log_every == 0 or i == steps - 1):
+                log(rec)
+    return trainer, history

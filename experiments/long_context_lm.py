@@ -1,6 +1,8 @@
 """Long-context LM training over a sequence-sharded mesh (ring attention).
 
-The sequence-parallel counterpart of the graph experiment CLIs: trains
+A thin CLI over :mod:`dgraph_tpu.train.lm` (set-up, the jitted step and the
+host-fed loop live there). The sequence-parallel counterpart of the graph
+experiment CLIs: trains
 :class:`~dgraph_tpu.models.transformer.SeqTransformerLM` on a synthetic
 induction corpus (second half repeats the first half, so exact causal
 attention over the FULL sequence is required to get below the unigram
@@ -19,7 +21,6 @@ Usage:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 
@@ -43,6 +44,12 @@ class Config:
     # DeepSpeed-MoE axis fusion); k = experts per token
     moe_k: int = 0
     moe_aux_weight: float = 0.01
+    # >0: the looped LM (models/looplm.py) in place of SeqTransformerLM: its
+    # num_layers layers are passed over loop_steps times with the same
+    # parameters, and with more than one pass the exit gate and the
+    # exit-distribution loss (train/lm.py) are on
+    loop_steps: int = 0
+    exit_beta: float = 0.1
     seed: int = 0
     log_path: str = "logs/long_context_lm.jsonl"
     log_every: int = 20
@@ -54,14 +61,11 @@ class Config:
 def main(cfg: Config):
     import numpy as np
     import jax
-    import jax.numpy as jnp
     import optax
-    from jax.sharding import Mesh, PartitionSpec as P
 
-    from dgraph_tpu.comm import Communicator
-    from dgraph_tpu.models.transformer import SeqTransformerLM
+    from dgraph_tpu.models.transformer import SeqTransformerLM, moe_param_specs
     from dgraph_tpu.obs import startup_record
-    from dgraph_tpu.obs.metrics import StepMetrics
+    from dgraph_tpu.train.lm import fit_lm, lm_comm
     from dgraph_tpu.utils import ExperimentLog
 
     W = cfg.world_size or len(jax.devices())
@@ -71,109 +75,42 @@ def main(cfg: Config):
             f"seq_len {T} must be even (induction corpus halves) and divide "
             f"by world_size {W}"
         )
-    mesh = Mesh(np.array(jax.devices()[:W]), ("graph",))
-    comm = Communicator.init_process_group("tpu", world_size=W)
-    from dgraph_tpu import config as fw_cfg
-    from dgraph_tpu.parallel.sequence import flash_attention_selfcheck
+    if cfg.moe_k > 0 and W == 1:
+        raise SystemExit("moe_k > 0 needs world_size > 1 (one expert a rank)")
+    comm = lm_comm(W)
+    if cfg.loop_steps > 0:
+        from dgraph_tpu.models.looplm import LoopLM
 
-    if fw_cfg.flash_attention_enabled():
-        # chip veto before the kernel is trusted (Mosaic divergence is
-        # invisible to CPU CI — same gate as bench.py's scatter kernels)
-        fw_cfg.set_flags(use_flash_attention=flash_attention_selfcheck())
-    model = SeqTransformerLM(
-        vocab=cfg.vocab, latent=cfg.latent, num_layers=cfg.num_layers,
-        num_heads=cfg.num_heads, max_len=T, comm=comm,
-        attn_impl=cfg.attn_impl, moe_k=cfg.moe_k,
-    )
+        model = LoopLM(
+            vocab=cfg.vocab, hidden_size=cfg.latent,
+            num_layers=cfg.num_layers, num_heads=cfg.num_heads,
+            head_dim=cfg.latent // cfg.num_heads, intermediate=4 * cfg.latent,
+            comm=comm, loop_steps=cfg.loop_steps,
+            exit_gate=cfg.loop_steps > 1, attn_impl=cfg.attn_impl)
+    else:
+        model = SeqTransformerLM(
+            vocab=cfg.vocab, latent=cfg.latent, num_layers=cfg.num_layers,
+            num_heads=cfg.num_heads, max_len=T, comm=comm,
+            attn_impl=cfg.attn_impl, moe_k=cfg.moe_k,
+        )
     rng = np.random.default_rng(cfg.seed)
-    pos = jnp.arange(T, dtype=jnp.int32)
 
-    def batch():
-        half = rng.integers(1, cfg.vocab, T // 2)
-        return jnp.asarray(np.concatenate([half, half]).astype(np.int32))
+    def batches():
+        while True:
+            half = rng.integers(1, cfg.vocab, T // 2)
+            yield np.concatenate([half, half]).astype(np.int32)
 
-    def shard_loss(params, toks, pos):
-        # Score ALL T-1 next-token predictions, not just each shard's
-        # local T_loc-1: every shard's last position predicts the right
-        # neighbor's first token (fetched by ppermute), so the objective —
-        # and the logged loss — is identical for any world size
-        # (ADVICE r2 #3: W=1 vs W=8 curves must be comparable).
-        aux = 0.0
-        if cfg.moe_k > 0:
-            logits, mut = model.apply(params, toks, pos, mutable=["losses"])
-            aux = sum(jnp.sum(v) for v in jax.tree.leaves(mut))
-            aux = cfg.moe_aux_weight * aux / max(cfg.num_layers, 1)
-        else:
-            logits = model.apply(params, toks, pos)
-        left = [(i, (i - 1) % W) for i in range(W)]
-        nxt = jax.lax.ppermute(toks[:1], "graph", left)
-        targets = jnp.concatenate([toks[1:], nxt])
-        logp = jax.nn.log_softmax(logits)
-        ll = jnp.take_along_axis(logp, targets[:, None], axis=1)[:, 0]
-        # the globally-last position's "target" is the wrapped-around
-        # first token — mask it out
-        t_loc = toks.shape[0]
-        is_last = jax.lax.axis_index("graph") == W - 1
-        valid = jnp.where(
-            is_last, jnp.arange(t_loc) < t_loc - 1, jnp.ones(t_loc, bool)
-        )
-        return (
-            -jax.lax.psum((ll * valid).sum(), "graph") / (T - 1) + aux
-        )
-
-    from dgraph_tpu.models.transformer import moe_param_specs
-
-    toks0 = batch()
-    # paths only (the MoE blocks trace collectives, so even shape
-    # derivation must run under shard_map; out_specs=P() is fine for
-    # PATH discovery — the real init below uses the derived specs)
-    shapes = jax.eval_shape(
-        jax.shard_map(
-            lambda tk, ps: model.init(jax.random.key(cfg.seed), tk, ps),
-            mesh=mesh, in_specs=(P("graph"), P("graph")), out_specs=P(),
-            check_vma=False,
-        ),
-        toks0, pos,
+    log = ExperimentLog(cfg.log_path)
+    log.write(startup_record("experiments.long_context_lm"))
+    uniform = float(np.log(cfg.vocab))
+    fit_lm(
+        model, optax.adam(cfg.lr), batches(), seq_len=T, world_size=W,
+        steps=cfg.steps, seed=cfg.seed, beta=cfg.exit_beta,
+        aux_weight=cfg.moe_aux_weight / max(cfg.num_layers, 1),
+        param_specs_fn=moe_param_specs if cfg.moe_k > 0 else None,
+        step_metrics=cfg.step_metrics, log_every=cfg.log_every,
+        log=lambda rec: log.write(dict(rec, uniform_nats=uniform)),
     )
-    pspecs = moe_param_specs(shapes)
-
-    loss_sm = jax.shard_map(
-        shard_loss, mesh=mesh,
-        in_specs=(pspecs, P("graph"), P("graph")), out_specs=P(),
-        check_vma=False,
-    )
-
-    with jax.set_mesh(mesh):
-        params = jax.shard_map(
-            lambda tk, ps: model.init(jax.random.key(cfg.seed), tk, ps),
-            mesh=mesh, in_specs=(P("graph"), P("graph")), out_specs=pspecs,
-            check_vma=False,
-        )(toks0, pos)
-        opt = optax.adam(cfg.lr)
-        opt_state = opt.init(params)
-
-        @jax.jit
-        def step(params, opt_state, toks):
-            l, g = jax.value_and_grad(
-                lambda p, tk: loss_sm(p, tk, pos)
-            )(params, toks)
-            # build-time flag: False traces the exact un-instrumented step
-            gn = optax.global_norm(g) if cfg.step_metrics else None
-            updates, opt_state = opt.update(g, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            return params, opt_state, StepMetrics(loss=l, grad_norm=gn)
-
-        log = ExperimentLog(cfg.log_path)
-        log.write(startup_record("experiments.long_context_lm"))
-        uniform = float(np.log(cfg.vocab))
-        t0 = time.perf_counter()
-        for i in range(cfg.steps):
-            params, opt_state, sm = step(params, opt_state, batch())
-            if i % cfg.log_every == 0 or i == cfg.steps - 1:
-                log.write(sm.record(
-                    step=i, uniform_nats=uniform, seq_len=T, world=W,
-                    ms_per_step=(time.perf_counter() - t0) / (i + 1) * 1e3,
-                ))
 
 
 if __name__ == "__main__":
