@@ -59,9 +59,10 @@ def pallas_fused_enabled() -> bool:
     return pallas_scatter_enabled()
 
 
-# The fused-BACKWARD kernel pair (chunk-major gd + epilogue="act" d_bias)
-# inside the fused op's VJP. Tri-state; None = engage whenever the fused
-# op itself runs. A Mosaic regression hitting only the bwd kernels can be
+# The fused-BACKWARD kernel pair (chunk-major gd, with d_w where the op
+# takes an edge weight, + epilogue="act" d_bias) inside the fused op's
+# VJP, weighted or not. Tri-state; None = engage whenever the fused op
+# itself runs. A Mosaic regression hitting only the bwd kernels can be
 # disabled here without vetoing the whole fused op (ADVICE r4): the
 # composed bwd fallback stays available as the A/B control.
 use_pallas_fused_bwd: bool | None = _env_flag("DGRAPH_TPU_PALLAS_FUSED_BWD", None)

@@ -411,30 +411,38 @@ def _make_ssbr(num_segments, max_chunks_per_block, block_e, block_n, interpret,
         data, segment_ids, bias, edge_weight = res
         cdt = data.dtype
 
-        # fused-bwd kernel pair (unweighted path): gd from ONE chunk-major
-        # pass (no bias-rows take, no g-rows take, no act tensor — the
-        # composed bwd streams all three through HBM), d_bias's Σact from
-        # ONE vblock-major pass (epilogue="act"). Engages when the plan
-        # carried the vblock-span hint (gather_mv) and the kernels can run
-        # (TPU, or interpret mode for tests); the fused kill switch
-        # already gated entry into this op at the dispatch point, and
+        # fused-bwd kernel pair, with or without an edge weight: gd (and
+        # d_w) from ONE chunk-major pass (no bias-rows take, no g-rows
+        # take, no act tensor — the composed bwd streams all three
+        # through HBM), d_bias's Σ w·act from ONE vblock-major pass
+        # (epilogue="act"). Engages when the plan carried the vblock-span
+        # hint (gather_mv) and the kernels can run (TPU, or interpret
+        # mode for tests); the fused kill switch already gated entry into
+        # this op at the dispatch point, and
         # config.pallas_fused_bwd_enabled() (trace-time read) disables
         # just this pair for debugging/A-B without losing the fused fwd.
+        # Which branch each traced backward took is counted
+        # (segsum.bwd_fused / segsum.bwd_composed, docs/tracing.md).
         from dgraph_tpu import config as _config
+        from dgraph_tpu.obs.metrics import default_registry
 
-        if (not has_weight and gather_mv > 0
+        if (gather_mv > 0
                 and _config.pallas_fused_bwd_enabled()
                 and (interpret or jax.default_backend() == "tpu")):
-            gd = _make_fused_bwd(
+            default_registry.counter("segsum.bwd_fused")
+            gd, d_w = _make_fused_bwd(
                 num_segments, gather_mv, block_e, block_n, interpret,
-                precision,
-            )(data, g.astype(cdt), bias.astype(cdt), segment_ids)
+                precision, has_weight,
+            )(data, g.astype(cdt), bias.astype(cdt), segment_ids,
+              edge_weight)
             sum_act = impl(data, segment_ids, bias, edge_weight,
-                           epilogue="act")  # f32 [N, F]
-            d_bias = sum_act * g.astype(jnp.float32)
-            return (gd, None, d_bias.astype(bias.dtype),
-                    jnp.zeros_like(edge_weight))
+                           epilogue="act")  # f32 [N, F], Σ w·act
+            d_bias = (sum_act * g.astype(jnp.float32)).astype(bias.dtype)
+            if d_w is None:
+                d_w = jnp.zeros_like(edge_weight)
+            return gd, None, d_bias, d_w.astype(edge_weight.dtype)
 
+        default_registry.counter("segsum.bwd_composed")
         # composed fallback: recompute the activation mask (remat: the
         # [E,F] pre-activation was never materialized in the forward —
         # that's the point); both row takes are by the plan's sorted ids
@@ -499,12 +507,12 @@ def sorted_segment_sum_bias_relu(
     block_n: int = 256,
     interpret: bool = False,
     gather_mv: int = 0,  # vblock-span hint (plan.gather_mv). >0 selects
-    # the UNWEIGHTED op's Pallas backward KERNEL PAIR on TPU
-    # (_fused_bwd_kernel gd + epilogue="act" d_bias), additionally gated
-    # by config.pallas_fused_bwd_enabled() read at trace time
+    # the op's Pallas backward KERNEL PAIR on TPU, weighted or not
+    # (_fused_bwd_kernel gd [+ d_w] + epilogue="act" d_bias), additionally
+    # gated by config.pallas_fused_bwd_enabled() read at trace time
     # (DGRAPH_TPU_PALLAS_FUSED_BWD — the pair's own kill switch; the
     # fused op as a whole still gates at the dispatch point). In
-    # the composed/weighted backward it additionally lets the cotangent
+    # the composed backward it additionally lets the cotangent
     # gather use sorted_row_gather under DGRAPH_TPU_PALLAS_GATHER.
     precision: str = "default",
 ) -> jax.Array:
@@ -691,23 +699,34 @@ def _make_srg(num_rows, max_vblocks, block_e, block_n, interpret, precision,
 
 
 def _fused_bwd_kernel(
-    vb_starts_ref, vb_counts_ref, ids_ref, data_ref, g_ref, bias_ref,
-    out_ref, g_acc, bias_acc, *, block_n, block_e, precision,
+    vb_starts_ref, vb_counts_ref, ids_ref, *refs,
+    block_n, block_e, precision, has_weight,
 ):
-    """gd[e] = g[ids[e]] * 1[data[e] + bias[ids[e]] > 0] in ONE
+    """gd[e] = w[e] * g[ids[e]] * 1[data[e] + bias[ids[e]] > 0] in ONE
     chunk-major pass: the fused scatter's data-gradient with no [E, F]
     HBM intermediates (no bias-rows take, no g-rows take, no act
-    materialization — the r4 composed bwd streamed all three). The
-    WEIGHTED fused op keeps the composed backward (it additionally needs
-    d_w, whose row-dot requires the very intermediates this kernel
-    avoids), so there is deliberately no edge-weight input here.
+    materialization — the r4 composed bwd streamed all three).
+
+    ``has_weight`` adds the chunk's edge weights as an operand (the
+    forward's ``[num_chunks, 1, block_e]`` layout) and a second output,
+    d_w[e] = Σ_f g[ids[e]] * relu(data[e] + bias[ids[e]]): at the finish
+    step the gathered g rows, the pre-activation and the weights are all
+    in VMEM, so the product is a multiply and the row-dot one small MXU
+    contraction there. Without it the kernel is operand for operand the
+    unweighted one.
 
     Chunk-major grid like :func:`_gather_kernel`; g and bias rows are
     accumulated per vertex-block via one-hot matmuls (disjoint per edge,
     so plain += is exact), and the activation mask is decided in f32 at
     the last vertex block of the chunk's span — the same rounding story
     as the forward kernel (operands rounded to the data dtype, compare
-    in f32)."""
+    in f32). Masked and padded edges match no one-hot column (or only
+    zero-padded vertex rows), so their gd and d_w read 0."""
+    if has_weight:
+        (wgt_ref, data_ref, g_ref, bias_ref, out_ref, dw_ref,
+         g_acc, bias_acc) = refs
+    else:
+        data_ref, g_ref, bias_ref, out_ref, g_acc, bias_acc = refs
     k = pl.program_id(0)  # edge chunk (owns the resident out block)
     j = pl.program_id(1)  # vertex-block iteration within the chunk's span
 
@@ -747,48 +766,79 @@ def _fused_bwd_kernel(
         chunk = data_ref[0]  # [block_e, F]
         pre = chunk.astype(jnp.float32) + bias_acc[...]
         act = (pre > 0).astype(jnp.float32)
-        out_ref[...] = (g_acc[...] * act).astype(out_ref.dtype)
+        gd = g_acc[...] * act
+        if has_weight:
+            # the row-dot Σ_f g·relu(pre) on the MXU: a ones tile
+            # contracted with the product over F leaves the edges on the
+            # LANES, which is d_w's layout; a VPU `.sum(axis=-1)` is a
+            # cross-lane reduction per row plus a sublane->lane relayout
+            # (measured: +4.5 ms a call at 2.33 M edges, v5e). Row 0 of the
+            # eight identical rows is stored. The product enters the MXU
+            # in the data dtype, as every other operand of this op does.
+            prod = (g_acc[...] * jnp.maximum(pre, 0)).astype(data_ref.dtype)
+            dw_ref[0] = jax.lax.dot_general(
+                jnp.ones((8, prod.shape[1]), prod.dtype), prod,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=precision,
+            )[0:1]
+            # f32 BEFORE the [:, None] (Mosaic inserts a minor dim on
+            # 32-bit vectors only: see _kernel_bias_relu)
+            gd = gd * wgt_ref[0, 0].astype(jnp.float32)[:, None]
+        out_ref[...] = gd.astype(out_ref.dtype)
 
 
 @functools.lru_cache(maxsize=None)
 def _make_fused_bwd(num_rows, max_vblocks, block_e, block_n, interpret,
-                    precision):
-    """Builder for the (unweighted) fused scatter's data-gradient kernel
-    (see :func:`_fused_bwd_kernel`). Returns fn(data, g, bias, ids) ->
-    [E, F] gd in data's dtype."""
+                    precision, has_weight=False):
+    """Builder for the fused scatter's data-gradient kernel (see
+    :func:`_fused_bwd_kernel`). Returns fn(data, g, bias, ids,
+    edge_weight) -> ([E, F] gd in data's dtype, [E] f32 d_w; None where
+    ``has_weight`` is off, and ``edge_weight`` is then not read)."""
 
-    def impl(data, g, bias, ids):
+    def impl(data, g, bias, ids, edge_weight=None):
         E, F = data.shape
         vs = _VBlockSchedule(ids, num_rows, E, block_e=block_e,
                              block_n=block_n, max_vblocks=max_vblocks)
         data3d = vs.pad_edges(data).reshape(vs.num_chunks, block_e, F)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(vs.num_chunks, max_vblocks),
-            in_specs=[
-                vs.ids_spec(),
-                pl.BlockSpec((1, block_e, F), lambda k, j, s, c: (k, 0, 0)),
-                vs.vtx_spec(F),
-                vs.vtx_spec(F),
-            ],
-            out_specs=vs.out_spec(F),
-            scratch_shapes=[
-                pltpu.VMEM((block_e, F), jnp.float32),  # g-rows acc
-                pltpu.VMEM((block_e, F), jnp.float32),  # bias-rows acc
-            ],
-        )
-        operands = (vs.vb_start, vs.vb_counts, vs.ids3d, data3d,
-                    vs.pad_vertices(g), vs.pad_vertices(bias))
-        out = pl.pallas_call(
+        in_specs = [
+            vs.ids_spec(),
+            pl.BlockSpec((1, block_e, F), lambda k, j, s, c: (k, 0, 0)),
+            vs.vtx_spec(F),
+            vs.vtx_spec(F),
+        ]
+        operands = [vs.ids3d, data3d, vs.pad_vertices(g),
+                    vs.pad_vertices(bias)]
+        if has_weight:
+            # one value an edge, chunk-major like the ids
+            in_specs.insert(1, vs.ids_spec())
+            operands.insert(1, vs.pad_edges(edge_weight).reshape(
+                vs.num_chunks, 1, block_e))
+        call_args = (vs.vb_start, vs.vb_counts, *operands)
+        out_specs = [vs.out_spec(F)]
+        out_shape = [_out_struct((vs.E_pad, F), data.dtype, *call_args)]
+        if has_weight:
+            out_specs.append(vs.ids_spec())
+            out_shape.append(_out_struct(
+                (vs.num_chunks, 1, block_e), jnp.float32, *call_args))
+        gd, *d_w = pl.pallas_call(
             functools.partial(
                 _fused_bwd_kernel, block_n=block_n, block_e=block_e,
-                precision=_precision(precision),
+                precision=_precision(precision), has_weight=has_weight,
             ),
-            grid_spec=grid_spec,
-            out_shape=_out_struct((vs.E_pad, F), data.dtype, *operands),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(vs.num_chunks, max_vblocks),
+                in_specs=in_specs,
+                out_specs=out_specs,
+                scratch_shapes=[
+                    pltpu.VMEM((block_e, F), jnp.float32),  # g-rows acc
+                    pltpu.VMEM((block_e, F), jnp.float32),  # bias-rows acc
+                ],
+            ),
+            out_shape=out_shape,
             interpret=interpret,
-        )(*operands)
-        return out[:E]
+        )(*call_args)
+        return gd[:E], (d_w[0].reshape(vs.E_pad)[:E] if d_w else None)
 
     return impl
 

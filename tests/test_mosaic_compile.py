@@ -1,0 +1,69 @@
+"""Compile-only: kernels of the main path at a benchmark cell's real shape,
+lowered by Mosaic for a DESCRIBED ``v5e`` (no chip attached), so a change
+that breaks a kernel's TPU lowering fails here and not on the chip.
+Interpret mode checks none of it (tiling, minor-dim inserts, VMEM).
+
+Nothing runs: a compile that passes is not a chip run and gives no time.
+The topology is described inside a fixture, never at import (one process at
+a time may load libtpu; the worker that is given this file is the one that
+does), and every such compile lives in this one file."""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+# gcn_arxiv.w1 (benchmark/configs/gcn_arxiv.json): e_pad, n_owner_pad, one
+# 128-column feature chunk in bf16, the plan's blocks (plan.SCATTER_BLOCK_*)
+# and a gather_mv of 2 (1024 sorted edges span at most two 256-row blocks)
+E, N, F, BE, BN, MV = 2_332_672, 169_344, 128, 1024, 256, 2
+
+
+@pytest.mark.parametrize("has_weight", [False, True])
+def test_fused_bwd_gd_kernel_compiles_at_arxiv_shape(one_chip, has_weight):
+    """The fused scatter's gd kernel (with d_w where weighted: the lane
+    reduction and the 32-bit minor-dim insert of the weight are what
+    Mosaic could refuse)."""
+    from dgraph_tpu.ops.pallas_segment import _make_fused_bwd
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    fn = _make_fused_bwd(N, MV, BE, BN, False, "default", has_weight)
+    args = [shape((E, F), jnp.bfloat16), shape((N, F), jnp.bfloat16),
+            shape((N, F), jnp.bfloat16), shape((E,), jnp.int32)]
+    if has_weight:
+        args.append(shape((E,), jnp.float32))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    gd, d_w = jax.eval_shape(fn, *args)
+    assert (gd.shape, gd.dtype) == ((E, F), jnp.bfloat16)
+    if has_weight:
+        assert (d_w.shape, d_w.dtype) == ((E,), jnp.float32)
+    else:
+        assert d_w is None

@@ -2,6 +2,8 @@
 the reference's CUDA-kernel-vs-dense-loop test pattern,
 ``tests/test_local_kernels.py:26-154``)."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -127,6 +129,21 @@ def test_fused_relu_input_op(rng):
     np.testing.assert_allclose(np.asarray(got), expected, rtol=1e-5, atol=1e-5)
 
 
+@contextlib.contextmanager
+def _bwd_branch_counts():
+    """Yields a function giving how many traced backwards of the fused op
+    took (the kernel pair, the composed ops) since entry: the program's
+    ``segsum.bwd_fused`` / ``segsum.bwd_composed`` counters."""
+    from dgraph_tpu.obs.metrics import default_registry
+
+    def read():
+        c = default_registry.snapshot()["counters"]
+        return (c.get("segsum.bwd_fused", 0), c.get("segsum.bwd_composed", 0))
+
+    before = read()
+    yield lambda: tuple(int(a - b) for a, b in zip(read(), before))
+
+
 class TestFusedBiasRelu:
     """sorted_segment_sum_bias_relu (the reference's fused scatter family,
     local_data_kernels.cuh:34-116): interpret-mode kernel vs numpy oracle,
@@ -207,19 +224,30 @@ class TestFusedBiasRelu:
                 err_msg=name,
             )
 
+    def _zero_some_weights(self, w, seed):
+        """A weight of 0 on a tenth of the edges: gd must read 0 there
+        while d_w does not."""
+        w = w.copy()
+        w[np.random.default_rng(seed).random(w.shape[0]) < 0.1] = 0.0
+        return w
+
+    @pytest.mark.parametrize("use_w", [False, True])
     @pytest.mark.parametrize("be,bn", [(128, 128), (256, 64)])
-    def test_kernel_bwd_pair_matches_composite(self, be, bn):
-        """The unweighted KERNEL backward (chunk-major gd kernel + the
-        epilogue='act' d_bias reduction — engaged when gather_mv > 0)
-        must produce the same gradients as plain autodiff through the
-        composed ops. This is the path the bf16 GCN epoch runs on TPU."""
+    def test_kernel_bwd_pair_matches_composite(self, be, bn, use_w):
+        """The KERNEL backward (chunk-major gd [+ d_w] kernel + the
+        epilogue='act' d_bias reduction — engaged when gather_mv > 0,
+        with or without an edge weight) must produce the same gradients
+        as plain autodiff through the composed ops, masked tail edges
+        (ids >= N) and zero weights included. This is the path the bf16
+        GCN epoch runs on TPU."""
         from dgraph_tpu.ops.pallas_segment import (
             max_chunks_hint,
             max_vblocks_hint,
             sorted_segment_sum_bias_relu,
         )
 
-        ids, data, bias, _ = self._case(4, E=1024, N=256, F=16)
+        ids, data, bias, w = self._case(4, E=1024, N=256, F=16)
+        w = self._zero_some_weights(w, 12)
         N = bias.shape[0]
         tgt = jnp.asarray(
             np.random.default_rng(5).standard_normal((N, 16)).astype(np.float32)
@@ -230,42 +258,55 @@ class TestFusedBiasRelu:
         safe = np.clip(ids, 0, N - 1).astype(np.int32)
         valid = (ids < N).astype(np.float32)[:, None]
 
-        def fused(d, b):
+        def fused(d, b, wgt):
             out = sorted_segment_sum_bias_relu(
                 d, jnp.asarray(ids), b, N,
+                edge_weight=wgt if use_w else None,
                 max_chunks_per_block=mc, block_e=be, block_n=bn,
                 gather_mv=mv, interpret=True,
             )
             return (out * tgt).sum()
 
-        def composed(d, b):
+        def composed(d, b, wgt):
             rows = jnp.take(b, jnp.asarray(safe), axis=0)
             m = jnp.maximum(d + rows, 0) * jnp.asarray(valid)
+            if use_w:
+                m = m * wgt[:, None]
             out = jax.ops.segment_sum(m, jnp.asarray(safe), num_segments=N)
             return (out * tgt).sum()
 
-        args = (jnp.asarray(data), jnp.asarray(bias))
-        ga = jax.grad(fused, argnums=(0, 1))(*args)
-        gb = jax.grad(composed, argnums=(0, 1))(*args)
-        for a, b, name in zip(ga, gb, ["d_data", "d_bias"]):
+        args = (jnp.asarray(data), jnp.asarray(bias), jnp.asarray(w))
+        with _bwd_branch_counts() as took:
+            ga = jax.grad(fused, argnums=(0, 1, 2))(*args)
+        assert took() == (1, 0), "the kernel pair did not engage"
+        gb = jax.grad(composed, argnums=(0, 1, 2))(*args)
+        for a, b, name in zip(ga, gb, ["d_data", "d_bias", "d_w"]):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4,
                 err_msg=name,
             )
+        if use_w:
+            assert np.abs(np.asarray(ga[2])[:-32]).max() > 0
+            np.testing.assert_array_equal(np.asarray(ga[0])[w == 0], 0.0)
+            np.testing.assert_array_equal(np.asarray(ga[2])[-32:], 0.0)
 
-    def test_kernel_bwd_pair_bf16_matches_composed_bwd(self):
+    @pytest.mark.parametrize("use_w", [False, True])
+    def test_kernel_bwd_pair_bf16_matches_composed_bwd(self, use_w):
         """bf16 KERNEL backward vs the bf16 COMPOSED backward (gather_mv=0
         disables the kernel pair): both decide the ReLU mask from the same
         bf16-rounded operands in f32, so they must agree to accumulation
         rounding — an f32 reference would differ by whole elements at
-        ReLU-boundary flips, which is inherent to bf16, not a kernel bug."""
+        ReLU-boundary flips, which is inherent to bf16, not a kernel bug.
+        d_w (f32, a sum over F of bf16-rounded products on the composed
+        side) is held to the same rounding."""
         from dgraph_tpu.ops.pallas_segment import (
             max_chunks_hint,
             max_vblocks_hint,
             sorted_segment_sum_bias_relu,
         )
 
-        ids, data, bias, _ = self._case(6, E=1024, N=256, F=16)
+        ids, data, bias, w = self._case(6, E=1024, N=256, F=16)
+        w = self._zero_some_weights(w, 13)
         N = bias.shape[0]
         mc = max_chunks_hint(ids, N)
         mv = max_vblocks_hint(ids, N)
@@ -273,29 +314,35 @@ class TestFusedBiasRelu:
             np.random.default_rng(7).standard_normal((N, 16)).astype(np.float32)
         )
 
-        def loss(d, b, gmv):
+        def loss(d, b, wgt, gmv):
             out = sorted_segment_sum_bias_relu(
                 jnp.asarray(d, jnp.bfloat16), jnp.asarray(ids),
                 jnp.asarray(b, jnp.bfloat16), N,
+                edge_weight=wgt if use_w else None,
                 max_chunks_per_block=mc, gather_mv=gmv, interpret=True,
             )
             return (out.astype(jnp.float32) * tgt).sum()
 
-        args = (jnp.asarray(data), jnp.asarray(bias))
-        gk = jax.grad(lambda d, b: loss(d, b, mv), argnums=(0, 1))(*args)
-        gc = jax.grad(lambda d, b: loss(d, b, 0), argnums=(0, 1))(*args)
-        for a, b, name in zip(gk, gc, ["d_data", "d_bias"]):
+        args = (jnp.asarray(data), jnp.asarray(bias), jnp.asarray(w))
+        gk = jax.grad(lambda d, b, x: loss(d, b, x, mv),
+                      argnums=(0, 1, 2))(*args)
+        gc = jax.grad(lambda d, b, x: loss(d, b, x, 0),
+                      argnums=(0, 1, 2))(*args)
+        for a, b, name in zip(gk, gc, ["d_data", "d_bias", "d_w"]):
+            # d_w sums F products: rounding scales with the row's norm
+            tol = 0.02 * (4 if name == "d_w" else 1)
             np.testing.assert_allclose(
                 np.asarray(a, np.float32), np.asarray(b, np.float32),
-                rtol=0.02, atol=0.02, err_msg=name,
+                rtol=tol, atol=tol, err_msg=name,
             )
 
-    def test_fused_bwd_kill_switch_routes_to_composed(self):
+    @pytest.mark.parametrize("use_w", [False, True])
+    def test_fused_bwd_kill_switch_routes_to_composed(self, use_w):
         """With use_pallas_fused_bwd=False the VJP must bypass the kernel
         pair even when gather_mv>0 (ADVICE r4: the pair needs its own
-        disable for Mosaic-regression debugging), and grads must match the
-        enabled path. The flag is read at trace time, so flipping it here
-        exercises the branch without env vars."""
+        disable for Mosaic-regression debugging), weighted or not, and
+        grads must match the enabled path. The flag is read at trace time,
+        so flipping it here exercises the branch without env vars."""
         from dgraph_tpu import config
         from dgraph_tpu.ops.pallas_segment import (
             max_chunks_hint,
@@ -303,7 +350,7 @@ class TestFusedBiasRelu:
             sorted_segment_sum_bias_relu,
         )
 
-        ids, data, bias, _ = self._case(9, E=512, N=128, F=8)
+        ids, data, bias, w = self._case(9, E=512, N=128, F=8)
         N = bias.shape[0]
         mc = max_chunks_hint(ids, N)
         mv = max_vblocks_hint(ids, N)
@@ -311,16 +358,18 @@ class TestFusedBiasRelu:
             np.random.default_rng(11).standard_normal((N, 8)).astype(np.float32)
         )
 
-        def loss(d, b):
+        def loss(d, b, wgt):
             out = sorted_segment_sum_bias_relu(
                 d, jnp.asarray(ids), b, N,
+                edge_weight=wgt if use_w else None,
                 max_chunks_per_block=mc, gather_mv=mv, interpret=True,
             )
             return (out.astype(jnp.float32) * tgt).sum()
 
         # the pair and the composed bwd agree numerically by design, so a
         # silently-ignored flag would still pass an allclose — count the
-        # kernel-pair factory's invocations to prove the ROUTING flips
+        # kernel-pair factory's invocations to prove the ROUTING flips,
+        # and read the program's own count of the branch taken beside it
         from dgraph_tpu.ops import pallas_segment as ps
 
         real_make = ps._make_fused_bwd
@@ -330,20 +379,24 @@ class TestFusedBiasRelu:
             calls.append(1)
             return real_make(*a, **kw)
 
-        args = (jnp.asarray(data), jnp.asarray(bias))
+        args = (jnp.asarray(data), jnp.asarray(bias), jnp.asarray(w))
         old_flag = config.use_pallas_fused_bwd
         ps._make_fused_bwd = counting_make
         try:
-            g_on = jax.grad(loss, argnums=(0, 1))(*args)
+            with _bwd_branch_counts() as took:
+                g_on = jax.grad(loss, argnums=(0, 1, 2))(*args)
             assert calls, "kernel pair did not engage with the flag on"
+            assert took() == (1, 0)
             calls.clear()
             config.set_flags(use_pallas_fused_bwd=False)
-            g_off = jax.grad(loss, argnums=(0, 1))(*args)
+            with _bwd_branch_counts() as took:
+                g_off = jax.grad(loss, argnums=(0, 1, 2))(*args)
             assert not calls, "kill switch ignored: kernel pair still ran"
+            assert took() == (0, 1)
         finally:
             ps._make_fused_bwd = real_make
             config.set_flags(use_pallas_fused_bwd=old_flag)
-        for a, b, name in zip(g_on, g_off, ["d_data", "d_bias"]):
+        for a, b, name in zip(g_on, g_off, ["d_data", "d_bias", "d_w"]):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4,
                 err_msg=name,

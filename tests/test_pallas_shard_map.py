@@ -9,6 +9,7 @@ outside shard_map, and on CPU the dispatch never picks Pallas, so only this
 file sees that failure."""
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -112,14 +113,36 @@ def test_fused_bias_relu_weighted_in_shard_map(mesh8, rng):
     _assert_close(got, want)
 
 
-def test_fused_bias_relu_and_bwd_pair_in_shard_map(mesh8, rng):
-    """Unweighted + ``gather_mv`` > 0: the VJP runs the fused-backward
-    kernel pair (gd kernel + the ``epilogue='act'`` reduction)."""
-    ids, data, bias, _, mc, mv = _shards(rng)
-    got = _run(mesh8, lambda d, b, i: sorted_segment_sum_bias_relu(
-        d, i, b, N, max_chunks_per_block=mc, block_e=BE, block_n=BN,
-        interpret=INTERP, gather_mv=mv, precision="highest"),
-        data, bias, ids)
-    want = _run(mesh8, lambda d, b, i: _composed_bias_relu(d, b, None, i),
-                data, bias, ids)
+def _bwd_fused_count():
+    from dgraph_tpu.obs.metrics import default_registry
+
+    return default_registry.snapshot()["counters"].get("segsum.bwd_fused", 0)
+
+
+@pytest.mark.parametrize("use_w", [False, True])
+def test_fused_bias_relu_and_bwd_pair_in_shard_map(mesh8, rng, use_w):
+    """``gather_mv`` > 0, with and without an edge weight: the VJP runs the
+    fused-backward kernel pair (gd kernel, which also gives d_w, + the
+    ``epilogue='act'`` reduction)."""
+    ids, data, bias, wgt, mc, mv = _shards(rng)
+    wgt[:, ::7] = 0.0  # some edges weigh nothing
+    floats = (data, bias, wgt) if use_w else (data, bias)
+
+    def fused(d, b, *rest):
+        *w, i = rest
+        return sorted_segment_sum_bias_relu(
+            d, i, b, N, edge_weight=w[0] if w else None,
+            max_chunks_per_block=mc, block_e=BE, block_n=BN,
+            interpret=INTERP, gather_mv=mv, precision="highest")
+
+    def composed(d, b, *rest):
+        *w, i = rest
+        return _composed_bias_relu(d, b, w[0] if w else None, i)
+
+    fused_before = _bwd_fused_count()
+    got = _run(mesh8, fused, *floats, ids)
+    assert _bwd_fused_count() == fused_before + 1
+    want = _run(mesh8, composed, *floats, ids)
+    assert len(got) == len(floats) + 1
     _assert_close(got, want)
+
