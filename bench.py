@@ -392,8 +392,7 @@ def bench_gcn(dtype_name: str, peaks: "dict | None"):
     plan_np, _ = build_edge_plan(
         edge_index, part, world_size=1, edge_owner="dst",
         pad_multiple=pad_multiple,
-        # both split lowerings ride the interior/boundary split
-        overlap=True if tuned_halo_impl in ("overlap", "pallas_p2p") else None,
+        overlap=True if tuned_halo_impl == "overlap" else None,
     )
     # interior/boundary split of the workload (plan.py): the boundary
     # fraction bounds the halo payload, the interior fraction bounds what

@@ -26,16 +26,9 @@ artifact rather than a hope:
   verifies the post-lowering schedule: collective kinds/counts/
   replica_groups vs the plan, operand bytes vs ``obs.footprint``, **no
   XLA-materialized collective the plan didn't schedule** (the accidental
-  all-gather class the ``pallas_p2p`` relaxed replication checker can no
-  longer catch), one transport family per program, and
+  all-gather class), one transport family per program, and
   ``(params, opt_state)`` donation surviving lowering as donor/alias
   entries.
-- :mod:`dgraph_tpu.analysis.kernel` — the **Pallas DMA-discipline
-  verifier**: static rules over the ``pallas_p2p`` transport kernel's
-  jaxpr (every ``dma_start`` paired with send+recv waits, nothing
-  outstanding at exit, wait-before-reuse on the double-buffer slots,
-  VMEM staging within the fused-mask budget, destination rows provably
-  ``[me*S, (me+1)*S)``).
 - :mod:`dgraph_tpu.analysis.spmd` — the **cross-rank SPMD divergence
   auditor** (ISSUE 13): every rank's train/eval/serve program lowered
   from that rank's plan-shard subset view under that rank's env, then

@@ -1,7 +1,7 @@
 """``python -m dgraph_tpu.analysis`` — static-analysis CLI: contract
-linter + trace auditor + lowered-artifact (StableHLO) auditor + Pallas
-DMA-discipline verifier + cross-rank SPMD divergence auditor + host-side
-concurrency & durability auditor.
+linter + trace auditor + lowered-artifact (StableHLO) auditor +
+cross-rank SPMD divergence auditor + host-side concurrency & durability
+auditor.
 
 The host tier (``analysis.host``, ISSUE 15) audits the *other* program —
 the jax-free concurrent control plane: per-class guarded-field/lock
@@ -14,8 +14,8 @@ registry, one pragma); the repo-level graphs land in the report's
 
 Default mode lints the whole ``dgraph_tpu`` tree and audits the canonical
 2-shard workload under every halo lowering at ALL verification tiers —
-the jaxpr-level trace audit, the post-lowering HLO audit, the
-``pallas_p2p`` kernel DMA verifier, and the cross-rank SPMD audit (every
+the jaxpr-level trace audit, the post-lowering HLO audit, and the
+cross-rank SPMD audit (every
 rank's program lowered from its own plan-shard-subset view and proven
 identical, in identical collective order) — printing one JSON line and
 exiting nonzero on any finding or drift; the pre-merge gate
@@ -25,13 +25,12 @@ exiting nonzero on any finding or drift; the pre-merge gate
 checks (every rule must fire on a violating snippet and stay quiet on a
 clean one), a clean-tree lint, the 2- AND 4-shard trace AND HLO audits
 across all four halo lowerings (op counts + operand bytes pinned against
-``obs.footprint`` at both tiers), the kernel audits, the cross-rank SPMD
+``obs.footprint`` at both tiers), the cross-rank SPMD
 audits (2- and 4-shard worlds plus both generations of a real
 ``train/shrink.py`` W -> W-1 transition), and vacuity guards proving each
 tier still FAILS on seeded drift: a wrong lowering, wrong bytes, a mixed
 program, a seeded extra all-gather, a dropped donation (declare- and
-shape-level), a dropped ``dma_wait`` (plus the other kernel-discipline
-mutants), a raw ``shard_map`` check kwarg, and the seeded SPMD
+shape-level), a raw ``shard_map`` check kwarg, and the seeded SPMD
 divergences (a rank-dependent branch dropping one ppermute round on rank
 1, a swapped two-collective order, a rank-divergent tuned record).  Zero
 XLA compiles: the jaxpr tier traces abstractly and the HLO/SPMD tiers
@@ -90,7 +89,6 @@ class Config:
     lint: bool = True
     audit: bool = True
     hlo: bool = True     # lowered-artifact (StableHLO) tier
-    kernel: bool = True  # pallas_p2p DMA-discipline tier
     spmd: bool = True    # cross-rank SPMD divergence tier
     host: bool = True    # host-side concurrency & durability tier
     root: str = ""  # lint root; "" = the repo containing this package
@@ -276,65 +274,18 @@ _RANK_ENV_BRANCH_BAD = (
 )
 
 
-# the pallas_p2p kernel module gets its own fixture pair per trace-
-# discipline rule: the one-sided transport is the newest place a config
-# read or span could sneak inside traced code, so the rules must
-# demonstrably fire (and stay quiet) on that path too
-_P2P_FIXTURES = {
-    "no-config-read-in-trace": {
-        "path": "dgraph_tpu/ops/pallas_p2p.py",
-        "bad": (
-            "from dgraph_tpu import config as _cfg\n"
-            "import jax\n"
-            "def p2p_transport(x):\n"
-            "    def body(y):\n"
-            "        return y if _cfg.use_pallas_p2p else -y\n"
-            "    return jax.jit(body)(x)\n"
-        ),
-        "good": (
-            "from dgraph_tpu import config as _cfg\n"
-            "import jax\n"
-            "def p2p_transport(x):\n"
-            "    interpret = _cfg.pallas_p2p_available()\n"
-            "    def body(y):\n"
-            "        return y if interpret else -y\n"
-            "    return jax.jit(body)(x)\n"
-        ),
-    },
-    "no-span-in-trace": {
-        "path": "dgraph_tpu/ops/pallas_p2p.py",
-        "bad": (
-            "import jax\n"
-            "from dgraph_tpu.obs import spans\n"
-            "def p2p_transport(x):\n"
-            "    def body(y):\n"
-            "        with spans.span('p2p.put', stage='exchange'):\n"
-            "            return y * 2\n"
-            "    return jax.jit(body)(x)\n"
-        ),
-        "good": (
-            "import jax\n"
-            "from dgraph_tpu.obs import spans\n"
-            "def p2p_transport(x):\n"
-            "    with spans.span('p2p.transport', stage='exchange'):\n"
-            "        return jax.jit(lambda y: y * 2)(x)\n"
-        ),
-    },
-}
-
-
 # pallas_call kernel bodies are traced code too — until ISSUE 12 they
 # were the trace-discipline rules' blind spot (kernels reach pallas_call
 # through a functools.partial alias, which the descent now sees through)
 _KERNEL_FIXTURES = {
     "no-config-read-in-trace": {
-        "path": "dgraph_tpu/ops/pallas_p2p.py",
+        "path": "dgraph_tpu/ops/pallas_segment.py",
         "bad": (
             "import functools\n"
             "from jax.experimental import pallas as pl\n"
             "from dgraph_tpu import config as _cfg\n"
             "def _kernel(x_ref, o_ref):\n"
-            "    o_ref[...] = x_ref[...] * (2 if _cfg.use_pallas_p2p else 1)\n"
+            "    o_ref[...] = x_ref[...] * (2 if _cfg.use_pallas_scatter else 1)\n"
             "def transport(x, shape):\n"
             "    kern = functools.partial(_kernel)\n"
             "    return pl.pallas_call(kern, out_shape=shape)(x)\n"
@@ -346,19 +297,19 @@ _KERNEL_FIXTURES = {
             "def _kernel(x_ref, o_ref, *, scale):\n"
             "    o_ref[...] = x_ref[...] * scale\n"
             "def transport(x, shape):\n"
-            "    scale = 2 if _cfg.use_pallas_p2p else 1\n"
+            "    scale = 2 if _cfg.use_pallas_scatter else 1\n"
             "    kern = functools.partial(_kernel, scale=scale)\n"
             "    return pl.pallas_call(kern, out_shape=shape)(x)\n"
         ),
     },
     "no-span-in-trace": {
-        "path": "dgraph_tpu/ops/pallas_p2p.py",
+        "path": "dgraph_tpu/ops/pallas_segment.py",
         "bad": (
             "import functools\n"
             "from jax.experimental import pallas as pl\n"
             "from dgraph_tpu.obs import spans\n"
             "def _kernel(x_ref, o_ref):\n"
-            "    with spans.span('p2p.tile', stage='exchange'):\n"
+            "    with spans.span('segsum.tile', stage='scatter'):\n"
             "        o_ref[...] = x_ref[...]\n"
             "def transport(x, shape):\n"
             "    kern = functools.partial(_kernel)\n"
@@ -371,7 +322,7 @@ _KERNEL_FIXTURES = {
             "def _kernel(x_ref, o_ref):\n"
             "    o_ref[...] = x_ref[...]\n"
             "def transport(x, shape):\n"
-            "    with spans.span('p2p.transport', stage='exchange'):\n"
+            "    with spans.span('segsum.call', stage='scatter'):\n"
             "        kern = functools.partial(_kernel)\n"
             "        return pl.pallas_call(kern, out_shape=shape)(x)\n"
         ),
@@ -420,7 +371,6 @@ def _lint_fixture_checks(failures: list) -> None:
 
     fixture_sets = (
         list(_FIXTURES.items())
-        + list(_P2P_FIXTURES.items())
         + list(_KERNEL_FIXTURES.items())
         + list(_SHARD_MAP_FIXTURES.items())
     )
@@ -541,10 +491,9 @@ def _audit_vacuity_checks(failures: list, w2, w4) -> None:
     finally:
         _cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
 
-    # mixed pallas_p2p + ppermute legs in ONE program must stay RED in
-    # the one-family audit: the exchange lowered as one-sided puts but
-    # its reverse leg as ppermute rounds is exactly the PR 4 hazard in
-    # its newest costume
+    # mixed all_to_all + ppermute legs in ONE program must stay RED in
+    # the one-family audit: the exchange lowered one way and its reverse
+    # leg another is exactly the PR 4 hazard
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -556,7 +505,7 @@ def _audit_vacuity_checks(failures: list, w2, w4) -> None:
             p = squeeze_plan(plan_)
             buf = collectives.halo_exchange(
                 x[0], p.halo, GRAPH_AXIS, deltas=p.halo_deltas,
-                impl="pallas_p2p",
+                impl="all_to_all",
             )
             back = collectives.halo_scatter_sum(
                 buf, p.halo, p.n_src_pad, GRAPH_AXIS,
@@ -568,18 +517,18 @@ def _audit_vacuity_checks(failures: list, w2, w4) -> None:
             body, mesh=w2.mesh,
             in_specs=(plan_in_specs(w2.plan), P(GRAPH_AXIS)),
             out_specs=P(GRAPH_AXIS),
-            **collectives.shard_map_checks(impl="pallas_p2p"),
+            **collectives.shard_map_checks(impl="all_to_all"),
         )(plan, xs)
 
     mism = []
     T._audit_one_program(
-        "vacuity-mixed", "pallas_p2p", mixed,
+        "vacuity-mixed", "all_to_all", mixed,
         (w2.batch["x"], w2.plan), w2.plan_np, mism,
     )
     _check(
         failures,
         any("mixed halo lowerings" in m for m in mism),
-        "auditor accepted a program mixing pallas_p2p puts with a "
+        "auditor accepted a program mixing an all_to_all leg with a "
         "ppermute leg",
     )
 
@@ -614,14 +563,16 @@ def _hlo_vacuity_checks(failures: list, w2) -> None:
         _cfg.set_flags(halo_impl="all_to_all", tuned_halo_impl=None)
         fn, args = _train_program(w2)
 
-        # seeded extra all-gather: the accidental-collective class the
-        # relaxed rep checker can no longer catch must go RED at the
-        # artifact level
+        # seeded extra all-gather: the accidental-collective class must
+        # go RED at the artifact level
         def seeded(params, opt_state, batch, plan):
             out = fn(params, opt_state, batch, plan)
             extra = jax.shard_map(
-                lambda x: lax.all_gather(x[0], GRAPH_AXIS),
-                mesh=w2.mesh, in_specs=(P(GRAPH_AXIS),), out_specs=P(),
+                # each rank keeps its own copy of the gathered rows: the vma
+                # checker is on, and all_gather's output counts as varying
+                lambda x: lax.all_gather(x[0], GRAPH_AXIS)[None],
+                mesh=w2.mesh, in_specs=(P(GRAPH_AXIS),),
+                out_specs=P(GRAPH_AXIS),
                 **shard_map_checks(relax="seeded vacuity mutant"),
             )(batch["x"])
             return out, extra
@@ -687,9 +638,6 @@ def _hlo_vacuity_checks(failures: list, w2) -> None:
 
 def _selftest(cfg: Config, log) -> dict:
     from dgraph_tpu.analysis.hlo import audit_workload_hlo
-    from dgraph_tpu.analysis.kernel import (
-        audit_workload_kernels, kernel_selftest_failures,
-    )
     from dgraph_tpu.analysis.lint import run_lint
     from dgraph_tpu.analysis.trace import audit_workload, build_audit_workload
 
@@ -729,19 +677,9 @@ def _selftest(cfg: Config, log) -> dict:
             failures, hrep["ok"],
             f"{world}-shard HLO audit drifted: {hrep['failures']}",
         )
-        # the DMA-discipline tier over the real transports
-        krep = audit_workload_kernels(w)
-        log.write(krep)
-        _check(
-            failures, krep["ok"],
-            f"{world}-shard kernel audit failed: {krep['failures']}",
-        )
 
     _audit_vacuity_checks(failures, workloads[2], workloads[4])
     _hlo_vacuity_checks(failures, workloads[2])
-    # kernel-verifier vacuity: the seeded kernel mutations (dropped
-    # dma_wait among them) must each go RED
-    failures.extend(kernel_selftest_failures())
 
     # the cross-rank SPMD tier: 2- and 4-shard worlds, both generations
     # of a real W -> W-1 shrink, and the seeded-divergence mutants —
@@ -874,7 +812,7 @@ def main(cfg: Config) -> dict:
                     f"{f['rule']} {f['path']}:{f['line']}"
                     for f in lint_report["findings"]
                 )
-        if cfg.audit or cfg.hlo or cfg.kernel:
+        if cfg.audit or cfg.hlo:
             from dgraph_tpu.analysis.trace import build_audit_workload
 
             w = build_audit_workload(cfg.world, seed=cfg.seed)
@@ -890,12 +828,6 @@ def main(cfg: Config) -> dict:
             hlo_report = audit_workload_hlo(w)
             out["hlo_audit"] = hlo_report
             problems.extend(hlo_report["failures"])
-        if cfg.kernel:
-            from dgraph_tpu.analysis.kernel import audit_workload_kernels
-
-            kernel_report = audit_workload_kernels(w)
-            out["kernel_audit"] = kernel_report
-            problems.extend(kernel_report["failures"])
         if cfg.host:
             # host-side concurrency & durability tier: the per-FILE host
             # rules (lock discipline, durable writes, pointer-flip-last)

@@ -24,9 +24,7 @@ buffers (the rule ``tests/README.md`` documents) — and walks the module:
 
 - collective op kinds/counts match the planned schedule (``all_to_all``
   count == exchange legs; ``collective_permute`` count == legs *
-  num_halo_deltas; ``pallas_p2p``'s interpret-mode DMA discharge ==
-  exactly one tile-shaped ``all_gather`` plus two scalar index gathers
-  per remote put);
+  num_halo_deltas);
 - ``replica_groups`` / ``source_target_pairs`` are exactly the graph-axis
   groups / live-delta rings the plan schedules;
 - per-operand bytes equal ``obs.footprint``'s pricing at the LOWERED
@@ -41,10 +39,7 @@ buffers (the rule ``tests/README.md`` documents) — and walks the module:
   every donor argument's type is covered by an output type, so XLA can
   actually alias it).
 
-Everything here assumes the virtual-CPU backend the analysis CLI pins
-(``pallas_p2p`` kernels lower through the Pallas interpret-mode DMA
-discharge there); the per-put all_gather census is that discharge's
-artifact shape, pinned by the selftest's vacuity guards.
+Everything here assumes the virtual-CPU backend the analysis CLI pins.
 """
 
 from __future__ import annotations
@@ -89,13 +84,6 @@ _MLIR_DTYPES = {
     # element type itself can appear in surrounding compute
     "f8E4M3FN": ("float8_e4m3fn", 1),
 }
-
-# interpret-mode DMA discharge artifact shape: per remote put, the
-# interpreter's discharge rule all-gathers the tile payload once and two i32
-# scalars (the raveled device id and the landing-row index) — anything
-# gathered beyond this budget per put was NOT scheduled by the plan
-_DMA_ARTIFACT_INT_GATHERS_PER_PUT = 2
-_DMA_ARTIFACT_INT_GATHER_MAX_BYTES = 32
 
 
 def _elt_info(elt: str) -> tuple:
@@ -287,40 +275,13 @@ def _audit_one_lowering(
     def fail(msg):
         failures.append(f"[hlo:{label}/{impl}] {msg}")
 
-    # split the p2p interpret-mode DMA artifacts out of the all_gather
-    # census BY SHAPE (a [.., S, F]-shaped float payload per remote put,
-    # plus two tiny integer indices); byte pricing is checked separately
-    # below, so a tile whose bytes drifted is reported as a BYTE mismatch,
-    # not misdiagnosed as an unscheduled collective. Every other gather is
-    # unscheduled.
-    tile_gathers, int_gathers, rogue_gathers = [], [], []
+    # no XLA-materialized collective the plan didn't schedule: no
+    # lowering gathers, so every all_gather is one
     for rec in coll["all_gather"]:
-        if impl == "pallas_p2p":
-            if (
-                # uint8: the fp8 wire payload — shape (not dtype) is what
-                # identifies the [.., S, F_wire] send tile either way
-                rec["dtype"] in ("float32", "bfloat16", "float16", "uint8")
-                and len(rec["shape"]) >= 2
-                and rec["shape"][-2] == S
-            ):
-                tile_gathers.append(rec)
-                continue
-            if (
-                rec["dtype"].startswith(("int", "uint"))
-                and rec["bytes"] <= _DMA_ARTIFACT_INT_GATHER_MAX_BYTES
-            ):
-                int_gathers.append(rec)
-                continue
-        rogue_gathers.append(rec)
-
-    # no XLA-materialized collective the plan didn't schedule — the class
-    # the relaxed rep checker can no longer catch at trace level
-    for rec in rogue_gathers:
         fail(
             f"unscheduled all_gather of {rec['shape']} ({rec['dtype']}, "
             f"{rec['bytes']} B) in the lowered module — XLA materialized a "
-            f"collective the plan never scheduled (wrong out-spec under "
-            f"the relaxed replication checker?)"
+            f"collective the plan never scheduled (wrong out-spec?)"
         )
     for kind in ("reduce_scatter", "collective_broadcast"):
         for rec in coll[kind]:
@@ -332,29 +293,21 @@ def _audit_one_lowering(
     # exactly one transport family per lowered program
     n_a2a = len(coll["all_to_all"])
     n_cp = len(coll["collective_permute"])
-    n_tile = len(tile_gathers)
-    families = [
-        name for name, count in (
-            ("all_to_all", n_a2a), ("ppermute", n_cp), ("pallas_p2p", n_tile),
-        ) if count
-    ]
-    want_family = impl if impl in ("all_to_all", "pallas_p2p") else "ppermute"
+    counts = {"all_to_all": n_a2a, "ppermute": n_cp}
+    families = [name for name, count in counts.items() if count]
+    want_family = "all_to_all" if impl == "all_to_all" else "ppermute"
     if len(families) > 1:
         fail(
             "mixed transport families in ONE lowered program: "
             + " + ".join(families)
         )
-    for fam, count in (
-        ("all_to_all", n_a2a), ("ppermute", n_cp), ("pallas_p2p", n_tile),
-    ):
+    for fam, count in counts.items():
         if fam != want_family and count:
             fail(
                 f"pinned lowering {impl!r} but the module contains {count} "
                 f"{fam} op(s)"
             )
-    if not {
-        "all_to_all": n_a2a, "ppermute": n_cp, "pallas_p2p": n_tile,
-    }[want_family]:
+    if not counts[want_family]:
         fail(f"pinned lowering {impl!r} lowered no {want_family} ops at all")
 
     # per-operand bytes == obs.footprint's pricing at the LOWERED
@@ -418,31 +371,6 @@ def _audit_one_lowering(
                          f"(deltas={deltas}, W={W})"
                 )
             )
-    for rec in tile_gathers:
-        F = rec["shape"][-1] if rec["shape"] else 0
-        want = _expected_bytes(plan, rec["dtype"], F)["ppermute_round_bytes"]
-        operand_rows.append({**{k: rec[k] for k in ("op", "shape", "dtype", "bytes")},
-                             "footprint_bytes": want})
-        if rec["bytes"] != want:
-            fail(
-                f"p2p tile-payload gather {rec['shape']} ({rec['dtype']}) "
-                f"is {rec['bytes']} B lowered; footprint prices {want} B "
-                f"per put"
-            )
-        if rec["replica_groups"] is not None and rec["replica_groups"] != groups:
-            fail(
-                f"p2p DMA-artifact gather groups {rec['replica_groups']} != "
-                f"planned graph-axis groups {groups}"
-            )
-    if impl == "pallas_p2p" and n_tile:
-        want_ints = _DMA_ARTIFACT_INT_GATHERS_PER_PUT * n_tile
-        if len(int_gathers) != want_ints:
-            fail(
-                f"{len(int_gathers)} scalar index gathers for {n_tile} "
-                f"remote put(s); the interpret DMA discharge emits exactly "
-                f"{_DMA_ARTIFACT_INT_GATHERS_PER_PUT} per put"
-            )
-
     # fp32 accumulation at the artifact level: reductions never run
     # sub-32-bit (bf16 may ride the wire; all_reduce must not)
     narrow = [
@@ -460,8 +388,6 @@ def _audit_one_lowering(
         "impl": impl,
         "num_all_to_all": n_a2a,
         "num_collective_permute": n_cp,
-        "num_tile_gathers": n_tile,
-        "num_index_gathers": len(int_gathers),
         "num_all_reduce": len(coll["all_reduce"]),
         "collective_operands": operand_rows,
         "s_pad": int(S),
@@ -542,7 +468,7 @@ def audit_workload_hlo(
     program_records = []
     legs: dict = {}
     donation = None
-    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl, _cfg.use_pallas_p2p)
+    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl)
     audited_impls = [
         impl for impl in impls
         if impl != "sched"
@@ -551,9 +477,6 @@ def audit_workload_hlo(
     try:
         for impl in audited_impls:
             _cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
-            _cfg.set_flags(
-                use_pallas_p2p=True if impl == "pallas_p2p" else saved[2]
-            )
             for label, build in (programs or PROGRAMS).items():
                 fn, args = build(w)
                 lowered = lower_program(fn, args)
@@ -586,10 +509,7 @@ def audit_workload_hlo(
                             failures,
                         )
     finally:
-        _cfg.set_flags(
-            halo_impl=saved[0], tuned_halo_impl=saved[1],
-            use_pallas_p2p=saved[2],
-        )
+        _cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
 
     # cross-lowering count pins, mirrored from the trace tier but against
     # the LOWERED ops: legs measured from the all_to_all-pinned module
@@ -626,23 +546,13 @@ def audit_workload_hlo(
                         f"{sorted(lowered_b)[:8]} != footprint rounds "
                         f"{sorted(exp)[:8]} x {k} leg(s)"
                     )
-        elif rec["impl"] in ("ppermute", "overlap"):
-            if rec["num_collective_permute"] != want:
-                failures.append(
-                    f"[hlo:{rec['program']}/{rec['impl']}] "
-                    f"{rec['num_collective_permute']} collective_permutes "
-                    f"lowered; expected legs({legs[rec['program']]}) * "
-                    f"num_halo_deltas({n_deltas}) = {want}"
-                )
-        elif rec["impl"] == "pallas_p2p":
-            if rec["num_tile_gathers"] != want:
-                failures.append(
-                    f"[hlo:{rec['program']}/{rec['impl']}] "
-                    f"{rec['num_tile_gathers']} tile-payload DMA artifacts "
-                    f"lowered; expected one per remote put = "
-                    f"legs({legs[rec['program']]}) * num_halo_deltas"
-                    f"({n_deltas}) = {want}"
-                )
+        elif rec["num_collective_permute"] != want:
+            failures.append(
+                f"[hlo:{rec['program']}/{rec['impl']}] "
+                f"{rec['num_collective_permute']} collective_permutes "
+                f"lowered; expected legs({legs[rec['program']]}) * "
+                f"num_halo_deltas({n_deltas}) = {want}"
+            )
 
     return {
         "kind": "hlo_audit",

@@ -62,8 +62,7 @@ from dgraph_tpu.utils.env import RANK_ENV_VAR
 # functions whose function-valued arguments are traced by jax: a config
 # read inside one is a trace-time read (the PR 4 hazard class).
 # pallas_call is one of them — the kernel body is traced like any jit
-# body, and was this linter's blind spot until the pallas_p2p transport
-# made kernels a live place for config reads/spans to hide.
+# body, so a config read or span inside a kernel is a trace-time read too.
 TRACING_ENTRY_POINTS = frozenset({
     "jit", "shard_map", "custom_vjp", "custom_jvp", "grad", "value_and_grad",
     "vjp", "jvp", "linearize", "scan", "while_loop", "fori_loop", "cond",
@@ -851,9 +850,6 @@ def check_monolithic_plan_pickle(relpath: str, tree: ast.AST, lines: list):
 NARROW_DTYPES = frozenset({
     "bfloat16", "float16", "float8_e4m3fn", "float8_e5m2", "int8", "uint8",
 })
-# calls that put an operand on the wire: the lax collectives plus the
-# pallas p2p transport entry point
-WIRE_EXCHANGE_CALLS = COLLECTIVE_CALLS | frozenset({"p2p_transport"})
 
 
 def _narrow_dtype_literal(node) -> Optional[str]:
@@ -870,9 +866,9 @@ def _narrow_dtype_literal(node) -> Optional[str]:
 @rule(
     "no-unpriced-wire-cast",
     "no literal dtype-narrowing astype/convert_element_type in a function "
-    "that puts operands on the wire (issues a lax collective or the p2p "
-    "transport): an ad-hoc cast ships bytes the footprint model, trace/HLO "
-    "auditors and tuner never price — narrowing wire payloads is "
+    "that puts operands on the wire (issues a lax collective): an ad-hoc "
+    "cast ships bytes the footprint model, trace/HLO auditors and tuner "
+    "never price — narrowing wire payloads is "
     "dgraph_tpu.wire's job (encode/decode pairs, priced end to end)",
     path_matcher("dgraph_tpu/comm/", "dgraph_tpu/ops/"),
     scope="comm/, ops/",
@@ -885,7 +881,7 @@ def check_unpriced_wire_cast(relpath: str, tree: ast.AST, lines: list):
         issues = [
             sub.lineno for sub in ast.walk(fn)
             if isinstance(sub, ast.Call)
-            and _last_segment(sub.func) in WIRE_EXCHANGE_CALLS
+            and _last_segment(sub.func) in COLLECTIVE_CALLS
         ]
         if not issues:
             continue

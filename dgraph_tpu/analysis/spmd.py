@@ -541,7 +541,6 @@ def resolution_agreement(
                 impl, source = resolve_halo_impl(
                     world_size, tuple(halo_deltas),
                     overlap_available=overlap_available,
-                    p2p_available=True,
                     sched_available=sched_available,
                     pair_rows=pair_rows,
                 )
@@ -659,7 +658,7 @@ def audit_plan_dir_spmd(
     )
 
     program_records: list = []
-    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl, _cfg.use_pallas_p2p)
+    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl)
     schedule_ok = True
     audited_impls = [
         i for i in impls
@@ -668,9 +667,6 @@ def audit_plan_dir_spmd(
     try:
         for impl in audited_impls:
             _cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
-            _cfg.set_flags(
-                use_pallas_p2p=True if impl == "pallas_p2p" else saved[2]
-            )
             for plabel, build in (programs or PROGRAMS).items():
                 tag = f"{prefix}{plabel}/{impl}"
                 texts, seqs, lowereds, cache = {}, {}, {}, {}
@@ -732,10 +728,7 @@ def audit_plan_dir_spmd(
                     "jit_cache_entries": cache,
                 })
     finally:
-        _cfg.set_flags(
-            halo_impl=saved[0], tuned_halo_impl=saved[1],
-            use_pallas_p2p=saved[2],
-        )
+        _cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
 
     # (c) n_deltas symmetry: absent, or proven program-invariant by the
     # very identity the modules just demonstrated. In static-only mode

@@ -40,16 +40,12 @@ import dataclasses
 import math
 from typing import Any, Callable, Optional
 
-HALO_IMPLS = ("all_to_all", "ppermute", "overlap", "pallas_p2p", "sched")
+from dgraph_tpu.plan import HALO_IMPLS  # the one list of lowerings
 
 # psum family across jax versions: 'psum' (0.6+), 'psum2'/'pbroadcast'
 # (0.4.x shard_map rewrite); pmean lowers through psum
 PSUM_PRIMS = ("psum", "psum2", "psum_invariant", "pmean")
 HALO_PRIMS = ("all_to_all", "ppermute")
-# the pallas_p2p lowering's collective is a pallas_call whose kernel
-# issues remote DMAs: dma_start eqns carrying a LOGICAL device id
-# (ops.pallas_p2p). Plain in-kernel copies are dma_start without one.
-REMOTE_DMA_PRIM = "dma_start"
 CALLBACK_PRIMS = (
     "pure_callback", "io_callback", "debug_callback", "outside_call",
     "host_callback_call", "python_callback",
@@ -79,71 +75,14 @@ def aval_bytes(aval) -> int:
     return int(math.prod(shape)) * dtype_nbytes(aval.dtype)
 
 
-def _remote_put_count(call_jaxpr) -> int:
-    """Remote-DMA puts (dma_start with a LOGICAL device id) inside one
-    pallas_call's kernel jaxpr."""
-    count = 0
-
-    def visit(eqn):
-        nonlocal count
-        if eqn.primitive.name == REMOTE_DMA_PRIM:
-            did = eqn.params.get("device_id_type")
-            if did is not None and "logical" in str(did).lower():
-                count += 1
-
-    walk_eqns(call_jaxpr, visit)
-    return count
-
-
 def collect_collectives(jaxpr) -> dict:
     """One pass over a (closed) jaxpr: every halo collective / psum /
-    host-callback eqn with operand shapes, dtypes, and bytes.
-
-    ``pallas_p2p`` entries are pallas_calls whose kernel issues remote
-    puts; the recorded operand is the ``[n_deltas, S, F]`` send-tile
-    stack (the unique float rank-3 operand of the transport kernel) and
-    ``puts`` the number of remote DMAs inside — the auditable analogue
-    of one collective eqn's operand + round count."""
+    host-callback eqn with operand shapes, dtypes, and bytes."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
-    out = {
-        "all_to_all": [], "ppermute": [], "pallas_p2p": [], "psum": [],
-        "callbacks": [],
-    }
+    out = {"all_to_all": [], "ppermute": [], "psum": [], "callbacks": []}
 
     def visit(eqn):
         name = eqn.primitive.name
-        if name == "pallas_call":
-            inner = eqn.params.get("jaxpr")
-            if inner is None:
-                return
-            puts = _remote_put_count(getattr(inner, "jaxpr", inner))
-            if not puts:
-                return
-            rank3 = [
-                v.aval for v in eqn.invars
-                if hasattr(getattr(v, "aval", None), "shape")
-                and len(v.aval.shape) == 3
-            ]
-            blocks = [a for a in rank3 if "int" not in str(a.dtype)]
-            if not blocks:
-                # fp8 wire payloads ride as uint8: the encoded send-tile
-                # stack is still the transport's one rank-3 payload (the
-                # index operands the float filter exists to skip are i32)
-                blocks = [a for a in rank3 if str(a.dtype) == "uint8"]
-            for aval in blocks[:1]:
-                out["pallas_p2p"].append({
-                    "primitive": "pallas_p2p",
-                    "shape": tuple(int(s) for s in aval.shape),
-                    "dtype": str(aval.dtype),
-                    "bytes": aval_bytes(aval),
-                    "puts": puts,
-                })
-            if not blocks:
-                out["pallas_p2p"].append({
-                    "primitive": "pallas_p2p", "shape": (), "dtype": "?",
-                    "bytes": 0, "puts": puts,
-                })
-            return
         if name in HALO_PRIMS:
             key = name
         elif name in PSUM_PRIMS:
@@ -423,9 +362,6 @@ def _expected_bytes(plan, dtype: str, feat_dim: int) -> dict:
     return {
         "a2a_operand_bytes": ex["a2a_operand_bytes_per_shard"],
         "ppermute_round_bytes": per_round,
-        # the p2p transport's one [n_deltas, S, F] send-tile stack — the
-        # same boundary-only bytes the ppermute rounds move in total
-        "p2p_operand_bytes": fp["halo"]["wire_bytes_per_shard"]["pallas_p2p"],
         # the compiled schedule's per-round operand bytes (rounds differ
         # in height, so this is a LIST — the audit compares multisets)
         "sched_round_bytes": list(sched_fp.get("round_bytes_per_shard", [])),
@@ -443,18 +379,12 @@ def _audit_one_program(
     jaxpr = jax.make_jaxpr(fn)(*args)
     coll = collect_collectives(jaxpr)
     n_a2a, n_pp = len(coll["all_to_all"]), len(coll["ppermute"])
-    n_p2p = len(coll["pallas_p2p"])
 
     def fail(msg):
         failures.append(f"[{label}/{impl}] {msg}")
 
-    # exactly one halo-lowering family per traced program (PR 4 hazard) —
-    # the pallas_p2p puts are a third family the same rule covers
-    families_present = [
-        name for name, count in (
-            ("all_to_all", n_a2a), ("ppermute", n_pp), ("pallas_p2p", n_p2p),
-        ) if count
-    ]
+    # exactly one halo-lowering family per traced program (PR 4 hazard)
+    families_present = [f for f in HALO_PRIMS if coll[f]]
     if len(families_present) > 1:
         fail(
             f"mixed halo lowerings in ONE program: "
@@ -463,8 +393,8 @@ def _audit_one_program(
             )
             + " eqns (two legs of one op resolved differently)"
         )
-    want_family = impl if impl in ("all_to_all", "pallas_p2p") else "ppermute"
-    for other in ("all_to_all", "ppermute", "pallas_p2p"):
+    want_family = "all_to_all" if impl == "all_to_all" else "ppermute"
+    for other in HALO_PRIMS:
         if other != want_family and coll[other]:
             fail(
                 f"pinned lowering {impl!r} but the trace contains "
@@ -502,7 +432,6 @@ def _audit_one_program(
         want = {
             "all_to_all": exp["a2a_operand_bytes"],
             "ppermute": exp["ppermute_round_bytes"],
-            "pallas_p2p": exp["p2p_operand_bytes"],
         }[want_family]
         byte_rows.append({
             "primitive": rec["primitive"], "shape": rec["shape"],
@@ -514,14 +443,6 @@ def _audit_one_program(
                 f"{rec['primitive']} operand {rec['shape']} ({rec['dtype']})"
                 f" carries {rec['bytes']} B; footprint prices {want} B — "
                 f"the tuner is ranking a schedule the program does not emit"
-            )
-        if want_family == "pallas_p2p" and rec.get("puts") != exp[
-            "num_halo_deltas"
-        ]:
-            fail(
-                f"pallas_p2p transport issues {rec.get('puts')} remote "
-                f"put(s); the plan has {exp['num_halo_deltas']} live "
-                f"delta(s) — one put per live delta per leg"
             )
 
     # no host callbacks inside traced code
@@ -548,8 +469,6 @@ def _audit_one_program(
         "impl": impl,
         "num_all_to_all": n_a2a,
         "num_ppermute": n_pp,
-        "num_pallas_p2p": n_p2p,
-        "num_remote_puts": sum(r.get("puts", 0) for r in coll["pallas_p2p"]),
         "num_psum": len(coll["psum"]),
         "collective_operands": byte_rows,
     }
@@ -616,7 +535,7 @@ def audit_workload(
     failures: list = []
     program_records = []
     legs: dict = {}
-    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl, _cfg.use_pallas_p2p)
+    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl)
     audited_impls = [
         impl for impl in impls
         if impl != "sched"
@@ -625,12 +544,6 @@ def audit_workload(
     try:
         for impl in audited_impls:
             _cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
-            # pinning pallas_p2p on a chip-less backend needs the explicit
-            # availability opt-in (the kernels trace in interpret mode —
-            # still zero compiles under make_jaxpr)
-            _cfg.set_flags(
-                use_pallas_p2p=True if impl == "pallas_p2p" else saved[2]
-            )
             for label, build in (programs or PROGRAMS).items():
                 fn, args = build(w)
                 rec = _audit_one_program(
@@ -640,37 +553,15 @@ def audit_workload(
                 if impl == "all_to_all":
                     legs[label] = rec["num_all_to_all"]
     finally:
-        _cfg.set_flags(
-            halo_impl=saved[0], tuned_halo_impl=saved[1],
-            use_pallas_p2p=saved[2],
-        )
+        _cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
 
     # cross-lowering count pin: the round-based lowerings must run exactly
-    # legs * num_halo_deltas rounds (pallas_p2p: legs transports carrying
-    # legs * num_halo_deltas remote puts), where legs is measured from
-    # the all_to_all-pinned trace of the SAME program (model-agnostic:
-    # the exchange-leg count is a property of the program, not the
-    # lowering)
+    # legs * num_halo_deltas rounds, where legs is measured from the
+    # all_to_all-pinned trace of the SAME program (model-agnostic: the
+    # exchange-leg count is a property of the program, not the lowering)
     n_deltas = len(w.plan_np.halo_deltas)
     for rec in program_records:
         if rec["impl"] == "all_to_all" or rec["program"] not in legs:
-            continue
-        if rec["impl"] == "pallas_p2p":
-            want_t = legs[rec["program"]]
-            want_puts = want_t * n_deltas
-            if rec["num_pallas_p2p"] != want_t:
-                failures.append(
-                    f"[{rec['program']}/{rec['impl']}] "
-                    f"{rec['num_pallas_p2p']} p2p transports; expected one "
-                    f"per exchange leg = {want_t}"
-                )
-            if rec["num_remote_puts"] != want_puts:
-                failures.append(
-                    f"[{rec['program']}/{rec['impl']}] "
-                    f"{rec['num_remote_puts']} remote puts; expected "
-                    f"legs({want_t}) * num_halo_deltas({n_deltas}) = "
-                    f"{want_puts}"
-                )
             continue
         if rec["impl"] == "sched":
             # the compiled schedule replays num_rounds ppermutes per

@@ -120,40 +120,6 @@ def overlap_active(plan: EdgePlan, axis_name) -> bool:
     )
 
 
-SPLIT_IMPLS = ("overlap", "pallas_p2p")
-
-
-def split_active(plan: EdgePlan, axis_name) -> bool:
-    """True when this plan routes through the interior/boundary split —
-    either split lowering: the double-buffered ppermute rounds
-    (``overlap``) or the device-initiated one-sided puts (``pallas_p2p``).
-    Everything downstream of the exchange (interior/boundary takes and
-    owner-side scatter sums) is collective-free and shared by both, so
-    models branch on THIS predicate and let
-    :func:`halo_exchange_split` pick the transport."""
-    return (
-        axis_name is not None
-        and getattr(plan, "overlap", None) is not None
-        and resolve_plan_impl(plan, axis_name) in SPLIT_IMPLS
-    )
-
-
-def halo_exchange_split(x, plan: EdgePlan, axis_name) -> jax.Array:
-    """The split lowerings' exchange leg: one resolution, then either the
-    overlap ppermute rounds or the pallas_p2p one-sided puts — both
-    produce the same ``[W*S, F]`` halo buffer the boundary takes index
-    directly (and bit-identical values)."""
-    impl = resolve_plan_impl(plan, axis_name)
-    wf = resolve_plan_wire_format(plan, axis_name)
-    if impl == "pallas_p2p":
-        return halo_exchange_p2p(
-            x, plan.halo, axis_name, tuple(plan.halo_deltas), wf
-        )
-    return halo_exchange_overlap(
-        x, plan.halo, axis_name, tuple(plan.halo_deltas), wf
-    )
-
-
 def shard_map_checks(
     plan: Optional[EdgePlan] = None,
     axis_name=None,
@@ -294,159 +260,6 @@ def _make_overlap_pair(axis_name, deltas, W, S, n_pad, wire_format="fp32",
 
     unexchange.defvjp(un_fwd, un_bwd)
     return exchange, unexchange
-
-
-def _p2p_rounds_fwd(x, send_idx, send_mask, axis_name, deltas, W, S,
-                    wire_format="fp32"):
-    """One-sided put schedule: gather each live delta's send tile exactly
-    like the a2a path gathers its blocks, then hand the stack to the
-    Pallas transport — the masking multiply fuses into the kernel (exact
-    elementwise op, staged in VMEM, overlapped with the previous tile's
-    in-flight put) and every tile DMAs straight into the destination
-    shard's halo buffer. Result layout and values are bit-identical to
-    the padded all_to_all lowering. Non-fp32 wire formats apply the mask
-    BEFORE encoding (per-row fp8 scales depend only on the masked row, so
-    the wire bytes match the a2a operand exactly) and ship the encoded
-    tiles with ``mask=None`` — the kernel is dtype-generic and treats
-    pre-masked tiles as pure data movement."""
-    from dgraph_tpu.ops import pallas_p2p as _p2p
-
-    me = lax.axis_index(axis_name)
-    d = jnp.asarray(deltas, jnp.int32)
-    peer_rows = (me + d) % W
-    blocks = x[send_idx[peer_rows]]  # [n, S, F]
-    msk = send_mask[peer_rows]  # [n, S]
-    enc, dec = _wire_fns(wire_format, x.dtype)
-    if enc is None:
-        return _p2p.p2p_transport(blocks, axis_name, deltas, W, S, mask=msk)
-    wire = enc(blocks * msk[..., None].astype(x.dtype))
-    out = _p2p.p2p_transport(wire, axis_name, deltas, W, S)
-    return dec(out.reshape(W, S, -1)).reshape(W * S, -1)
-
-
-def _p2p_rounds_rev(h, send_idx, send_mask, n_pad, axis_name, deltas, W, S,
-                    wire_format="fp32"):
-    """Reverse of :func:`_p2p_rounds_fwd`: each delta's halo-slot block
-    flies back to its owner as a one-sided put (``sign=-1`` mirrors the
-    forward targets), lands in the same per-source-rank layout the
-    all_to_all reverse produces, and reduces with the SAME masked flat
-    segment-sum — bit-identical values, one-sided transport. Cotangent
-    blocks are encoded UNMASKED (mask applies after decode, exactly as
-    the other reverse lowerings order it) so the per-row wire bytes match
-    the a2a reverse operand."""
-    from dgraph_tpu.ops import pallas_p2p as _p2p
-
-    F = h.shape[-1]
-    me = lax.axis_index(axis_name)
-    d = jnp.asarray(deltas, jnp.int32)
-    src_rows = (me - d) % W
-    blocks = h.reshape(W, S, F)[src_rows]  # [n, S, F]
-    enc, dec = _wire_fns(wire_format, h.dtype)
-    if enc is None:
-        back = _p2p.p2p_transport(blocks, axis_name, deltas, W, S, sign=-1)
-        back = back.reshape(W, S, F)
-    else:
-        wire = _p2p.p2p_transport(enc(blocks), axis_name, deltas, W, S,
-                                  sign=-1)
-        back = dec(wire.reshape(W, S, -1))
-    back = back * send_mask[..., None].astype(h.dtype)
-    flat_idx = send_idx.reshape(-1)
-    return local_ops.segment_sum(back.reshape(W * S, -1), flat_idx, n_pad)
-
-
-@functools.lru_cache(maxsize=None)
-def _make_p2p_pair(axis_name, deltas, W, S, n_pad, wire_format="fp32",
-                   dtype_name="float32"):
-    """The pallas_p2p exchange/unexchange custom-VJP pair — the exact
-    mirror of :func:`_make_overlap_pair` with the ppermute rounds swapped
-    for the one-sided transport: the exchange's backward IS the reverse
-    puts (halo cotangents delivered back to their owners) and the
-    reverse's backward IS the forward puts. Pinned explicitly so AD never
-    differentiates through the pallas_call (the kernel is pure data
-    movement; its transpose is the mirrored transport). Cache key carries
-    the static wire format + activation dtype like the overlap pair."""
-
-    @jax.custom_vjp
-    def exchange(x, send_idx, send_mask):
-        return _p2p_rounds_fwd(x, send_idx, send_mask, axis_name, deltas,
-                               W, S, wire_format)
-
-    def ex_fwd(x, send_idx, send_mask):
-        return exchange(x, send_idx, send_mask), (send_idx, send_mask)
-
-    def ex_bwd(res, g):
-        send_idx, send_mask = res
-        dx = _p2p_rounds_rev(
-            g, send_idx, send_mask, n_pad, axis_name, deltas, W, S,
-            wire_format)
-        return dx, None, None
-
-    exchange.defvjp(ex_fwd, ex_bwd)
-
-    @jax.custom_vjp
-    def unexchange(h, send_idx, send_mask):
-        return _p2p_rounds_rev(
-            h, send_idx, send_mask, n_pad, axis_name, deltas, W, S,
-            wire_format)
-
-    def un_fwd(h, send_idx, send_mask):
-        return unexchange(h, send_idx, send_mask), (send_idx, send_mask)
-
-    def un_bwd(res, g):
-        send_idx, send_mask = res
-        dh = _p2p_rounds_fwd(g, send_idx, send_mask, axis_name, deltas,
-                             W, S, wire_format)
-        return dh, None, None
-
-    unexchange.defvjp(un_fwd, un_bwd)
-    return exchange, unexchange
-
-
-@_scoped("dgraph.halo_exchange_p2p")
-def halo_exchange_p2p(
-    x: jax.Array,
-    halo: HaloSpec,
-    axis_name: Optional[str],
-    deltas: tuple,
-    wire_format: str = "fp32",
-) -> jax.Array:
-    """:func:`halo_exchange` lowered as device-initiated one-sided puts
-    (``pltpu.make_async_remote_copy`` issued from inside the Pallas
-    kernel — the TPU analogue of DGraph's NVSHMEM backend, PAPER.md
-    L1/L2): per-tile DMAs with semaphores in scratch, the send-mask
-    multiply fused in-kernel and double-buffered against the in-flight
-    put, no exchange buffer staged through HBM. Values are bit-identical
-    to the all_to_all lowering; the custom VJP is the mirrored reverse
-    transport."""
-    W, S = halo.send_idx.shape[0], halo.s_pad
-    if axis_name is None or not deltas:
-        return halo_exchange(x, halo, axis_name, deltas=deltas, impl="none")
-    ex, _ = _make_p2p_pair(axis_name, tuple(deltas), W, S, x.shape[0],
-                           wire_format, str(jnp.dtype(x.dtype)))
-    return ex(x, halo.send_idx, halo.send_mask)
-
-
-@_scoped("dgraph.halo_scatter_sum_p2p")
-def halo_scatter_sum_p2p(
-    h: jax.Array,
-    halo: HaloSpec,
-    n_pad: int,
-    axis_name: Optional[str],
-    deltas: tuple,
-    wire_format: str = "fp32",
-) -> jax.Array:
-    """:func:`halo_scatter_sum` lowered as reverse one-sided puts (the
-    pallas_p2p pair's transpose): every halo-slot partial flies back to
-    its owner as a per-tile DMA, then reduces with the same masked flat
-    segment-sum the all_to_all reverse path runs — bit-identical
-    values."""
-    W, S = halo.send_idx.shape[0], halo.s_pad
-    if axis_name is None or not deltas:
-        return halo_scatter_sum(h, halo, n_pad, axis_name, deltas=deltas,
-                                impl="none")
-    _, unex = _make_p2p_pair(axis_name, tuple(deltas), W, S, n_pad,
-                             wire_format, str(jnp.dtype(h.dtype)))
-    return unex(h, halo.send_idx, halo.send_mask)
 
 
 @_scoped("dgraph.halo_exchange_overlap")
@@ -747,8 +560,6 @@ def halo_exchange(
         send = x[halo.send_idx] * halo.send_mask[..., None].astype(x.dtype)
         return send.reshape(-1, F)  # world size 1: mask is all-zero
     impl = _resolve_halo_arg(impl, deltas, W)
-    if impl == "pallas_p2p":
-        return halo_exchange_p2p(x, halo, axis_name, tuple(deltas), wf)
     if impl == "overlap":
         return halo_exchange_overlap(x, halo, axis_name, tuple(deltas), wf)
     if impl == "sched":
@@ -827,9 +638,6 @@ def halo_scatter_sum(
         return jnp.zeros((n_pad, F), h.dtype)
     if axis_name is not None:
         impl = _resolve_halo_arg(impl, deltas, W)
-        if impl == "pallas_p2p":
-            return halo_scatter_sum_p2p(h, halo, n_pad, axis_name,
-                                        tuple(deltas), wf)
         if impl == "overlap":
             return halo_scatter_sum_overlap(h, halo, n_pad, axis_name,
                                             tuple(deltas), wf)
@@ -1026,8 +834,6 @@ def scatter_sum(
     impl = resolve_plan_impl(plan, axis_name) if axis_name is not None else None
     if impl == "overlap":
         return _scatter_sum_overlap(edata, plan, side, axis_name)
-    if impl == "pallas_p2p":
-        return _scatter_sum_p2p(edata, plan, side, axis_name)
     W = plan.world_size
     n_full = n_pad + W * plan.halo.s_pad
     if plan.halo_sort_perm is not None:
@@ -1208,51 +1014,51 @@ def gather_scatter_overlap(
     return agg_int + boundary_scatter_sum(m_bnd, plan, owner)
 
 
-def _scatter_sum_split(edata, plan, side, axis_name, remote_fn):
-    """The ONE split halo-side scatter schedule both split lowerings
-    share (the PR 8 single-core discipline — two copies of this schedule
-    could silently desynchronize the lowerings' values): the boundary
-    subset is pre-reduced into halo slots and handed to ``remote_fn``'s
-    reverse transport FIRST; the interior subset (local-vertex targets)
-    aggregates while it flies; local and returned remote partials merge
-    last. ``remote_fn`` — :func:`halo_scatter_sum_overlap` (reverse
-    ppermute rounds) or :func:`halo_scatter_sum_p2p` (reverse one-sided
-    puts) — is the ONLY difference between the lowerings, mirroring how
-    :func:`halo_exchange_split` dispatches the exchange leg. The VJP
-    composes the building blocks' pinned transposes — takes transpose to
-    segment-sums and the reverse transport to the forward transport —
-    mirroring the gather/scatter adjoint pair. ``edata`` must already be
-    edge-masked (the public :func:`scatter_sum` wrapper does this)."""
+@_scoped("dgraph.scatter_sum_overlap")
+def _scatter_sum_overlap(
+    edata: jax.Array, plan: EdgePlan, side: str, axis_name: Optional[str]
+) -> jax.Array:
+    """Halo-side :func:`scatter_sum` under the overlap schedule: the
+    boundary subset is pre-reduced into halo slots and handed to the
+    reverse ppermute rounds (:func:`halo_scatter_sum_overlap`) FIRST; the
+    interior subset (local-vertex targets) aggregates while they fly;
+    local and returned remote partials merge last. The VJP composes the
+    building blocks' pinned transposes — takes transpose to segment-sums
+    and the reverse rounds to the forward rounds — mirroring the
+    gather/scatter adjoint pair. ``edata`` must already be edge-masked
+    (the public :func:`scatter_sum` wrapper does this).
+
+    Values against the serial path: bit-identical at W = 2; the same
+    terms, regrouped, beyond. Each piece taken alone (the interior sum,
+    the slot partials, the reverse rounds' masked flat segment-sum) is
+    bit-identical to its serial twin; what differs is the last merge. A
+    vertex's sum is its local partial plus one returned partial per
+    delta, and once the op is compiled XLA folds that ``+`` into the
+    segment-sum's accumulation differently for the two programs, so the
+    partial sums over deltas are added in another order than the serial
+    path's slots. With one delta there is one order; with more, float32
+    results differ by rounding (≤ 1e-6 on sums of O(1) terms; bounded by
+    ``2 u (n-1) Σ|terms|``, which ``tests/test_overlap.py`` holds it to).
+    The gather, its gradient and this op's gradient only move rows or
+    run the same reduction, and stay bit-identical at every W."""
     ov = _overlap_spec(plan)
     n_pad = _side_npad(plan, side)
     W, S = plan.world_size, plan.halo.s_pad
-    # boundary leg first: rows -> slot partials -> reverse transport
+    # boundary leg first: rows -> slot partials -> reverse rounds
     bnd_rows = local_ops.take_rows(edata, ov.bnd_epos)
     slot_sums = local_ops.segment_sum(
         bnd_rows, ov.side("boundary", side), W * S, indices_are_sorted=False
     )
-    remote = remote_fn(
+    remote = halo_scatter_sum_overlap(
         slot_sums, plan.halo, n_pad, axis_name, tuple(plan.halo_deltas),
         resolve_plan_wire_format(plan, axis_name),
     )
-    # interior leg while the transport is in flight
+    # interior leg while the rounds are in flight
     int_rows = local_ops.take_rows(edata, ov.int_epos)
     interior = local_ops.segment_sum(
         int_rows, ov.side("interior", side), n_pad, indices_are_sorted=False
     )
     return interior + remote
-
-
-@_scoped("dgraph.scatter_sum_overlap")
-def _scatter_sum_overlap(
-    edata: jax.Array, plan: EdgePlan, side: str, axis_name: Optional[str]
-) -> jax.Array:
-    """Halo-side :func:`scatter_sum` under the overlap schedule — the
-    shared split schedule (:func:`_scatter_sum_split`) with the reverse
-    ppermute rounds as the remote leg."""
-    return _scatter_sum_split(
-        edata, plan, side, axis_name, halo_scatter_sum_overlap
-    )
 
 
 def scatter_sum_overlap(
@@ -1268,21 +1074,6 @@ def scatter_sum_overlap(
             "(or interior/boundary_scatter_sum for split streams)"
         )
     return _scatter_sum_overlap(edata, plan, side, axis_name)
-
-
-@_scoped("dgraph.scatter_sum_p2p")
-def _scatter_sum_p2p(
-    edata: jax.Array, plan: EdgePlan, side: str, axis_name: Optional[str]
-) -> jax.Array:
-    """Halo-side :func:`scatter_sum` under the pallas_p2p lowering — the
-    shared split schedule (:func:`_scatter_sum_split`) with reverse
-    one-sided puts as the remote leg: the per-delta slot-partial tiles
-    DMA back to their owners while the interior subset aggregates.
-    Reduction operands and order are identical to the serial path, so
-    values stay bit-identical."""
-    return _scatter_sum_split(
-        edata, plan, side, axis_name, halo_scatter_sum_p2p
-    )
 
 
 @_scoped("dgraph.scatter_bias_relu_overlap")

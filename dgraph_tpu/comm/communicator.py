@@ -87,20 +87,6 @@ class _BaseComm:
         interior/boundary overlap schedule (models' routing predicate)."""
         return collectives.overlap_active(plan, self.graph_axis)
 
-    def split_active(self, plan: EdgePlan) -> bool:
-        """True when this plan routes through the interior/boundary split
-        under EITHER split lowering — 'overlap' (ppermute rounds) or
-        'pallas_p2p' (device-initiated one-sided puts). The models'
-        routing predicate; :meth:`halo_exchange_split` picks the
-        transport."""
-        return collectives.split_active(plan, self.graph_axis)
-
-    def halo_exchange_split(self, x, plan: EdgePlan):
-        """The split lowerings' exchange: overlap ppermute rounds or
-        pallas_p2p one-sided puts (one resolution decides), producing the
-        [W*S, F] buffer the boundary takes index directly."""
-        return collectives.halo_exchange_split(x, plan, self.graph_axis)
-
     def interior_take(self, x, plan: EdgePlan, side: str = "src"):
         """Interior-subset per-edge rows from the local table (no
         dependence on the in-flight exchange)."""

@@ -73,28 +73,6 @@ def pallas_fused_bwd_enabled() -> bool:
         return use_pallas_fused_bwd
     return True
 
-# The device-initiated one-sided halo transport (halo_impl="pallas_p2p":
-# pltpu.make_async_remote_copy puts issued from inside the Pallas kernel,
-# ops.pallas_p2p). Tri-state like the scatter kernels: None = auto (the
-# lowering is AVAILABLE on a TPU backend — actual adoption still requires
-# an env pin or tuned record; resolve_halo_impl never heuristically picks
-# an un-A/B'd kernel), True forces availability on ANY backend (off-TPU
-# the kernels run in Pallas interpret mode — how the tier-1 parity pins
-# run without a chip), False vetoes it everywhere.
-use_pallas_p2p: bool | None = _env_flag("DGRAPH_TPU_PALLAS_P2P", None)
-
-
-def pallas_p2p_available() -> bool:
-    """Can halo_impl='pallas_p2p' lower on this backend? (One of the two
-    gates resolve_halo_impl applies; the other is the plan carrying the
-    interior/boundary split.)"""
-    if use_pallas_p2p is not None:
-        return use_pallas_p2p
-    import jax
-
-    return jax.default_backend() == "tpu"
-
-
 # Mosaic flash-attention kernel for the Ulysses full-sequence per-head
 # attention (parallel/sequence.py). Tri-state like the scatter kernels:
 # None = auto (ON on TPU when shapes qualify), env DGRAPH_TPU_FLASH_ATTN
@@ -145,15 +123,13 @@ gather_col_block: int = int(os.environ.get("DGRAPH_TPU_GATHER_COL_BLOCK", "128")
 # Halo exchange lowering: 'auto' (ppermute neighbor rounds when the plan's
 # active peer-delta set is sparse, else one padded all_to_all; 'overlap'
 # — interior/boundary split with the boundary rounds hidden behind
-# interior aggregation — whenever the plan carries its OverlapSpec),
-# 'all_to_all', 'ppermute', 'overlap', 'pallas_p2p' (device-initiated
-# one-sided puts fused into the Pallas kernel; needs the overlap split
-# AND pallas_p2p_available()), or 'sched' (a compiled multi-round
-# schedule — dgraph_tpu.sched — replayed as data; needs the plan's
-# attached halo_schedule). Resolution precedence lives in
-# plan.resolve_halo_impl: this env pin > the adopted tuning record
-# (tuned_halo_impl below) > the cost-model heuristic (which never picks
-# pallas_p2p or sched on its own).
+# interior aggregation — whenever the plan carries its OverlapSpec), or
+# one of plan.HALO_IMPLS: 'all_to_all', 'ppermute', 'overlap', or 'sched'
+# (a compiled multi-round schedule — dgraph_tpu.sched — replayed as data;
+# needs the plan's attached halo_schedule). Any other value is refused.
+# Resolution precedence lives in plan.resolve_halo_impl: this env pin >
+# the adopted tuning record (tuned_halo_impl below) > the cost-model
+# heuristic (which never picks sched on its own).
 halo_impl: str = os.environ.get("DGRAPH_TPU_HALO_IMPL", "auto")
 
 # Edge-axis chunk count for the overlap lowering's interior aggregation

@@ -84,15 +84,14 @@ class GraphConvLayer(nn.Module):
         def over_chunks(fn):
             return map_feature_chunks(fn, D)
 
-        # Split routing (plans carrying an interior/boundary split whose
-        # resolved halo lowering is 'overlap' or 'pallas_p2p'): issue the
-        # boundary exchange FIRST — double-buffered ppermute rounds or
-        # device-initiated one-sided puts, halo_exchange_split decides —
+        # Overlap routing (plans carrying an interior/boundary split whose
+        # resolved halo lowering is 'overlap'): issue the boundary exchange
+        # FIRST as double-buffered ppermute rounds,
         # aggregate interior edges from the local tables while it flies,
         # merge the landed boundary contributions last. Same math — relu
         # is per-edge and the aggregation sums over a partitioned edge
         # set — with the collective hidden behind the interior work.
-        use_overlap = self.comm.split_active(plan)
+        use_overlap = self.comm.overlap_active(plan)
 
         if (
             self.activation is nn.relu
@@ -105,7 +104,7 @@ class GraphConvLayer(nn.Module):
             h_bias = h_d if owner == "dst" else h_s
             h_stream = h_s if owner == "dst" else h_d
             if use_overlap:
-                halo_buf = self.comm.halo_exchange_split(h_stream, plan)
+                halo_buf = self.comm.halo_exchange_overlap(h_stream, plan)
                 return over_chunks(
                     lambda sl: self.comm.scatter_bias_relu_overlap(
                         h_stream[:, sl], halo_buf[:, sl], h_bias[:, sl],
@@ -126,7 +125,7 @@ class GraphConvLayer(nn.Module):
                 owner = self.aggregate_to
                 h_halo = h_s if plan.halo_side == "src" else h_d
                 h_own = h_d if plan.halo_side == "src" else h_s
-                halo_buf = self.comm.halo_exchange_split(h_halo, plan)
+                halo_buf = self.comm.halo_exchange_overlap(h_halo, plan)
                 from dgraph_tpu.comm.collectives import overlap_edge_weight
 
                 w_int, w_bnd = overlap_edge_weight(edge_weight, plan)

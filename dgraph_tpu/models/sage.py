@@ -35,14 +35,12 @@ class SAGEConv(nn.Module):
         # would run every [e_pad, F] take/scatter at double width (the
         # dtype-discipline rule — see tests/test_dtype_discipline.py)
         xa = x.astype(dt) if dt is not None else x
-        if plan.halo_side != "dst" and self.comm.split_active(plan):
-            # split route (overlap rounds or pallas_p2p one-sided puts;
-            # halo_exchange_split decides): the boundary exchange goes out
-            # first; the interior
+        if plan.halo_side != "dst" and self.comm.overlap_active(plan):
+            # overlap route: the boundary rounds go out first; the interior
             # neighbor sum (reading only the local table) runs while they
             # fly; boundary contributions merge once landed. One exchange
             # per layer, chunk-local work exactly as below.
-            halo_buf = self.comm.halo_exchange_split(xa, plan)
+            halo_buf = self.comm.halo_exchange_overlap(xa, plan)
             agg = map_feature_chunks(
                 lambda sl: self.comm.gather_scatter_overlap(
                     xa[:, sl], halo_buf[:, sl], plan

@@ -55,7 +55,8 @@ import os
 import re
 from typing import Optional
 
-DEFAULT_IMPLS = ("all_to_all", "overlap", "pallas_p2p")
+from dgraph_tpu.plan import HALO_IMPLS as DEFAULT_IMPLS
+
 SCHEMA_VERSION = 1
 
 
@@ -207,8 +208,6 @@ def _train_scan(w, *, with_optimizer: bool, elide_exchange: bool = False):
     grad_fn = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(), batch_specs, plan_specs), out_specs=(P(), P()),
-        # pallas_p2p programs relax the 0.4.x rep checker (pallas_call
-        # has no replication rule there); every other lowering keeps it
         **shard_map_checks(plan, _GA),
     )
 
@@ -274,11 +273,12 @@ def _exchange_scan(w, impl: str, num_layers: int = 2):
         h = x[0]
         for _ in range(num_layers):
             buf = collectives.halo_exchange(
-                h, p.halo, GRAPH_AXIS, deltas=p.halo_deltas, impl=impl
+                h, p.halo, GRAPH_AXIS, deltas=p.halo_deltas, impl=impl,
+                schedule=p.halo_schedule,
             )
             back = collectives.halo_scatter_sum(
                 buf, p.halo, p.n_src_pad, GRAPH_AXIS,
-                deltas=p.halo_deltas, impl=impl,
+                deltas=p.halo_deltas, impl=impl, schedule=p.halo_schedule,
             )
             h = h + back * 1e-6
         return h[None]
@@ -361,7 +361,7 @@ def scan_delta_attribution(
                 return ms
         return float("nan")
 
-    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl, _cfg.use_pallas_p2p)
+    saved = (_cfg.halo_impl, _cfg.tuned_halo_impl)
     by_impl = {}
     try:
         # interior-only (exchange elided) is lowering-independent: one
@@ -373,12 +373,6 @@ def scan_delta_attribution(
 
         for impl in impls:
             _cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
-            # pinning pallas_p2p on the (wedged-round) CPU backend needs
-            # the explicit availability opt-in: the kernels execute in
-            # Pallas interpret mode, timed like any other lowering
-            _cfg.set_flags(
-                use_pallas_p2p=True if impl == "pallas_p2p" else saved[2]
-            )
             run, state = _train_scan(w, with_optimizer=True)
             t_full = time_one(run, state)
             run, state = _train_scan(w, with_optimizer=False)
@@ -413,10 +407,7 @@ def scan_delta_attribution(
                 "exposed_exchange_ms": _num(exposed),
             }
     finally:
-        _cfg.set_flags(
-            halo_impl=saved[0], tuned_halo_impl=saved[1],
-            use_pallas_p2p=saved[2],
-        )
+        _cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
 
     rec = {
         "kind": "cpu_scan_delta",
@@ -457,7 +448,7 @@ class Config:
     num_classes: int = 4
     n_long: int = 6
     reps: int = 1
-    impls: str = "all_to_all,overlap,pallas_p2p"
+    impls: str = ",".join(DEFAULT_IMPLS)
     seed: int = 0
     log_path: str = "logs/attribution.jsonl"
     indent: int = 0

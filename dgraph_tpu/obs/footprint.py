@@ -142,12 +142,9 @@ def plan_footprint(
     pp_operand = n_deltas * S * wire_row_bytes  # one [S, F_wire] per delta
     # the overlap lowering sends the same boundary-only round payloads as
     # ppermute — its win is SCHEDULING (exposed time), not wire bytes.
-    # pallas_p2p moves the same boundary-only tiles as one-sided puts:
-    # its win is the transport (device-initiated per-tile DMA, one kernel
-    # launch, no exchange buffer staged through HBM), not wire bytes.
     wire_per_shard = {
         "all_to_all": a2a_ici, "ppermute": pp_operand, "overlap": pp_operand,
-        "pallas_p2p": pp_operand, "sched": sched_wire,
+        "sched": sched_wire,
     }
     chosen_wire = wire_per_shard.get(impl, 0)
     real_bytes = real_rows * wire_row_bytes
@@ -158,14 +155,8 @@ def plan_footprint(
     # only; 'none' never gathers a send buffer at all).
     sent_blocks = {
         "all_to_all": W, "ppermute": n_deltas, "overlap": n_deltas,
-        "pallas_p2p": n_deltas, "sched": len(sched_rows),
+        "sched": len(sched_rows),
     }.get(impl, 0)
-    # pallas_p2p is billed the same (2*sent + W) streams as the rounds it
-    # replaces: only the FORWARD leg's in-VMEM mask fusion can skip the
-    # masked block's HBM round trip, and only when the stack fits the
-    # VMEM budget — the reverse leg always pre-stages its tiles — so the
-    # conservative figure is what the headline (and the tuner) must use;
-    # the fused-forward saving is reported separately below.
     hbm_per_shard = (2 * sent_blocks + W) * S * row_bytes
 
     def _roofline(ici_bytes: float, hbm_bytes: float) -> dict:
@@ -179,8 +170,7 @@ def plan_footprint(
 
     operand_by_impl = {
         "all_to_all": a2a_operand, "ppermute": pp_operand,
-        "overlap": pp_operand, "pallas_p2p": pp_operand,
-        "sched": sched_wire,
+        "overlap": pp_operand, "sched": sched_wire,
     }
     exchange = {
         "impl": impl,
@@ -229,32 +219,6 @@ def plan_footprint(
             "exposed_us": round(exposed, 3),
             "serial_us": round(serial, 3),
             "hidden_us": round(serial - exposed, 3),
-        }
-        # pallas_p2p per-tile schedule (ISSUE 11 pricing model): each live
-        # delta is one device-initiated put whose DMA overlaps the next
-        # tile's stage+mask (and, at the model layer, the interior-edge
-        # aggregation the split routing runs while the puts fly), so the
-        # exposed cost per tile is max(tile DMA, its interior compute
-        # share) — boundary-only operand bytes, one kernel launch total.
-        tile_stage_us = (
-            S * row_bytes / (hbm_gbps * 1e3) if hbm_gbps else 0.0
-        )
-        p2p_exposed = n_deltas * max(round_comm_us, per_round_int)
-        exchange["pallas_p2p"] = {
-            "tiles": n_deltas,
-            "tile_bytes": S * wire_row_bytes,
-            "tile_dma_us": round(round_comm_us, 3),
-            "tile_stage_us": round(tile_stage_us, 3),
-            "interior_tile_us": round(per_round_int, 3),
-            "exposed_us": round(p2p_exposed, 3),
-            "serial_us": round(serial, 3),
-            "hidden_us": round(serial - p2p_exposed, 3),
-            # FORWARD-leg-only figure, valid when the send stack fits the
-            # kernel's VMEM staging budget (the mask multiply then rides
-            # VMEM and the masked block never round-trips HBM); the
-            # reverse leg always pays the full (2*n + W) streams, so the
-            # headline hbm_bytes_per_shard above stays conservative
-            "fwd_fused_hbm_bytes_per_shard": (n_deltas + W) * S * row_bytes,
         }
     if sched_rows:
         # compiled-schedule pricing: each round k ships a [C_k, F] operand

@@ -1,7 +1,6 @@
 from dgraph_tpu.ops import local
 
-# dgraph_tpu.ops.pallas_segment and dgraph_tpu.ops.pallas_p2p are
-# imported lazily by their dispatch points (ops.local, comm.collectives)
-# so importing the package never pays the Pallas import on paths that
-# don't run kernels.
+# dgraph_tpu.ops.pallas_segment is imported lazily by its dispatch point
+# (ops.local) so importing the package never pays the Pallas import on
+# paths that don't run kernels.
 __all__ = ["local"]

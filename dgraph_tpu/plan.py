@@ -641,16 +641,21 @@ def plan_wire_format(world_size: int, halo_deltas: tuple) -> str:
     return name
 
 
+# Every lowering of the halo exchange, named ONCE. The resolver's legal set,
+# the audit tiers' columns, the tuner's candidates and the record validator
+# all import or derive from this tuple; ``'none'`` (a plan with no
+# cross-rank traffic) is a verdict of the resolver, not a lowering.
+HALO_IMPLS = ("all_to_all", "ppermute", "overlap", "sched")
+
+
 def resolve_halo_impl(
     world_size: int, halo_deltas: tuple, *, overlap_available: bool = False,
-    p2p_available: "bool | None" = None, sched_available: bool = False,
-    pair_rows: tuple = (),
+    sched_available: bool = False, pair_rows: tuple = (),
 ) -> tuple[str, str]:
     """The halo lowering the run will actually execute, plus who decided.
 
-    Returns ``(impl, source)`` with impl one of ``'none'``,
-    ``'all_to_all'``, ``'ppermute'``, ``'overlap'``, ``'pallas_p2p'``,
-    ``'sched'`` and source one of:
+    Returns ``(impl, source)`` with impl ``'none'`` or one of
+    :data:`HALO_IMPLS`, and source one of:
 
     - ``'env'``       — ``DGRAPH_TPU_HALO_IMPL`` (or ``config.set_flags``)
       pins the lowering; the operator's word is final.
@@ -668,27 +673,16 @@ def resolve_halo_impl(
     pin (env or record) on a plan WITHOUT the split cannot lower — that
     tier is skipped (logged once per process) and the NEXT tier decides
     (an env-pin miss still honors an adopted record, then the heuristic),
-    never a silent wrong answer.
-
-    ``'pallas_p2p'`` (device-initiated one-sided puts,
-    :mod:`dgraph_tpu.ops.pallas_p2p`) is gated TWICE: the plan must carry
-    the overlap split (its model routing rides the interior/boundary
-    streams) and the backend must be able to lower the kernels
-    (``config.pallas_p2p_available()``: a TPU backend, or the explicit
-    ``DGRAPH_TPU_PALLAS_P2P=1`` opt-in that runs them in Pallas interpret
-    mode). A pin that misses either gate degrades with a one-time warning
-    exactly like an overlap pin without the split. ``p2p_available``
-    overrides the config/backend probe (the probe imports jax, so it is
-    only consulted when a pallas_p2p pin or record is actually present).
-    The heuristic tier never picks ``pallas_p2p`` on its own — an
-    un-A/B'd kernel engages only through an explicit pin or a persisted
-    tuning record (the ``use_pallas_gather`` precedent).
+    never a silent wrong answer. A pin that names no lowering at all
+    (anything outside :data:`HALO_IMPLS` and ``'auto'``) raises
+    ``ValueError``: a typo like ``alltoall`` silently training on the
+    heuristic's choice would misattribute every measurement.
 
     ``'sched'`` (the compiled multi-round schedule,
     :mod:`dgraph_tpu.sched`, replayed by ``comm.collectives``'s round
-    executor) follows the same discipline: it is legal only when the
-    plan actually carries a compiled schedule (``sched_available``,
-    i.e. ``plan.halo_schedule is not None``) — a pin or record naming it
+    executor) is likewise legal only when the plan actually carries a
+    compiled schedule (``sched_available``, i.e.
+    ``plan.halo_schedule is not None``) — a pin or record naming it
     on a schedule-less plan degrades with a one-time warning to the next
     tier — and the heuristic tier never picks it on its own: a compiled
     schedule engages only through an explicit pin or a persisted tuning
@@ -708,30 +702,25 @@ def resolve_halo_impl(
     if not halo_deltas:
         return "none", "plan"
 
-    def _p2p_ok() -> bool:
-        if not overlap_available:
-            return False
-        if p2p_available is not None:
-            return p2p_available
-        return _cfg.pallas_p2p_available()
-
-    legal = ("all_to_all", "ppermute") + (
-        ("overlap",) if overlap_available else ()
-    ) + (("sched",) if sched_available else ())
-    for impl, source in (
-        (_cfg.halo_impl, "env"),
-        (_cfg.tuned_halo_impl, "record"),
+    needs = {"overlap": overlap_available, "sched": sched_available}
+    legal = tuple(k for k in HALO_IMPLS if needs.get(k, True))
+    for impl, source, flag, unset in (
+        (_cfg.halo_impl, "env", "DGRAPH_TPU_HALO_IMPL", "auto"),
+        (_cfg.tuned_halo_impl, "record", "config.tuned_halo_impl", None),
     ):
+        if impl == unset:
+            continue
+        if impl not in HALO_IMPLS:
+            raise ValueError(
+                f"{flag}={impl!r} names no halo lowering; expected "
+                f"{unset!r} or one of {HALO_IMPLS}"
+            )
         if impl in legal:
             return impl, source
         if impl == "overlap":  # pinned but the plan carries no split
             _warn_overlap_unavailable(source)
         if impl == "sched":  # pinned but the plan carries no schedule
             _warn_sched_unavailable(source)
-        if impl == "pallas_p2p":
-            if _p2p_ok():
-                return impl, source
-            _warn_p2p_unavailable(source, overlap_available)
     if overlap_available:
         return "overlap", "heuristic"
     return pick_halo_impl(world_size, halo_deltas, pair_rows), "heuristic"
@@ -740,17 +729,14 @@ def resolve_halo_impl(
 def resolve_overlap_intent() -> bool:
     """Whether a plan built RIGHT NOW with ``overlap=None`` (auto) would
     attach the interior/boundary split: the env pin or the adopted tuning
-    record asks for the overlap lowering — or for ``pallas_p2p``, which
-    rides the same split (its model routing aggregates interior edges
-    while the one-sided puts are in flight). The ONE copy of this rule —
+    record asks for the overlap lowering. The ONE copy of this rule —
     ``build_edge_plan``'s auto default and the plan cache's fingerprint
     (``train.checkpoint.cached_edge_plan``) both resolve through here, so
     what gets built and what the cache key claims was built can never
     diverge."""
     from dgraph_tpu import config as _cfg
 
-    intents = (_cfg.halo_impl, _cfg.tuned_halo_impl)
-    return "overlap" in intents or "pallas_p2p" in intents
+    return "overlap" in (_cfg.halo_impl, _cfg.tuned_halo_impl)
 
 
 _overlap_warned: set = set()
@@ -778,31 +764,6 @@ def _warn_sched_unavailable(source: str) -> None:
             "the schedule compiler or has no cross-rank traffic); the next "
             "resolution tier decides the lowering instead", source,
         )
-
-
-_p2p_warned: set = set()
-
-
-def _warn_p2p_unavailable(source: str, overlap_available: bool) -> None:
-    key = (source, overlap_available)
-    if key in _p2p_warned:
-        return
-    _p2p_warned.add(key)
-    if not overlap_available:
-        why = (
-            "the plan carries no interior/boundary split (built without "
-            "overlap=True)"
-        )
-    else:
-        why = (
-            "the backend cannot lower the Pallas TPU kernels (set "
-            "DGRAPH_TPU_PALLAS_P2P=1 to force interpret-mode kernels "
-            "off-TPU)"
-        )
-    _logger.warning(
-        "halo_impl='pallas_p2p' requested by %s but %s; the next "
-        "resolution tier decides the lowering instead", source, why,
-    )
 
 
 def plan_efficiency(plan: EdgePlan, layout: EdgePlanLayout) -> dict:
@@ -1033,31 +994,6 @@ def _reject_incompatible_knobs(
             "on owner-sorted edge order (monotone segment ids per subset); "
             "drop one of the two knobs"
         )
-    from dgraph_tpu import config as _cfg
-
-    if "pallas_p2p" in (_cfg.halo_impl, _cfg.tuned_halo_impl):
-        # fail the un-lowerable combos at build time, naming the knobs —
-        # not at the first pallas_call deep inside a jitted step
-        if not sort_edges:
-            raise ValueError(
-                "halo_impl='pallas_p2p' conflicts with sort_edges=False: "
-                "the one-sided lowering routes through the interior/"
-                "boundary split, which relies on owner-sorted edge order; "
-                "drop the pin or re-enable sort_edges"
-            )
-        if s_pad is not None and s_pad % 8:
-            raise ValueError(
-                f"halo_impl='pallas_p2p' conflicts with s_pad={s_pad}: the "
-                f"per-delta [s_pad, F] DMA tiles need 8-row (sublane) "
-                f"alignment; pick s_pad={_pad_to(s_pad, 8)} or drop the pin"
-            )
-        if pad_multiple % 8 and s_pad is None:
-            raise ValueError(
-                f"halo_impl='pallas_p2p' conflicts with pad_multiple="
-                f"{pad_multiple}: s_pad inherits this multiple and the "
-                f"per-delta DMA tiles need 8-row (sublane) alignment; use "
-                f"a multiple of 8 or pass an aligned explicit s_pad"
-            )
     if pad_multiple < 1:
         raise ValueError(f"pad_multiple={pad_multiple} must be >= 1")
     if e_pad is not None:
@@ -1482,7 +1418,7 @@ def halo_wire_rows(plan: "EdgePlan", impl: str) -> int:
     W, S = plan.world_size, plan.halo.s_pad
     if impl == "all_to_all":
         return W * (W - 1) * S
-    if impl in ("ppermute", "overlap", "pallas_p2p"):
+    if impl in ("ppermute", "overlap"):
         return len(plan.halo_deltas) * W * S
     if impl == "sched" and plan.halo_schedule is not None:
         return W * sum(plan.halo_schedule.round_rows())

@@ -191,7 +191,7 @@ def init_world(
         "pad_multiple": int(pad_multiple),
         # plan-build knobs every later generation must REPLAY: a shrink
         # that rebuilt without the interior/boundary split would silently
-        # outlaw the overlap/pallas_p2p lowerings in the degraded world
+        # outlaw the overlap lowering in the degraded world
         "plan_overlap": bool(overlap),
         "lost_history": [],
     }
@@ -211,7 +211,7 @@ def build_generation_plan(
     streaming per-rank builder (durable after every shard, RESUMABLE from
     its own manifest), replaying the world record's plan knobs — a
     transition that rebuilt without the interior/boundary split would
-    silently outlaw the overlap/pallas_p2p lowerings in the new world.
+    silently outlaw the overlap lowering in the new world.
     Shared by the shrink AND grow transitions (:mod:`dgraph_tpu.train.
     grow` is lint-enforced jax-free, so the jax-pulling
     :mod:`dgraph_tpu.plan` import stays quarantined here)."""

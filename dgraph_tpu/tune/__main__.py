@@ -165,64 +165,6 @@ def _selftest(cfg: Config, log) -> dict:
         finally:
             _dcfg.set_flags(tuned_halo_impl=saved_impl)
 
-        # pallas_p2p knob coverage (mirror of the overlap clause): every
-        # analytic row prices the one-sided lowering next to the others,
-        # and a record persisting halo_impl='pallas_p2p' round-trips
-        # save -> load -> adopt -> resolve — with both degrade paths
-        # (no split / no backend support) staying un-lowerable
-        if priced and not all("pallas_p2p_exposed_us" in t for t in priced):
-            failures.append("analytic trace rows carry no pallas_p2p pricing")
-        p2p_rec = TuningRecord.create(
-            rec.signature,
-            {**rec.config, "halo_impl": "pallas_p2p"},
-            rec.cost, rec.phase,
-        )
-        with tempfile.TemporaryDirectory(
-            prefix="dgraph_tune_selftest_p2p_"
-        ) as p2p_dir:
-            p2p_path = p2p_rec.save(p2p_dir)
-            reloaded_p2p = TuningRecord.load(p2p_path)
-            saved_impl = _dcfg.tuned_halo_impl
-            saved_p2p = _dcfg.use_pallas_p2p
-            try:
-                adopt_record(reloaded_p2p)
-                if _dcfg.tuned_halo_impl != "pallas_p2p":
-                    failures.append(
-                        f"adopt_record set tuned_halo_impl="
-                        f"{_dcfg.tuned_halo_impl!r}, expected 'pallas_p2p'"
-                    )
-                from dgraph_tpu.plan import resolve_halo_impl
-
-                _dcfg.set_flags(use_pallas_p2p=True)
-                impl, source = resolve_halo_impl(
-                    2, (1,), overlap_available=True)
-                if (impl, source) != ("pallas_p2p", "record"):
-                    failures.append(
-                        f"resolve_halo_impl under the adopted pallas_p2p "
-                        f"record returned ({impl!r}, {source!r}), expected "
-                        f"('pallas_p2p', 'record')"
-                    )
-                # a plan WITHOUT the split must degrade, never half-lower
-                impl_no_spec, _ = resolve_halo_impl(
-                    2, (1,), overlap_available=False)
-                if impl_no_spec == "pallas_p2p":
-                    failures.append(
-                        "resolve_halo_impl lowered 'pallas_p2p' on a plan "
-                        "without the interior/boundary split"
-                    )
-                # ... and so must a backend that cannot lower the kernels
-                _dcfg.set_flags(use_pallas_p2p=False)
-                impl_no_backend, _ = resolve_halo_impl(
-                    2, (1,), overlap_available=True)
-                if impl_no_backend == "pallas_p2p":
-                    failures.append(
-                        "resolve_halo_impl lowered 'pallas_p2p' with "
-                        "pallas_p2p_available() False"
-                    )
-            finally:
-                _dcfg.set_flags(
-                    tuned_halo_impl=saved_impl, use_pallas_p2p=saved_p2p)
-
         # round trip: the persisted JSON reloads, validates, and is found
         # by a signature lookup
         reloaded = TuningRecord.load(path)

@@ -119,11 +119,14 @@ class TuningRecord:
             if pm is not None and (not isinstance(pm, int) or pm < 1):
                 errors.append(f"pad_multiple {pm!r} not a positive int")
             impl = self.config.get("halo_impl")
-            if impl is not None and impl not in (
-                "none", "ppermute", "all_to_all", "overlap", "pallas_p2p",
-                "sched",
-            ):
-                errors.append(f"halo_impl {impl!r} unknown")
+            if impl is not None:
+                from dgraph_tpu.plan import HALO_IMPLS
+
+                known = ("none",) + HALO_IMPLS
+                if impl not in known:
+                    errors.append(
+                        f"halo_impl {impl!r} unknown (known: {known})"
+                    )
             wf = self.config.get("wire_format")
             if wf is not None:
                 from dgraph_tpu.wire.spec import WIRE_FORMAT_NAMES
@@ -265,12 +268,10 @@ def adopt_record(rec: TuningRecord) -> dict:
     """
     from dgraph_tpu import config as _cfg
 
+    from dgraph_tpu.plan import HALO_IMPLS
+
     impl = rec.config.get("halo_impl")
-    _cfg.set_flags(
-        tuned_halo_impl=impl
-        if impl in ("ppermute", "all_to_all", "overlap", "pallas_p2p", "sched")
-        else None
-    )
+    _cfg.set_flags(tuned_halo_impl=impl if impl in HALO_IMPLS else None)
     # the tuned wire format rides the 'record' tier of wire.spec.
     # resolve_wire_format; an fp32 winner clears the flag (identity is
     # the default, not an adoption)

@@ -38,6 +38,7 @@ from dgraph_tpu.obs.footprint import (
     dtype_bytes,
     plan_footprint,
 )
+from dgraph_tpu.plan import HALO_IMPLS
 from dgraph_tpu.tune.record import TuningRecord
 from dgraph_tpu.tune.signature import graph_signature
 from dgraph_tpu.tune.space import (
@@ -106,21 +107,9 @@ def candidate_cost(
     int_rows_max = max(split["interior_per_shard"] or [0])
     interior_leg_us = 3 * int_rows_max * row / (hbm_gbps * 1e3)
     overlap_exposed = 0.0
-    p2p_exposed = 0.0
     if n_d:
         pp_us = exch_bound("ppermute")
         overlap_exposed = max(pp_us - interior_leg_us, 0.0)
-        # pallas_p2p: the same boundary-only tiles as one-sided puts
-        # issued from inside the Pallas kernel — ONE launch instead of
-        # n_d collective rounds; the split routing hides the puts behind
-        # the interior aggregation like overlap does. HBM streams are
-        # billed at ppermute's (2*n_d + W) blocks: only the forward
-        # leg's in-VMEM mask fusion can skip a stream, and only when the
-        # stack fits the budget — the ranking must not credit a saving
-        # the reverse leg never delivers.
-        p2p_wire_us = wire.get("pallas_p2p", 0) / (ici_gbps * 1e3) + LAUNCH_US
-        p2p_hbm_us = (2 * n_d + W) * S * row / (hbm_gbps * 1e3)
-        p2p_exposed = max(max(p2p_wire_us, p2p_hbm_us) - interior_leg_us, 0.0)
 
     # the compiled schedule enters the ranking only when the plan carries
     # one (plan.halo_schedule attached at build) — ranked from the SAME
@@ -141,13 +130,6 @@ def candidate_cost(
             max(sched_wire_us, sched_hbm_us) - interior_leg_us, 0.0
         )
 
-    # the pallas_p2p knob only enters the ranking where it can actually
-    # lower (TPU backend, or the explicit interpret opt-in) — a record
-    # should not persist a winner the run would degrade away from
-    from dgraph_tpu import config as _cfg
-
-    p2p_rankable = bool(n_d) and _cfg.pallas_p2p_available()
-
     if n_d == 0:
         impl, exch_us = "none", 0.0
     else:
@@ -156,17 +138,16 @@ def candidate_cost(
             "ppermute": exch_bound("ppermute"),
             "overlap": overlap_exposed,
         }
-        if p2p_rankable:
-            bounds["pallas_p2p"] = p2p_exposed
         if sched_rankable:
             bounds["sched"] = sched_exposed
         # stable tie-break preserving the pre-overlap semantics: ppermute
         # beats all_to_all on equal cost (as before), overlap — equal to
         # ppermute exactly when there is no interior work to hide behind
-        # — only wins when it actually hides something, and pallas_p2p /
-        # sched (last) only when they strictly beat the fixed lowerings:
-        # an un-A/B'd transport or compiled schedule never wins a tie
-        order = ("ppermute", "all_to_all", "overlap", "pallas_p2p", "sched")
+        # — only wins when it actually hides something, and sched (last)
+        # only when it strictly beats the fixed lowerings: an un-A/B'd
+        # compiled schedule never wins a tie. plan.HALO_IMPLS with
+        # ppermute moved to the front IS that order.
+        order = ("ppermute",) + tuple(k for k in HALO_IMPLS if k != "ppermute")
         impl = min(
             (k for k in order if k in bounds),
             key=lambda k: (bounds[k], order.index(k)),
@@ -195,12 +176,10 @@ def candidate_cost(
     if n_d and res_row:
         launches_by = {
             "all_to_all": 1, "ppermute": n_d, "overlap": n_d,
-            "pallas_p2p": 1,
             "sched": sched_fp["rounds"] if sched_fp else 0,
         }
         sent_by = {
             "all_to_all": W, "ppermute": n_d, "overlap": n_d,
-            "pallas_p2p": n_d,
             "sched": sched_fp["rounds"] if sched_fp else 0,
         }
 
@@ -211,7 +190,7 @@ def candidate_cost(
             )
             hbm_us = (2 * sent_by[impl] + W) * S * row / (hbm_gbps * 1e3)
             bound = max(wire_us, hbm_us)
-            if impl in ("overlap", "pallas_p2p", "sched"):
+            if impl in ("overlap", "sched"):
                 bound = max(bound - interior_leg_us, 0.0)
             return bound
 
@@ -246,10 +225,6 @@ def candidate_cost(
         # overlap-knob pricing: both alternatives land in the trace so the
         # record's choice is auditable (overlap in {off, on} first-class)
         "overlap_exposed_us": round(overlap_exposed, 3),
-        # pallas_p2p-knob pricing: always priced (auditable even where it
-        # cannot lower); ranked only when pallas_p2p_rankable
-        "pallas_p2p_exposed_us": round(p2p_exposed, 3),
-        "pallas_p2p_rankable": p2p_rankable,
         # compiled-schedule pricing: always reported when a schedule is
         # attached (auditable), ranked only via sched_rankable
         "sched_exposed_us": round(sched_exposed, 3),

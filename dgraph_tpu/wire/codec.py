@@ -23,8 +23,8 @@ sees one stable callable per configuration:
   (``all_to_all(split=0, concat=0)`` is its own transpose; a ppermute's
   transpose is the inverted permutation).
 
-The multi-round executors in ``comm.collectives`` (overlap / pallas_p2p
-/ sched) are ALREADY custom-VJP bodies — opaque to AD — so they call the
+The multi-round executors in ``comm.collectives`` (overlap / sched)
+are ALREADY custom-VJP bodies — opaque to AD — so they call the
 raw transforms directly and encode their hand-built cotangent legs with
 the same pair.
 
@@ -33,7 +33,7 @@ bit): per-row scale ``max|x| / 448`` (zero rows scale 1.0), payload
 ``(x/scale) -> e4m3 -> bitcast uint8``, the f32 scale bitcast into 4
 trailing uint8 lanes of the same ``[.., F+4]`` operand — one collective,
 one priced operand. An all-zero wire row (ppermute's zeros at
-non-receivers, p2p's untouched buffer tail) decodes to exactly 0.0
+non-receivers) decodes to exactly 0.0
 because both its payload and its scale lanes are zero bytes.
 """
 

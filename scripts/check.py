@@ -29,16 +29,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PASSES = [
     # default analysis = lint + ALL audit tiers (jaxpr trace, lowered
-    # StableHLO, pallas_p2p DMA discipline) on the canonical workload
+    # StableHLO, cross-rank SPMD, host) on the canonical workload
     ("analysis", [sys.executable, "-m", "dgraph_tpu.analysis"]),
     ("analysis-selftest",
      [sys.executable, "-m", "dgraph_tpu.analysis", "--selftest", "true"]),
-    # Pallas DMA-discipline verifier standalone: the broken-kernel
-    # vacuity guards (dropped dma_wait & co.) plus the real-transport
-    # audit — make_jaxpr only, zero XLA compiles
-    ("kernel-verifier-selftest",
-     [sys.executable, "-m", "dgraph_tpu.analysis.kernel",
-      "--selftest", "true"]),
     # host-side concurrency & durability auditor: guarded-field/lock
     # discipline, lock-order cycles, atomic durable writes,
     # pointer-flip-last commits, chaos-registry coverage — stdlib ast,
@@ -56,12 +50,6 @@ PASSES = [
     # rendezvous, straggler/loss events — pure stdlib, fake-clock driven
     ("membership-selftest",
      [sys.executable, "-m", "dgraph_tpu.comm.membership",
-      "--selftest", "true"]),
-    # device-initiated one-sided halo transport: interpret-mode put
-    # parity vs the masked all_to_all on 2- and 4-shard rings (tiny CPU
-    # compiles only — the kernels never dial an accelerator here)
-    ("pallas-p2p-selftest",
-     [sys.executable, "-m", "dgraph_tpu.ops.pallas_p2p",
       "--selftest", "true"]),
     # cross-rank SPMD divergence auditor standalone: per-rank lowered-
     # module identity + collective issue order on 2/4-shard worlds and a

@@ -62,9 +62,6 @@ SECTIONS = [
      ["sorted_segment_sum", "sorted_segment_sum_bias_relu",
       "sorted_row_gather", "max_chunks_hint", "max_vblocks_hint",
       "block_chunk_counts", "chunk_vblock_spans"]),
-    ("Pallas one-sided halo transport", "dgraph_tpu.ops.pallas_p2p",
-     ["p2p_transport", "p2p_interpret_mode", "transport_fused_mask",
-      "FUSED_MASK_VMEM_BUDGET", "P2P_COLLECTIVE_ID"]),
     ("Models", "dgraph_tpu.models", None),
     ("GraphCast", "dgraph_tpu.models.graphcast", None),
     ("Tensor parallelism", "dgraph_tpu.parallel.tensor", None),
@@ -148,10 +145,6 @@ SECTIONS = [
     ("Static analysis: lowered-artifact auditor", "dgraph_tpu.analysis.hlo",
      ["lower_program", "collect_stablehlo", "audit_workload_hlo",
       "donation_entries", "hlo_drift_record", "COLLECTIVE_HLO_OPS"]),
-    ("Static analysis: Pallas DMA-discipline verifier",
-     "dgraph_tpu.analysis.kernel",
-     ["collect_transports", "verify_transport", "audit_workload_kernels",
-      "kernel_selftest_failures"]),
     ("Static analysis: cross-rank SPMD divergence auditor",
      "dgraph_tpu.analysis.spmd",
      ["build_spmd_fixture", "build_shrink_fixture", "build_rank_workload",

@@ -1,13 +1,12 @@
-"""Lowered-artifact auditor + Pallas DMA-discipline verifier (ISSUE 12).
+"""Lowered-artifact auditor (ISSUE 12).
 
 Everything here is **lower-only**: programs reach StableHLO through
-``jit(...).lower()`` and kernels through ``jax.make_jaxpr`` — zero new
-XLA compiles (asserted explicitly via the jit-cache counter below; the
-budget rule tests/README.md documents). The clean-tree GREEN pins run
-the full 2- and 4-shard audits across all four halo lowerings; the
-vacuity guards prove each new tier still goes RED on seeded drift — a
-seeded extra all-gather, a dropped ``dma_wait``, and a dropped donation,
-plus the raw-``shard_map`` lint shape.
+``jit(...).lower()`` — zero new XLA compiles (asserted explicitly via
+the jit-cache counter below; the budget rule tests/README.md documents).
+The clean-tree GREEN pins run the full 2- and 4-shard audits across
+every halo lowering; the vacuity guards prove the tier still goes RED on
+seeded drift — a seeded extra all-gather and a dropped donation, plus
+the raw-``shard_map`` lint shape.
 """
 
 import warnings
@@ -15,7 +14,6 @@ import warnings
 import pytest
 
 from dgraph_tpu.analysis import hlo as H
-from dgraph_tpu.analysis import kernel as K
 from dgraph_tpu.analysis import lint as L
 
 
@@ -34,7 +32,7 @@ def workload4():
 
 
 # ---------------------------------------------------------------------------
-# clean-tree GREEN pins (2- and 4-shard, all four lowerings)
+# clean-tree GREEN pins (2- and 4-shard, every lowering)
 # ---------------------------------------------------------------------------
 
 
@@ -64,8 +62,7 @@ def test_hlo_audit_clean_green(world, workload2, workload4):
 
 def test_hlo_count_pins_mirror_trace_tier(workload2):
     """Cross-lowering count discipline at the artifact level: permutes ==
-    legs * deltas; the p2p interpret discharge lands exactly one
-    tile-payload gather (plus two scalar index gathers) per remote put."""
+    legs * deltas."""
     rep = H.audit_workload_hlo(workload2)
     assert rep["ok"], rep["failures"]
     n_deltas = rep["num_halo_deltas"]
@@ -76,9 +73,6 @@ def test_hlo_count_pins_mirror_trace_tier(workload2):
             assert by[(prog, impl)]["num_collective_permute"] == (
                 legs * n_deltas
             )
-        p2p = by[(prog, "pallas_p2p")]
-        assert p2p["num_tile_gathers"] == legs * n_deltas
-        assert p2p["num_index_gathers"] == 2 * legs * n_deltas
 
 
 def test_hlo_audit_is_lower_only(workload2):
@@ -101,27 +95,13 @@ def test_hlo_audit_is_lower_only(workload2):
         cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
 
 
-def test_kernel_audit_clean_green(workload2, workload4):
-    """The real pallas_p2p transports (train/eval/serve, fwd+bwd legs)
-    pass the DMA-discipline verifier at both shard counts — including
-    W=4's three live deltas, which exercise the slot-reuse wait."""
-    for w in (workload2, workload4):
-        rep = K.audit_workload_kernels(w)
-        assert rep["ok"], rep["failures"]
-        assert len(rep["kernels"]) >= 4
-    # W=4 traced at least one fused kernel with slot reuse in play
-    fused = [k for k in rep["kernels"] if k["fused_mask"]]
-    assert fused and any(k["n_deltas"] >= 3 for k in fused)
-
-
 # ---------------------------------------------------------------------------
 # vacuity guards: seeded drift must go RED
 # ---------------------------------------------------------------------------
 
 
 def test_seeded_extra_all_gather_goes_red(workload2):
-    """An XLA-materialized all_gather the plan never scheduled — the
-    class the relaxed replication checker can no longer catch — must
+    """An XLA-materialized all_gather the plan never scheduled must
     fail the HLO audit."""
     import jax
     from jax import lax
@@ -141,8 +121,11 @@ def test_seeded_extra_all_gather_goes_red(workload2):
         def seeded(params, opt_state, batch, plan):
             out = fn(params, opt_state, batch, plan)
             extra = jax.shard_map(
-                lambda x: lax.all_gather(x[0], GRAPH_AXIS),
-                mesh=w.mesh, in_specs=(P(GRAPH_AXIS),), out_specs=P(),
+                # each rank keeps its own copy of the gathered rows: the vma
+                # checker is on, and all_gather's output counts as varying
+                lambda x: lax.all_gather(x[0], GRAPH_AXIS)[None],
+                mesh=w.mesh, in_specs=(P(GRAPH_AXIS),),
+                out_specs=P(GRAPH_AXIS),
                 **shard_map_checks(relax="seeded test mutant"),
             )(batch["x"])
             return out, extra
@@ -191,24 +174,6 @@ def test_dropped_donation_goes_red(workload2):
         assert failures
     finally:
         cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
-
-
-def test_dropped_dma_wait_goes_red():
-    """Every seeded kernel-discipline mutation (dropped send wait,
-    dropped recv wait, slot reuse without wait, wrong dst-row slot,
-    oversized staging) is flagged; the clean kernel is not."""
-    assert K.kernel_selftest_failures() == []
-
-
-def test_kernel_verifier_flags_each_mutation_specifically():
-    mism = []
-    jaxpr = K._mutant_jaxpr(4, 8, 16, (1, 2, 3), "drop_send_wait")
-    K.verify_transport(*K.collect_transports(jaxpr)[0], "m", mism)
-    assert any("send semaphore" in m for m in mism), mism
-    mism = []
-    jaxpr = K._mutant_jaxpr(4, 8, 16, (1, 2, 3), "bad_dst_row")
-    K.verify_transport(*K.collect_transports(jaxpr)[0], "m", mism)
-    assert any("me*S" in m for m in mism), mism
 
 
 def test_hlo_rejects_wrong_lowering_family(workload2):
@@ -277,25 +242,25 @@ def test_raw_shard_map_site_flagged():
 def test_lint_descends_into_pallas_kernels():
     """A config read (or span) inside a kernel handed to pallas_call via
     a functools.partial alias fires — the pre-ISSUE-12 blind spot."""
-    path = "dgraph_tpu/ops/pallas_p2p.py"
+    path = "dgraph_tpu/ops/pallas_segment.py"
     bad = (
         "import functools\n"
         "from jax.experimental import pallas as pl\n"
         "from dgraph_tpu import config as _cfg\n"
         "def _kernel(x_ref, o_ref):\n"
-        "    o_ref[...] = x_ref[...] * (2 if _cfg.use_pallas_p2p else 1)\n"
+        "    o_ref[...] = x_ref[...] * (2 if _cfg.use_pallas_scatter else 1)\n"
         "def transport(x, shape):\n"
         "    kern = functools.partial(_kernel)\n"
         "    return pl.pallas_call(kern, out_shape=shape)(x)\n"
     )
     good = bad.replace(
         "def _kernel(x_ref, o_ref):\n"
-        "    o_ref[...] = x_ref[...] * (2 if _cfg.use_pallas_p2p else 1)\n",
+        "    o_ref[...] = x_ref[...] * (2 if _cfg.use_pallas_scatter else 1)\n",
         "def _kernel(x_ref, o_ref, *, scale):\n"
         "    o_ref[...] = x_ref[...] * scale\n",
     ).replace(
         "    kern = functools.partial(_kernel)\n",
-        "    scale = 2 if _cfg.use_pallas_p2p else 1\n"
+        "    scale = 2 if _cfg.use_pallas_scatter else 1\n"
         "    kern = functools.partial(_kernel, scale=scale)\n",
     )
     assert _run_rule("no-config-read-in-trace", path, bad)
@@ -304,7 +269,7 @@ def test_lint_descends_into_pallas_kernels():
         "from jax.experimental import pallas as pl\n"
         "from dgraph_tpu.obs import spans\n"
         "def _kernel(x_ref, o_ref):\n"
-        "    with spans.span('p2p.tile', stage='exchange'):\n"
+        "    with spans.span('segsum.tile', stage='scatter'):\n"
         "        o_ref[...] = x_ref[...]\n"
         "def transport(x, shape):\n"
         "    return pl.pallas_call(_kernel, out_shape=shape)(x)\n"
@@ -314,9 +279,9 @@ def test_lint_descends_into_pallas_kernels():
 
 def test_shipped_tree_has_no_unchecked_shard_maps():
     """The clean-tree pin for the new rule: the five raw sites ISSUE 12
-    fixed (train/loop.py init, ops/pallas_p2p.py selftest, the blanket
-    RELAXED_CHECKS in parallel/sequence.py, and the two analysis-internal
-    ones) stay fixed."""
+    fixed (train/loop.py init, the blanket RELAXED_CHECKS in
+    parallel/sequence.py, and the two analysis-internal ones) stay
+    fixed."""
     report = L.run_lint()
     raw = [f for f in report["findings"]
            if f["rule"] == "no-unchecked-shard-map"]
@@ -334,7 +299,7 @@ def test_hlo_drift_record_shape():
     rec = H.hlo_drift_record(2, num_nodes=64, num_edges=256, feat_dim=8)
     assert rec["kind"] == "hlo_drift"
     assert rec["drift"] is False
-    for impl in ("all_to_all", "ppermute", "overlap", "pallas_p2p"):
+    for impl in ("all_to_all", "ppermute", "overlap", "sched"):
         row = rec["train_step_by_impl"][impl]
         assert row["lowered_bytes"] == row["footprint_bytes"] > 0
     don = rec["donation"]

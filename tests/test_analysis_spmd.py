@@ -215,7 +215,7 @@ def test_spmd_drift_record_shape():
     assert rec["kind"] == "spmd_drift"
     assert rec["drift"] is False
     assert rec["num_halo_deltas"] >= 1
-    for impl in ("all_to_all", "ppermute", "overlap", "pallas_p2p"):
+    for impl in ("all_to_all", "ppermute", "overlap", "sched"):
         row = rec["train_step_by_impl"][impl]
         assert row["identical"] is True
         assert row["num_collectives"] > 0

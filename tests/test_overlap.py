@@ -73,19 +73,29 @@ def _run_all(mesh, plan, xs, ed, ct_e, ct_v):
 @pytest.mark.parametrize("W", [2, 4])
 def test_overlap_bitwise_parity_with_all_to_all(rng, impl_flags, W):
     """halo_exchange_overlap / scatter_sum_overlap (through the gather and
-    halo-side scatter they lower) are bit-identical to the all_to_all
-    path, forward AND backward — the overlap schedule reorders execution,
-    never the summed terms."""
+    halo-side scatter they lower) against the all_to_all path, forward AND
+    backward. Gather forward, gather gradient and scatter gradient are
+    bit-identical at every W: the exchange moves rows and its transpose
+    runs the same masked flat segment-sum over the same buffer. Scatter
+    FORWARD is bit-identical at W = 2 only. Piece by piece (interior
+    sum, slot partials, reverse rounds) the two paths agree to the bit;
+    the last merge — a vertex's local partial plus one returned partial
+    per delta — is folded into the segment-sum's accumulation differently
+    by XLA once each op is compiled, so the partial sums over deltas are
+    added in another order than the serial path's slots: the same terms,
+    regrouped. With one delta there is one order; beyond, float32
+    rounding differs (54–61 of 320 elements by ≤ 9.6e-7 on four seeds),
+    bounded per vertex by the two-orderings bound ``2 u (n-1) sum|terms|``
+    (u = 2^-24, n = that vertex's edge count), which is what W > 2 is
+    held to. No blanket rtol: a dropped or doubled term is O(1)."""
     edges, part, plan, layout = _case(rng, W)
     V, F = len(part), 5
     xs = jnp.asarray(shard_vertex_data(
         rng.normal(size=(V, F)).astype(np.float32),
         layout.src_counts, plan.n_src_pad,
     ))
-    ed = jnp.asarray(shard_edge_data(
-        rng.normal(size=(edges.shape[1], F)).astype(np.float32),
-        layout, plan.e_pad,
-    ))
+    ed_raw = rng.normal(size=(edges.shape[1], F)).astype(np.float32)
+    ed = jnp.asarray(shard_edge_data(ed_raw, layout, plan.e_pad))
     ct_e = jnp.asarray(shard_edge_data(
         rng.normal(size=(edges.shape[1], F)).astype(np.float32),
         layout, plan.e_pad,
@@ -104,6 +114,20 @@ def test_overlap_bitwise_parity_with_all_to_all(rng, impl_flags, W):
         ("gather fwd", "gather grad", "scatter fwd", "scatter grad"),
         got_ov, got_a2a,
     ):
+        if name == "scatter fwd" and W > 2:
+            abs_sum = np.zeros((V, F), np.float32)
+            np.add.at(abs_sum, edges[0], np.abs(ed_raw))
+            n_terms = np.bincount(edges[0], minlength=V)
+            atol = shard_vertex_data(
+                np.finfo(np.float32).eps
+                * np.maximum(n_terms - 1, 0)[:, None] * abs_sum,
+                layout.src_counts, plan.n_src_pad,
+            )
+            assert (np.abs(a - b) <= atol).all(), (
+                f"{name} beyond the regrouping bound: "
+                f"{np.abs(a - b).max()} vs {atol.max()}"
+            )
+            continue
         np.testing.assert_array_equal(a, b, err_msg=f"{name} not bit-identical")
 
 

@@ -544,6 +544,89 @@ class TestResolveHaloImplLadder:
         assert len(warns) == 1, "degrade warning must fire once per source"
         pl._overlap_warned.clear()
 
+    @pytest.mark.parametrize("name", ["alltoall", "one_sided_put"])
+    def test_unknown_lowering_name_is_refused(self, name):
+        """A pin that names no lowering must not fall through to the
+        heuristic in silence (a typo, or a lowering this tree no longer
+        has, would train on another lowering than the operator asked
+        for): ValueError naming the legal values, from either tier."""
+        self._set(env=name)
+        with pytest.raises(ValueError, match="DGRAPH_TPU_HALO_IMPL") as ei:
+            pl.resolve_halo_impl(8, (1,), overlap_available=True)
+        for legal in ("auto",) + pl.HALO_IMPLS:
+            assert legal in str(ei.value)
+        self._set(record=name)
+        with pytest.raises(ValueError, match="tuned_halo_impl"):
+            pl.resolve_halo_impl(8, (1,), overlap_available=True)
+
+    def test_record_naming_an_unknown_lowering_is_a_lookup_miss(
+        self, tmp_path, monkeypatch, caplog
+    ):
+        """A persisted TuningRecord is a cache file: one that names a
+        lowering this tree does not have is ignored with ONE warning
+        (the defaults apply), never adopted and never a crash."""
+        import json
+        import logging
+
+        from dgraph_tpu.tune.record import (
+            ENV_DIR, ENV_RECORD, TuningRecord, lookup_record, record_path,
+        )
+        from dgraph_tpu.tune.signature import graph_signature
+
+        monkeypatch.delenv(ENV_RECORD, raising=False)
+        monkeypatch.setenv(ENV_DIR, str(tmp_path))
+        sig = graph_signature(EDGES, len(PART), 2)
+        good = TuningRecord.create(
+            sig, {"halo_impl": "all_to_all"}, {"winner_us": 1.0}, "analytic"
+        )
+        good.save(str(tmp_path))
+        assert lookup_record(sig, cache_dir=str(tmp_path)) is not None
+        stale = good.to_dict()
+        stale["config"]["halo_impl"] = "one_sided_put"
+        with open(record_path(str(tmp_path), sig), "w") as f:
+            json.dump(stale, f)
+        with caplog.at_level(logging.WARNING, logger="dgraph_tpu.tune"):
+            assert lookup_record(sig, cache_dir=str(tmp_path)) is None
+        warns = [r for r in caplog.records if "one_sided_put" in r.getMessage()]
+        assert len(warns) == 1, [r.getMessage() for r in caplog.records]
+
+    def test_every_list_of_lowerings_is_the_plans(self):
+        """plan.HALO_IMPLS is the one list: the audit tiers' columns, the
+        attribution tier's default and the tuner's candidate order hold
+        it (or a reordering of it), never a literal of their own."""
+        import importlib
+        import inspect
+
+        from dgraph_tpu.analysis import hlo, spmd, trace
+        from dgraph_tpu.obs import attribution
+        from dgraph_tpu.tune import record
+
+        # (the package re-exports a function of the same name)
+        search = importlib.import_module("dgraph_tpu.tune.search")
+
+        assert trace.HALO_IMPLS is pl.HALO_IMPLS
+        assert attribution.DEFAULT_IMPLS is pl.HALO_IMPLS
+        for audit in (trace.audit_workload, hlo.audit_workload_hlo,
+                      spmd.audit_plan_dir_spmd):
+            default = inspect.signature(audit).parameters["impls"].default
+            assert default is pl.HALO_IMPLS, audit
+        assert search.HALO_IMPLS is pl.HALO_IMPLS
+        # the legal set with both gates open, and the record validator's
+        for name in pl.HALO_IMPLS:
+            self._set(env=name)
+            assert pl.resolve_halo_impl(
+                4, (1,), overlap_available=True, sched_available=True
+            ) == (name, "env")
+            record.TuningRecord.create(
+                {"degree_digest": "x"}, {"halo_impl": name},
+                {"winner_us": 1.0}, "analytic",
+            )
+        with pytest.raises(ValueError, match="halo_impl"):
+            record.TuningRecord.create(
+                {"degree_digest": "x"}, {"halo_impl": "one_sided_put"},
+                {"winner_us": 1.0}, "analytic",
+            )
+
     def test_reported_source_reaches_plan_efficiency(self):
         """The deciding source is not just returned — it lands in the
         plan_efficiency report (the operator-facing surface)."""

@@ -1,5 +1,7 @@
-"""ppermute neighbor-rounds halo exchange == all_to_all lowering, forward
-and backward, on both sparse (ring) and dense (random) peer sets."""
+"""Every halo lowering that needs no compiled schedule (all_to_all,
+ppermute neighbor rounds, overlap) against the dense oracle at W = 8,
+forward and backward, gather and halo-side scatter, on both sparse (ring)
+and dense (random) peer sets."""
 
 import numpy as np
 import pytest
@@ -19,8 +21,16 @@ from dgraph_tpu.testing import (
 )
 
 
+@pytest.fixture(params=["ppermute", "all_to_all", "overlap"])
+def impl(request):
+    old = cfg.halo_impl
+    cfg.set_flags(halo_impl=request.param)
+    yield request.param
+    cfg.set_flags(halo_impl=old)
+
+
 @pytest.fixture(params=["ring", "random"])
-def case(request, rng):
+def case(request, rng, impl):
     W, V = 8, 96
     if request.param == "ring":
         # block-partition a ring graph: traffic only to rank+-1 -> sparse deltas
@@ -31,16 +41,12 @@ def case(request, rng):
     else:
         edges = rng.integers(0, V, size=(2, 600))
         part = np.sort(rng.integers(0, W, V)).astype(np.int32)
-    plan, layout = pl.build_edge_plan(edges, part, world_size=W)
+    # the overlap lowering needs the plan's interior/boundary split
+    plan, layout = pl.build_edge_plan(
+        edges, part, world_size=W, overlap=(impl == "overlap")
+    )
+    assert collectives.resolve_plan_impl(plan, "graph") == impl
     return edges, part, plan, layout, request.param
-
-
-@pytest.fixture(params=["ppermute", "all_to_all"])
-def impl(request):
-    old = cfg.halo_impl
-    cfg.set_flags(halo_impl=request.param)
-    yield request.param
-    cfg.set_flags(halo_impl=old)
 
 
 def test_ring_partition_has_sparse_deltas(rng):
@@ -93,3 +99,23 @@ def test_gather_grad_matches_dense(mesh8, case, impl, rng):
     np.testing.assert_allclose(
         got, dense_scatter_sum(ct, edges, "src", V), rtol=1e-5, atol=1e-5
     )
+
+
+def test_scatter_to_halo_side_grad_matches_dense(mesh8, case, impl, rng):
+    """The gradient of the halo-side scatter_sum is the gather of the
+    cotangent: d/d(edata_e) sum_v ct_v * out_v = ct[src(e)]."""
+    edges, part, plan, layout, _ = case
+    V, F = len(part), 3
+    edata = rng.normal(size=(edges.shape[1], F)).astype(np.float32)
+    ed = jnp.asarray(shard_edge_data(edata, layout, plan.e_pad))
+    ct = rng.normal(size=(V, F)).astype(np.float32)
+    ct_sh = jnp.asarray(shard_vertex_data(ct, layout.src_counts, plan.n_src_pad))
+
+    def loss_fn(ed_):
+        out = spmd_apply(mesh8, collectives.scatter_sum, plan, ed_, static_args=("src", "graph"))
+        return jnp.sum(out * ct_sh)
+
+    with jax.set_mesh(mesh8):
+        grad = jax.jit(jax.grad(loss_fn))(ed)
+    got = unshard_edge_data(np.asarray(grad), layout)
+    np.testing.assert_allclose(got, dense_gather(ct, edges, "src"), rtol=1e-6)

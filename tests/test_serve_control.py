@@ -595,6 +595,11 @@ def test_serve_health_carries_tenants_and_lineage(control):
     from dgraph_tpu.serve.health import serve_health_record
 
     engine, *_ = control
+    # the swap whose record the health record must carry is made HERE: the
+    # module-scoped engine starts with an empty lineage, so relying on an
+    # earlier test's swap made this one fail when run alone
+    swap = engine.swap_params(step=1)
+    assert swap["adopted"]
     table = TenantTable(TenantQuota(rps=0.0, burst=8, max_queue_share=0.9))
     bat = MicroBatcher(engine, max_delay_ms=0.2, tenants=table)
     try:
@@ -603,7 +608,10 @@ def test_serve_health_carries_tenants_and_lineage(control):
         assert "acme" in rec["tenants"]
         assert rec["tenants"]["acme"]["admitted"] == 1
         assert rec["tenants"]["acme"]["latency_ms"]["count"] == 1
-        assert isinstance(rec["lineage"], list) and rec["lineage"]
+        assert isinstance(rec["lineage"], list)
+        assert rec["lineage"][-1] == engine.lineage[-1]
+        assert rec["lineage"][-1]["event"] == "swap"
+        assert rec["lineage"][-1]["step"] == 1
         json.dumps(rec, default=str)
     finally:
         bat.stop()

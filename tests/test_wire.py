@@ -63,11 +63,10 @@ GRAD_BOUND = {"bf16": 5e-2, "fp8": 3.5e-1}
 def wire_flags():
     """Save/restore every flag the wire + halo ladders read."""
     saved = (cfg.wire_format, cfg.tuned_wire_format, cfg.halo_impl,
-             cfg.tuned_halo_impl, cfg.use_pallas_p2p)
+             cfg.tuned_halo_impl)
     yield
     cfg.set_flags(wire_format=saved[0], tuned_wire_format=saved[1],
-                  halo_impl=saved[2], tuned_halo_impl=saved[3],
-                  use_pallas_p2p=saved[4])
+                  halo_impl=saved[2], tuned_halo_impl=saved[3])
 
 
 def _graph(rng, W, V=96, E=600):
@@ -466,22 +465,6 @@ def test_trace_audit_green_under_pinned_format(
         assert op["traced_bytes"] == op["footprint_bytes"]
 
 
-@requires_fp8
-def test_hlo_audit_green_under_fp8_p2p(audit_workload_f32, wire_flags):
-    """The uint8 wire payload survives lowering as the p2p send tile:
-    the DMA-artifact classifier must price it (F+4 scale lanes), not
-    report it as an unscheduled collective."""
-    from dgraph_tpu.analysis import hlo as H
-
-    cfg.set_flags(wire_format="fp8", tuned_wire_format=None)
-    rep = H.audit_workload_hlo(
-        audit_workload_f32, impls=("all_to_all", "pallas_p2p")
-    )
-    assert rep["ok"], rep["failures"]
-    tiles = [p for p in rep["programs"] if p["impl"] == "pallas_p2p"]
-    assert tiles and all(p["num_tile_gathers"] > 0 for p in tiles)
-
-
 def test_hlo_audit_green_under_bf16(audit_workload_f32, wire_flags):
     """The LOWERED modules agree too: StableHLO collective operands are
     byte-exact against the bf16-priced footprint (the wire cast must
@@ -558,8 +541,7 @@ def wire_case(request):
 
 
 def _gather_once(mesh, plan, xs, *, fmt, impl):
-    cfg.set_flags(wire_format=fmt, tuned_wire_format=None, halo_impl=impl,
-                  use_pallas_p2p=(impl == "pallas_p2p"))
+    cfg.set_flags(wire_format=fmt, tuned_wire_format=None, halo_impl=impl)
     return np.asarray(spmd_apply(
         mesh, collectives.gather, plan, xs, static_args=("src", "graph")
     ))
@@ -605,10 +587,9 @@ def test_bf16_forward_parity_across_lowerings(wire_case, wire_flags):
     x = rng.normal(size=(len(part), 6)).astype(np.float32)
     xs = jnp.asarray(shard_vertex_data(x, layout.src_counts, plan.n_src_pad))
     out = {impl: _gather_once(mesh, plan, xs, fmt="bf16", impl=impl)
-           for impl in ("all_to_all", "ppermute", "overlap", "sched",
-                        "pallas_p2p")}
+           for impl in ("all_to_all", "ppermute", "overlap", "sched")}
     base = out["all_to_all"]
-    for impl in ("overlap", "sched", "pallas_p2p"):
+    for impl in ("overlap", "sched"):
         assert (out[impl] == base).all(), f"{impl} differs from all_to_all"
     np.testing.assert_allclose(out["ppermute"], base, rtol=1e-6, atol=1e-6)
     err = _rel_err(unshard_edge_data(base, layout),
@@ -630,8 +611,7 @@ def test_fp8_forward_parity_sched_vs_a2a(wire_case, wire_flags):
 
 
 def _gather_grad_once(mesh, plan, xs, ct_sh, *, fmt, impl):
-    cfg.set_flags(wire_format=fmt, tuned_wire_format=None, halo_impl=impl,
-                  use_pallas_p2p=(impl == "pallas_p2p"))
+    cfg.set_flags(wire_format=fmt, tuned_wire_format=None, halo_impl=impl)
 
     def loss_fn(xs_):
         out = spmd_apply(mesh, collectives.gather, plan, xs_,
