@@ -40,11 +40,20 @@ buffers (the rule ``tests/README.md`` documents) — and walks the module:
   actually alias it).
 
 Everything here assumes the virtual-CPU backend the analysis CLI pins.
+
+One reader below works a level further down, on the text of a COMPILED
+module (``compiled.as_text()``, e.g. of a step compiled for a described
+TPU as ``benchmark/tools/rehearse_w4.py`` does):
+:func:`gather_table_placement` lists every row gather with the memory
+space the compiler gave its table. Whether a table was placed on chip is
+decided by the compiler's memory assignment and is in no jaxpr and no
+StableHLO; it is worth 4.3 against 24.8 ms a gather (PERF.md, PR 31).
 """
 
 from __future__ import annotations
 
 import math
+import re
 from typing import Optional
 
 from dgraph_tpu.analysis.trace import (
@@ -60,6 +69,8 @@ __all__ = [
     "audit_workload_hlo",
     "donation_entries",
     "hlo_drift_record",
+    "gather_table_placement",
+    "placement_line",
 ]
 
 # StableHLO ops that move data across devices; anything here that the
@@ -605,3 +616,82 @@ def hlo_drift_record(
         "failures": report["failures"],
         "drift": not report["ok"],
     }
+
+
+# ---------------------------------------------------------------------------
+# compiled-module reader: where each row gather's table lives
+# ---------------------------------------------------------------------------
+
+_HLO_ITEMSIZE = {
+    "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
+    "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
+    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
+}
+# `%name = dtype[dims]{layout} opcode(operands...), attrs` — the layout has
+# no blank in it, so it ends at the first one
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>\S+) = (?P<dtype>\w+)\[(?P<dims>[\d,]*)\]"
+    r"(?P<layout>\{\S*\})? (?P<op>[\w-]+)\((?P<rest>.*)$"
+)
+
+
+def gather_table_placement(compiled_text: str) -> list:
+    """Every row gather of a compiled module (``compiled.as_text()``): a
+    ``gather`` that takes whole rows (``slice_sizes={1, C}``) of a 2-D
+    ``[N, C]`` table. One dict a gather, in module order: ``gather`` and
+    ``computation`` names, ``table_shape``, ``table_dtype``,
+    ``table_bytes``, ``rows`` (taken), ``memory_space`` of the table as
+    the gather's computation sees it (0 = HBM; on a TPU ``S(1)`` is
+    on-chip memory) and ``op_name`` from the metadata. A fused gather's
+    table is ``parameter(0)`` of its fused computation, and that
+    parameter's layout carries the space the operand was assigned."""
+    out = []
+    computation, defs = None, {}
+    for line in compiled_text.splitlines():
+        stripped = line.strip()
+        if stripped.endswith("{") and " -> " in stripped:
+            head = stripped.split(" (", 1)[0].split()
+            computation, defs = head[-1].lstrip("%"), {}
+            continue
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        defs[m["name"]] = m
+        if m["op"] != "gather":
+            continue
+        table = defs.get(m["rest"].split(",", 1)[0].strip().lstrip("%"))
+        sizes = re.search(r"slice_sizes=\{([\d,]*)\}", m["rest"])
+        if table is None or sizes is None:
+            continue
+        shape = tuple(int(d) for d in table["dims"].split(",") if d)
+        if len(shape) != 2 or sizes[1] != f"1,{shape[1]}":
+            continue
+        space = re.search(r"S\((\d+)\)", table["layout"] or "")
+        op_name = re.search(r'op_name="([^"]*)"', m["rest"])
+        out.append({
+            "gather": m["name"],
+            "computation": computation,
+            "table_shape": shape,
+            "table_dtype": table["dtype"],
+            "table_bytes": shape[0] * shape[1]
+            * _HLO_ITEMSIZE.get(table["dtype"], 0),
+            "rows": math.prod(int(d) for d in m["dims"].split(",") if d)
+            // max(shape[1], 1),
+            "memory_space": int(space[1]) if space else 0,
+            "op_name": op_name[1] if op_name else "",
+        })
+    return out
+
+
+def placement_line(gathers: list) -> str:
+    """``gather tables on chip: k of n`` over ``gathers``, with the sizes
+    of those left in HBM (a backward gather's ``[E, C]`` table always is)."""
+    left = [g for g in gathers if g["memory_space"] == 0]
+    line = f"gather tables on chip: {len(gathers) - len(left)} of {len(gathers)}"
+    if left:
+        tables = sorted({(g["table_shape"], g["table_dtype"], g["table_bytes"])
+                         for g in left})
+        line += "; in HBM: " + ", ".join(
+            f"{dt}[{r},{c}] ({nbytes / 1e6:.1f} MB)"
+            for (r, c), dt, nbytes in tables)
+    return line

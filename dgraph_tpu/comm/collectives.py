@@ -701,18 +701,85 @@ def _side_npad(plan: EdgePlan, side: str) -> int:
     return plan.n_src_pad if side == "src" else plan.n_dst_pad
 
 
+def _chunk_slices(width: int, chunk: Optional[int]) -> list:
+    from dgraph_tpu import config as _cfg
+
+    cb = chunk or _cfg.gather_col_block or width
+    return [slice(j, min(j + cb, width)) for j in range(0, width, cb)]
+
+
+def _concat_chunks(outs: list) -> jax.Array:
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
+
+
 def map_feature_chunks(fn, width: int, chunk: Optional[int] = None):
     """Scaffold of the feature-chunked edge pipeline (models/gcn.py
     rationale): apply ``fn(slice)`` over <=chunk-wide feature slices and
     concat the results on the last axis. ``chunk`` defaults to
     ``config.gather_col_block``. Callers are responsible for the gates
     (feature-separable per-edge math, collective-free per-chunk ops —
-    pair with :func:`halo_extend` + :func:`local_take`)."""
-    from dgraph_tpu import config as _cfg
+    pair with :func:`halo_extend` + :func:`local_take`). The chunks are
+    independent expressions: the compiler may run them in any order. A
+    call site whose chunk ends in a vertex-level reduction wants
+    :func:`map_vertex_chunks`."""
+    return _concat_chunks([fn(sl) for sl in _chunk_slices(width, chunk)])
 
-    cb = chunk or _cfg.gather_col_block or width
-    outs = [fn(slice(j, min(j + cb, width))) for j in range(0, width, cb)]
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
+
+@jax.custom_jvp
+def _run_after(token, cols):
+    """``cols`` unchanged, but not available before ``token`` is."""
+    return lax.optimization_barrier((token, cols))[1]
+
+
+@_run_after.defjvp
+def _run_after_jvp(primals, tangents):
+    # the tie is the forward's alone: tangents (and so cotangents) pass
+    # straight through and the token gets none. The barrier's own
+    # transpose would order the chunks' cotangents as well, which holds
+    # [E, chunk] tensors longer (+3.5 % temporaries on gcn_papers100m.w4,
+    # PERF.md PR 31)
+    return _run_after(*primals), tangents[1]
+
+
+# On-chip memory a row gather's table can be placed in: 128 MiB on a TPU
+# v5e (as on a v4 and a v6e). A hardware fact, not an option.
+ON_CHIP_BYTES = 128 << 20
+
+
+def map_vertex_chunks(fn, tables, chunk: Optional[int] = None):
+    """:func:`map_feature_chunks` for a pipeline whose chunk result is
+    vertex-level (``fn(*cols) -> [N, chunk]``: take -> edge math ->
+    segment-sum), with the chunks run ONE AFTER THE OTHER where that can
+    pay: chunk j+1's column slices of ``tables`` wait on chunk j's result
+    (an ``optimization_barrier``: same values, same bits, forward only).
+
+    Why: a chunk is also the unit of on-chip placement. The compiler
+    keeps a row gather's table in on-chip memory when it fits (from
+    there a row costs 1.85 ns, from HBM 10.6), and as independent
+    expressions all of a layer's ``[N, chunk]`` tables are written by one
+    fusion and want their place through all of its gathers, so only one
+    of gcn_arxiv's two 43 MB tables was placed. Ordered, a table needs
+    its place through its own gather only (the compiler moves it on chip
+    once the previous chunk is done) and every one is placed. Waiting on
+    an ``[N, chunk]`` token is free; an ``[E, chunk]`` chunk result
+    (GraphCast, edge attention) would be held by it — those call sites
+    stay on :func:`map_feature_chunks`.
+
+    Ordering is not free: XLA masks a layer's independent chunks in one
+    fusion and ordered chunks in one each (+1.5 ms a layer at 2.3 M
+    edges). So chunks whose tables cannot be placed anyway (a slice past
+    :data:`ON_CHIP_BYTES`: gcn_papers100m.w4's 207 MB) stay independent,
+    and that program is what it was (PERF.md, PR 31)."""
+    from dgraph_tpu.obs.metrics import default_registry
+
+    out = []
+    for sl in _chunk_slices(tables[0].shape[-1], chunk):
+        cols = tuple(t[:, sl] for t in tables)
+        if out and max(c.size * c.dtype.itemsize for c in cols) <= ON_CHIP_BYTES:
+            cols = _run_after(out[-1], cols)
+            default_registry.counter("gather.chunks_sequenced")
+        out.append(fn(*cols))
+    return _concat_chunks(out)
 
 
 @_scoped("dgraph.halo_extend")

@@ -117,7 +117,10 @@ def resolve_compute_dtype(dtype):
 
 # Column-chunk width for row gathers (ops.local.row_take). XLA's TPU
 # row-gather fast path covers one 128-lane tile; wider rows are gathered
-# in <=this many columns per pass. 0 disables splitting.
+# in <=this many columns per pass. 0 disables splitting. A chunk is also
+# the unit of on-chip placement of a gather's table
+# (collectives.map_vertex_chunks); 256 was turned down for the memory it
+# costs (docs/tuning.md).
 gather_col_block: int = int(os.environ.get("DGRAPH_TPU_GATHER_COL_BLOCK", "128"))
 
 # Halo exchange lowering: 'auto' (ppermute neighbor rounds when the plan's

@@ -76,13 +76,11 @@ class GraphConvLayer(nn.Module):
         # (comm.halo_extend) so chunking never multiplies all_to_alls.
         # Gated on: feature-separable activation (relu — softmax-style
         # activations normalize ACROSS features and must see full width)
-        # and a collective-free aggregation side.
-        from dgraph_tpu.comm.collectives import map_feature_chunks
-
-        D = self.out_features
-
-        def over_chunks(fn):
-            return map_feature_chunks(fn, D)
+        # and a collective-free aggregation side. Every chunk ends in a
+        # vertex-level [N, chunk] sum, so the chunks run one after the
+        # other (map_vertex_chunks: each gather table is live through its
+        # own gather only, which is what lets the compiler place it on chip).
+        from dgraph_tpu.comm.collectives import map_vertex_chunks
 
         # Overlap routing (plans carrying an interior/boundary split whose
         # resolved halo lowering is 'overlap'): issue the boundary exchange
@@ -105,18 +103,19 @@ class GraphConvLayer(nn.Module):
             h_stream = h_s if owner == "dst" else h_d
             if use_overlap:
                 halo_buf = self.comm.halo_exchange_overlap(h_stream, plan)
-                return over_chunks(
-                    lambda sl: self.comm.scatter_bias_relu_overlap(
-                        h_stream[:, sl], halo_buf[:, sl], h_bias[:, sl],
-                        plan, side=owner, edge_weight=edge_weight,
-                    )
+                return map_vertex_chunks(
+                    lambda hs, hb, b: self.comm.scatter_bias_relu_overlap(
+                        hs, hb, b, plan, side=owner, edge_weight=edge_weight,
+                    ),
+                    (h_stream, halo_buf, h_bias),
                 )
             h_ext = self.comm.halo_extend(h_stream, plan, side=stream)
-            return over_chunks(
-                lambda sl: self.comm.scatter_bias_relu(
-                    self.comm.local_take(h_ext[:, sl], plan, side=stream),
-                    h_bias[:, sl], plan, side=owner, edge_weight=edge_weight,
-                )
+            return map_vertex_chunks(
+                lambda t, b: self.comm.scatter_bias_relu(
+                    self.comm.local_take(t, plan, side=stream),
+                    b, plan, side=owner, edge_weight=edge_weight,
+                ),
+                (h_ext, h_bias),
             )
 
         separable = self.activation in (nn.relu, jax.nn.relu)
@@ -130,17 +129,17 @@ class GraphConvLayer(nn.Module):
 
                 w_int, w_bnd = overlap_edge_weight(edge_weight, plan)
 
-                def chunked_ov(sl):
+                def chunked_ov(halo_c, own_c, buf_c):
                     m_i = self.comm.interior_take(
-                        h_halo[:, sl], plan, side=plan.halo_side
-                    ) + self.comm.interior_take(h_own[:, sl], plan, side=owner)
+                        halo_c, plan, side=plan.halo_side
+                    ) + self.comm.interior_take(own_c, plan, side=owner)
                     m_i = self.activation(m_i)
                     if w_int is not None:
                         m_i = m_i * w_int[:, None]
                     agg = self.comm.interior_scatter_sum(m_i, plan, side=owner)
                     m_b = self.comm.boundary_take(
-                        halo_buf[:, sl], plan, side=plan.halo_side
-                    ) + self.comm.boundary_take(h_own[:, sl], plan, side=owner)
+                        buf_c, plan, side=plan.halo_side
+                    ) + self.comm.boundary_take(own_c, plan, side=owner)
                     m_b = self.activation(m_b)
                     if w_bnd is not None:
                         m_b = m_b * w_bnd[:, None]
@@ -148,20 +147,20 @@ class GraphConvLayer(nn.Module):
                         m_b, plan, side=owner
                     )
 
-                return over_chunks(chunked_ov)
+                return map_vertex_chunks(chunked_ov, (h_halo, h_own, halo_buf))
             hs_ext = self.comm.halo_extend(h_s, plan, side="src")
             hd_ext = self.comm.halo_extend(h_d, plan, side="dst")
 
-            def chunked(sl):
+            def chunked(hs_c, hd_c):
                 m = self.comm.local_take(
-                    hs_ext[:, sl], plan, side="src"
-                ) + self.comm.local_take(hd_ext[:, sl], plan, side="dst")
+                    hs_c, plan, side="src"
+                ) + self.comm.local_take(hd_c, plan, side="dst")
                 m = self.activation(m)
                 if edge_weight is not None:
                     m = m * edge_weight[:, None]
                 return self.comm.scatter_sum(m, plan, side=self.aggregate_to)
 
-            return over_chunks(chunked)
+            return map_vertex_chunks(chunked, (hs_ext, hd_ext))
 
         # full-width fallback: non-separable activation or halo-side
         # aggregation (chunking would repeat the reverse exchange)

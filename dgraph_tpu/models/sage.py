@@ -27,10 +27,9 @@ class SAGEConv(nn.Module):
     def __call__(self, x: jax.Array, plan: EdgePlan) -> jax.Array:
         from dgraph_tpu import config as _cfg
 
-        from dgraph_tpu.comm.collectives import map_feature_chunks
+        from dgraph_tpu.comm.collectives import map_vertex_chunks
 
         dt = _cfg.resolve_compute_dtype(self.dtype)
-        F = x.shape[-1]
         # cast BEFORE the edge pipeline: aggregating the raw f32 input
         # would run every [e_pad, F] take/scatter at double width (the
         # dtype-discipline rule — see tests/test_dtype_discipline.py)
@@ -41,24 +40,23 @@ class SAGEConv(nn.Module):
             # fly; boundary contributions merge once landed. One exchange
             # per layer, chunk-local work exactly as below.
             halo_buf = self.comm.halo_exchange_overlap(xa, plan)
-            agg = map_feature_chunks(
-                lambda sl: self.comm.gather_scatter_overlap(
-                    xa[:, sl], halo_buf[:, sl], plan
-                ),
-                F,
+            agg = map_vertex_chunks(
+                lambda xc, hb: self.comm.gather_scatter_overlap(xc, hb, plan),
+                (xa, halo_buf),
             )
         elif plan.halo_side != "dst":
             # feature-chunked neighbor sum (models/gcn.py rationale): the
             # per-edge op here is IDENTITY, so chunking is exact for any
             # activation; one full-width halo exchange, local work in
-            # <=col_block-wide slices, concat only at the vertex level
+            # <=col_block-wide slices run one after the other, concat only
+            # at the vertex level
             x_ext = self.comm.halo_extend(xa, plan, side="src")
-            agg = map_feature_chunks(
-                lambda sl: self.comm.scatter_sum(
-                    self.comm.local_take(x_ext[:, sl], plan, side="src"),
+            agg = map_vertex_chunks(
+                lambda xc: self.comm.scatter_sum(
+                    self.comm.local_take(xc, plan, side="src"),
                     plan, side="dst",
                 ),
-                F,
+                (x_ext,),
             )
         else:
             h_src = self.comm.gather(xa, plan, side="src")  # [e_pad, F]

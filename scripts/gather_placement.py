@@ -1,0 +1,60 @@
+"""Where a GCN cell's row-gather tables live, without a chip: the cell's
+train and eval steps compiled at the real size for a described v5e by
+``benchmark/tools/rehearse_w4.py`` (its graph, plan and Pallas branches;
+~10 s a cell on one chip's program, ~45 s for four), and each compiled
+module read by ``dgraph_tpu.analysis.hlo.gather_table_placement``:
+
+    JAX_PLATFORMS=cpu python3 scripts/gather_placement.py --workload gcn_arxiv.w1
+
+prints, before each of the tool's ``<train|eval> step, per chip`` lines,
+
+    gather tables on chip: 4 of 8; in HBM: bf16[2332672,128] (597.2 MB)
+
+(every row gather of the module: ``local_take``'s forward gathers, its
+backward's gathers by ``halo_sort_perm`` whose ``[E, C]`` table can never
+be placed, the halo exchange's send gathers) and after them the ties
+``map_vertex_chunks`` traced (``gather.chunks_sequenced``; it ties where a
+table slice fits on-chip memory). A table on chip is worth 4.3 against
+24.8 ms a gather in ``gcn_arxiv.w1`` (PERF.md, PR 31). Compile only: not a
+chip run, and no time comes from here.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main() -> int:
+    from benchmark.tools import rehearse_w4  # sets the CPU platform first
+
+    import jax
+
+    from dgraph_tpu.analysis.hlo import gather_table_placement, placement_line
+    from dgraph_tpu.obs.metrics import default_registry
+
+    compile_lowered = jax.stages.Lowered.compile
+
+    def compile_and_read(self, *args, **kwargs):
+        compiled = compile_lowered(self, *args, **kwargs)
+        print(placement_line(gather_table_placement(compiled.as_text())),
+              flush=True)
+        return compiled
+
+    jax.stages.Lowered.compile = compile_and_read
+    try:
+        rc = rehearse_w4.main()
+    finally:
+        jax.stages.Lowered.compile = compile_lowered
+    ties = default_registry.snapshot()["counters"].get(
+        "gather.chunks_sequenced", 0)
+    print(f"gather.chunks_sequenced: {ties:.0f} (both steps, and the "
+          f"parameter init on the small graph)")
+    return rc
+
+
+if __name__ == "__main__":
+    sys.exit(main())
