@@ -177,7 +177,7 @@ class _BaseComm:
 
     @_scoped("dgraph.comm.seq_attention")
     def seq_attention(self, q, k, v, *, causal: bool = False, kv_mask=None,
-                      impl: str = "ring"):
+                      impl: str = "ring", mask=None):
         """Exact attention over the axis-sharded token/vertex dimension.
 
         ``tpu`` mode runs ring attention (K/V blocks stream around the
@@ -190,21 +190,46 @@ class _BaseComm:
         shapes qualify (``config.use_flash_attention``).
 
         Args:
-          q/k/v: [T_loc, H, D] per-shard (full [T, H, D] in single mode).
+          q/k/v: [T_loc, H, D] per-shard (full [T, H, D] in single mode);
+            k and v may have fewer heads (grouped-query: KV head
+            ``j // (H / Hkv)`` serves query head j).
           kv_mask: [T_loc] 1.0 = real position (padding excluded from keys).
           impl: 'ring' (default; O(T/W) memory, ICI neighbor hops) or
             'ulysses' (2 all_to_alls, needs heads % axis == 0).
+          mask: a structured mask object beside ``causal``
+            (``parallel.sequence.BlockDiffusionMask``), over the full
+            sequence. ``single`` mode only: the dense oracle honours it
+            exactly; on a TPU the tile-skipping splash kernels do, with
+            native grouped-query heads, once their self-check has passed
+            for this kind of mask and head grouping. Ring and Ulysses
+            refuse it by name.
         """
         from dgraph_tpu.parallel.sequence import (
             _flash_applicable,
             _flash_dense,
+            _splash_dense,
             dense_attention,
+            repeat_kv,
             ring_attention,
             ulysses_attention,
         )
 
         if impl not in ("ring", "ulysses"):
             raise ValueError(f"unknown seq_attention impl: {impl!r}")
+        if mask is not None:
+            if causal or kv_mask is not None:
+                raise ValueError(
+                    f"the {mask.name} mask stands in causal's place and "
+                    f"takes no kv_mask (causal={causal})")
+            if self.graph_axis is not None:
+                raise NotImplementedError(
+                    f"{impl} attention has no {mask.name} mask: a structured "
+                    f"mask runs where one device holds the whole sequence "
+                    f"(ROADMAP R11)")
+            if _flash_applicable(q, require_pinned=True, mask=mask,
+                                 group=q.shape[1] // k.shape[1]):
+                return _splash_dense(q, k, v, mask=mask, scale=None)
+            return dense_attention(q, k, v, mask=mask)
         if self.graph_axis is None:
             # flash here ONLY on an explicit pinned True (post-self-check):
             # single mode is the dense ORACLE parity harnesses compare
@@ -213,6 +238,7 @@ class _BaseComm:
                 return _flash_dense(q, k, v, causal=causal, scale=None,
                                     kv_mask=kv_mask)
             return dense_attention(q, k, v, causal=causal, kv_mask=kv_mask)
+        k, v = repeat_kv(q, k, v)  # the ring and Ulysses know one head count
         if impl == "ulysses":
             return ulysses_attention(
                 q, k, v, self.graph_axis, causal=causal, kv_mask=kv_mask

@@ -21,6 +21,22 @@ Equations (d hidden, H heads of ``head_dim``, F intermediate, no bias):
 - the loop: ``h0 = E[tokens]``; for t = 1..R: ``h_t = RMSNorm_f(Stack(h_{t-1}))``;
   ``logits_t = W_head h_t``; exit gate ``lambda_t = sigmoid(w_g . h_t + b_g)``.
 
+The same layer, by build-time fields, is a sparse-expert block-diffusion
+decoder's (SDAR-30B-A3B, a Qwen3-MoE layer: ``sandwich_norm=False``,
+``qk_norm=True``, ``experts=HeldExperts(...)``, ``block_length > 0``):
+
+- pre-norm only: ``h <- h + Attn(RMSNorm1(h))``, ``h <- h + MoE(RMSNorm3(h))``;
+- ``Attn``: RMSNorm over each head's ``head_dim`` with a learned gain on q and
+  on k before the rotary embedding; ``num_kv_heads < num_heads`` KV heads read
+  where they lie (no repeat); with ``block_length`` the ``2L`` rows are the
+  noised and the clean copy of one sequence (row i of either carries position
+  i) under ``BlockDiffusionMask(L, block_length)`` in ``causal``'s place;
+- ``MoE``: ``p = softmax(W_r x)`` over all ``n_total`` experts in float32
+  (the router reads the norm's float32 output), the ``k`` largest, gates
+  renormalised over all chosen; this chip adds its ``n_held`` experts' part
+  (:func:`dgraph_tpu.parallel.expert.held_experts_ffn`) and leaves the rest
+  out.
+
 Everything but attention is token-local, so the attention collective is the
 only communication. Parameters are float32; matmuls run in
 ``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
@@ -31,6 +47,7 @@ in blocks of positions under recomputation.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Optional
 
@@ -68,9 +85,77 @@ def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
         [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class HeldExperts:
+    """The chip's share of a sparse-expert FFN: ``n_held`` of ``n_total``
+    routed experts of width ``width`` (ids ``first_held ...``), ``k`` a
+    token with their gates renormalised over the ``k`` chosen. ``rows``
+    bounds the buffer of rows routed here (None: the worst case, which can
+    drop nothing); a row past it is dropped and counted. Independent of the
+    objective: a causal model takes expert layers as a block-diffusion one
+    does."""
+
+    n_total: int
+    n_held: int
+    k: int
+    width: int
+    first_held: int = 0
+    rows: Optional[int] = None
+
+
+class _Kernel(nn.Module):
+    """A stack of expert kernels ``[n, fan_in, fan_out]`` under the leaf name
+    every kernel has."""
+
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param(
+            "kernel", nn.initializers.lecun_normal(batch_axis=(0,)),
+            self.shape)
+
+
+class HeldExpertsFFN(nn.Module):
+    """``(norm's float32 output [T, d]) -> (the held experts' part [T, d]
+    float32, stats)``; scope ``dgraph.lm.moe`` with ``router``, ``dispatch``,
+    ``experts``, ``combine``."""
+
+    spec: HeldExperts
+    comm: Any
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x32):
+        from dgraph_tpu.parallel.expert import held_experts_ffn, route_topk
+
+        sp, d = self.spec, x32.shape[-1]
+        with jax.named_scope("dgraph.lm.moe"):
+            with jax.named_scope("router"):
+                # float32 end to end: the k-th and (k+1)-th probabilities of
+                # a row can lie within a bf16 rounding of each other
+                logits = nn.Dense(
+                    sp.n_total, use_bias=False, dtype=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST, name="router")(x32)
+                gates, experts = route_topk(logits, sp.k)
+                # for a caller that asks (mutable=["intermediates"]): which
+                # experts each row chose, to set beside a reference's
+                self.sow("intermediates", "chosen", experts)
+            return held_experts_ffn(
+                x32.astype(self.dtype), gates, experts,
+                _Kernel((sp.n_held, d, sp.width), name="gate_proj")(),
+                _Kernel((sp.n_held, d, sp.width), name="up_proj")(),
+                _Kernel((sp.n_held, sp.width, d), name="down_proj")(),
+                first_held=sp.first_held, rows=sp.rows,
+                axis_name=self.comm.graph_axis)
+
+
 class LoopLMLayer(nn.Module):
-    """One decoder layer with sandwich norms; ``(h, rope) -> (h, None)``, the
-    signature ``nn.scan`` wants of a body."""
+    """One decoder layer; ``(h, rope) -> (h, stats)``, the signature
+    ``nn.scan`` wants of a body (``stats``: the expert layer's counts, None
+    for a dense FFN). Sandwich norms and a gated MLP by default (Ouro's);
+    ``sandwich_norm=False``, ``qk_norm``, ``experts``, ``block_length`` give
+    the pre-norm sparse-expert block-diffusion layer (module docstring)."""
 
     hidden: int
     num_heads: int
@@ -81,6 +166,10 @@ class LoopLMLayer(nn.Module):
     rms_eps: float = 1e-6
     dtype: Any = None
     attn_impl: str = "ring"
+    sandwich_norm: bool = True
+    qk_norm: bool = False
+    experts: Optional[HeldExperts] = None  # None: the gated MLP
+    block_length: int = 0  # > 0: rows [xt ; x0] under the block-diffusion mask
 
     @nn.compact
     def __call__(self, h, rope):  # [T_loc, hidden], (cos, sin)
@@ -94,30 +183,47 @@ class LoopLMLayer(nn.Module):
         n = h.shape[0]
         dense = functools.partial(nn.Dense, use_bias=False, dtype=dt)
         norm = functools.partial(RMSNorm, epsilon=self.rms_eps, dtype=dt)
+        post = (lambda name: norm(name=name)) if self.sandwich_norm \
+            else (lambda name: lambda y: y.astype(h.dtype))
 
         x = norm(name="norm_attn_in")(h)
         q = dense(H * D, name="q_proj")(x).reshape(n, H, D)
         k = dense(Hkv * D, name="k_proj")(x).reshape(n, Hkv, D)
         v = dense(Hkv * D, name="v_proj")(x).reshape(n, Hkv, D)
+        if self.qk_norm:  # over each head's D, one gain vector for all heads
+            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
         q, k = apply_rotary(q, *rope), apply_rotary(k, *rope)
-        if Hkv != H:  # grouped-query: each kv head serves H / Hkv query heads
-            k, v = (jnp.repeat(t, H // Hkv, axis=1) for t in (k, v))
-        a = self.comm.seq_attention(q, k, v, causal=True, impl=self.attn_impl)
-        a = dense(self.hidden, name="o_proj")(a.reshape(n, H * D))
-        h = h + norm(name="norm_attn_out")(a)
+        if self.block_length:
+            from dgraph_tpu.parallel.sequence import BlockDiffusionMask
 
+            a = self.comm.seq_attention(
+                q, k, v, impl=self.attn_impl,
+                mask=BlockDiffusionMask(n // 2, self.block_length))
+        else:
+            a = self.comm.seq_attention(q, k, v, causal=True,
+                                        impl=self.attn_impl)
+        a = dense(self.hidden, name="o_proj")(a.reshape(n, H * D))
+        h = h + post("norm_attn_out")(a)
+
+        if self.experts is not None:
+            u32 = RMSNorm(epsilon=self.rms_eps, dtype=jnp.float32,
+                          name="norm_mlp_in")(h)
+            m, stats = HeldExpertsFFN(self.experts, self.comm, dt,
+                                      name="experts")(u32)
+            return h + post("norm_mlp_out")(m), stats
         u = norm(name="norm_mlp_in")(h)
         m = nn.silu(dense(self.intermediate, name="gate_proj")(u)) \
             * dense(self.intermediate, name="up_proj")(u)
         m = dense(self.hidden, name="down_proj")(m)
-        return h + norm(name="norm_mlp_out")(m), None
+        return h + post("norm_mlp_out")(m), None
 
 
 class LoopPass(nn.Module):
     """One pass over the stack: ``num_layers`` layers under ``nn.scan``
     (parameters stacked on axis 0, each application rematerialised where
     ``remat``), then the final norm. ``(h, rope) -> (h_t, h_t)``: the carry
-    of the loop and the pass's exit state."""
+    of the loop and the pass's exit state (with expert layers, ``(h_t,
+    stats [num_layers, 4])`` in the second place)."""
 
     num_layers: int
     layer: dict  # LoopLMLayer's fields
@@ -130,15 +236,16 @@ class LoopPass(nn.Module):
             if self.remat:
                 cls = nn.remat(cls, prevent_cse=False)  # inside a scan
             stack = nn.scan(
-                cls, variable_axes={"params": 0}, split_rngs={"params": True},
+                cls, variable_axes={"params": 0, "intermediates": 0},
+                split_rngs={"params": True},
                 in_axes=nn.broadcast, length=self.num_layers)
-            h, _ = stack(**self.layer, name="layers")(h, rope)
+            h, stats = stack(**self.layer, name="layers")(h, rope)
             # rematerialised too: its float32 internals would otherwise be
             # saved once a pass
             norm_f = nn.remat(RMSNorm) if self.remat else RMSNorm
             h = norm_f(epsilon=self.layer["rms_eps"], dtype=h.dtype,
                        name="norm_f")(h)
-        return h, h
+        return h, (h if stats is None else (h, stats))
 
 
 class LoopLM(nn.Module):
@@ -166,6 +273,11 @@ class LoopLM(nn.Module):
     dtype: Any = None
     attn_impl: str = "ring"
     remat: bool = True
+    sandwich_norm: bool = True
+    qk_norm: bool = False
+    experts: Optional[HeldExperts] = None
+    block_length: int = 0  # > 0: trained by block diffusion (train/lm.py)
+    mask_token: Optional[int] = None  # the id a noised token is replaced by
 
     def setup(self):
         from dgraph_tpu import config as _cfg
@@ -176,10 +288,13 @@ class LoopLM(nn.Module):
             hidden=self.hidden_size, num_heads=self.num_heads,
             head_dim=self.head_dim, intermediate=self.intermediate,
             comm=self.comm, num_kv_heads=self.num_kv_heads,
-            rms_eps=self.rms_eps, dtype=self.dtype, attn_impl=self.attn_impl)
+            rms_eps=self.rms_eps, dtype=self.dtype, attn_impl=self.attn_impl,
+            sandwich_norm=self.sandwich_norm, qk_norm=self.qk_norm,
+            experts=self.experts, block_length=self.block_length)
         # the same parameters every pass: broadcast, not split
         loop = nn.scan(
             LoopPass, variable_broadcast="params",
+            variable_axes={"intermediates": 0},
             split_rngs={"params": False}, in_axes=nn.broadcast,
             length=self.loop_steps)
         self.stack = loop(self.num_layers, layer, self.remat)
@@ -191,7 +306,18 @@ class LoopLM(nn.Module):
     def hidden(self, tokens, positions):  # [T_loc] int32 each
         rope = rotary_tables(positions, self.head_dim, self.rope_theta)
         _, hs = self.stack(self.embed(tokens), rope)
-        return hs  # [loop_steps, T_loc, hidden]
+        # [loop_steps, T_loc, hidden]; with expert layers also their counts,
+        # [loop_steps, num_layers, 4] (parallel.expert.HELD_STATS)
+        return hs
+
+    def attention_mask(self, seq_len: int):
+        """The structured mask the layers attend under for a sequence of
+        ``seq_len`` tokens (None: causal)."""
+        if not self.block_length:
+            return None
+        from dgraph_tpu.parallel.sequence import BlockDiffusionMask
+
+        return BlockDiffusionMask(seq_len, self.block_length)
 
     def logits(self, h):
         with jax.named_scope("dgraph.lm.head"):
@@ -203,5 +329,7 @@ class LoopLM(nn.Module):
 
     def __call__(self, tokens, positions):
         hs = self.hidden(tokens, positions)
+        if self.experts is not None:
+            hs, _ = hs  # the expert layers' counts go with the trainer's step
         return self.logits(hs), (
             self.gate_logit(hs) if self.exit_gate else None)

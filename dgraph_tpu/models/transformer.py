@@ -31,10 +31,9 @@ class TransformerBlock(nn.Module):
     causal: bool = True
     attn_impl: str = "ring"  # or 'ulysses' (heads % axis == 0)
     # MoE FFN: one expert per rank of the SEQUENCE axis (the classic
-    # DeepSpeed-MoE axis fusion — tokens are already sharded over it, so
-    # routing is the standard two all_to_alls). 0 = dense FFN.
+    # DeepSpeed-MoE axis fusion — tokens are already sharded over it).
+    # 0 = dense FFN.
     moe_k: int = 0  # top-k routing (1 = switch, 2 = GShard/Mixtral)
-    moe_capacity_factor: float = 2.0
 
     @nn.compact
     def __call__(self, x):  # [T_loc, L]
@@ -80,8 +79,6 @@ class TransformerBlock(nn.Module):
 
         L = self.latent
         E = self.comm.get_world_size()
-        T_loc = y.shape[0]
-        cap = max(1, int(self.moe_capacity_factor * self.moe_k * T_loc / E))
         logits = nn.Dense(E, dtype=dt, name="router")(y)
         w1 = self.param(
             "moe_w1", nn.initializers.lecun_normal(), (1, L, 4 * L))
@@ -93,7 +90,7 @@ class TransformerBlock(nn.Module):
             return h @ p["w2"].astype(z.dtype)
 
         out = moe_apply(
-            y, logits, expert_fn, {"w1": w1[0], "w2": w2[0]}, cap,
+            y, logits, expert_fn, {"w1": w1[0], "w2": w2[0]},
             self.comm.graph_axis, k=self.moe_k,
         )
         if self.is_mutable_collection("losses"):
@@ -118,7 +115,6 @@ class SeqTransformerLM(nn.Module):
     dtype: Any = None
     attn_impl: str = "ring"
     moe_k: int = 0  # >0: expert-parallel FFN over the sequence axis
-    moe_capacity_factor: float = 2.0
 
     @nn.compact
     def __call__(self, tokens, positions):  # [T_loc] int32, [T_loc] int32
@@ -129,7 +125,6 @@ class SeqTransformerLM(nn.Module):
                 self.latent, self.num_heads, comm=self.comm,
                 dtype=self.dtype, attn_impl=self.attn_impl,
                 moe_k=self.moe_k,
-                moe_capacity_factor=self.moe_capacity_factor,
                 name=f"block_{i}",
             )(h)
         from dgraph_tpu import config as _cfg

@@ -48,6 +48,9 @@ class StepMetrics:
     grad_norm: Any = None
     mask_count: Any = None
     nonfinite_skipped: Any = None  # 0.0/1.0 from the non-finite step guard
+    # int32 [4], a sparse-expert LM's counts of the step
+    # (parallel.expert.HELD_STATS); not a field of the step record
+    moe_rows: Any = None
 
     # dict-style access so call sites written against the legacy metrics
     # dict (``m["loss"]``) take a StepMetrics unchanged

@@ -22,7 +22,8 @@ Maps the reference's parallelism inventory (SURVEY.md §2.3) onto mesh axes:
   :mod:`dgraph_tpu.parallel.pipeline`.
 - Tensor parallelism: Megatron column/row-parallel linear pairs —
   :mod:`dgraph_tpu.parallel.tensor`.
-- Expert parallelism: top-1 token-dispatch MoE over an ``expert`` axis —
+- Expert parallelism: a dropless top-k sparse-expert layer that holds a
+  share of the experts, alone or over an ``expert`` axis —
   :mod:`dgraph_tpu.parallel.expert`.
 
 Every strategy in SURVEY §2.3 (plus four the reference lacks) is therefore
@@ -30,10 +31,10 @@ implemented and tested on the virtual 8-device mesh.
 """
 
 from dgraph_tpu.parallel.expert import (
+    held_experts_ffn,
     load_balance_loss,
     moe_apply,
-    top1_dispatch,
-    topk_dispatch,
+    route_topk,
 )
 from dgraph_tpu.parallel.pipeline import pipeline_apply, stack_stage_params
 from dgraph_tpu.parallel.tensor import (
@@ -74,8 +75,8 @@ __all__ = [
     "shard_columns",
     "shard_rows",
     "moe_apply",
-    "top1_dispatch",
-    "topk_dispatch",
+    "held_experts_ffn",
+    "route_topk",
     "load_balance_loss",
     "pipeline_apply",
     "stack_stage_params",

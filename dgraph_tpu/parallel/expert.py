@@ -1,150 +1,41 @@
-"""Expert parallelism: top-k token-dispatch mixture-of-experts over a mesh
-axis (k=1 switch routing and k>=2 GShard/Mixtral-style mixtures).
+"""Expert parallelism: a sparse-expert layer that is TOLD which experts it
+holds (``n_held`` of ``n_total``, ids ``first_held ...``), routes over all of
+them, drops no token, and computes its own experts' part of the result over
+the rows routed to them (ROADMAP R9).
 
 Beyond-reference (SURVEY.md §2.3 lists expert parallelism as absent in the
-reference). One expert lives on each rank of an ``expert`` axis; a learned
-router picks an expert per token; tokens travel to their expert and back
-with the SAME padded ``all_to_all`` discipline as the halo exchange
-(static per-peer capacity, masked overflow) — XLA's compile-once model
-wants fixed shapes, so the classic "capacity factor" of production MoE
-layers is the exact analogue of this framework's ``s_pad`` halo padding.
+reference). The routes (``T * k`` of them, :func:`route_topk`) are ordered by
+expert with two sorts, the rows routed to held experts are gathered into one
+buffer in expert order, the experts run over the buffer, and each token sums
+its gate-weighted rows back: row gathers in both directions of
+differentiation, no scatter and no one-hot matrix. The router learns through
+the gate product.
 
-Dispatch math is all segment/one-hot primitives already used by the graph
-side: position-within-expert via a cumulative sum over the one-hot routing
-matrix (choice-major, so 1st choices claim capacity first), inverse
-routing by scatter into the dispatch slots' origin rows. Differentiable
-end to end — the router learns through the gate product: raw softmax
-probability at k=1 (the switch estimator), renormalized top-k gates at
-k>1 (GShard/Mixtral); the all_to_all transposes are all_to_alls.
+Two layers stand on that (:func:`held_experts_apply`):
+
+- :func:`held_experts_ffn`, the gated-SiLU FFN of a sparse-expert model at
+  published sizes, the held experts' rows multiplied as groups
+  (:func:`grouped_matmul`). With no mesh axis its partial sum is the chip's
+  share of an expert-parallel deployment (what the absent experts would add
+  is left out); with an axis the shares of the ranks are summed.
+- :func:`moe_apply`, one expert of any form a rank of an axis (k = 1 switch
+  routing, k >= 2 GShard/Mixtral mixtures): the ``n_held = 1`` case.
+
+Over an axis every rank gathers all tokens and their routes, computes its
+experts' part for all of them, and the parts are summed and scattered back
+(``all_gather`` / ``psum_scatter``): exact whatever the imbalance, at the
+price of every rank seeing every token. (The capacity-dropping
+``all_to_all`` exchange this module began with is gone: a production
+exchange is what is left of R9.)
 """
 
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-
-def topk_dispatch(
-    x: jax.Array,  # [T, F] this shard's tokens
-    router_logits: jax.Array,  # [T, E] router scores (E = axis size)
-    capacity: int,  # per-(src shard -> expert) slot budget (static)
-    axis_name: str,
-    *,
-    k: int = 2,
-    normalize_gates: bool = True,
-):
-    """Route each token to its top-k experts; returns everything the
-    combine step needs.
-
-    Slot assignment is CHOICE-MAJOR: every token's 1st choice claims
-    capacity before any 2nd choice does (the GShard priority rule), so
-    under pressure the layer degrades toward top-1 rather than dropping
-    primary routes. ``normalize_gates=True`` renormalizes the selected
-    gates to sum to 1 per token (the GShard/Mixtral convention);
-    ``False`` keeps raw softmax probabilities (the top-1 switch
-    estimator uses this).
-
-    Returns (expert_in, combine): ``expert_in`` [E*capacity, F] — the
-    tokens THIS rank's expert must process (peer p's block at rows
-    [p*capacity, (p+1)*capacity)); ``combine(expert_out)`` returns each
-    token's gate-weighted SUM over its k expert outputs (zeros for
-    dropped/overflow routes).
-    """
-    T, F = x.shape
-    E = lax.psum(1, axis_name)
-    if router_logits.shape[-1] != E:  # both static under shard_map
-        raise ValueError(
-            f"router width {router_logits.shape[-1]} != expert-axis size "
-            f"{E}: out-of-range expert ids would be silently dropped"
-        )
-    if not 1 <= k <= E:
-        raise ValueError(f"top-k k={k} must be in [1, {E}]")
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gates_k, experts_k = lax.top_k(probs, k)  # [T, k] each
-    if normalize_gates:
-        gates_k = gates_k / jnp.maximum(
-            gates_k.sum(axis=-1, keepdims=True), 1e-20)
-
-    # flatten routes CHOICE-major: row c*T + t = token t's c-th choice
-    ec = experts_k.T.reshape(k * T)  # [k*T]
-    gc = gates_k.T.reshape(k * T)
-    # position of each route within its expert's send block (one-hot
-    # cumsum — the plan builder's slot numbering, done in-jit)
-    onehot = jax.nn.one_hot(ec, E, dtype=jnp.int32)  # [k*T, E]
-    pos = (jnp.cumsum(onehot, axis=0) - 1)[jnp.arange(k * T), ec]
-    keep = pos < capacity  # overflow routes are dropped (capacity factor)
-
-    # build the per-expert send buffer [E, capacity, F]; distinct routes
-    # always land in distinct slots, so the scatter has no conflicts
-    slot = jnp.where(keep, ec * capacity + pos, E * capacity)
-    x_rep = jnp.tile(x, (k, 1))  # choice-major replication
-    send = jnp.zeros((E * capacity, F), x.dtype).at[slot].set(
-        x_rep, mode="drop"
-    ).reshape(E, capacity, F)
-    # tokens land on their expert's rank, peer blocks in rank order — the
-    # halo-exchange landing discipline
-    expert_in = lax.all_to_all(
-        send, axis_name, split_axis=0, concat_axis=0
-    ).reshape(E * capacity, F)
-
-    def combine(expert_out: jax.Array) -> jax.Array:  # [E*capacity, F']
-        back = lax.all_to_all(
-            expert_out.reshape(E, capacity, -1), axis_name,
-            split_axis=0, concat_axis=0,
-        ).reshape(E * capacity, -1)
-        rows = jnp.take(back, jnp.minimum(slot, E * capacity - 1), axis=0)
-        rows = jnp.where(keep[:, None], rows, 0.0)
-        # scale by the router gate: the router learns through this
-        # product (switch estimator at k=1; weighted mixture at k>1)
-        rows = rows * gc[:, None].astype(rows.dtype)
-        return rows.reshape(k, T, -1).sum(axis=0)
-
-    return expert_in, combine
-
-
-def top1_dispatch(
-    x: jax.Array,
-    router_logits: jax.Array,
-    capacity: int,
-    axis_name: str,
-):
-    """Top-1 switch routing = :func:`topk_dispatch` with k=1 and RAW
-    softmax gates (the switch gradient estimator)."""
-    return topk_dispatch(
-        x, router_logits, capacity, axis_name, k=1, normalize_gates=False
-    )
-
-
-def moe_apply(
-    x: jax.Array,  # [T, F] this shard's tokens
-    router_logits: jax.Array,  # [T, E]
-    expert_fn: Callable,  # (params, [N, F]) -> [N, F'] THIS rank's expert
-    expert_params,
-    capacity: int,
-    axis_name: str,
-    *,
-    k: int = 1,
-    normalize_gates: bool | None = None,
-) -> jax.Array:
-    """Full MoE layer: dispatch -> local expert -> combine.
-
-    ONE ``all_to_all`` each way — two per layer regardless of k (the
-    routes multiplex into the same padded buffers); overflow beyond
-    ``capacity`` per (shard, expert) pair contributes zeros (route a
-    residual around the layer upstream, as switch transformers do).
-    k=1 keeps the raw-probability switch estimator; k>1 defaults to
-    gate renormalization (GShard/Mixtral) unless overridden.
-    """
-    if normalize_gates is None:
-        normalize_gates = k > 1
-    expert_in, combine = topk_dispatch(
-        x, router_logits, capacity, axis_name, k=k,
-        normalize_gates=normalize_gates,
-    )
-    return combine(expert_fn(expert_params, expert_in))
 
 
 def load_balance_loss(router_logits: jax.Array, axis_name: str) -> jax.Array:
@@ -160,3 +51,277 @@ def load_balance_loss(router_logits: jax.Array, axis_name: str) -> jax.Array:
     frac = lax.pmean(frac, axis_name)
     mean_p = lax.pmean(mean_p, axis_name)
     return E * jnp.sum(frac * mean_p)
+
+
+def route_topk(router_logits: jax.Array, k: int, *, normalize: bool = True):
+    """(gates, experts), each ``[T, k]``: the ``k`` largest of
+    ``softmax(router_logits)`` over ALL experts, in float32; with
+    ``normalize`` the chosen gates are divided by their sum over all ``k``
+    chosen, wherever those experts live (GShard/Mixtral, ``norm_topk_prob``);
+    without, they stay the raw probabilities (the switch estimator)."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    gates, experts = lax.top_k(probs, k)
+    if normalize:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-20)
+    return gates, experts.astype(jnp.int32)
+
+
+class HeldRoutes(NamedTuple):
+    """Where the routes to the held experts lie in the row buffer (rows
+    ordered by expert, ``rows`` of them at most)."""
+
+    token: jax.Array  # [rows] the token of each buffer row
+    route: jax.Array  # [rows] its flat route index t * k + c
+    pos: jax.Array  # [T, k] the buffer row of each route (clamped)
+    valid: jax.Array  # [T, k] routed to a held expert, and inside the buffer
+    live: jax.Array  # [rows] this buffer row holds such a route
+    group_sizes: jax.Array  # [n_held] rows of each held expert, in order
+    stats: jax.Array  # [4] int32: rows here, most of one expert, dropped,
+    # rows the grouped products' tiles cover (HELD_STATS)
+
+
+HELD_STATS = ("rows_here", "rows_max_expert", "rows_dropped", "rows_tiled")
+
+
+def held_routes(experts: jax.Array, *, first_held: int, n_held: int,
+                rows: int, tile_rows: int) -> HeldRoutes:
+    """Order the ``T * k`` routes by expert and keep those to experts
+    ``first_held .. first_held + n_held - 1``: two sorts of ``T * k`` keys
+    (the order and its inverse), no scatter. ``rows`` bounds the buffer: a
+    route past it is DROPPED and counted (``stats[2]``); ``rows = T * k`` can
+    drop nothing."""
+    T, k = experts.shape
+    local = experts.reshape(T * k) - first_held
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
+    sizes = (key[:, None] == jnp.arange(n_held, dtype=jnp.int32)).sum(
+        0, dtype=jnp.int32)
+    ends = jnp.minimum(jnp.cumsum(sizes), rows)
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+    kept = ends - starts
+    here = sizes.sum()
+    tiles = jnp.where(kept > 0, (ends + tile_rows - 1) // tile_rows
+                      - starts // tile_rows, 0)
+    valid = (key.reshape(T, k) < n_held) & (pos < rows)
+    return HeldRoutes(
+        token=order[:rows] // k, route=order[:rows],
+        pos=jnp.minimum(pos, rows - 1), valid=valid,
+        live=jnp.arange(rows, dtype=jnp.int32) < ends[-1],
+        group_sizes=kept,
+        stats=jnp.stack([here, sizes.max(), here - ends[-1],
+                         tiles.sum() * tile_rows]).astype(jnp.int32))
+
+
+def _sum_slots(rows: jax.Array, pos: jax.Array, weight: jax.Array,
+               valid: jax.Array) -> jax.Array:
+    """``out[t] = sum_c weight[t, c] * rows[pos[t, c]]`` over the valid
+    slots, float32: ``k`` row gathers, no scatter."""
+    out = 0.0
+    for c in range(pos.shape[1]):
+        got = rows[pos[:, c]].astype(jnp.float32) * weight[:, c, None]
+        out = out + jnp.where(valid[:, c, None], got, 0.0)
+    return out
+
+
+@jax.custom_vjp
+def _to_buffer(x, routes: HeldRoutes):
+    """``x[token]``: each buffer row's token. Its transpose is
+    :func:`_sum_slots` (a token's cotangent is the sum over its routes), so
+    neither direction scatters."""
+    return x[routes.token]
+
+
+def _to_buffer_fwd(x, routes):
+    return x[routes.token], routes
+
+
+def _to_buffer_bwd(routes, g):  # g has x's dtype
+    ones = jnp.ones(routes.pos.shape, jnp.float32)
+    return _sum_slots(g, routes.pos, ones, routes.valid).astype(g.dtype), None
+
+
+_to_buffer.defvjp(_to_buffer_fwd, _to_buffer_bwd)
+
+
+@jax.custom_vjp
+def _from_buffer(y, gates, routes: HeldRoutes):
+    """``out[t] = sum over t's valid routes of gate * y[row of the route]``,
+    float32. The gates' gradient is worked out in the buffer's order from
+    the one gather the rows' gradient needs anyway."""
+    return _sum_slots(y, routes.pos, gates, routes.valid)
+
+
+def _from_buffer_fwd(y, gates, routes):
+    return _from_buffer(y, gates, routes), (y, gates, routes)
+
+
+def _from_buffer_bwd(res, g):
+    y, gates, routes = res
+    T, k = gates.shape
+    # the cotangent in the rows' dtype before it is gathered: it is the
+    # residual stream's, which has that dtype already, and a float32 buffer
+    # of rows is twice the bytes
+    g_rows = g.astype(y.dtype)[routes.token]  # [rows, d]
+    gate_rows = gates.reshape(T * k)[routes.route]
+    d_y = jnp.where(routes.live[:, None],
+                    g_rows * gate_rows[:, None].astype(y.dtype), 0)
+    d_gate_rows = jnp.where(
+        routes.live, jnp.einsum("rd,rd->r", g_rows, y,
+                                preferred_element_type=jnp.float32), 0.0)
+    d_gates = jnp.where(routes.valid, d_gate_rows[routes.pos], 0.0)
+    return d_y.astype(y.dtype), d_gates.astype(gates.dtype), None
+
+
+_from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
+
+# Row tile of the grouped products: an expert's rows start where the last
+# one's end, so a tile on a boundary is computed for both (PERF.md section 6,
+# PR 32: 512 rows and columns of up to 1024 read fastest on the chip).
+GROUPED_TILE_ROWS = 512
+
+
+def _grouped_tiling(m: int, k: int, n: int):
+    """Tiles of one grouped product: GROUPED_TILE_ROWS rows, and along each
+    of the other two dimensions the largest listed tile that divides it
+    (2048 -> 1024, 768 -> 768: no tile is padded), else the whole of it."""
+    def fit(x):
+        return next((t for t in (1024, 768, 512, 256, 128) if x % t == 0), x)
+
+    return min(GROUPED_TILE_ROWS, m), fit(k), fit(n)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   *, interpret: bool = False) -> jax.Array:
+    """``lhs[rows of group e] @ rhs[e]`` for every group, rows of a group
+    contiguous and in group order: ``[m, k] x [g, k, n] -> [m, n]`` in
+    ``lhs``'s dtype with float32 accumulation. Rows past the groups' end are
+    NOT written (callers mask them). On a TPU the Mosaic grouped matmul that
+    ships with JAX (``megablox.gmm``: only the row tiles that hold a group's
+    rows are computed, its backward is a grouped product and a transposed
+    one); elsewhere ``lax.ragged_dot``, the only one of the two that runs
+    off a TPU beside collectives (on the chip, at the SDAR cell's shape, it
+    takes 10.27 ms forward + backward a layer against the kernel's 7.33:
+    PERF.md section 6, PR 32). ``interpret`` is for the test that sets the
+    kernel beside it on the CPU."""
+    if jax.default_backend() == "tpu" or interpret:
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype,
+                            _grouped_tiling, None, None, False, interpret)
+    return lax.ragged_dot(lhs, rhs, group_sizes,
+                          preferred_element_type=lhs.dtype)
+
+
+def held_experts_apply(
+    x: jax.Array,  # [T, d] this shard's tokens
+    gates: jax.Array,  # [T, k] float32, of route_topk
+    experts: jax.Array,  # [T, k] int32 expert ids in 0 .. n_total - 1
+    rows_fn: Callable,  # (buffer [rows, d], HeldRoutes) -> [rows, d']
+    *,
+    n_held: int,
+    first_held: int = 0,
+    rows: Optional[int] = None,  # row buffer; None = the worst case
+    axis_name: Optional[str] = None,
+):
+    """``out[t] = sum over t's chosen experts e that are held here of
+    gate[t, e] * (expert e's row for x_t)``, float32 ``[T, d']``, and
+    ``stats`` (``HELD_STATS``, int32). ``rows_fn`` computes the held experts'
+    rows from the buffer of tokens routed to them (expert order,
+    ``routes.group_sizes`` rows each; what it returns past ``routes.live`` is
+    never read). The worst case is ``T * min(k, n_held)`` rows (a token's
+    ``k`` experts are distinct); no token is dropped while the rows routed
+    here fit ``rows``, and ``stats[2]`` counts those that did not.
+
+    With ``axis_name`` (inside ``shard_map``, tokens sharded over the axis,
+    rank r holding experts ``first_held + r * n_held ...``): every rank
+    gathers all tokens and their routes, computes its experts' part for all
+    of them, and the parts are summed and scattered back (``psum_scatter``):
+    the whole layer, still dropless.
+    """
+    if axis_name is not None:
+        first_held = first_held + lax.axis_index(axis_name) * n_held
+        x, gates, experts = (lax.all_gather(a, axis_name, tiled=True)
+                             for a in (x, gates, experts))
+    T, k = experts.shape
+    most = T * min(k, n_held)
+    rows = most if rows is None else min(rows, most)
+    routes = held_routes(experts, first_held=first_held, n_held=n_held,
+                         rows=rows, tile_rows=min(GROUPED_TILE_ROWS, rows))
+    with jax.named_scope("dispatch"):
+        xs = _to_buffer(x, routes)
+    with jax.named_scope("experts"):
+        y = rows_fn(xs, routes)
+    with jax.named_scope("combine"):
+        out = _from_buffer(y, gates, routes)
+        if axis_name is not None:
+            out = lax.psum_scatter(out, axis_name, tiled=True)
+    return out, routes.stats
+
+
+def held_experts_ffn(
+    x: jax.Array,  # [T, d] this shard's tokens, compute dtype
+    gates: jax.Array,
+    experts: jax.Array,
+    w_gate: jax.Array,  # [n_held, d, f] the held experts' weights
+    w_up: jax.Array,  # [n_held, d, f]
+    w_down: jax.Array,  # [n_held, f, d]
+    *,
+    first_held: int = 0,
+    rows: Optional[int] = None,
+    axis_name: Optional[str] = None,
+):
+    """The held experts' part of a gated-SiLU sparse FFN, expert e's row for
+    a token being ``W_down,e (silu(W_gate,e x_t) * W_up,e x_t)``: three
+    grouped products over the buffer (:func:`held_experts_apply`,
+    :func:`grouped_matmul`). The gradient reaches the router through
+    ``gates``."""
+    dt = x.dtype
+
+    def ffn(xs, routes):
+        # rows past the routed ones are not written by the products: zeroed
+        # BEFORE the nonlinearity, so that nothing downstream or in the
+        # backward pass ever multiplies what happens to lie there
+        live = routes.live[:, None]
+        g = jnp.where(live, grouped_matmul(
+            xs, w_gate.astype(dt), routes.group_sizes), 0)
+        u = jnp.where(live, grouped_matmul(
+            xs, w_up.astype(dt), routes.group_sizes), 0)
+        return jnp.where(live, grouped_matmul(
+            jax.nn.silu(g) * u, w_down.astype(dt), routes.group_sizes), 0)
+
+    return held_experts_apply(
+        x, gates, experts, ffn, n_held=w_gate.shape[0], first_held=first_held,
+        rows=rows, axis_name=axis_name)
+
+
+def moe_apply(
+    x: jax.Array,  # [T, F] this shard's tokens
+    router_logits: jax.Array,  # [T, E], E = the axis's size
+    expert_fn: Callable,  # (params, [N, F]) -> [N, F'] THIS rank's expert
+    expert_params,
+    axis_name: str,
+    *,
+    k: int = 1,
+    normalize_gates: Optional[bool] = None,
+) -> jax.Array:
+    """A mixture of one expert a rank of ``axis_name``, each of any form:
+    every token's gate-weighted sum over its ``k`` chosen experts' outputs,
+    in ``x``'s dtype, dropless (:func:`held_experts_apply` with
+    ``n_held = 1``; the rank's expert runs densely over its buffer). k = 1
+    keeps the raw-probability switch estimator; k > 1 renormalises the chosen
+    gates (GShard/Mixtral) unless overridden."""
+    E = lax.psum(1, axis_name)
+    if router_logits.shape[-1] != E:  # both static under shard_map
+        raise ValueError(
+            f"router width {router_logits.shape[-1]} != expert-axis size "
+            f"{E}: an expert id past the axis would be held by no rank")
+    if not 1 <= k <= E:
+        raise ValueError(f"top-k k={k} must be in [1, {E}]")
+    gates, experts = route_topk(
+        router_logits, k, normalize=k > 1 if normalize_gates is None
+        else normalize_gates)
+    out, _ = held_experts_apply(
+        x, gates, experts, lambda xs, routes: expert_fn(expert_params, xs),
+        n_held=1, axis_name=axis_name)
+    return out.astype(x.dtype)

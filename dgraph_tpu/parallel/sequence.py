@@ -27,9 +27,12 @@ no hand-written transpose needed (pinned in tests/test_sequence.py).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Optional
+from typing import ClassVar, Optional
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +42,78 @@ NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)  # finite -inf stand-in:
 # keeps the online-softmax recurrence NaN-free for fully-masked blocks
 # (exp(NEG_BIG - NEG_BIG) would be exp(0); masked probabilities are
 # re-zeroed explicitly, see below)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask:
+    """The training mask of block diffusion in its vectorised form (one pass
+    over the noised and the clean copy of a sequence).
+
+    The ``2 * seq_len`` rows are ``[xt ; x0]``: row ``i < seq_len`` is token
+    ``i`` of the noised copy, row ``seq_len + i`` token ``i`` of the clean
+    one; tokens lie in blocks of ``block``. Row q may attend row k iff
+
+    - both are noised and in the same block (a block denoises jointly), or
+    - q is noised, k is clean and k's block is EARLIER than q's, or
+    - both are clean and k's block is not later than q's (block-causal);
+
+    a clean row never attends a noised one. ``seq_len * (seq_len + block)``
+    of the ``4 seq_len^2`` pairs are allowed, and every row has at least its
+    own block. Static and hashable: a structured mask is a build-time
+    constant of a program, beside ``causal``.
+    """
+
+    seq_len: int
+    block: int
+    name: ClassVar[str] = "block_diffusion"
+
+    def __post_init__(self):
+        if self.block < 1 or self.seq_len % self.block:
+            raise ValueError(
+                f"block {self.block} does not divide seq_len {self.seq_len}")
+
+    @property
+    def rows(self) -> int:
+        return 2 * self.seq_len
+
+    def allowed(self, q_ids, k_ids):
+        """May row ``q_ids`` attend row ``k_ids``? Broadcasts; numpy or jax
+        integer arrays alike (the splash kernel calls it on tiles of ids)."""
+        L, B = self.seq_len, self.block
+        q_clean, k_clean = q_ids >= L, k_ids >= L
+        if B & (B - 1):
+            blk = lambda i: i // B
+        else:  # a shift: cheaper than a division inside a kernel
+            blk = lambda i: i >> (B.bit_length() - 1)
+        qb = blk(q_ids - L * q_clean)
+        kb = blk(k_ids - L * k_clean)
+        # a clean key of an earlier block, for either kind of query; or the
+        # query's own block in the query's own copy
+        return (k_clean & (kb < qb)) | ((q_clean == k_clean) & (kb == qb))
+
+    def pairs(self) -> int:
+        return self.seq_len * (self.seq_len + self.block)
+
+    def dense(self) -> np.ndarray:
+        """The ``[rows, rows]`` boolean matrix, written out (small sizes)."""
+        ids = np.arange(self.rows)
+        return np.asarray(self.allowed(ids[:, None], ids[None, :]))
+
+    def tile_map(self, tile: int) -> np.ndarray:
+        """``[rows / tile, rows / tile]`` bool: which ``tile x tile`` tiles of
+        the square hold an allowed pair (what a tile-skipping kernel visits).
+        The mask is constant within a pair of blocks, so one row a block is
+        looked at."""
+        if tile % self.block or self.rows % tile:
+            raise ValueError(f"tile {tile} against block {self.block}, "
+                             f"rows {self.rows}")
+        ids = np.arange(0, self.rows, self.block)
+        per, n = tile // self.block, self.rows // tile
+        m = np.asarray(self.allowed(ids[:, None], ids[None, :]))
+        return m.reshape(n, per, n, per).any(axis=(1, 3))
+
+    def tile_pairs(self, tile: int) -> int:
+        return int(self.tile_map(tile).sum()) * tile * tile
 
 
 def _block_attend(q, k, v, m, l, o, allowed, scale):
@@ -166,15 +241,36 @@ def _zero_padded_rows(out: jax.Array, kv_mask: jax.Array) -> jax.Array:
     return out * (kv_mask > 0).astype(out.dtype)[:, None, None]
 
 
+def repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
+    """Grouped-query heads for an implementation that knows one head count:
+    KV head ``j // (H / Hkv)`` serves query head ``j``, so each KV head is
+    repeated ``H / Hkv`` times along the head axis. (The splash path reads
+    the KV heads where they lie and never calls this.)"""
+    H, Hkv = q.shape[1], k.shape[1]
+    if H == Hkv:
+        return k, v
+    if H % Hkv:
+        raise ValueError(f"heads {H} not divisible by kv heads {Hkv}")
+    return tuple(jnp.repeat(t, H // Hkv, axis=1) for t in (k, v))
+
+
 def dense_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = False, scale: Optional[float] = None,
-    kv_mask: Optional[jax.Array] = None,
+    kv_mask: Optional[jax.Array] = None, mask=None,
 ) -> jax.Array:
     """Single-device oracle: softmax(q k^T) v over the FULL sequence
-    ([T, H, D] inputs). The equivalence target for :func:`ring_attention`
-    (tests/test_sequence.py) and the small-sequence fallback."""
+    ([T, H, D] queries; keys and values may have fewer, grouped-query,
+    heads). ``mask``: a structured mask object (``BlockDiffusionMask``),
+    honoured exactly, in ``causal``'s place. The equivalence target for
+    :func:`ring_attention` and the kernels (tests/test_sequence.py) and the
+    small-sequence fallback."""
     T, H, D = q.shape
+    k, v = repeat_kv(q, k, v)
+    if mask is not None and (causal or mask.rows != T):
+        raise ValueError(
+            f"{mask.name} mask over {mask.rows} rows against causal={causal}, "
+            f"T={T}: a structured mask stands in causal's place, on its rows")
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     logits = jnp.einsum(
@@ -186,6 +282,9 @@ def dense_attention(
         allowed = allowed & (
             jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
         )
+    if mask is not None:
+        allowed = allowed & mask.allowed(
+            jnp.arange(T)[:, None], jnp.arange(T)[None, :])
     logits = jnp.where(allowed[:, None, :], logits, NEG_BIG)  # bcast to heads
     p = jax.nn.softmax(logits, axis=-1)
     p = p * allowed[:, None, :]
@@ -251,9 +350,13 @@ def ulysses_attention(
     return head_to_seq(out)
 
 
-def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False) -> bool:
+def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
+                      mask=None, group: int = 1) -> bool:
     """Use the Mosaic flash-attention kernel for a full-sequence dense
-    attention site?
+    attention site? With a structured ``mask`` the kernel is the splash one,
+    which additionally needs ITS self-check passed in this process for this
+    kind of mask and this many query heads a KV head (``group``): no flag
+    stands in for that.
 
     Trace-time decision: config tri-state (``DGRAPH_TPU_FLASH_ATTN``) +
     shape constraints of the TPU kernel (T a multiple of its 128 query
@@ -277,6 +380,8 @@ def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False) -> bool:
         # process (the scatter kernels' central-veto discipline); an
         # explicit pinned True is the operator's override
         return False
+    if mask is not None and (mask.name, group) not in _splash_verified:
+        return False
     T, _, D = qh.shape
     return T % 128 == 0 and D % 128 == 0
 
@@ -290,6 +395,7 @@ def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
     T, H, D = qh.shape
+    kh, vh = repeat_kv(qh, kh, vh)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     # kernel layout: [batch, heads, T, D]
@@ -315,14 +421,87 @@ def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
 # at long T; FLASH_BLOCK is what the chip measured fastest at T = 8192,
 # H = 16, D = 128 (PERF.md, section 6, PR 28).
 FLASH_BLOCK = 1024
+# Columns of a kv tile the splash kernels take through the softmax at a time:
+# what the chip measured fastest under the block-diffusion mask at 16384 rows,
+# 32 heads on 4 (forward 19.5 ms at 256, 23.8 at 512, 22.1 at 128; PERF.md,
+# section 6, PR 32).
+SPLASH_KV_COMPUTE = 256
 
 
-def _flash_block_sizes(fa, T: int):
-    """One tile edge for every block of the kernels: the largest power of
-    two <= FLASH_BLOCK that divides T (T is a multiple of 128 here)."""
+def flash_tile(T: int) -> int:
+    """The tile edge of every block of the kernels: the largest power of two
+    <= FLASH_BLOCK that divides T (T is a multiple of 128 here)."""
     b = FLASH_BLOCK
     while T % b:
         b //= 2
+    return b
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_mask(mask, heads: int):
+    """``mask`` as the splash kernels take one: ``heads`` copies of a mask
+    they compute tile by tile from the row ids (nothing of size T x T is
+    ever stored; a tile with no allowed pair is skipped, one with all of
+    them is not masked)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as sm)
+
+    class Computed(sm._ComputableMask):
+        def __init__(self):
+            super().__init__(shape=(mask.rows, mask.rows),
+                             mask_function=mask.allowed)
+
+        def __eq__(self, other):
+            return type(other) is type(self)
+
+        def __hash__(self):
+            return hash((type(self), mask))
+
+    return sm.MultiHeadMask([Computed()] * heads)
+
+
+def _splash_dense(qh, kh, vh, *, mask, scale, interpret: bool = False):
+    """[T, H, D] queries on [T, Hkv, D] keys and values under a structured
+    mask, via ``jax.experimental.pallas.ops.tpu.splash_attention``: tiles of
+    ``flash_tile(T)`` rows, skipped where the mask allows no pair, forward
+    and backward Mosaic kernels with their own VJP. Grouped-query heads are
+    native: one multi-query kernel over the ``H / Hkv`` query heads of a KV
+    head, mapped over the KV heads; K and V are read where they lie."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+
+    T, H, D = qh.shape
+    Hkv = kh.shape[1]
+    if H % Hkv or mask.rows != T:
+        raise ValueError(f"heads {H} on {Hkv} kv heads, T={T} under a mask "
+                         f"over {mask.rows} rows")
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+    kernel = sk.make_splash_mqa_single_device(
+        _splash_mask(mask, H // Hkv), interpret=interpret,
+        block_sizes=_splash_block_sizes(sk, T))
+    # kernel layout: [kv heads, query heads of one kv head, T, D]; the kernel
+    # has no scale of its own
+    q4 = (qh * jnp.asarray(scale, qh.dtype)).transpose(1, 0, 2).reshape(
+        Hkv, H // Hkv, T, D)
+    out = jax.vmap(kernel)(q4, kh.transpose(1, 0, 2), vh.transpose(1, 0, 2))
+    return out.reshape(H, T, D).transpose(1, 0, 2).astype(qh.dtype)
+
+
+def _splash_block_sizes(sk, T: int):
+    """Square tiles of ``flash_tile(T)`` for the three splash kernels, the
+    softmax taken over SPLASH_KV_COMPUTE columns of a kv tile at a time."""
+    b = flash_tile(T)
+    c = min(b, SPLASH_KV_COMPUTE)
+    return sk.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=c,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=c,
+        block_q_dq=b, block_kv_dq=b)
+
+
+def _flash_block_sizes(fa, T: int):
+    """One tile edge for every block of the flash kernels."""
+    b = flash_tile(T)
     return fa.BlockSizes(
         block_q=b, block_k_major=b, block_k=b, block_b=1,
         block_q_major_dkv=b, block_k_major_dkv=b, block_k_dkv=b,
@@ -332,19 +511,28 @@ def _flash_block_sizes(fa, T: int):
 # Auto-mode flash engages only after flash_attention_selfcheck() passes
 # in this process (pinned config True bypasses — operator override).
 _flash_verified = False
+# (mask kind, query heads a kv head) pairs whose splash self-check passed
+_splash_verified: set = set()
 
 
-def flash_attention_selfcheck() -> bool:
+def flash_attention_selfcheck(mask=None, group: int = 1) -> bool:
     """Chip-gated equivalence check vs :func:`dense_attention` (the same
     Mosaic-divergence rationale as bench.py's scatter self-checks: the
     kernel class is invisible to CPU CI). Passing LATCHES auto-mode flash
     on for this process; returns False off-TPU.
+
+    With a structured ``mask`` (and ``group`` query heads a KV head) the
+    splash kernels are checked too, forward and backward, under a mask of the
+    same kind over four tiles a side (whole, partial and skipped tiles all
+    occur) and two KV heads; passing latches that pair in
+    ``_splash_verified``. True only if everything asked for passed.
     """
     global _flash_verified
-    import numpy as np
 
     if jax.default_backend() != "tpu":
         return False
+    if mask is not None:
+        return flash_attention_selfcheck() and _splash_selfcheck(mask, group)
     rng = np.random.default_rng(3)
     T, H, D = 256, 2, 128
     q, k, v = (
@@ -388,6 +576,46 @@ def flash_attention_selfcheck() -> bool:
     except Exception:
         return False
     _flash_verified = True
+    return True
+
+
+def _splash_selfcheck(mask, group: int, *, interpret: bool = False) -> bool:
+    """Splash forward and backward against the dense oracle under a mask of
+    ``mask``'s kind; see :func:`flash_attention_selfcheck`. The oracle runs
+    one KV head at a time (its ``[T, group, T]`` float32 logits)."""
+    rows = 4 * (128 if interpret else FLASH_BLOCK)  # four tiles a side
+    small = dataclasses.replace(mask, seq_len=rows // 2)
+    rng = np.random.default_rng(5)
+    Hkv, D = 2, 128
+    q, w = (jnp.asarray(rng.standard_normal((rows, Hkv * group, D)),
+                        jnp.bfloat16) for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((rows, Hkv, D)), jnp.bfloat16)
+            for _ in range(2))
+    close = lambda a, b: np.allclose(
+        np.asarray(a, np.float32), np.asarray(b, np.float32),
+        rtol=5e-2, atol=5e-2)
+
+    def both(attend, q_, k_, v_, w_):
+        f = lambda *a: (attend(*a).astype(jnp.float32)
+                        * w_.astype(jnp.float32)).sum()
+        return (attend(q_, k_, v_),) + jax.grad(f, argnums=(0, 1, 2))(
+            q_, k_, v_)
+
+    try:
+        got = both(lambda *a: _splash_dense(
+            *a, mask=small, scale=None, interpret=interpret), q, k, v, w)
+        for j in range(Hkv):
+            heads = slice(j * group, (j + 1) * group)
+            want = both(lambda *a: dense_attention(*a, mask=small),
+                        q[:, heads], k[:, j:j + 1], v[:, j:j + 1],
+                        w[:, heads])
+            mine = (got[0][:, heads], got[1][:, heads],
+                    got[2][:, j:j + 1], got[3][:, j:j + 1])
+            if not all(close(a, b) for a, b in zip(mine, want)):
+                return False
+    except Exception:
+        return False
+    _splash_verified.add((mask.name, group))
     return True
 
 

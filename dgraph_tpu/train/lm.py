@@ -29,6 +29,12 @@ positions under recomputation, so no ``[R, T, vocab]`` tensor is ever live. A
 model that returns logits directly
 (:class:`~dgraph_tpu.models.transformer.SeqTransformerLM`, whose MoE blocks
 also sow an auxiliary loss) goes through the same step as one pass.
+
+A model with ``block_length > 0`` is trained by block diffusion instead
+(:func:`block_diffusion_loss_sum`): a batch is ``(tokens, masked, weight)``,
+the stack runs over the ``2L`` rows ``[xt ; x0]`` under the block-diffusion
+mask, and the loss is the masked-token cross-entropy on the noised rows,
+weighted per block by ``1 / t_b``, over ``L``.
 """
 
 from __future__ import annotations
@@ -72,10 +78,12 @@ def lm_mesh(world_size: int, devices=None):
 
 
 def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
-                      head_dim: int) -> str:
+                      head_dim: int, mask=None, group: int = 1) -> str:
     """Decide, before anything is traced, which attention implementation
     ``comm.seq_attention`` will run, and say which: 'flash', 'dense', 'ring',
-    'ulysses+flash' or 'ulysses+dense'.
+    'ulysses+flash' or 'ulysses+dense'; under a structured ``mask`` (with
+    ``group`` query heads a KV head) 'splash' or 'dense', the self-check then
+    covering the splash kernels for that mask and grouping as well.
 
     Wherever a device holds a full-sequence view the Mosaic flash kernel is
     engaged only after ``flash_attention_selfcheck()`` passed on this chip
@@ -87,17 +95,23 @@ def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
     from dgraph_tpu.parallel import sequence as seq
 
     world = comm.get_world_size()
+    if mask is not None and comm.graph_axis is not None:
+        raise NotImplementedError(
+            f"the {mask.name} mask runs where one device holds the whole "
+            f"sequence; world size {world} shards it (ROADMAP R11)")
     if comm.graph_axis is not None and attn_impl == "ring":
         return "ring"
     if cfg.flash_attention_enabled():
-        cfg.set_flags(use_flash_attention=seq.flash_attention_selfcheck())
+        cfg.set_flags(
+            use_flash_attention=seq.flash_attention_selfcheck(mask, group))
     if comm.graph_axis is None:
         t_full, heads, prefix = t_local, num_heads, ""
     else:  # ulysses: the full sequence, a share of the heads
         t_full, heads, prefix = t_local * world, num_heads // world, "ulysses+"
     view = jax.ShapeDtypeStruct((t_full, heads, head_dim), jnp.float32)
-    if seq._flash_applicable(view, require_pinned=comm.graph_axis is None):
-        return prefix + "flash"
+    if seq._flash_applicable(view, require_pinned=comm.graph_axis is None,
+                             mask=mask, group=group):
+        return prefix + ("flash" if mask is None else "splash")
     if heads * t_full * t_full * 4 > DENSE_LOGITS_LIMIT_BYTES:
         raise RuntimeError(
             f"attention over T={t_full} with {heads} heads would materialise "
@@ -185,18 +199,37 @@ def _is_looped(model) -> bool:
     return hasattr(model, "hidden") and hasattr(model, "loop_steps")
 
 
+def _has_experts(model) -> bool:
+    return getattr(model, "experts", None) is not None
+
+
+def hidden_states(model, params, tokens, positions):
+    """(the exit states ``[passes, T, d]`` of a looped model, its expert
+    layers' counts ``[passes, layers, 4]`` or None for dense FFNs)."""
+    out = model.apply(params, tokens, positions, method="hidden")
+    return out if _has_experts(model) else (out, None)
+
+
+def expert_counts(stats: jax.Array) -> jax.Array:
+    """One step's counts, int32 ``[4]`` (``parallel.expert.HELD_STATS``), of
+    the expert layers' ``[passes, layers, 4]``: sums over the layers; the
+    most rows one expert got is a maximum."""
+    stats = stats.reshape(-1, stats.shape[-1])
+    return stats.sum(0).at[1].set(stats[:, 1].max())
+
+
 def local_loss_sum(model, params, tokens, comm, *, seq_len: int,
                    beta: float = 0.0, loss_block: Optional[int] = None):
     """(sum over this shard's scored positions of the per-position loss,
-    the model's own auxiliary loss): per shard, inside ``shard_map`` where
-    the communicator has an axis."""
+    the model's own auxiliary loss, its expert layers' counts or None): per
+    shard, inside ``shard_map`` where the communicator has an axis."""
     t_loc = tokens.shape[0]
     rank = 0 if comm.graph_axis is None else lax.axis_index(comm.graph_axis)
     positions = rank * t_loc + jnp.arange(t_loc, dtype=jnp.int32)
     targets, valid = next_token_targets(tokens, comm, seq_len)
-    aux = 0.0
+    aux, stats = 0.0, None
     if _is_looped(model):
-        hs = model.apply(params, tokens, positions, method="hidden")
+        hs, stats = hidden_states(model, params, tokens, positions)
         gated = model.exit_gate and model.loop_steps > 1
         if not gated:
             hs = hs[-1:]  # the last pass exits with certainty
@@ -221,22 +254,68 @@ def local_loss_sum(model, params, tokens, comm, *, seq_len: int,
             logp = jax.nn.log_softmax(logits.astype(jnp.float32))
             per_pos = -jnp.take_along_axis(
                 logp, targets[:, None], axis=1)[:, 0]
-    return jnp.where(valid, per_pos, 0.0).sum(), aux
+    return jnp.where(valid, per_pos, 0.0).sum(), aux, stats
+
+
+def _is_block_diffusion(model) -> bool:
+    return bool(getattr(model, "block_length", 0))
+
+
+def block_diffusion_loss_sum(model, params, batch, comm, *,
+                             loss_block: Optional[int] = None):
+    """(sum over the noised tokens of ``weight * CE(logits(xt row i),
+    x0_i)``, the expert layers' counts or None) for one sequence
+    ``batch = (tokens [L] int32, masked [L] bool, weight [L] float32)``,
+    noised on the host with the batch: ``xt`` is ``tokens`` with the masked
+    positions replaced by ``model.mask_token``, and ``weight`` is ``1 / t_b``
+    of the token's block. The stack runs once over the ``2L`` rows
+    ``[xt ; x0]`` (row i of either copy at position i) under
+    ``BlockDiffusionMask(L, model.block_length)``; the head and the
+    cross-entropy (no shift) run over the ``L`` noised rows only, in blocks
+    under recomputation."""
+    tokens, masked, weight = batch  # (a sharded sequence: seq_attention refuses)
+    L = tokens.shape[0]
+    rows = jnp.concatenate(
+        [jnp.where(masked, jnp.int32(model.mask_token), tokens), tokens])
+    positions = jnp.tile(jnp.arange(L, dtype=jnp.int32), 2)
+    hs, stats = hidden_states(model, params, rows, positions)
+    with jax.named_scope("dgraph.lm.exit_loss"):
+        block = loss_block or loss_block_size(L, model.vocab)
+        ce = blockwise_cross_entropy(
+            lambda h: model.apply(params, h, method="logits"),
+            hs[-1:, :L], tokens, block)[0]
+        total = jnp.where(masked, weight.astype(jnp.float32) * ce, 0.0).sum()
+    return total, stats
 
 
 def make_lm_loss(model, mesh, comm, *, seq_len: int, beta: float = 0.0,
                  loss_block: Optional[int] = None, aux_weight: float = 0.0,
                  param_specs=None):
     """``(params, tokens [T]) -> loss``: the mean over the ``T - 1`` scored
-    positions, under ``shard_map`` where the sequence is sharded."""
+    positions, under ``shard_map`` where the sequence is sharded; for a
+    block-diffusion model ``(params, (tokens, masked, weight)) -> loss``, the
+    weighted sum over ``T``. A model with expert layers (``model.experts``)
+    gives ``(loss, expert_counts)``, whatever its objective."""
+    counted = _has_experts(model)
+    if counted and comm.graph_axis is not None:
+        raise NotImplementedError(
+            "a LoopLM's expert layers over a mesh axis need their kernels "
+            "sharded over it and their counts summed; the trainer holds one "
+            "rank's share of the experts today (ROADMAP R9)")
 
-    def body(params, tokens):
-        total, aux = local_loss_sum(
-            model, params, tokens, comm, seq_len=seq_len, beta=beta,
-            loss_block=loss_block)
-        if comm.graph_axis is not None:
-            total = lax.psum(total, comm.graph_axis)
-        return total / (seq_len - 1) + aux_weight * aux
+    def body(params, batch):
+        if _is_block_diffusion(model):
+            total, stats = block_diffusion_loss_sum(
+                model, params, batch, comm, loss_block=loss_block)
+            loss = total / seq_len
+        else:
+            total, aux, stats = local_loss_sum(
+                model, params, batch, comm, seq_len=seq_len, beta=beta,
+                loss_block=loss_block)
+            if comm.graph_axis is not None:
+                total = lax.psum(total, comm.graph_axis)
+            loss = total / (seq_len - 1) + aux_weight * aux
+        return (loss, expert_counts(stats)) if counted else loss
 
     if comm.graph_axis is None:
         return body
@@ -248,7 +327,7 @@ def make_lm_loss(model, mesh, comm, *, seq_len: int, beta: float = 0.0,
                   P(comm.graph_axis)),
         out_specs=P(),
         **shard_map_checks(relax="the neighbour-token ppermute and the MoE "
-                                 "all_to_alls are replicated by construction"),
+                                 "psum_scatter are replicated by construction"),
     )
 
 
@@ -264,13 +343,18 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, mesh,
         model, mesh, comm, seq_len=seq_len, beta=beta, loss_block=loss_block,
         aux_weight=aux_weight, param_specs=param_specs)
 
+    counted = _has_experts(model)
+
     def lm_train_step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
+        out, grads = jax.value_and_grad(loss_fn, has_aux=counted)(
+            params, tokens)
+        loss, counts = out if counted else (out, None)
         gn = optax.global_norm(grads) if step_metrics else None
         with jax.named_scope("dgraph.lm.optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-        return params, opt_state, StepMetrics(loss=loss, grad_norm=gn)
+        return params, opt_state, StepMetrics(
+            loss=loss, grad_norm=gn, moe_rows=counts)
 
     return jax.jit(lm_train_step, donate_argnums=(0, 1) if donate else ())
 
@@ -279,9 +363,12 @@ def make_lm_eval_step(model, mesh, comm, *, seq_len: int, beta: float = 0.0,
                       loss_block: Optional[int] = None, param_specs=None):
     """Jitted ``(params, tokens [T]) -> loss``: the forward pass and the
     training objective, no gradient."""
-    return jax.jit(make_lm_loss(
+    loss_fn = make_lm_loss(
         model, mesh, comm, seq_len=seq_len, beta=beta, loss_block=loss_block,
-        param_specs=param_specs))
+        param_specs=param_specs)
+    if _has_experts(model):
+        return jax.jit(lambda params, batch: loss_fn(params, batch)[0])
+    return jax.jit(loss_fn)
 
 
 # --- set-up -------------------------------------------------------------------
@@ -299,8 +386,10 @@ def init_lm_params(model, mesh, comm, seed: int = 0, *,
 
     def init(tokens):
         t_loc = tokens.shape[0]
-        return model.init(jax.random.key(seed), tokens,
-                          jnp.arange(t_loc, dtype=jnp.int32))
+        positions = jnp.arange(t_loc, dtype=jnp.int32)
+        if _is_block_diffusion(model):  # the rows are two copies
+            tokens, positions = jnp.tile(tokens, 2), jnp.tile(positions, 2)
+        return model.init(jax.random.key(seed), tokens, positions)
 
     probe = jnp.zeros((INIT_PROBE_TOKENS * world,), jnp.int32)
     specs = None
@@ -339,14 +428,16 @@ class LMTrainer:
     eval_step: Callable
     startup: dict  # what ran: attention implementation, sizes
     steps_done: int = 0
+    expert_rows_max: int = 0  # most rows one expert got in one layer, so far
 
-    def feed(self, tokens: np.ndarray) -> jax.Array:
-        """One host batch ``[T]`` of token ids onto the mesh, sharded over
-        the graph axis."""
+    def feed(self, tokens):
+        """One host batch onto the mesh, sharded over the graph axis: ``[T]``
+        token ids, or for a block-diffusion model the three ``[T]`` arrays
+        ``(tokens, masked, weight)``."""
         return jax.device_put(
             tokens, NamedSharding(self.mesh, P(GRAPH_AXIS)))
 
-    def step(self, tokens: np.ndarray) -> StepMetrics:
+    def step(self, tokens) -> StepMetrics:
         """One step of the loop. Host-boundary spans (never inside the jitted
         step), one attribute read each while tracing is off."""
         from dgraph_tpu.obs import spans
@@ -359,10 +450,26 @@ class LMTrainer:
                     self.params, self.opt_state, toks)
             with spans.span("block"):
                 jax.block_until_ready(sm.loss)
+        if sm.moe_rows is not None:  # came back with the loss: no new sync
+            self._count_expert_rows(np.asarray(sm.moe_rows))
         self.steps_done += 1
         return sm
 
-    def evaluate(self, tokens: np.ndarray) -> jax.Array:
+    def _count_expert_rows(self, rows: np.ndarray) -> None:
+        """The step's expert-layer counts into the registry: ``moe.rows_*``
+        (``parallel.expert.HELD_STATS``) beside the rows routed anywhere."""
+        from dgraph_tpu.parallel.expert import HELD_STATS
+
+        default_registry.counter("moe.rows_routed", self.startup["moe_routes"])
+        for name, v in zip(HELD_STATS, rows):
+            if name == "rows_max_expert":  # a maximum over the steps: a gauge
+                self.expert_rows_max = max(self.expert_rows_max, int(v))
+                default_registry.gauge("moe.rows_max_expert",
+                                       self.expert_rows_max)
+            else:
+                default_registry.counter(f"moe.{name}", float(v))
+
+    def evaluate(self, tokens) -> jax.Array:
         """The training objective on one batch, forward only."""
         from dgraph_tpu.obs import spans
 
@@ -391,8 +498,12 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         raise ValueError(f"seq_len {seq_len} does not divide by world {world}")
     heads = getattr(model, "num_heads", 1)
     head_dim = getattr(model, "head_dim", None) or model.latent // heads
+    mask = model.attention_mask(seq_len) \
+        if hasattr(model, "attention_mask") else None
     attention = resolve_attention(
-        comm, model.attn_impl, seq_len // world, heads, head_dim)
+        comm, model.attn_impl,
+        seq_len // world if mask is None else mask.rows, heads, head_dim,
+        mask, heads // (getattr(model, "num_kv_heads", None) or heads))
     specs = None
     if params is None:
         params, specs = init_lm_params(
@@ -415,6 +526,22 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         default_registry.counter(f"lm.{name}", startup[name])
     default_registry.counter("lm.tokens_per_step", seq_len)
     default_registry.counter(f"lm.attention.{attention}")
+    if mask is not None:  # pairs the mask allows / pairs in the tiles visited
+        from dgraph_tpu.parallel.sequence import flash_tile
+
+        tile = flash_tile(mask.rows) if attention == "splash" else mask.rows
+        startup.update(attention_mask=mask.name, mask_pairs=mask.pairs(),
+                       tile_pairs=mask.tile_pairs(tile))
+        default_registry.counter("attn.mask_pairs", startup["mask_pairs"])
+        default_registry.counter("attn.tile_pairs", startup["tile_pairs"])
+    experts = getattr(model, "experts", None)
+    if experts is not None:
+        rows = (mask.rows if mask is not None else seq_len)
+        startup.update(
+            experts_held=experts.n_held, experts_total=experts.n_total,
+            moe_routes=rows * experts.k * startup["layer_applications"])
+        default_registry.counter("moe.experts_held", experts.n_held)
+        default_registry.counter("moe.experts_total", experts.n_total)
     kw = dict(seq_len=seq_len, beta=beta, loss_block=loss_block,
               param_specs=specs)
     return LMTrainer(

@@ -18,7 +18,6 @@ from dgraph_tpu.parallel.sequence import dense_attention, ring_attention
 SEQ, EXP = 4, 2
 T, H, D = 32, 2, 8  # T_loc = 8 per seq shard
 F = H * D
-CAP = 64  # ample capacity: no drops, exact oracle
 
 
 def _mesh():
@@ -54,7 +53,7 @@ def test_ring_attention_then_moe_on_2d_mesh():
         # expert-parallel MoE over 'expert' on the attention output
         out = moe_apply(
             toks, toks @ wr, _expert_fn, jax.tree.map(lambda l: l[0], ep),
-            CAP, "expert",
+            "expert",
         )
         return out.reshape(a.shape)
 

@@ -1,4 +1,5 @@
-"""Top-1 MoE over an 8-expert axis vs a dense single-device oracle."""
+"""One expert a rank of an 8-expert axis (``moe_apply``: top-1 switch routing
+and top-2 mixtures, dropless) vs a dense single-device oracle."""
 
 import numpy as np
 import pytest
@@ -34,24 +35,22 @@ def _params(rng):
     ]
 
 
-def _dense_oracle(x, logits, params_list, capacity):
-    """Per-shard-equivalent dense computation incl. the capacity drop."""
+def _dense_oracle(x, logits, params_list):
+    """Top-1, raw softmax gate: every token through its expert."""
     probs = jax.nn.softmax(jnp.asarray(logits), axis=-1)
     expert = np.argmax(np.asarray(probs), axis=-1)
     gate = np.take_along_axis(np.asarray(probs), expert[:, None], 1)[:, 0]
     out = np.zeros_like(np.asarray(x))
-    counts = np.zeros(E, np.int64)
     for t in range(len(x)):
-        e = int(expert[t])
-        if counts[e] < capacity:
-            y = np.tanh(np.asarray(x)[t] @ params_list[e]["w"] + params_list[e]["b"])
-            out[t] = gate[t] * y
-        counts[e] += 1
+        p = params_list[int(expert[t])]
+        out[t] = gate[t] * np.tanh(np.asarray(x)[t] @ p["w"] + p["b"])
     return out
 
 
-@pytest.mark.parametrize("capacity", [16, 4])  # ample and overflowing
-def test_moe_equals_dense_oracle(capacity):
+@pytest.mark.parametrize("load", ["spread", "one_expert"])
+def test_moe_equals_dense_oracle(load):
+    """``one_expert``: every token of every shard chooses expert 3, the load
+    a capacity factor would drop most of; no token is dropped."""
     mesh = _mesh()
     rng = np.random.default_rng(0)
     params_list = _params(rng)
@@ -62,11 +61,12 @@ def test_moe_equals_dense_oracle(capacity):
     # routes the same T tokens, so the oracle is per-shard identical too
     x = jnp.asarray(rng.standard_normal((T, F)), jnp.float32)
     logits = jnp.asarray(rng.standard_normal((T, E)), jnp.float32)
+    if load == "one_expert":
+        logits = logits.at[:, 3].add(10.0)
 
     fn = jax.shard_map(
         lambda p, x_, lg: moe_apply(
-            x_, lg, _expert_fn, jax.tree.map(lambda l: l[0], p),
-            capacity, "expert",
+            x_, lg, _expert_fn, jax.tree.map(lambda l: l[0], p), "expert",
         ),
         mesh=mesh,
         in_specs=(P("expert"), P(), P()),
@@ -74,7 +74,8 @@ def test_moe_equals_dense_oracle(capacity):
         check_vma=False,
     )
     got = fn(stacked, x, logits)
-    want = _dense_oracle(x, logits, params_list, capacity)
+    want = _dense_oracle(x, logits, params_list)
+    assert np.abs(want).min(axis=1).max() > 0  # no token left out
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
 
 
@@ -92,7 +93,7 @@ def test_moe_gradients_flow_to_router_and_experts():
         fn = jax.shard_map(
             lambda p, x_, wr_: moe_apply(
                 x_, x_ @ wr_, _expert_fn, jax.tree.map(lambda l: l[0], p),
-                16, "expert",
+                "expert",
             ),
             mesh=mesh,
             in_specs=(P("expert"), P(), P()),
@@ -122,7 +123,7 @@ def test_load_balance_loss_range():
 
 
 def _dense_topk_oracle(x, logits, params_list, k):
-    """Ample-capacity dense oracle for top-k: per token, the gate-weighted
+    """Dense oracle for top-k: per token, the gate-weighted
     sum of its top-k experts' outputs with gates renormalized over k."""
     probs = np.asarray(jax.nn.softmax(jnp.asarray(logits), axis=-1))
     order = np.argsort(-probs, axis=-1)[:, :k]  # [T, k]
@@ -149,11 +150,10 @@ def test_top2_matches_dense_oracle():
     stacked = jax.tree.map(
         lambda *xs: jnp.asarray(np.stack(xs)), *params_list
     )
-    CAP = E * T  # ample: nothing drops
 
     def body(x_, lg, ep):
         return moe_apply(
-            x_, lg, _expert_fn, jax.tree.map(lambda l: l[0], ep), CAP,
+            x_, lg, _expert_fn, jax.tree.map(lambda l: l[0], ep),
             "expert", k=2,
         )
 
@@ -176,30 +176,25 @@ def test_top2_matches_dense_oracle():
         )
 
 
-def test_top2_choice_major_priority_under_pressure():
-    """First choices must claim capacity before ANY second choice (the
-    GShard priority rule). Routes genuinely compete: ODD tokens' 1st
-    choice is expert 0, EVEN tokens' 2nd choice is also expert 0 (and
-    symmetrically for expert 1), with capacity = half the per-expert
-    demand. Choice-major assignment keeps exactly every 1st-choice route
-    and drops every 2nd-choice route; token-major assignment would let
-    early even tokens' 2nd choices steal expert-0 slots from late odd
-    tokens' 1st choices — a different, detectably wrong output."""
+def test_top2_keeps_every_route_under_pressure():
+    """Two of the eight experts get every route of every shard (odd tokens
+    choose expert 0 then 1, even tokens 1 then 0): 8 x the even load, which a
+    capacity factor would cut to first choices or less. Every token still
+    gets both of its experts, gates renormalised over the two."""
     mesh = _mesh()
     rng = np.random.default_rng(4)
-    x = rng.standard_normal((E * T, F)).astype(np.float32)
-    logits = np.zeros((E * T, E), np.float32)
-    odd = (np.arange(E * T) % 2).astype(bool)
-    logits[odd, 0], logits[odd, 1] = 4.0, 2.0   # odd: 1st->e0, 2nd->e1
-    logits[~odd, 1], logits[~odd, 0] = 4.0, 2.0  # even: 1st->e1, 2nd->e0
-    cap = T // 2  # = the number of 1st-choice routes per (shard, expert)
+    x = rng.standard_normal((E, T, F)).astype(np.float32)
+    logits = np.zeros((E, T, E), np.float32)
+    odd = (np.arange(T) % 2).astype(bool)
+    logits[:, odd, 0], logits[:, odd, 1] = 4.0, 2.0   # odd: 1st->e0, 2nd->e1
+    logits[:, ~odd, 1], logits[:, ~odd, 0] = 4.0, 2.0  # even: 1st->e1, 2nd->e0
 
     params_list = _params(rng)
     stacked = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *params_list)
 
     def body(x_, lg, ep):
         return moe_apply(
-            x_, lg, _expert_fn, jax.tree.map(lambda l: l[0], ep), cap,
+            x_, lg, _expert_fn, jax.tree.map(lambda l: l[0], ep),
             "expert", k=2,
         )
 
@@ -210,22 +205,13 @@ def test_top2_choice_major_priority_under_pressure():
         check_vma=False,
     )
     with jax.set_mesh(mesh):
-        got = np.asarray(fn(jnp.asarray(x), jnp.asarray(logits), stacked))
-
-    # oracle: every token keeps ONLY its 1st choice (x its renormalized
-    # 1st gate); every 2nd-choice route drops
-    probs = np.asarray(jax.nn.softmax(jnp.asarray(logits), axis=-1))
-    g1 = np.take_along_axis(probs, np.argmax(probs, -1)[:, None], 1)[:, 0]
-    g2 = np.partition(probs, -2, axis=-1)[:, -2]
-    w1 = g1 / (g1 + g2)
-    first = np.where(odd, 0, 1)
-    want = np.zeros_like(got)
-    for e in (0, 1):
-        sel = first == e
-        p = {k2: jnp.asarray(v) for k2, v in params_list[e].items()}
-        want[sel] = w1[sel, None] * np.asarray(
-            _expert_fn(p, jnp.asarray(x[sel])))
-    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+        got = np.asarray(fn(jnp.asarray(x.reshape(E * T, F)),
+                            jnp.asarray(logits.reshape(E * T, E)), stacked))
+    for s in range(E):
+        want = _dense_topk_oracle(x[s], logits[s], params_list, k=2)
+        np.testing.assert_allclose(
+            got[s * T:(s + 1) * T], want, rtol=2e-5, atol=2e-5,
+            err_msg=f"shard {s}")
 
 
 def test_top2_router_gradients_flow():
@@ -240,7 +226,7 @@ def test_top2_router_gradients_flow():
         def body(x_, lg_, ep):
             out = moe_apply(
                 x_, lg_, _expert_fn, jax.tree.map(lambda l: l[0], ep),
-                2 * T, "expert", k=2,
+                "expert", k=2,
             )
             return out
 

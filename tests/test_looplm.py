@@ -66,7 +66,7 @@ def reference():
 
 
 def single_loss(model, params, tokens, **kw):
-    total, _ = lm.local_loss_sum(
+    total, _, _ = lm.local_loss_sum(
         model, params, tokens, model.comm, seq_len=T, **kw)
     return total / (T - 1)
 
