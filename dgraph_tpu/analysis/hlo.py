@@ -685,7 +685,10 @@ def gather_table_placement(compiled_text: str) -> list:
 
 def placement_line(gathers: list) -> str:
     """``gather tables on chip: k of n`` over ``gathers``, with the sizes
-    of those left in HBM (a backward gather's ``[E, C]`` table always is)."""
+    of those left in HBM. An ``[E, C]`` edge tensor among them is a
+    backward gather by ``halo_sort_perm``: it can never be placed, and
+    since PR 33 the fused GCN layer's backward has none (it gathers from
+    ``[n_owner_pad, C]`` vertex tables instead)."""
     left = [g for g in gathers if g["memory_space"] == 0]
     line = f"gather tables on chip: {len(gathers) - len(left)} of {len(gathers)}"
     if left:

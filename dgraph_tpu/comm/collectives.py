@@ -1215,6 +1215,179 @@ def scatter_bias_relu(
     return scatter_sum(m, plan, side, axis_name)
 
 
+def _transposed_bwd_applies(table, bias, plan: EdgePlan, stream_side: str,
+                            owner_side: str) -> bool:
+    """Whether :func:`take_scatter_bias_relu`'s gradient to ``table`` can
+    run as the transposed aggregation: the streamed side is the plan's
+    halo side and its sorted route carries the owner ids in that order,
+    the owner side is plan-sorted with the fused backward's span hint,
+    the fused kernels run here (``ops.local``'s dispatch rule, and the
+    backward pair's own switch), and an owner-side table slice fits
+    on-chip memory. The last is the rule and the constant of
+    :func:`map_vertex_chunks`: the route trades one permutation of an
+    ``[E, chunk]`` tensor for TWO row gathers from ``[n_owner_pad, chunk]``
+    tables, 4.3 ms each from on-chip memory and 24.8 from HBM against the
+    permutation's 24.8 (PERF.md, PR 33), so a table too large to place
+    keeps the permutation."""
+    from dgraph_tpu import config as _cfg
+
+    chunk = _chunk_slices(bias.shape[-1], None)[0]
+    return (
+        stream_side == plan.halo_side
+        and plan.halo_sorted_owner_ids is not None
+        and plan.ids_sorted(owner_side)
+        and plan.gather_mv > 0
+        and local_ops.fused_bias_relu_kernel_runs()
+        and _cfg.pallas_fused_bwd_enabled()
+        and bias.shape[0] * (chunk.stop - chunk.start) * table.dtype.itemsize
+        <= ON_CHIP_BYTES
+    )
+
+
+def _take_then_scatter(table, bias, edge_weight, plan, stream_side,
+                       owner_side, axis_name, chunk_fn=None):
+    """The layer's forward: per column chunk, ``scatter_bias_relu`` of
+    ``local_take``, the chunks in :func:`map_vertex_chunks`' order.
+    ``chunk_fn(edata, bias_chunk)`` stands in for the fused scatter when
+    the caller wants more than its value (its VJP)."""
+
+    def fused(edata, b):
+        return scatter_bias_relu(edata, b, plan, owner_side, axis_name,
+                                 edge_weight=edge_weight)
+
+    chunk_fn = chunk_fn or fused
+    return map_vertex_chunks(
+        lambda t, b: chunk_fn(local_take(t, plan, stream_side), b),
+        (table, bias),
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _take_scatter_bias_relu(table, bias, edge_weight, plan, stream_side,
+                            owner_side, axis_name):
+    return _take_then_scatter(table, bias, edge_weight, plan, stream_side,
+                              owner_side, axis_name)
+
+
+def _tsbr_fwd(table, bias, edge_weight, plan, stream_side, owner_side,
+              axis_name):
+    if not _transposed_bwd_applies(table, bias, plan, stream_side,
+                                   owner_side):
+        # every op's own VJP, as composed autodiff runs them
+        out, vjp = jax.vjp(
+            lambda t, b, w: _take_then_scatter(
+                t, b, w, plan, stream_side, owner_side, axis_name),
+            table, bias, edge_weight,
+        )
+        return out, (vjp, None)
+    # the same calls; each chunk's fused scatter keeps its VJP for d_bias
+    # and d_w, the gathers' is what the transposed route replaces
+    chunk_vjps = []
+
+    def fused_with_vjp(edata, b):
+        out, vjp = jax.vjp(
+            lambda e, b_, w: scatter_bias_relu(
+                e, b_, plan, owner_side, axis_name, edge_weight=w),
+            edata, b, edge_weight,
+        )
+        chunk_vjps.append(vjp)
+        return out
+
+    out = _take_then_scatter(table, bias, edge_weight, plan, stream_side,
+                             owner_side, axis_name, chunk_fn=fused_with_vjp)
+    return out, (tuple(chunk_vjps), (table, bias, edge_weight, plan))
+
+
+def _tsbr_bwd(stream_side, owner_side, axis_name, res, g):
+    from dgraph_tpu.obs.metrics import default_registry
+
+    vjps, transposed = res
+    if transposed is None:
+        default_registry.counter(
+            "gather.bwd_permuted", len(_chunk_slices(g.shape[-1], None)))
+        return (*vjps(g), None)
+    table, bias, edge_weight, plan = transposed
+    cdt = table.dtype
+    owner_ids = plan.halo_sorted_owner_ids
+    with _scoped("dgraph.local_take"):
+        # once a step: every layer's and chunk's is this expression, and
+        # the compiler shares it
+        w_sorted = None if edge_weight is None else local_ops.take_values(
+            edge_weight, plan.halo_sort_perm)
+    d_table, d_bias, d_w = [], [], None
+    for vjp, sl in zip(vjps, _chunk_slices(g.shape[-1], None)):
+        default_registry.counter("gather.bwd_transposed")
+        # gd, the [E, chunk] cotangent the gather's own VJP would permute,
+        # is not read: the compiler drops the kernel that writes it unless
+        # an edge weight is differentiated (d_w is its second output)
+        g_cols = g[:, sl]
+        _, d_bias_c, d_w_c = vjp(g_cols)
+        d_bias.append(d_bias_c)
+        if edge_weight is not None:
+            d_w = d_w_c if d_w is None else d_w + d_w_c
+        with _scoped("dgraph.local_take"):
+            # every factor of gd[e] is a row of an owner-side VERTEX
+            # table: take them in the halo-sorted order, each table after
+            # the gather or chunk before it (map_vertex_chunks' tie), so
+            # that it needs its place on chip through its own gather
+            # only. The bias slice is cut out of the whole table AFTER
+            # the tie: cut before it, it is the forward's own slice, made
+            # then and held in HBM since; cut after, it is written to
+            # on-chip memory for this gather (read off the compiled
+            # modules, scripts/gather_placement.py: 12 of 12 tables
+            # placed in gcn_arxiv.w1 against 8, PERF.md PR 33). A padded
+            # edge's owner id clamps onto the last row; its sorted id is
+            # the route's sentinel, past every vertex block, so no
+            # one-hot column reads the row.
+            g_table = g_cols.astype(cdt)
+            if d_table:
+                g_table = _run_after(d_table[-1], g_table)
+            g_rows = local_ops.row_take(g_table, owner_ids)
+            bias_rows = local_ops.row_take(
+                _run_after(g_rows, bias)[:, sl].astype(cdt), owner_ids)
+        with _scoped("dgraph.scatter_bias_relu"):
+            d_table.append(local_ops.sorted_segment_grad_bias_relu(
+                bias_rows, g_rows, plan.halo_sorted_ids, table[:, sl],
+                plan.scatter_block_e, plan.scatter_block_n,
+                plan.halo_sort_mc, edge_weight=w_sorted,
+            ))
+    return _concat_chunks(d_table), _concat_chunks(d_bias), d_w, None
+
+
+_take_scatter_bias_relu.defvjp(_tsbr_fwd, _tsbr_bwd)
+
+
+def take_scatter_bias_relu(
+    table: jax.Array,  # [n_rows, F] stream-side vertex table (halo-extended)
+    bias: jax.Array,  # [n_owner_pad, F] owner-side vertex operand
+    plan: EdgePlan,
+    stream_side: str,
+    owner_side: str,
+    axis_name: Optional[str],
+    edge_weight: Optional[jax.Array] = None,  # [e_pad]
+) -> jax.Array:
+    """The fused GCN layer's aggregation, out[v] = Σ_{e: owner_e = v} w_e ·
+    relu(table[stream_e] + bias[v]), as ONE op with one VJP. The forward
+    is ``scatter_bias_relu(local_take(table), bias)`` a column chunk, the
+    chunks in :func:`map_vertex_chunks`' order. The gradient to ``table``,
+    which the two ops' own VJPs compute by writing gd[e] = w_e ·
+    g[owner_e] · 1[table[stream_e] + bias[owner_e] > 0] as an ``[E,
+    chunk]`` tensor in owner-sorted order, permuting it by
+    ``halo_sort_perm`` and segment-summing it, runs as the transposed
+    aggregation where :func:`_transposed_bwd_applies`: two rows an edge
+    gathered from the owner-side vertex tables ``g`` and ``bias`` in the
+    halo-sorted order, contracted by ``halo_sorted_ids`` with ``table``'s
+    block resident (``ops.pallas_segment.sorted_segment_grad_bias_relu``:
+    the forward kernel, sides exchanged; the same rounding points). The
+    backward is one op over the layer's chunks so that it can run them one
+    after the other, as the forward does. d_bias and d_w are the fused
+    scatter's own. Elsewhere every VJP runs as it did. Which route a
+    chunk's traced backward took is counted: ``gather.bwd_transposed`` /
+    ``gather.bwd_permuted`` (docs/tracing.md)."""
+    return _take_scatter_bias_relu(
+        table, bias, edge_weight, plan, stream_side, owner_side, axis_name)
+
+
 @_scoped("dgraph.gather_concat")
 def gather_concat(
     x_src: jax.Array,

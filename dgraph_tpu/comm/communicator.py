@@ -152,6 +152,18 @@ class _BaseComm:
             edata, bias, plan, side, self.graph_axis, edge_weight
         )
 
+    def take_scatter_bias_relu(self, table, bias, plan: EdgePlan,
+                               stream_side: str = "src",
+                               owner_side: str = "dst", edge_weight=None):
+        """``scatter_bias_relu(local_take(table), bias)`` as one op whose
+        gradient to ``table`` is the transposed aggregation from
+        owner-side vertex tables where that can pay
+        (:func:`collectives.take_scatter_bias_relu`)."""
+        return collectives.take_scatter_bias_relu(
+            table, bias, plan, stream_side, owner_side, self.graph_axis,
+            edge_weight,
+        )
+
     @_scoped("dgraph.comm.put")
     def put(self, send: jax.Array) -> jax.Array:
         """Deliver per-peer blocks by offsets — the ``BackendEngine.put``

@@ -110,12 +110,9 @@ class GraphConvLayer(nn.Module):
                     (h_stream, halo_buf, h_bias),
                 )
             h_ext = self.comm.halo_extend(h_stream, plan, side=stream)
-            return map_vertex_chunks(
-                lambda t, b: self.comm.scatter_bias_relu(
-                    self.comm.local_take(t, plan, side=stream),
-                    b, plan, side=owner, edge_weight=edge_weight,
-                ),
-                (h_ext, h_bias),
+            return self.comm.take_scatter_bias_relu(
+                h_ext, h_bias, plan, stream_side=stream, owner_side=owner,
+                edge_weight=edge_weight,
             )
 
         separable = self.activation in (nn.relu, jax.nn.relu)

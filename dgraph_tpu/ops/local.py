@@ -70,6 +70,38 @@ def row_take(
     )
 
 
+def take_values(values: jax.Array, idx: jax.Array, lanes: int = 128,
+                pieces: int = 8) -> jax.Array:
+    """``values[idx]`` for a 1-D vector and in-range ids, as a ROW gather:
+    the vector is viewed as ``[n / lanes, lanes]``, row ``idx // lanes`` is
+    taken an id and lane ``idx % lanes`` selected from it. XLA's element
+    gather costs 7.1 ns an id on a v5e whatever the width (16.7 ms for the
+    2.33 M edge weights of gcn_arxiv.w1), its row gather of 512-byte rows
+    from a table this small 3.2, select included (7.5 ms; PERF.md PR 33).
+    Exact: one term of the lane sum is the value, the rest are zeros.
+
+    The taken rows are ``lanes`` times the result (1.19 GB there), so the
+    ids are cut into ``pieces`` that run one after the other (each piece's
+    ids wait on the piece before: an ``optimization_barrier``, as
+    ``collectives.map_vertex_chunks`` orders a layer's chunks) and only a
+    piece's rows are live. A vector that does not divide into ``lanes``, or
+    ids that do not divide into ``pieces``, take the element gather."""
+    n = values.shape[0]
+    if (values.ndim != 1 or idx.ndim != 1 or n % lanes
+            or idx.shape[0] % pieces):
+        return jnp.take(values, idx)
+    table = values.reshape(n // lanes, lanes)
+    out = []
+    for ids in jnp.split(idx, pieces):
+        if out:
+            ids = jax.lax.optimization_barrier((out[-1], ids))[1]
+        rows = jnp.take(table, ids // lanes, axis=0)
+        hit = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1) == (
+            ids % lanes)[:, None]
+        out.append(jnp.where(hit, rows, 0).sum(-1))
+    return jnp.concatenate(out)
+
+
 @functools.lru_cache(maxsize=None)
 def _make_take_rows(n_rows, sorted_ids, col_block, pallas, block_e, block_n,
                     mc, gather_mv=0):
@@ -183,6 +215,32 @@ def sorted_segment_sum_any(data, sorted_ids, n_rows, be, bn, mc, gather_mv=0):
     return segment_sum(data, sorted_ids, n_rows, indices_are_sorted=True)
 
 
+def fused_bias_relu_kernel_runs() -> bool:
+    """The dispatch rule of the fused bias-relu kernel family: its kill
+    switch is not thrown and the backend is a TPU."""
+    from dgraph_tpu import config as _cfg
+
+    return _cfg.pallas_fused_enabled() and jax.default_backend() == "tpu"
+
+
+def sorted_segment_grad_bias_relu(
+    rows, g_rows, sorted_ids, table, be, bn, mc, edge_weight=None,
+):
+    """Σ_{e: ids[e]=u} w·g_rows·1[rows + table[u] > 0], the fused op's
+    gradient with its sides exchanged
+    (``ops.pallas_segment.sorted_segment_grad_bias_relu``), under the
+    precision policy of :func:`sorted_segment_sum_bias_relu_any`. Kernel
+    only: callers ask :func:`fused_bias_relu_kernel_runs` first."""
+    from dgraph_tpu.ops import pallas_segment
+
+    return pallas_segment.sorted_segment_grad_bias_relu(
+        rows, g_rows, sorted_ids, table, table.shape[0],
+        edge_weight=edge_weight, max_chunks_per_block=mc, block_e=be,
+        block_n=bn,
+        precision="default" if rows.dtype == jnp.bfloat16 else "highest",
+    )
+
+
 def sorted_segment_sum_bias_relu_any(
     edata, sorted_ids, bias, n_rows, be, bn, mc, edge_weight=None,
     gather_mv=0,
@@ -198,7 +256,7 @@ def sorted_segment_sum_bias_relu_any(
     # internally; the composed fallback must match, or a f32 bias with
     # bf16 edata would promote every [e_pad, F] tensor of the fallback
     bias = bias.astype(edata.dtype)
-    if _cfg.pallas_fused_enabled() and jax.default_backend() == "tpu":
+    if fused_bias_relu_kernel_runs():
         from dgraph_tpu.ops.pallas_segment import sorted_segment_sum_bias_relu
 
         prec = "default" if edata.dtype == jnp.bfloat16 else "highest"

@@ -283,11 +283,22 @@ def _kernel_bias_relu(
     ``epilogue="act"`` accumulates w[e] * 1[data[e]+bias[v] > 0] instead —
     the VJP's d_bias reduction (d_bias[v] = g[v] * Σ w·act), computed from
     ONE pass over data with no [E, F] HBM intermediates.
+
+    ``epilogue="grad"`` takes a second streamed operand ``other`` (after
+    ``data``) and accumulates w[e] * other[e] * 1[data[e]+bias[v] > 0]: the
+    gradient of the op with the two sides exchanged
+    (:func:`sorted_segment_grad_bias_relu`). Per edge it is
+    :func:`_fused_bwd_kernel`'s ``gd`` — the product in float32, rounded
+    once to the data dtype — contracted here instead of written out.
     """
+    wgt_ref = other_ref = None
+    refs = list(refs)
     if has_weight:
-        wgt_ref, data_ref, bias_ref, out_ref = refs
-    else:
-        (data_ref, bias_ref, out_ref), wgt_ref = refs, None
+        wgt_ref = refs.pop(0)
+    data_ref = refs.pop(0)
+    if epilogue == "grad":
+        other_ref = refs.pop(0)
+    bias_ref, out_ref = refs
     b = pl.program_id(0)
     k = pl.program_id(1)
 
@@ -315,6 +326,9 @@ def _kernel_bias_relu(
         pre = chunk.astype(jnp.float32) + bias_rows
         if epilogue == "act":
             chunk = (pre > 0).astype(jnp.float32)
+        elif epilogue == "grad":
+            chunk = other_ref[0].astype(jnp.float32) * (pre > 0).astype(
+                jnp.float32)
         else:
             chunk = jnp.maximum(pre, 0)
         if has_weight:
@@ -351,23 +365,33 @@ def _take_sorted(g, ids, gather_mv, block_e, block_n, mc):
 
 
 @functools.lru_cache(maxsize=None)
-def _make_ssbr(num_segments, max_chunks_per_block, block_e, block_n, interpret,
-               precision, has_weight, gather_mv=0):
-    def impl(data, segment_ids, bias, edge_weight, epilogue="relu"):
+def _make_ssbr_impl(num_segments, max_chunks_per_block, block_e, block_n,
+                    interpret, precision, has_weight):
+    """The vblock-major pass of :func:`_kernel_bias_relu` under one of its
+    epilogues, not differentiable: the fused op's forward (``"relu"``), its
+    backward's Σ w·act (``"act"``) and the transposed gradient
+    (``"grad"``, with the second streamed operand ``other``)."""
+
+    def impl(data, segment_ids, bias, edge_weight, epilogue="relu",
+             other=None):
         E, F = data.shape
         sched = _ChunkSchedule(
             segment_ids, num_segments, E, block_e=block_e, block_n=block_n,
             max_chunks_per_block=max_chunks_per_block,
         )
-        data3d = sched.pad_edges(data).reshape(sched.num_chunks, block_e, F)
         if sched.N_pad != num_segments:
             bias = jnp.pad(bias, ((0, sched.N_pad - num_segments), (0, 0)))
+        # data, then epilogue="grad"'s second streamed operand
+        streamed = [
+            sched.pad_edges(t).reshape(sched.num_chunks, block_e, F)
+            for t in ((data,) if other is None else (data, other))
+        ]
         in_specs = [
             sched.chunk_spec((1, 1, block_e)),
-            sched.chunk_spec((1, block_e, F)),
+            *[sched.chunk_spec((1, block_e, F)) for _ in streamed],
             sched.block_spec(F),
         ]
-        operands = [sched.ids3d, data3d, bias]
+        operands = [sched.ids3d, *streamed, bias]
         if has_weight:
             wgt3d = sched.pad_edges(edge_weight).reshape(
                 sched.num_chunks, 1, block_e
@@ -392,11 +416,22 @@ def _make_ssbr(num_segments, max_chunks_per_block, block_e, block_n, interpret,
             out_shape=_out_struct((sched.N_pad, F), jnp.float32, *call_args),
             interpret=interpret,
         )(*call_args)
-        if epilogue != "relu":
+        if epilogue == "act":
             # the act-count reduction is bwd-internal and vertex-sized —
             # keep the f32 accumulator precision (a bf16 count saturates)
             return out[:num_segments]
         return out[:num_segments].astype(data.dtype)
+
+    return impl
+
+
+@functools.lru_cache(maxsize=None)
+def _make_ssbr(num_segments, max_chunks_per_block, block_e, block_n, interpret,
+               precision, has_weight, gather_mv=0):
+    impl = _make_ssbr_impl(
+        num_segments, max_chunks_per_block, block_e, block_n, interpret,
+        precision, has_weight,
+    )
 
     @jax.custom_vjp
     def f(data, segment_ids, bias, edge_weight):
@@ -493,6 +528,35 @@ def _make_ssbr(num_segments, max_chunks_per_block, block_e, block_n, interpret,
 
     f.defvjp(fwd, bwd)
     return f
+
+
+def sorted_segment_grad_bias_relu(
+    rows: jax.Array,  # [E, F] the OTHER side's vertex operand, a row an edge
+    g_rows: jax.Array,  # [E, F] the other side's cotangent, a row an edge
+    segment_ids: jax.Array,  # [E] int32 MONOTONE ids of THIS side
+    table: jax.Array,  # [num_segments, F] this side's vertex operand
+    num_segments: int,
+    *,
+    edge_weight: Optional[jax.Array] = None,  # [E], in the ids' order
+    max_chunks_per_block: int,
+    block_e: int = 512,
+    block_n: int = 256,
+    interpret: bool = False,
+    precision: str = "default",
+) -> jax.Array:
+    """d_table[u] = Σ_{e: ids[e]=u} w[e] · g_rows[e] · 1[rows[e] + table[u] > 0]:
+    the gradient of ``Σ w·relu(table[src] + h[dst])`` to ``table`` as the
+    TRANSPOSED aggregation, edges in ``table``'s sorted order and the other
+    side's two vertex operands gathered a row an edge. The forward kernel
+    with ``epilogue="grad"`` (see :func:`_kernel_bias_relu`): ``table``'s
+    block is the resident operand the forward calls bias. Not
+    differentiable (a backward-internal pass)."""
+    impl = _make_ssbr_impl(
+        num_segments, max_chunks_per_block, block_e, block_n, interpret,
+        precision, edge_weight is not None,
+    )
+    return impl(rows, segment_ids, table.astype(rows.dtype), edge_weight,
+                epilogue="grad", other=g_rows)
 
 
 def sorted_segment_sum_bias_relu(

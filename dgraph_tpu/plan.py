@@ -433,6 +433,13 @@ class EdgePlan:
     # multiply by edge_mask before the route).
     halo_sort_perm: Any = None  # i32[W, E] or None
     halo_sorted_ids: Any = None  # i32[W, E] or None
+    # the OWNER-side index in the same order (owner_index[halo_sort_perm];
+    # a padded edge keeps its out-of-range n_owner_pad): with it the route
+    # is a second CSR of the graph, and the fused GCN layer's gradient to
+    # its halo-side table is an aggregation over it from owner-side VERTEX
+    # tables (comm.collectives.take_scatter_bias_relu), not a permutation
+    # of an [E, F] edge tensor
+    halo_sorted_owner_ids: Any = None  # i32[W, E] or None
     halo_sort_mc: int = 1  # static; max_chunks hint for the sorted route
     # Pallas sorted-row-gather hint: max vertex blocks any scatter_block_e
     # edge chunk spans (ops.pallas_segment.sorted_row_gather). 0 on plans
@@ -511,7 +518,8 @@ def plan_memory_usage(
     W, S = plan.world_size, plan.halo.s_pad
     idx_bytes = plan.e_pad * 4 * 2 + plan.e_pad * 4  # src/dst idx + mask
     if plan.halo_sort_perm is not None:
-        idx_bytes += plan.e_pad * 4 * 2  # halo_sort_perm + halo_sorted_ids
+        # halo_sort_perm + halo_sorted_ids + halo_sorted_owner_ids
+        idx_bytes += plan.e_pad * 4 * 3
     ov = getattr(plan, "overlap", None)
     if ov is not None:
         # interior/boundary split: src+dst+epos (i32) + mask (f32) per slot
@@ -850,7 +858,13 @@ def validate_plan(plan: EdgePlan) -> None:
         # transient host RAM W-fold on every cache load of a huge plan.
         perm = np_.asarray(plan.halo_sort_perm)
         sids = np_.asarray(plan.halo_sorted_ids)
-        halo_idx = src if plan.halo_side == "src" else dst
+        halo_idx, owner_idx = (
+            (src, dst) if plan.halo_side == "src" else (dst, src))
+        oids = (None if plan.halo_sorted_owner_ids is None
+                else np_.asarray(plan.halo_sorted_owner_ids))
+        if oids is None:
+            errors.append(
+                "halo_sorted_owner_ids missing on a plan with a sorted route")
         sentinel = halo_sort_sentinel(
             src_hi if plan.halo_side == "src" else dst_hi,
             plan.scatter_block_n)
@@ -876,6 +890,11 @@ def validate_plan(plan: EdgePlan) -> None:
                     f"{int(want[i])}: a real edge carries halo_index[perm], "
                     f"a masked edge the sentinel {sentinel} (this one is "
                     f"{'real' if real[i] else 'masked'})")
+                break
+            if oids is not None and not np_.array_equal(
+                    oids[r], owner_idx[r][pr]):
+                errors.append(
+                    f"halo_sorted_owner_ids[{r}] != owner_index[halo_sort_perm]")
                 break
     ov = plan.overlap
     if ov is not None:
@@ -1385,13 +1404,15 @@ def halo_sort_sentinel(n_halo_rows: int, block_n: int) -> int:
     return _pad_to(n_halo_rows, block_n)
 
 
-def halo_sort_route(halo_idx, edge_mask, n_halo_rows: int):
-    """The halo-sorted route ``(halo_sort_perm, halo_sorted_ids)`` of one
-    rank's halo-side index row (or of the ``[W, e_pad]`` stack of them) and
-    its ``edge_mask``: a stable sort by ``where(mask > 0, idx, sentinel)``,
+def halo_sort_route(halo_idx, edge_mask, n_halo_rows: int, owner_idx):
+    """The halo-sorted route ``(halo_sort_perm, halo_sorted_ids,
+    halo_sorted_owner_ids)`` of one rank's halo-side index row (or of the
+    ``[W, e_pad]`` stack of them), its ``edge_mask`` and its owner-side
+    index row: a stable sort by ``where(mask > 0, idx, sentinel)``,
     so real edges keep the order a sort by index gives them and every
     masked edge follows at :func:`halo_sort_sentinel`, outside every vertex
-    block. The ONE place the route is made (monolithic, native and streamed
+    block; the owner-side ids ride along in that order. The ONE place the
+    route is made (monolithic, native and streamed
     builds call it); a row with no padding gets what a plain argsort gives.
 
     The ids stay monotone through the kernel's own tail pad
@@ -1406,7 +1427,12 @@ def halo_sort_route(halo_idx, edge_mask, n_halo_rows: int):
         np.int32(halo_sort_sentinel(n_halo_rows, SCATTER_BLOCK_N)),
     ).astype(np.int32, copy=False)
     perm = np.argsort(key, axis=-1, kind="stable").astype(np.int32)
-    return perm, np.take_along_axis(key, perm, axis=-1)
+    return (
+        perm,
+        np.take_along_axis(key, perm, axis=-1),
+        np.take_along_axis(
+            np.asarray(owner_idx).astype(np.int32, copy=False), perm, axis=-1),
+    )
 
 
 def halo_wire_rows(plan: "EdgePlan", impl: str) -> int:
@@ -1464,7 +1490,7 @@ def _finalize_plan(
         gather_mv = 0
 
     # halo-side sorted route (see EdgePlan.halo_sort_perm)
-    halo_sort_perm = halo_sorted_ids = None
+    halo_sort_perm = halo_sorted_ids = halo_sorted_owner_ids = None
     halo_sort_mc = 1
     if sort_route:
         from dgraph_tpu.ops.pallas_segment import block_chunk_counts
@@ -1473,8 +1499,9 @@ def _finalize_plan(
         n_halo_rows = (
             n_src_pad_val if halo_side == "src" else n_dst_pad_val
         ) + W * s_pad_val
-        halo_sort_perm, halo_sorted_ids = halo_sort_route(
-            halo_idx_arr, edge_mask, n_halo_rows)
+        halo_sort_perm, halo_sorted_ids, halo_sorted_owner_ids = (
+            halo_sort_route(halo_idx_arr, edge_mask, n_halo_rows,
+                            owner_idx_arr))
         halo_sort_mc = _count_grid("halo_sort", [
             block_chunk_counts(
                 halo_sorted_ids[r], n_halo_rows,
@@ -1520,6 +1547,7 @@ def _finalize_plan(
         halo_deltas=halo_deltas,
         halo_sort_perm=halo_sort_perm,
         halo_sorted_ids=halo_sorted_ids,
+        halo_sorted_owner_ids=halo_sorted_owner_ids,
         halo_sort_mc=halo_sort_mc,
         gather_mv=gather_mv,
         overlap=overlap_spec,
@@ -1805,7 +1833,8 @@ def shard_nbytes_estimate(statics: dict) -> int:
     e_pad, W, s_pad = statics["e_pad"], statics["world_size"], statics["s_pad"]
     n = e_pad * (4 + 4 + 4)  # src/dst idx + mask
     if statics.get("sort_route"):
-        n += 2 * e_pad * 4  # halo_sort_perm + halo_sorted_ids
+        # halo_sort_perm + halo_sorted_ids + halo_sorted_owner_ids
+        n += 3 * e_pad * 4
     if statics.get("overlap"):
         n += (statics["e_int_pad"] + statics["e_bnd_pad"]) * 4 * 4
     n += 2 * W * s_pad * 4  # send_idx + send_mask rows
@@ -1852,12 +1881,13 @@ def _assemble_shard_payload(prep, r: int, *, sort_edges: bool,
             block_e=SCATTER_BLOCK_E, block_n=SCATTER_BLOCK_N,
         )
 
-    perm = sorted_ids = None
+    perm = sorted_ids = sorted_owner_ids = None
     if sort_route:
         from dgraph_tpu.ops.pallas_segment import block_chunk_counts
 
         n_halo_rows = prep.n_halo_pad + W * prep.s_pad
-        perm, sorted_ids = halo_sort_route(halo_row, mask_row, n_halo_rows)
+        perm, sorted_ids, sorted_owner_ids = halo_sort_route(
+            halo_row, mask_row, n_halo_rows, own_row)
         counts["halo_sort"] = block_chunk_counts(
             sorted_ids, n_halo_rows,
             block_e=SCATTER_BLOCK_E, block_n=SCATTER_BLOCK_N,
@@ -1874,6 +1904,7 @@ def _assemble_shard_payload(prep, r: int, *, sort_edges: bool,
         "send_mask": prep.send_mask[r],
         "halo_sort_perm": perm,
         "halo_sorted_ids": sorted_ids,
+        "halo_sorted_owner_ids": sorted_owner_ids,
         "overlap": None,
     }
     if overlap:
@@ -2196,6 +2227,8 @@ def assemble_plan(manifest: dict, payloads: dict, ranks: list) -> EdgePlan:
         halo_deltas=tuple(int(d) for d in st["halo_deltas"]),
         halo_sort_perm=stack("halo_sort_perm") if sort_route else None,
         halo_sorted_ids=stack("halo_sorted_ids") if sort_route else None,
+        halo_sorted_owner_ids=(
+            stack("halo_sorted_owner_ids") if sort_route else None),
         halo_sort_mc=int(st.get("halo_sort_mc", 1)),
         gather_mv=int(st.get("gather_mv", 0)),
         overlap=overlap_spec,

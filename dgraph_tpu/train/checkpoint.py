@@ -222,7 +222,10 @@ def checkpoint_keys(ckpt_dir: str, step: Optional[int] = None):
 # Bump whenever EdgePlan's fields/defaults change shape or meaning: stale
 # cache pickles must REBUILD, not silently inherit new class defaults for
 # fields they were never built with (e.g. scatter_block_e).
-PLAN_FORMAT_VERSION = 11  # v11: the halo-sorted route's padded edges
+PLAN_FORMAT_VERSION = 12  # v12: halo_sorted_owner_ids, the owner-side
+# index in the halo-sorted order (the fused GCN layer's backward aggregates
+# over it; validate_plan refuses a sorted route without it);
+# v11: the halo-sorted route's padded edges
 # sort last, at a sentinel id past the last vertex block
 # (plan.halo_sort_route), not into block 0 at id 0 — validate_plan holds
 # the new rule, so plans cached under the old one must rebuild;

@@ -8,15 +8,22 @@ module read by ``dgraph_tpu.analysis.hlo.gather_table_placement``:
 
 prints, before each of the tool's ``<train|eval> step, per chip`` lines,
 
-    gather tables on chip: 4 of 8; in HBM: bf16[2332672,128] (597.2 MB)
+    gather tables on chip: 20 of 20
 
-(every row gather of the module: ``local_take``'s forward gathers, its
-backward's gathers by ``halo_sort_perm`` whose ``[E, C]`` table can never
-be placed, the halo exchange's send gathers) and after them the ties
-``map_vertex_chunks`` traced (``gather.chunks_sequenced``; it ties where a
-table slice fits on-chip memory). A table on chip is worth 4.3 against
-24.8 ms a gather in ``gcn_arxiv.w1`` (PERF.md, PR 31). Compile only: not a
-chip run, and no time comes from here.
+(every row gather of the module: ``local_take``'s forward gathers, the
+fused layer's backward gathers from the owner-side vertex tables, the
+edge weights' row gathers, the halo exchange's send gathers) and after
+them the ties ``map_vertex_chunks`` traced (``gather.chunks_sequenced``; it
+ties where a table slice fits on-chip memory) and the routes the fused
+layer's backward took (``gather.bwd_transposed`` / ``gather.bwd_permuted``).
+A table left in HBM is named with its size: until PR 33 the train step of
+``gcn_arxiv.w1`` read ``4 of 8; in HBM: bf16[2332672,128] (597.2 MB)``,
+the backward's gathers by ``halo_sort_perm`` out of an ``[E, C]`` edge
+tensor that can never be placed; a ``bf16[2332672,128]`` there again means
+a backward fell back to the permutation. ``gcn_papers100m.w4`` keeps its
+forward tables (``bf16[809728,128]``, 207.3 MB) in HBM. A table on chip is
+worth 4.3 against 24.8 ms a gather in ``gcn_arxiv.w1`` (PERF.md, PR 31 and
+PR 33). Compile only: not a chip run, and no time comes from here.
 """
 
 from __future__ import annotations
@@ -49,10 +56,13 @@ def main() -> int:
         rc = rehearse_w4.main()
     finally:
         jax.stages.Lowered.compile = compile_lowered
-    ties = default_registry.snapshot()["counters"].get(
-        "gather.chunks_sequenced", 0)
-    print(f"gather.chunks_sequenced: {ties:.0f} (both steps, and the "
-          f"parameter init on the small graph)")
+    counters = default_registry.snapshot()["counters"]
+    print(f"gather.chunks_sequenced: "
+          f"{counters.get('gather.chunks_sequenced', 0):.0f} (both steps, "
+          f"and the parameter init on the small graph); "
+          f"gather.bwd_transposed / gather.bwd_permuted: "
+          f"{counters.get('gather.bwd_transposed', 0):.0f} / "
+          f"{counters.get('gather.bwd_permuted', 0):.0f} (the train step)")
     return rc
 
 

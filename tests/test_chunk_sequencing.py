@@ -240,3 +240,27 @@ def test_gather_table_placement_reads_the_memory_space_of_each_table():
         "gather tables on chip: 1 of 2; in HBM: bf16[169352,128] (43.4 MB)")
     assert placement_line(got[:1]) == "gather tables on chip: 1 of 1"
     assert gather_table_placement("HloModule empty\n") == []
+
+
+def test_placement_line_names_an_edge_tensor_left_in_hbm():
+    """What ``scripts/gather_placement.py`` prints for a train step. The
+    fused GCN layer's backward (ISSUE 33) gathers from owner-side vertex
+    tables, which are placed like the forward's; a backward that falls back
+    to the permutation by ``halo_sort_perm`` gathers from an ``[E, C]`` edge
+    tensor, which never is, and the line names it."""
+    from dgraph_tpu.analysis.hlo import gather_table_placement, placement_line
+
+    transposed = _CANNED.replace(
+        'op_name="jit(step)/dgraph.local_take/gather"',
+        'op_name="jit(step)/transpose(jvp(GCN))/GraphConvLayer_0/'
+        'dgraph.local_take/gather"'
+    ).replace("param_0.22 = bf16[169352,128]{1,0:T(8,128)(2,1)}",
+              "param_0.22 = bf16[169352,128]{1,0:T(8,128)(2,1)S(1)}")
+    got = gather_table_placement(transposed)
+    assert got[0]["op_name"].startswith("jit(step)/transpose(jvp(GCN))")
+    assert placement_line(got) == "gather tables on chip: 2 of 2"
+    permuted = _CANNED.replace("param_0.22: bf16[169352,128]",
+                               "param_0.22: bf16[2332672,128]").replace(
+        "param_0.22 = bf16[169352,128]", "param_0.22 = bf16[2332672,128]")
+    assert placement_line(gather_table_placement(permuted)) == (
+        "gather tables on chip: 1 of 2; in HBM: bf16[2332672,128] (597.2 MB)")

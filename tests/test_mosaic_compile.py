@@ -67,3 +67,29 @@ def test_fused_bwd_gd_kernel_compiles_at_arxiv_shape(one_chip, has_weight):
         assert (d_w.shape, d_w.dtype) == ((E,), jnp.float32)
     else:
         assert d_w is None
+
+
+@pytest.mark.parametrize("has_weight", [False, True])
+def test_transposed_grad_kernel_compiles_at_arxiv_shape(one_chip, has_weight):
+    """The fused layer's gradient to its streamed table (ISSUE 33): the
+    forward kernel under ``epilogue="grad"``, two streamed ``[E, 128]``
+    operands and the table's block resident, at the halo-sorted route's
+    hint (``halo_sort_mc`` reads 3 in ``gcn_arxiv.w1``)."""
+    from dgraph_tpu.ops.pallas_segment import sorted_segment_grad_bias_relu
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    def fn(rows, g_rows, ids, table, *w):
+        return sorted_segment_grad_bias_relu(
+            rows, g_rows, ids, table, N, edge_weight=w[0] if w else None,
+            max_chunks_per_block=3, block_e=BE, block_n=BN)
+
+    args = [shape((E, F), jnp.bfloat16), shape((E, F), jnp.bfloat16),
+            shape((E,), jnp.int32), shape((N, F), jnp.bfloat16)]
+    if has_weight:
+        args.append(shape((E,), jnp.float32))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    out = jax.eval_shape(fn, *args)
+    assert (out.shape, out.dtype) == ((N, F), jnp.bfloat16)

@@ -139,6 +139,30 @@ class TestSortRouteValidation:
         with pytest.raises(ValueError, match="not a permutation"):
             validate_plan(bad)
 
+    def test_owner_ids_out_of_step_with_the_route_rejected(self):
+        """``halo_sorted_owner_ids`` is ``dst_index[halo_sort_perm]``
+        (format v12): the fused GCN layer's backward gathers by it, so a
+        row that is not the owner index in the route's order, or a route
+        without it, must not load."""
+        import dataclasses
+
+        plan = self._plan()
+        oids = np.asarray(plan.halo_sorted_owner_ids)
+        np.testing.assert_array_equal(
+            oids, np.take_along_axis(
+                np.asarray(plan.dst_index), np.asarray(plan.halo_sort_perm),
+                axis=1))
+        # a padded edge keeps the owner side's out-of-range id
+        assert (oids[:, -1] == plan.n_dst_pad).all()
+        for bad_ids in (np.asarray(plan.dst_index), np.roll(oids, 1, axis=1)):
+            bad = dataclasses.replace(plan, halo_sorted_owner_ids=bad_ids)
+            with pytest.raises(
+                    ValueError, match=r"owner_index\[halo_sort_perm\]"):
+                validate_plan(bad)
+        with pytest.raises(ValueError, match="halo_sorted_owner_ids missing"):
+            validate_plan(
+                dataclasses.replace(plan, halo_sorted_owner_ids=None))
+
     def test_ids_mismatch_rejected(self):
         import dataclasses
 
@@ -167,9 +191,10 @@ def test_a_plan_cached_under_format_v10_rebuilds(tmp_path, monkeypatch):
         return ck.cached_edge_plan(
             str(tmp_path), edges, part, world_size=4, edge_owner="dst")[0]
 
-    def v10_route(halo_idx, edge_mask, n_halo_rows):
+    def v10_route(halo_idx, edge_mask, n_halo_rows, owner_idx):
         perm = np.argsort(halo_idx, axis=-1, kind="stable").astype(np.int32)
-        return perm, np.take_along_axis(halo_idx, perm, axis=-1)
+        return (perm, np.take_along_axis(halo_idx, perm, axis=-1),
+                np.take_along_axis(owner_idx, perm, axis=-1))
 
     with monkeypatch.context() as m:
         m.setattr(ck, "PLAN_FORMAT_VERSION", 10)
@@ -182,3 +207,61 @@ def test_a_plan_cached_under_format_v10_rebuilds(tmp_path, monkeypatch):
     validate_plan(fresh)
     assert len([d for d in tmp_path.iterdir() if d.name.startswith("plan_")]) == 2
     validate_plan(cached())  # and the warm hit is the fresh artifact
+
+
+def test_a_plan_cached_under_format_v11_rebuilds(tmp_path, monkeypatch):
+    """Format v11 had no ``halo_sorted_owner_ids`` (stood in for here by a
+    row of zeros: a shard without the key does not even stack). Its cached
+    plans no longer pass validate_plan, so the version in the cache key has
+    to send the same call to a fresh build, not to the stale artifact."""
+    from dgraph_tpu.train import checkpoint as ck
+
+    rng = np.random.default_rng(3)
+    edges = np.stack([rng.integers(0, 64, 400), rng.integers(0, 64, 400)])
+    part = np.sort(rng.integers(0, 4, 64)).astype(np.int32)
+    route = pl.halo_sort_route
+
+    def cached():
+        return ck.cached_edge_plan(
+            str(tmp_path), edges, part, world_size=4, edge_owner="dst")[0]
+
+    with monkeypatch.context() as m:
+        m.setattr(ck, "PLAN_FORMAT_VERSION", 11)
+        m.setattr(pl, "halo_sort_route", lambda *args: (
+            *route(*args)[:2], np.zeros_like(route(*args)[2])))
+        stale = cached()
+    with pytest.raises(ValueError, match=r"owner_index\[halo_sort_perm\]"):
+        validate_plan(stale)
+    assert ck.PLAN_FORMAT_VERSION >= 12
+    fresh = cached()
+    validate_plan(fresh)
+    assert len([d for d in tmp_path.iterdir() if d.name.startswith("plan_")]) == 2
+
+
+@pytest.mark.parametrize("edge_owner", ["dst", "src"])
+def test_every_build_gives_the_same_owner_ids_on_the_route(
+        tmp_path, edge_owner):
+    """Monolithic numpy, native and streamed-shard builds make the route in
+    one place (``halo_sort_route``): the owner ids in the route's order are
+    the same array, and are the owner index permuted."""
+    from dgraph_tpu import native
+
+    rng = np.random.default_rng(5)
+    edges = np.stack([rng.integers(0, 200, 1500), rng.integers(0, 200, 1500)])
+    part = np.sort(rng.integers(0, 4, 200)).astype(np.int32)
+    kw = dict(world_size=4, edge_owner=edge_owner)
+    plans = {"numpy": pl.build_edge_plan(edges, part, use_native=False, **kw)[0],
+             "streamed": pl.build_edge_plan_sharded(
+                 edges, part, out_dir=str(tmp_path / "shards"), **kw)[0]}
+    if native.available():
+        plans["native"] = pl.build_edge_plan(
+            edges, part, use_native=True, **kw)[0]
+    want = np.asarray(plans["numpy"].halo_sorted_owner_ids)
+    owner = (plans["numpy"].dst_index if edge_owner == "dst"
+             else plans["numpy"].src_index)
+    np.testing.assert_array_equal(want, np.take_along_axis(
+        np.asarray(owner), np.asarray(plans["numpy"].halo_sort_perm), axis=1))
+    for name, plan in plans.items():
+        validate_plan(plan)
+        np.testing.assert_array_equal(
+            np.asarray(plan.halo_sorted_owner_ids), want, err_msg=name)
