@@ -246,7 +246,10 @@ class _BaseComm:
             # flash here ONLY on an explicit pinned True (post-self-check):
             # single mode is the dense ORACLE parity harnesses compare
             # against — an unverified kernel must not replace it on auto
-            if _flash_applicable(q, require_pinned=True):
+            narrow = q.shape[-1] % 128 != 0  # its kernel path is causal only
+            if not (narrow and (not causal or kv_mask is not None)) \
+                    and _flash_applicable(q, require_pinned=True,
+                                          group=q.shape[1] // k.shape[1]):
                 return _flash_dense(q, k, v, causal=causal, scale=None,
                                     kv_mask=kv_mask)
             return dense_attention(q, k, v, causal=causal, kv_mask=kv_mask)

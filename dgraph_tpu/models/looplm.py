@@ -37,8 +37,30 @@ decoder's (SDAR-30B-A3B, a Qwen3-MoE layer: ``sandwich_norm=False``,
   (:func:`dgraph_tpu.parallel.expert.held_experts_ffn`) and leaves the rest
   out.
 
-Everything but attention is token-local, so the attention collective is the
-only communication. Parameters are float32; matmuls run in
+A stack may hold layers of MORE THAN ONE KIND (``LoopLM.pattern``, a tuple of
+kinds in stack order; LFM2-8B-A1B's decoder): a kind is ``"<mixer>+<ffn>"`` with
+mixer ``attn`` (above) or ``conv`` and ffn ``dense`` (the gated MLP of width
+``intermediate``) or ``experts`` (``experts``). Consecutive layers of one kind
+are one ``nn.scan`` over stacked parameters (``layers_0``, ``layers_1``, ...
+in stack order), unequal ones follow each other. No pattern: one scan,
+``layers``, every layer of the kind the other fields give.
+
+- ``conv``, the gated short convolution (kernel K = ``conv_kernel``):
+  ``(B, C, x~) = split3(W_in x)``, each ``[T, d]``; ``y = B * x~``;
+  ``z_t = sum_{j<K} w_j * y_{t-(K-1)+j}`` (depthwise, causal, zero before the
+  sequence's start, one weight a channel and tap, no bias); ``Conv(x) =
+  W_out (C * z)``. Token-local but for a ``K - 1``-row halo: over a sharded
+  sequence a shard takes the last ``K - 1`` rows of ``y`` from the rank
+  before it (one ``ppermute``; the first rank gets zeros);
+- an expert layer's router may be the sigmoid form with a selection bias
+  (``HeldExperts.score``, ``.select_bias``: :func:`route_topk`); the bias is a
+  leaf of the parameter tree that takes no gradient, and the trainer's step
+  leaves it alone (``FROZEN_LEAVES``: a masked update);
+- ``tie_head``: ``logits(h) = h E^T`` with ``E`` the embedding, in the compute
+  dtype with a float32 result; no ``head`` leaf.
+
+Everything but attention and the convolution's halo is token-local, so those
+two are the only communication. Parameters are float32; matmuls run in
 ``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
 logits are float32. The exit-distribution loss over the passes lives with the
 trainer (:mod:`dgraph_tpu.train.lm`), which applies :meth:`LoopLM.logits`
@@ -93,7 +115,11 @@ class HeldExperts:
     bounds the buffer of rows routed here (None: the worst case, which can
     drop nothing); a row past it is dropped and counted. Independent of the
     objective: a causal model takes expert layers as a block-diffusion one
-    does."""
+    does. The router's form (``parallel.expert.route_topk``): ``score``
+    ``"softmax"`` or ``"sigmoid"``; ``select_bias``: a ``[n_total]`` leaf
+    ``select_bias`` added to the scores for the choice only (no gradient, no
+    optimizer update); ``gate_eps`` added to the chosen gates' sum (None: the
+    softmax form's guard); ``gate_scale`` on the normalised gates."""
 
     n_total: int
     n_held: int
@@ -101,6 +127,15 @@ class HeldExperts:
     width: int
     first_held: int = 0
     rows: Optional[int] = None
+    score: str = "softmax"
+    select_bias: bool = False
+    gate_eps: Optional[float] = None
+    gate_scale: float = 1.0
+
+
+# Leaves of the parameter tree, by name, that are buffers: no gradient
+# reaches them and the trainer's step zeroes their update (train/lm.py).
+FROZEN_LEAVES = ("select_bias",)
 
 
 class _Kernel(nn.Module):
@@ -137,7 +172,12 @@ class HeldExpertsFFN(nn.Module):
                 logits = nn.Dense(
                     sp.n_total, use_bias=False, dtype=jnp.float32,
                     precision=jax.lax.Precision.HIGHEST, name="router")(x32)
-                gates, experts = route_topk(logits, sp.k)
+                bias = self.param(
+                    "select_bias", nn.initializers.zeros,
+                    (sp.n_total,)) if sp.select_bias else None
+                gates, experts = route_topk(
+                    logits, sp.k, score=sp.score, select_bias=bias,
+                    eps=sp.gate_eps, scale=sp.gate_scale)
                 # for a caller that asks (mutable=["intermediates"]): which
                 # experts each row chose, to set beside a reference's
                 self.sow("intermediates", "chosen", experts)
@@ -150,12 +190,86 @@ class HeldExpertsFFN(nn.Module):
                 axis_name=self.comm.graph_axis)
 
 
+def previous_rows(y: jax.Array, n: int, comm) -> jax.Array:
+    """The ``n`` rows before this shard's first: the last ``n`` rows of the
+    rank before it (one ``ppermute`` over the graph axis; its transpose hands
+    the cotangent back), zeros on the first rank and on one device."""
+    if comm is None or comm.graph_axis is None:
+        return jnp.zeros((n,) + y.shape[1:], y.dtype)
+    if y.shape[0] < n:
+        raise ValueError(f"a shard of {y.shape[0]} rows has no {n}-row halo")
+    world = comm.get_world_size()
+    # no pair ends at rank 0: ppermute leaves zeros there
+    return jax.lax.ppermute(y[-n:], comm.graph_axis,
+                            [(i, i + 1) for i in range(world - 1)])
+
+
+class _Taps(nn.Module):
+    """The taps ``[K, d]`` of a depthwise convolution under the leaf name
+    every kernel has (fan-in K)."""
+
+    shape: tuple
+
+    @nn.compact
+    def __call__(self):
+        return self.param(
+            "kernel", nn.initializers.normal(self.shape[0] ** -0.5),
+            self.shape)
+
+
+class GatedShortConv(nn.Module):
+    """``x [T_loc, d] -> W_out (C * conv_K(B * x~))`` (module docstring);
+    scope ``dgraph.lm.conv`` with ``in_proj``, ``gate_conv``, ``out_proj``.
+    The two products run in the compute dtype; both gates and the taps' sum
+    in float32 (one fused pass over the three streams: the arithmetic's width
+    costs no HBM byte), the result rounded once for ``out_proj``."""
+
+    kernel_size: int
+    comm: Any
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        d, K = x.shape[-1], self.kernel_size
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        with jax.named_scope("dgraph.lm.conv"):
+            with jax.named_scope("in_proj"):
+                bcx = dense(3 * d, name="in_proj")(x)
+            with jax.named_scope("gate_conv"):
+                b, c, xt = (t.astype(jnp.float32)
+                            for t in jnp.split(bcx, 3, axis=-1))
+                y = b * xt
+                taps = _Taps((K, d), name="conv")().astype(jnp.float32)
+                rows = jnp.concatenate(
+                    [previous_rows(y, K - 1, self.comm), y]) if K > 1 else y
+                n = y.shape[0]
+                z = sum(taps[j] * rows[j:j + n] for j in range(K))
+                g = (c * z).astype(bcx.dtype)
+            with jax.named_scope("out_proj"):
+                return dense(d, name="out_proj")(g)
+
+
+LAYER_MIXERS = ("attn", "conv")
+LAYER_FFNS = ("dense", "experts")
+
+
+def split_kind(kind: str):
+    """``"conv+experts" -> ("conv", "experts")``; raises on an unknown kind."""
+    mixer, _, ffn = kind.partition("+")
+    if mixer not in LAYER_MIXERS or ffn not in LAYER_FFNS:
+        raise ValueError(
+            f"layer kind {kind!r}: '<mixer>+<ffn>' with mixer in "
+            f"{LAYER_MIXERS} and ffn in {LAYER_FFNS}")
+    return mixer, ffn
+
+
 class LoopLMLayer(nn.Module):
     """One decoder layer; ``(h, rope) -> (h, stats)``, the signature
     ``nn.scan`` wants of a body (``stats``: the expert layer's counts, None
     for a dense FFN). Sandwich norms and a gated MLP by default (Ouro's);
     ``sandwich_norm=False``, ``qk_norm``, ``experts``, ``block_length`` give
-    the pre-norm sparse-expert block-diffusion layer (module docstring)."""
+    the pre-norm sparse-expert block-diffusion layer; ``mixer="conv"`` puts
+    the gated short convolution in attention's place (module docstring)."""
 
     hidden: int
     num_heads: int
@@ -170,22 +284,32 @@ class LoopLMLayer(nn.Module):
     qk_norm: bool = False
     experts: Optional[HeldExperts] = None  # None: the gated MLP
     block_length: int = 0  # > 0: rows [xt ; x0] under the block-diffusion mask
+    mixer: str = "attn"  # or "conv": the gated short convolution
+    conv_kernel: int = 3
 
     @nn.compact
     def __call__(self, h, rope):  # [T_loc, hidden], (cos, sin)
         from dgraph_tpu import config as _cfg
 
         dt = _cfg.resolve_compute_dtype(self.dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt)
+        norm = functools.partial(RMSNorm, epsilon=self.rms_eps, dtype=dt)
+        post = (lambda name: norm(name=name)) if self.sandwich_norm \
+            else (lambda name: lambda y: y.astype(h.dtype))
+        if self.mixer == "conv":
+            a = GatedShortConv(self.conv_kernel, self.comm, dt, name="conv")(
+                norm(name="norm_conv_in")(h))
+            h = h + post("norm_conv_out")(a)
+        else:
+            h = self.attend(h, rope, dense, norm, post)
+        return self.ffn(h, dt, dense, norm, post)
+
+    def attend(self, h, rope, dense, norm, post):
         H, D = self.num_heads, self.head_dim
         Hkv = self.num_kv_heads or H
         if H % Hkv:
             raise ValueError(f"heads {H} not divisible by kv heads {Hkv}")
         n = h.shape[0]
-        dense = functools.partial(nn.Dense, use_bias=False, dtype=dt)
-        norm = functools.partial(RMSNorm, epsilon=self.rms_eps, dtype=dt)
-        post = (lambda name: norm(name=name)) if self.sandwich_norm \
-            else (lambda name: lambda y: y.astype(h.dtype))
-
         x = norm(name="norm_attn_in")(h)
         q = dense(H * D, name="q_proj")(x).reshape(n, H, D)
         k = dense(Hkv * D, name="k_proj")(x).reshape(n, Hkv, D)
@@ -203,8 +327,9 @@ class LoopLMLayer(nn.Module):
             a = self.comm.seq_attention(q, k, v, causal=True,
                                         impl=self.attn_impl)
         a = dense(self.hidden, name="o_proj")(a.reshape(n, H * D))
-        h = h + post("norm_attn_out")(a)
+        return h + post("norm_attn_out")(a)
 
+    def ffn(self, h, dt, dense, norm, post):
         if self.experts is not None:
             u32 = RMSNorm(epsilon=self.rms_eps, dtype=jnp.float32,
                           name="norm_mlp_in")(h)
@@ -218,16 +343,31 @@ class LoopLMLayer(nn.Module):
         return h + post("norm_mlp_out")(m), None
 
 
+def layer_runs(pattern) -> list:
+    """``[(kind, length)]``: the runs of consecutive equal kinds of a
+    pattern, in stack order."""
+    runs = []
+    for kind in pattern:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return [tuple(r) for r in runs]
+
+
 class LoopPass(nn.Module):
     """One pass over the stack: ``num_layers`` layers under ``nn.scan``
     (parameters stacked on axis 0, each application rematerialised where
     ``remat``), then the final norm. ``(h, rope) -> (h_t, h_t)``: the carry
     of the loop and the pass's exit state (with expert layers, ``(h_t,
-    stats [num_layers, 4])`` in the second place)."""
+    stats [expert layers, 4])`` in the second place). With a ``pattern`` of
+    kinds each run of equal kinds is such a scan (``layers_<run>``), one
+    after the other; ``layer``'s ``experts`` go to the expert layers only."""
 
     num_layers: int
     layer: dict  # LoopLMLayer's fields
     remat: bool = True
+    pattern: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, h, rope):
@@ -235,11 +375,26 @@ class LoopPass(nn.Module):
             cls = LoopLMLayer
             if self.remat:
                 cls = nn.remat(cls, prevent_cse=False)  # inside a scan
-            stack = nn.scan(
-                cls, variable_axes={"params": 0, "intermediates": 0},
-                split_rngs={"params": True},
-                in_axes=nn.broadcast, length=self.num_layers)
-            h, stats = stack(**self.layer, name="layers")(h, rope)
+            scan = functools.partial(
+                nn.scan, cls,
+                variable_axes={"params": 0, "intermediates": 0},
+                split_rngs={"params": True}, in_axes=nn.broadcast)
+            if self.pattern is None:
+                h, stats = scan(length=self.num_layers)(
+                    **self.layer, name="layers")(h, rope)
+            else:
+                counted = []
+                for i, (kind, n) in enumerate(layer_runs(self.pattern)):
+                    mixer, ffn = split_kind(kind)
+                    fields = dict(
+                        self.layer, mixer=mixer,
+                        experts=self.layer["experts"] if ffn == "experts"
+                        else None)
+                    h, st = scan(length=n)(**fields, name=f"layers_{i}")(
+                        h, rope)
+                    if st is not None:
+                        counted.append(st)
+                stats = jnp.concatenate(counted) if counted else None
             # rematerialised too: its float32 internals would otherwise be
             # saved once a pass
             norm_f = nn.remat(RMSNorm) if self.remat else RMSNorm
@@ -252,7 +407,8 @@ class LoopLM(nn.Module):
     """Token ids in, the exit state of every pass out.
 
     ``hidden(tokens, positions) -> [loop_steps, T_loc, hidden]``;
-    ``logits(h) -> [..., vocab]`` float32 (the untied head);
+    ``logits(h) -> [..., vocab]`` float32 (the untied head; with ``tie_head``
+    the embedding transposed);
     ``gate_logit(h) -> [...]`` float32 (``exit_gate`` only). Calling the
     module gives ``(logits of every pass, gate logits or None)`` whole: for
     ``init`` and for small sequences; a trainer applies the head in blocks.
@@ -278,10 +434,28 @@ class LoopLM(nn.Module):
     experts: Optional[HeldExperts] = None
     block_length: int = 0  # > 0: trained by block diffusion (train/lm.py)
     mask_token: Optional[int] = None  # the id a noised token is replaced by
+    pattern: Optional[tuple] = None  # kinds "<mixer>+<ffn>", in stack order
+    conv_kernel: int = 3
+    tie_head: bool = False
+
+    def layer_kinds(self) -> tuple:
+        """The kind of each of the ``num_layers`` layers, in stack order."""
+        if self.pattern is not None:
+            return tuple(self.pattern)
+        ffn = "dense" if self.experts is None else "experts"
+        return (f"attn+{ffn}",) * self.num_layers
 
     def setup(self):
         from dgraph_tpu import config as _cfg
 
+        if self.pattern is not None:
+            ffns = {split_kind(kind)[1] for kind in self.pattern}
+            if len(self.pattern) != self.num_layers:
+                raise ValueError(f"a pattern of {len(self.pattern)} kinds for "
+                                 f"{self.num_layers} layers")
+            if ("experts" in ffns) != (self.experts is not None):
+                raise ValueError("expert layers in the pattern and `experts` "
+                                 "go together")
         dt = _cfg.resolve_compute_dtype(self.dtype)
         self.embed = nn.Embed(self.vocab, self.hidden_size, dtype=dt)
         layer = dict(
@@ -290,16 +464,18 @@ class LoopLM(nn.Module):
             comm=self.comm, num_kv_heads=self.num_kv_heads,
             rms_eps=self.rms_eps, dtype=self.dtype, attn_impl=self.attn_impl,
             sandwich_norm=self.sandwich_norm, qk_norm=self.qk_norm,
-            experts=self.experts, block_length=self.block_length)
+            experts=self.experts, block_length=self.block_length,
+            conv_kernel=self.conv_kernel)
         # the same parameters every pass: broadcast, not split
         loop = nn.scan(
             LoopPass, variable_broadcast="params",
             variable_axes={"intermediates": 0},
             split_rngs={"params": False}, in_axes=nn.broadcast,
             length=self.loop_steps)
-        self.stack = loop(self.num_layers, layer, self.remat)
-        self.head = nn.Dense(self.vocab, use_bias=False, dtype=dt,
-                             dot_general=_dot_f32_out)
+        self.stack = loop(self.num_layers, layer, self.remat, self.pattern)
+        if not self.tie_head:
+            self.head = nn.Dense(self.vocab, use_bias=False, dtype=dt,
+                                 dot_general=_dot_f32_out)
         if self.exit_gate:
             self.gate = nn.Dense(1, dtype=dt, dot_general=_dot_f32_out)
 
@@ -321,6 +497,11 @@ class LoopLM(nn.Module):
 
     def logits(self, h):
         with jax.named_scope("dgraph.lm.head"):
+            if self.tie_head:  # h E^T, compute-dtype operands, float32 result
+                dt = self.embed.dtype
+                return _dot_f32_out(
+                    h.astype(dt), self.embed.embedding.astype(dt),
+                    (((h.ndim - 1,), (1,)), ((), ())))
             return self.head(h).astype(jnp.float32)
 
     def gate_logit(self, h):

@@ -11,6 +11,12 @@ its gate-weighted rows back: row gathers in both directions of
 differentiation, no scatter and no one-hot matrix. The router learns through
 the gate product.
 
+The router has two forms (:func:`route_topk`): ``p = softmax(W_r x)``, the k
+largest, ``g_e = p_e / sum of the chosen`` (SDAR's); and ``s = sigmoid(W_r
+x)``, the k largest of ``s + b`` with ``b`` a selection bias that enters the
+choice and not the gate, ``g_e = scale * s_e / (sum of the chosen s + eps)``
+(LFM2's). Dispatch, grouped products and combine do not know which.
+
 Two layers stand on that (:func:`held_experts_apply`):
 
 - :func:`held_experts_ffn`, the gated-SiLU FFN of a sparse-expert model at
@@ -53,16 +59,47 @@ def load_balance_loss(router_logits: jax.Array, axis_name: str) -> jax.Array:
     return E * jnp.sum(frac * mean_p)
 
 
-def route_topk(router_logits: jax.Array, k: int, *, normalize: bool = True):
-    """(gates, experts), each ``[T, k]``: the ``k`` largest of
-    ``softmax(router_logits)`` over ALL experts, in float32; with
-    ``normalize`` the chosen gates are divided by their sum over all ``k``
-    chosen, wherever those experts live (GShard/Mixtral, ``norm_topk_prob``);
-    without, they stay the raw probabilities (the switch estimator)."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gates, experts = lax.top_k(probs, k)
+def route_topk(router_logits: jax.Array, k: int, *, normalize: bool = True,
+               score: str = "softmax", select_bias: Optional[jax.Array] = None,
+               eps: Optional[float] = None, scale: float = 1.0):
+    """(gates, experts), each ``[T, k]``, over ALL experts, in float32. Two
+    forms of router:
+
+    - ``score="softmax"`` (the default; GShard/Mixtral/Qwen3-MoE): the scores
+      are ``softmax(router_logits)``, the ``k`` largest are chosen, and with
+      ``normalize`` the chosen gates are divided by their sum over all ``k``
+      chosen, wherever those experts live (``norm_topk_prob``); without, they
+      stay the raw probabilities (the switch estimator).
+    - ``score="sigmoid"`` (DeepSeek-V3's and LFM2's): the scores are
+      ``s = sigmoid(router_logits)``, each expert's own. ``select_bias``
+      ``[n_total]`` steers the CHOICE only: the ``k`` largest of ``s + b``
+      are chosen and their gates are ``s`` (not ``s + b``), so the bias takes
+      no gradient (it is a buffer that a balancing rule outside the loss
+      would move). With ``normalize`` the gates are divided by their sum
+      ``+ eps``; then times ``scale`` (``routed_scaling_factor``).
+
+    ``eps=None`` guards the softmax form's division as before (a sum under
+    1e-20 is held there); a given ``eps`` is ADDED to the sum, as the
+    sigmoid form's published code does."""
+    x = router_logits.astype(jnp.float32)
+    if score == "softmax":
+        scores = jax.nn.softmax(x, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(x)
+    else:
+        raise ValueError(f"unknown router score {score!r}")
+    if select_bias is None:
+        gates, experts = lax.top_k(scores, k)
+    else:
+        _, experts = lax.top_k(
+            scores + lax.stop_gradient(select_bias.astype(jnp.float32)), k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
     if normalize:
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-20)
+        total = gates.sum(-1, keepdims=True)
+        gates = gates / (jnp.maximum(total, 1e-20) if eps is None
+                         else total + eps)
+    if scale != 1.0:
+        gates = gates * scale
     return gates, experts.astype(jnp.int32)
 
 

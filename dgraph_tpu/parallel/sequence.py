@@ -115,6 +115,31 @@ class BlockDiffusionMask:
     def tile_pairs(self, tile: int) -> int:
         return int(self.tile_map(tile).sum()) * tile * tile
 
+    def over(self, rows: int) -> "BlockDiffusionMask":
+        """A mask of the same kind over ``rows`` rows (the self-check's)."""
+        return dataclasses.replace(self, seq_len=rows // 2)
+
+
+@dataclasses.dataclass(frozen=True)
+class CausalMask:
+    """``causal`` as a mask object, for the kernels that take one: row q may
+    attend row k iff ``k <= q``. The splash kernels skip the tiles above the
+    diagonal and read grouped KV heads where they lie, which is how a head
+    narrower than the 128 lanes runs (:func:`_flash_dense`)."""
+
+    seq_len: int
+    name: ClassVar[str] = "causal"
+
+    @property
+    def rows(self) -> int:
+        return self.seq_len
+
+    def allowed(self, q_ids, k_ids):
+        return k_ids <= q_ids
+
+    def over(self, rows: int) -> "CausalMask":
+        return CausalMask(rows)
+
 
 def _block_attend(q, k, v, m, l, o, allowed, scale):
     """One online-softmax accumulation step against a K/V block.
@@ -360,7 +385,8 @@ def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
 
     Trace-time decision: config tri-state (``DGRAPH_TPU_FLASH_ATTN``) +
     shape constraints of the TPU kernel (T a multiple of its 128 query
-    block, head_dim lane-friendly). ``require_pinned=True`` (the
+    block; head_dim a multiple of the 128 lanes, or a narrower one whose
+    causal self-check at ``group`` passed). ``require_pinned=True`` (the
     single-comm ORACLE site) engages only on an explicit config True —
     never on auto — so an unverified Mosaic kernel can't silently replace
     the dense reference that parity harnesses compare against.
@@ -380,10 +406,15 @@ def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
         # process (the scatter kernels' central-veto discipline); an
         # explicit pinned True is the operator's override
         return False
-    if mask is not None and (mask.name, group) not in _splash_verified:
-        return False
     T, _, D = qh.shape
-    return T % 128 == 0 and D % 128 == 0
+    # a structured mask, and causal attention at a head that is no multiple
+    # of the lanes, run through the splash kernels: once THEIR self-check
+    # passed for this kind of mask, grouping and head size
+    kind = CausalMask.name if mask is None else mask.name
+    if (mask is not None or D % 128) \
+            and (kind, group, D) not in _splash_verified:
+        return False
+    return T % 128 == 0
 
 
 def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
@@ -395,6 +426,16 @@ def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
     T, H, D = qh.shape
+    if D % 128:
+        # measured at D = 64, 32 heads on 8, T = 16384 (PERF.md section 6,
+        # PR 34): the flash kernel with K and V repeated 19.1 ms forward /
+        # 99.2 forward + backward (zero-padded to 128: 20.2 / 100.7), the
+        # splash kernels under a causal mask 17.7 / 83.7
+        if not causal or kv_mask is not None:
+            raise NotImplementedError(
+                f"head_dim {D}: the narrow-head kernel path is causal and "
+                f"takes no kv_mask")
+        return _splash_dense(qh, kh, vh, mask=CausalMask(T), scale=scale)
     kh, vh = repeat_kv(qh, kh, vh)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -426,6 +467,10 @@ FLASH_BLOCK = 1024
 # 32 heads on 4 (forward 19.5 ms at 256, 23.8 at 512, 22.1 at 128; PERF.md,
 # section 6, PR 32).
 SPLASH_KV_COMPUTE = 256
+# ... and for a head of 64 under the causal mask at 16384 rows, 32 heads on 8
+# (forward / forward + backward 17.68 / 83.70 ms at 512, 18.07 / 83.88 at 256,
+# 19.58 / 87.49 at 1024; PERF.md, section 6, PR 34).
+SPLASH_KV_COMPUTE_NARROW = 512
 
 
 def flash_tile(T: int) -> int:
@@ -479,7 +524,7 @@ def _splash_dense(qh, kh, vh, *, mask, scale, interpret: bool = False):
         scale = 1.0 / math.sqrt(D)
     kernel = sk.make_splash_mqa_single_device(
         _splash_mask(mask, H // Hkv), interpret=interpret,
-        block_sizes=_splash_block_sizes(sk, T))
+        block_sizes=_splash_block_sizes(sk, T, D))
     # kernel layout: [kv heads, query heads of one kv head, T, D]; the kernel
     # has no scale of its own
     q4 = (qh * jnp.asarray(scale, qh.dtype)).transpose(1, 0, 2).reshape(
@@ -488,11 +533,12 @@ def _splash_dense(qh, kh, vh, *, mask, scale, interpret: bool = False):
     return out.reshape(H, T, D).transpose(1, 0, 2).astype(qh.dtype)
 
 
-def _splash_block_sizes(sk, T: int):
+def _splash_block_sizes(sk, T: int, D: int = 128):
     """Square tiles of ``flash_tile(T)`` for the three splash kernels, the
-    softmax taken over SPLASH_KV_COMPUTE columns of a kv tile at a time."""
+    softmax taken over SPLASH_KV_COMPUTE columns of a kv tile at a time
+    (SPLASH_KV_COMPUTE_NARROW for a head narrower than the lanes)."""
     b = flash_tile(T)
-    c = min(b, SPLASH_KV_COMPUTE)
+    c = min(b, SPLASH_KV_COMPUTE if D % 128 == 0 else SPLASH_KV_COMPUTE_NARROW)
     return sk.BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=c,
         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=c,
@@ -511,11 +557,12 @@ def _flash_block_sizes(fa, T: int):
 # Auto-mode flash engages only after flash_attention_selfcheck() passes
 # in this process (pinned config True bypasses — operator override).
 _flash_verified = False
-# (mask kind, query heads a kv head) pairs whose splash self-check passed
+# (mask kind, query heads a kv head, head_dim) whose splash self-check passed
 _splash_verified: set = set()
 
 
-def flash_attention_selfcheck(mask=None, group: int = 1) -> bool:
+def flash_attention_selfcheck(mask=None, group: int = 1,
+                              head_dim: int = 128) -> bool:
     """Chip-gated equivalence check vs :func:`dense_attention` (the same
     Mosaic-divergence rationale as bench.py's scatter self-checks: the
     kernel class is invisible to CPU CI). Passing LATCHES auto-mode flash
@@ -524,15 +571,19 @@ def flash_attention_selfcheck(mask=None, group: int = 1) -> bool:
     With a structured ``mask`` (and ``group`` query heads a KV head) the
     splash kernels are checked too, forward and backward, under a mask of the
     same kind over four tiles a side (whole, partial and skipped tiles all
-    occur) and two KV heads; passing latches that pair in
-    ``_splash_verified``. True only if everything asked for passed.
+    occur) and two KV heads, at heads of ``head_dim``; passing latches
+    (kind, group, head_dim) in ``_splash_verified``. A ``head_dim`` that is no multiple of 128 runs
+    causal attention through the splash kernels too (:func:`_flash_dense`),
+    so they are checked under the causal mask at THAT head size and grouping.
+    True only if everything asked for passed.
     """
     global _flash_verified
 
     if jax.default_backend() != "tpu":
         return False
-    if mask is not None:
-        return flash_attention_selfcheck() and _splash_selfcheck(mask, group)
+    if mask is not None or head_dim % 128:
+        return flash_attention_selfcheck() and _splash_selfcheck(
+            mask or CausalMask(0), group, head_dim=head_dim)
     rng = np.random.default_rng(3)
     T, H, D = 256, 2, 128
     q, k, v = (
@@ -579,14 +630,16 @@ def flash_attention_selfcheck(mask=None, group: int = 1) -> bool:
     return True
 
 
-def _splash_selfcheck(mask, group: int, *, interpret: bool = False) -> bool:
+def _splash_selfcheck(mask, group: int, *, interpret: bool = False,
+                      head_dim: int = 128) -> bool:
     """Splash forward and backward against the dense oracle under a mask of
-    ``mask``'s kind; see :func:`flash_attention_selfcheck`. The oracle runs
-    one KV head at a time (its ``[T, group, T]`` float32 logits)."""
+    ``mask``'s kind at heads of ``head_dim``; see
+    :func:`flash_attention_selfcheck`. The oracle runs one KV head at a time
+    (its ``[T, group, T]`` float32 logits)."""
     rows = 4 * (128 if interpret else FLASH_BLOCK)  # four tiles a side
-    small = dataclasses.replace(mask, seq_len=rows // 2)
+    small = mask.over(rows)
     rng = np.random.default_rng(5)
-    Hkv, D = 2, 128
+    Hkv, D = 2, head_dim
     q, w = (jnp.asarray(rng.standard_normal((rows, Hkv * group, D)),
                         jnp.bfloat16) for _ in range(2))
     k, v = (jnp.asarray(rng.standard_normal((rows, Hkv, D)), jnp.bfloat16)
@@ -615,7 +668,7 @@ def _splash_selfcheck(mask, group: int, *, interpret: bool = False) -> bool:
                 return False
     except Exception:
         return False
-    _splash_verified.add((mask.name, group))
+    _splash_verified.add((mask.name, group, D))
     return True
 
 

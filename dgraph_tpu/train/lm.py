@@ -83,7 +83,10 @@ def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
     ``comm.seq_attention`` will run, and say which: 'flash', 'dense', 'ring',
     'ulysses+flash' or 'ulysses+dense'; under a structured ``mask`` (with
     ``group`` query heads a KV head) 'splash' or 'dense', the self-check then
-    covering the splash kernels for that mask and grouping as well.
+    covering the splash kernels for that mask and grouping as well. A head
+    that is no multiple of 128 lanes runs causal attention through the splash
+    kernels too ('splash'), after their self-check at that head size and
+    grouping.
 
     Wherever a device holds a full-sequence view the Mosaic flash kernel is
     engaged only after ``flash_attention_selfcheck()`` passed on this chip
@@ -102,8 +105,8 @@ def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
     if comm.graph_axis is not None and attn_impl == "ring":
         return "ring"
     if cfg.flash_attention_enabled():
-        cfg.set_flags(
-            use_flash_attention=seq.flash_attention_selfcheck(mask, group))
+        cfg.set_flags(use_flash_attention=seq.flash_attention_selfcheck(
+            mask, group, head_dim))
     if comm.graph_axis is None:
         t_full, heads, prefix = t_local, num_heads, ""
     else:  # ulysses: the full sequence, a share of the heads
@@ -111,7 +114,8 @@ def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
     view = jax.ShapeDtypeStruct((t_full, heads, head_dim), jnp.float32)
     if seq._flash_applicable(view, require_pinned=comm.graph_axis is None,
                              mask=mask, group=group):
-        return prefix + ("flash" if mask is None else "splash")
+        return prefix + ("flash" if mask is None and head_dim % 128 == 0
+                         else "splash")
     if heads * t_full * t_full * 4 > DENSE_LOGITS_LIMIT_BYTES:
         raise RuntimeError(
             f"attention over T={t_full} with {heads} heads would materialise "
@@ -331,6 +335,19 @@ def make_lm_loss(model, mesh, comm, *, seq_len: int, beta: float = 0.0,
     )
 
 
+def _leave_buffers_alone(updates):
+    """Zero the update of every leaf that is a buffer by name
+    (``models.looplm.FROZEN_LEAVES``: an expert router's selection bias): no
+    gradient reaches it, and neither does weight decay. A masked update; the
+    optimizer's state keeps the leaf (its moments stay zero). A tree without
+    such a leaf passes through as it is."""
+    from dgraph_tpu.models.looplm import FROZEN_LEAVES
+
+    return jax.tree_util.tree_map_with_path(
+        lambda path, u: jnp.zeros_like(u)
+        if getattr(path[-1], "key", None) in FROZEN_LEAVES else u, updates)
+
+
 def make_lm_train_step(model, optimizer: optax.GradientTransformation, mesh,
                        comm, *, seq_len: int, beta: float = 0.0,
                        loss_block: Optional[int] = None,
@@ -352,7 +369,8 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, mesh,
         gn = optax.global_norm(grads) if step_metrics else None
         with jax.named_scope("dgraph.lm.optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            params = optax.apply_updates(
+                params, _leave_buffers_alone(updates))
         return params, opt_state, StepMetrics(
             loss=loss, grad_norm=gn, moe_rows=counts)
 
@@ -492,7 +510,8 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     (after the chip's self-check), initialise the parameters (``params``
     given: those instead, placed on the mesh) and the optimizer state on the
     mesh, build the steps. Stages ``setup.init_params`` (or ``setup.place``)
-    and ``setup.init_opt_state``; counters ``lm.*``."""
+    and ``setup.init_opt_state``; counters ``lm.*`` (the layers by kind:
+    ``lm.layers.conv / .attention / .dense_ffn / .expert_ffn``)."""
     world = comm.get_world_size()
     if seq_len % world:
         raise ValueError(f"seq_len {seq_len} does not divide by world {world}")
@@ -500,10 +519,19 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     head_dim = getattr(model, "head_dim", None) or model.latent // heads
     mask = model.attention_mask(seq_len) \
         if hasattr(model, "attention_mask") else None
-    attention = resolve_attention(
-        comm, model.attn_impl,
-        seq_len // world if mask is None else mask.rows, heads, head_dim,
-        mask, heads // (getattr(model, "num_kv_heads", None) or heads))
+    kinds = model.layer_kinds() if hasattr(model, "layer_kinds") else None
+    by_kind = None if kinds is None else {
+        "conv": sum(k.startswith("conv+") for k in kinds),
+        "attention": sum(k.startswith("attn+") for k in kinds),
+        "dense_ffn": sum(k.endswith("+dense") for k in kinds),
+        "expert_ffn": sum(k.endswith("+experts") for k in kinds)}
+    if by_kind is not None and not by_kind["attention"]:
+        attention = "none"  # a stack of convolutions attends nowhere
+    else:
+        attention = resolve_attention(
+            comm, model.attn_impl,
+            seq_len // world if mask is None else mask.rows, heads, head_dim,
+            mask, heads // (getattr(model, "num_kv_heads", None) or heads))
     specs = None
     if params is None:
         params, specs = init_lm_params(
@@ -526,6 +554,15 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         default_registry.counter(f"lm.{name}", startup[name])
     default_registry.counter("lm.tokens_per_step", seq_len)
     default_registry.counter(f"lm.attention.{attention}")
+    expert_layers = model.num_layers
+    if by_kind is not None:
+        startup["layers_by_kind"] = by_kind
+        for kind, n in by_kind.items():
+            default_registry.counter(f"lm.layers.{kind}", n)
+        default_registry.counter("lm.attention.head_dim", head_dim)
+        if by_kind["conv"]:
+            default_registry.counter("lm.conv.kernel_size", model.conv_kernel)
+        expert_layers = by_kind["expert_ffn"]
     if mask is not None:  # pairs the mask allows / pairs in the tiles visited
         from dgraph_tpu.parallel.sequence import flash_tile
 
@@ -539,7 +576,7 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         rows = (mask.rows if mask is not None else seq_len)
         startup.update(
             experts_held=experts.n_held, experts_total=experts.n_total,
-            moe_routes=rows * experts.k * startup["layer_applications"])
+            moe_routes=rows * experts.k * expert_layers * loops)
         default_registry.counter("moe.experts_held", experts.n_held)
         default_registry.counter("moe.experts_total", experts.n_total)
     kw = dict(seq_len=seq_len, beta=beta, loss_block=loss_block,

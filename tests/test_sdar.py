@@ -337,7 +337,7 @@ def test_splash_kernels_match_the_oracle_in_interpret_mode():
     128 of which some are whole, some partial, some skipped."""
     assert seq._splash_selfcheck(seq.BlockDiffusionMask(256, 4), 2,
                                  interpret=True)
-    assert ("block_diffusion", 2) in seq._splash_verified
+    assert ("block_diffusion", 2, 128) in seq._splash_verified
     assert not seq.flash_attention_selfcheck(seq.BlockDiffusionMask(256, 4), 2)  # off-TPU
 
 
