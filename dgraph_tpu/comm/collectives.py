@@ -725,27 +725,6 @@ def map_feature_chunks(fn, width: int, chunk: Optional[int] = None):
     return _concat_chunks([fn(sl) for sl in _chunk_slices(width, chunk)])
 
 
-@jax.custom_jvp
-def _run_after(token, cols):
-    """``cols`` unchanged, but not available before ``token`` is."""
-    return lax.optimization_barrier((token, cols))[1]
-
-
-@_run_after.defjvp
-def _run_after_jvp(primals, tangents):
-    # the tie is the forward's alone: tangents (and so cotangents) pass
-    # straight through and the token gets none. The barrier's own
-    # transpose would order the chunks' cotangents as well, which holds
-    # [E, chunk] tensors longer (+3.5 % temporaries on gcn_papers100m.w4,
-    # PERF.md PR 31)
-    return _run_after(*primals), tangents[1]
-
-
-# On-chip memory a row gather's table can be placed in: 128 MiB on a TPU
-# v5e (as on a v4 and a v6e). A hardware fact, not an option.
-ON_CHIP_BYTES = 128 << 20
-
-
 def map_vertex_chunks(fn, tables, chunk: Optional[int] = None):
     """:func:`map_feature_chunks` for a pipeline whose chunk result is
     vertex-level (``fn(*cols) -> [N, chunk]``: take -> edge math ->
@@ -767,16 +746,22 @@ def map_vertex_chunks(fn, tables, chunk: Optional[int] = None):
 
     Ordering is not free: XLA masks a layer's independent chunks in one
     fusion and ordered chunks in one each (+1.5 ms a layer at 2.3 M
-    edges). So chunks whose tables cannot be placed anyway (a slice past
-    :data:`ON_CHIP_BYTES`: gcn_papers100m.w4's 207 MB) stay independent,
-    and that program is what it was (PERF.md, PR 31)."""
+    edges). So a chunk is tied only where its largest table slice can be
+    gathered from on-chip memory, whole or in the row parts
+    ``ops.local.row_take`` takes it in (``ops.local.on_chip_row_parts``:
+    gcn_papers100m.w4's 207 MB slices, in two; PERF.md, PR 31 and PR 35);
+    chunks whose tables cannot be placed anyway (an ``[E, chunk]`` edge
+    tensor) stay independent."""
     from dgraph_tpu.obs.metrics import default_registry
 
     out = []
     for sl in _chunk_slices(tables[0].shape[-1], chunk):
         cols = tuple(t[:, sl] for t in tables)
-        if out and max(c.size * c.dtype.itemsize for c in cols) <= ON_CHIP_BYTES:
-            cols = _run_after(out[-1], cols)
+        largest = max(cols, key=lambda c: c.size * c.dtype.itemsize)
+        if out and local_ops.on_chip_row_parts(
+                largest.shape[0],
+                largest.shape[-1] * largest.dtype.itemsize):
+            cols = local_ops.run_after(out[-1], cols)
             default_registry.counter("gather.chunks_sequenced")
         out.append(fn(*cols))
     return _concat_chunks(out)
@@ -1223,12 +1208,13 @@ def _transposed_bwd_applies(table, bias, plan: EdgePlan, stream_side: str,
     the owner side is plan-sorted with the fused backward's span hint,
     the fused kernels run here (``ops.local``'s dispatch rule, and the
     backward pair's own switch), and an owner-side table slice fits
-    on-chip memory. The last is the rule and the constant of
-    :func:`map_vertex_chunks`: the route trades one permutation of an
-    ``[E, chunk]`` tensor for TWO row gathers from ``[n_owner_pad, chunk]``
-    tables, 4.3 ms each from on-chip memory and 24.8 from HBM against the
-    permutation's 24.8 (PERF.md, PR 33), so a table too large to place
-    keeps the permutation."""
+    on-chip memory WHOLE (``ops.local.on_chip_row_parts`` = 1, the size
+    rule :func:`map_vertex_chunks` ties by): the route trades one
+    permutation of an ``[E, chunk]`` tensor for TWO row gathers from
+    ``[n_owner_pad, chunk]`` tables, 4.3 ms each from on-chip memory and
+    24.8 from HBM against the permutation's 24.8 (PERF.md, PR 33), so a
+    table too large to place keeps the permutation, and so does one that
+    would be gathered in row parts (four gathers and two select passes)."""
     from dgraph_tpu import config as _cfg
 
     chunk = _chunk_slices(bias.shape[-1], None)[0]
@@ -1239,8 +1225,9 @@ def _transposed_bwd_applies(table, bias, plan: EdgePlan, stream_side: str,
         and plan.gather_mv > 0
         and local_ops.fused_bias_relu_kernel_runs()
         and _cfg.pallas_fused_bwd_enabled()
-        and bias.shape[0] * (chunk.stop - chunk.start) * table.dtype.itemsize
-        <= ON_CHIP_BYTES
+        and local_ops.on_chip_row_parts(
+            bias.shape[0], (chunk.stop - chunk.start) * table.dtype.itemsize
+        ) == 1
     )
 
 
@@ -1341,10 +1328,10 @@ def _tsbr_bwd(stream_side, owner_side, axis_name, res, g):
             # one-hot column reads the row.
             g_table = g_cols.astype(cdt)
             if d_table:
-                g_table = _run_after(d_table[-1], g_table)
+                g_table = local_ops.run_after(d_table[-1], g_table)
             g_rows = local_ops.row_take(g_table, owner_ids)
             bias_rows = local_ops.row_take(
-                _run_after(g_rows, bias)[:, sl].astype(cdt), owner_ids)
+                local_ops.run_after(g_rows, bias)[:, sl].astype(cdt), owner_ids)
         with _scoped("dgraph.scatter_bias_relu"):
             d_table.append(local_ops.sorted_segment_grad_bias_relu(
                 bias_rows, g_rows, plan.halo_sorted_ids, table[:, sl],

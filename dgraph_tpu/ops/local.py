@@ -30,6 +30,100 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.custom_jvp
+def run_after(token, cols):
+    """``cols`` unchanged, but not available before ``token`` is."""
+    return jax.lax.optimization_barrier((token, cols))[1]
+
+
+@run_after.defjvp
+def _run_after_jvp(primals, tangents):
+    # the tie is the forward's alone: tangents (and so cotangents) pass
+    # straight through and the token gets none. The barrier's own
+    # transpose would order the chunks' cotangents as well, which holds
+    # [E, chunk] tensors longer (+3.5 % temporaries on gcn_papers100m.w4,
+    # PERF.md PR 31)
+    return run_after(*primals), tangents[1]
+
+
+# On-chip memory a row gather's table can be placed in: 128 MiB on a TPU
+# v5e (as on a v4 and a v6e). A hardware fact, not an option.
+ON_CHIP_BYTES = 128 << 20
+# What the compiler gives ONE gather table of it: seven eighths. Read off
+# compiles for a described v5e (a [rows, 128] bf16 table alone in its
+# program, 2.56 M ids or 128): placed at 112 MiB, left in HBM at 113; on
+# the chip a row from a 104 MB table costs what one from 43 MB does
+# (1.86 ns), from a 112 MiB one 1.96 (PERF.md PR 35).
+GATHER_TABLE_BYTES = ON_CHIP_BYTES // 8 * 7
+# Row parts a gather may be taken in (on_chip_row_parts has the arithmetic).
+MAX_ROW_PARTS = 3
+
+
+def on_chip_row_parts(n_rows: int, row_bytes: int) -> int:
+    """How a row gather from an ``[n_rows, row_bytes]`` table slice reads
+    on-chip memory: 1 = the slice fits whole (``GATHER_TABLE_BYTES``), k =
+    taken in k equal row parts that fit (:func:`row_take`), 0 = not at all.
+
+    The one size rule of the local gather: ``row_take`` cuts by it,
+    ``collectives.map_vertex_chunks`` ties a chunk where it is not 0, the
+    fused GCN layer's transposed backward asks for 1.
+
+    Why k stops at ``MAX_ROW_PARTS``: every part gathers every id, so at
+    2.56 M ids k parts cost k x 4.77 ms of gathers from on-chip memory and
+    one select pass over k + 1 ``[E, C]`` streams against 30.1 ms for the
+    one gather from HBM with its mask pass. Measured alone on a v5e, mask
+    pass included: 13.7 ms at k = 2 (a 207 MB table) and 19.3 at k = 3
+    (311 MB); by the same arithmetic ~25 at k = 4 with the slice copies
+    and the ordering still to pay, ~31 at 5 (PERF.md PR 35). An ``[E, C]``
+    edge tensor (597-655 MB: six parts) keeps its one gather."""
+    nbytes = n_rows * row_bytes
+    if nbytes <= GATHER_TABLE_BYTES:
+        return 1
+    k = -(-nbytes // GATHER_TABLE_BYTES)
+    return k if k <= MAX_ROW_PARTS else 0
+
+
+def _take_in_row_parts(chunk, idx, k, oob):
+    """:func:`row_take`'s gather from a table too large for on-chip memory:
+    ``chunk`` cut by rows into ``k`` equal parts that fit, each part
+    gathered with the ids shifted and clamped into it, and the part that
+    holds an id chosen per row in ONE select chain over the k results (it
+    fuses with ``local_take``'s edge-mask multiply: one pass over ``[E,
+    C]`` where the unparted gather has one). Every output row is one table
+    row (or a zero row), so the result is ``row_take``'s to the bit.
+
+    Order is what gets the parts placed (read off compiled modules,
+    scripts/gather_placement.py): part p + 1 is cut out of the WHOLE chunk
+    after the chunk has been tied behind part p's gather, so it is a
+    buffer written for its own gather and needs its place through that
+    gather only (cut before the tie it is made early and held in HBM: the
+    bias slice of PR 33). The ids stay whole: tied to the gathers and
+    selected once, gcn_papers100m.w4's train step holds 4.52 GB of
+    temporaries against the unparted 4.64; ids in two pieces that each
+    select and are concatenated cost two more passes and leave a part a
+    layer in HBM (PERF.md PR 35)."""
+    from dgraph_tpu.obs.metrics import default_registry
+
+    n = chunk.shape[0]
+    rows = -(-n // k)
+    idx = jnp.where(idx < 0, idx + n, idx)  # numpy's wrap, as x[idx] has it
+    if oob != "fill":
+        idx = jnp.clip(idx, 0, n - 1)
+    taken = []
+    for lo in range(0, n, rows):
+        part = (run_after(taken[-1], chunk) if taken else chunk)[lo : lo + rows]
+        taken.append(jnp.take(
+            part, jnp.clip(idx - lo, 0, part.shape[0] - 1), axis=0,
+            mode="clip"))
+    default_registry.counter("gather.row_parts", len(taken))
+    out = taken[-1]
+    for p in range(len(taken) - 2, -1, -1):
+        out = jnp.where((idx < (p + 1) * rows)[:, None], taken[p], out)
+    if oob == "fill":
+        out = jnp.where(((idx >= 0) & (idx < n))[:, None], out, 0)
+    return out
+
+
 def row_take(
     x: jax.Array,
     idx: jax.Array,
@@ -48,6 +142,11 @@ def row_take(
     (``local_data_kernels.cuh:353-406``): reshape the access so the memory
     system moves full-width units.
 
+    A column chunk too large for on-chip memory, where a row costs 1.85 ns
+    against 10.6 from HBM, is taken in row parts that fit
+    (:func:`on_chip_row_parts`, :func:`_take_in_row_parts`): the same
+    bits, gcn_papers100m.w4's 207 MB forward tables.
+
     ``col_block=None`` reads :data:`dgraph_tpu.config.gather_col_block`;
     0 disables splitting. ``oob="fill"`` zeroes out-of-range rows (the
     padding convention VJPs need); "clamp" keeps plain-indexing semantics.
@@ -58,6 +157,11 @@ def row_take(
         col_block = _cfg.gather_col_block
 
     def one(chunk):
+        if chunk.ndim == 2 and idx.ndim == 1:
+            k = on_chip_row_parts(
+                chunk.shape[0], chunk.shape[1] * chunk.dtype.itemsize)
+            if k > 1:
+                return _take_in_row_parts(chunk, idx, k, oob)
         if oob == "fill":
             return jnp.take(chunk, idx, axis=0, mode="fill", fill_value=0)
         return chunk[idx]

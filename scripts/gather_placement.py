@@ -14,16 +14,22 @@ prints, before each of the tool's ``<train|eval> step, per chip`` lines,
 fused layer's backward gathers from the owner-side vertex tables, the
 edge weights' row gathers, the halo exchange's send gathers) and after
 them the ties ``map_vertex_chunks`` traced (``gather.chunks_sequenced``; it
-ties where a table slice fits on-chip memory) and the routes the fused
-layer's backward took (``gather.bwd_transposed`` / ``gather.bwd_permuted``).
+ties where a table slice can be gathered from on-chip memory, whole or in
+row parts), the row parts ``row_take`` cut its gathers into
+(``gather.row_parts``) and the routes the fused layer's backward took
+(``gather.bwd_transposed`` / ``gather.bwd_permuted``).
 A table left in HBM is named with its size: until PR 33 the train step of
 ``gcn_arxiv.w1`` read ``4 of 8; in HBM: bf16[2332672,128] (597.2 MB)``,
 the backward's gathers by ``halo_sort_perm`` out of an ``[E, C]`` edge
 tensor that can never be placed; a ``bf16[2332672,128]`` there again means
-a backward fell back to the permutation. ``gcn_papers100m.w4`` keeps its
-forward tables (``bf16[809728,128]``, 207.3 MB) in HBM. A table on chip is
-worth 4.3 against 24.8 ms a gather in ``gcn_arxiv.w1`` (PERF.md, PR 31 and
-PR 33). Compile only: not a chip run, and no time comes from here.
+a backward fell back to the permutation. ``gcn_papers100m.w4``'s forward
+tables (``bf16[809728,128]``, 207.3 MB) fit no on-chip memory and are
+gathered in two row parts since PR 35 (``bf16[404864,128]``, every one
+placed: ``39 of 45`` in its train step, ``15 of 15`` in its eval step;
+``gather.row_parts`` 36); a ``bf16[809728,128]`` named in HBM again means
+the parts were not taken. A table on chip is worth 4.3 against 24.8 ms a
+gather in ``gcn_arxiv.w1`` (PERF.md, PR 31 and PR 33). Compile only: not a
+chip run, and no time comes from here.
 """
 
 from __future__ import annotations
@@ -60,6 +66,7 @@ def main() -> int:
     print(f"gather.chunks_sequenced: "
           f"{counters.get('gather.chunks_sequenced', 0):.0f} (both steps, "
           f"and the parameter init on the small graph); "
+          f"gather.row_parts: {counters.get('gather.row_parts', 0):.0f}; "
           f"gather.bwd_transposed / gather.bwd_permuted: "
           f"{counters.get('gather.bwd_transposed', 0):.0f} / "
           f"{counters.get('gather.bwd_permuted', 0):.0f} (the train step)")

@@ -27,6 +27,7 @@ from dgraph_tpu.comm.mesh import (
     squeeze_plan,
 )
 from dgraph_tpu.obs.metrics import default_registry
+from dgraph_tpu.ops import local as local_ops
 from dgraph_tpu.plan import shard_vertex_data
 
 W, V, E, F = 2, 48, 300, 12
@@ -43,9 +44,12 @@ def flags():
                   gather_col_block=saved[2])
 
 
+def _counted(name: str) -> float:
+    return default_registry.snapshot()["counters"].get(name, 0)
+
+
 def _ties_counted() -> float:
-    return default_registry.snapshot()["counters"].get(
-        "gather.chunks_sequenced", 0)
+    return _counted("gather.chunks_sequenced")
 
 
 def _layer(branch: str, comm):
@@ -108,7 +112,7 @@ def test_sequenced_chunks_are_the_same_program_but_for_the_ties(
         *ties, sequenced = trace_and_run()
         assert ties == [TIES, TIES, 0]  # counted, forward's, backward's
         monkeypatch.setattr(
-            collectives, "_run_after", lambda token, cols: cols)
+            local_ops, "run_after", lambda token, cols: cols)
         *ties, plain = trace_and_run()
         assert ties == [TIES, 0, 0]
     for a, b in zip(jax.tree.leaves(sequenced), jax.tree.leaves(plain)):
@@ -125,7 +129,7 @@ def test_graphcast_edge_block_is_not_sequenced(rng, flags, monkeypatch):
     def no_tie(token, cols):
         raise AssertionError("the edge block took a tie")
 
-    monkeypatch.setattr(collectives, "_run_after", no_tie)
+    monkeypatch.setattr(local_ops, "run_after", no_tie)
     part = np.zeros(V, np.int32)
     edges = np.stack([rng.integers(0, V, E), rng.integers(0, V, E)])
     plan, _ = pl.build_edge_plan(edges, part, world_size=1)
@@ -149,33 +153,164 @@ def test_map_vertex_chunks_one_chunk_is_a_plain_call(flags):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x) * 2)
 
 
-def test_chunks_whose_tables_cannot_be_placed_stay_independent(
-        flags, monkeypatch):
+@pytest.mark.parametrize("limit_rows, tied", [
+    (8, True),  # the slice fits whole
+    (7, True),  # in two row parts of 4
+    (3, True),  # in three of 3, 3 and 2
+    (2, False),  # four parts: past ``MAX_ROW_PARTS``, cannot pay
+])
+def test_chunks_are_tied_where_their_tables_can_be_gathered_on_chip(
+        flags, monkeypatch, limit_rows, tied):
     """Ordering costs a mask fusion a chunk, so it is taken only where a
-    table slice fits on-chip memory (``ON_CHIP_BYTES``): a larger one
-    (``gcn_papers100m.w4``'s 207 MB) traces to the unordered program."""
+    table slice can be gathered from on-chip memory: whole
+    (``GATHER_TABLE_BYTES``) or in the row parts ``row_take`` takes it in
+    (``gcn_papers100m.w4``'s 207 MB slices, in two). A slice that would
+    need more than ``MAX_ROW_PARTS`` (an ``[E, chunk]`` edge tensor)
+    traces to the unordered program."""
     x = jnp.ones((8, 12), jnp.float32)  # slices of 8 x 4 x 4 bytes
     bias = jnp.ones((2, 12), jnp.float32)  # the largest slice decides
+    monkeypatch.setattr(local_ops, "GATHER_TABLE_BYTES", limit_rows * 4 * 4)
+    before = _ties_counted()
+    jaxpr = str(jax.make_jaxpr(lambda t, b: collectives.map_vertex_chunks(
+        lambda tc, bc: tc.sum(0, keepdims=True) + bc, (t, b)))(x, bias))
+    want = TIES if tied else 0
+    assert (_ties_counted() - before,
+            jaxpr.count("optimization_barrier")) == (want, want)
 
-    def ties(limit):
-        monkeypatch.setattr(collectives, "ON_CHIP_BYTES", limit)
-        before = _ties_counted()
-        jaxpr = str(jax.make_jaxpr(lambda t, b: collectives.map_vertex_chunks(
-            lambda tc, bc: tc.sum(0, keepdims=True) + bc, (t, b)))(x, bias))
-        return _ties_counted() - before, jaxpr.count("optimization_barrier")
 
-    assert ties(8 * 4 * 4) == (TIES, TIES)
-    assert ties(8 * 4 * 4 - 1) == (0, 0)
-    # the real limit parts the two GCN cells' tables (rows x 128 bf16)
-    monkeypatch.undo()
-    assert 169352 * 128 * 2 <= collectives.ON_CHIP_BYTES < 809728 * 128 * 2
+def test_the_size_rule_parts_the_benchmarks_tables():
+    """Rows x 128 bf16 columns: both GCN cells' vertex tables and the fused
+    backward's owner-side tables whole, ``gcn_papers100m.w4``'s
+    halo-extended table in two, an ``[E, 128]`` edge tensor not at all."""
+    assert local_ops.GATHER_TABLE_BYTES == 112 << 20
+    parts = [local_ops.on_chip_row_parts(rows, 128 * 2)
+             for rows in (169352, 166656, 809728, 2332672, 2561024)]
+    assert parts == [1, 1, 2, 0, 0]
+    # three parts are the most (the arithmetic is in the rule's docstring)
+    assert local_ops.on_chip_row_parts(3 * (112 << 20) // 256, 256) == 3
+    assert local_ops.on_chip_row_parts(3 * (112 << 20) // 256 + 1, 256) == 0
+
+
+# ---------------------------------------------------------------------------
+# a table too large for on-chip memory, taken in row parts (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+
+def _boundary_ids(rng, n, k, count):
+    """Ids on both sides of every part boundary, at both ends of the
+    table, past it and before it (numpy's wrap), and random ones; an odd
+    count."""
+    rows = -(-n // k)
+    edges = [b + d for b in range(0, n + rows, rows) for d in (-2, -1, 0, 1)]
+    ids = np.concatenate([
+        edges, [n - 1, n, n + 7, 2 * n, -1, -n, -n - 1],
+        rng.integers(0, n, count)])
+    return jnp.asarray(ids[: len(ids) // 2 * 2 + 1].astype(np.int32))
+
+
+@pytest.mark.parametrize("oob", ["fill", "clamp"])
+@pytest.mark.parametrize("k", [2, 3])
+@pytest.mark.parametrize("n", [48, 53])  # divides by 2 and 3, and neither
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_gather_in_row_parts_is_the_whole_gather_to_the_bit(
+        rng, monkeypatch, dtype, n, k, oob):
+    x = jnp.asarray(rng.standard_normal((n, 8)), dtype)
+    idx = _boundary_ids(rng, n, k, 40)
+    row_bytes = 8 * x.dtype.itemsize
+    monkeypatch.setattr(
+        local_ops, "GATHER_TABLE_BYTES", -(-n // k) * row_bytes)
+    assert local_ops.on_chip_row_parts(n, row_bytes) == k
+    before = _counted("gather.row_parts")
+    got = jax.jit(lambda x_, i_: local_ops.row_take(x_, i_, oob=oob))(x, idx)
+    assert _counted("gather.row_parts") - before == k
+    want = (jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+            if oob == "fill" else x[idx])
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert np.abs(np.asarray(got, np.float32)).sum() > 0
+
+
+@pytest.mark.parametrize("oob", ["fill", "clamp"])
+def test_a_table_that_fits_traces_the_unparted_gather(rng, oob):
+    """Under the threshold ``row_take`` is the expression it was: the same
+    jaxpr, no tie, no part counted (every cell but ``gcn_papers100m.w4``)."""
+    x = jnp.asarray(rng.standard_normal((48, 8)), jnp.bfloat16)
+    idx = _boundary_ids(rng, 48, 2, 40)
+
+    def parent(x_, i_):
+        if oob == "fill":
+            return jnp.take(x_, i_, axis=0, mode="fill", fill_value=0)
+        return x_[i_]
+
+    before = _counted("gather.row_parts")
+    got = str(jax.make_jaxpr(
+        lambda x_, i_: local_ops.row_take(x_, i_, oob=oob))(x, idx))
+    assert _counted("gather.row_parts") == before
+    assert got == str(jax.make_jaxpr(parent)(x, idx))
+    assert "optimization_barrier" not in got
+
+
+@pytest.mark.parametrize("k", [2, 3])
+def test_the_parts_are_cut_after_their_ties_and_selected_once(
+        rng, monkeypatch, k):
+    """Part p + 1 is a slice of the whole table tied behind part p's
+    gather (k - 1 barriers, each with the whole table as an operand), and
+    one select chain picks the part: k - 1 selects and the fill."""
+    x = jnp.asarray(rng.standard_normal((48, 8)), jnp.float32)
+    idx = _boundary_ids(rng, 48, k, 40)
+    monkeypatch.setattr(local_ops, "GATHER_TABLE_BYTES", 48 // k * 8 * 4)
+    jaxpr = jax.make_jaxpr(
+        lambda x_, i_: local_ops.row_take(x_, i_, oob="fill"))(x, idx)
+    ties = [e for e in jaxpr.jaxpr.eqns
+            if e.primitive.name == "custom_jvp_call"]  # run_after
+    assert len(ties) == k - 1 == str(jaxpr).count("optimization_barrier")
+    for e in ties:
+        assert [v.aval.shape for v in e.invars] == [(idx.shape[0], 8), (48, 8)]
+    calls = [e.params["name"] for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "jit"]
+    assert calls.count("_take") == k
+    # the ids' wrap, k - 1 choices between parts, the fill
+    assert calls.count("_where") == k + 1
+
+
+@pytest.mark.parametrize("k", [2, 3])
+def test_the_sort_routes_vjp_does_not_see_the_parts(rng, monkeypatch, k):
+    """``take_rows_sort_route``'s gradient (gather by the permutation,
+    sorted segment-sum) is the same to the bit whether its forward took
+    the table whole or in parts; the tie is the forward's alone."""
+    n, e = 53, 200
+    x = jnp.asarray(rng.standard_normal((n, 8)), jnp.float32)
+    ids = rng.integers(0, n, e).astype(np.int32)
+    perm = np.argsort(ids, kind="stable").astype(np.int32)
+    ct = jnp.asarray(rng.standard_normal((e, 8)), jnp.float32)
+
+    def grad():
+        # a fresh function each time: jit caches a trace by identity
+        def loss(x_):
+            rows = local_ops.take_rows_sort_route(
+                x_, jnp.asarray(ids), jnp.asarray(perm),
+                jnp.asarray(ids[perm]), pallas_hints=(0, 0, 0))
+            return (rows * ct).sum()
+
+        jaxpr = str(jax.make_jaxpr(jax.grad(loss))(x))
+        return jaxpr.count("optimization_barrier"), jax.jit(
+            jax.value_and_grad(loss))(x)
+
+    ties, whole = grad()
+    assert ties == 0
+    monkeypatch.setattr(local_ops, "GATHER_TABLE_BYTES", -(-n // k) * 8 * 4)
+    ties, parted = grad()
+    assert ties == k - 1  # the forward's; none came with the transpose
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(parted)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_run_after_differentiates_both_ways_and_gives_the_token_nothing():
     token, x = jnp.ones((3,)), jnp.arange(4.0)
 
     def f(token, x):
-        (y,) = collectives._run_after(token, (x,))
+        (y,) = local_ops.run_after(token, (x,))
         return jnp.sum(y * y)
 
     g_token, g_x = jax.grad(f, argnums=(0, 1))(token, x)

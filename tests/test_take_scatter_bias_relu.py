@@ -25,6 +25,7 @@ from dgraph_tpu import config as cfg
 from dgraph_tpu import plan as pl
 from dgraph_tpu.comm import Communicator, collectives
 from dgraph_tpu.obs.metrics import default_registry
+from dgraph_tpu.ops import local as local_ops
 
 V, E_HALF, F = 600, 2500, 256  # two 128-column chunks a layer
 
@@ -144,6 +145,58 @@ def test_gradients_match_the_composed_ops_and_the_oracle(
                 got[0], np.float32)[plan_np.n_src_pad:]).sum() > 0
 
 
+@pytest.mark.parametrize("k", [2, 3])
+@pytest.mark.parametrize("world_size", [1, 2, 4])
+def test_a_streamed_table_taken_in_row_parts_gives_the_same_bits(
+        tpu_interpret, monkeypatch, world_size, k):
+    """ISSUE 35: a streamed table slice too large for on-chip memory is
+    gathered in k row parts (``ops.local.row_take``; gcn_papers100m.w4's
+    halo-extended tables). The layer's value and its three gradients are
+    what they were to the bit, on every rank's shard: each gathered row is
+    one table row, chosen, and the VJP never sees the parts."""
+    plan_np = _plan(world_size, seed=world_size)
+    rng = np.random.default_rng(11)
+    n_rows = plan_np.n_src_pad + world_size * plan_np.halo.s_pad
+    whole_bytes = local_ops.GATHER_TABLE_BYTES
+
+    def parts_counted():
+        return default_registry.snapshot()["counters"].get(
+            "gather.row_parts", 0)
+
+    for r in range(world_size):
+        plan = _shard(plan_np, r)
+        table = jnp.asarray(rng.standard_normal((n_rows, F)), jnp.bfloat16)
+        bias = jnp.asarray(
+            rng.standard_normal((plan_np.n_dst_pad, F)), jnp.bfloat16)
+        tgt = jnp.asarray(
+            rng.standard_normal((plan_np.n_dst_pad, F)), jnp.float32)
+        w = jnp.asarray(rng.uniform(0.5, 2.0, plan_np.e_pad), jnp.float32)
+
+        def run():
+            # a fresh function each time: jit caches a trace by identity
+            def loss(t, b, w_):
+                out = collectives.take_scatter_bias_relu(
+                    t, b, plan, "src", "dst", None, w_)
+                return (out.astype(jnp.float32) * tgt).sum()
+
+            before = parts_counted()
+            got = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(table, bias, w)
+            return parts_counted() - before, got
+
+        monkeypatch.setattr(local_ops, "GATHER_TABLE_BYTES", whole_bytes)
+        counted, whole = run()
+        assert counted == 0
+        monkeypatch.setattr(
+            local_ops, "GATHER_TABLE_BYTES", -(-n_rows // k) * 128 * 2)
+        counted, parted = run()
+        assert counted == 2 * k  # two column chunks, traced once
+        for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(parted)):
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                err_msg=f"rank {r}")
+        assert np.abs(np.asarray(parted[1][0], np.float32)).sum() > 0
+
+
 def _engaged_args(plan_np, dtype=jnp.bfloat16):
     plan = _shard(plan_np, 0)
     n_rows = plan_np.n_src_pad + plan_np.world_size * plan_np.halo.s_pad
@@ -214,9 +267,12 @@ def test_a_table_slice_over_on_chip_memory_keeps_the_permutation(
     the size rule of ``map_vertex_chunks``, on the owner-side slice."""
     plan_np = _plan(1, mask_some=False)
     slice_bytes = plan_np.n_dst_pad * 128 * 2  # [n_owner_pad, 128] bf16
-    monkeypatch.setattr(collectives, "ON_CHIP_BYTES", slice_bytes)
+    monkeypatch.setattr(local_ops, "GATHER_TABLE_BYTES", slice_bytes)
     assert _gcn_backward_counts(plan_np) == [4, 0]
-    monkeypatch.setattr(collectives, "ON_CHIP_BYTES", slice_bytes - 1)
+    # in two row parts it could be gathered on chip, but four gathers and
+    # two selects do not beat the permutation: the route asks for ONE part
+    monkeypatch.setattr(local_ops, "GATHER_TABLE_BYTES", slice_bytes - 1)
+    assert local_ops.on_chip_row_parts(plan_np.n_dst_pad, 128 * 2) == 2
     assert _gcn_backward_counts(plan_np) == [0, 4]
 
 
