@@ -42,6 +42,33 @@ from dgraph_tpu.ops import local as local_ops
 # traces (canonical alias lives in utils.timing).
 from dgraph_tpu.utils.timing import named_scope as _scoped  # noqa: E402
 
+# The children of the ``dgraph.halo_*`` scopes, under every lowering
+# (docs/tracing.md has what each one's transposed and rematted form is):
+# ``send_gather`` the gather of the rows to send by ``send_idx``; ``wire``
+# the collective and nothing else; ``scatter_add`` the sum of received rows
+# into the owner's table; ``mask`` the send / receive mask multiplies;
+# ``concat`` ``halo_extend``'s concatenate. The per-layer metrics
+# ``halo_*_ms.train`` match the names in a device trace.
+
+
+def _masked_send(x, idx, msk):
+    """``x[idx] * msk``: the rows one peer is sent, masked in ``x``'s dtype."""
+    with _scoped("send_gather"):
+        rows = x[idx]
+    with _scoped("mask"):
+        return rows * msk[..., None].astype(x.dtype)
+
+
+def _masked_scatter_add(back, send_idx, send_mask, n_pad):
+    """Received rows ``[W, S, F]`` masked and summed into the owner's table
+    by ``send_idx``: the reduction every reverse lowering ends in."""
+    with _scoped("mask"):
+        back = back * send_mask[..., None].astype(back.dtype)
+    flat_idx = send_idx.reshape(-1)
+    with _scoped("scatter_add"):
+        return local_ops.segment_sum(
+            back.reshape(flat_idx.shape[0], -1), flat_idx, n_pad)
+
 
 def resolve_plan_impl(plan: EdgePlan, axis_name) -> str:
     """The halo lowering THIS call site will use — resolved exactly ONCE
@@ -163,12 +190,13 @@ def _overlap_rounds_fwd(x, send_idx, send_mask, axis_name, deltas, W, S,
         peer_row = (me + d) % W
         idx = jnp.take(send_idx, peer_row, axis=0)
         msk = jnp.take(send_mask, peer_row, axis=0)
-        blk = x[idx] * msk[..., None].astype(x.dtype)  # [S, F]
+        blk = _masked_send(x, idx, msk)  # [S, F]
         sends.append(enc(blk) if enc is not None else blk)
-    recvs = [
-        lax.ppermute(s, axis_name, [(i, (i + d) % W) for i in range(W)])
-        for s, d in zip(sends, deltas)
-    ]
+    with _scoped("wire"):
+        recvs = [
+            lax.ppermute(s, axis_name, [(i, (i + d) % W) for i in range(W)])
+            for s, d in zip(sends, deltas)
+        ]
     out = jnp.zeros((W * S, F), x.dtype)
     for d, recv in zip(deltas, recvs):
         src_rank = (me - d) % W
@@ -197,19 +225,18 @@ def _overlap_rounds_rev(h, send_idx, send_mask, n_pad, axis_name, deltas, W, S,
         src_rank = (me - d) % W
         blk = lax.dynamic_slice(h, (src_rank * S, 0), (S, F))
         blocks.append(enc(blk) if enc is not None else blk)
-    recvs = [
-        lax.ppermute(b, axis_name, [(i, (i - d) % W) for i in range(W)])
-        for b, d in zip(blocks, deltas)
-    ]
+    with _scoped("wire"):
+        recvs = [
+            lax.ppermute(b, axis_name, [(i, (i - d) % W) for i in range(W)])
+            for b, d in zip(blocks, deltas)
+        ]
     back = jnp.zeros((W, S, F), h.dtype)
     for d, recv in zip(deltas, recvs):
         peer_row = (me + d) % W
         if dec is not None:
             recv = dec(recv)
         back = lax.dynamic_update_slice(back, recv[None], (peer_row, 0, 0))
-    back = back * send_mask[..., None].astype(back.dtype)
-    flat_idx = send_idx.reshape(-1)
-    return local_ops.segment_sum(back.reshape(W * S, -1), flat_idx, n_pad)
+    return _masked_scatter_add(back, send_idx, send_mask, n_pad)
 
 
 @functools.lru_cache(maxsize=None)
@@ -340,15 +367,16 @@ def _sched_rounds_fwd(x, send_idx, send_mask, axis_name, schedule, W, S,
         start = jnp.asarray(ra["send_start"], jnp.int32)[me]
         idx = jnp.take(send_idx, dst, axis=0)
         msk = jnp.take(send_mask, dst, axis=0)
-        blk = x[idx] * msk[..., None].astype(x.dtype)  # [S, F]
+        blk = _masked_send(x, idx, msk)  # [S, F]
         blk = lax.dynamic_slice(blk, (start, 0), (rows[k], F))
         # encode AFTER the row slice: per-row codecs commute with row
         # slicing, so the wire bytes match the a2a operand's rows exactly
         sends.append(enc(blk) if enc is not None else blk)
-    recvs = [
-        lax.ppermute(s, axis_name, schedule.rounds[k].pairs)
-        for k, s in enumerate(sends)
-    ]
+    with _scoped("wire"):
+        recvs = [
+            lax.ppermute(s, axis_name, schedule.rounds[k].pairs)
+            for k, s in enumerate(sends)
+        ]
     out = jnp.zeros((W * S + c_max, F), x.dtype)
     for k, recv in enumerate(recvs):
         ra = schedule.rank_arrays(k)
@@ -385,13 +413,14 @@ def _sched_rounds_rev(h, send_idx, send_mask, n_pad, axis_name, schedule,
         off = jnp.asarray(ra["slice_off"], jnp.int32)[me]
         blk = lax.dynamic_slice(h, (off, 0), (rows[k], F))
         blocks.append(enc(blk) if enc is not None else blk)
-    recvs = [
-        lax.ppermute(
-            b, axis_name,
-            [(d, s) for (s, d) in schedule.rounds[k].pairs],
-        )
-        for k, b in enumerate(blocks)
-    ]
+    with _scoped("wire"):
+        recvs = [
+            lax.ppermute(
+                b, axis_name,
+                [(d, s) for (s, d) in schedule.rounds[k].pairs],
+            )
+            for k, b in enumerate(blocks)
+        ]
     back = jnp.zeros((W + 1, S, F), h.dtype)
     for k, recv in enumerate(recvs):
         ra = schedule.rank_arrays(k)
@@ -400,9 +429,7 @@ def _sched_rounds_rev(h, send_idx, send_mask, n_pad, axis_name, schedule,
         if dec is not None:
             recv = dec(recv)
         back = lax.dynamic_update_slice(back, recv[None], (plane, start, 0))
-    back = back[:W] * send_mask[..., None].astype(back.dtype)
-    flat_idx = send_idx.reshape(-1)
-    return local_ops.segment_sum(back.reshape(W * S, -1), flat_idx, n_pad)
+    return _masked_scatter_add(back[:W], send_idx, send_mask, n_pad)
 
 
 @functools.lru_cache(maxsize=None)
@@ -557,7 +584,7 @@ def halo_exchange(
         # halo_extend concat and EVERY downstream [E, F] tensor of the
         # layer (caught in the r4 TPU export: the whole edge pipeline ran
         # f32 and the scatter kernel picked its "highest" precision path)
-        send = x[halo.send_idx] * halo.send_mask[..., None].astype(x.dtype)
+        send = _masked_send(x, halo.send_idx, halo.send_mask)
         return send.reshape(-1, F)  # world size 1: mask is all-zero
     impl = _resolve_halo_arg(impl, deltas, W)
     if impl == "overlap":
@@ -579,7 +606,7 @@ def halo_exchange(
             peer_row = (me + d) % W
             idx = jnp.take(halo.send_idx, peer_row, axis=0)
             msk = jnp.take(halo.send_mask, peer_row, axis=0)
-            send = x[idx] * msk[..., None].astype(x.dtype)  # [S, F]
+            send = _masked_send(x, idx, msk)  # [S, F]
             perm = tuple((i, (i + d) % W) for i in range(W))
             # trip = decode(ppermute(encode(.))) wrapped in a custom VJP
             # (the fp8 payload is uint8 — plain AD would drop the
@@ -587,18 +614,21 @@ def halo_exchange(
             trip = make_ppermute_codec(axis_name, perm, wf,
                                        str(jnp.dtype(x.dtype)))
             if trip is None:
-                recv = lax.ppermute(send, axis_name, list(perm))
+                with _scoped("wire"):
+                    recv = lax.ppermute(send, axis_name, list(perm))
             else:
-                recv = trip(send)
+                recv = trip(send)  # opens ``wire`` around its collective
             src_rank = (me - d) % W
             out = lax.dynamic_update_slice(out, recv, (src_rank * S, 0))
         return out
     from dgraph_tpu.wire.codec import make_a2a_codec
 
-    send = x[halo.send_idx] * halo.send_mask[..., None].astype(x.dtype)
+    send = _masked_send(x, halo.send_idx, halo.send_mask)
     trip = make_a2a_codec(axis_name, wf, str(jnp.dtype(x.dtype)))
     if trip is None:
-        recv = lax.all_to_all(send, axis_name, split_axis=0, concat_axis=0)
+        with _scoped("wire"):
+            recv = lax.all_to_all(send, axis_name, split_axis=0,
+                                  concat_axis=0)
     else:
         recv = trip(send)
     return recv.reshape(-1, F)
@@ -665,14 +695,17 @@ def halo_scatter_sum(
                 trip = make_ppermute_codec(axis_name, perm, wf,
                                            str(jnp.dtype(h.dtype)))
                 if trip is None:
-                    recv = lax.ppermute(block, axis_name, list(perm))
+                    with _scoped("wire"):
+                        recv = lax.ppermute(block, axis_name, list(perm))
                 else:
                     recv = trip(block)  # from (me+d)
                 peer_row = (me + d) % W
                 idx = jnp.take(halo.send_idx, peer_row, axis=0)
                 msk = jnp.take(halo.send_mask, peer_row, axis=0)
-                out = out + local_ops.segment_sum(
-                    recv * msk[..., None].astype(h.dtype), idx, n_pad)
+                with _scoped("mask"):
+                    recv = recv * msk[..., None].astype(h.dtype)
+                with _scoped("scatter_add"):
+                    out = out + local_ops.segment_sum(recv, idx, n_pad)
             return out
     h = h.reshape(W, S, F)
     if axis_name is None:
@@ -682,15 +715,15 @@ def halo_scatter_sum(
 
         trip = make_a2a_codec(axis_name, wf, str(jnp.dtype(h.dtype)))
         if trip is None:
-            back = lax.all_to_all(h, axis_name, split_axis=0, concat_axis=0)
+            with _scoped("wire"):
+                back = lax.all_to_all(h, axis_name, split_axis=0,
+                                      concat_axis=0)
         else:
             # cotangent rows ride the wire encoded UNMASKED (the mask
             # applies after decode, below) — same ordering as every
             # round-based reverse lowering, so wire bytes stay identical
             back = trip(h)
-    back = back * halo.send_mask[..., None].astype(back.dtype)
-    flat_idx = halo.send_idx.reshape(-1)
-    return local_ops.segment_sum(back.reshape(flat_idx.shape[0], -1), flat_idx, n_pad)
+    return _masked_scatter_add(back, halo.send_idx, halo.send_mask, n_pad)
 
 
 def _side_index(plan: EdgePlan, side: str) -> jax.Array:
@@ -790,7 +823,8 @@ def halo_extend(
                            schedule=getattr(plan, "halo_schedule", None),
                            wire_format=resolve_plan_wire_format(
                                plan, axis_name))
-    return jnp.concatenate([x, haloed], axis=0)
+    with _scoped("concat"):
+        return jnp.concatenate([x, haloed], axis=0)
 
 
 @_scoped("dgraph.local_take")
@@ -812,7 +846,8 @@ def local_take(full: jax.Array, plan: EdgePlan, side: str) -> jax.Array:
                     plan.scatter_block_e, plan.scatter_block_n, plan.halo_sort_mc
                 ),
             )
-            return taken * plan.edge_mask[:, None].astype(full.dtype)
+            with _scoped("mask"):
+                return taken * plan.edge_mask[:, None].astype(full.dtype)
         sorted_ids = False
     else:
         # owner-side ids are plan-sorted; route the VJP (a scatter-sum
@@ -827,7 +862,8 @@ def local_take(full: jax.Array, plan: EdgePlan, side: str) -> jax.Array:
         full, idx, indices_are_sorted=sorted_ids, pallas_hints=hints,
         gather_mv=plan.gather_mv,
     )
-    return taken * plan.edge_mask[:, None].astype(full.dtype)
+    with _scoped("mask"):
+        return taken * plan.edge_mask[:, None].astype(full.dtype)
 
 
 @_scoped("dgraph.gather")
@@ -1289,9 +1325,10 @@ def _tsbr_bwd(stream_side, owner_side, axis_name, res, g):
     from dgraph_tpu.obs.metrics import default_registry
 
     vjps, transposed = res
+    chunks = len(_chunk_slices(g.shape[-1], None))
+    default_registry.counter("gather.bwd_chunks", chunks)
     if transposed is None:
-        default_registry.counter(
-            "gather.bwd_permuted", len(_chunk_slices(g.shape[-1], None)))
+        default_registry.counter("gather.bwd_permuted", chunks)
         return (*vjps(g), None)
     table, bias, edge_weight, plan = transposed
     cdt = table.dtype
@@ -1326,12 +1363,14 @@ def _tsbr_bwd(stream_side, owner_side, axis_name, res, g):
             # edge's owner id clamps onto the last row; its sorted id is
             # the route's sentinel, past every vertex block, so no
             # one-hot column reads the row.
-            g_table = g_cols.astype(cdt)
-            if d_table:
-                g_table = local_ops.run_after(d_table[-1], g_table)
+            with _scoped("slice"):
+                g_table = g_cols.astype(cdt)
+                if d_table:
+                    g_table = local_ops.run_after(d_table[-1], g_table)
             g_rows = local_ops.row_take(g_table, owner_ids)
-            bias_rows = local_ops.row_take(
-                local_ops.run_after(g_rows, bias)[:, sl].astype(cdt), owner_ids)
+            with _scoped("slice"):
+                bias_table = local_ops.run_after(g_rows, bias)[:, sl].astype(cdt)
+            bias_rows = local_ops.row_take(bias_table, owner_ids)
         with _scoped("dgraph.scatter_bias_relu"):
             d_table.append(local_ops.sorted_segment_grad_bias_relu(
                 bias_rows, g_rows, plan.halo_sorted_ids, table[:, sl],
@@ -1370,7 +1409,7 @@ def take_scatter_bias_relu(
     after the other, as the forward does. d_bias and d_w are the fused
     scatter's own. Elsewhere every VJP runs as it did. Which route a
     chunk's traced backward took is counted: ``gather.bwd_transposed`` /
-    ``gather.bwd_permuted`` (docs/tracing.md)."""
+    ``gather.bwd_permuted`` of ``gather.bwd_chunks`` (docs/tracing.md)."""
     return _take_scatter_bias_relu(
         table, bias, edge_weight, plan, stream_side, owner_side, axis_name)
 

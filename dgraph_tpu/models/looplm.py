@@ -153,8 +153,8 @@ class _Kernel(nn.Module):
 
 class HeldExpertsFFN(nn.Module):
     """``(norm's float32 output [T, d]) -> (the held experts' part [T, d]
-    float32, stats)``; scope ``dgraph.lm.moe`` with ``router``, ``dispatch``,
-    ``experts``, ``combine``."""
+    float32, stats)``; scope ``dgraph.lm.moe`` with ``router``, ``routes``,
+    ``dispatch``, ``experts``, ``combine``."""
 
     spec: HeldExperts
     comm: Any
@@ -483,7 +483,7 @@ class LoopLM(nn.Module):
         rope = rotary_tables(positions, self.head_dim, self.rope_theta)
         _, hs = self.stack(self.embed(tokens), rope)
         # [loop_steps, T_loc, hidden]; with expert layers also their counts,
-        # [loop_steps, num_layers, 4] (parallel.expert.HELD_STATS)
+        # [loop_steps, num_layers, 5] (parallel.expert.HELD_STATS)
         return hs
 
     def attention_mask(self, seq_len: int):

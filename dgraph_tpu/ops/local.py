@@ -29,6 +29,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# The device operations of a local gather run under three named scopes, the
+# children of ``dgraph.local_take`` (docs/tracing.md): ``rows`` a row gather,
+# ``mask`` a select or mask pass over gathered rows, ``slice`` a table slice,
+# its tie and its copy. The names are what the per-layer metrics
+# ``gather_rows_ms.*`` / ``gather_mask_ms.*`` match in a device trace.
+
 
 @jax.custom_jvp
 def run_after(token, cols):
@@ -111,16 +117,20 @@ def _take_in_row_parts(chunk, idx, k, oob):
         idx = jnp.clip(idx, 0, n - 1)
     taken = []
     for lo in range(0, n, rows):
-        part = (run_after(taken[-1], chunk) if taken else chunk)[lo : lo + rows]
-        taken.append(jnp.take(
-            part, jnp.clip(idx - lo, 0, part.shape[0] - 1), axis=0,
-            mode="clip"))
+        with jax.named_scope("slice"):
+            part = (run_after(taken[-1], chunk) if taken else chunk)[
+                lo : lo + rows]
+        with jax.named_scope("rows"):
+            taken.append(jnp.take(
+                part, jnp.clip(idx - lo, 0, part.shape[0] - 1), axis=0,
+                mode="clip"))
     default_registry.counter("gather.row_parts", len(taken))
-    out = taken[-1]
-    for p in range(len(taken) - 2, -1, -1):
-        out = jnp.where((idx < (p + 1) * rows)[:, None], taken[p], out)
-    if oob == "fill":
-        out = jnp.where(((idx >= 0) & (idx < n))[:, None], out, 0)
+    with jax.named_scope("mask"):
+        out = taken[-1]
+        for p in range(len(taken) - 2, -1, -1):
+            out = jnp.where((idx < (p + 1) * rows)[:, None], taken[p], out)
+        if oob == "fill":
+            out = jnp.where(((idx >= 0) & (idx < n))[:, None], out, 0)
     return out
 
 
@@ -147,6 +157,10 @@ def row_take(
     (:func:`on_chip_row_parts`, :func:`_take_in_row_parts`): the same
     bits, gcn_papers100m.w4's 207 MB forward tables.
 
+    Every gather runs under the named scope ``rows`` (a part gather's
+    select chain under ``mask``, its slice under ``slice``): children of
+    whatever scope the caller opened, ``dgraph.local_take`` above all.
+
     ``col_block=None`` reads :data:`dgraph_tpu.config.gather_col_block`;
     0 disables splitting. ``oob="fill"`` zeroes out-of-range rows (the
     padding convention VJPs need); "clamp" keeps plain-indexing semantics.
@@ -162,9 +176,10 @@ def row_take(
                 chunk.shape[0], chunk.shape[1] * chunk.dtype.itemsize)
             if k > 1:
                 return _take_in_row_parts(chunk, idx, k, oob)
-        if oob == "fill":
-            return jnp.take(chunk, idx, axis=0, mode="fill", fill_value=0)
-        return chunk[idx]
+        with jax.named_scope("rows"):
+            if oob == "fill":
+                return jnp.take(chunk, idx, axis=0, mode="fill", fill_value=0)
+            return chunk[idx]
 
     F = x.shape[-1]
     if not col_block or F <= col_block:
@@ -196,13 +211,18 @@ def take_values(values: jax.Array, idx: jax.Array, lanes: int = 128,
         return jnp.take(values, idx)
     table = values.reshape(n // lanes, lanes)
     out = []
-    for ids in jnp.split(idx, pieces):
+    with jax.named_scope("slice"):
+        id_pieces = jnp.split(idx, pieces)
+    for ids in id_pieces:
         if out:
-            ids = jax.lax.optimization_barrier((out[-1], ids))[1]
-        rows = jnp.take(table, ids // lanes, axis=0)
-        hit = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1) == (
-            ids % lanes)[:, None]
-        out.append(jnp.where(hit, rows, 0).sum(-1))
+            with jax.named_scope("slice"):
+                ids = jax.lax.optimization_barrier((out[-1], ids))[1]
+        with jax.named_scope("rows"):
+            rows = jnp.take(table, ids // lanes, axis=0)
+        with jax.named_scope("mask"):
+            hit = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1) == (
+                ids % lanes)[:, None]
+            out.append(jnp.where(hit, rows, 0).sum(-1))
     return jnp.concatenate(out)
 
 
@@ -233,10 +253,11 @@ def _make_take_rows(n_rows, sorted_ids, col_block, pallas, block_e, block_n,
 
         def take_kernel(x, idx):
             prec = "default" if x.dtype == jnp.bfloat16 else "highest"
-            return sorted_row_gather(
-                x, idx, max_vblocks=gather_mv, block_e=block_e,
-                block_n=block_n, scatter_mc=mc, precision=prec,
-            )
+            with jax.named_scope("rows"):
+                return sorted_row_gather(
+                    x, idx, max_vblocks=gather_mv, block_e=block_e,
+                    block_n=block_n, scatter_mc=mc, precision=prec,
+                )
 
         return take_kernel
 

@@ -5,8 +5,8 @@ the rows routed to them (ROADMAP R9).
 
 Beyond-reference (SURVEY.md §2.3 lists expert parallelism as absent in the
 reference). The routes (``T * k`` of them, :func:`route_topk`) are ordered by
-expert with two sorts, the rows routed to held experts are gathered into one
-buffer in expert order, the experts run over the buffer, and each token sums
+expert with two sorts (named scope ``routes``), the rows routed to held
+experts are gathered into one buffer in expert order (``dispatch``), the experts run over the buffer, and each token sums
 its gate-weighted rows back: row gathers in both directions of
 differentiation, no scatter and no one-hot matrix. The router learns through
 the gate product.
@@ -113,11 +113,17 @@ class HeldRoutes(NamedTuple):
     valid: jax.Array  # [T, k] routed to a held expert, and inside the buffer
     live: jax.Array  # [rows] this buffer row holds such a route
     group_sizes: jax.Array  # [n_held] rows of each held expert, in order
-    stats: jax.Array  # [4] int32: rows here, most of one expert, dropped,
-    # rows the grouped products' tiles cover (HELD_STATS)
+    stats: jax.Array  # [5] int32: rows here, most of one expert, dropped,
+    # rows the grouped products' tiles cover, rows in the buffer (HELD_STATS)
 
 
-HELD_STATS = ("rows_here", "rows_max_expert", "rows_dropped", "rows_tiled")
+# What one layer counts. Over a step's layers the trainer sums them, except
+# the two ``rows_max_*``, which are maxima: the most rows one expert got, and
+# the most rows ONE layer put into its buffer (how near it came to ``rows``).
+HELD_STATS = ("rows_here", "rows_max_expert", "rows_dropped", "rows_tiled",
+              "rows_max_layer")
+HELD_STATS_MAX = tuple(i for i, name in enumerate(HELD_STATS)
+                       if name.startswith("rows_max_"))
 
 
 def held_routes(experts: jax.Array, *, first_held: int, n_held: int,
@@ -147,7 +153,7 @@ def held_routes(experts: jax.Array, *, first_held: int, n_held: int,
         live=jnp.arange(rows, dtype=jnp.int32) < ends[-1],
         group_sizes=kept,
         stats=jnp.stack([here, sizes.max(), here - ends[-1],
-                         tiles.sum() * tile_rows]).astype(jnp.int32))
+                         tiles.sum() * tile_rows, ends[-1]]).astype(jnp.int32))
 
 
 def _sum_slots(rows: jax.Array, pos: jax.Array, weight: jax.Array,
@@ -250,6 +256,15 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                           preferred_element_type=lhs.dtype)
 
 
+def buffer_rows(tokens: int, k: int, n_held: int,
+                rows: Optional[int] = None) -> int:
+    """Rows of one layer's buffer: ``rows``, and never more than the worst
+    case ``tokens * min(k, n_held)`` (a token's ``k`` experts are distinct),
+    which is also what None asks for."""
+    most = tokens * min(k, n_held)
+    return most if rows is None else min(rows, most)
+
+
 def held_experts_apply(
     x: jax.Array,  # [T, d] this shard's tokens
     gates: jax.Array,  # [T, k] float32, of route_topk
@@ -281,10 +296,10 @@ def held_experts_apply(
         x, gates, experts = (lax.all_gather(a, axis_name, tiled=True)
                              for a in (x, gates, experts))
     T, k = experts.shape
-    most = T * min(k, n_held)
-    rows = most if rows is None else min(rows, most)
-    routes = held_routes(experts, first_held=first_held, n_held=n_held,
-                         rows=rows, tile_rows=min(GROUPED_TILE_ROWS, rows))
+    rows = buffer_rows(T, k, n_held, rows)
+    with jax.named_scope("routes"):
+        routes = held_routes(experts, first_held=first_held, n_held=n_held,
+                             rows=rows, tile_rows=min(GROUPED_TILE_ROWS, rows))
     with jax.named_scope("dispatch"):
         xs = _to_buffer(x, routes)
     with jax.named_scope("experts"):

@@ -576,14 +576,28 @@ def flash_attention_selfcheck(mask=None, group: int = 1,
     causal attention through the splash kernels too (:func:`_flash_dense`),
     so they are checked under the causal mask at THAT head size and grouping.
     True only if everything asked for passed.
+
+    The call is the stage ``setup.attention_selfcheck`` (always on, one a
+    latch asked for; off a TPU it ends at once with ``passed`` false).
     """
+    from dgraph_tpu.obs import spans
+
+    with spans.stage("setup.attention_selfcheck",
+                     mask=CausalMask.name if mask is None else mask.name,
+                     group=group, head_dim=head_dim) as st:
+        passed = jax.default_backend() == "tpu" and _flash_selfcheck()
+        if passed and (mask is not None or head_dim % 128):
+            passed = _splash_selfcheck(
+                mask or CausalMask(0), group, head_dim=head_dim)
+        st.annotate(passed=passed)
+    return passed
+
+
+def _flash_selfcheck() -> bool:
+    """The Mosaic flash kernels against the dense oracle, forward and
+    backward; passing latches ``_flash_verified``."""
     global _flash_verified
 
-    if jax.default_backend() != "tpu":
-        return False
-    if mask is not None or head_dim % 128:
-        return flash_attention_selfcheck() and _splash_selfcheck(
-            mask or CausalMask(0), group, head_dim=head_dim)
     rng = np.random.default_rng(3)
     T, H, D = 256, 2, 128
     q, k, v = (

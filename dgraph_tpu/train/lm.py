@@ -215,11 +215,15 @@ def hidden_states(model, params, tokens, positions):
 
 
 def expert_counts(stats: jax.Array) -> jax.Array:
-    """One step's counts, int32 ``[4]`` (``parallel.expert.HELD_STATS``), of
-    the expert layers' ``[passes, layers, 4]``: sums over the layers; the
-    most rows one expert got is a maximum."""
+    """One step's counts, int32 ``[5]`` (``parallel.expert.HELD_STATS``), of
+    the expert layers' ``[passes, layers, 5]``: sums over the layers; the
+    most rows one expert got and the most rows one layer put into its buffer
+    are maxima."""
+    from dgraph_tpu.parallel.expert import HELD_STATS_MAX
+
     stats = stats.reshape(-1, stats.shape[-1])
-    return stats.sum(0).at[1].set(stats[:, 1].max())
+    most = jnp.asarray(HELD_STATS_MAX)
+    return stats.sum(0).at[most].set(stats[:, most].max(0))
 
 
 def local_loss_sum(model, params, tokens, comm, *, seq_len: int,
@@ -446,7 +450,9 @@ class LMTrainer:
     eval_step: Callable
     startup: dict  # what ran: attention implementation, sizes
     steps_done: int = 0
-    expert_rows_max: int = 0  # most rows one expert got in one layer, so far
+    # the run's maxima so far, by stat: the most rows one expert got in one
+    # layer, the most rows one layer put into its buffer
+    expert_rows_max: dict = dataclasses.field(default_factory=dict)
 
     def feed(self, tokens):
         """One host batch onto the mesh, sharded over the graph axis: ``[T]``
@@ -475,15 +481,16 @@ class LMTrainer:
 
     def _count_expert_rows(self, rows: np.ndarray) -> None:
         """The step's expert-layer counts into the registry: ``moe.rows_*``
-        (``parallel.expert.HELD_STATS``) beside the rows routed anywhere."""
-        from dgraph_tpu.parallel.expert import HELD_STATS
+        (``parallel.expert.HELD_STATS``) beside the rows routed anywhere;
+        the ``rows_max_*`` are maxima over the steps too: gauges."""
+        from dgraph_tpu.parallel.expert import HELD_STATS, HELD_STATS_MAX
 
         default_registry.counter("moe.rows_routed", self.startup["moe_routes"])
-        for name, v in zip(HELD_STATS, rows):
-            if name == "rows_max_expert":  # a maximum over the steps: a gauge
-                self.expert_rows_max = max(self.expert_rows_max, int(v))
-                default_registry.gauge("moe.rows_max_expert",
-                                       self.expert_rows_max)
+        for i, (name, v) in enumerate(zip(HELD_STATS, rows)):
+            if i in HELD_STATS_MAX:
+                most = max(self.expert_rows_max.get(name, 0), int(v))
+                self.expert_rows_max[name] = most
+                default_registry.gauge(f"moe.{name}", most)
             else:
                 default_registry.counter(f"moe.{name}", float(v))
 
@@ -574,11 +581,17 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     experts = getattr(model, "experts", None)
     if experts is not None:
         rows = (mask.rows if mask is not None else seq_len)
+        from dgraph_tpu.parallel.expert import buffer_rows
+
         startup.update(
             experts_held=experts.n_held, experts_total=experts.n_total,
-            moe_routes=rows * experts.k * expert_layers * loops)
+            moe_routes=rows * experts.k * expert_layers * loops,
+            moe_buffer_rows=buffer_rows(  # one layer's
+                rows, experts.k, experts.n_held, experts.rows))
         default_registry.counter("moe.experts_held", experts.n_held)
         default_registry.counter("moe.experts_total", experts.n_total)
+        default_registry.counter("moe.buffer_rows",
+                                 startup["moe_buffer_rows"])
     kw = dict(seq_len=seq_len, beta=beta, loss_block=loss_block,
               param_specs=specs)
     return LMTrainer(

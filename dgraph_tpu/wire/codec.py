@@ -161,8 +161,10 @@ def make_a2a_codec(axis_name: str, fmt_name: str, dtype_name: str):
         return None
 
     def _trip(v):
-        return dec(lax.all_to_all(enc(v), axis_name,
-                                  split_axis=0, concat_axis=0))
+        v = enc(v)
+        with jax.named_scope("wire"):  # the collective and nothing else
+            v = lax.all_to_all(v, axis_name, split_axis=0, concat_axis=0)
+        return dec(v)
 
     @jax.custom_vjp
     def wire_a2a(x):
@@ -185,7 +187,10 @@ def make_ppermute_codec(axis_name: str, perm: tuple, fmt_name: str,
     inv_perm = tuple((d, s) for s, d in fwd_perm)
 
     def _trip(v, p):
-        return dec(lax.ppermute(enc(v), axis_name, p))
+        v = enc(v)
+        with jax.named_scope("wire"):
+            v = lax.ppermute(v, axis_name, p)
+        return dec(v)
 
     @jax.custom_vjp
     def wire_pp(x):

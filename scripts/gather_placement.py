@@ -12,12 +12,16 @@ prints, before each of the tool's ``<train|eval> step, per chip`` lines,
 
 (every row gather of the module: ``local_take``'s forward gathers, the
 fused layer's backward gathers from the owner-side vertex tables, the
-edge weights' row gathers, the halo exchange's send gathers) and after
-them the ties ``map_vertex_chunks`` traced (``gather.chunks_sequenced``; it
+edge weights' row gathers, the halo exchange's send gathers), the same count
+by the child scope that named each gather (``by child scope: rows 36 of 36,
+send_gather 3 of 6``: the names the per-layer metrics ``gather_rows_ms.*`` and
+``halo_send_gather_ms.train`` read in a device trace, docs/tracing.md) and
+after them the ties ``map_vertex_chunks`` traced (``gather.chunks_sequenced``; it
 ties where a table slice can be gathered from on-chip memory, whole or in
 row parts), the row parts ``row_take`` cut its gathers into
 (``gather.row_parts``) and the routes the fused layer's backward took
-(``gather.bwd_transposed`` / ``gather.bwd_permuted``).
+(``gather.bwd_transposed`` / ``gather.bwd_permuted`` of ``gather.bwd_chunks``:
+the per-layer metric ``gather_bwd_transposed_pct.train``).
 A table left in HBM is named with its size: until PR 33 the train step of
 ``gcn_arxiv.w1`` read ``4 of 8; in HBM: bf16[2332672,128] (597.2 MB)``,
 the backward's gathers by ``halo_sort_perm`` out of an ``[E, C]`` edge
@@ -35,10 +39,37 @@ chip run, and no time comes from here.
 from __future__ import annotations
 
 import os
+import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+
+
+# the scopes a row gather runs under (``ops/local.py``, ``comm/collectives.py``)
+CHILD = re.compile(r"/(rows|send_gather|scatter_add)/")
+
+
+def by_child_line(gathers: list, compiled_text: str) -> str:
+    """``placement_line``'s count, by the child scope in each gather's
+    ``op_name`` or, for a gather that a ``jit`` of its own traced (its
+    ``op_name`` is the bare primitive), in that of the fusion that calls its
+    computation."""
+    callers = {}
+    for line in compiled_text.splitlines():
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if called and name:
+            callers[called[1]] = name[1]
+    groups = {}
+    for g in gathers:
+        m = CHILD.search(g["op_name"]) or CHILD.search(
+            callers.get(g["computation"], ""))
+        placed = groups.setdefault(m[1] if m else "(none)", [0, 0])
+        placed[0] += g["memory_space"] != 0
+        placed[1] += 1
+    return "by child scope: " + ", ".join(
+        f"{name} {on} of {n}" for name, (on, n) in sorted(groups.items()))
 
 
 def main() -> int:
@@ -53,8 +84,10 @@ def main() -> int:
 
     def compile_and_read(self, *args, **kwargs):
         compiled = compile_lowered(self, *args, **kwargs)
-        print(placement_line(gather_table_placement(compiled.as_text())),
-              flush=True)
+        text = compiled.as_text()
+        gathers = gather_table_placement(text)
+        print(placement_line(gathers), flush=True)
+        print(by_child_line(gathers, text), flush=True)
         return compiled
 
     jax.stages.Lowered.compile = compile_and_read
@@ -69,7 +102,9 @@ def main() -> int:
           f"gather.row_parts: {counters.get('gather.row_parts', 0):.0f}; "
           f"gather.bwd_transposed / gather.bwd_permuted: "
           f"{counters.get('gather.bwd_transposed', 0):.0f} / "
-          f"{counters.get('gather.bwd_permuted', 0):.0f} (the train step)")
+          f"{counters.get('gather.bwd_permuted', 0):.0f} of "
+          f"gather.bwd_chunks {counters.get('gather.bwd_chunks', 0):.0f} "
+          f"(the train step)")
     return rc
 
 

@@ -12,7 +12,12 @@ say under the profiler. One process, on the chip:
 2. One more ``fit()`` with the tracer on under ``jax.profiler``, reduced
    with ``benchmark/xtrace.py``'s interval functions: the device's idle
    gaps by the PROGRAM's span names (``step_dispatch``, ``block``), the idle
-   share, and ``train.step``'s mean on the profiler's clock.
+   share, ``train.step``'s mean on the profiler's clock, and the device time
+   a step under each of the program's layer scopes (``dgraph.local_take``,
+   ``dgraph.halo_exchange``, ...) with, beside each sum, its child scopes'
+   (``rows``, ``mask``, ``slice``; ``send_gather``, ``wire``, ...:
+   docs/tracing.md): every operation of the traced steps is in it, not the
+   ten costliest.
 
 Without a TPU it exits 2; ``--tiny-cpu`` is the explicit tiny mode (code path
 only: its times say nothing about the chip). Writes
@@ -25,6 +30,7 @@ import argparse
 import gzip
 import json
 import os
+import re
 import shutil
 import statistics
 import sys
@@ -59,6 +65,33 @@ def periods(fit_once, epochs: int) -> list:
     return [b - a for a, b in zip(called, called[1:])][WARM:]
 
 
+# a layer scope of an operation's path, and the scope opened under it (a
+# lower-case name followed by more of the path: a primitive ends the path)
+SCOPE = re.compile(r"(dgraph\.[\w.]+)\)*/(?:([a-z_]+)/)?")
+
+
+def scope_sums(trace, phase: str = "train") -> dict:
+    """``{layer scope: {"all": ms, child: ms, ...}}`` a traced step, averaged
+    over the devices: each operation under its innermost ``dgraph.*`` scope,
+    and under the child scope that follows it, if one does."""
+    from benchmark import xtrace
+
+    by_dev = xtrace.phase_ops(trace, phase)
+    share = 1e3 / max(len(by_dev), 1) / len(trace.phases[phase]["steps"])
+    out = {}
+    for ops in by_dev.values():
+        for o in ops:
+            found = SCOPE.findall(o.scope)
+            if not found:
+                continue
+            scope, child = found[-1]
+            row = out.setdefault(scope, {"all": 0.0})
+            row["all"] += o.dur * share
+            if child:
+                row[child] = row.get(child, 0.0) + o.dur * share
+    return out
+
+
 def reduce_trace(trace_dir: str) -> dict:
     """fit()'s own spans and the device's operations, on one clock."""
     from benchmark import xtrace
@@ -90,6 +123,11 @@ def reduce_trace(trace_dir: str) -> dict:
         out.update(xtrace.account(trace, "train"))
         out["device_idle_pct"] = 100.0 * out["idle_ms"] / out["step_ms"]
         out["idle_gaps"] = xtrace.breakdown(trace)["idle_gaps"]
+        out["scope_ms"] = scope_sums(trace)
+        for scope, row in sorted(out["scope_ms"].items()):
+            print(f"[span_cost] {scope}: " + " ".join(
+                f"{k}={v:.3f}" for k, v in row.items()) + " ms a step",
+                flush=True)
     return out
 
 

@@ -438,10 +438,12 @@ def ouro_and_sdar_tiny():
 
 # sha256[:16] of (the parameter tree's shapes, the train step's jaxpr) at
 # commit e47b801 (PR 33), before a pattern, a router form or a second head
-# size existed: no pattern given = that program
+# size existed: no pattern given = that program. SDAR's jaxpr is that one
+# with PR 36's fifth count in every expert layer's stats (``rows_max_layer``;
+# 9e32b1d66760a135 until then); its tree and both of Ouro's are e47b801's.
 PARENT_PROGRAMS = {
     "ouro": ("001bbafd6c891dd1", "36ed7e9025061d24"),
-    "sdar": ("7aabb5340b3078f4", "9e32b1d66760a135"),
+    "sdar": ("7aabb5340b3078f4", "f89e56a41ea5fa01"),
 }
 
 

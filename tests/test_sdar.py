@@ -100,7 +100,7 @@ def test_loss_and_every_gradient_leaf_match_reference(seeded, batch, reference):
         assert float(jnp.linalg.norm(g - ref[path])) <= 3e-4 * scale, path
     # the program's counts are the reference's routing, counted
     held = np.asarray(chosen) < 4
-    assert stats.shape == (1, 2, 4)
+    assert stats.shape == (1, 2, 5)
     assert (np.asarray(stats)[0, :, 0] == held.sum((1, 2))).all()
     assert (np.asarray(stats)[0, :, 2] == 0).all()
 
@@ -116,7 +116,7 @@ def test_reference_follows_adamw_like_the_trainer(seeded, batch, reference):
     for _ in range(3):
         params, state, sm = step(params, state, dev)
         losses.append(float(sm.loss))
-    assert sm.moe_rows.shape == (4,) and int(sm.moe_rows[2]) == 0
+    assert sm.moe_rows.shape == (5,) and int(sm.moe_rows[2]) == 0
     got = reference.follow(jax.device_get(seeded), [batch] * 3, SIZE)
     np.testing.assert_allclose(losses, got["loss"], rtol=3e-5)
     delta = jax.tree.map(lambda a, b: float(jnp.linalg.norm(a - b)), params, seeded)
