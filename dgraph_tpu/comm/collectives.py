@@ -827,11 +827,15 @@ def halo_extend(
         return jnp.concatenate([x, haloed], axis=0)
 
 
-@_scoped("dgraph.local_take")
-def local_take(full: jax.Array, plan: EdgePlan, side: str) -> jax.Array:
-    """The LOCAL half of :func:`gather`: per-edge rows taken from the
-    (already halo-extended) vertex table. No collectives; masked edges are
-    zero."""
+def _side_rows(full: jax.Array, plan: EdgePlan, side: str,
+               oob: str = "fill") -> jax.Array:
+    """The row-taking half of :func:`local_take`: row ``side``-index[e] of
+    the (already halo-extended) vertex table an edge slot, unmasked. A
+    padded slot reads what its id says: row 0 on the halo side, a zero row
+    on the owner side, whose ``n_owner_pad`` is out of range.
+    ``oob="clamp"`` is for a caller whose ids are all in range:
+    ``ops.local.row_take``'s plain indexing, without the select over the
+    rows by which ``"fill"`` zeroes an out-of-range one."""
     from dgraph_tpu import config as _cfg
 
     idx = _side_index(plan, side)
@@ -840,14 +844,13 @@ def local_take(full: jax.Array, plan: EdgePlan, side: str) -> jax.Array:
         # plan's sorting permutation still gives the VJP a sorted
         # segment-sum path (gather-by-perm first) when present
         if plan.halo_sort_perm is not None:
-            taken = local_ops.take_rows_sort_route(
+            return local_ops.take_rows_sort_route(
                 full, idx, plan.halo_sort_perm, plan.halo_sorted_ids,
                 pallas_hints=(
                     plan.scatter_block_e, plan.scatter_block_n, plan.halo_sort_mc
                 ),
+                oob=oob,
             )
-            with _scoped("mask"):
-                return taken * plan.edge_mask[:, None].astype(full.dtype)
         sorted_ids = False
     else:
         # owner-side ids are plan-sorted; route the VJP (a scatter-sum
@@ -858,12 +861,36 @@ def local_take(full: jax.Array, plan: EdgePlan, side: str) -> jax.Array:
         if (sorted_ids and _cfg.pallas_scatter_enabled())
         else None
     )
-    taken = local_ops.take_rows(
+    return local_ops.take_rows(
         full, idx, indices_are_sorted=sorted_ids, pallas_hints=hints,
-        gather_mv=plan.gather_mv,
+        gather_mv=plan.gather_mv, oob=oob,
     )
+
+
+@_scoped("dgraph.local_take")
+def local_take(full: jax.Array, plan: EdgePlan, side: str) -> jax.Array:
+    """The LOCAL half of :func:`gather`: per-edge rows taken from the
+    (already halo-extended) vertex table. No collectives; masked edges are
+    zero."""
+    taken = _side_rows(full, plan, side)
     with _scoped("mask"):
         return taken * plan.edge_mask[:, None].astype(full.dtype)
+
+
+@_scoped("dgraph.local_take")
+def _local_take_unmasked(full: jax.Array, plan: EdgePlan, side: str) -> jax.Array:
+    """:func:`local_take` without its ``[E, F]`` pass after the gather, for
+    the one caller whose aggregation drops a padded edge by its ID
+    (:func:`take_scatter_bias_relu`; the property of every ``EdgePlan`` it
+    rests on is stated at ``EdgePlan.halo_sort_perm``). That pass is ONE
+    fusion of two things (``select_multiply_fusion`` in the compiled
+    module): the edge-mask multiply and the select by which an
+    out-of-range id reads a zero row. Neither is made here for the halo
+    side, whose ids are all in range (a padded slot's is 0, so its row is
+    table row 0, not zeros); the owner side's padded id is out of range and
+    keeps its select."""
+    return _side_rows(full, plan, side,
+                      oob="clamp" if side == plan.halo_side else "fill")
 
 
 @_scoped("dgraph.gather")
@@ -1269,10 +1296,22 @@ def _transposed_bwd_applies(table, bias, plan: EdgePlan, stream_side: str,
 
 def _take_then_scatter(table, bias, edge_weight, plan, stream_side,
                        owner_side, axis_name, chunk_fn=None):
-    """The layer's forward: per column chunk, ``scatter_bias_relu`` of
-    ``local_take``, the chunks in :func:`map_vertex_chunks`' order.
+    """The layer's forward: per column chunk, ``scatter_bias_relu`` of the
+    chunk's per-edge rows, the chunks in :func:`map_vertex_chunks`' order.
     ``chunk_fn(edata, bias_chunk)`` stands in for the fused scatter when
-    the caller wants more than its value (its VJP)."""
+    the caller wants more than its value (its VJP).
+
+    The rows are taken UNMASKED (:func:`_local_take_unmasked`):
+    ``edge_mask`` is the padding mask, and ``scatter_bias_relu`` drops a
+    padded edge by its owner-side id ``n_owner_pad`` on every route it has
+    (the kernels' one-hot and ``[:num_segments]`` slice, the sorted
+    segment-sum off a TPU, ``scatter_sum``'s own mask on the unsorted
+    fallback), as do its ``act`` pass, its VJP (a padded edge's ``gd`` and
+    ``d_w`` read 0: no row of ``g`` has its id) and the take's (the route's
+    sentinel). So the row such a slot gathered reaches no output row,
+    multiplied by zero first or not, and the ``[E, chunk]`` multiply
+    between the gather and the kernel (2.55 ms each of gcn_arxiv.w1's four
+    a step, PERF.md PR 37) is not made."""
 
     def fused(edata, b):
         return scatter_bias_relu(edata, b, plan, owner_side, axis_name,
@@ -1280,7 +1319,7 @@ def _take_then_scatter(table, bias, edge_weight, plan, stream_side,
 
     chunk_fn = chunk_fn or fused
     return map_vertex_chunks(
-        lambda t, b: chunk_fn(local_take(t, plan, stream_side), b),
+        lambda t, b: chunk_fn(_local_take_unmasked(t, plan, stream_side), b),
         (table, bias),
     )
 
@@ -1395,8 +1434,24 @@ def take_scatter_bias_relu(
     """The fused GCN layer's aggregation, out[v] = Σ_{e: owner_e = v} w_e ·
     relu(table[stream_e] + bias[v]), as ONE op with one VJP. The forward
     is ``scatter_bias_relu(local_take(table), bias)`` a column chunk, the
-    chunks in :func:`map_vertex_chunks`' order. The gradient to ``table``,
-    which the two ops' own VJPs compute by writing gd[e] = w_e ·
+    chunks in :func:`map_vertex_chunks`' order, without ``local_take``'s
+    edge-mask pass: the aggregation drops a padded edge by its id
+    (:func:`_take_then_scatter`).
+
+    CONTRACT: ``plan.edge_mask`` is the PADDING mask and nothing else. A
+    masked slot's owner-side id is ``n_owner_pad`` and its halo-side id 0,
+    as every builder fills it; ``plan._finalize_plan`` and
+    ``plan.assemble_plan`` (a fresh build, a cache load) refuse a plan
+    that breaks this, as ``validate_plan`` does. On the routes that end in
+    a sorted segment-sum (every owner-sorted plan) the op never reads
+    ``edge_mask``: a REAL edge masked out by hand (its owner id in range)
+    would be aggregated here and dropped by ``gather`` / ``scatter_sum``,
+    i.e. by the unfused GCN branch, SAGE and GraphCast. (A mask
+    multiply before the aggregation would not drop such an edge either:
+    its zeroed row still adds ``w_e · relu(bias[v])`` to its owner.)
+
+    The gradient to ``table``, which the two ops' own VJPs compute by
+    writing gd[e] = w_e ·
     g[owner_e] · 1[table[stream_e] + bias[owner_e] > 0] as an ``[E,
     chunk]`` tensor in owner-sorted order, permuting it by
     ``halo_sort_perm`` and segment-summing it, runs as the transposed

@@ -228,7 +228,7 @@ def take_values(values: jax.Array, idx: jax.Array, lanes: int = 128,
 
 @functools.lru_cache(maxsize=None)
 def _make_take_rows(n_rows, sorted_ids, col_block, pallas, block_e, block_n,
-                    mc, gather_mv=0):
+                    mc, gather_mv=0, oob="fill"):
     """Row gather whose VJP is an explicitly-routed segment reduction.
 
     JAX's default transpose of ``x[idx]`` is a generic XLA scatter-add —
@@ -263,7 +263,7 @@ def _make_take_rows(n_rows, sorted_ids, col_block, pallas, block_e, block_n,
 
     @jax.custom_vjp
     def take(x, idx):
-        return row_take(x, idx, col_block, oob="fill")
+        return row_take(x, idx, col_block, oob=oob)
 
     def fwd(x, idx):
         return take(x, idx), idx
@@ -293,13 +293,17 @@ def take_rows(
     col_block: int | None = None,
     pallas_hints: tuple | None = None,  # (block_e, block_n, max_chunks) or None
     gather_mv: int = 0,  # >0 + config.use_pallas_gather: Pallas fwd kernel
+    oob: str = "fill",
 ) -> jax.Array:
     """``x[idx]`` row gather with a fast-path VJP (see
     :func:`_make_take_rows`). Out-of-range ids produce zero rows (padding
-    convention). ``pallas_hints`` enables the sorted one-hot MXU kernel for
-    the backward when ids are monotone (plan-guaranteed); ``gather_mv``
-    additionally enables the sorted-row-gather FORWARD kernel when
-    ``config.use_pallas_gather`` is pinned on."""
+    convention) unless the caller says its ids are all in range
+    (``oob="clamp"``: :func:`row_take`'s plain indexing, without the
+    ``[E, F]`` select that zeroes a row). ``pallas_hints`` enables the
+    sorted one-hot MXU kernel for the backward when ids are monotone
+    (plan-guaranteed); ``gather_mv`` additionally enables the
+    sorted-row-gather FORWARD kernel when ``config.use_pallas_gather`` is
+    pinned on."""
     from dgraph_tpu import config as _cfg
 
     if col_block is None:
@@ -312,7 +316,8 @@ def take_rows(
     be, bn, mc = pallas_hints if use_pallas else (0, 0, 0)
     mv = gather_mv if (use_pallas and _cfg.pallas_gather_enabled()) else 0
     return _make_take_rows(
-        x.shape[0], indices_are_sorted, col_block, use_pallas, be, bn, mc, mv
+        x.shape[0], indices_are_sorted, col_block, use_pallas, be, bn, mc, mv,
+        oob,
     )(x, idx)
 
 
@@ -414,7 +419,7 @@ def sorted_segment_sum_bias_relu_any(
 
 
 @functools.lru_cache(maxsize=None)
-def _make_take_rows_sortroute(n_rows, col_block, be, bn, mc):
+def _make_take_rows_sortroute(n_rows, col_block, be, bn, mc, oob="fill"):
     """Row gather for UNSORTED ids whose VJP still runs the sorted fast
     path: the plan carries a static permutation ``perm`` with
     ``ids[perm]`` monotone (``EdgePlan.halo_sort_perm``), so the transpose
@@ -423,7 +428,7 @@ def _make_take_rows_sortroute(n_rows, col_block, be, bn, mc):
 
     @jax.custom_vjp
     def take(x, idx, perm, sorted_ids):
-        return row_take(x, idx, col_block, oob="fill")
+        return row_take(x, idx, col_block, oob=oob)
 
     def fwd(x, idx, perm, sorted_ids):
         return take(x, idx, perm, sorted_ids), (perm, sorted_ids)
@@ -439,15 +444,17 @@ def _make_take_rows_sortroute(n_rows, col_block, be, bn, mc):
 
 
 def take_rows_sort_route(x, idx, perm, sorted_ids, *, pallas_hints,
-                         col_block=None):
-    """``x[idx]`` (OOB -> 0) with the VJP routed through a plan-provided
-    sorting permutation of ``idx`` (see :func:`_make_take_rows_sortroute`)."""
+                         col_block=None, oob="fill"):
+    """``x[idx]`` (OOB -> 0, or :func:`row_take`'s plain indexing for a
+    caller whose ids are all in range: ``oob="clamp"``) with the VJP routed
+    through a plan-provided sorting permutation of ``idx`` (see
+    :func:`_make_take_rows_sortroute`)."""
     if col_block is None:
         from dgraph_tpu import config as _cfg
 
         col_block = _cfg.gather_col_block
     be, bn, mc = pallas_hints
-    return _make_take_rows_sortroute(x.shape[0], col_block, be, bn, mc)(
+    return _make_take_rows_sortroute(x.shape[0], col_block, be, bn, mc, oob)(
         x, idx, perm, sorted_ids
     )
 

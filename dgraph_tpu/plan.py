@@ -375,7 +375,10 @@ class EdgePlan:
         ``halo_side=='src'`` else ``[0, n_src_pad)``.
       - ``dst_index``: [E] into ``[0, n_dst_pad + W*s_pad)`` if
         ``halo_side=='dst'`` else ``[0, n_dst_pad)``.
-    Padded edges have both indices 0 and ``edge_mask`` 0.
+    ``edge_mask`` is the PADDING mask and nothing else: 1 on the slots real
+    edges were written to, 0 on the padded tail, whose halo-side index is 0
+    and whose owner-side index is the out-of-range ``n_owner_pad``
+    (:func:`validate_plan` holds every plan to it).
     """
 
     # leaves (leading axis = world_size, shard over 'graph')
@@ -429,8 +432,15 @@ class EdgePlan:
     # real edge and its sorted id is the sentinel halo_sort_sentinel(), the
     # first id past the kernel's last vertex block, so no block's chunk
     # range holds it and halo_sort_mc is the widest block of REAL edges.
-    # The rows it no longer reaches are exact zeros (gather and scatter
-    # multiply by edge_mask before the route).
+    # The rows it no longer reaches are exact zeros where gather and
+    # scatter multiply by edge_mask before the route, and are not looked
+    # at where they do not: the fused GCN layer
+    # (comm.collectives.take_scatter_bias_relu) takes its per-edge rows
+    # UNMASKED and relies on the ids alone. A masked slot is a padded one,
+    # its owner-side id is n_owner_pad and its sorted id this sentinel, so
+    # every aggregation by either id drops it (segment reductions drop an
+    # out-of-range id, the kernels' one-hot finds no output row for it),
+    # in the forward, its backward and the transposed route.
     halo_sort_perm: Any = None  # i32[W, E] or None
     halo_sorted_ids: Any = None  # i32[W, E] or None
     # the OWNER-side index in the same order (owner_index[halo_sort_perm];
@@ -815,6 +825,46 @@ def plan_efficiency(plan: EdgePlan, layout: EdgePlanLayout) -> dict:
     }
 
 
+def _padding_mask_errors(src, dst, mask, halo_side: str, n_src_pad: int,
+                         n_dst_pad: int) -> list:
+    """What is wrong with ``edge_mask`` as the PADDING mask of ``[R, E]``
+    index rows (``mask`` boolean): a masked slot must carry the out-of-range
+    owner-side id ``n_owner_pad`` and the halo-side id 0, as every builder
+    fills it. The fused GCN layer
+    (``comm.collectives.take_scatter_bias_relu``) takes its per-edge rows
+    unmasked and drops a padded edge by these ids alone, so a plan that
+    masks a REAL edge (its owner id in range) would have that edge
+    aggregated there and dropped by every other model. Held where plans
+    enter the program (:func:`_finalize_plan`, :func:`assemble_plan`) and
+    by :func:`validate_plan`; a row at a time, so the transient is one
+    shard's."""
+    owner_idx, n_owner_pad, halo_idx = (
+        (dst, n_dst_pad, src) if halo_side == "src" else (src, n_src_pad, dst))
+    errors = []
+    for r in range(len(mask)):
+        pad = ~mask[r]
+        if (owner_idx[r][pad] != n_owner_pad).any():
+            errors.append(
+                f"row {r}: a masked edge's owner-side index is not "
+                f"n_owner_pad ({n_owner_pad}): edge_mask must be the "
+                f"padding mask")
+        if (halo_idx[r][pad] != 0).any():
+            errors.append(
+                f"row {r}: a masked edge's halo-side index is not 0")
+    return errors
+
+
+def _require_padding_mask(src, dst, edge_mask, halo_side, n_src_pad,
+                          n_dst_pad) -> None:
+    """Raise where a plan enters the program if its ``edge_mask`` is not
+    the padding mask (:func:`_padding_mask_errors`)."""
+    errors = _padding_mask_errors(
+        np.asarray(src), np.asarray(dst), np.asarray(edge_mask) > 0,
+        halo_side, n_src_pad, n_dst_pad)
+    if errors:
+        raise ValueError("invalid EdgePlan: " + "; ".join(errors))
+
+
 def validate_plan(plan: EdgePlan) -> None:
     """Host-side structural validation (the index-bounds asserts the
     reference scatters through its kernels, ``RankLocalOps.py:183-184``;
@@ -846,6 +896,8 @@ def validate_plan(plan: EdgePlan) -> None:
     counts = np_.asarray(plan.num_edges)
     if (counts > plan.e_pad).any():
         errors.append("num_edges exceeds e_pad")
+    errors += _padding_mask_errors(
+        src, dst, mask, plan.halo_side, plan.n_src_pad, plan.n_dst_pad)
     if plan.halo_sort_perm is not None:
         # sorted route: perm must be a permutation of [0, e_pad) per shard
         # and the recorded sorted ids monotone and equal to the key
@@ -1462,6 +1514,8 @@ def _finalize_plan(
     scheduling hints, EdgePlan/EdgePlanLayout construction, efficiency log.
     Keeping it in one place means a plan-format change cannot silently
     diverge between the two paths."""
+    _require_padding_mask(src_idx_arr, dst_idx_arr, edge_mask, halo_side,
+                          n_src_pad_val, n_dst_pad_val)
     n_owner_pad = n_dst_pad_val if edge_owner == "dst" else n_src_pad_val
     owner_idx_arr = dst_idx_arr if edge_owner == "dst" else src_idx_arr
     scatter_block_e, scatter_block_n = SCATTER_BLOCK_E, SCATTER_BLOCK_N
@@ -2179,6 +2233,12 @@ def assemble_plan(manifest: dict, payloads: dict, ranks: list) -> EdgePlan:
     def counts(key):
         return np.asarray([payloads[r][key] for r in ranks], np.int32)
 
+    # a cached or hand-edited shard is held to the padding convention as
+    # a fresh build is (_finalize_plan)
+    src_index, dst_index, edge_mask = (
+        stack("src_index"), stack("dst_index"), stack("edge_mask"))
+    _require_padding_mask(src_index, dst_index, edge_mask, st["halo_side"],
+                          int(st["n_src_pad"]), int(st["n_dst_pad"]))
     sort_route = st.get("sort_route", False)
     pair_rows = tuple(
         tuple(int(v) for v in row) for row in st.get("halo_pair_rows", [])
@@ -2204,9 +2264,9 @@ def assemble_plan(manifest: dict, payloads: dict, ranks: list) -> EdgePlan:
             boundary_mc=int(st.get("boundary_mc", 1)),
         )
     return EdgePlan(
-        src_index=stack("src_index"),
-        dst_index=stack("dst_index"),
-        edge_mask=stack("edge_mask"),
+        src_index=src_index,
+        dst_index=dst_index,
+        edge_mask=edge_mask,
         num_local_src=counts("num_local_src"),
         num_local_dst=counts("num_local_dst"),
         num_edges=counts("num_edges"),

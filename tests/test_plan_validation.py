@@ -36,6 +36,86 @@ def test_corrupted_plan_caught(rng):
         validate_plan(bad2)
 
 
+def _masked(plan, how):
+    """``plan`` with ``edge_mask`` no longer the padding mask: a REAL edge
+    masked out with its ids left in place (``real_edge``: what a hand-made
+    "drop these edges" mask is), or a padded slot whose halo-side id is
+    not 0 (``halo_id``)."""
+    import dataclasses
+
+    mask = np.asarray(plan.edge_mask).copy()
+    src = np.asarray(plan.src_index).copy()
+    rank = int(np.argmax(np.asarray(plan.num_edges) > 0))
+    if how == "real_edge":
+        mask[rank, np.flatnonzero(mask[rank] > 0)[0]] = 0
+    else:
+        assert plan.halo_side == "src"
+        src[rank, np.flatnonzero(mask[rank] == 0)[-1]] = 1
+    return dataclasses.replace(plan, edge_mask=mask, src_index=src)
+
+
+@pytest.mark.parametrize("how,match", [
+    ("real_edge", "owner-side index is not n_owner_pad"),
+    ("halo_id", "halo-side index is not 0"),
+])
+@pytest.mark.parametrize("sort_route", [True, False])
+def test_an_edge_mask_that_is_not_the_padding_mask_is_refused(
+        rng, how, match, sort_route):
+    """``edge_mask`` is the padding mask and nothing else: the fused GCN
+    layer (``take_scatter_bias_relu``) never reads it and drops a padded
+    edge by its ids, so a masked REAL edge would be aggregated there and
+    dropped by every other model. ``validate_plan`` refuses such a plan,
+    with or without the sorted route, under each of its two checks."""
+    edges = rng.integers(0, 64, size=(2, 400))
+    part = np.sort(rng.integers(0, 4, 64)).astype(np.int32)
+    plan, _ = pl.build_edge_plan(
+        edges, part, world_size=4, sort_route=sort_route)
+    validate_plan(plan)
+    with pytest.raises(ValueError, match=match):
+        validate_plan(_masked(plan, how))
+
+
+@pytest.mark.parametrize("how", ["real_edge", "halo_id"])
+def test_a_cached_shard_with_a_masked_real_edge_does_not_load(
+        rng, tmp_path, monkeypatch, how):
+    """The same two checks where a plan ENTERS the program: a shard of the
+    on-disk artifact edited by hand (or written by an older builder) is
+    refused by ``assemble_plan``, so it cannot reach the fused layer
+    unseen; the builders' shared tail ``_finalize_plan`` holds a fresh
+    build to them too."""
+    from dgraph_tpu import plan_shards as ps
+
+    edges = rng.integers(0, 64, size=(2, 400))
+    part = np.sort(rng.integers(0, 4, 64)).astype(np.int32)
+    plan_dir = str(tmp_path / "plan")
+    plan, _ = pl.build_edge_plan_sharded(
+        edges, part, out_dir=plan_dir, world_size=4)
+    validate_plan(plan)
+    manifest = ps.read_manifest(plan_dir)
+    payloads = {
+        r: ps.read_shard(plan_dir, r, manifest["shards"][str(r)])
+        for r in range(4)
+    }
+    pl.assemble_plan(manifest, payloads, list(range(4)))  # as built: loads
+    bad = _masked(plan, how)
+    for r in range(4):
+        payloads[r] = dict(
+            payloads[r], edge_mask=np.asarray(bad.edge_mask)[r],
+            src_index=np.asarray(bad.src_index)[r])
+    with pytest.raises(ValueError, match="invalid EdgePlan"):
+        pl.assemble_plan(manifest, payloads, list(range(4)))
+    with pytest.raises(ValueError, match="invalid EdgePlan"):
+        pl._require_padding_mask(
+            bad.src_index, bad.dst_index, bad.edge_mask, bad.halo_side,
+            bad.n_src_pad, bad.n_dst_pad)
+    seen = []
+    monkeypatch.setattr(
+        pl, "_padding_mask_errors", lambda *a: seen.append(a) or [])
+    pl.build_edge_plan(edges, part, world_size=4)
+    pl.build_edge_plan(edges, part, world_size=4, use_native=False)
+    assert len(seen) == 2  # _finalize_plan, whichever core built the rows
+
+
 @pytest.mark.slow
 def test_scale_plan_build_5m_edges(rng):
     """papers100M-direction scale check: 500k vertices / 5M edges through

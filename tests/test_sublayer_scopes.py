@@ -104,8 +104,8 @@ def gcn_step_paths(sbm, world: int, impl: str) -> list:
 def test_a_gcn_train_step_names_the_work_under_its_scopes(
         flags, sbm, world, impl, exchange):
     """Forward and backward, at one device and over a mesh under each
-    lowering: the row gathers and the edge-mask multiplies of the local
-    take, the exchange's send gather, masks and (over a mesh) its
+    lowering: the row gathers of the local take (and no edge-mask multiply:
+    the fused layer's is gone), the exchange's send gather, masks and (over a mesh) its
     collective, ``halo_extend``'s concatenate. A child's name travels with
     its transpose: the backward's operations carry the same children."""
     paths = gcn_step_paths(sbm, world, impl)
@@ -125,9 +125,12 @@ def test_a_gcn_train_step_names_the_work_under_its_scopes(
                    if "transpose(" in p)
     if impl != "overlap":  # the overlap layer takes no concatenated table
         assert "concat" in children(paths, "dgraph.halo_extend", fwd)
+        # the fused layer takes its rows unmasked (ISSUE 37): its
+        # aggregation drops a padded edge by its id, so no edge-mask
+        # multiply follows the gather, forward or transposed
         for direction in (fwd, bwd):
-            assert {"rows", "mask"} <= children(
-                paths, "dgraph.local_take", direction), direction
+            assert children(
+                paths, "dgraph.local_take", direction) == {"rows"}, direction
     else:
         assert "rows" in children(paths, "dgraph.boundary_take", fwd)
     # ``wire`` holds the collective and nothing else
@@ -142,7 +145,7 @@ def test_halo_scatter_sum_names_its_scatter_add(flags, impl):
     receive mask under ``mask``, the sum into the owner's table under
     ``scatter_add``."""
     world = 2
-    plan_np = _plan(world, seed=3, mask_some=False)
+    plan_np = _plan(world, seed=3, long_tail=False)
     mesh = make_graph_mesh(ranks_per_graph=world, devices=jax.devices()[:world])
     h = jnp.ones((world, world * plan_np.halo.s_pad, 8), jnp.float32)
     halo = jax.tree.map(jnp.asarray, plan_np.halo)
@@ -216,6 +219,7 @@ def test_every_operation_of_a_local_take_is_under_one_child_or_none(
     whole = _engaged_grad_paths()
     took = np.subtract(counted(*ROUTES), before)
     assert took.tolist() == [2, 2, 0]  # two chunks, both transposed
+    whole_unweighted = _engaged_grad_paths(weighted=False)
     n_rows = _plan(1, seed=1).n_src_pad + _plan(1, seed=1).halo.s_pad
     monkeypatch.setattr(
         local_ops, "GATHER_TABLE_BYTES", -(-n_rows // 2) * 128 * 2)
@@ -230,6 +234,31 @@ def test_every_operation_of_a_local_take_is_under_one_child_or_none(
     # the part gathers' slices and select chain are the forward's
     fwd_parted = [p for p in parted if "transpose(" not in p]
     assert children(fwd_parted, "dgraph.local_take") >= set(TAKE_CHILDREN)
+    # ``mask`` holds a select chain or ``take_values``' lane select, and no
+    # edge-mask multiply (ISSUE 37): a whole table and no edge weight, none
+    took_children = children(whole_unweighted, "dgraph.local_take")
+    assert {"rows", "slice"} <= took_children and "mask" not in took_children
+    assert "mask" not in children(
+        [p for p in whole if "transpose(" not in p], "dgraph.local_take")
+
+
+def test_local_take_called_directly_keeps_its_mask():
+    """Only the fused layer's take leaves the edge-mask pass out:
+    ``local_take`` called directly, as every other model calls it, lowers
+    a ``mask`` child beside ``rows`` and hands back zero rows in the
+    padded slots, where the unmasked take hands back table row 0."""
+    plan = _shard(_plan(1, seed=1), 0)
+    table = jnp.full((plan.n_src_pad + plan.halo.s_pad, 128), 3, jnp.bfloat16)
+    padded = np.asarray(plan.edge_mask) == 0
+    assert padded.any()
+    for take, kids, fill in (
+            (collectives.local_take, {"rows", "mask"}, 0.0),
+            (collectives._local_take_unmasked, {"rows"}, 3.0)):
+        fn = jax.jit(lambda t, take=take: take(t, plan, "src"))
+        assert children(
+            op_paths(fn.lower(table)), "dgraph.local_take") == kids
+        rows = np.asarray(fn(table), np.float32)
+        assert (rows[padded] == fill).all() and (rows[~padded] == 3.0).all()
 
 
 def test_bwd_chunks_is_the_sum_of_the_two_routes(tpu_interpret):  # noqa: F811

@@ -45,32 +45,66 @@ def tpu_interpret(monkeypatch):
     monkeypatch.setattr(pallas, "pallas_call", interpreted)
 
 
-def _plan(world_size, seed=0, mask_some=True, **build):
-    """A symmetrised random graph's plan, stacked over ranks, with padded
-    edges (every rank's row ends in them) and, with ``mask_some``, a few
-    REAL edges masked out: their route entries move to the sentinel."""
+def _graph(world_size, seed=0):
     rng = np.random.default_rng(seed)
     src, dst = rng.integers(0, V, E_HALF), rng.integers(0, V, E_HALF)
     edges = np.stack([np.concatenate([src, dst]), np.concatenate([dst, src])])
-    part = np.sort(rng.integers(0, world_size, V)).astype(np.int32)
-    plan, _ = pl.build_edge_plan(
-        edges, part, world_size=world_size, edge_owner="dst",
-        pad_multiple=128, **build)
+    return edges, np.sort(rng.integers(0, world_size, V)).astype(np.int32)
+
+
+def _long_e_pad(plan):
+    """Two more whole edge chunks of padding than the plan was built with."""
+    be = plan.scatter_block_e
+    return (-(-plan.e_pad // be) + 2) * be
+
+
+def _plan(world_size, seed=0, long_tail=True, **build):
+    """A symmetrised random graph's plan, stacked over ranks. Every rank's
+    row ends in padded edges, and with ``long_tail`` in more of them than a
+    kernel's edge chunk holds, so whole chunks are padding. ``edge_mask``
+    is the padding mask and nothing else (``validate_plan``)."""
+    edges, part = _graph(world_size, seed)
+    build = dict(world_size=world_size, edge_owner="dst",
+                 pad_multiple=128) | build
+    plan, _ = pl.build_edge_plan(edges, part, **build)
+    if long_tail:
+        plan, _ = pl.build_edge_plan(
+            edges, part, e_pad=_long_e_pad(plan), **build)
     assert (np.asarray(plan.num_edges) < plan.e_pad).all()
-    if mask_some and plan.halo_sort_perm is not None:
-        mask = np.asarray(plan.edge_mask).copy()
-        for r in range(world_size):
-            real = np.flatnonzero(mask[r] > 0)
-            mask[r, rng.choice(real, size=len(real) // 10, replace=False)] = 0
-        n_halo_rows = plan.n_src_pad + world_size * plan.halo.s_pad
-        perm, sids, oids = pl.halo_sort_route(
-            np.asarray(plan.src_index), mask, n_halo_rows,
-            np.asarray(plan.dst_index))
-        plan = dataclasses.replace(
-            plan, edge_mask=mask, halo_sort_perm=perm, halo_sorted_ids=sids,
-            halo_sorted_owner_ids=oids)
-        pl.validate_plan(plan)
+    pl.validate_plan(plan)
     return plan
+
+
+@pytest.mark.parametrize("world_size", [1, 2, 4])
+def test_a_plan_that_masks_real_edges_is_refused(world_size):
+    """Until PR 37 this file's plans masked a tenth of their REAL edges
+    (ids left in place, the route moved to the sentinel) and the fused op
+    was held to ``scatter_bias_relu(local_take())`` on them: both added
+    ``w·relu(bias[v])`` for such an edge, where ``scatter_sum`` and so
+    every unfused model dropped it. The op no longer reads ``edge_mask``
+    at all, so the program states the contract it always needed:
+    ``edge_mask`` is the padding mask, and a plan that masks a real edge is
+    refused, by ``validate_plan`` and where plans enter the program
+    (tests/test_plan_validation.py)."""
+    plan = _plan(world_size, seed=world_size)
+    rng = np.random.default_rng(world_size)
+    mask = np.asarray(plan.edge_mask).copy()
+    for r in range(world_size):
+        real = np.flatnonzero(mask[r] > 0)
+        mask[r, rng.choice(real, size=len(real) // 10, replace=False)] = 0
+    perm, sids, oids = pl.halo_sort_route(
+        np.asarray(plan.src_index), mask,
+        plan.n_src_pad + world_size * plan.halo.s_pad,
+        np.asarray(plan.dst_index))
+    masked = dataclasses.replace(
+        plan, edge_mask=mask, halo_sort_perm=perm, halo_sorted_ids=sids,
+        halo_sorted_owner_ids=oids)
+    with pytest.raises(ValueError, match="edge_mask must be the padding"):
+        pl.validate_plan(masked)
+    with pytest.raises(ValueError, match="edge_mask must be the padding"):
+        pl._require_padding_mask(
+            masked.src_index, masked.dst_index, mask, masked.halo_side,
+            masked.n_src_pad, masked.n_dst_pad)
 
 
 def _shard(plan, r):
@@ -90,10 +124,13 @@ def _oracle(table, bias, w, plan, tgt):
     return (out * tgt).sum()
 
 
-def _counts():
+def _counted(*names):
     c = default_registry.snapshot()["counters"]
-    return np.array([c.get("gather.bwd_transposed", 0),
-                     c.get("gather.bwd_permuted", 0)])
+    return np.array([c.get(n, 0) for n in names])
+
+
+def _counts():
+    return _counted("gather.bwd_transposed", "gather.bwd_permuted")
 
 
 @pytest.mark.parametrize("weighted", [False, True])
@@ -103,8 +140,8 @@ def test_gradients_match_the_composed_ops_and_the_oracle(
         tpu_interpret, world_size, dtype, weighted):
     """Gradients to table, bias and (where it is differentiated) the edge
     weight: bit-equal to the two ops' own VJPs, which the op replaced, and
-    close to plain float32 autodiff; padded and masked edges, and on 2 and
-    4 ranks a table with halo rows."""
+    close to plain float32 autodiff; padded edges (whole chunks of them),
+    and on 2 and 4 ranks a table with halo rows."""
     plan_np = _plan(world_size, seed=world_size)
     dt = jnp.dtype(dtype)
     rng = np.random.default_rng(7)
@@ -160,8 +197,7 @@ def test_a_streamed_table_taken_in_row_parts_gives_the_same_bits(
     whole_bytes = local_ops.GATHER_TABLE_BYTES
 
     def parts_counted():
-        return default_registry.snapshot()["counters"].get(
-            "gather.row_parts", 0)
+        return _counted("gather.row_parts")[0]
 
     for r in range(world_size):
         plan = _shard(plan_np, r)
@@ -197,6 +233,132 @@ def test_a_streamed_table_taken_in_row_parts_gives_the_same_bits(
         assert np.abs(np.asarray(parted[1][0], np.float32)).sum() > 0
 
 
+def _built(kind, tmp_path):
+    """The plan of a case of the unmasked forward: one through each builder
+    that fills ``edge_mask`` (numpy, the native core, the streamed shards),
+    the owner-side padded id ``n_owner_pad`` inside the kernels' last
+    vertex block (640 % 256, as gcn_arxiv's 169 344) or past it (768); a
+    padded tail longer than two edge chunks in all of them."""
+    if kind == "shards":  # the streamed build has no native core
+        plan, _ = pl.build_edge_plan_sharded(
+            *_graph(1, seed=5), out_dir=str(tmp_path / "plan"), world_size=1,
+            edge_owner="dst", pad_multiple=128,
+            e_pad=_long_e_pad(_plan(1, seed=5, long_tail=False)))
+        pl.validate_plan(plan)
+    elif kind == "no_sort_route":  # the take's VJP: a segment-sum by idx
+        plan = _plan(1, seed=5, sort_route=False)
+    elif kind == "unsorted_edges":  # the fallback that ends in scatter_sum
+        plan = _plan(1, seed=5, sort_edges=False)
+    else:
+        plan = _plan(
+            1, seed=5, use_native=kind == "native",
+            pad_multiple=256 if kind == "past_last_block" else 128)
+    in_last_block = plan.n_dst_pad % plan.scatter_block_n != 0
+    assert in_last_block == (kind != "past_last_block")
+    assert plan.e_pad - int(plan.num_edges[0]) > 2 * plan.scatter_block_e
+    return plan
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("kind,route", [
+    (kind, route)
+    for kind in ("in_last_block", "past_last_block", "native", "shards",
+                 "row_parts")
+    for route in ("transposed", "permuted", "off_tpu")
+] + [
+    # no transposed route without the sorted route or the sorted owner ids:
+    # with the kernels ("permuted") and without
+    (kind, route)
+    for kind in ("no_sort_route", "unsorted_edges")
+    for route in ("permuted", "off_tpu")
+])
+def test_the_unmasked_forward_is_the_masked_composition_to_the_bit(
+        request, monkeypatch, tmp_path, kind, route, weighted):
+    """ISSUE 37: the fused layer takes its per-edge rows WITHOUT
+    ``local_take``'s edge-mask multiply, because its aggregation drops a
+    padded edge by its id. Held to the masked composition
+    ``scatter_bias_relu(local_take(table), bias)`` bit for bit: the value
+    and the gradients to table, bias and the edge weight's live slots, on
+    the transposed route, on the permuted one with the kernels, and off the
+    TPU. Row 0 of the table, which every padded slot gathers, is large and
+    positive (it would pass the ReLU) and the padded tail is longer than
+    two edge chunks: a leak would be seen."""
+    if route != "off_tpu":
+        request.getfixturevalue("tpu_interpret")
+    if route == "permuted":
+        monkeypatch.setattr(
+            collectives, "_transposed_bwd_applies", lambda *a: False)
+    plan_np = _built(kind, tmp_path)
+    plan = _shard(plan_np, 0)
+    n_rows = plan_np.n_src_pad + plan_np.halo.s_pad
+    if kind == "row_parts":
+        # an owner-side slice fits whole (the transposed route's rule), the
+        # stream's, which has the halo rows too, in two parts
+        assert plan_np.n_dst_pad < n_rows <= 2 * plan_np.n_dst_pad
+        monkeypatch.setattr(
+            local_ops, "GATHER_TABLE_BYTES", plan_np.n_dst_pad * 128 * 2)
+    rng = np.random.default_rng(13)
+    table = rng.standard_normal((n_rows, F))
+    table[0] = 100.0
+    table = jnp.asarray(table, jnp.bfloat16)
+    bias = jnp.asarray(
+        rng.standard_normal((plan_np.n_dst_pad, F)), jnp.bfloat16)
+    tgt = jnp.asarray(
+        rng.standard_normal((plan_np.n_dst_pad, F)), jnp.float32)
+    w = jnp.asarray(
+        rng.uniform(0.5, 2.0, plan_np.e_pad), jnp.float32
+    ) if weighted else None
+    argnums = (0, 1, 2) if weighted else (0, 1)
+
+    def unmasked(t, b, w_):
+        return collectives.take_scatter_bias_relu(
+            t, b, plan, "src", "dst", None, w_)
+
+    def masked(t, b, w_):
+        return collectives.map_vertex_chunks(
+            lambda tc, bc: collectives.scatter_bias_relu(
+                collectives.local_take(tc, plan, "src"), bc, plan, "dst",
+                None, edge_weight=w_),
+            (t, b))
+
+    def value_and_grads(fn):
+        def loss(t, b, w_):
+            out = fn(t, b, w_)
+            return (out.astype(jnp.float32) * tgt).sum(), out
+
+        return jax.jit(jax.value_and_grad(loss, argnums, has_aux=True))(
+            table, bias, w)
+
+    before = _counts()
+    parts = _counted("gather.row_parts")
+    (_, got_out), got = value_and_grads(unmasked)
+    assert (_counts() - before).tolist() == (
+        [2, 0] if route == "transposed" else [0, 2])
+    assert _counted("gather.row_parts") - parts == (
+        4 if kind == "row_parts" else 0)
+    (_, want_out), want = value_and_grads(masked)
+    np.testing.assert_array_equal(
+        np.asarray(got_out, np.float32), np.asarray(want_out, np.float32))
+    assert np.abs(np.asarray(got_out, np.float32)).sum() > 0
+    live = np.asarray(plan.edge_mask) > 0
+    # what the kernels were handed in the padded slots: row 0, not zeros
+    rows = collectives._local_take_unmasked(table[:, :128], plan, "src")
+    assert (np.asarray(rows, np.float32)[~live] == 100.0).all()
+    for name, a, b in zip(("d_table", "d_bias", "d_w"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if name == "d_w":
+            assert not a[~live].any()  # a padded slot's weight: no gradient
+            a, b = a[live], b[live]
+        assert np.abs(a).sum() > 0, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    # row 0 is the row every padded slot gathered: where the take's VJP is
+    # a plain segment-sum by the halo-side id (no sorted route, whose
+    # sentinel drops a padded slot), only gd == 0 on those slots keeps
+    # their cotangent out of it
+    np.testing.assert_array_equal(
+        np.asarray(got[0][0], np.float32), np.asarray(want[0][0], np.float32))
+
+
 def _engaged_args(plan_np, dtype=jnp.bfloat16):
     plan = _shard(plan_np, 0)
     n_rows = plan_np.n_src_pad + plan_np.world_size * plan_np.halo.s_pad
@@ -208,20 +370,25 @@ def _engaged_args(plan_np, dtype=jnp.bfloat16):
 
 def test_the_forward_is_the_two_calls_it_replaced(tpu_interpret):
     """Undifferentiated (the eval step, the serve engine) the op is
-    ``scatter_bias_relu(local_take(...))`` a chunk: the custom VJP's inner
-    jaxpr is the composed function's, equation for equation."""
+    ``scatter_bias_relu`` of the chunk's rows, taken as ``local_take``
+    takes them but for its edge-mask multiply (ISSUE 37): the custom VJP's
+    inner jaxpr is that composition's, equation for equation, and its
+    value the masked composition's to the bit."""
     plan, table, bias, w = _engaged_args(_plan(1))
 
     def one_op(t, b, w_, p):
         return collectives.take_scatter_bias_relu(
             t, b, p, "src", "dst", None, w_)
 
-    def two_ops(t, b, w_, p):
-        return collectives.map_vertex_chunks(
+    def composed(take):
+        return lambda t, b, w_, p: collectives.map_vertex_chunks(
             lambda tc, bc: collectives.scatter_bias_relu(
-                collectives.local_take(tc, p, "src"), bc, p, "dst", None,
-                edge_weight=w_),
+                take(tc, p, "src"), bc, p, "dst", None, edge_weight=w_),
             (t, b))
+
+    two_ops = composed(collectives._local_take_unmasked)
+    masked = jax.make_jaxpr(composed(collectives.local_take))(
+        table, bias, w, plan)
 
     outer = jax.make_jaxpr(one_op)(table, bias, w, plan)
     (call,) = [e for e in outer.jaxpr.eqns
@@ -230,9 +397,13 @@ def test_the_forward_is_the_two_calls_it_replaced(tpu_interpret):
     inner = call.params["call_jaxpr"]
     want = jax.make_jaxpr(two_ops)(table, bias, w, plan)
     assert str(inner.jaxpr) == str(want.jaxpr)
+    # one multiply a chunk fewer than the masked composition, nothing else
+    assert str(masked.jaxpr).count(" mul ") - str(want.jaxpr).count(
+        " mul ") == 2
     np.testing.assert_array_equal(
         np.asarray(jax.jit(one_op)(table, bias, w, plan), np.float32),
-        np.asarray(jax.jit(two_ops)(table, bias, w, plan), np.float32))
+        np.asarray(jax.jit(composed(collectives.local_take))(
+            table, bias, w, plan), np.float32))
 
 
 def _gcn_backward_counts(plan_np):
@@ -258,14 +429,14 @@ def _gcn_backward_counts(plan_np):
 
 
 def test_counters_read_four_transposed_on_the_tiny_gcn(tpu_interpret):
-    assert _gcn_backward_counts(_plan(1, mask_some=False)) == [4, 0]
+    assert _gcn_backward_counts(_plan(1, long_tail=False)) == [4, 0]
 
 
 def test_a_table_slice_over_on_chip_memory_keeps_the_permutation(
         tpu_interpret, monkeypatch):
     """Two gathers from HBM cost more than the permutation they replace:
     the size rule of ``map_vertex_chunks``, on the owner-side slice."""
-    plan_np = _plan(1, mask_some=False)
+    plan_np = _plan(1, long_tail=False)
     slice_bytes = plan_np.n_dst_pad * 128 * 2  # [n_owner_pad, 128] bf16
     monkeypatch.setattr(local_ops, "GATHER_TABLE_BYTES", slice_bytes)
     assert _gcn_backward_counts(plan_np) == [4, 0]
@@ -279,7 +450,7 @@ def test_a_table_slice_over_on_chip_memory_keeps_the_permutation(
 def test_a_plan_without_the_sorted_route_keeps_the_ops_own_vjps(
         tpu_interpret):
     assert _gcn_backward_counts(
-        _plan(1, mask_some=False, sort_route=False)) == [0, 4]
+        _plan(1, long_tail=False, sort_route=False)) == [0, 4]
 
 
 def test_off_the_tpu_the_backward_is_the_ops_own():
@@ -307,7 +478,7 @@ def test_the_fused_backward_switch_covers_the_route(
     """``config.pallas_fused_bwd_enabled()`` off: no kernel pair, so no
     transposed route either (no flag of its own)."""
     monkeypatch.setattr(cfg, "use_pallas_fused_bwd", False)
-    assert _gcn_backward_counts(_plan(1, mask_some=False)) == [0, 4]
+    assert _gcn_backward_counts(_plan(1, long_tail=False)) == [0, 4]
 
 
 @pytest.mark.parametrize("n", [1024, 1000])
