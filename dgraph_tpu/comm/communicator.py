@@ -204,13 +204,14 @@ class _BaseComm:
         Args:
           q/k/v: [T_loc, H, D] per-shard (full [T, H, D] in single mode);
             k and v may have fewer heads (grouped-query: KV head
-            ``j // (H / Hkv)`` serves query head j).
+            ``j // (H / Hkv)`` serves query head j), and v a head size of
+            its own (the result's; one device only).
           kv_mask: [T_loc] 1.0 = real position (padding excluded from keys).
           impl: 'ring' (default; O(T/W) memory, ICI neighbor hops) or
             'ulysses' (2 all_to_alls, needs heads % axis == 0).
           mask: a structured mask object beside ``causal``
-            (``parallel.sequence.BlockDiffusionMask``), over the full
-            sequence. ``single`` mode only: the dense oracle honours it
+            (``parallel.sequence.BlockDiffusionMask``, ``WindowMask``),
+            over the full sequence. ``single`` mode only: the dense oracle honours it
             exactly; on a TPU the tile-skipping splash kernels do, with
             native grouped-query heads, once their self-check has passed
             for this kind of mask and head grouping. Ring and Ulysses
@@ -239,7 +240,8 @@ class _BaseComm:
                     f"mask runs where one device holds the whole sequence "
                     f"(ROADMAP R11)")
             if _flash_applicable(q, require_pinned=True, mask=mask,
-                                 group=q.shape[1] // k.shape[1]):
+                                 group=q.shape[1] // k.shape[1],
+                                 v_head_dim=v.shape[-1]):
                 return _splash_dense(q, k, v, mask=mask, scale=None)
             return dense_attention(q, k, v, mask=mask)
         if self.graph_axis is None:
@@ -249,10 +251,16 @@ class _BaseComm:
             narrow = q.shape[-1] % 128 != 0  # its kernel path is causal only
             if not (narrow and (not causal or kv_mask is not None)) \
                     and _flash_applicable(q, require_pinned=True,
-                                          group=q.shape[1] // k.shape[1]):
+                                          group=q.shape[1] // k.shape[1],
+                                          v_head_dim=v.shape[-1]):
                 return _flash_dense(q, k, v, causal=causal, scale=None,
                                     kv_mask=kv_mask)
             return dense_attention(q, k, v, causal=causal, kv_mask=kv_mask)
+        if v.shape[-1] != q.shape[-1]:
+            raise NotImplementedError(
+                f"{impl} attention carries one head size; values of "
+                f"{v.shape[-1]} beside a q.k head of {q.shape[-1]} run where "
+                f"one device holds the whole sequence (ROADMAP R10)")
         k, v = repeat_kv(q, k, v)  # the ring and Ulysses know one head count
         if impl == "ulysses":
             return ulysses_attention(

@@ -59,9 +59,43 @@ in stack order), unequal ones follow each other. No pattern: one scan,
 - ``tie_head``: ``logits(h) = h E^T`` with ``E`` the embedding, in the compute
   dtype with a float32 result; no ``head`` leaf.
 
-Everything but attention and the convolution's halo is token-local, so those
-two are the only communication. Parameters are float32; matmuls run in
-``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
+Further mixers, of a decoder-hybrid-decoder (Phi-4-mini-flash-reasoning:
+SambaY with differential attention; Ren et al. 2025), and what goes with them
+(``norm="layer"``: LayerNorm with gain and bias; ``attn_bias``; ``fused_mlp``:
+``(g, y) = split2(W_1 u)``, ``W_2 (silu(g) * y)``; ``rope_theta=None``: no
+positional encoding):
+
+- ``ssm`` / ``ssm_keep``, the selective state-space layer (Mamba-1;
+  ``ssm``: :class:`StateSpace`, C = ``inner`` channels, N states, K taps,
+  rank R): ``(u, z) = split2(W_in x)``; ``u <- silu(conv_K(u) + b)``
+  (depthwise, causal, the ``K - 1``-row halo as ``conv``'s);
+  ``(d, B_t, C_t) = split(W_x u)``; ``Delta = softplus(W_dt d + b_dt)``;
+  ``A = -exp(A_log)``; ``y`` = the selective scan of
+  :mod:`dgraph_tpu.ops.selective_scan` (float32 inside, the state crossing a
+  sharded sequence rank by rank); ``Mix = W_out (y * silu(z))``.
+  ``ssm_keep`` also KEEPS ``m = y`` for the layers after it;
+- ``gmu``, the gated memory unit: ``Mix = W_2 (silu(W_1 x) * m)`` with ``m``
+  what the last ``ssm_keep`` layer before it kept;
+- ``diff_win`` / ``diff_keep`` / ``cross``, differential attention (Ye et al.
+  2024): the H query and Hkv key heads of D are H/2 query pairs ``(q1, q2)``
+  on Hkv/2 key pairs ``(k1, k2)`` (adjacent heads pair), the value heads pair
+  into ``V = [v1 ; v2]`` of 2D; ``A_i = softmax(q_i k_i^T / sqrt(D) + mask)``;
+  ``o = (1 - l0) RMSNorm_2D(A_1 V - l A_2 V)``, ``l = exp(lq1 . lk1) -
+  exp(lq2 . lk2) + l0``, ``l0 = 0.8 - 0.6 exp(-0.3 depth)`` at the layer's
+  PUBLISHED depth (``first_depth`` + its place in the stack); so every softmax
+  map has a q.k head of D and a v head of 2D, and the H maps go through ONE
+  ``seq_attention`` call as H query heads on Hkv key heads with the values
+  repeated. ``diff_win``: under a causal window of ``window`` keys;
+  ``diff_keep``: full causal, and the layer KEEPS its ``(k, v)``; ``cross``:
+  projects ``q`` only and attends, full causal, the ``(k, v)`` the last
+  ``diff_keep`` layer kept.
+
+What a layer kept reaches its readers as a loop-invariant input of their scan
+(never a carry: nothing is copied per layer), inside one pass over the stack.
+
+Everything but attention, the convolutions' halo and the scan's state is
+token-local, so those are the only communication. Parameters are float32;
+matmuls run in ``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
 logits are float32. The exit-distribution loss over the passes lives with the
 trainer (:mod:`dgraph_tpu.train.lm`), which applies :meth:`LoopLM.logits`
 in blocks of positions under recomputation.
@@ -71,6 +105,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -249,8 +284,105 @@ class GatedShortConv(nn.Module):
                 return dense(d, name="out_proj")(g)
 
 
-LAYER_MIXERS = ("attn", "conv")
+@dataclasses.dataclass(frozen=True)
+class StateSpace:
+    """The sizes of a selective state-space mixer: ``inner`` channels, ``state``
+    states a channel, ``conv`` taps of the causal convolution before the scan,
+    the rank ``dt_rank`` of the step size's projection, and the scan's
+    ``chunk`` (None: ``ops.selective_scan.SCAN_CHUNK``)."""
+
+    inner: int
+    state: int = 16
+    conv: int = 4
+    dt_rank: int = 1
+    chunk: Optional[int] = None
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log(1 .. N)`` a channel: Mamba's own (decays from e^-dt to e^-N dt)."""
+    return jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32, lo=1e-3, hi=0.1):
+    """The inverse softplus of a log-uniform step size in ``[lo, hi]``."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (math.log(hi) - math.log(lo)) + math.log(lo))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class SelectiveSSM(nn.Module):
+    """``x [T_loc, d] -> (W_out (y * silu(z)), y)`` (module docstring); scope
+    ``dgraph.lm.ssm`` with ``in_proj``, ``conv``, ``dt_bc``, ``scan``,
+    ``gate``, ``out_proj``. The products run in the compute dtype (``W_x``'s
+    and ``W_dt``'s with a float32 result); the taps' sum, the step size, the
+    scan and the gate in float32; ``u``, ``y`` as kept and the gate's result
+    are compute-dtype streams."""
+
+    spec: StateSpace
+    comm: Any
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        from dgraph_tpu.ops.selective_scan import SCAN_CHUNK, scan_sequence
+
+        sp, d, n = self.spec, x.shape[-1], x.shape[0]
+        C, N, K, R = sp.inner, sp.state, sp.conv, sp.dt_rank
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        wide = functools.partial(dense, dot_general=_dot_f32_out)
+        with jax.named_scope("dgraph.lm.ssm"):
+            with jax.named_scope("in_proj"):
+                u, z = jnp.split(dense(2 * C, name="in_proj")(x), 2, axis=-1)
+            with jax.named_scope("conv"):
+                taps = _Taps((K, C), name="conv")().astype(jnp.float32)
+                bias = self.param("conv_bias", nn.initializers.zeros, (C,))
+                u32 = u.astype(jnp.float32)
+                rows = jnp.concatenate(
+                    [previous_rows(u32, K - 1, self.comm), u32]) \
+                    if K > 1 else u32
+                u = nn.silu(sum(taps[j] * rows[j:j + n] for j in range(K))
+                            + bias).astype(u.dtype)
+            with jax.named_scope("dt_bc"):
+                step, B, Cm = jnp.split(
+                    wide(R + 2 * N, name="x_proj")(u), [R, R + N], axis=-1)
+                delta = nn.softplus(
+                    wide(C, name="dt_proj")(step.astype(u.dtype))
+                    + self.param("dt_bias", _dt_bias_init, (C,)))
+                A = -jnp.exp(self.param("A_log", _a_log_init, (C, N)))
+                D = self.param("D", nn.initializers.ones, (C,))
+            with jax.named_scope("scan"):
+                y = scan_sequence(u, delta, A, B, Cm, D, self.comm,
+                                  chunk=sp.chunk or SCAN_CHUNK)
+            with jax.named_scope("gate"):
+                g = (y * nn.silu(z.astype(jnp.float32))).astype(u.dtype)
+            with jax.named_scope("out_proj"):
+                return dense(d, name="out_proj")(g), y.astype(u.dtype)
+
+
+class GatedMemoryUnit(nn.Module):
+    """``(x [T_loc, d], m [T_loc, C]) -> W_2 (silu(W_1 x) * m)``; scope
+    ``dgraph.lm.gmu``. The gate in float32 (one fused pass over two
+    compute-dtype streams)."""
+
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x, m):
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        with jax.named_scope("dgraph.lm.gmu"):
+            g = nn.silu(dense(m.shape[-1], name="in_proj")(x).astype(
+                jnp.float32)) * m.astype(jnp.float32)
+            return dense(x.shape[-1], name="out_proj")(g.astype(m.dtype))
+
+
+LAYER_MIXERS = ("attn", "conv", "ssm", "ssm_keep", "gmu", "diff_win",
+                "diff_keep", "cross")
 LAYER_FFNS = ("dense", "experts")
+DIFF_MIXERS = ("diff_win", "diff_keep", "cross")  # differential attention
+ATTENDING = ("attn",) + DIFF_MIXERS
+KEEPS = {"ssm_keep": "m", "diff_keep": "kv"}  # mixer -> what it keeps
+READS = {"gmu": "m", "cross": "kv"}  # mixer -> what it reads of the kept
 
 
 def split_kind(kind: str):
@@ -269,7 +401,10 @@ class LoopLMLayer(nn.Module):
     for a dense FFN). Sandwich norms and a gated MLP by default (Ouro's);
     ``sandwich_norm=False``, ``qk_norm``, ``experts``, ``block_length`` give
     the pre-norm sparse-expert block-diffusion layer; ``mixer="conv"`` puts
-    the gated short convolution in attention's place (module docstring)."""
+    the gated short convolution in attention's place (module docstring). A
+    mixer that reads what an earlier layer kept (``READS``) takes it as a
+    third argument; one that keeps something (``KEEPS``) returns ``(h,
+    (stats, kept))``."""
 
     hidden: int
     num_heads: int
@@ -284,25 +419,47 @@ class LoopLMLayer(nn.Module):
     qk_norm: bool = False
     experts: Optional[HeldExperts] = None  # None: the gated MLP
     block_length: int = 0  # > 0: rows [xt ; x0] under the block-diffusion mask
-    mixer: str = "attn"  # or "conv": the gated short convolution
+    mixer: str = "attn"  # one of LAYER_MIXERS
     conv_kernel: int = 3
+    norm: str = "rms"  # or "layer": LayerNorm with gain and bias
+    attn_bias: bool = False  # a bias on attention's projections
+    fused_mlp: bool = False  # one W_1 for the gate and the value
+    window: int = 0  # keys a query of a "diff_win" layer sees
+    ssm: Optional[StateSpace] = None
+    depth: int = 0  # the published index of the layer (differential attention)
 
     @nn.compact
-    def __call__(self, h, rope):  # [T_loc, hidden], (cos, sin)
+    def __call__(self, h, rope, kept=None):  # [T_loc, hidden], (cos, sin)
         from dgraph_tpu import config as _cfg
 
         dt = _cfg.resolve_compute_dtype(self.dtype)
         dense = functools.partial(nn.Dense, use_bias=False, dtype=dt)
-        norm = functools.partial(RMSNorm, epsilon=self.rms_eps, dtype=dt)
+        if self.norm == "rms":
+            norm = functools.partial(RMSNorm, epsilon=self.rms_eps, dtype=dt)
+        else:
+            norm = functools.partial(nn.LayerNorm, epsilon=self.rms_eps,
+                                     dtype=dt)
         post = (lambda name: norm(name=name)) if self.sandwich_norm \
             else (lambda name: lambda y: y.astype(h.dtype))
+        keep = None
         if self.mixer == "conv":
             a = GatedShortConv(self.conv_kernel, self.comm, dt, name="conv")(
                 norm(name="norm_conv_in")(h))
             h = h + post("norm_conv_out")(a)
+        elif self.mixer in ("ssm", "ssm_keep"):
+            a, keep = SelectiveSSM(self.ssm, self.comm, dt, name="ssm")(
+                norm(name="norm_ssm_in")(h))
+            h = h + post("norm_ssm_out")(a)
+        elif self.mixer == "gmu":
+            a = GatedMemoryUnit(dt, name="gmu")(norm(name="norm_gmu_in")(h),
+                                                kept)
+            h = h + post("norm_gmu_out")(a)
+        elif self.mixer in DIFF_MIXERS:
+            h, keep = self.differ(h, kept, dt, dense, norm, post)
         else:
             h = self.attend(h, rope, dense, norm, post)
-        return self.ffn(h, dt, dense, norm, post)
+        h, stats = self.ffn(h, dt, dense, norm, post)
+        return h, ((stats, keep) if self.mixer in KEEPS else stats)
 
     def attend(self, h, rope, dense, norm, post):
         H, D = self.num_heads, self.head_dim
@@ -316,7 +473,8 @@ class LoopLMLayer(nn.Module):
         v = dense(Hkv * D, name="v_proj")(x).reshape(n, Hkv, D)
         if self.qk_norm:  # over each head's D, one gain vector for all heads
             q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
-        q, k = apply_rotary(q, *rope), apply_rotary(k, *rope)
+        if rope is not None:
+            q, k = apply_rotary(q, *rope), apply_rotary(k, *rope)
         if self.block_length:
             from dgraph_tpu.parallel.sequence import BlockDiffusionMask
 
@@ -329,6 +487,52 @@ class LoopLMLayer(nn.Module):
         a = dense(self.hidden, name="o_proj")(a.reshape(n, H * D))
         return h + post("norm_attn_out")(a)
 
+    def differ(self, h, kept, dt, dense, norm, post):
+        """Differential attention (module docstring): ``(h + W_o o, the
+        layer's (k, v))``. The H softmax maps are one ``seq_attention`` call:
+        H query heads of D on Hkv key heads (map i of a key pair's queries
+        side by side on key head i of the pair), values ``[v1 ; v2]`` of 2D
+        once a key head. The difference, its norm and the scale are float32
+        under the scope ``dgraph.lm.diff``."""
+        from dgraph_tpu.parallel.sequence import WindowMask
+
+        H, D = self.num_heads, self.head_dim
+        Hkv = self.num_kv_heads or H
+        if H % Hkv or Hkv % 2:
+            raise ValueError(f"differential attention pairs the heads: {H} "
+                             f"query heads on {Hkv} kv heads")
+        g, n = H // Hkv, h.shape[0]
+        biased = functools.partial(dense, use_bias=self.attn_bias)
+        x = norm(name="norm_attn_in")(h)
+        if self.mixer == "cross":
+            q = biased(H * D, name="q_proj")(x)
+            k, v = kept
+        else:
+            q, k, v = jnp.split(
+                biased((H + 2 * Hkv) * D, name="qkv_proj")(x),
+                [H * D, (H + Hkv) * D], axis=-1)
+            k, v = k.reshape(n, Hkv, D), v.reshape(n, Hkv // 2, 2 * D)
+        # query heads (pair, map) -> (key pair, map, pair of the key pair)
+        q = q.reshape(n, Hkv // 2, g, 2, D).swapaxes(2, 3).reshape(n, H, D)
+        windowed = self.mixer == "diff_win"
+        a = self.comm.seq_attention(
+            q, k, jnp.repeat(v, 2, axis=1), causal=not windowed,
+            mask=WindowMask(n, self.window) if windowed else None,
+            impl=self.attn_impl)
+        with jax.named_scope("dgraph.lm.diff"):
+            lam0 = 0.8 - 0.6 * math.exp(-0.3 * self.depth)
+            vec = lambda name: self.param(
+                name, nn.initializers.normal(0.1), (D,))
+            lam = jnp.exp(jnp.sum(vec("lambda_q1") * vec("lambda_k1"))) \
+                - jnp.exp(jnp.sum(vec("lambda_q2") * vec("lambda_k2"))) + lam0
+            a = a.reshape(n, Hkv // 2, 2, g, 2 * D).astype(jnp.float32)
+            o = RMSNorm(epsilon=self.rms_eps, dtype=jnp.float32,
+                        name="subln")(a[:, :, 0] - lam * a[:, :, 1])
+            o = (o * (1.0 - lam0)).astype(dt).reshape(n, H * D)
+        a = biased(self.hidden, name="o_proj")(o)
+        return h + post("norm_attn_out")(a), (
+            (k, v) if self.mixer == "diff_keep" else None)
+
     def ffn(self, h, dt, dense, norm, post):
         if self.experts is not None:
             u32 = RMSNorm(epsilon=self.rms_eps, dtype=jnp.float32,
@@ -337,8 +541,13 @@ class LoopLMLayer(nn.Module):
                                       name="experts")(u32)
             return h + post("norm_mlp_out")(m), stats
         u = norm(name="norm_mlp_in")(h)
-        m = nn.silu(dense(self.intermediate, name="gate_proj")(u)) \
-            * dense(self.intermediate, name="up_proj")(u)
+        if self.fused_mlp:
+            g, y = jnp.split(dense(2 * self.intermediate,
+                                   name="gate_up_proj")(u), 2, axis=-1)
+            m = nn.silu(g) * y
+        else:
+            m = nn.silu(dense(self.intermediate, name="gate_proj")(u)) \
+                * dense(self.intermediate, name="up_proj")(u)
         m = dense(self.hidden, name="down_proj")(m)
         return h + post("norm_mlp_out")(m), None
 
@@ -362,42 +571,79 @@ class LoopPass(nn.Module):
     of the loop and the pass's exit state (with expert layers, ``(h_t,
     stats [expert layers, 4])`` in the second place). With a ``pattern`` of
     kinds each run of equal kinds is such a scan (``layers_<run>``), one
-    after the other; ``layer``'s ``experts`` go to the expert layers only."""
+    after the other; ``layer``'s ``experts`` go to the expert layers only.
+    What a ``KEEPS`` layer kept goes to the ``READS`` layers after it as a
+    loop-invariant input of their scan; a differential-attention layer is
+    told its published depth, ``first_depth`` + its place in the stack."""
 
     num_layers: int
     layer: dict  # LoopLMLayer's fields
     remat: bool = True
     pattern: Optional[tuple] = None
+    first_depth: int = 0
 
     @nn.compact
     def __call__(self, h, rope):
         with jax.named_scope("dgraph.lm.loop_pass"):
-            cls = LoopLMLayer
-            if self.remat:
-                cls = nn.remat(cls, prevent_cse=False)  # inside a scan
-            scan = functools.partial(
-                nn.scan, cls,
-                variable_axes={"params": 0, "intermediates": 0},
-                split_rngs={"params": True}, in_axes=nn.broadcast)
+            def scan(barrier=False, **kw):
+                cls = LoopLMLayer
+                if self.remat:  # inside a scan: no barrier against CSE
+                    cls = nn.remat(cls, prevent_cse=barrier)
+                return nn.scan(
+                    cls, variable_axes={"params": 0, "intermediates": 0},
+                    split_rngs={"params": True},
+                    **{"in_axes": nn.broadcast, **kw})
+
             if self.pattern is None:
                 h, stats = scan(length=self.num_layers)(
                     **self.layer, name="layers")(h, rope)
             else:
-                counted = []
+                counted, kept, depth = [], {}, self.first_depth
                 for i, (kind, n) in enumerate(layer_runs(self.pattern)):
                     mixer, ffn = split_kind(kind)
                     fields = dict(
                         self.layer, mixer=mixer,
                         experts=self.layer["experts"] if ffn == "experts"
                         else None)
-                    h, st = scan(length=n)(**fields, name=f"layers_{i}")(
-                        h, rope)
+                    if mixer in DIFF_MIXERS or mixer in KEEPS:
+                        if n > 1:
+                            raise ValueError(
+                                f"a run of {n} {kind!r} layers: a layer that "
+                                f"keeps, or whose lambda depends on its "
+                                f"depth, is a run of its own")
+                        fields["depth"] = depth
+                    # a run of ONE layer is a one-trip loop, which XLA
+                    # inlines: without the barrier the recomputation is then
+                    # merged with the forward pass and nothing is
+                    # rematerialised (the six-kind stack compiled for a v5e at
+                    # T 8192: 15.84 GB so, 11.44 GB with the barrier). The
+                    # runs of the kinds that were there keep their program.
+                    barrier = n == 1 and mixer not in ("attn", "conv")
+                    if mixer in READS:
+                        if READS[mixer] not in kept:
+                            raise ValueError(
+                                f"a {kind!r} layer reads what a layer before "
+                                f"it kept, and none did")
+                        h, st = scan(barrier, length=n, in_axes=(
+                            nn.broadcast, nn.broadcast))(
+                                **fields, name=f"layers_{i}")(
+                                    h, rope, kept[READS[mixer]])
+                    else:
+                        h, st = scan(barrier, length=n)(
+                            **fields, name=f"layers_{i}")(h, rope)
+                    if mixer in KEEPS:  # the run's one layer's
+                        st, keep = st
+                        kept[KEEPS[mixer]] = jax.tree.map(
+                            lambda a: a[0], keep)
                     if st is not None:
                         counted.append(st)
+                    depth += n
                 stats = jnp.concatenate(counted) if counted else None
             # rematerialised too: its float32 internals would otherwise be
             # saved once a pass
-            norm_f = nn.remat(RMSNorm) if self.remat else RMSNorm
+            norm_cls = RMSNorm if self.layer.get("norm", "rms") == "rms" \
+                else nn.LayerNorm
+            norm_f = nn.remat(norm_cls) if self.remat else norm_cls
             h = norm_f(epsilon=self.layer["rms_eps"], dtype=h.dtype,
                        name="norm_f")(h)
         return h, (h if stats is None else (h, stats))
@@ -425,7 +671,7 @@ class LoopLM(nn.Module):
     loop_steps: int = 1
     exit_gate: bool = False
     rms_eps: float = 1e-6
-    rope_theta: float = 10000.0
+    rope_theta: Optional[float] = 10000.0  # None: no positional encoding
     dtype: Any = None
     attn_impl: str = "ring"
     remat: bool = True
@@ -437,6 +683,12 @@ class LoopLM(nn.Module):
     pattern: Optional[tuple] = None  # kinds "<mixer>+<ffn>", in stack order
     conv_kernel: int = 3
     tie_head: bool = False
+    norm: str = "rms"  # or "layer"
+    attn_bias: bool = False
+    fused_mlp: bool = False
+    window: int = 0
+    ssm: Optional[StateSpace] = None
+    first_depth: int = 0  # the published index of the stack's first layer
 
     def layer_kinds(self) -> tuple:
         """The kind of each of the ``num_layers`` layers, in stack order."""
@@ -456,6 +708,13 @@ class LoopLM(nn.Module):
             if ("experts" in ffns) != (self.experts is not None):
                 raise ValueError("expert layers in the pattern and `experts` "
                                  "go together")
+            mixers = {split_kind(kind)[0] for kind in self.pattern}
+            if mixers & {"ssm", "ssm_keep"} and self.ssm is None:
+                raise ValueError("state-space layers in the pattern need "
+                                 "`ssm`, their sizes")
+            if "diff_win" in mixers and self.window < 1:
+                raise ValueError("windowed layers in the pattern need "
+                                 "`window`")
         dt = _cfg.resolve_compute_dtype(self.dtype)
         self.embed = nn.Embed(self.vocab, self.hidden_size, dtype=dt)
         layer = dict(
@@ -465,14 +724,17 @@ class LoopLM(nn.Module):
             rms_eps=self.rms_eps, dtype=self.dtype, attn_impl=self.attn_impl,
             sandwich_norm=self.sandwich_norm, qk_norm=self.qk_norm,
             experts=self.experts, block_length=self.block_length,
-            conv_kernel=self.conv_kernel)
+            conv_kernel=self.conv_kernel, norm=self.norm,
+            attn_bias=self.attn_bias, fused_mlp=self.fused_mlp,
+            window=self.window, ssm=self.ssm)
         # the same parameters every pass: broadcast, not split
         loop = nn.scan(
             LoopPass, variable_broadcast="params",
             variable_axes={"intermediates": 0},
             split_rngs={"params": False}, in_axes=nn.broadcast,
             length=self.loop_steps)
-        self.stack = loop(self.num_layers, layer, self.remat, self.pattern)
+        self.stack = loop(self.num_layers, layer, self.remat, self.pattern,
+                          self.first_depth)
         if not self.tie_head:
             self.head = nn.Dense(self.vocab, use_bias=False, dtype=dt,
                                  dot_general=_dot_f32_out)
@@ -480,7 +742,8 @@ class LoopLM(nn.Module):
             self.gate = nn.Dense(1, dtype=dt, dot_general=_dot_f32_out)
 
     def hidden(self, tokens, positions):  # [T_loc] int32 each
-        rope = rotary_tables(positions, self.head_dim, self.rope_theta)
+        rope = None if self.rope_theta is None else rotary_tables(
+            positions, self.head_dim, self.rope_theta)
         _, hs = self.stack(self.embed(tokens), rope)
         # [loop_steps, T_loc, hidden]; with expert layers also their counts,
         # [loop_steps, num_layers, 5] (parallel.expert.HELD_STATS)
@@ -494,6 +757,18 @@ class LoopLM(nn.Module):
         from dgraph_tpu.parallel.sequence import BlockDiffusionMask
 
         return BlockDiffusionMask(seq_len, self.block_length)
+
+    def attention_masks(self, seq_len: int):
+        """Of a stack with differential attention, the mask of each attending
+        layer in stack order (``WindowMask`` or ``CausalMask`` objects); None
+        for every other stack (one mask: :meth:`attention_mask`)."""
+        from dgraph_tpu.parallel.sequence import CausalMask, WindowMask
+
+        mixers = [split_kind(kind)[0] for kind in self.layer_kinds()]
+        if not set(mixers) & set(DIFF_MIXERS):
+            return None
+        return [WindowMask(seq_len, self.window) if m == "diff_win"
+                else CausalMask(seq_len) for m in mixers if m in ATTENDING]
 
     def logits(self, h):
         with jax.named_scope("dgraph.lm.head"):

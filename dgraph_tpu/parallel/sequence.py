@@ -137,8 +137,63 @@ class CausalMask:
     def allowed(self, q_ids, k_ids):
         return k_ids <= q_ids
 
+    def pairs(self) -> int:
+        return self.seq_len * (self.seq_len + 1) // 2
+
+    def tile_pairs(self, tile: int) -> int:
+        return _band_tile_pairs(self, tile)
+
     def over(self, rows: int) -> "CausalMask":
         return CausalMask(rows)
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowMask:
+    """Causal attention under a sliding window: row q may attend row k iff
+    ``q - window < k <= q`` (itself and the ``window - 1`` rows before it).
+    The splash kernels skip the tiles above the diagonal and those wholly
+    below the band."""
+
+    seq_len: int
+    window: int
+    name: ClassVar[str] = "window"
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"a window of {self.window} rows")
+
+    @property
+    def rows(self) -> int:
+        return self.seq_len
+
+    def allowed(self, q_ids, k_ids):
+        return (k_ids <= q_ids) & (q_ids - k_ids < self.window)
+
+    def pairs(self) -> int:
+        w = min(self.window, self.seq_len)
+        return w * (w + 1) // 2 + (self.seq_len - w) * w
+
+    def tile_pairs(self, tile: int) -> int:
+        return _band_tile_pairs(self, tile)
+
+    def over(self, rows: int) -> "WindowMask":
+        """The self-check's: a window of an eighth of its rows at most, so
+        that whole, partial and skipped tiles all occur."""
+        return WindowMask(rows, min(self.window, max(1, rows // 8)))
+
+
+def _band_tile_pairs(mask, tile: int) -> int:
+    """Pairs in the ``tile x tile`` tiles of a causal or windowed mask that
+    hold an allowed pair: tile (i, j) does iff its closest corner pair, row
+    ``i tile`` (or the diagonal's) against key ``j tile + tile - 1``, is
+    allowed."""
+    if mask.rows % tile:
+        raise ValueError(f"tile {tile} against {mask.rows} rows")
+    i = np.arange(mask.rows // tile)[:, None]
+    j = np.arange(mask.rows // tile)[None, :]
+    q = i * tile + (tile - 1) * (i == j)
+    k = np.minimum(j * tile + tile - 1, q)
+    return int(((j <= i) & mask.allowed(q, k)).sum()) * tile * tile
 
 
 def _block_attend(q, k, v, m, l, o, allowed, scale):
@@ -286,7 +341,7 @@ def dense_attention(
 ) -> jax.Array:
     """Single-device oracle: softmax(q k^T) v over the FULL sequence
     ([T, H, D] queries; keys and values may have fewer, grouped-query,
-    heads). ``mask``: a structured mask object (``BlockDiffusionMask``),
+    heads, and the values a head size of their own). ``mask``: a structured mask object (``BlockDiffusionMask``),
     honoured exactly, in ``causal``'s place. The equivalence target for
     :func:`ring_attention` and the kernels (tests/test_sequence.py) and the
     small-sequence fallback."""
@@ -375,8 +430,15 @@ def ulysses_attention(
     return head_to_seq(out)
 
 
+def _head_key(head_dim: int, v_head_dim=None):
+    """How a head size is latched: the q.k size, or ``(q.k, v)`` where the
+    values' differs."""
+    return head_dim if v_head_dim in (None, head_dim) \
+        else (head_dim, v_head_dim)
+
+
 def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
-                      mask=None, group: int = 1) -> bool:
+                      mask=None, group: int = 1, v_head_dim=None) -> bool:
     """Use the Mosaic flash-attention kernel for a full-sequence dense
     attention site? With a structured ``mask`` the kernel is the splash one,
     which additionally needs ITS self-check passed in this process for this
@@ -412,7 +474,8 @@ def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
     # passed for this kind of mask, grouping and head size
     kind = CausalMask.name if mask is None else mask.name
     if (mask is not None or D % 128) \
-            and (kind, group, D) not in _splash_verified:
+            and (kind, group, _head_key(D, v_head_dim)) \
+            not in _splash_verified:
         return False
     return T % 128 == 0
 
@@ -511,7 +574,8 @@ def _splash_dense(qh, kh, vh, *, mask, scale, interpret: bool = False):
     ``flash_tile(T)`` rows, skipped where the mask allows no pair, forward
     and backward Mosaic kernels with their own VJP. Grouped-query heads are
     native: one multi-query kernel over the ``H / Hkv`` query heads of a KV
-    head, mapped over the KV heads; K and V are read where they lie."""
+    head, mapped over the KV heads; K and V are read where they lie. The
+    values' head size may differ from D (the result has theirs)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
 
@@ -530,7 +594,7 @@ def _splash_dense(qh, kh, vh, *, mask, scale, interpret: bool = False):
     q4 = (qh * jnp.asarray(scale, qh.dtype)).transpose(1, 0, 2).reshape(
         Hkv, H // Hkv, T, D)
     out = jax.vmap(kernel)(q4, kh.transpose(1, 0, 2), vh.transpose(1, 0, 2))
-    return out.reshape(H, T, D).transpose(1, 0, 2).astype(qh.dtype)
+    return out.reshape(H, T, vh.shape[-1]).transpose(1, 0, 2).astype(qh.dtype)
 
 
 def _splash_block_sizes(sk, T: int, D: int = 128):
@@ -562,7 +626,7 @@ _splash_verified: set = set()
 
 
 def flash_attention_selfcheck(mask=None, group: int = 1,
-                              head_dim: int = 128) -> bool:
+                              head_dim: int = 128, v_head_dim=None) -> bool:
     """Chip-gated equivalence check vs :func:`dense_attention` (the same
     Mosaic-divergence rationale as bench.py's scatter self-checks: the
     kernel class is invisible to CPU CI). Passing LATCHES auto-mode flash
@@ -574,8 +638,9 @@ def flash_attention_selfcheck(mask=None, group: int = 1,
     occur) and two KV heads, at heads of ``head_dim``; passing latches
     (kind, group, head_dim) in ``_splash_verified``. A ``head_dim`` that is no multiple of 128 runs
     causal attention through the splash kernels too (:func:`_flash_dense`),
-    so they are checked under the causal mask at THAT head size and grouping.
-    True only if everything asked for passed.
+    so they are checked under the causal mask at THAT head size and grouping
+    (and with values of ``v_head_dim``, where that differs: latched as
+    ``(head_dim, v_head_dim)``). True only if everything asked for passed.
 
     The call is the stage ``setup.attention_selfcheck`` (always on, one a
     latch asked for; off a TPU it ends at once with ``passed`` false).
@@ -588,7 +653,8 @@ def flash_attention_selfcheck(mask=None, group: int = 1,
         passed = jax.default_backend() == "tpu" and _flash_selfcheck()
         if passed and (mask is not None or head_dim % 128):
             passed = _splash_selfcheck(
-                mask or CausalMask(0), group, head_dim=head_dim)
+                mask or CausalMask(0), group, head_dim=head_dim,
+                v_head_dim=v_head_dim)
         st.annotate(passed=passed)
     return passed
 
@@ -645,7 +711,7 @@ def _flash_selfcheck() -> bool:
 
 
 def _splash_selfcheck(mask, group: int, *, interpret: bool = False,
-                      head_dim: int = 128) -> bool:
+                      head_dim: int = 128, v_head_dim=None) -> bool:
     """Splash forward and backward against the dense oracle under a mask of
     ``mask``'s kind at heads of ``head_dim``; see
     :func:`flash_attention_selfcheck`. The oracle runs one KV head at a time
@@ -653,11 +719,11 @@ def _splash_selfcheck(mask, group: int, *, interpret: bool = False,
     rows = 4 * (128 if interpret else FLASH_BLOCK)  # four tiles a side
     small = mask.over(rows)
     rng = np.random.default_rng(5)
-    Hkv, D = 2, head_dim
-    q, w = (jnp.asarray(rng.standard_normal((rows, Hkv * group, D)),
-                        jnp.bfloat16) for _ in range(2))
-    k, v = (jnp.asarray(rng.standard_normal((rows, Hkv, D)), jnp.bfloat16)
-            for _ in range(2))
+    Hkv, D, Dv = 2, head_dim, v_head_dim or head_dim
+    q, w = (jnp.asarray(rng.standard_normal((rows, Hkv * group, d)),
+                        jnp.bfloat16) for d in (D, Dv))
+    k, v = (jnp.asarray(rng.standard_normal((rows, Hkv, d)), jnp.bfloat16)
+            for d in (D, Dv))
     close = lambda a, b: np.allclose(
         np.asarray(a, np.float32), np.asarray(b, np.float32),
         rtol=5e-2, atol=5e-2)
@@ -682,7 +748,7 @@ def _splash_selfcheck(mask, group: int, *, interpret: bool = False,
                 return False
     except Exception:
         return False
-    _splash_verified.add((mask.name, group, D))
+    _splash_verified.add((mask.name, group, _head_key(D, v_head_dim)))
     return True
 
 

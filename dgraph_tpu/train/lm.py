@@ -78,7 +78,8 @@ def lm_mesh(world_size: int, devices=None):
 
 
 def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
-                      head_dim: int, mask=None, group: int = 1) -> str:
+                      head_dim: int, mask=None, group: int = 1,
+                      v_head_dim: Optional[int] = None) -> str:
     """Decide, before anything is traced, which attention implementation
     ``comm.seq_attention`` will run, and say which: 'flash', 'dense', 'ring',
     'ulysses+flash' or 'ulysses+dense'; under a structured ``mask`` (with
@@ -86,7 +87,7 @@ def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
     covering the splash kernels for that mask and grouping as well. A head
     that is no multiple of 128 lanes runs causal attention through the splash
     kernels too ('splash'), after their self-check at that head size and
-    grouping.
+    grouping (and with values of ``v_head_dim``, where that is another size).
 
     Wherever a device holds a full-sequence view the Mosaic flash kernel is
     engaged only after ``flash_attention_selfcheck()`` passed on this chip
@@ -106,14 +107,14 @@ def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
         return "ring"
     if cfg.flash_attention_enabled():
         cfg.set_flags(use_flash_attention=seq.flash_attention_selfcheck(
-            mask, group, head_dim))
+            mask, group, head_dim, v_head_dim))
     if comm.graph_axis is None:
         t_full, heads, prefix = t_local, num_heads, ""
     else:  # ulysses: the full sequence, a share of the heads
         t_full, heads, prefix = t_local * world, num_heads // world, "ulysses+"
     view = jax.ShapeDtypeStruct((t_full, heads, head_dim), jnp.float32)
     if seq._flash_applicable(view, require_pinned=comm.graph_axis is None,
-                             mask=mask, group=group):
+                             mask=mask, group=group, v_head_dim=v_head_dim):
         return prefix + ("flash" if mask is None and head_dim % 128 == 0
                          else "splash")
     if heads * t_full * t_full * 4 > DENSE_LOGITS_LIMIT_BYTES:
@@ -435,6 +436,25 @@ def init_lm_params(model, mesh, comm, seed: int = 0, *,
     return params, specs
 
 
+def layers_by_kind(kinds) -> dict:
+    """How many of a stack's layers are of which kind (``models.looplm``'s
+    ``"<mixer>+<ffn>"``): ``conv``, ``attention`` (every attending mixer),
+    ``dense_ffn``, ``expert_ffn``, and, where the stack has any, ``ssm``,
+    ``gmu``, ``window`` (attention under a window) and ``cross`` (attention
+    on another layer's keys and values)."""
+    from dgraph_tpu.models.looplm import ATTENDING, split_kind
+
+    mixers = [split_kind(k)[0] for k in kinds]
+    count = lambda *names: sum(m in names for m in mixers)
+    out = {"conv": count("conv"), "attention": count(*ATTENDING),
+           "dense_ffn": sum(k.endswith("+dense") for k in kinds),
+           "expert_ffn": sum(k.endswith("+experts") for k in kinds)}
+    more = {"ssm": count("ssm", "ssm_keep"), "gmu": count("gmu"),
+            "window": count("diff_win"), "cross": count("cross")}
+    out.update({k: n for k, n in more.items() if n})
+    return out
+
+
 @dataclasses.dataclass
 class LMTrainer:
     """What one launch holds, and the loop's step: host batch -> device,
@@ -518,7 +538,11 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     given: those instead, placed on the mesh) and the optimizer state on the
     mesh, build the steps. Stages ``setup.init_params`` (or ``setup.place``)
     and ``setup.init_opt_state``; counters ``lm.*`` (the layers by kind:
-    ``lm.layers.conv / .attention / .dense_ffn / .expert_ffn``)."""
+    ``lm.layers.conv / .attention / .dense_ffn / .expert_ffn``, and where the
+    stack has them ``.ssm / .gmu / .window / .cross`` with ``lm.ssm.state /
+    .inner / .chunk``, ``lm.attention.window / .v_head_dim``); a stack of
+    several masks (differential attention under a window and full) has each
+    self-checked, and ``attn.mask_pairs / .tile_pairs`` summed over them."""
     world = comm.get_world_size()
     if seq_len % world:
         raise ValueError(f"seq_len {seq_len} does not divide by world {world}")
@@ -527,18 +551,26 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     mask = model.attention_mask(seq_len) \
         if hasattr(model, "attention_mask") else None
     kinds = model.layer_kinds() if hasattr(model, "layer_kinds") else None
-    by_kind = None if kinds is None else {
-        "conv": sum(k.startswith("conv+") for k in kinds),
-        "attention": sum(k.startswith("attn+") for k in kinds),
-        "dense_ffn": sum(k.endswith("+dense") for k in kinds),
-        "expert_ffn": sum(k.endswith("+experts") for k in kinds)}
+    by_kind = None if kinds is None else layers_by_kind(kinds)
+    masks = model.attention_masks(seq_len) \
+        if hasattr(model, "attention_masks") else None
+    group = heads // (getattr(model, "num_kv_heads", None) or heads)
     if by_kind is not None and not by_kind["attention"]:
         attention = "none"  # a stack of convolutions attends nowhere
+    elif masks:  # differential attention: a map's values are [v1 ; v2]
+        from dgraph_tpu.parallel.sequence import CausalMask
+
+        attention = "/".join(dict.fromkeys(
+            resolve_attention(
+                comm, model.attn_impl, seq_len // world, heads, head_dim,
+                None if isinstance(m, CausalMask) else m, group,
+                v_head_dim=2 * head_dim)
+            for m in dict.fromkeys(masks)))
     else:
         attention = resolve_attention(
             comm, model.attn_impl,
             seq_len // world if mask is None else mask.rows, heads, head_dim,
-            mask, heads // (getattr(model, "num_kv_heads", None) or heads))
+            mask, group)
     specs = None
     if params is None:
         params, specs = init_lm_params(
@@ -569,13 +601,29 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         default_registry.counter("lm.attention.head_dim", head_dim)
         if by_kind["conv"]:
             default_registry.counter("lm.conv.kernel_size", model.conv_kernel)
+        if by_kind.get("ssm"):
+            from dgraph_tpu.ops.selective_scan import SCAN_CHUNK
+
+            default_registry.counter("lm.ssm.state", model.ssm.state)
+            default_registry.counter("lm.ssm.inner", model.ssm.inner)
+            default_registry.counter("lm.ssm.chunk",
+                                     model.ssm.chunk or SCAN_CHUNK)
+        if by_kind.get("window"):
+            default_registry.counter("lm.attention.window", model.window)
+        if masks:
+            default_registry.counter("lm.attention.v_head_dim", 2 * head_dim)
         expert_layers = by_kind["expert_ffn"]
-    if mask is not None:  # pairs the mask allows / pairs in the tiles visited
+    if mask is not None:
+        masks = [mask]
+    if masks:  # pairs the masks allow / pairs in the tiles visited
         from dgraph_tpu.parallel.sequence import flash_tile
 
-        tile = flash_tile(mask.rows) if attention == "splash" else mask.rows
-        startup.update(attention_mask=mask.name, mask_pairs=mask.pairs(),
-                       tile_pairs=mask.tile_pairs(tile))
+        rows = masks[0].rows
+        tile = flash_tile(rows) if attention == "splash" else rows
+        startup.update(
+            attention_mask="+".join(dict.fromkeys(m.name for m in masks)),
+            mask_pairs=sum(m.pairs() for m in masks),
+            tile_pairs=sum(m.tile_pairs(tile) for m in masks))
         default_registry.counter("attn.mask_pairs", startup["mask_pairs"])
         default_registry.counter("attn.tile_pairs", startup["tile_pairs"])
     experts = getattr(model, "experts", None)

@@ -62,6 +62,8 @@ SECTIONS = [
      ["sorted_segment_sum", "sorted_segment_sum_bias_relu",
       "sorted_row_gather", "max_chunks_hint", "max_vblocks_hint",
       "block_chunk_counts", "chunk_vblock_spans"]),
+    ("Selective scan", "dgraph_tpu.ops.selective_scan",
+     ["selective_scan", "scan_sequence"]),
     ("Models", "dgraph_tpu.models", None),
     ("GraphCast", "dgraph_tpu.models.graphcast", None),
     ("Tensor parallelism", "dgraph_tpu.parallel.tensor", None),

@@ -393,16 +393,16 @@ def test_runs_of_equal_kinds_are_scanned_and_unequal_ones_follow():
                    if "/layers_2/" in n or "/layers_0/" in n)
     assert "params/head/kernel" not in shapes
     assert model.layer_kinds() == PATTERN
-    with pytest.raises(ValueError, match="layer kind 'ssm\\+dense'"):
-        looplm.split_kind("ssm+dense")
+    with pytest.raises(ValueError, match="layer kind 'rnn\\+dense'"):
+        looplm.split_kind("rnn+dense")
     bad = build(lm.lm_comm(1), pattern=("conv+dense", "attn+dense"))
     with pytest.raises(ValueError, match="go together"):
         bad.init(jax.random.key(0), jnp.zeros(8, jnp.int32), jnp.arange(8))
 
 
-def ouro_and_sdar_tiny():
-    """(name, model, seq_len, loss keywords, batch) of the two configurations
-    that were there, at their tiny presets."""
+def earlier_tiny():
+    """(name, model, seq_len, loss keywords, batch) of the three
+    configurations that were there before a fourth, at their tiny presets."""
     comm = lm.lm_comm(1)
     with open(os.path.join(ROOT, "benchmark", "configs", "ouro_2p6b.json")) as f:
         o = json.load(f)["tiny"]
@@ -429,11 +429,31 @@ def ouro_and_sdar_tiny():
             k=s["num_experts_per_tok"], width=s["moe_intermediate_size"],
             first_held=s["first_expert"], rows=s["moe_buffer_rows"]),
         block_length=s["block_length"], mask_token=s["mask_token_id"])
-    T_o, T_s = o["seq_len"], s["seq_len"]
+    with open(os.path.join(ROOT, "benchmark", "configs", "lfm2_8b_a1b.json")) as f:
+        l = json.load(f)["tiny"]
+    lfm2 = LoopLM(
+        vocab=l["vocab_size"], hidden_size=l["hidden_size"],
+        num_layers=len(l["layer_pattern"]), pattern=tuple(l["layer_pattern"]),
+        conv_kernel=l["conv_L_cache"], tie_head=True,
+        num_heads=l["num_attention_heads"],
+        num_kv_heads=l["num_key_value_heads"], head_dim=l["head_dim"],
+        intermediate=l["intermediate_size"], comm=comm, loop_steps=1,
+        exit_gate=False, rms_eps=l["norm_eps"],
+        rope_theta=float(l["rope_theta"]),
+        dtype=jnp.dtype(l["compute_dtype"]), remat=l["remat"],
+        sandwich_norm=False, qk_norm=True,
+        experts=HeldExperts(
+            n_total=l["num_experts_total"], n_held=l["num_experts"],
+            k=l["num_experts_per_tok"], width=l["moe_intermediate_size"],
+            first_held=l["first_expert"], rows=l["moe_buffer_rows"],
+            score="sigmoid", select_bias=True, gate_eps=1e-6,
+            gate_scale=float(l["routed_scaling_factor"])))
+    T_o, T_s, T_l = o["seq_len"], s["seq_len"], l["seq_len"]
     return [
         ("ouro", ouro, T_o, dict(beta=o["exit_beta"]), jnp.zeros(T_o, jnp.int32)),
         ("sdar", sdar, T_s, {}, (jnp.zeros(T_s, jnp.int32), jnp.zeros(T_s, bool),
-                                 jnp.ones(T_s, jnp.float32)))]
+                                 jnp.ones(T_s, jnp.float32))),
+        ("lfm2", lfm2, T_l, {}, jnp.zeros(T_l, jnp.int32))]
 
 
 # sha256[:16] of (the parameter tree's shapes, the train step's jaxpr) at
@@ -441,15 +461,18 @@ def ouro_and_sdar_tiny():
 # size existed: no pattern given = that program. SDAR's jaxpr is that one
 # with PR 36's fifth count in every expert layer's stats (``rows_max_layer``;
 # 9e32b1d66760a135 until then); its tree and both of Ouro's are e47b801's.
+# "lfm2": this file's own pattern stack at its tiny preset at commit f4c1a93
+# (PR 37), before a state-space, memory-unit or differential mixer existed.
 PARENT_PROGRAMS = {
     "ouro": ("001bbafd6c891dd1", "36ed7e9025061d24"),
     "sdar": ("7aabb5340b3078f4", "f89e56a41ea5fa01"),
+    "lfm2": ("43de925768624568", "088bbebae7c5f993"),
 }
 
 
-@pytest.mark.parametrize("which", [0, 1], ids=["ouro", "sdar"])
+@pytest.mark.parametrize("which", [0, 1, 2], ids=["ouro", "sdar", "lfm2"])
 def test_no_pattern_is_the_parents_program(which):
-    name, model, seq_len, kw, batch = ouro_and_sdar_tiny()[which]
+    name, model, seq_len, kw, batch = earlier_tiny()[which]
     opt = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1)
     mesh = lm.lm_mesh(1)
     tr = lm.lm_setup(model, opt, mesh, model.comm, seq_len=seq_len, seed=0, **kw)
@@ -461,8 +484,9 @@ def test_no_pattern_is_the_parents_program(which):
     jaxpr = re.sub(r"0x[0-9a-f]+", "0x", jaxpr)
     digest = lambda s: hashlib.sha256(s.encode()).hexdigest()[:16]
     assert (digest(tree), digest(jaxpr)) == PARENT_PROGRAMS[name]
-    assert model.layer_kinds() == (
-        ("attn+dense",) if name == "ouro" else ("attn+experts",)) * model.num_layers
+    assert model.layer_kinds() == (model.pattern or (
+        ("attn+dense",) if name == "ouro" else ("attn+experts",))
+        * model.num_layers)
 
 
 def test_tied_heads_gradient_is_the_sum_of_both_uses(seeded, tokens):
