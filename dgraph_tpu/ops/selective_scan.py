@@ -1,5 +1,6 @@
-"""The selective state-space scan (Mamba-1; Gu & Dao 2023), chunked, with its
-own backward.
+"""The selective state-space scan (Mamba-1; Gu & Dao 2023) with its own
+backward, in two schedules of one recurrence: chunked in plain ``lax``, and
+step by step in Pallas kernels that keep the state on chip.
 
 For inputs ``u, delta [T, C]``, ``A [C, N]`` (negative), ``B, Cm [T, N]``,
 ``D [C]`` and a start state ``s_{-1} = s0 [C, N]`` (zeros if None), per
@@ -9,7 +10,8 @@ channel c and state n:
     y_t[c]    = sum_n Cm_t[n] s_t[c, n] + D[c] u_t[c]
 
 :func:`selective_scan` returns ``(y [T, C] float32, s_{T-1} [C, N])``. The
-``[T, C, N]`` states (2.7 GB at T 8192, C 5120, N 16) are never whole:
+``[T, C, N]`` states (2.7 GB at T 8192, C 5120, N 16) are never whole. The
+``lax`` form, which runs anywhere and is the oracle of the other:
 
 - forward: time is cut into chunks of ``chunk`` steps. Every chunk runs its
   steps from a ZERO state, all chunks side by side (``chunk`` sequential
@@ -28,10 +30,21 @@ channel c and state n:
   their steps forms ``g_t = Cm_t dy_t + G`` and every cotangent from ``g_t``,
   ``s_{t-1}`` and ``a_t``. No loop runs over more than ``chunk`` steps.
 
+Its steps are vector-unit work in ``while`` loops whose carries go through
+HBM every iteration. On a TPU, where the shapes allow (channels a multiple
+of 128 lanes, states of 8 sublanes, a ``chunk`` of whole sublane tiles whose
+buffers at one lane tile fit the kernels' VMEM budget:
+:func:`pallas_scan.applies`), forward and backward are instead the kernels of
+:mod:`dgraph_tpu.ops.pallas_scan`: the sequential recurrence itself, ``chunk``
+steps a time block, the ``[N, C]`` state (and the backward's ``G`` and ``dA``)
+in VMEM for the whole of time; no zero-state chunks, hops or correction
+pass. The route is decided a call from the backend and the shapes, and
+counted (``ssm.scan_calls``, ``ssm.scan_fused``); both routes keep the inputs
+and the start states ``[T / chunk, N, C]`` for the backward.
+
 Everything inside is float32 whatever the streams' types; a ``T`` that is no
 multiple of ``chunk`` is padded with steps of ``delta = 0`` (decay 1, input
-0: the state passes through). Plain ``lax``: the steps are vector-unit work
-in ``while`` loops, which is what a fused kernel would replace (ROADMAP R14).
+0: the state passes through).
 
 :func:`scan_sequence` is the operator over a sequence sharded on a mesh axis:
 each rank scans its shard from a zero state, the ranks' end states and whole
@@ -47,11 +60,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# Steps a chunk, chunks the backward takes side by side (its stored states are
+from dgraph_tpu.obs.metrics import default_registry
+from dgraph_tpu.ops import pallas_scan
+
+# Steps a chunk (both routes: the kernels' time block too), and for the lax
+# route alone chunks the backward takes side by side (its stored states are
 # [SCAN_CHUNK, SCAN_GROUP, N, C] float32: 335 MB at N 16, C 5120) and steps of
-# a sequential loop traced into one body: what the chip measured fastest at
-# T 8192, C 5120, N 16; as (chunk, group, unroll), forward / forward + backward
-# in ms (PERF.md section 6, PR 39): (128, 8, 4) 11.86 / 28.46; (256, 4, 4)
+# a sequential loop traced into one body: what the chip measured fastest for
+# the lax route at T 8192, C 5120, N 16 (the kernels there: 2.94 / 9.27,
+# PERF.md section 6, PR 41); as (chunk, group, unroll), forward / forward +
+# backward in ms (PERF.md section 6, PR 39): (128, 8, 4) 11.86 / 28.46; (256, 4, 4)
 # 11.67 / 32.39; (256, 8, 4) 11.70 / 32.51; (256, 1, 4) 11.68 / 39.57; (512,
 # 2, 4) 11.30 / 37.52; (256, 4, 1) 19.60 / 33.42; (256, 4, 8) 14.15 / 34.33.
 SCAN_CHUNK = 128
@@ -196,21 +214,34 @@ def _backward(u, delta, At, B, Cm, starts, dy, d_last, L: int):
     return d_u, d_delta, dAt.sum(0), d_B, d_C, d_s0
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _scan(u, delta, A, B, Cm, D, s0, chunk):
-    return _scan_fwd(u, delta, A, B, Cm, D, s0, chunk)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _scan(u, delta, A, B, Cm, D, s0, chunk, fused):
+    return _scan_fwd(u, delta, A, B, Cm, D, s0, chunk, fused)[0]
 
 
-def _scan_fwd(u, delta, A, B, Cm, D, s0, chunk):
-    y, starts, last = _forward(u, delta, A.T, B, Cm, s0.T, chunk)
-    return (y + D * u.astype(jnp.float32), last.T), (
-        u, delta, A, B, Cm, D, starts)
+def _scan_fwd(u, delta, A, B, Cm, D, s0, chunk, fused):
+    if fused:
+        with jax.named_scope("fused_fwd"):
+            y, starts, last = pallas_scan.fused_forward(
+                u, delta, A.T, B, Cm, D, s0.T, chunk)
+    else:
+        y, starts, last = _forward(u, delta, A.T, B, Cm, s0.T, chunk)
+        y = y + D * u.astype(jnp.float32)
+    return (y, last.T), (u, delta, A, B, Cm, D, starts)
 
 
-def _scan_bwd(chunk, res, cts):
+def _scan_bwd(chunk, fused, res, cts):
     u, delta, A, B, Cm, D, starts = res
     dy, d_last = cts
     dy = dy.astype(jnp.float32)
+    if fused:
+        with jax.named_scope("fused_bwd"):
+            d_u, d_delta, dAt, d_B, d_C, d_D, d_s0 = \
+                pallas_scan.fused_backward(
+                    u, delta, A.T, B, Cm, D, starts, dy,
+                    d_last.astype(jnp.float32).T, chunk)
+        return (d_u, d_delta, dAt.T, d_B.astype(B.dtype),
+                d_C.astype(Cm.dtype), d_D, d_s0.T)
     d_u, d_delta, dAt, d_B, d_C, d_s0 = _backward(
         u, delta, A.T, B, Cm, starts, dy, d_last.astype(jnp.float32).T, chunk)
     u32 = u.astype(jnp.float32)
@@ -225,12 +256,20 @@ _scan.defvjp(_scan_fwd, _scan_bwd)
 def selective_scan(u, delta, A, B, Cm, D, s0=None, *, chunk: int = SCAN_CHUNK):
     """``(y [T, C] float32, the last state [C, N] float32)`` of the
     recurrence in the module docstring; differentiable in every argument
-    (``s0`` too) through the chunked backward. ``delta`` is float32."""
+    (``s0`` too) through its own backward. ``delta`` is float32. On a TPU,
+    where the shapes allow (:func:`pallas_scan.applies`), forward and
+    backward are the kernels of :mod:`dgraph_tpu.ops.pallas_scan` with
+    ``chunk`` the steps of a time block; everywhere else the chunked ``lax``
+    form. Counted a traced call: ``ssm.scan_calls``, ``ssm.scan_fused``."""
     if s0 is None:
         s0 = jnp.zeros(A.shape, jnp.float32)
     chunk = max(1, min(chunk, u.shape[0]))
+    fused = jax.default_backend() == "tpu" and pallas_scan.applies(u, A, chunk)
+    default_registry.counter("ssm.scan_calls")
+    if fused:
+        default_registry.counter("ssm.scan_fused")
     return _scan(u, delta.astype(jnp.float32), A.astype(jnp.float32), B, Cm,
-                 D.astype(jnp.float32), s0.astype(jnp.float32), chunk)
+                 D.astype(jnp.float32), s0.astype(jnp.float32), chunk, fused)
 
 
 def scan_sequence(u, delta, A, B, Cm, D, comm=None, *,

@@ -64,6 +64,9 @@ SECTIONS = [
       "block_chunk_counts", "chunk_vblock_spans"]),
     ("Selective scan", "dgraph_tpu.ops.selective_scan",
      ["selective_scan", "scan_sequence"]),
+    ("Selective scan kernels", "dgraph_tpu.ops.pallas_scan",
+     ["applies", "channel_block", "vmem_bytes", "fused_forward",
+      "fused_backward"]),
     ("Models", "dgraph_tpu.models", None),
     ("GraphCast", "dgraph_tpu.models.graphcast", None),
     ("Tensor parallelism", "dgraph_tpu.parallel.tensor", None),

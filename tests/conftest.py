@@ -52,6 +52,22 @@ def _drop_what_outlives_the_module():
     gc.freeze()
 
 
+@pytest.fixture
+def tpu_interpret(monkeypatch):
+    """The program's TPU branches, their kernels interpreted."""
+    import jax
+    from jax.experimental import pallas
+
+    real_call = pallas.pallas_call
+
+    def interpreted(*args, **kwargs):
+        kwargs["interpret"] = True
+        return real_call(*args, **kwargs)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pallas, "pallas_call", interpreted)
+
+
 @pytest.fixture(scope="session")
 def mesh8():
     import jax

@@ -93,3 +93,46 @@ def test_transposed_grad_kernel_compiles_at_arxiv_shape(one_chip, has_weight):
     assert "tpu_custom_call" in compiled.as_text()
     out = jax.eval_shape(fn, *args)
     assert (out.shape, out.dtype) == ((N, F), jnp.bfloat16)
+
+
+# phi4_mini_flash.seq8k (benchmark/configs/phi4_mini_flash.json): seq_len,
+# d_inner, d_state; u in bf16, the rest float32. Its scan_chunk is 128 (a
+# channel block of 1024 lanes); 512 is the longest power of two that takes
+# the kernels there (256 lanes), 1024 keeps the lax form.
+SCAN_T, SCAN_C, SCAN_N = 8192, 5120, 16
+
+
+@pytest.mark.parametrize("bt,bc", [(128, 1024), (512, 256)])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_selective_scan_kernels_compile_at_phi4_shape(one_chip, direction, bt,
+                                                      bc):
+    """The selective scan's kernels (ISSUE 41) at the cell's shape and the
+    channel block derived there: the state scratch over the time blocks, the
+    columns spread over the lanes, the backward's states and decays (16.8 MB
+    at 128 steps) in VMEM under the limit derived from them."""
+    from dgraph_tpu.ops import pallas_scan as ps
+
+    def shape(*s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    T, C, N = SCAN_T, SCAN_C, SCAN_N
+    u = shape(T, C, dtype=jnp.bfloat16)
+    assert ps.applies(u, shape(C, N), bt) and not ps.applies(
+        u, shape(C, N), 1024)
+    assert ps.channel_block(C, N, bt, 2) == bc
+    args = [u, shape(T, C), shape(N, C), shape(T, N), shape(T, N), shape(C)]
+    if direction == "forward":
+        fn = lambda *a: ps.fused_forward(*a, bt)
+        args.append(shape(N, C))
+        want = [((T, C), jnp.float32), ((T // bt, N, C), jnp.float32),
+                ((N, C), jnp.float32)]
+    else:
+        fn = lambda *a: ps.fused_backward(*a, bt)
+        args += [shape(T // bt, N, C), shape(T, C), shape(N, C)]
+        want = [((T, C), jnp.bfloat16), ((T, C), jnp.float32),
+                ((N, C), jnp.float32), ((T, N), jnp.float32),
+                ((T, N), jnp.float32), ((C,), jnp.float32),
+                ((N, C), jnp.float32)]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert [(o.shape, o.dtype) for o in jax.eval_shape(fn, *args)] == want

@@ -30,7 +30,7 @@ from dgraph_tpu.ops import local as local_ops
 from dgraph_tpu.train import lm
 
 from test_sdar import build as build_sdar  # the tiny two-layer expert model
-from test_take_scatter_bias_relu import _plan, _shard, tpu_interpret  # noqa: F401
+from test_take_scatter_bias_relu import _plan, _shard
 
 TAKE_CHILDREN = ("rows", "mask", "slice")
 
@@ -210,7 +210,7 @@ ROUTES = ("gather.bwd_chunks", "gather.bwd_transposed", "gather.bwd_permuted")
 
 
 def test_every_operation_of_a_local_take_is_under_one_child_or_none(
-        tpu_interpret, monkeypatch):  # noqa: F811
+        tpu_interpret, monkeypatch):
     """With the table taken in row parts and the backward on the transposed
     route, all three children occur; no operation's path holds two of them,
     and what is under none is what stays with the parent (the ids' shape

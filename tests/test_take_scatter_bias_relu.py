@@ -30,21 +30,6 @@ from dgraph_tpu.ops import local as local_ops
 V, E_HALF, F = 600, 2500, 256  # two 128-column chunks a layer
 
 
-@pytest.fixture
-def tpu_interpret(monkeypatch):
-    """The program's TPU branches, their kernels interpreted."""
-    from jax.experimental import pallas
-
-    real_call = pallas.pallas_call
-
-    def interpreted(*args, **kwargs):
-        kwargs["interpret"] = True
-        return real_call(*args, **kwargs)
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(pallas, "pallas_call", interpreted)
-
-
 def _graph(world_size, seed=0):
     rng = np.random.default_rng(seed)
     src, dst = rng.integers(0, V, E_HALF), rng.integers(0, V, E_HALF)
