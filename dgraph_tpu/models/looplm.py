@@ -93,6 +93,31 @@ positional encoding):
 What a layer kept reaches its readers as a loop-invariant input of their scan
 (never a carry: nothing is copied per layer), inside one pass over the stack.
 
+A kind may have ONE half: ``"<mixer>+none"`` is a mixer alone, ``"none+<ffn>"``
+a feed-forward part alone (``h <- h + Part(Norm(h))``, once). That is the
+layer of a hybrid Mamba-2 / expert decoder (NVIDIA Nemotron-H, ``model_type``
+nemotron_h; ``ssd+none``, ``none+experts``, ``attn+none``, RMSNorm at eps 1e-5,
+no bias, no positional encoding, an untied head), with:
+
+- ``ssd``, the Mamba-2 mixer (``ssd``: :class:`Mamba2Mixer`, H heads of P, G
+  groups of N states, K taps): ``(z, xBC, dt) = split(W_in u)`` of sizes ``H P
+  | H P + 2 G N | H``; ``xBC <- silu(conv_K(xBC) + b)`` (depthwise, causal,
+  zeros before the start, the ``K - 1``-row halo as ``conv``'s); ``(x, B, C)
+  = split(xBC)``, ``x`` as ``[T, H, P]``, ``B``, ``C`` as ``[T, G, N]``, head
+  h reading group ``h // (H / G)``; ``dt = softplus(dt + dt_bias)``; ``a_t =
+  exp(dt_t A)``, ``A = -exp(A_log)`` a head; ``S_t = a_t S_{t-1} + dt_t x_t
+  B_t^T`` from ``S_{-1} = 0``, ``y_t = S_t C_t + D x_t``
+  (:mod:`dgraph_tpu.ops.ssd`: chunked, float32 decays and states, the state
+  crossing a sharded sequence rank by rank); ``y <- RMSNorm_{H P / G}(y *
+  silu(z)) * g`` (the norm over each of the G groups of channels, AFTER the
+  gate); ``Mix = W_out y``;
+- an expert of the ungated form (``HeldExperts.form = "relu2"``): ``W_down,e
+  relu(W_up,e u)^2``, two products and no gate leaf; and ONE SHARED expert
+  beside the routed ones (``HeldExperts.shared_width``): ``FFN(u) = (sum over
+  the chosen experts held here) + W_down,s relu(W_up,s u)^2`` in the experts'
+  form, a dense FFN on every token, which every chip of a deployment computes
+  alike (a sum over shares counts it once).
+
 Everything but attention, the convolutions' halo and the scan's state is
 token-local, so those are the only communication. Parameters are float32;
 matmuls run in ``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
@@ -154,7 +179,12 @@ class HeldExperts:
     ``"softmax"`` or ``"sigmoid"``; ``select_bias``: a ``[n_total]`` leaf
     ``select_bias`` added to the scores for the choice only (no gradient, no
     optimizer update); ``gate_eps`` added to the chosen gates' sum (None: the
-    softmax form's guard); ``gate_scale`` on the normalised gates."""
+    softmax form's guard); ``gate_scale`` on the normalised gates. ``form``:
+    an expert is ``"gated_silu"`` (leaves ``gate_proj``, ``up_proj``,
+    ``down_proj``) or ``"relu2"`` (``W_down relu(W_up x)^2``: no
+    ``gate_proj``); ``shared_width`` > 0: one shared expert of that width and
+    the same form on every token, added to the held part (leaves
+    ``shared_up_proj``, ``shared_down_proj`` and, gated, ``shared_gate_proj``)."""
 
     n_total: int
     n_held: int
@@ -166,6 +196,8 @@ class HeldExperts:
     select_bias: bool = False
     gate_eps: Optional[float] = None
     gate_scale: float = 1.0
+    form: str = "gated_silu"
+    shared_width: int = 0
 
 
 # Leaves of the parameter tree, by name, that are buffers: no gradient
@@ -189,7 +221,9 @@ class _Kernel(nn.Module):
 class HeldExpertsFFN(nn.Module):
     """``(norm's float32 output [T, d]) -> (the held experts' part [T, d]
     float32, stats)``; scope ``dgraph.lm.moe`` with ``router``, ``routes``,
-    ``dispatch``, ``experts``, ``combine``."""
+    ``dispatch``, ``experts``, ``combine`` and, with a shared expert,
+    ``shared`` (a dense FFN on this shard's own tokens, added after
+    ``combine``)."""
 
     spec: HeldExperts
     comm: Any
@@ -216,13 +250,26 @@ class HeldExpertsFFN(nn.Module):
                 # for a caller that asks (mutable=["intermediates"]): which
                 # experts each row chose, to set beside a reference's
                 self.sow("intermediates", "chosen", experts)
-            return held_experts_ffn(
-                x32.astype(self.dtype), gates, experts,
-                _Kernel((sp.n_held, d, sp.width), name="gate_proj")(),
+            x = x32.astype(self.dtype)
+            gated = sp.form == "gated_silu"
+            out, stats = held_experts_ffn(
+                x, gates, experts,
+                _Kernel((sp.n_held, d, sp.width), name="gate_proj")()
+                if gated else None,
                 _Kernel((sp.n_held, d, sp.width), name="up_proj")(),
                 _Kernel((sp.n_held, sp.width, d), name="down_proj")(),
                 first_held=sp.first_held, rows=sp.rows,
-                axis_name=self.comm.graph_axis)
+                axis_name=self.comm.graph_axis, form=sp.form)
+            if sp.shared_width:
+                with jax.named_scope("shared"):
+                    dense = functools.partial(
+                        nn.Dense, use_bias=False, dtype=self.dtype)
+                    u = dense(sp.shared_width, name="shared_up_proj")(x)
+                    mid = nn.silu(dense(sp.shared_width,
+                                        name="shared_gate_proj")(x)) * u \
+                        if gated else jnp.square(nn.relu(u))
+                    out = out + dense(d, name="shared_down_proj")(mid)
+            return out, stats
 
 
 def previous_rows(y: jax.Array, n: int, comm) -> jax.Array:
@@ -237,6 +284,15 @@ def previous_rows(y: jax.Array, n: int, comm) -> jax.Array:
     # no pair ends at rank 0: ppermute leaves zeros there
     return jax.lax.ppermute(y[-n:], comm.graph_axis,
                             [(i, i + 1) for i in range(world - 1)])
+
+
+def causal_taps(y: jax.Array, taps: jax.Array, comm) -> jax.Array:
+    """``z_t = sum_j taps[j] * y_{t-(K-1)+j}`` for ``taps`` ``[K, d]``: a
+    depthwise causal convolution whose rows before the shard's first are the
+    rank before's (:func:`previous_rows`; zeros at the sequence's start)."""
+    K, n = taps.shape[0], y.shape[0]
+    rows = jnp.concatenate([previous_rows(y, K - 1, comm), y]) if K > 1 else y
+    return sum(taps[j] * rows[j:j + n] for j in range(K))
 
 
 class _Taps(nn.Module):
@@ -275,11 +331,7 @@ class GatedShortConv(nn.Module):
                             for t in jnp.split(bcx, 3, axis=-1))
                 y = b * xt
                 taps = _Taps((K, d), name="conv")().astype(jnp.float32)
-                rows = jnp.concatenate(
-                    [previous_rows(y, K - 1, self.comm), y]) if K > 1 else y
-                n = y.shape[0]
-                z = sum(taps[j] * rows[j:j + n] for j in range(K))
-                g = (c * z).astype(bcx.dtype)
+                g = (c * causal_taps(y, taps, self.comm)).astype(bcx.dtype)
             with jax.named_scope("out_proj"):
                 return dense(d, name="out_proj")(g)
 
@@ -327,7 +379,7 @@ class SelectiveSSM(nn.Module):
     def __call__(self, x):
         from dgraph_tpu.ops.selective_scan import SCAN_CHUNK, scan_sequence
 
-        sp, d, n = self.spec, x.shape[-1], x.shape[0]
+        sp, d = self.spec, x.shape[-1]
         C, N, K, R = sp.inner, sp.state, sp.conv, sp.dt_rank
         dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
         wide = functools.partial(dense, dot_general=_dot_f32_out)
@@ -337,11 +389,7 @@ class SelectiveSSM(nn.Module):
             with jax.named_scope("conv"):
                 taps = _Taps((K, C), name="conv")().astype(jnp.float32)
                 bias = self.param("conv_bias", nn.initializers.zeros, (C,))
-                u32 = u.astype(jnp.float32)
-                rows = jnp.concatenate(
-                    [previous_rows(u32, K - 1, self.comm), u32]) \
-                    if K > 1 else u32
-                u = nn.silu(sum(taps[j] * rows[j:j + n] for j in range(K))
+                u = nn.silu(causal_taps(u.astype(jnp.float32), taps, self.comm)
                             + bias).astype(u.dtype)
             with jax.named_scope("dt_bc"):
                 step, B, Cm = jnp.split(
@@ -358,6 +406,89 @@ class SelectiveSSM(nn.Module):
                 g = (y * nn.silu(z.astype(jnp.float32))).astype(u.dtype)
             with jax.named_scope("out_proj"):
                 return dense(d, name="out_proj")(g), y.astype(u.dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class Mamba2Mixer:
+    """The sizes of a Mamba-2 mixer: ``heads`` heads of ``head_dim`` channels
+    (``heads * head_dim`` inner channels), ``groups`` groups of B and C of
+    ``state`` states each, ``conv`` taps of the causal convolution over ``x |
+    B | C``, and the ``chunk`` of the chunked recurrence (None:
+    ``ops.ssd.SSD_CHUNK``)."""
+
+    heads: int
+    head_dim: int
+    groups: int = 8
+    state: int = 128
+    conv: int = 4
+    chunk: Optional[int] = None
+
+
+def _a_log_heads_init(key, shape, dtype=jnp.float32, lo=1.0, hi=16.0):
+    """``log U(lo, hi)`` a head: Mamba-2's own (``A_init_range``)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, lo, hi))
+
+
+class _Gain(nn.Module):
+    """A gain vector under the leaf name every norm's has."""
+
+    size: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.size,))
+
+
+class SSDMixer(nn.Module):
+    """``x [T_loc, d] -> W_out (RMSNorm_groups(y * silu(z)) * g)`` (module
+    docstring); scope ``dgraph.lm.ssd`` with ``in_proj``, ``conv``, ``chunk``
+    and ``state`` (:mod:`dgraph_tpu.ops.ssd`'s), ``norm``, ``out_proj``. The
+    two projections run in the compute dtype (``dt`` is read from ``W_in``'s
+    result in that type, as the published kernels do under autocast); the
+    taps' sum, the step size, the decays and states of the recurrence, the
+    gate and the grouped norm in float32; ``x``, ``B``, ``C`` and the norm's
+    result are compute-dtype streams."""
+
+    spec: Mamba2Mixer
+    comm: Any
+    dtype: Any = None
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        from dgraph_tpu.ops.ssd import SSD_CHUNK, ssd_sequence
+
+        sp, d, n = self.spec, x.shape[-1], x.shape[0]
+        H, Pd, G, N, K = sp.heads, sp.head_dim, sp.groups, sp.state, sp.conv
+        inner, wide = H * Pd, H * Pd + 2 * G * N
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        with jax.named_scope("dgraph.lm.ssd"):
+            with jax.named_scope("in_proj"):
+                z, xbc, step = jnp.split(
+                    dense(inner + wide + H, name="in_proj")(x),
+                    [inner, inner + wide], axis=-1)
+            with jax.named_scope("conv"):
+                taps = _Taps((K, wide), name="conv")().astype(jnp.float32)
+                bias = self.param("conv_bias", nn.initializers.zeros, (wide,))
+                xbc = nn.silu(causal_taps(xbc.astype(jnp.float32), taps,
+                                          self.comm) + bias).astype(xbc.dtype)
+            xs, B, Cm = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+            delta = nn.softplus(step.astype(jnp.float32) + self.param(
+                "dt_bias", _dt_bias_init, (H,)))
+            A = -jnp.exp(self.param("A_log", _a_log_heads_init, (H,)))
+            D = self.param("D", nn.initializers.ones, (H,))
+            y = ssd_sequence(
+                xs.reshape(n, H, Pd), delta, A, B.reshape(n, G, N),
+                Cm.reshape(n, G, N), D, self.comm, chunk=sp.chunk or SSD_CHUNK)
+            with jax.named_scope("norm"):
+                g = (y.reshape(n, inner) * nn.silu(z.astype(jnp.float32))
+                     ).reshape(n, G, inner // G)
+                g = g * jax.lax.rsqrt(
+                    jnp.mean(g * g, -1, keepdims=True) + self.eps)
+                g = (g.reshape(n, inner) * _Gain(inner, name="norm")()
+                     ).astype(xbc.dtype)
+            with jax.named_scope("out_proj"):
+                return dense(d, name="out_proj")(g)
 
 
 class GatedMemoryUnit(nn.Module):
@@ -377,8 +508,8 @@ class GatedMemoryUnit(nn.Module):
 
 
 LAYER_MIXERS = ("attn", "conv", "ssm", "ssm_keep", "gmu", "diff_win",
-                "diff_keep", "cross")
-LAYER_FFNS = ("dense", "experts")
+                "diff_keep", "cross", "ssd", "none")
+LAYER_FFNS = ("dense", "experts", "none")  # "none": the half is not there
 DIFF_MIXERS = ("diff_win", "diff_keep", "cross")  # differential attention
 ATTENDING = ("attn",) + DIFF_MIXERS
 KEEPS = {"ssm_keep": "m", "diff_keep": "kv"}  # mixer -> what it keeps
@@ -386,12 +517,15 @@ READS = {"gmu": "m", "cross": "kv"}  # mixer -> what it reads of the kept
 
 
 def split_kind(kind: str):
-    """``"conv+experts" -> ("conv", "experts")``; raises on an unknown kind."""
+    """``"conv+experts" -> ("conv", "experts")``; a layer of one half spells
+    the other ``none`` (``"ssd+none"``, ``"none+experts"``); raises on an
+    unknown kind and on a layer of no half."""
     mixer, _, ffn = kind.partition("+")
-    if mixer not in LAYER_MIXERS or ffn not in LAYER_FFNS:
+    if mixer not in LAYER_MIXERS or ffn not in LAYER_FFNS \
+            or mixer == ffn == "none":
         raise ValueError(
             f"layer kind {kind!r}: '<mixer>+<ffn>' with mixer in "
-            f"{LAYER_MIXERS} and ffn in {LAYER_FFNS}")
+            f"{LAYER_MIXERS} and ffn in {LAYER_FFNS}, not both none")
     return mixer, ffn
 
 
@@ -401,7 +535,8 @@ class LoopLMLayer(nn.Module):
     for a dense FFN). Sandwich norms and a gated MLP by default (Ouro's);
     ``sandwich_norm=False``, ``qk_norm``, ``experts``, ``block_length`` give
     the pre-norm sparse-expert block-diffusion layer; ``mixer="conv"`` puts
-    the gated short convolution in attention's place (module docstring). A
+    the gated short convolution in attention's place (module docstring);
+    ``mixer="none"`` / ``has_ffn=False`` leave a half out. A
     mixer that reads what an earlier layer kept (``READS``) takes it as a
     third argument; one that keeps something (``KEEPS``) returns ``(h,
     (stats, kept))``."""
@@ -427,6 +562,8 @@ class LoopLMLayer(nn.Module):
     window: int = 0  # keys a query of a "diff_win" layer sees
     ssm: Optional[StateSpace] = None
     depth: int = 0  # the published index of the layer (differential attention)
+    ssd: Optional[Mamba2Mixer] = None
+    has_ffn: bool = True  # False: the mixer alone ("<mixer>+none")
 
     @nn.compact
     def __call__(self, h, rope, kept=None):  # [T_loc, hidden], (cos, sin)
@@ -454,11 +591,16 @@ class LoopLMLayer(nn.Module):
             a = GatedMemoryUnit(dt, name="gmu")(norm(name="norm_gmu_in")(h),
                                                 kept)
             h = h + post("norm_gmu_out")(a)
+        elif self.mixer == "ssd":
+            a = SSDMixer(self.ssd, self.comm, dt, self.rms_eps, name="ssd")(
+                norm(name="norm_ssd_in")(h))
+            h = h + post("norm_ssd_out")(a)
         elif self.mixer in DIFF_MIXERS:
             h, keep = self.differ(h, kept, dt, dense, norm, post)
-        else:
+        elif self.mixer != "none":
             h = self.attend(h, rope, dense, norm, post)
-        h, stats = self.ffn(h, dt, dense, norm, post)
+        h, stats = self.ffn(h, dt, dense, norm, post) if self.has_ffn \
+            else (h, None)
         return h, ((stats, keep) if self.mixer in KEEPS else stats)
 
     def attend(self, h, rope, dense, norm, post):
@@ -602,7 +744,7 @@ class LoopPass(nn.Module):
                 for i, (kind, n) in enumerate(layer_runs(self.pattern)):
                     mixer, ffn = split_kind(kind)
                     fields = dict(
-                        self.layer, mixer=mixer,
+                        self.layer, mixer=mixer, has_ffn=ffn != "none",
                         experts=self.layer["experts"] if ffn == "experts"
                         else None)
                     if mixer in DIFF_MIXERS or mixer in KEEPS:
@@ -618,7 +760,8 @@ class LoopPass(nn.Module):
                     # rematerialised (the six-kind stack compiled for a v5e at
                     # T 8192: 15.84 GB so, 11.44 GB with the barrier). The
                     # runs of the kinds that were there keep their program.
-                    barrier = n == 1 and mixer not in ("attn", "conv")
+                    barrier = n == 1 and (mixer not in ("attn", "conv")
+                                          or ffn == "none")
                     if mixer in READS:
                         if READS[mixer] not in kept:
                             raise ValueError(
@@ -689,6 +832,7 @@ class LoopLM(nn.Module):
     window: int = 0
     ssm: Optional[StateSpace] = None
     first_depth: int = 0  # the published index of the stack's first layer
+    ssd: Optional[Mamba2Mixer] = None
 
     def layer_kinds(self) -> tuple:
         """The kind of each of the ``num_layers`` layers, in stack order."""
@@ -712,6 +856,9 @@ class LoopLM(nn.Module):
             if mixers & {"ssm", "ssm_keep"} and self.ssm is None:
                 raise ValueError("state-space layers in the pattern need "
                                  "`ssm`, their sizes")
+            if "ssd" in mixers and self.ssd is None:
+                raise ValueError("Mamba-2 layers in the pattern need `ssd`, "
+                                 "their sizes")
             if "diff_win" in mixers and self.window < 1:
                 raise ValueError("windowed layers in the pattern need "
                                  "`window`")
@@ -726,7 +873,7 @@ class LoopLM(nn.Module):
             experts=self.experts, block_length=self.block_length,
             conv_kernel=self.conv_kernel, norm=self.norm,
             attn_bias=self.attn_bias, fused_mlp=self.fused_mlp,
-            window=self.window, ssm=self.ssm)
+            window=self.window, ssm=self.ssm, ssd=self.ssd)
         # the same parameters every pass: broadcast, not split
         loop = nn.scan(
             LoopPass, variable_broadcast="params",

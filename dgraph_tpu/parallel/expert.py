@@ -19,8 +19,9 @@ choice and not the gate, ``g_e = scale * s_e / (sum of the chosen s + eps)``
 
 Two layers stand on that (:func:`held_experts_apply`):
 
-- :func:`held_experts_ffn`, the gated-SiLU FFN of a sparse-expert model at
-  published sizes, the held experts' rows multiplied as groups
+- :func:`held_experts_ffn`, the FFN of a sparse-expert model at published
+  sizes in either of two forms (gated SiLU, three products an expert; ungated
+  squared ReLU, two), the held experts' rows multiplied as groups
   (:func:`grouped_matmul`). With no mesh axis its partial sum is the chip's
   share of an expert-parallel deployment (what the absent experts would add
   is left out); with an axis the shares of the ranks are summed.
@@ -37,6 +38,7 @@ exchange is what is left of R9.)
 
 from __future__ import annotations
 
+import math
 from typing import Callable, NamedTuple, Optional
 
 import jax
@@ -224,14 +226,23 @@ _from_buffer.defvjp(_from_buffer_fwd, _from_buffer_bwd)
 GROUPED_TILE_ROWS = 512
 
 
+def grouped_tile_rows(m: int) -> int:
+    """The row tile of a buffer of ``m`` rows: GROUPED_TILE_ROWS, a smaller
+    buffer whole, and where that does not divide ``m`` (6 routes a token over
+    a 128-token probe: 768 rows) their greatest common divisor: the kernel
+    pads no tile."""
+    tile = min(GROUPED_TILE_ROWS, m)
+    return tile if m % tile == 0 else math.gcd(m, GROUPED_TILE_ROWS)
+
+
 def _grouped_tiling(m: int, k: int, n: int):
-    """Tiles of one grouped product: GROUPED_TILE_ROWS rows, and along each
-    of the other two dimensions the largest listed tile that divides it
+    """Tiles of one grouped product: :func:`grouped_tile_rows` rows, and along
+    each of the other two dimensions the largest listed tile that divides it
     (2048 -> 1024, 768 -> 768: no tile is padded), else the whole of it."""
     def fit(x):
         return next((t for t in (1024, 768, 512, 256, 128) if x % t == 0), x)
 
-    return min(GROUPED_TILE_ROWS, m), fit(k), fit(n)
+    return grouped_tile_rows(m), fit(k), fit(n)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
@@ -299,7 +310,7 @@ def held_experts_apply(
     rows = buffer_rows(T, k, n_held, rows)
     with jax.named_scope("routes"):
         routes = held_routes(experts, first_held=first_held, n_held=n_held,
-                             rows=rows, tile_rows=min(GROUPED_TILE_ROWS, rows))
+                             rows=rows, tile_rows=grouped_tile_rows(rows))
     with jax.named_scope("dispatch"):
         xs = _to_buffer(x, routes)
     with jax.named_scope("experts"):
@@ -311,23 +322,33 @@ def held_experts_apply(
     return out, routes.stats
 
 
+# The forms of an expert (and of the shared expert beside them):
+# W_down (silu(W_gate x) * W_up x), and W_down relu(W_up x)^2 with no gate.
+EXPERT_FORMS = ("gated_silu", "relu2")
+
+
 def held_experts_ffn(
     x: jax.Array,  # [T, d] this shard's tokens, compute dtype
     gates: jax.Array,
     experts: jax.Array,
-    w_gate: jax.Array,  # [n_held, d, f] the held experts' weights
-    w_up: jax.Array,  # [n_held, d, f]
+    w_gate: Optional[jax.Array],  # [n_held, d, f]; None for form "relu2"
+    w_up: jax.Array,  # [n_held, d, f] the held experts' weights
     w_down: jax.Array,  # [n_held, f, d]
     *,
+    form: str = "gated_silu",
     first_held: int = 0,
     rows: Optional[int] = None,
     axis_name: Optional[str] = None,
 ):
-    """The held experts' part of a gated-SiLU sparse FFN, expert e's row for
-    a token being ``W_down,e (silu(W_gate,e x_t) * W_up,e x_t)``: three
-    grouped products over the buffer (:func:`held_experts_apply`,
-    :func:`grouped_matmul`). The gradient reaches the router through
-    ``gates``."""
+    """The held experts' part of a sparse FFN over the buffer
+    (:func:`held_experts_apply`, :func:`grouped_matmul`), expert e's row for
+    a token being, by ``form``: ``"gated_silu"``, ``W_down,e (silu(W_gate,e
+    x_t) * W_up,e x_t)``, three grouped products; ``"relu2"``, ``W_down,e
+    relu(W_up,e x_t)^2``, two, and no ``w_gate``. The gradient reaches the
+    router through ``gates``."""
+    if form not in EXPERT_FORMS or (w_gate is None) != (form == "relu2"):
+        raise ValueError(f"expert form {form!r} (of {EXPERT_FORMS}) with"
+                         f"{'out' if w_gate is None else ''} a gate kernel")
     dt = x.dtype
 
     def ffn(xs, routes):
@@ -335,15 +356,19 @@ def held_experts_ffn(
         # BEFORE the nonlinearity, so that nothing downstream or in the
         # backward pass ever multiplies what happens to lie there
         live = routes.live[:, None]
-        g = jnp.where(live, grouped_matmul(
-            xs, w_gate.astype(dt), routes.group_sizes), 0)
-        u = jnp.where(live, grouped_matmul(
-            xs, w_up.astype(dt), routes.group_sizes), 0)
-        return jnp.where(live, grouped_matmul(
-            jax.nn.silu(g) * u, w_down.astype(dt), routes.group_sizes), 0)
+
+        def product(a, w):
+            return jnp.where(live, grouped_matmul(
+                a, w.astype(dt), routes.group_sizes), 0)
+
+        if form == "relu2":
+            return product(jnp.square(jax.nn.relu(product(xs, w_up))), w_down)
+        g = product(xs, w_gate)
+        u = product(xs, w_up)
+        return product(jax.nn.silu(g) * u, w_down)
 
     return held_experts_apply(
-        x, gates, experts, ffn, n_held=w_gate.shape[0], first_held=first_held,
+        x, gates, experts, ffn, n_held=w_up.shape[0], first_held=first_held,
         rows=rows, axis_name=axis_name)
 
 

@@ -440,8 +440,10 @@ def layers_by_kind(kinds) -> dict:
     """How many of a stack's layers are of which kind (``models.looplm``'s
     ``"<mixer>+<ffn>"``): ``conv``, ``attention`` (every attending mixer),
     ``dense_ffn``, ``expert_ffn``, and, where the stack has any, ``ssm``,
-    ``gmu``, ``window`` (attention under a window) and ``cross`` (attention
-    on another layer's keys and values)."""
+    ``gmu``, ``window`` (attention under a window), ``cross`` (attention
+    on another layer's keys and values), ``ssd`` (Mamba-2 mixers) and the
+    layers of one half: ``mixer_only`` (``"<mixer>+none"``) and
+    ``experts_only`` (``"none+experts"``)."""
     from dgraph_tpu.models.looplm import ATTENDING, split_kind
 
     mixers = [split_kind(k)[0] for k in kinds]
@@ -450,7 +452,10 @@ def layers_by_kind(kinds) -> dict:
            "dense_ffn": sum(k.endswith("+dense") for k in kinds),
            "expert_ffn": sum(k.endswith("+experts") for k in kinds)}
     more = {"ssm": count("ssm", "ssm_keep"), "gmu": count("gmu"),
-            "window": count("diff_win"), "cross": count("cross")}
+            "window": count("diff_win"), "cross": count("cross"),
+            "ssd": count("ssd"),
+            "mixer_only": sum(k.endswith("+none") for k in kinds),
+            "experts_only": sum(k == "none+experts" for k in kinds)}
     out.update({k: n for k, n in more.items() if n})
     return out
 
@@ -540,7 +545,9 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     and ``setup.init_opt_state``; counters ``lm.*`` (the layers by kind:
     ``lm.layers.conv / .attention / .dense_ffn / .expert_ffn``, and where the
     stack has them ``.ssm / .gmu / .window / .cross`` with ``lm.ssm.state /
-    .inner / .chunk``, ``lm.attention.window / .v_head_dim``); a stack of
+    .inner / .chunk``, ``lm.attention.window / .v_head_dim``, ``.ssd /
+    .mixer_only / .experts_only`` with ``lm.ssd.heads / .groups / .state /
+    .chunk``; ``moe.shared_width`` beside the ``moe.*`` of a shared expert); a stack of
     several masks (differential attention under a window and full) has each
     self-checked, and ``attn.mask_pairs / .tile_pairs`` summed over them."""
     world = comm.get_world_size()
@@ -608,6 +615,14 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
             default_registry.counter("lm.ssm.inner", model.ssm.inner)
             default_registry.counter("lm.ssm.chunk",
                                      model.ssm.chunk or SCAN_CHUNK)
+        if by_kind.get("ssd"):
+            from dgraph_tpu.ops.ssd import SSD_CHUNK
+
+            for name in ("heads", "groups", "state"):
+                default_registry.counter(f"lm.ssd.{name}",
+                                         getattr(model.ssd, name))
+            default_registry.counter("lm.ssd.chunk",
+                                     model.ssd.chunk or SSD_CHUNK)
         if by_kind.get("window"):
             default_registry.counter("lm.attention.window", model.window)
         if masks:
@@ -640,6 +655,9 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         default_registry.counter("moe.experts_total", experts.n_total)
         default_registry.counter("moe.buffer_rows",
                                  startup["moe_buffer_rows"])
+        if experts.shared_width:
+            startup["moe_shared_width"] = experts.shared_width
+            default_registry.counter("moe.shared_width", experts.shared_width)
     kw = dict(seq_len=seq_len, beta=beta, loss_block=loss_block,
               param_specs=specs)
     return LMTrainer(

@@ -67,7 +67,12 @@ SECTIONS = [
     ("Selective scan kernels", "dgraph_tpu.ops.pallas_scan",
      ["applies", "channel_block", "vmem_bytes", "fused_forward",
       "fused_backward"]),
+    ("Chunked matrix-state recurrence (Mamba-2)", "dgraph_tpu.ops.ssd",
+     ["ssd", "ssd_sequence"]),
     ("Models", "dgraph_tpu.models", None),
+    ("Sequence-LM layers", "dgraph_tpu.models.looplm",
+     ["HeldExperts", "HeldExpertsFFN", "StateSpace", "Mamba2Mixer",
+      "SSDMixer", "split_kind", "causal_taps", "previous_rows"]),
     ("GraphCast", "dgraph_tpu.models.graphcast", None),
     ("Tensor parallelism", "dgraph_tpu.parallel.tensor", None),
     ("Pipeline parallelism", "dgraph_tpu.parallel.pipeline", None),
