@@ -29,12 +29,29 @@ work inside a chunk is three matrix products, with the log-decays
 Log-decays, their sums, every ``exp`` and the carried states are float32, and
 ``exp`` is only ever taken of a difference that is <= 0 (the mask goes in
 BEFORE it). The operands of the products are in the streams' type (``x``'s:
-the compute dtype), their results float32. The backward pass is the
-automatic differentiation of this form: the caller's layer is rematerialised,
-so what it keeps (the ``[T / L, H, L, L]`` decay matrix, 268 MB at T 8192, H
-64, L 128, among it) lives for one layer's backward. A ``T`` that is no
-multiple of ``chunk`` is padded with steps of ``dt = 0`` (decay 1, input 0:
-the state passes through).
+the compute dtype), their results float32. A ``T`` that is no multiple of
+``chunk`` is padded with steps of ``dt = 0`` (decay 1, input 0: the state
+passes through).
+
+Two routes run this one algorithm, chosen a call from the backend and the
+shapes and counted (``ssd.core_calls``, ``ssd.core_fused``):
+
+- the ``lax`` form below, everywhere but where the kernels apply: the CPU,
+  a ``T`` the chunk does not divide, chunks, states or a group's channels
+  that are no whole lane tiles. Its backward pass is the automatic
+  differentiation of this form, and what it keeps (the ``[T / L, H, L, L]``
+  float32 decay matrix, 268 MB at T 8192, H 64, L 128, among it) is this
+  route's alone; the caller's layer is rematerialised, so it lives for one
+  layer's backward. It is also the oracle of the other route's tests.
+- on a TPU where :func:`pallas_ssd.applies`, the kernels of
+  :mod:`dgraph_tpu.ops.pallas_ssd`, forward and backward (``_fused``, its own
+  ``custom_vjp``), all under the scope ``chunk``: a chunk's ``[L, L]`` tiles
+  and the ``[P, N]`` states between chunks stay in on-chip memory, the hops
+  are the grid's order, and what is kept for the backward is the inputs and
+  each chunk's start state (``[T / L, N, H P]`` float32: 134 MB at those
+  sizes). XLA's part there: the log-decays' running sums and their
+  transposed cotangent (``dA`` a sum of it), ``dt`` with time on the lanes,
+  ``D`` over a head's lanes, the states' transposes.
 
 :func:`ssd_sequence` is the operator over a sequence sharded on a mesh axis,
 by :func:`~dgraph_tpu.ops.selective_scan.scan_sequence`'s contract: each rank
@@ -50,6 +67,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from dgraph_tpu.obs.metrics import default_registry
+from dgraph_tpu.ops import pallas_ssd
 
 SSD_CHUNK = 128  # steps a chunk: the published chunk_size of the one model
 
@@ -75,10 +95,75 @@ def _read_out(Cm, cum, start, like):
     return y * jnp.exp(cum).reshape(cum.shape[:-1] + (G, K, 1))
 
 
+def _rows(t, G: int):
+    """``[T, H] -> [G, K, T]``: time on the lanes, a group's heads together."""
+    return t.T.reshape(G, t.shape[1] // G, t.shape[0])
+
+
+def _lanes(s):
+    """A state ``[H, P, N] -> [N, H P]``: states on the sublanes."""
+    return s.transpose(2, 0, 1).reshape(s.shape[2], -1)
+
+
+def _kernel_inputs(x, dt, A, B, Cm, D, L: int):
+    """What both kernels read, in their layouts: ``x [T, H P]``, ``dt`` and
+    the running sum of the log-decays ``dt A`` inside each chunk ``[G, K,
+    T]``, ``B``, ``Cm [T, G N]``, ``D`` over a head's lanes ``[1, H P]``."""
+    T, H, P = x.shape
+    G, N = B.shape[1:]
+    cum = jnp.cumsum((dt * A).reshape(T // L, L, H), axis=1).reshape(T, H)
+    return (x.reshape(T, H * P), _rows(dt, G), _rows(cum, G),
+            B.reshape(T, G * N), Cm.reshape(T, G * N), jnp.repeat(D, P)[None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _fused(x, dt, A, B, Cm, D, s0, L):
+    return _fused_fwd(x, dt, A, B, Cm, D, s0, L)[0]
+
+
+def _fused_fwd(x, dt, A, B, Cm, D, s0, L):
+    """The kernels' route: ``dt``, ``A``, ``D``, ``s0`` float32, ``B`` and
+    ``Cm`` in ``x``'s type, ``T`` whole chunks of ``L``."""
+    H, P, N = s0.shape
+    y, starts, last = pallas_ssd.fused_forward(
+        *_kernel_inputs(x, dt, A, B, Cm, D, L), _lanes(s0), L, P)
+    return (y.reshape(x.shape), last.reshape(N, H, P).transpose(1, 2, 0)), \
+        (x, dt, A, B, Cm, D, starts)
+
+
+def _fused_bwd(L, res, cts):
+    x, dt, A, B, Cm, D, starts = res
+    dy, d_last = cts
+    T, H, P = x.shape
+    f32 = jnp.float32
+    dx, ddt, dcum, dB, dC, dD, ds0 = pallas_ssd.fused_backward(
+        *_kernel_inputs(x, dt, A, B, Cm, D, L), starts,
+        dy.astype(f32).reshape(T, H * P), _lanes(d_last.astype(f32)), L, P)
+    steps = lambda t: t.reshape(H, T).T  # [G, K, T] -> [T, H]
+    # c_i = sum_{t <= i} dt_t A inside a chunk: each step's log-decay gathers
+    # the cotangents of the running sums at and after it
+    dl = jnp.flip(jnp.cumsum(jnp.flip(
+        steps(dcum).reshape(T // L, L, H), 1), axis=1), 1).reshape(T, H)
+    return (dx.reshape(x.shape), steps(ddt) + dl * A, (dl * dt).sum(0),
+            dB.reshape(B.shape), dC.reshape(Cm.shape),
+            dD.sum(0).reshape(H, P).sum(1),
+            ds0.reshape(-1, H, P).transpose(1, 2, 0))
+
+
+_fused.defvjp(_fused_fwd, _fused_bwd)
+
+
 def ssd(x, dt, A, B, Cm, D, s0=None, *, chunk: int = SSD_CHUNK):
     """``(y [T, H, P] float32, the last state [H, P, N] float32)`` of the
     recurrence in the module docstring, differentiable in every argument
-    (``s0`` too)."""
+    (``s0`` too). On a TPU, where the shapes allow
+    (:func:`pallas_ssd.applies`: whole chunks, lane tiles, blocks within the
+    VMEM budget), forward and backward are the kernels of
+    :mod:`dgraph_tpu.ops.pallas_ssd`, which keep the inputs and each chunk's
+    start state; everywhere else (the CPU, every other shape) the ``lax``
+    form, differentiated automatically, whose ``[T / L, H, L, L]`` float32
+    decay matrix (268 MB at T 8192, H 64, L 128) is that route's alone.
+    Counted a traced call: ``ssd.core_calls``, ``ssd.core_fused``."""
     T, H, P = x.shape
     G, N = B.shape[1:]
     if H % G:
@@ -90,6 +175,13 @@ def ssd(x, dt, A, B, Cm, D, s0=None, *, chunk: int = SSD_CHUNK):
     dt = dt.astype(f32)
     if s0 is None:
         s0 = jnp.zeros((H, P, N), f32)
+    fused = jax.default_backend() == "tpu" and pallas_ssd.applies(x, B, L)
+    default_registry.counter("ssd.core_calls")
+    if fused:
+        default_registry.counter("ssd.core_fused")
+        with jax.named_scope("chunk"):
+            return _fused(x, dt, A.astype(f32), B.astype(x.dtype),
+                          Cm.astype(x.dtype), D.astype(f32), s0.astype(f32), L)
     x_c = _chunks(x, nc, L).reshape(nc, L, G, K, P)
     B_c, C_c = (_chunks(t.astype(x.dtype), nc, L) for t in (B, Cm))
     dt_c = _chunks(dt, nc, L)  # [nc, L, H]

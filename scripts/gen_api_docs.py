@@ -69,6 +69,8 @@ SECTIONS = [
       "fused_backward"]),
     ("Chunked matrix-state recurrence (Mamba-2)", "dgraph_tpu.ops.ssd",
      ["ssd", "ssd_sequence"]),
+    ("Chunked recurrence kernels", "dgraph_tpu.ops.pallas_ssd",
+     ["applies", "vmem_bytes", "fused_forward", "fused_backward"]),
     ("Models", "dgraph_tpu.models", None),
     ("Sequence-LM layers", "dgraph_tpu.models.looplm",
      ["HeldExperts", "HeldExpertsFFN", "StateSpace", "Mamba2Mixer",

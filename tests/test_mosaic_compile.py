@@ -136,3 +136,48 @@ def test_selective_scan_kernels_compile_at_phi4_shape(one_chip, direction, bt,
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert [(o.shape, o.dtype) for o in jax.eval_shape(fn, *args)] == want
+
+
+# nemotron3_nano_30b_a3b.seq8k (benchmark/configs/nemotron3_nano_30b_a3b.json):
+# T 8192, 64 heads of 64 in 8 groups of 128 states; the published chunk of
+# 128 steps, and 512, the longest power of two whose blocks fit the budget.
+SSD_T, SSD_H, SSD_P, SSD_G, SSD_N = 8192, 64, 64, 8, 128
+
+
+@pytest.mark.parametrize("L", [128, 512])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_chunked_recurrence_kernels_compile_at_nemotron_shape(one_chip,
+                                                              direction, L):
+    """The Mamba-2 recurrence's kernels (ISSUE 44) at the cell's shape: the
+    padded transpose that turns a head's row into a column, the products
+    with a transposed left operand, a 64-lane head inside a 128-lane tile,
+    the state scratch over the chunks, under the limit derived from
+    ``vmem_bytes`` (which Mosaic's own count must stay within)."""
+    from dgraph_tpu.ops import pallas_ssd as ps
+
+    def shape(*s, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    T, H, P, G, N = SSD_T, SSD_H, SSD_P, SSD_G, SSD_N
+    bf16 = jnp.bfloat16
+    x3, B3 = shape(T, H, P, dtype=bf16), shape(T, G, N, dtype=bf16)
+    assert ps.applies(x3, B3, L) and not ps.applies(x3, B3, 1024)
+    rows = shape(G, H // G, T)
+    args = [shape(T, H * P, dtype=bf16), rows, rows,
+            shape(T, G * N, dtype=bf16), shape(T, G * N, dtype=bf16),
+            shape(1, H * P)]
+    if direction == "forward":
+        fn = lambda *a: ps.fused_forward(*a, L, P)
+        args.append(shape(N, H * P))
+        want = [((T, H * P), jnp.float32), ((T // L, N, H * P), jnp.float32),
+                ((N, H * P), jnp.float32)]
+    else:
+        fn = lambda *a: ps.fused_backward(*a, L, P)
+        args += [shape(T // L, N, H * P), shape(T, H * P), shape(N, H * P)]
+        want = [((T, H * P), bf16), ((G, H // G, T), jnp.float32),
+                ((G, H // G, T), jnp.float32), ((T, G * N), bf16),
+                ((T, G * N), bf16), ((8, H * P), jnp.float32),
+                ((N, H * P), jnp.float32)]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert [(o.shape, o.dtype) for o in jax.eval_shape(fn, *args)] == want
