@@ -473,11 +473,9 @@ def bench_gcn(dtype_name: str, peaks: "dict | None"):
     from dgraph_tpu import config as _dcfg
     from dgraph_tpu.plan import resolve_halo_impl
 
-    _schedule = getattr(plan_np, "halo_schedule", None)
     halo_impl, halo_impl_source = resolve_halo_impl(
         plan_np.world_size, plan_np.halo_deltas,
         overlap_available=plan_np.overlap is not None,
-        sched_available=_schedule is not None,
         pair_rows=getattr(plan_np, "halo_pair_rows", ()),
     )
     # the RESOLVED wire format rides the JSON the same way: which codec
@@ -499,28 +497,8 @@ def bench_gcn(dtype_name: str, peaks: "dict | None"):
         "wire_format": wire_format,
         "wire_format_source": wire_format_source,
         "wire_format_env_pin": _dcfg.wire_format,
-        # compiled-schedule identity (dgraph_tpu.sched): the content hash
-        # names the exact round order this plan would replay under
-        # halo_impl='sched', whether or not sched was the resolved impl
-        "halo_schedule_id": _schedule.schedule_id if _schedule else None,
-        "halo_schedule_rounds": _schedule.num_rounds if _schedule else 0,
     }
-    if _schedule is not None:
-        # the compiled schedule joins the perf ledger as its own record
-        # kind (regress byte-exact-gates rounds/bytes across commits);
-        # _ledger_ingest swallows failures, same as the round JSON
-        _ledger_ingest({
-            "kind": "sched_compile",
-            "workload": {"world_size": plan_np.world_size,
-                         "nodes": Vp, "hidden": H},
-            "schedule_id": _schedule.schedule_id,
-            "rounds": _schedule.num_rounds,
-            "transfers": _schedule.num_transfers,
-            "operand_bytes_per_shard": sum(_schedule.round_rows()) * H * b,
-            "round_rows": list(_schedule.round_rows()),
-            "git_rev": _git_rev(),
-        })
-    # the resolved wire format joins the ledger too: operand_bytes rides
+    # the resolved wire format joins the ledger: operand_bytes rides
     # regress's byte-exact class, so a codec or pricing change that
     # alters what this workload ships on the wire goes RED across
     # commits (footprint prices the exchange at the resolved format)

@@ -238,25 +238,6 @@ def _permute_pair_sets(R: int, W: int, deltas) -> dict:
     return sets
 
 
-def _sched_pair_sets(R: int, W: int, schedule) -> dict:
-    """frozenset of (src, tgt) device pairs -> "r{round}:{fwd|rev}" for
-    every compiled round, in both directions (the reverse leg replays the
-    schedule with every pair flipped) — a lowered permute under
-    ``halo_impl='sched'`` must match one round exactly. Unlike the
-    delta-ring sets these are PARTIAL: a round names only its members,
-    and the non-members' zero-fill is absorbed by the executor's scratch
-    rows, so a full ring here would be drift, not correctness."""
-    sets = {}
-    for k, rnd in enumerate(schedule.rounds):
-        for flip, tag in ((False, "fwd"), (True, "rev")):
-            base = [(d, s) for (s, d) in rnd.pairs] if flip else rnd.pairs
-            pairs = frozenset(
-                (r * W + s, r * W + d) for r in range(R) for (s, d) in base
-            )
-            sets.setdefault(pairs, f"r{k}:{tag}")
-    return sets
-
-
 def _audit_one_lowering(
     label: str,
     impl: str,
@@ -276,12 +257,7 @@ def _audit_one_lowering(
     n_deltas = len(deltas)
     S = plan.halo.s_pad
     groups = _graph_groups(R, W)
-    schedule = getattr(plan, "halo_schedule", None)
-    pair_sets = (
-        _sched_pair_sets(R, W, schedule)
-        if impl == "sched" and schedule is not None
-        else _permute_pair_sets(R, W, deltas)
-    )
+    pair_sets = _permute_pair_sets(R, W, deltas)
 
     def fail(msg):
         failures.append(f"[hlo:{label}/{impl}] {msg}")
@@ -341,46 +317,22 @@ def _audit_one_lowering(
             )
     for rec in coll["collective_permute"]:
         F = rec["shape"][-1] if rec["shape"] else 0
-        exp = _expected_bytes(plan, rec["dtype"], F)
-        if impl == "sched":
-            # per-round membership (rounds differ in height); the full
-            # multiset — every priced round exactly legs times — is
-            # pinned cross-program in audit_workload_hlo
-            allowed = set(exp["sched_round_bytes"])
-            member = rec["bytes"] in allowed
-            operand_rows.append({
-                **{k: rec[k] for k in ("op", "shape", "dtype", "bytes")},
-                "footprint_bytes": rec["bytes"] if member else 0,
-            })
-            if not member:
-                fail(
-                    f"collective_permute operand {rec['shape']} "
-                    f"({rec['dtype']}) is {rec['bytes']} B lowered; "
-                    f"footprint prices rounds of {sorted(allowed)} B"
-                )
-        else:
-            want = exp["ppermute_round_bytes"]
-            operand_rows.append({
-                **{k: rec[k] for k in ("op", "shape", "dtype", "bytes")},
-                "footprint_bytes": want,
-            })
-            if rec["bytes"] != want:
-                fail(
-                    f"collective_permute operand {rec['shape']} "
-                    f"({rec['dtype']}) is {rec['bytes']} B lowered; "
-                    f"footprint prices {want} B per round"
-                )
+        want = _expected_bytes(plan, rec["dtype"], F)["ppermute_round_bytes"]
+        operand_rows.append({
+            **{k: rec[k] for k in ("op", "shape", "dtype", "bytes")},
+            "footprint_bytes": want,
+        })
+        if rec["bytes"] != want:
+            fail(
+                f"collective_permute operand {rec['shape']} "
+                f"({rec['dtype']}) is {rec['bytes']} B lowered; "
+                f"footprint prices {want} B per round"
+            )
         pairs = frozenset(map(tuple, rec["source_target_pairs"] or []))
         if pairs not in pair_sets:
             fail(
                 f"collective_permute pairs {sorted(pairs)} match no "
-                + (
-                    f"compiled schedule round (id="
-                    f"{schedule.schedule_id}, W={W})"
-                    if impl == "sched" and schedule is not None
-                    else f"live delta ring of the plan "
-                         f"(deltas={deltas}, W={W})"
-                )
+                f"live delta ring of the plan (deltas={deltas}, W={W})"
             )
     # fp32 accumulation at the artifact level: reductions never run
     # sub-32-bit (bf16 may ride the wire; all_reduce must not)
@@ -480,13 +432,8 @@ def audit_workload_hlo(
     legs: dict = {}
     donation = None
     saved = (_cfg.halo_impl, _cfg.tuned_halo_impl)
-    audited_impls = [
-        impl for impl in impls
-        if impl != "sched"
-        or getattr(w.plan_np, "halo_schedule", None) is not None
-    ]
     try:
-        for impl in audited_impls:
+        for impl in impls:
             _cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
             for label, build in (programs or PROGRAMS).items():
                 fn, args = build(w)
@@ -529,35 +476,7 @@ def audit_workload_hlo(
         if rec["impl"] == "all_to_all" or rec["program"] not in legs:
             continue
         want = legs[rec["program"]] * n_deltas
-        if rec["impl"] == "sched":
-            schedule = w.plan_np.halo_schedule
-            n_rounds = schedule.num_rounds
-            want = legs[rec["program"]] * n_rounds
-            if rec["num_collective_permute"] != want:
-                failures.append(
-                    f"[hlo:{rec['program']}/{rec['impl']}] "
-                    f"{rec['num_collective_permute']} collective_permutes "
-                    f"lowered; expected legs({legs[rec['program']]}) * "
-                    f"schedule rounds({n_rounds}) = {want}"
-                )
-                continue
-            groups: dict = {}
-            for o in rec["collective_operands"]:
-                F = o["shape"][-1] if o["shape"] else 0
-                groups.setdefault((o["dtype"], F), []).append(o["bytes"])
-            for (dt, F), lowered_b in sorted(groups.items()):
-                exp = _expected_bytes(
-                    w.plan_np, dt, F
-                )["sched_round_bytes"]
-                k, r = divmod(len(lowered_b), max(len(exp), 1))
-                if not exp or r or sorted(lowered_b) != sorted(exp * k):
-                    failures.append(
-                        f"[hlo:{rec['program']}/{rec['impl']}] lowered "
-                        f"round bytes at ({dt}, F={F}) "
-                        f"{sorted(lowered_b)[:8]} != footprint rounds "
-                        f"{sorted(exp)[:8]} x {k} leg(s)"
-                    )
-        elif rec["num_collective_permute"] != want:
+        if rec["num_collective_permute"] != want:
             failures.append(
                 f"[hlo:{rec['program']}/{rec['impl']}] "
                 f"{rec['num_collective_permute']} collective_permutes "
@@ -570,7 +489,7 @@ def audit_workload_hlo(
         "world_size": w.world_size,
         "num_nodes": w.num_nodes,
         "num_halo_deltas": n_deltas,
-        "impls": list(audited_impls),
+        "impls": list(impls),
         "exchange_legs": legs,
         "programs": program_records,
         "donation": donation,

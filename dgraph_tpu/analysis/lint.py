@@ -203,26 +203,18 @@ JAX_FREE_TARGETS = (
     "dgraph_tpu/obs/ledger.py",
     "dgraph_tpu/obs/regress.py",
     "dgraph_tpu/obs/report.py",
-    # the halo schedule compiler core (IR + passes + selftest): the
-    # schedule is DATA — compiled, verified, serialized, and diffed on
-    # hosts with no backend (plan tooling, regress, operators reading a
-    # manifest), so everything except the executor stays stdlib-only.
-    # comm/collectives.py replays the schedule and is the ONE jax
-    # consumer, deliberately outside this list.
-    "dgraph_tpu/sched/",
     # the grow-to-fit transition: the world-growth decision path (join
     # discovery, unfold, gather, adopt) must keep working while jax is
     # wedged — everything that pulls jax (plan builder, reshard kernel)
     # is reached through train/shrink.py's function-scope imports, and
     # the join announcement path rides membership.py (already a target)
     "dgraph_tpu/train/grow.py",
-    # the wire-format registry, dedup planner, and their selftest: wire
-    # formats are DATA (resolved, priced, serialized into plans and
-    # tuning records) on the same backend-less hosts as the schedule
-    # compiler — wire/codec.py holds the jax encode/decode pairs and is
-    # deliberately outside this list (wire/__init__ lazy-exports it)
+    # the wire-format registry and its selftest: wire formats are DATA
+    # (resolved, priced, serialized into plans and tuning records) on
+    # hosts with no backend — wire/codec.py holds the jax encode/decode
+    # pairs and is deliberately outside this list (wire/__init__
+    # lazy-exports it)
     "dgraph_tpu/wire/spec.py",
-    "dgraph_tpu/wire/dedup.py",
     "dgraph_tpu/wire/__main__.py",
 )
 

@@ -115,10 +115,9 @@ _STATIC_FIELDS = (
     "world_size", "n_src_pad", "n_dst_pad", "e_pad", "halo_side",
     "homogeneous", "owner_sorted", "halo_deltas", "scatter_mc",
     "scatter_block_e", "scatter_block_n", "halo_sort_mc", "gather_mv",
-    # the FULL-WORLD traffic matrix and the schedule compiled from it
-    # (dgraph_tpu.sched): a rank whose matrix row drifted compiles a
-    # different round order — the deadlock class the sched lowering adds
-    "halo_pair_rows", "halo_schedule",
+    # the FULL-WORLD traffic matrix: a rank whose matrix drifted weighs
+    # the heuristic differently and may resolve another lowering
+    "halo_pair_rows",
     # the wire format attached at build time (dgraph_tpu.wire): a rank
     # whose format drifted encodes collective operands at a different
     # dtype/width — every exchange rendezvous disagrees on byte counts
@@ -506,7 +505,6 @@ def resolution_agreement(
     halo_deltas: tuple,
     *,
     overlap_available: bool,
-    sched_available: bool = False,
     pair_rows: tuple = (),
     rank_tuned: Optional[Dict[int, Optional[str]]] = None,
     plan_wire_format: str = "fp32",
@@ -541,7 +539,6 @@ def resolution_agreement(
                 impl, source = resolve_halo_impl(
                     world_size, tuple(halo_deltas),
                     overlap_available=overlap_available,
-                    sched_available=sched_available,
                     pair_rows=pair_rows,
                 )
                 wf, wf_source = resolve_wire_format(
@@ -642,7 +639,6 @@ def audit_plan_dir_spmd(
     # tuned-record resolution agreement (each rank under its own record)
     resolution = resolution_agreement(
         W, halo_deltas, overlap_available=base.get("overlap", False),
-        sched_available=base.get("halo_schedule") is not None,
         pair_rows=base.get("halo_pair_rows", ()),
         rank_tuned=rank_tuned,
         plan_wire_format=base.get("wire_format", "fp32"),
@@ -660,12 +656,8 @@ def audit_plan_dir_spmd(
     program_records: list = []
     saved = (_cfg.halo_impl, _cfg.tuned_halo_impl)
     schedule_ok = True
-    audited_impls = [
-        i for i in impls
-        if i != "sched" or base.get("halo_schedule") is not None
-    ]
     try:
-        for impl in audited_impls:
+        for impl in impls:
             _cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
             for plabel, build in (programs or PROGRAMS).items():
                 tag = f"{prefix}{plabel}/{impl}"
@@ -757,7 +749,7 @@ def audit_plan_dir_spmd(
         "world_size": W,
         "num_halo_deltas": len(halo_deltas),
         "halo_deltas": list(halo_deltas),
-        "impls": list(audited_impls),
+        "impls": list(impls),
         "programs": program_records,
         "statics_agree": not any("statics" in f for f in failures),
         "per_rank_live_deltas": {

@@ -358,13 +358,9 @@ def _expected_bytes(plan, dtype: str, feat_dim: int) -> dict:
         fp["halo"]["wire_bytes_per_shard"]["ppermute"] // n_deltas
         if n_deltas else 0
     )
-    sched_fp = ex.get("sched") or {}
     return {
         "a2a_operand_bytes": ex["a2a_operand_bytes_per_shard"],
         "ppermute_round_bytes": per_round,
-        # the compiled schedule's per-round operand bytes (rounds differ
-        # in height, so this is a LIST — the audit compares multisets)
-        "sched_round_bytes": list(sched_fp.get("round_bytes_per_shard", [])),
         "num_halo_deltas": n_deltas,
     }
 
@@ -409,26 +405,6 @@ def _audit_one_program(
     for rec in coll[want_family]:
         feat = rec["shape"][-1] if rec["shape"] else 0
         exp = _expected_bytes(plan, rec["dtype"], feat)
-        if impl == "sched":
-            # compiled-schedule rounds differ in height, so each traced
-            # operand must be SOME priced round (membership here); the
-            # full multiset equality — every round present exactly legs
-            # times — is pinned cross-program in audit_workload
-            allowed = set(exp["sched_round_bytes"])
-            member = rec["bytes"] in allowed
-            byte_rows.append({
-                "primitive": rec["primitive"], "shape": rec["shape"],
-                "dtype": rec["dtype"], "traced_bytes": rec["bytes"],
-                "footprint_bytes": rec["bytes"] if member else 0,
-            })
-            if not member:
-                fail(
-                    f"{rec['primitive']} operand {rec['shape']} "
-                    f"({rec['dtype']}) carries {rec['bytes']} B; footprint "
-                    f"prices rounds of {sorted(allowed)} B — the traced "
-                    f"round is not one the compiled schedule contains"
-                )
-            continue
         want = {
             "all_to_all": exp["a2a_operand_bytes"],
             "ppermute": exp["ppermute_round_bytes"],
@@ -536,13 +512,8 @@ def audit_workload(
     program_records = []
     legs: dict = {}
     saved = (_cfg.halo_impl, _cfg.tuned_halo_impl)
-    audited_impls = [
-        impl for impl in impls
-        if impl != "sched"
-        or getattr(w.plan_np, "halo_schedule", None) is not None
-    ]
     try:
-        for impl in audited_impls:
+        for impl in impls:
             _cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
             for label, build in (programs or PROGRAMS).items():
                 fn, args = build(w)
@@ -563,41 +534,6 @@ def audit_workload(
     for rec in program_records:
         if rec["impl"] == "all_to_all" or rec["program"] not in legs:
             continue
-        if rec["impl"] == "sched":
-            # the compiled schedule replays num_rounds ppermutes per
-            # exchange leg, and the traced per-(dtype, width) byte
-            # multiset must equal the footprint-priced rounds repeated
-            # once per leg — byte-exact, order-free
-            schedule = w.plan_np.halo_schedule
-            n_rounds = schedule.num_rounds
-            want = legs[rec["program"]] * n_rounds
-            if rec["num_ppermute"] != want:
-                failures.append(
-                    f"[{rec['program']}/{rec['impl']}] "
-                    f"{rec['num_ppermute']} ppermute rounds; expected "
-                    f"legs({legs[rec['program']]}) * "
-                    f"schedule rounds({n_rounds}) = {want}"
-                )
-                continue
-            groups: dict = {}
-            for o in rec["collective_operands"]:
-                feat = o["shape"][-1] if o["shape"] else 0
-                groups.setdefault((o["dtype"], feat), []).append(
-                    o["traced_bytes"]
-                )
-            for (dt, feat), traced in sorted(groups.items()):
-                exp = _expected_bytes(
-                    w.plan_np, dt, feat
-                )["sched_round_bytes"]
-                k, r = divmod(len(traced), max(len(exp), 1))
-                if not exp or r or sorted(traced) != sorted(exp * k):
-                    failures.append(
-                        f"[{rec['program']}/{rec['impl']}] traced round "
-                        f"bytes at ({dt}, F={feat}) "
-                        f"{sorted(traced)[:8]} != footprint rounds "
-                        f"{sorted(exp)[:8]} x {k} leg(s)"
-                    )
-            continue
         want = legs[rec["program"]] * n_deltas
         if rec["num_ppermute"] != want:
             failures.append(
@@ -612,7 +548,7 @@ def audit_workload(
         "world_size": w.world_size,
         "num_nodes": w.num_nodes,
         "num_halo_deltas": n_deltas,
-        "impls": list(audited_impls),
+        "impls": list(impls),
         "exchange_legs": legs,
         "programs": program_records,
         "donation": donation,

@@ -83,7 +83,6 @@ def resolve_plan_impl(plan: EdgePlan, axis_name) -> str:
     impl, _ = resolve_halo_impl(
         plan.world_size, plan.halo_deltas,
         overlap_available=getattr(plan, "overlap", None) is not None,
-        sched_available=getattr(plan, "halo_schedule", None) is not None,
         pair_rows=getattr(plan, "halo_pair_rows", ()),
     )
     return impl
@@ -335,196 +334,6 @@ def halo_scatter_sum_overlap(
     return unex(h, halo.send_idx, halo.send_mask)
 
 
-def _sched_rounds_fwd(x, send_idx, send_mask, axis_name, schedule, W, S,
-                      wire_format="fp32"):
-    """Replay a compiled :class:`~dgraph_tpu.sched.ir.HaloSchedule`:
-    per round, every rank gathers + masks the send block for its (static)
-    round peer and slices its transfer's row window; all ppermutes are
-    issued before any received block is placed (the overlap executor's
-    double-buffered shape, so XLA's scheduler can hide the wire behind
-    interleaved compute). Placement offsets come from the schedule's
-    per-rank static tables indexed by the traced ``lax.axis_index`` —
-    every rank traces the IDENTICAL program (the SPMD auditor's
-    invariant). Ranks a round's ppermute names as no-one's receiver get
-    zeros (lax.ppermute semantics), which land in a scratch tail row
-    band ``[W*S, W*S+Cmax)`` and are dropped, so the clamping semantics
-    of dynamic_update_slice never corrupt live slots. Result layout and
-    values are bit-identical to the padded all_to_all lowering: each
-    round writes rows of the masked (src -> dst) send block at the same
-    ``src*S + row`` halo-slot positions the all_to_all produces, padded
-    round rows carry the same masked values both lowerings carry, and
-    the verifier guarantees the transfers tile each live block exactly
-    once."""
-    F = x.shape[-1]
-    me = lax.axis_index(axis_name)
-    enc, dec = _wire_fns(wire_format, x.dtype)
-    rows = schedule.round_rows()
-    c_max = max(rows)
-    sends = []
-    for k in range(schedule.num_rounds):
-        ra = schedule.rank_arrays(k)
-        dst = jnp.asarray(ra["send_dst"], jnp.int32)[me]
-        start = jnp.asarray(ra["send_start"], jnp.int32)[me]
-        idx = jnp.take(send_idx, dst, axis=0)
-        msk = jnp.take(send_mask, dst, axis=0)
-        blk = _masked_send(x, idx, msk)  # [S, F]
-        blk = lax.dynamic_slice(blk, (start, 0), (rows[k], F))
-        # encode AFTER the row slice: per-row codecs commute with row
-        # slicing, so the wire bytes match the a2a operand's rows exactly
-        sends.append(enc(blk) if enc is not None else blk)
-    with _scoped("wire"):
-        recvs = [
-            lax.ppermute(s, axis_name, schedule.rounds[k].pairs)
-            for k, s in enumerate(sends)
-        ]
-    out = jnp.zeros((W * S + c_max, F), x.dtype)
-    for k, recv in enumerate(recvs):
-        ra = schedule.rank_arrays(k)
-        off = jnp.asarray(ra["place_off"], jnp.int32)[me]
-        if dec is not None:
-            # non-receivers get all-zero wire rows from ppermute, which
-            # every codec decodes to exactly 0.0 — the scratch band stays
-            # as clean as in the fp32 path
-            recv = dec(recv)
-        out = lax.dynamic_update_slice(out, recv, (off, 0))
-    return out[: W * S]
-
-
-def _sched_rounds_rev(h, send_idx, send_mask, n_pad, axis_name, schedule,
-                      W, S, wire_format="fp32"):
-    """Reverse replay: per round, each fwd RECEIVER slices the cotangent
-    window its transfer landed in and ppermutes it along the reversed
-    pairs back to the fwd sender, which parks it in its ``[W+1, S, F]``
-    reduce buffer (plane = the peer it had sent to; idle ranks park the
-    zeros ppermute hands them in the scratch plane W). The buffer then
-    reduces with the SAME masked flat segment-sum the all_to_all reverse
-    runs — the mask zeroes padded round rows, so values are bit-identical
-    to it. All reverse ppermutes are issued before any placement, keeping
-    each round individually overlappable (the exact mirror of
-    :func:`_overlap_rounds_rev`)."""
-    F = h.shape[-1]
-    me = lax.axis_index(axis_name)
-    enc, dec = _wire_fns(wire_format, h.dtype)
-    h = h.reshape(W * S, F)
-    rows = schedule.round_rows()
-    blocks = []
-    for k in range(schedule.num_rounds):
-        ra = schedule.rank_arrays(k)
-        off = jnp.asarray(ra["slice_off"], jnp.int32)[me]
-        blk = lax.dynamic_slice(h, (off, 0), (rows[k], F))
-        blocks.append(enc(blk) if enc is not None else blk)
-    with _scoped("wire"):
-        recvs = [
-            lax.ppermute(
-                b, axis_name,
-                [(d, s) for (s, d) in schedule.rounds[k].pairs],
-            )
-            for k, b in enumerate(blocks)
-        ]
-    back = jnp.zeros((W + 1, S, F), h.dtype)
-    for k, recv in enumerate(recvs):
-        ra = schedule.rank_arrays(k)
-        plane = jnp.asarray(ra["back_plane"], jnp.int32)[me]
-        start = jnp.asarray(ra["send_start"], jnp.int32)[me]
-        if dec is not None:
-            recv = dec(recv)
-        back = lax.dynamic_update_slice(back, recv[None], (plane, start, 0))
-    return _masked_scatter_add(back[:W], send_idx, send_mask, n_pad)
-
-
-@functools.lru_cache(maxsize=None)
-def _make_sched_pair(axis_name, schedule, W, S, n_pad, wire_format="fp32",
-                     dtype_name="float32"):
-    """The compiled-schedule exchange/unexchange custom-VJP pair — the
-    exact mirror of :func:`_make_overlap_pair` with the per-delta rings
-    swapped for the compiled rounds: the exchange's backward IS the
-    reverse replay and the reverse's backward IS the forward replay,
-    pinned explicitly so the transpose keeps the round schedule (and its
-    op count, which the trace/HLO auditors pin per-round) instead of
-    whatever JAX's default transpose would serialize. Cache key includes
-    the (frozen, hashable) schedule itself plus the static wire format +
-    activation dtype, so two configurations never share an executor."""
-
-    @jax.custom_vjp
-    def exchange(x, send_idx, send_mask):
-        return _sched_rounds_fwd(
-            x, send_idx, send_mask, axis_name, schedule, W, S, wire_format)
-
-    def ex_fwd(x, send_idx, send_mask):
-        return exchange(x, send_idx, send_mask), (send_idx, send_mask)
-
-    def ex_bwd(res, g):
-        send_idx, send_mask = res
-        dx = _sched_rounds_rev(
-            g, send_idx, send_mask, n_pad, axis_name, schedule, W, S,
-            wire_format)
-        return dx, None, None
-
-    exchange.defvjp(ex_fwd, ex_bwd)
-
-    @jax.custom_vjp
-    def unexchange(h, send_idx, send_mask):
-        return _sched_rounds_rev(
-            h, send_idx, send_mask, n_pad, axis_name, schedule, W, S,
-            wire_format)
-
-    def un_fwd(h, send_idx, send_mask):
-        return unexchange(h, send_idx, send_mask), (send_idx, send_mask)
-
-    def un_bwd(res, g):
-        send_idx, send_mask = res
-        dh = _sched_rounds_fwd(
-            g, send_idx, send_mask, axis_name, schedule, W, S, wire_format)
-        return dh, None, None
-
-    unexchange.defvjp(un_fwd, un_bwd)
-    return exchange, unexchange
-
-
-@_scoped("dgraph.halo_exchange_sched")
-def halo_exchange_sched(
-    x: jax.Array,
-    halo: HaloSpec,
-    axis_name: Optional[str],
-    schedule,
-    wire_format: str = "fp32",
-) -> jax.Array:
-    """:func:`halo_exchange` lowered as a compiled multi-round schedule
-    (:mod:`dgraph_tpu.sched`): small pairs merged into shared ppermute
-    rounds, hub pairs recursive-doubling split across rounds, rounds
-    ordered heavy-first — all decided at plan build and replayed here as
-    data. Values are bit-identical to the all_to_all lowering; the
-    custom VJP is the mirrored reverse replay."""
-    W, S = halo.send_idx.shape[0], halo.s_pad
-    if axis_name is None or schedule is None or not schedule.rounds:
-        return halo_exchange(x, halo, axis_name, deltas=(), impl="none")
-    ex, _ = _make_sched_pair(axis_name, schedule, W, S, x.shape[0],
-                             wire_format, str(jnp.dtype(x.dtype)))
-    return ex(x, halo.send_idx, halo.send_mask)
-
-
-@_scoped("dgraph.halo_scatter_sum_sched")
-def halo_scatter_sum_sched(
-    h: jax.Array,
-    halo: HaloSpec,
-    n_pad: int,
-    axis_name: Optional[str],
-    schedule,
-    wire_format: str = "fp32",
-) -> jax.Array:
-    """:func:`halo_scatter_sum` lowered as the compiled schedule's
-    reverse replay (the sched pair's transpose) — same masked flat
-    segment-sum over the same buffer as the all_to_all reverse,
-    bit-identical values."""
-    W, S = halo.send_idx.shape[0], halo.s_pad
-    if axis_name is None or schedule is None or not schedule.rounds:
-        return halo_scatter_sum(h, halo, n_pad, axis_name, deltas=(),
-                                impl="none")
-    _, unex = _make_sched_pair(axis_name, schedule, W, S, n_pad,
-                               wire_format, str(jnp.dtype(h.dtype)))
-    return unex(h, halo.send_idx, halo.send_mask)
-
-
 @_scoped("dgraph.halo_exchange")
 def halo_exchange(
     x: jax.Array,
@@ -532,7 +341,6 @@ def halo_exchange(
     axis_name: Optional[str],
     deltas: Optional[tuple] = None,
     impl: Optional[str] = None,
-    schedule=None,
     wire_format: Optional[str] = None,
 ) -> jax.Array:
     """Exchange boundary vertex features; returns the halo buffer.
@@ -547,9 +355,6 @@ def halo_exchange(
       to actual neighbors"; the NVSHMEM one-sided put analogue).
     - overlap: the double-buffered round schedule
       (:func:`halo_exchange_overlap`).
-    - sched: a compiled multi-round schedule replayed as data
-      (:func:`halo_exchange_sched`; requires ``schedule`` — the plan's
-      attached :class:`~dgraph_tpu.sched.ir.HaloSchedule`).
 
     Args:
       x: [n_pad, F] local (padded) vertex features of this shard.
@@ -560,10 +365,6 @@ def halo_exchange(
       impl: the lowering, already resolved by the CALLER (one resolution
         per call site — see :func:`resolve_plan_impl`); None resolves
         here for direct/legacy callers.
-      schedule: the plan's compiled HaloSchedule (``plan.halo_schedule``)
-        — consulted only under ``impl='sched'``, where its absence is a
-        loud error: the resolver only returns 'sched' when the plan
-        carries a schedule, so a miss here means a caller bypassed it.
       wire_format: the payload codec (dgraph_tpu.wire), already resolved
         by the CALLER like ``impl`` (one resolution per call site — see
         :func:`resolve_plan_wire_format`). None = 'fp32' identity, which
@@ -589,14 +390,6 @@ def halo_exchange(
     impl = _resolve_halo_arg(impl, deltas, W)
     if impl == "overlap":
         return halo_exchange_overlap(x, halo, axis_name, tuple(deltas), wf)
-    if impl == "sched":
-        if schedule is None:
-            raise ValueError(
-                "halo_exchange(impl='sched') needs the plan's compiled "
-                "halo schedule; resolve through resolve_plan_impl and "
-                "pass schedule=plan.halo_schedule"
-            )
-        return halo_exchange_sched(x, halo, axis_name, schedule, wf)
     if impl == "ppermute":
         from dgraph_tpu.wire.codec import make_ppermute_codec
 
@@ -642,7 +435,6 @@ def halo_scatter_sum(
     axis_name: Optional[str],
     deltas: Optional[tuple] = None,
     impl: Optional[str] = None,
-    schedule=None,
     wire_format: Optional[str] = None,
 ) -> jax.Array:
     """Linear transpose of :func:`halo_exchange`: deliver halo-slot values
@@ -671,15 +463,6 @@ def halo_scatter_sum(
         if impl == "overlap":
             return halo_scatter_sum_overlap(h, halo, n_pad, axis_name,
                                             tuple(deltas), wf)
-        if impl == "sched":
-            if schedule is None:
-                raise ValueError(
-                    "halo_scatter_sum(impl='sched') needs the plan's "
-                    "compiled halo schedule; resolve through "
-                    "resolve_plan_impl and pass schedule=plan.halo_schedule"
-                )
-            return halo_scatter_sum_sched(h, halo, n_pad, axis_name,
-                                          schedule, wf)
         if impl == "ppermute":
             from dgraph_tpu.wire.codec import make_ppermute_codec
 
@@ -820,7 +603,6 @@ def halo_extend(
         impl = resolve_plan_impl(plan, axis_name)
     haloed = halo_exchange(x, plan.halo, axis_name, deltas=plan.halo_deltas,
                            impl=impl,
-                           schedule=getattr(plan, "halo_schedule", None),
                            wire_format=resolve_plan_wire_format(
                                plan, axis_name))
     with _scoped("concat"):
@@ -966,7 +748,7 @@ def scatter_sum(
     remote_part = full[n_pad:]
     return local_part + halo_scatter_sum(
         remote_part, plan.halo, n_pad, axis_name, deltas=plan.halo_deltas,
-        impl=impl, schedule=getattr(plan, "halo_schedule", None),
+        impl=impl,
         wire_format=resolve_plan_wire_format(plan, axis_name),
     )
 

@@ -127,12 +127,11 @@ gather_col_block: int = int(os.environ.get("DGRAPH_TPU_GATHER_COL_BLOCK", "128")
 # active peer-delta set is sparse, else one padded all_to_all; 'overlap'
 # — interior/boundary split with the boundary rounds hidden behind
 # interior aggregation — whenever the plan carries its OverlapSpec), or
-# one of plan.HALO_IMPLS: 'all_to_all', 'ppermute', 'overlap', or 'sched'
-# (a compiled multi-round schedule — dgraph_tpu.sched — replayed as data;
-# needs the plan's attached halo_schedule). Any other value is refused.
+# one of plan.HALO_IMPLS: 'all_to_all', 'ppermute' or 'overlap'. Any
+# other value is refused.
 # Resolution precedence lives in plan.resolve_halo_impl: this env pin >
 # the adopted tuning record (tuned_halo_impl below) > the cost-model
-# heuristic (which never picks sched on its own).
+# heuristic.
 halo_impl: str = os.environ.get("DGRAPH_TPU_HALO_IMPL", "auto")
 
 # Edge-axis chunk count for the overlap lowering's interior aggregation

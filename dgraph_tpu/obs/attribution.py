@@ -274,11 +274,10 @@ def _exchange_scan(w, impl: str, num_layers: int = 2):
         for _ in range(num_layers):
             buf = collectives.halo_exchange(
                 h, p.halo, GRAPH_AXIS, deltas=p.halo_deltas, impl=impl,
-                schedule=p.halo_schedule,
             )
             back = collectives.halo_scatter_sum(
                 buf, p.halo, p.n_src_pad, GRAPH_AXIS,
-                deltas=p.halo_deltas, impl=impl, schedule=p.halo_schedule,
+                deltas=p.halo_deltas, impl=impl,
             )
             h = h + back * 1e-6
         return h[None]

@@ -123,17 +123,11 @@ def plan_footprint(
     # the report must account the lowering the run actually executes,
     # whoever chose it (incl. 'overlap' when the plan carries its split)
     overlap_available = getattr(plan, "overlap", None) is not None
-    schedule = getattr(plan, "halo_schedule", None)
     impl, impl_source = resolve_halo_impl(
         W, plan.halo_deltas, overlap_available=overlap_available,
-        sched_available=schedule is not None,
         pair_rows=getattr(plan, "halo_pair_rows", ()),
     )
     edge_split = interior_boundary_edge_counts(plan)
-    # compiled schedule (dgraph_tpu.sched): per-round padded operand rows
-    # C_k; every round is a ppermute, fully remote. () when unattached.
-    sched_rows = schedule.round_rows() if schedule is not None else ()
-    sched_wire = sum(sched_rows) * wire_row_bytes
 
     # one halo_exchange (the gather's comm leg); halo_scatter_sum (the
     # scatter's reverse leg / the exchange's transpose) moves the same.
@@ -144,7 +138,6 @@ def plan_footprint(
     # ppermute — its win is SCHEDULING (exposed time), not wire bytes.
     wire_per_shard = {
         "all_to_all": a2a_ici, "ppermute": pp_operand, "overlap": pp_operand,
-        "sched": sched_wire,
     }
     chosen_wire = wire_per_shard.get(impl, 0)
     real_bytes = real_rows * wire_row_bytes
@@ -155,7 +148,6 @@ def plan_footprint(
     # only; 'none' never gathers a send buffer at all).
     sent_blocks = {
         "all_to_all": W, "ppermute": n_deltas, "overlap": n_deltas,
-        "sched": len(sched_rows),
     }.get(impl, 0)
     hbm_per_shard = (2 * sent_blocks + W) * S * row_bytes
 
@@ -170,7 +162,7 @@ def plan_footprint(
 
     operand_by_impl = {
         "all_to_all": a2a_operand, "ppermute": pp_operand,
-        "overlap": pp_operand, "sched": sched_wire,
+        "overlap": pp_operand,
     }
     exchange = {
         "impl": impl,
@@ -220,41 +212,6 @@ def plan_footprint(
             "serial_us": round(serial, 3),
             "hidden_us": round(serial - exposed, 3),
         }
-    if sched_rows:
-        # compiled-schedule pricing: each round k ships a [C_k, F] operand
-        # (every rank, fully remote — ppermute), so the wire is priced
-        # per-round at the COMPILED heights, not at s_pad. Exposed time
-        # under the same interior-absorption model as the overlap rounds:
-        # the interior compute splits across the schedule's rounds and
-        # each round exposes max(its wire time, its compute share). The
-        # per-round byte list is what the trace/HLO auditors pin the
-        # lowered CollectivePermute operands against, byte-exact.
-        int_rows_max = max(edge_split["interior_per_shard"] or [0])
-        interior_us = (
-            3 * int_rows_max * row_bytes / (hbm_gbps * 1e3) if hbm_gbps
-            else 0.0
-        )
-        round_bytes = [int(c) * wire_row_bytes for c in sched_rows]
-        round_us = [
-            (rb / (ici_gbps * 1e3) if ici_gbps else 0.0)
-            for rb in round_bytes
-        ]
-        per_round_int = interior_us / len(sched_rows)
-        sched_exposed = sum(max(u, per_round_int) for u in round_us)
-        sched_serial = sum(round_us) + interior_us
-        exchange["sched"] = {
-            "schedule_id": schedule.schedule_id,
-            "rounds": len(sched_rows),
-            "transfers": schedule.num_transfers,
-            "round_rows": [int(c) for c in sched_rows],
-            "round_bytes_per_shard": round_bytes,
-            "operand_bytes_per_shard": sched_wire,
-            "interior_compute_us": round(interior_us, 3),
-            "exposed_us": round(sched_exposed, 3),
-            "serial_us": round(sched_serial, 3),
-            "hidden_us": round(sched_serial - sched_exposed, 3),
-        }
-
     psum = None
     if param_count:
         # ring all-reduce: each member sends 2*(W-1)/W of the payload

@@ -85,7 +85,6 @@ ENTRY_KINDS = (
     "hlo_drift",         # fallback tier 3: lowered-vs-footprint bytes
     "spmd_drift",        # fallback tier 4: cross-rank schedule identity
     "tune_record",       # tune_<sig>.json TuningRecord
-    "sched_compile",     # compiled halo schedule: id, rounds, priced bytes
     "wire_compile",      # resolved wire format: name, priced operand bytes
     "serve_health",      # serving latency/recompile/tenant record
     "supervise_lineage",        # single-child restart lineage
@@ -497,34 +496,6 @@ def _norm_lineage(obj: dict, source: str, round_n=None, git_rev=None) -> tuple:
     )], []
 
 
-def _norm_sched_compile(obj: dict, source: str) -> tuple:
-    """sched_compile: one compiled halo schedule (dgraph_tpu.sched) with
-    its footprint pricing. The ``_bytes``/``_count`` metric suffixes put
-    the compiled shape under obs.regress's byte-exact zero-tolerance
-    class: a commit that silently changes what the compiler emits for
-    the same workload goes RED, while ``exposed_us`` rides the
-    noise-aware timing gate. The schedule_id in meta names the exact
-    round order (content hash of the serialized IR)."""
-    metrics = {
-        "rounds_count": obj.get("rounds"),
-        "transfers_count": obj.get("transfers"),
-        "operand_bytes": obj.get("operand_bytes_per_shard"),
-        "exposed_us": obj.get("exposed_us"),
-    }
-    rb = obj.get("round_bytes_per_shard")
-    if isinstance(rb, (list, tuple)):
-        metrics["max_round_bytes"] = max(rb, default=0)
-    return [_entry(
-        "sched_compile", metrics,
-        workload=_workload_tag(obj.get("workload")),
-        halo_impl="sched",
-        git_rev=obj.get("git_rev"), recorded_at=obj.get("recorded_at"),
-        source=source, round_n=obj.get("round"),
-        meta={"schedule_id": obj.get("schedule_id"),
-              "round_rows": list(obj.get("round_rows") or [])[:64]},
-    )], []
-
-
 def _norm_wire_compile(obj: dict, source: str) -> tuple:
     """wire_compile: one resolved wire format (dgraph_tpu.wire) with its
     priced exchange operand. ``operand_bytes`` rides obs.regress's
@@ -607,6 +578,8 @@ _DECLINED_KINDS = {
                     "round/record-level summaries",
     "lint_report": "analysis reports are regenerated by scripts/check.py",
     "check_report": "analysis reports are regenerated by scripts/check.py",
+    "sched_compile": "no halo lowering replays a compiled schedule; the "
+                     "record describes nothing a run can take",
 }
 
 
@@ -636,8 +609,6 @@ def normalize_record(obj, source: str = "") -> tuple:
             return _norm_grow_transition(obj, source)
         if kind == "run_health":
             return _norm_run_health(obj, source)
-        if kind == "sched_compile":
-            return _norm_sched_compile(obj, source)
         if kind == "wire_compile":
             return _norm_wire_compile(obj, source)
         if kind == "tune_record" or (

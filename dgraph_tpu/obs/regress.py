@@ -28,12 +28,11 @@ format — ``utils.logging.ExperimentLog`` itself imports jax, which this
 module may not: it is jax-free by the same lint-enforced contract as the
 ledger, and runs on a machine where jax is wedged or absent).
 
-``--selftest`` seeds a synthetic trajectory and seven drifted mutants
+``--selftest`` seeds a synthetic trajectory and six drifted mutants
 (inflated wire bytes, slowed scan-delta, fattened p99, dropped tier,
-drifted compiled schedule, drifted wire-format bytes, drifted grown
-world) — each must go RED, and the clean trajectory must stay GREEN, or
-the selftest itself fails (the vacuity guard: a sentinel that can't see
-seeded drift gates nothing).
+drifted wire-format bytes, drifted grown world) — each must go RED, and
+the clean trajectory must stay GREEN, or the selftest itself fails (the
+vacuity guard: a sentinel that can't see seeded drift gates nothing).
 """
 
 from __future__ import annotations
@@ -301,25 +300,6 @@ def _fx_serve(i: int, *, p99: float = 50.0) -> dict:
     }
 
 
-def _fx_sched(i: int, *, operand_bytes: int = 2048, rounds: int = 3) -> dict:
-    """One compiled-schedule record (dgraph_tpu.sched -> obs.ledger
-    ``sched_compile``). Shape metrics carry the exact-class suffixes, so
-    the mutant's +64 bytes must go RED with zero tolerance."""
-    jitter = [0.0, 0.4, -0.2, 0.1, 0.3, -0.1, 0.2][i % 7]
-    return {
-        "kind": "sched_compile",
-        "workload": {"world_size": 2, "nodes": 96, "edges": 400,
-                     "feat_dim": 8, "seed": 0},
-        "schedule_id": "fixture0sched",
-        "rounds": rounds, "transfers": 4,
-        "operand_bytes_per_shard": operand_bytes,
-        "round_rows": [64, 32, 32],
-        "exposed_us": 12.0 + jitter,
-        "git_rev": f"rev{i:04d}",
-        "recorded_at": f"2026-08-01T02:{i:02d}:00Z",
-    }
-
-
 def _fx_wire(i: int, *, operand_bytes: int = 1024) -> dict:
     """One resolved-wire-format record (dgraph_tpu.wire -> obs.ledger
     ``wire_compile``). ``operand_bytes`` carries the exact-class suffix,
@@ -355,7 +335,6 @@ def _seed(tmp: str, n: int = 6) -> None:
     for i in range(n):
         ingest(_fx_round(i), f"fixture_r{i:02d}", tmp)
         ingest(_fx_serve(i), f"fixture_serve_r{i:02d}", tmp)
-        ingest(_fx_sched(i), f"fixture_sched_r{i:02d}", tmp)
         ingest(_fx_wire(i), f"fixture_wire_r{i:02d}", tmp)
         ingest(_fx_grow(i), f"fixture_grow_r{i:02d}", tmp)
 
@@ -412,15 +391,7 @@ def _selftest() -> dict:
                                "fixture_r06", tmp),
             "fallback_tiers",
         ),
-        # 5. drifted compiled schedule: +64 operand bytes for the same
-        # workload — a compiler change altering the emitted schedule must
-        # hit the byte-exact class, not a percentage gate
-        "drifted_schedule": (
-            lambda tmp: ingest(_fx_sched(6, operand_bytes=2048 + 64),
-                               "fixture_sched_r06", tmp),
-            "operand_bytes",
-        ),
-        # 6. drifted wire bytes: +64 priced operand bytes for the same
+        # 5. drifted wire bytes: +64 priced operand bytes for the same
         # workload at the same format — a codec/pricing change altering
         # what ships on the wire must hit the byte-exact class too
         "drifted_wire_bytes": (
@@ -428,7 +399,7 @@ def _selftest() -> dict:
                                "fixture_wire_r06", tmp),
             "operand_bytes",
         ),
-        # 7. drifted grown world: a re-recorded generation-1 transition
+        # 6. drifted grown world: a re-recorded generation-1 transition
         # whose adopted world size changed 3 -> 4 — a grow path that
         # reshards to the wrong world must hit the byte-exact class
         "drifted_world": (

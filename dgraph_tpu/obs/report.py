@@ -197,8 +197,8 @@ def _selftest() -> dict:
         entries, _ = read_ledger(tmp)
         md = render_trajectory(entries, directory=tmp)
         for want in ("## Bench rounds", "## cpu_scan_delta",
-                     "## serve_health", "## sched_compile",
-                     "## wire_compile", "## grow_transition",
+                     "## serve_health", "## wire_compile",
+                     "## grow_transition",
                      "operand_bytes", "exchange_ms", "p99_ms",
                      "new_world_count", "450."):
             check(want in md, f"rendered trajectory lacks {want!r}")

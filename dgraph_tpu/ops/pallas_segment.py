@@ -165,34 +165,34 @@ def _sorted_segment_sum_impl(
     if input_op not in ("none", "relu"):
         raise ValueError(f"input_op must be 'none' or 'relu', got {input_op!r}")
     E, F = data.shape
-    sched = _ChunkSchedule(
+    chunks = _ChunkSchedule(
         segment_ids, num_segments, E, block_e=block_e, block_n=block_n,
         max_chunks_per_block=max_chunks_per_block,
     )
-    data3d = sched.pad_edges(data).reshape(sched.num_chunks, block_e, F)
+    data3d = chunks.pad_edges(data).reshape(chunks.num_chunks, block_e, F)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(sched.nb, sched.max_chunks),
+        grid=(chunks.nb, chunks.max_chunks),
         in_specs=[
-            sched.chunk_spec((1, 1, block_e)),
-            sched.chunk_spec((1, block_e, F)),
+            chunks.chunk_spec((1, 1, block_e)),
+            chunks.chunk_spec((1, block_e, F)),
         ],
-        out_specs=sched.block_spec(F),
+        out_specs=chunks.block_spec(F),
     )
     # The MXU accumulator must be 32-bit ('tpu.matmul' rejects a bf16 acc),
     # and f32 accumulation over long segments is the atomicAdd-parity
     # semantics anyway — so the VMEM-resident output block is ALWAYS f32
     # (bf16 inputs still ride the fast bf16 MXU passes under
     # precision='default'); cast back to the input dtype on the way out.
-    operands = (sched.chunk_start, sched.chunk_counts, sched.ids3d, data3d)
+    operands = (chunks.chunk_start, chunks.chunk_counts, chunks.ids3d, data3d)
     out = pl.pallas_call(
         functools.partial(
             _kernel, block_n=block_n, block_e=block_e, input_op=input_op,
             precision=_precision(precision),
         ),
         grid_spec=grid_spec,
-        out_shape=_out_struct((sched.N_pad, F), jnp.float32, *operands),
+        out_shape=_out_struct((chunks.N_pad, F), jnp.float32, *operands),
         interpret=interpret,
     )(*operands)
     return out[:num_segments].astype(data.dtype)
@@ -375,37 +375,37 @@ def _make_ssbr_impl(num_segments, max_chunks_per_block, block_e, block_n,
     def impl(data, segment_ids, bias, edge_weight, epilogue="relu",
              other=None):
         E, F = data.shape
-        sched = _ChunkSchedule(
+        chunks = _ChunkSchedule(
             segment_ids, num_segments, E, block_e=block_e, block_n=block_n,
             max_chunks_per_block=max_chunks_per_block,
         )
-        if sched.N_pad != num_segments:
-            bias = jnp.pad(bias, ((0, sched.N_pad - num_segments), (0, 0)))
+        if chunks.N_pad != num_segments:
+            bias = jnp.pad(bias, ((0, chunks.N_pad - num_segments), (0, 0)))
         # data, then epilogue="grad"'s second streamed operand
         streamed = [
-            sched.pad_edges(t).reshape(sched.num_chunks, block_e, F)
+            chunks.pad_edges(t).reshape(chunks.num_chunks, block_e, F)
             for t in ((data,) if other is None else (data, other))
         ]
         in_specs = [
-            sched.chunk_spec((1, 1, block_e)),
-            *[sched.chunk_spec((1, block_e, F)) for _ in streamed],
-            sched.block_spec(F),
+            chunks.chunk_spec((1, 1, block_e)),
+            *[chunks.chunk_spec((1, block_e, F)) for _ in streamed],
+            chunks.block_spec(F),
         ]
-        operands = [sched.ids3d, *streamed, bias]
+        operands = [chunks.ids3d, *streamed, bias]
         if has_weight:
-            wgt3d = sched.pad_edges(edge_weight).reshape(
-                sched.num_chunks, 1, block_e
+            wgt3d = chunks.pad_edges(edge_weight).reshape(
+                chunks.num_chunks, 1, block_e
             )
-            in_specs.insert(1, sched.chunk_spec((1, 1, block_e)))
+            in_specs.insert(1, chunks.chunk_spec((1, 1, block_e)))
             operands.insert(1, wgt3d)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(sched.nb, sched.max_chunks),
+            grid=(chunks.nb, chunks.max_chunks),
             in_specs=in_specs,
-            out_specs=sched.block_spec(F),
+            out_specs=chunks.block_spec(F),
         )
-        call_args = (sched.chunk_start, sched.chunk_counts, *operands)
+        call_args = (chunks.chunk_start, chunks.chunk_counts, *operands)
         out = pl.pallas_call(
             functools.partial(
                 _kernel_bias_relu, block_n=block_n, block_e=block_e,
@@ -413,7 +413,7 @@ def _make_ssbr_impl(num_segments, max_chunks_per_block, block_e, block_n,
                 epilogue=epilogue,
             ),
             grid_spec=grid_spec,
-            out_shape=_out_struct((sched.N_pad, F), jnp.float32, *call_args),
+            out_shape=_out_struct((chunks.N_pad, F), jnp.float32, *call_args),
             interpret=interpret,
         )(*call_args)
         if epilogue == "act":

@@ -358,7 +358,6 @@ class OverlapSpec:
         "halo_sort_mc",
         "gather_mv",
         "halo_pair_rows",
-        "halo_schedule",
         "wire_format",
     )
 )
@@ -464,17 +463,9 @@ class EdgePlan:
     # Static [W][W] traffic matrix: deduped live halo rows per
     # (sender, needer) pair — halo_counts as plain nested int tuples, so
     # it survives plan pickling/sharding and rides the jit cache key.
-    # Feeds the row-weighted pick_halo_impl heuristic and the schedule
-    # compiler (dgraph_tpu.sched). () on plans predating the compiler
-    # (stale caches rebuild via PLAN_FORMAT_VERSION).
+    # Feeds the row-weighted pick_halo_impl heuristic. () on plans
+    # without the matrix (stale caches rebuild via PLAN_FORMAT_VERSION).
     halo_pair_rows: tuple = ()
-    # Compiled multi-round halo schedule (dgraph_tpu.sched.ir.
-    # HaloSchedule — frozen/hashable, so static aux is safe), attached
-    # deterministically at plan build whenever halo_pair_rows is live.
-    # Replayed by comm.collectives' round executor under
-    # halo_impl="sched"; None when no cross-rank traffic (or on plans
-    # predating the compiler).
-    halo_schedule: Any = None
     # Wire format name (dgraph_tpu.wire.spec.WIRE_FORMATS) attached
     # deterministically at plan build — the build-time resolution of the
     # adoption ladder, so a cache round-trip keeps an adopted codec.
@@ -611,31 +602,6 @@ def pick_halo_impl(
     return "ppermute" if n_eff <= max(1, world_size // 2) else "all_to_all"
 
 
-def compile_plan_schedule(
-    pair_rows: tuple, *, s_pad: int, world_size: int, halo_deltas: tuple,
-):
-    """The ONE attach rule for a plan's compiled halo schedule: both
-    plan-build paths (:func:`_finalize_plan`) and the shard assembler
-    (:func:`assemble_plan`) compile through here, so a monolithic build
-    and a cache/shard round-trip of the same graph carry byte-identical
-    schedules (same ``schedule_id``) — and, because ``pair_rows`` is
-    always the FULL-WORLD static matrix (rank-subset loads keep whole-
-    world statics), every rank holds the identical round order by
-    construction: the rank-divergence/deadlock class the SPMD
-    issue-sequence auditor proves absent. Returns ``None`` when there is
-    no cross-rank traffic (or no matrix: plans predating the compiler).
-    """
-    if not halo_deltas or not pair_rows:
-        return None
-    if not any(v for row in pair_rows for v in row):
-        return None
-    from dgraph_tpu.sched.passes import compile_halo_schedule
-
-    return compile_halo_schedule(
-        pair_rows, s_pad=int(s_pad), world_size=int(world_size)
-    )
-
-
 def plan_wire_format(world_size: int, halo_deltas: tuple) -> str:
     """The ONE attach rule for a plan's wire format
     (:mod:`dgraph_tpu.wire`): both plan-build paths
@@ -663,12 +629,12 @@ def plan_wire_format(world_size: int, halo_deltas: tuple) -> str:
 # the audit tiers' columns, the tuner's candidates and the record validator
 # all import or derive from this tuple; ``'none'`` (a plan with no
 # cross-rank traffic) is a verdict of the resolver, not a lowering.
-HALO_IMPLS = ("all_to_all", "ppermute", "overlap", "sched")
+HALO_IMPLS = ("all_to_all", "ppermute", "overlap")
 
 
 def resolve_halo_impl(
     world_size: int, halo_deltas: tuple, *, overlap_available: bool = False,
-    sched_available: bool = False, pair_rows: tuple = (),
+    pair_rows: tuple = (),
 ) -> tuple[str, str]:
     """The halo lowering the run will actually execute, plus who decided.
 
@@ -696,16 +662,6 @@ def resolve_halo_impl(
     ``ValueError``: a typo like ``alltoall`` silently training on the
     heuristic's choice would misattribute every measurement.
 
-    ``'sched'`` (the compiled multi-round schedule,
-    :mod:`dgraph_tpu.sched`, replayed by ``comm.collectives``'s round
-    executor) is likewise legal only when the plan actually carries a
-    compiled schedule (``sched_available``, i.e.
-    ``plan.halo_schedule is not None``) — a pin or record naming it
-    on a schedule-less plan degrades with a one-time warning to the next
-    tier — and the heuristic tier never picks it on its own: a compiled
-    schedule engages only through an explicit pin or a persisted tuning
-    record that A/B'd it against the fixed lowerings.
-
     ``pair_rows`` (``plan.halo_pair_rows``) is forwarded to
     :func:`pick_halo_impl` so the heuristic tier weighs actual per-pair
     traffic, not just the ring count.
@@ -720,8 +676,9 @@ def resolve_halo_impl(
     if not halo_deltas:
         return "none", "plan"
 
-    needs = {"overlap": overlap_available, "sched": sched_available}
-    legal = tuple(k for k in HALO_IMPLS if needs.get(k, True))
+    legal = tuple(
+        k for k in HALO_IMPLS if k != "overlap" or overlap_available
+    )
     for impl, source, flag, unset in (
         (_cfg.halo_impl, "env", "DGRAPH_TPU_HALO_IMPL", "auto"),
         (_cfg.tuned_halo_impl, "record", "config.tuned_halo_impl", None),
@@ -737,8 +694,6 @@ def resolve_halo_impl(
             return impl, source
         if impl == "overlap":  # pinned but the plan carries no split
             _warn_overlap_unavailable(source)
-        if impl == "sched":  # pinned but the plan carries no schedule
-            _warn_sched_unavailable(source)
     if overlap_available:
         return "overlap", "heuristic"
     return pick_halo_impl(world_size, halo_deltas, pair_rows), "heuristic"
@@ -770,20 +725,6 @@ def _warn_overlap_unavailable(source: str) -> None:
         )
 
 
-_sched_warned: set = set()
-
-
-def _warn_sched_unavailable(source: str) -> None:
-    if source not in _sched_warned:
-        _sched_warned.add(source)
-        _logger.warning(
-            "halo_impl='sched' requested by %s but the plan carries no "
-            "compiled halo schedule (halo_schedule is None — plan predates "
-            "the schedule compiler or has no cross-rank traffic); the next "
-            "resolution tier decides the lowering instead", source,
-        )
-
-
 def plan_efficiency(plan: EdgePlan, layout: EdgePlanLayout) -> dict:
     """Real/padded fill ratios — the padded design's skew telemetry.
 
@@ -802,7 +743,6 @@ def plan_efficiency(plan: EdgePlan, layout: EdgePlanLayout) -> dict:
     dst_total = int(layout.dst_counts.sum())
     impl, impl_source = resolve_halo_impl(
         W, plan.halo_deltas, overlap_available=plan.overlap is not None,
-        sched_available=plan.halo_schedule is not None,
         pair_rows=plan.halo_pair_rows,
     )
     return {
@@ -998,7 +938,6 @@ def validate_plan(plan: EdgePlan) -> None:
         raise ValueError("invalid EdgePlan: " + "; ".join(errors))
     impl, impl_source = resolve_halo_impl(
         W, plan.halo_deltas, overlap_available=plan.overlap is not None,
-        sched_available=plan.halo_schedule is not None,
         pair_rows=plan.halo_pair_rows,
     )
     _logger.info(
@@ -1491,15 +1430,12 @@ def halo_wire_rows(plan: "EdgePlan", impl: str) -> int:
     """Rows one halo exchange puts on the wire, over all ranks, under the
     lowering ``impl``, padding included (``obs.footprint`` prices the same
     rows in bytes): all_to_all moves every remote peer block at ``s_pad``;
-    the round lowerings move one ``s_pad`` block a live delta; a compiled
-    schedule moves its rounds' padded widths."""
+    the round lowerings move one ``s_pad`` block a live delta."""
     W, S = plan.world_size, plan.halo.s_pad
     if impl == "all_to_all":
         return W * (W - 1) * S
     if impl in ("ppermute", "overlap"):
         return len(plan.halo_deltas) * W * S
-    if impl == "sched" and plan.halo_schedule is not None:
-        return W * sum(plan.halo_schedule.round_rows())
     return 0
 
 
@@ -1575,10 +1511,6 @@ def _finalize_plan(
     halo_pair_rows = tuple(
         tuple(int(v) for v in row) for row in np.asarray(halo_counts)
     )
-    halo_schedule = compile_plan_schedule(
-        halo_pair_rows, s_pad=s_pad_val, world_size=W,
-        halo_deltas=halo_deltas,
-    )
 
     plan = EdgePlan(
         src_index=src_idx_arr,
@@ -1606,7 +1538,6 @@ def _finalize_plan(
         gather_mv=gather_mv,
         overlap=overlap_spec,
         halo_pair_rows=halo_pair_rows,
-        halo_schedule=halo_schedule,
         wire_format=plan_wire_format(W, halo_deltas),
     )
     layout = EdgePlanLayout(
@@ -1856,7 +1787,7 @@ def _shard_statics(prep, *, homogeneous, edge_owner, sort_edges, sort_route,
         "scatter_block_n": SCATTER_BLOCK_N,
         "halo_deltas": [int(d) for d in prep.halo_deltas],
         # full-world traffic matrix: rank-subset loads keep whole-world
-        # statics, so every host compiles the identical halo schedule
+        # statics, so every host resolves the identical halo lowering
         "halo_pair_rows": [
             [int(v) for v in row] for row in np.asarray(prep.halo_counts)
         ],
@@ -2293,11 +2224,6 @@ def assemble_plan(manifest: dict, payloads: dict, ranks: list) -> EdgePlan:
         gather_mv=int(st.get("gather_mv", 0)),
         overlap=overlap_spec,
         halo_pair_rows=pair_rows,
-        halo_schedule=compile_plan_schedule(
-            pair_rows, s_pad=int(st["s_pad"]),
-            world_size=int(st["world_size"]),
-            halo_deltas=tuple(int(d) for d in st["halo_deltas"]),
-        ),
         # stamped manifests carry their build-time resolution; pre-codec
         # manifests (no key) re-resolve through the same ONE attach rule
         wire_format=st.get("wire_format") or plan_wire_format(

@@ -222,7 +222,9 @@ def checkpoint_keys(ckpt_dir: str, step: Optional[int] = None):
 # Bump whenever EdgePlan's fields/defaults change shape or meaning: stale
 # cache pickles must REBUILD, not silently inherit new class defaults for
 # fields they were never built with (e.g. scatter_block_e).
-PLAN_FORMAT_VERSION = 12  # v12: halo_sorted_owner_ids, the owner-side
+PLAN_FORMAT_VERSION = 13  # v13: EdgePlan has one static fewer (plans
+# and shard manifests cached with it rebuild, never half-read);
+# v12: halo_sorted_owner_ids, the owner-side
 # index in the halo-sorted order (the fused GCN layer's backward aggregates
 # over it; validate_plan refuses a sorted route without it);
 # v11: the halo-sorted route's padded edges
@@ -233,9 +235,8 @@ PLAN_FORMAT_VERSION = 12  # v12: halo_sorted_owner_ids, the owner-side
 # the adopted halo-payload codec rides EdgePlan statics + the sharded
 # manifest, so cached plans predating the codec layer must rebuild and
 # stamp their build-time resolution;
-# v9: halo_pair_rows traffic matrix + compiled
-# halo_schedule statics (dgraph_tpu.sched) — cached plans predating the
-# schedule compiler must rebuild so the matrix lands in the manifest;
+# v9: halo_pair_rows traffic matrix static — cached plans predating it
+# must rebuild so the matrix lands in the manifest;
 # v8: sharded plan artifacts — per-rank
 # shard_XXXX.pkl files under plan_<key>/ with a checksummed manifest.json
 # (dgraph_tpu.plan_shards), streamed by plan.build_edge_plan_sharded,

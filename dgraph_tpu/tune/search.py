@@ -111,25 +111,6 @@ def candidate_cost(
         pp_us = exch_bound("ppermute")
         overlap_exposed = max(pp_us - interior_leg_us, 0.0)
 
-    # the compiled schedule enters the ranking only when the plan carries
-    # one (plan.halo_schedule attached at build) — ranked from the SAME
-    # launch-aware bound family as the fixed lowerings: per-round compiled
-    # operand bytes on the wire + one launch per round, the staged blocks'
-    # HBM streams, minus the same interior absorption the overlap rounds
-    # get (the sched executor has the identical issue-all-then-place shape)
-    sched_fp = fp["collectives"]["halo_exchange"].get("sched")
-    sched_rankable = bool(n_d) and sched_fp is not None
-    sched_exposed = 0.0
-    if sched_rankable:
-        n_r = sched_fp["rounds"]
-        sched_wire_us = (
-            wire.get("sched", 0) / (ici_gbps * 1e3) + n_r * LAUNCH_US
-        )
-        sched_hbm_us = (2 * n_r + W) * S * row / (hbm_gbps * 1e3)
-        sched_exposed = max(
-            max(sched_wire_us, sched_hbm_us) - interior_leg_us, 0.0
-        )
-
     if n_d == 0:
         impl, exch_us = "none", 0.0
     else:
@@ -138,15 +119,11 @@ def candidate_cost(
             "ppermute": exch_bound("ppermute"),
             "overlap": overlap_exposed,
         }
-        if sched_rankable:
-            bounds["sched"] = sched_exposed
         # stable tie-break preserving the pre-overlap semantics: ppermute
         # beats all_to_all on equal cost (as before), overlap — equal to
         # ppermute exactly when there is no interior work to hide behind
-        # — only wins when it actually hides something, and sched (last)
-        # only when it strictly beats the fixed lowerings: an un-A/B'd
-        # compiled schedule never wins a tie. plan.HALO_IMPLS with
-        # ppermute moved to the front IS that order.
+        # — only wins when it actually hides something. plan.HALO_IMPLS
+        # with ppermute moved to the front IS that order.
         order = ("ppermute",) + tuple(k for k in HALO_IMPLS if k != "ppermute")
         impl = min(
             (k for k in order if k in bounds),
@@ -174,14 +151,8 @@ def candidate_cost(
     wf_winner = "fp32"
     wire_operand_bytes = 0
     if n_d and res_row:
-        launches_by = {
-            "all_to_all": 1, "ppermute": n_d, "overlap": n_d,
-            "sched": sched_fp["rounds"] if sched_fp else 0,
-        }
-        sent_by = {
-            "all_to_all": W, "ppermute": n_d, "overlap": n_d,
-            "sched": sched_fp["rounds"] if sched_fp else 0,
-        }
+        launches_by = {"all_to_all": 1, "ppermute": n_d, "overlap": n_d}
+        sent_by = {"all_to_all": W, "ppermute": n_d, "overlap": n_d}
 
         def _bound_at_wire_scale(scale: float) -> float:
             wire_us = (
@@ -190,7 +161,7 @@ def candidate_cost(
             )
             hbm_us = (2 * sent_by[impl] + W) * S * row / (hbm_gbps * 1e3)
             bound = max(wire_us, hbm_us)
-            if impl in ("overlap", "sched"):
+            if impl == "overlap":
                 bound = max(bound - interior_leg_us, 0.0)
             return bound
 
@@ -225,15 +196,6 @@ def candidate_cost(
         # overlap-knob pricing: both alternatives land in the trace so the
         # record's choice is auditable (overlap in {off, on} first-class)
         "overlap_exposed_us": round(overlap_exposed, 3),
-        # compiled-schedule pricing: always reported when a schedule is
-        # attached (auditable), ranked only via sched_rankable
-        "sched_exposed_us": round(sched_exposed, 3),
-        "sched_rankable": sched_rankable,
-        "sched_rounds": int(sched_fp["rounds"]) if sched_fp else 0,
-        "sched_schedule_id": sched_fp["schedule_id"] if sched_fp else None,
-        "sched_operand_bytes": (
-            int(sched_fp["operand_bytes_per_shard"]) if sched_fp else 0
-        ),
         # wire-format ranking: every priced alternative lands in the
         # trace (auditable); the winner is what the record adopts
         "wire_format": wf_winner,
@@ -504,34 +466,12 @@ def search(
         phase="result", record_id=record.record_id, winner=winner_cand.key,
         **cost,
     )
-    if winner_cost.get("sched_schedule_id"):
-        # the winner's compiled halo schedule joins the perf ledger: its
-        # _bytes/_count metrics land in regress's byte-exact class, so a
-        # compiler change that alters what this workload's schedule looks
-        # like goes RED across commits (off unless DGRAPH_LEDGER_DIR set;
-        # maybe_ingest swallows every failure)
-        from dgraph_tpu.obs.ledger import maybe_ingest
-
-        maybe_ingest(
-            {
-                "kind": "sched_compile",
-                "workload": {
-                    "world_size": world_size, "nodes": num_nodes,
-                    "edges": int(edge_index.shape[1]),
-                    "feat_dim": feat_dim,
-                },
-                "schedule_id": winner_cost["sched_schedule_id"],
-                "rounds": winner_cost["sched_rounds"],
-                "operand_bytes_per_shard": winner_cost["sched_operand_bytes"],
-                "exposed_us": winner_cost["sched_exposed_us"],
-            },
-            source="tune.search", default_on=False,
-        )
     if winner_cost.get("wire_operand_bytes"):
-        # the winner's wire format joins the perf ledger the same way:
-        # operand_bytes lands in regress's byte-exact class, so a codec
-        # or pricing change that alters what this workload ships on the
-        # wire goes RED across commits
+        # the winner's wire format joins the perf ledger: operand_bytes
+        # lands in regress's byte-exact class, so a codec or pricing
+        # change that alters what this workload ships on the wire goes
+        # RED across commits (off unless DGRAPH_LEDGER_DIR set;
+        # maybe_ingest swallows every failure)
         from dgraph_tpu.obs.ledger import maybe_ingest
 
         maybe_ingest(

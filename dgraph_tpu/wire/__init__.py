@@ -1,31 +1,20 @@
 """Wire codec layer: compressed halo payloads as verified, tuner-ranked
 wire formats (ROADMAP item 1 — attack the dominant halo wire bytes).
 
-Separates *what rows cross the wire* (the plan's send tables, the sched
-compiler's rounds) from *how they are encoded*: a registry of
-serializable :class:`~dgraph_tpu.wire.spec.WireFormat` specs (fp32
-identity / bf16 / scaled fp8-e4m3), a resolution ladder mirroring
-``resolve_halo_impl``, hub-row dedup with a delivery-simulation
-verifier, and jax codecs whose custom-VJP pairs encode cotangents with
-the same format.
+Separates *what rows cross the wire* (the plan's send tables) from *how
+they are encoded*: a registry of serializable
+:class:`~dgraph_tpu.wire.spec.WireFormat` specs (fp32 identity / bf16 /
+scaled fp8-e4m3), a resolution ladder mirroring ``resolve_halo_impl``,
+and jax codecs whose custom-VJP pairs encode cotangents with the same
+format.
 
-The spec, pricing, resolver, and dedup modules are jax-free by the
+The spec, pricing and resolver modules are jax-free by the
 lint-enforced contract; the jax codecs live in
 :mod:`dgraph_tpu.wire.codec` and are re-exported lazily below (PEP 562)
 so jax-free consumers importing ``dgraph_tpu.wire.spec`` never pay the
 jax import.
 """
 
-from dgraph_tpu.wire.dedup import (
-    DedupPlan,
-    HubRow,
-    RelayTransfer,
-    build_dedup_plan,
-    dedup_stats,
-    detect_hub_rows,
-    pair_live_rows,
-    verify_dedup_coverage,
-)
 from dgraph_tpu.wire.spec import (
     E4M3_MAX,
     FP8_SCALE_BYTES,
@@ -62,27 +51,19 @@ def __getattr__(name):  # PEP 562: jax loads only when a codec is asked for
 
 
 __all__ = [
-    "DedupPlan",
     "E4M3_MAX",
     "FP8_SCALE_BYTES",
-    "HubRow",
-    "RelayTransfer",
     "WIRE_FORMATS",
     "WIRE_FORMAT_NAMES",
     "WIRE_FORMAT_VERSION",
     "WireFormat",
-    "build_dedup_plan",
-    "dedup_stats",
     "delta_skip_rows",
-    "detect_hub_rows",
     "fp8_available",
     "get_format",
     "np_decode",
     "np_encode",
     "np_encode_compensated",
     "np_roundtrip_bound",
-    "pair_live_rows",
     "resolve_wire_format",
-    "verify_dedup_coverage",
     *_CODEC_EXPORTS,
 ]

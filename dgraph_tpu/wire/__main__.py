@@ -17,10 +17,8 @@ with zero XLA compiles and without importing jax:
 - the resolution ladder: env pin > tuned record > plan-attached >
   fp32 default, with precondition failures (fp8 without e4m3, unknown
   names) degrading to the next tier;
-- hub-row dedup: the fixture plan verifies delivery-exact, and the
-  vacuity mutants — wrong fp8 scale, dropped compensation residual,
-  duplicated relay (double-count), dropped needer, non-causal carrier —
-  must each go RED. A verifier that cannot fail proves nothing.
+- the vacuity mutants — wrong fp8 scale, dropped compensation residual —
+  must each go RED. A check that cannot fail proves nothing.
 
 Wired as the ``wire-selftest`` pass in ``scripts/check.py``.
 """
@@ -33,13 +31,6 @@ import sys
 
 import numpy as np
 
-from dgraph_tpu.wire.dedup import (
-    RelayTransfer,
-    build_dedup_plan,
-    dedup_stats,
-    detect_hub_rows,
-    verify_dedup_coverage,
-)
 from dgraph_tpu.wire.spec import (
     WIRE_FORMATS,
     WireFormat,
@@ -50,27 +41,6 @@ from dgraph_tpu.wire.spec import (
     np_roundtrip_bound,
     resolve_wire_format,
 )
-
-
-def _dedup_fixture():
-    """4-rank world, s_pad=4: src 0's row 5 is a hub needed by ranks
-    1, 2 and 3 (primary 1); everything else is plain pair traffic."""
-    W, S = 4, 4
-    idx = np.zeros((W, W, S), dtype=np.int32)
-    msk = np.zeros((W, W, S), dtype=np.int32)
-
-    def block(s, d, rows):
-        for k, r in enumerate(rows):
-            idx[s, d, k] = r
-            msk[s, d, k] = 1
-
-    block(0, 1, [5, 6])
-    block(0, 2, [5])
-    block(0, 3, [5, 9])
-    block(1, 0, [3])
-    block(2, 3, [4, 8])
-    block(3, 2, [2, 5])
-    return idx, msk, S
 
 
 def _selftest() -> dict:
@@ -184,42 +154,6 @@ def _selftest() -> dict:
     finally:
         _cfg.set_flags(wire_format=saved[0], tuned_wire_format=saved[1])
 
-    # --- hub-row dedup ----------------------------------------------
-    idx, msk, s_pad = _dedup_fixture()
-    hubs = detect_hub_rows(idx, msk)
-    check(len(hubs) == 1 and hubs[0].src == 0 and hubs[0].row == 5
-          and hubs[0].needers == (1, 2, 3),
-          f"hub detection wrong: {hubs}")
-    plan = build_dedup_plan(idx, msk, s_pad=s_pad)
-    check(verify_dedup_coverage(plan, idx, msk) == [],
-          "dedup fixture plan fails its own delivery verifier")
-    stats = dedup_stats(plan, idx, msk)
-    check(stats["owner_egress_rows_saved"] == 2,
-          f"hub with 3 needers must save 2 owner-egress rows: {stats}")
-    check(stats["relay_rows"] == 2 and stats["relay_rounds"] == 2,
-          f"recursive-doubling fan-out of 3 needers is 2 relays: {stats}")
-    check(stats["max_rank_egress_after"]
-          <= stats["max_rank_egress_before"],
-          f"dedup must not worsen the bottleneck egress: {stats}")
-
-    # vacuity mutants against the delivery verifier
-    dup = dataclasses.replace(plan, relay_rounds=plan.relay_rounds + (
-        (RelayTransfer(carrier=1, dst=2, src=0, row=5),),))
-    check(any("delivered 2 times" in f
-              for f in verify_dedup_coverage(dup, idx, msk)),
-          "vacuity: duplicated relay (double-count) not flagged RED")
-    dropped = dataclasses.replace(plan,
-                                  relay_rounds=plan.relay_rounds[:1])
-    check(any("never delivered" in f
-              for f in verify_dedup_coverage(dropped, idx, msk)),
-          "vacuity: dropped needer not flagged RED")
-    noncausal = dataclasses.replace(plan, relay_rounds=(
-        (RelayTransfer(carrier=2, dst=3, src=0, row=5),),
-        (RelayTransfer(carrier=1, dst=2, src=0, row=5),),))
-    check(any("does not hold" in f
-              for f in verify_dedup_coverage(noncausal, idx, msk)),
-          "vacuity: non-causal relay carrier not flagged RED")
-
     # --- delta-skip accounting ---------------------------------------
     rows = ((0, 64, 1, 2), (1, 0, 1, 0), (2, 1, 0, 1), (0, 2, 1, 0))
     ds = delta_skip_rows(rows, world_size=4, s_pad=64)
@@ -231,7 +165,7 @@ def _selftest() -> dict:
 
     if not jax_preloaded:
         check("jax" not in sys.modules,
-              "selftest imported jax — wire spec/dedup are not jax-free")
+              "selftest imported jax — wire spec is not jax-free")
 
     return {"kind": "wire_selftest", "formats": sorted(WIRE_FORMATS),
             "failures": failures, "ok": not failures}
@@ -240,8 +174,8 @@ def _selftest() -> dict:
 @dataclasses.dataclass
 class Config:
     """Wire-codec CLI: ``--selftest true`` runs the compile-free codec
-    + resolver + dedup invariant and vacuity-mutant suite; exit 1 on
-    any failure."""
+    + resolver invariant and vacuity-mutant suite; exit 1 on any
+    failure."""
 
     selftest: bool = False
     indent: int = 0

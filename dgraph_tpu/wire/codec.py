@@ -23,10 +23,10 @@ sees one stable callable per configuration:
   (``all_to_all(split=0, concat=0)`` is its own transpose; a ppermute's
   transpose is the inverted permutation).
 
-The multi-round executors in ``comm.collectives`` (overlap / sched)
-are ALREADY custom-VJP bodies — opaque to AD — so they call the
-raw transforms directly and encode their hand-built cotangent legs with
-the same pair.
+The multi-round executor in ``comm.collectives`` (overlap) is
+ALREADY a custom-VJP body — opaque to AD — so it calls the raw
+transforms directly and encodes its hand-built cotangent legs with the
+same pair.
 
 fp8 packing (must match :func:`dgraph_tpu.wire.spec.np_encode` bit for
 bit): per-row scale ``max|x| / 448`` (zero rows scale 1.0), payload

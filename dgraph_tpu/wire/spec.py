@@ -1,9 +1,8 @@
 """Wire-format specs: WHAT encoding halo payloads ride the wire in.
 
 The codec layer separates *which rows cross the wire* (the plan's halo
-send tables and the compiled :mod:`dgraph_tpu.sched` rounds) from *how
-they are encoded*. This module is the format side: a registry of
-serializable :class:`WireFormat` specs, the resolution ladder that
+send tables) from *how they are encoded*. This module is the format
+side: a registry of serializable :class:`WireFormat` specs, the resolution ladder that
 decides which one a run adopts, byte pricing (what ``obs.footprint``
 and the trace/HLO byte pins charge per row), and numpy reference codecs
 that are the ground truth the jax codecs
@@ -29,7 +28,7 @@ steps stay within a pinned tolerance of fp32 — the classic
 error-feedback trick, exposed at the codec level for training loops
 that thread residual state.
 
-Contracts (mirrors :mod:`dgraph_tpu.sched.ir`):
+Contracts:
 
 - **jax-free** (``analysis.lint``'s ``jax-free-module`` rule): specs,
   pricing, the resolution ladder, and the selftest codecs must load and
@@ -335,16 +334,16 @@ def np_encode_compensated(
 
 
 # ---------------------------------------------------------------------------
-# delta-skip accounting: what the n_deltas-aware schedules save
+# delta-skip accounting: what shipping only live rows would save
 # ---------------------------------------------------------------------------
 
 
 def delta_skip_rows(pair_rows, world_size: int, s_pad: int) -> dict:
-    """Row accounting of shipping ONLY live rows (the compiled
-    schedule's per-pair heights) versus the dense lowerings' padded
-    operands — the delta-skip generalization, as numbers: the ``sched``
-    lowering already ships ~``live_rows`` per shard where ``all_to_all``
-    ships ``(W-1) * s_pad`` and a ppermute ring ``n_deltas * s_pad``."""
+    """Row accounting of shipping ONLY live rows (the per-pair heights
+    of ``pair_rows``) versus the dense lowerings' padded operands — the
+    delta-skip generalization, as numbers: ``live_rows`` a shard where
+    ``all_to_all`` ships ``(W-1) * s_pad`` and a ppermute ring
+    ``n_deltas * s_pad``."""
     rows = tuple(tuple(int(v) for v in r) for r in pair_rows)
     live = sum(v for r in rows for v in r)
     deltas = sorted({

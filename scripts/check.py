@@ -58,17 +58,11 @@ PASSES = [
     ("spmd-selftest",
      [sys.executable, "-m", "dgraph_tpu.analysis.spmd",
       "--selftest", "true"]),
-    # halo schedule compiler: IR round-trip identity, pass-pipeline
-    # invariants (conflict-freedom, exact coverage, split/pack bounds),
-    # and the vacuity mutants (a conflicting round and a dropped
-    # transfer must each go RED) — pure stdlib, zero XLA compiles
-    ("sched-selftest",
-     [sys.executable, "-m", "dgraph_tpu.sched", "--selftest", "true"]),
-    # perf-trajectory drift sentinel: the seven seeded-drift vacuity
+    # perf-trajectory drift sentinel: the six seeded-drift vacuity
     # mutants (inflated wire bytes, slowed scan-delta, fattened p99,
-    # dropped fallback tier, drifted schedule, drifted wire-format
-    # bytes, drifted grown world) must each go RED and the clean fixture
-    # ledger must gate GREEN — pure stdlib, zero compiles
+    # dropped fallback tier, drifted wire-format bytes, drifted grown
+    # world) must each go RED and the clean fixture ledger must gate
+    # GREEN — pure stdlib, zero compiles
     ("regress-selftest",
      [sys.executable, "-m", "dgraph_tpu.obs.regress",
       "--selftest", "true"]),
@@ -82,7 +76,7 @@ PASSES = [
       "--selftest", "true"]),
     # wire codec layer: registry byte pins, numpy round-trip bounds per
     # format, the wrong-scale/dropped-row vacuity mutants, the resolver
-    # ladder, the hub-dedup plan fixtures, and the jax-free guard —
+    # ladder, the delta-skip accounting, and the jax-free guard —
     # pure stdlib + numpy, zero compiles
     ("wire-selftest",
      [sys.executable, "-m", "dgraph_tpu.wire", "--selftest", "true"]),

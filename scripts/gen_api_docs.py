@@ -38,7 +38,6 @@ SECTIONS = [
     ("Sharded plan builds (cache format v8)", "dgraph_tpu.plan",
      ["build_plan_shards", "build_edge_plan_sharded", "load_sharded_plan",
       "assemble_plan", "shard_nbytes_estimate", "reshard_vertex_data"]),
-    ("Halo schedule compiler", "dgraph_tpu.sched", None),
     ("Wire formats: registry & resolution", "dgraph_tpu.wire.spec",
      ["WireFormat", "get_format", "fp8_available", "resolve_wire_format",
       "np_encode", "np_decode", "np_roundtrip_bound",
@@ -47,10 +46,6 @@ SECTIONS = [
     ("Wire formats: jax codecs", "dgraph_tpu.wire.codec",
      ["make_wire_transform", "make_wire_codec", "make_a2a_codec",
       "make_ppermute_codec", "encode_compensated", "fp8_jnp_ok"]),
-    ("Wire formats: hub-row dedup", "dgraph_tpu.wire.dedup",
-     ["HubRow", "RelayTransfer", "DedupPlan", "pair_live_rows",
-      "detect_hub_rows", "build_dedup_plan", "verify_dedup_coverage",
-      "dedup_stats"]),
     ("Plan shard IO & integrity", "dgraph_tpu.plan_shards",
      ["PlanShardWriter", "PlanManifestError", "PlanShardError",
       "PlanBuildMemoryExceeded", "read_manifest", "write_manifest",

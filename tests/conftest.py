@@ -68,6 +68,21 @@ def tpu_interpret(monkeypatch):
     monkeypatch.setattr(pallas, "pallas_call", interpreted)
 
 
+@pytest.fixture
+def compiled_fresh():
+    """A test whose programs are compiled here, none loaded from the
+    persistent compilation cache (and none written to it)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # forget that the cache was in use
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="session")
 def mesh8():
     import jax

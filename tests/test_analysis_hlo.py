@@ -95,6 +95,45 @@ def test_hlo_audit_is_lower_only(workload2):
         cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
 
 
+def test_the_unpinned_train_step_lowers_one_all_to_all_a_leg():
+    """Nothing pinned, no record, a W = 4 plan built without the split
+    (what every trainer and the W = 4 cell build): the lowered train step
+    is the all_to_all one, an all_to_all an exchange leg and not one
+    collective_permute."""
+    import numpy as np
+
+    from dgraph_tpu import config as cfg
+    from dgraph_tpu import plan as pl
+    from dgraph_tpu.analysis.trace import _train_program, workload_from_plan
+
+    W, V = 4, 96
+    rng = np.random.default_rng(0)
+    part = np.sort(rng.integers(0, W, V)).astype(np.int32)
+    plan, _ = pl.build_edge_plan(
+        rng.integers(0, V, size=(2, 600)), part, world_size=W)
+    assert plan.overlap is None and len(plan.halo_deltas) == W - 1
+    w = workload_from_plan(plan, num_nodes=V)
+
+    def lowered_under(pin):
+        cfg.set_flags(halo_impl=pin, tuned_halo_impl=None)
+        return H.lower_program(*_train_program(w))
+
+    saved = (cfg.halo_impl, cfg.tuned_halo_impl)
+    try:
+        unpinned = lowered_under("auto")
+        assert pl.resolve_halo_impl(
+            W, plan.halo_deltas, pair_rows=plan.halo_pair_rows
+        ) == ("all_to_all", "heuristic")
+        pinned = lowered_under("all_to_all")
+    finally:
+        cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
+    coll = H.collect_stablehlo(unpinned)
+    legs = len(H.collect_stablehlo(pinned)["all_to_all"])
+    assert legs > 0 and len(coll["all_to_all"]) == legs
+    assert not coll["collective_permute"]
+    assert unpinned.as_text() == pinned.as_text()
+
+
 # ---------------------------------------------------------------------------
 # vacuity guards: seeded drift must go RED
 # ---------------------------------------------------------------------------
@@ -299,7 +338,8 @@ def test_hlo_drift_record_shape():
     rec = H.hlo_drift_record(2, num_nodes=64, num_edges=256, feat_dim=8)
     assert rec["kind"] == "hlo_drift"
     assert rec["drift"] is False
-    for impl in ("all_to_all", "ppermute", "overlap", "sched"):
+    assert set(rec["train_step_by_impl"]) == set(H.HALO_IMPLS)
+    for impl in H.HALO_IMPLS:
         row = rec["train_step_by_impl"][impl]
         assert row["lowered_bytes"] == row["footprint_bytes"] > 0
     don = rec["donation"]

@@ -215,7 +215,10 @@ def test_spmd_drift_record_shape():
     assert rec["kind"] == "spmd_drift"
     assert rec["drift"] is False
     assert rec["num_halo_deltas"] >= 1
-    for impl in ("all_to_all", "ppermute", "overlap", "sched"):
+    from dgraph_tpu.plan import HALO_IMPLS
+
+    assert set(rec["train_step_by_impl"]) == set(HALO_IMPLS)
+    for impl in HALO_IMPLS:
         row = rec["train_step_by_impl"][impl]
         assert row["identical"] is True
         assert row["num_collectives"] > 0

@@ -174,6 +174,43 @@ def test_torn_trailing_line_skipped_earlier_entries_intact(tmp_path):
     assert len(entries) == n + 1 and len(skips) == 1
 
 
+def test_a_compiled_schedules_record_is_skipped_and_the_rest_ingests(
+        tmp_path):
+    """A ``sched_compile`` artifact, as a run of an earlier tree wrote
+    them, names a lowering no run can take: it is declined with a reason
+    and counted, whether it arrives as an artifact or already lies in the
+    stored file, and everything around it is kept."""
+    d = str(tmp_path)
+    stale = {
+        "kind": "sched_compile",
+        "workload": {"world_size": 4, "nodes": 96, "feat_dim": 8},
+        "schedule_id": "abc123def456", "rounds": 3, "transfers": 5,
+        "operand_bytes_per_shard": 4096, "round_rows": [64, 32, 32],
+        "exposed_us": 7.5,
+    }
+    entries, skips = normalize_record(stale, "old_run.json")
+    assert not entries and len(skips) == 1
+    assert "compiled schedule" in skips[0]["reason"]
+    reports = [
+        ingest(_fixture_bench_round(), "BENCH_r06.json", d),
+        ingest(stale, "old_run.json", d),
+        ingest(regress._fx_wire(0), "wire_r00.json", d),
+    ]
+    assert [r["appended"] > 0 for r in reports] == [True, False, True]
+    assert [len(r["skipped"]) for r in reports] == [0, 1, 0]
+    # a line an earlier tree stored under that kind is read like any other
+    # entry and gates nothing: the rest of the file still checks GREEN
+    atomic_append_jsonl(ledger_path(d), [{
+        "entry_id": "old-sched-0", "kind": "sched_compile",
+        "workload": "w4_n96", "metrics": {"operand_bytes": 4096},
+    }])
+    kept, read_skips = read_ledger(d)
+    assert not read_skips
+    assert summarize(d)["by_kind"]["sched_compile"] == 1
+    assert len(kept) == sum(r["appended"] for r in reports) + 1
+    assert regress.check_ledger(d)["ok"]
+
+
 def test_ledger_dir_knob(tmp_path, monkeypatch):
     monkeypatch.delenv("DGRAPH_LEDGER_DIR", raising=False)
     assert resolve_ledger_dir(default_on=True) == DEFAULT_LEDGER_DIR

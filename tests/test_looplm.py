@@ -255,10 +255,13 @@ def sharded(world, impl, seeded, tokens):
 
 
 @pytest.mark.parametrize("world,impl", [(4, "ring"), (8, "ring"), (4, "ulysses")])
-def test_sharded_model_matches_single_device(world, impl, seeded, tokens):
+def test_sharded_model_matches_single_device(
+        world, impl, seeded, tokens, compiled_fresh):
     """Logits of every pass (rotary positions from the shard's global offset),
     the loss over all T - 1 positions, and every gradient leaf (summed over
-    shards AND over the four uses of each weight)."""
+    shards AND over the four uses of each weight). Compiled fresh: the
+    eight-device CPU executable loaded back from the persistent compilation
+    cache aborts the process or gives a wrong loss."""
     model = build(lm.lm_comm(1))
     want_logits, _ = model.apply(seeded, tokens, jnp.arange(T))
     want, want_g = jax.value_and_grad(

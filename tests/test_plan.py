@@ -202,6 +202,24 @@ class TestPlanEfficiency:
         assert pl.pick_halo_impl(8, (1, 2, 3, 4, 5)) == "all_to_all"
         assert pl.pick_halo_impl(2, (1,)) == "ppermute"
 
+    def test_pick_halo_impl_weighs_row_counts(self):
+        W = 8
+        deltas = (1, 2, 3, 4, 5)  # 5 > W//2: the count-only rule says a2a
+        assert pl.pick_halo_impl(W, deltas) == "all_to_all"
+        # skewed matrix: one pair carries ~all rows -> effectively ONE round
+        # of traffic; the weighted rule must pick ppermute
+        skewed = tuple(
+            tuple(100 if (i, j) == (0, 1) else (1 if i != j else 0)
+                  for j in range(W))
+            for i in range(W)
+        )
+        assert pl.pick_halo_impl(W, deltas, skewed) == "ppermute"
+        # uniform matrix reduces to the count-only rule
+        uniform = tuple(
+            tuple(0 if i == j else 5 for j in range(W)) for i in range(W)
+        )
+        assert pl.pick_halo_impl(W, deltas, uniform) == "all_to_all"
+
     def test_ring_partition_picks_ppermute(self, rng):
         """Locality (block) partition of a ring graph has only deltas {1, W-1}."""
         V, W = 64, 8
@@ -497,6 +515,41 @@ class TestResolveHaloImplLadder:
         assert pl.resolve_halo_impl(8, tuple(range(1, 8))) == (
             "ppermute", "env")
 
+    def test_env_pin_beats_tuned_record(self):
+        self._set(env="ppermute", record="all_to_all")
+        assert pl.resolve_halo_impl(4, (1, 2)) == ("ppermute", "env")
+        self._set(env="all_to_all", record="ppermute")
+        assert pl.resolve_halo_impl(4, (1, 2)) == ("all_to_all", "env")
+
+    @pytest.mark.parametrize("world", [2, 4, 8])
+    @pytest.mark.parametrize("live", ["one_delta", "all_deltas"])
+    @pytest.mark.parametrize("traffic", ["no_matrix", "giant_pair"])
+    def test_unpinned_resolution_is_the_heuristics(self, world, live, traffic):
+        """Nothing pinned, no record: the lowering is ``pick_halo_impl``'s
+        (all_to_all or ppermute, by the live deltas weighed by the pair
+        rows), and ``overlap`` only on a plan built with the split."""
+        self._set()
+        W = world
+        deltas = (1,) if live == "one_delta" else tuple(range(1, W))
+        pair_rows = ()
+        if traffic == "giant_pair":
+            # every live (sender, needer) pair holds one row, one holds 1000
+            pair_rows = tuple(
+                tuple(
+                    1000 if (i, j) == (0, deltas[0] % W)
+                    else int((j - i) % W in deltas)
+                    for j in range(W))
+                for i in range(W)
+            )
+        sparse = len(deltas) <= max(1, W // 2) or traffic == "giant_pair"
+        want = "ppermute" if sparse else "all_to_all"
+        assert pl.pick_halo_impl(W, deltas, pair_rows) == want
+        assert pl.resolve_halo_impl(W, deltas, pair_rows=pair_rows) == (
+            want, "heuristic")
+        assert pl.resolve_halo_impl(
+            W, deltas, overlap_available=True, pair_rows=pair_rows
+        ) == ("overlap", "heuristic")
+
     def test_no_traffic_shortcuts_every_tier(self):
         # an empty delta set means there is nothing to choose: even an
         # explicit env pin reports source='plan'
@@ -544,7 +597,7 @@ class TestResolveHaloImplLadder:
         assert len(warns) == 1, "degrade warning must fire once per source"
         pl._overlap_warned.clear()
 
-    @pytest.mark.parametrize("name", ["alltoall", "one_sided_put"])
+    @pytest.mark.parametrize("name", ["alltoall", "one_sided_put", "sched"])
     def test_unknown_lowering_name_is_refused(self, name):
         """A pin that names no lowering must not fall through to the
         heuristic in silence (a typo, or a lowering this tree no longer
@@ -559,8 +612,9 @@ class TestResolveHaloImplLadder:
         with pytest.raises(ValueError, match="tuned_halo_impl"):
             pl.resolve_halo_impl(8, (1,), overlap_available=True)
 
+    @pytest.mark.parametrize("name", ["one_sided_put", "sched"])
     def test_record_naming_an_unknown_lowering_is_a_lookup_miss(
-        self, tmp_path, monkeypatch, caplog
+        self, tmp_path, monkeypatch, caplog, name
     ):
         """A persisted TuningRecord is a cache file: one that names a
         lowering this tree does not have is ignored with ONE warning
@@ -582,12 +636,12 @@ class TestResolveHaloImplLadder:
         good.save(str(tmp_path))
         assert lookup_record(sig, cache_dir=str(tmp_path)) is not None
         stale = good.to_dict()
-        stale["config"]["halo_impl"] = "one_sided_put"
+        stale["config"]["halo_impl"] = name
         with open(record_path(str(tmp_path), sig), "w") as f:
             json.dump(stale, f)
         with caplog.at_level(logging.WARNING, logger="dgraph_tpu.tune"):
             assert lookup_record(sig, cache_dir=str(tmp_path)) is None
-        warns = [r for r in caplog.records if "one_sided_put" in r.getMessage()]
+        warns = [r for r in caplog.records if name in r.getMessage()]
         assert len(warns) == 1, [r.getMessage() for r in caplog.records]
 
     def test_every_list_of_lowerings_is_the_plans(self):
@@ -615,17 +669,18 @@ class TestResolveHaloImplLadder:
         for name in pl.HALO_IMPLS:
             self._set(env=name)
             assert pl.resolve_halo_impl(
-                4, (1,), overlap_available=True, sched_available=True
+                4, (1,), overlap_available=True
             ) == (name, "env")
             record.TuningRecord.create(
                 {"degree_digest": "x"}, {"halo_impl": name},
                 {"winner_us": 1.0}, "analytic",
             )
-        with pytest.raises(ValueError, match="halo_impl"):
-            record.TuningRecord.create(
-                {"degree_digest": "x"}, {"halo_impl": "one_sided_put"},
-                {"winner_us": 1.0}, "analytic",
-            )
+        for gone in ("one_sided_put", "sched"):
+            with pytest.raises(ValueError, match="halo_impl"):
+                record.TuningRecord.create(
+                    {"degree_digest": "x"}, {"halo_impl": gone},
+                    {"winner_us": 1.0}, "analytic",
+                )
 
     def test_reported_source_reaches_plan_efficiency(self):
         """The deciding source is not just returned — it lands in the
@@ -642,3 +697,44 @@ class TestResolveHaloImplLadder:
         self._set()
         eff = pl.plan_efficiency(plan, layout)
         assert eff["halo_impl_source"] == "heuristic"
+
+
+@pytest.mark.parametrize("impl", pl.HALO_IMPLS)
+def test_halo_wire_rows_are_the_traced_operands_rows(impl, rng):
+    """``halo_wire_rows(plan, impl)`` counts what the traced exchange hands
+    its collectives at W = 4: one ``[W, S, F]`` operand a shard under
+    all_to_all (the block a shard keeps for itself never leaves the chip),
+    one ``[S, F]`` operand a live delta under the round lowerings."""
+    import jax
+    import jax.numpy as jnp
+
+    from dgraph_tpu import config as cfg
+    from dgraph_tpu.analysis.trace import collect_collectives
+    from dgraph_tpu.comm import collectives
+    from dgraph_tpu.comm.mesh import make_graph_mesh
+    from dgraph_tpu.testing import spmd_apply
+
+    W, V, F = 4, 96, 8
+    edges = rng.integers(0, V, size=(2, 600))
+    part = np.sort(rng.integers(0, W, V)).astype(np.int32)
+    plan, _ = pl.build_edge_plan(
+        edges, part, world_size=W, overlap=(impl == "overlap"))
+    S = plan.halo.s_pad
+    mesh = make_graph_mesh(ranks_per_graph=W, devices=jax.devices()[:W])
+    xs = jnp.zeros((W, plan.n_src_pad, F), jnp.float32)
+    saved = (cfg.halo_impl, cfg.tuned_halo_impl)
+    cfg.set_flags(halo_impl=impl, tuned_halo_impl=None)
+    try:
+        jaxpr = jax.make_jaxpr(lambda p, x: spmd_apply(
+            mesh, collectives.gather, p, x, static_args=("src", "graph")
+        ))(plan, xs)
+    finally:
+        cfg.set_flags(halo_impl=saved[0], tuned_halo_impl=saved[1])
+    coll = collect_collectives(jaxpr)
+    family = "all_to_all" if impl == "all_to_all" else "ppermute"
+    other = "ppermute" if family == "all_to_all" else "all_to_all"
+    assert coll[family] and not coll[other]
+    rows_a_shard = sum(
+        int(np.prod(rec["shape"][:-1])) for rec in coll[family])
+    kept_on_chip = S if impl == "all_to_all" else 0
+    assert W * (rows_a_shard - kept_on_chip) == pl.halo_wire_rows(plan, impl)

@@ -477,6 +477,53 @@ def test_fresh_start_deletes_stale_artifact(tmp_path):
     assert not os.path.exists(ps.manifest_path(out))
 
 
+def test_shards_stamped_with_the_last_format_are_rebuilt(tmp_path):
+    """A finished artifact in a fixed out_dir whose manifest says format 12
+    (an ``EdgePlan`` with one more static) is none of this build's
+    progress: every shard is written again, the manifest says the
+    format that wrote it, and the assembled plan is today's class."""
+    import dgraph_tpu.plan_shards as ps
+    from dgraph_tpu.plan import build_edge_plan, build_edge_plan_sharded
+    from dgraph_tpu.train.checkpoint import PLAN_FORMAT_VERSION
+
+    assert PLAN_FORMAT_VERSION == 13
+    edges, part, w = _graph()
+    out = str(tmp_path / "shards")
+    build_edge_plan_sharded(edges, part, out_dir=out, world_size=w)
+    man = ps.read_manifest(out)
+    assert man["format_version"] == PLAN_FORMAT_VERSION and man["complete"]
+    man.pop("manifest_sha256")
+    ps.write_manifest(out, {**man, "format_version": 12})
+    for e in man["shards"].values():  # a rebuilt shard is a new file
+        os.utime(os.path.join(out, e["file"]), (1, 1))
+
+    plan, _ = build_edge_plan_sharded(edges, part, out_dir=out, world_size=w)
+    man2 = ps.read_manifest(out)
+    assert man2["format_version"] == PLAN_FORMAT_VERSION and man2["complete"]
+    for e in man2["shards"].values():
+        assert os.path.getmtime(os.path.join(out, e["file"])) > 1, e["file"]
+    assert not hasattr(plan, "halo_schedule")
+    mono, _ = build_edge_plan(edges, part, world_size=w)
+    _assert_plans_equal(mono, plan)
+
+
+def test_a_plan_cached_under_the_last_format_is_not_loaded(
+        tmp_path, monkeypatch):
+    """The cache key holds the format: what format 12 cached lies under
+    another name, so this tree builds its own and never unpickles it."""
+    from dgraph_tpu.train import checkpoint
+
+    monkeypatch.setattr(checkpoint, "PLAN_FORMAT_VERSION", 12)
+    _cached(tmp_path)
+    old = _plan_dir(tmp_path)
+    monkeypatch.undo()
+    plan, _ = _cached(tmp_path)
+    dirs = sorted(
+        x for x in os.listdir(str(tmp_path)) if x.startswith("plan_"))
+    assert len(dirs) == 2 and os.path.basename(old) in dirs
+    assert not hasattr(plan, "halo_schedule")
+
+
 def test_cached_edge_plan_ignores_use_native(tmp_path, caplog):
     """The v8 cache always streams through the numpy core (the native
     core fills the whole [W, E_pad] stack); an explicit use_native=True

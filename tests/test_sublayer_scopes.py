@@ -99,7 +99,6 @@ def gcn_step_paths(sbm, world: int, impl: str) -> list:
     (2, "all_to_all", "dgraph.halo_exchange"),
     (2, "ppermute", "dgraph.halo_exchange"),
     (4, "overlap", "dgraph.halo_exchange_overlap"),
-    (4, "sched", "dgraph.halo_exchange_sched"),
 ])
 def test_a_gcn_train_step_names_the_work_under_its_scopes(
         flags, sbm, world, impl, exchange):
@@ -112,7 +111,7 @@ def test_a_gcn_train_step_names_the_work_under_its_scopes(
     fwd, bwd = "fwd", "bwd"
     wire = {"wire"} if world > 1 else set()
     assert {"send_gather", "mask"} | wire <= children(paths, exchange, fwd)
-    if impl in ("overlap", "sched"):
+    if impl == "overlap":
         # the pinned VJP is the reverse rounds: their own scatter_add child
         assert {"scatter_add", "mask", "wire"} <= children(
             paths, exchange, bwd)

@@ -1,6 +1,6 @@
 """Wire codec layer (dgraph_tpu.wire): format registry + byte-pricing
-pins, numpy/jax codec parity, the resolution ladder, hub-row dedup
-coverage, and end-to-end parity of compressed halo payloads across the
+pins, numpy/jax codec parity, the resolution ladder, and end-to-end
+parity of compressed halo payloads across the
 halo lowerings — fp32 identity bit-identical (forward AND backward),
 bf16/fp8 within the pinned round-trip bounds on 2- and 4-shard graphs.
 
@@ -381,57 +381,6 @@ def test_delta_skip_accounting_matches_plan(rng):
 
 
 # ---------------------------------------------------------------------------
-# hub-row dedup: verified coverage on a real plan's send tables (pure)
-# ---------------------------------------------------------------------------
-
-
-def test_dedup_star_graph_verified_coverage(rng):
-    """A star graph concentrates demand on vertex 0's row: the dedup
-    pass must find the hub, cut the owner's egress to one direct send,
-    and the relay structure must still deliver every original
-    (needer, src, row) demand exactly once."""
-    from dgraph_tpu.wire.dedup import (
-        build_dedup_plan,
-        dedup_stats,
-        detect_hub_rows,
-        verify_dedup_coverage,
-    )
-
-    V, E, W = 16, 64, 4
-    edges = np.stack([np.zeros(E, np.int64), rng.integers(0, V, E)])
-    part = np.sort(rng.integers(0, W, V)).astype(np.int32)
-    plan, _ = pl.build_edge_plan(edges, part, world_size=W, edge_owner="dst")
-    send_idx = np.asarray(plan.halo.send_idx)
-    send_mask = np.asarray(plan.halo.send_mask)
-    hubs = detect_hub_rows(send_idx, send_mask)
-    assert hubs, "star graph must surface at least one hub row"
-    assert max(len(h.needers) for h in hubs) >= 2
-    dplan = build_dedup_plan(send_idx, send_mask, s_pad=plan.halo.s_pad)
-    assert verify_dedup_coverage(dplan, send_idx, send_mask) == []
-    stats = dedup_stats(dplan, send_idx, send_mask)
-    assert stats["owner_egress_rows_saved"] > 0
-    assert stats["relay_rows"] == stats["owner_egress_rows_saved"]
-
-
-def test_dedup_identity_on_hubless_traffic():
-    """Pairwise-unique traffic: no hubs, no relays, and the direct
-    schedule covers the ORIGINAL matrix untouched."""
-    from dgraph_tpu.wire.dedup import build_dedup_plan, verify_dedup_coverage
-
-    W, S = 4, 3
-    send_idx = np.zeros((W, W, S), np.int32)
-    send_mask = np.zeros((W, W, S), np.float32)
-    for s in range(W):
-        for d in range(W):
-            if s != d:
-                send_idx[s, d] = [10 * s + 2 * d, 10 * s + 2 * d + 1, 0]
-                send_mask[s, d] = [1, 1, 0]
-    dplan = build_dedup_plan(send_idx, send_mask, s_pad=S)
-    assert not dplan.hubs and not dplan.relay_rounds
-    assert verify_dedup_coverage(dplan, send_idx, send_mask) == []
-
-
-# ---------------------------------------------------------------------------
 # analysis tiers under pinned formats (compile-free: trace + lower only)
 # ---------------------------------------------------------------------------
 
@@ -535,7 +484,6 @@ def wire_case(request):
     plan, layout = pl.build_edge_plan(
         edges, part, world_size=W, overlap=True
     )
-    assert plan.halo_schedule is not None
     mesh = make_graph_mesh(ranks_per_graph=W, num_replicas=8 // W)
     return W, edges, part, plan, layout, mesh
 
@@ -587,10 +535,9 @@ def test_bf16_forward_parity_across_lowerings(wire_case, wire_flags):
     x = rng.normal(size=(len(part), 6)).astype(np.float32)
     xs = jnp.asarray(shard_vertex_data(x, layout.src_counts, plan.n_src_pad))
     out = {impl: _gather_once(mesh, plan, xs, fmt="bf16", impl=impl)
-           for impl in ("all_to_all", "ppermute", "overlap", "sched")}
+           for impl in pl.HALO_IMPLS}
     base = out["all_to_all"]
-    for impl in ("overlap", "sched"):
-        assert (out[impl] == base).all(), f"{impl} differs from all_to_all"
+    assert (out["overlap"] == base).all(), "overlap differs from all_to_all"
     np.testing.assert_allclose(out["ppermute"], base, rtol=1e-6, atol=1e-6)
     err = _rel_err(unshard_edge_data(base, layout),
                    dense_gather(x, edges, "src"))
@@ -598,7 +545,7 @@ def test_bf16_forward_parity_across_lowerings(wire_case, wire_flags):
 
 
 @requires_fp8
-def test_fp8_forward_parity_sched_vs_a2a(wire_case, wire_flags):
+def test_fp8_forward_parity_overlap_vs_a2a(wire_case, wire_flags):
     W, edges, part, plan, layout, mesh = wire_case
     if W != 2:
         pytest.skip("the fp8 cross-lowering pin runs once, on 2 shards")
@@ -606,8 +553,8 @@ def test_fp8_forward_parity_sched_vs_a2a(wire_case, wire_flags):
     x = rng.normal(size=(len(part), 6)).astype(np.float32)
     xs = jnp.asarray(shard_vertex_data(x, layout.src_counts, plan.n_src_pad))
     a2a = _gather_once(mesh, plan, xs, fmt="fp8", impl="all_to_all")
-    sched = _gather_once(mesh, plan, xs, fmt="fp8", impl="sched")
-    assert (sched == a2a).all(), "sched fp8 payload differs from all_to_all"
+    got = _gather_once(mesh, plan, xs, fmt="fp8", impl="overlap")
+    assert (got == a2a).all(), "overlap fp8 payload differs from all_to_all"
 
 
 def _gather_grad_once(mesh, plan, xs, ct_sh, *, fmt, impl):
@@ -656,11 +603,10 @@ def test_bf16_grad_parity_across_lowerings(wire_case, wire_flags):
     ct_sh = jnp.asarray(shard_edge_data(ct, layout, plan.e_pad))
     grads = {impl: _gather_grad_once(mesh, plan, xs, ct_sh,
                                      fmt="bf16", impl=impl)
-             for impl in ("all_to_all", "overlap", "sched")}
-    for impl in ("overlap", "sched"):
-        assert (grads[impl] == grads["all_to_all"]).all(), (
-            f"{impl} bf16 backward differs from all_to_all"
-        )
+             for impl in ("all_to_all", "overlap")}
+    assert (grads["overlap"] == grads["all_to_all"]).all(), (
+        "overlap bf16 backward differs from all_to_all"
+    )
 
 
 def test_config_flip_cannot_recompile_a_served_program(
