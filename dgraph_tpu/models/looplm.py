@@ -118,6 +118,33 @@ no bias, no positional encoding, an untied head), with:
   form, a dense FFN on every token, which every chip of a deployment computes
   alike (a sum over shares counts it once).
 
+A sparse-expert decoder whose ROUTER READS THE LAYER'S INPUT, with plain
+grouped-query attention under a window beside full layers without positions
+(PowerInfer SmallThinker; kinds ``attn+experts`` and ``attn_win+experts``,
+pre-norm RMSNorm at eps 1e-6, no bias, no q/k norm, no shared expert, an
+untied head):
+
+- layer with input ``x``: ``r = W_r x`` over all ``n_total`` experts, float32,
+  of the stream ITSELF (no norm before the router: ``HeldExperts.router_reads
+  = "layer_input"``); the ``k`` largest logits are chosen; gates ``g =
+  softmax`` over the ``k`` chosen logits (= the softmax over all divided by
+  the chosen ones' sum: :func:`route_topk` with renormalisation); ``h = x +
+  Attn(RMSNorm1(x))``; ``y = h + sum over the chosen experts held here of g_e
+  W_down,e (relu(W_gate,e u) * W_up,e u)``, ``u = RMSNorm2(h)`` (ReGLU:
+  ``HeldExperts.form = "gated_relu"``). The layer routes at its top and hands
+  the gates and the chosen experts past the mixer to the experts' half, so
+  the router's gradient joins the residual at the layer's input; what the
+  placement is FOR (experts fetched, or an exchange issued, while attention
+  runs) exists only where something is fetched or exchanged (ROADMAP R9);
+- ``attn_win``, plain attention under a window: ``q, k, v`` as ``attn``'s,
+  the rotary embedding on q and k, then exact softmax over the keys ``j``
+  with ``i - window < j <= i`` (the query's own position and the ``window -
+  1`` before it: ``WindowMask``), scope ``dgraph.lm.attn_win`` around the
+  call; and positions BY KIND: with ``full_attn_rope=False`` an ``attn``
+  layer of the same stack takes NO positional encoding and attends the whole
+  causal prefix, while the windowed layers take the table (``rope_theta=None``
+  keeps meaning that no layer has positions).
+
 Everything but attention, the convolutions' halo and the scan's state is
 token-local, so those are the only communication. Parameters are float32;
 matmuls run in ``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
@@ -173,18 +200,27 @@ class HeldExperts:
     routed experts of width ``width`` (ids ``first_held ...``), ``k`` a
     token with their gates renormalised over the ``k`` chosen. ``rows``
     bounds the buffer of rows routed here (None: the worst case, which can
-    drop nothing); a row past it is dropped and counted. Independent of the
-    objective: a causal model takes expert layers as a block-diffusion one
-    does. The router's form (``parallel.expert.route_topk``): ``score``
+    drop nothing); a row past it is dropped and counted; ``ladder`` False
+    takes no smaller rung under it (``parallel.expert.buffer_ladder``: every
+    layer-step then costs the one buffer's rows, whatever was routed here;
+    every rung computes the same sums). Independent of
+    the objective: a causal model takes expert layers as a block-diffusion
+    one does. The router's form (``parallel.expert.route_topk``): ``score``
     ``"softmax"`` or ``"sigmoid"``; ``select_bias``: a ``[n_total]`` leaf
     ``select_bias`` added to the scores for the choice only (no gradient, no
     optimizer update); ``gate_eps`` added to the chosen gates' sum (None: the
     softmax form's guard); ``gate_scale`` on the normalised gates. ``form``:
-    an expert is ``"gated_silu"`` (leaves ``gate_proj``, ``up_proj``,
-    ``down_proj``) or ``"relu2"`` (``W_down relu(W_up x)^2``: no
-    ``gate_proj``); ``shared_width`` > 0: one shared expert of that width and
-    the same form on every token, added to the held part (leaves
-    ``shared_up_proj``, ``shared_down_proj`` and, gated, ``shared_gate_proj``)."""
+    an expert is ``"gated_silu"`` or ``"gated_relu"`` (ReGLU; leaves
+    ``gate_proj``, ``up_proj``, ``down_proj``) or ``"relu2"`` (``W_down
+    relu(W_up x)^2``: no ``gate_proj``); ``shared_width`` > 0: one shared
+    expert of that width and the same form on every token, added to the held
+    part (leaves ``shared_up_proj``, ``shared_down_proj`` and, gated,
+    ``shared_gate_proj``). ``router_reads``, the router's place
+    (``ROUTER_READS``): ``"ffn_input"``, what the experts multiply, the
+    float32 output of the norm before them; or ``"layer_input"``, the
+    residual stream as it reaches the layer, un-normed, before the mixer
+    (SmallThinker's: the layer then routes at its top and hands the gates
+    and the chosen experts past the mixer to the experts)."""
 
     n_total: int
     n_held: int
@@ -198,11 +234,15 @@ class HeldExperts:
     gate_scale: float = 1.0
     form: str = "gated_silu"
     shared_width: int = 0
+    router_reads: str = "ffn_input"
+    ladder: bool = True
 
 
 # Leaves of the parameter tree, by name, that are buffers: no gradient
 # reaches them and the trainer's step zeroes their update (train/lm.py).
 FROZEN_LEAVES = ("select_bias",)
+# What an expert layer's router reads (``HeldExperts.router_reads``).
+ROUTER_READS = ("ffn_input", "layer_input")
 
 
 class _Kernel(nn.Module):
@@ -223,51 +263,65 @@ class HeldExpertsFFN(nn.Module):
     float32, stats)``; scope ``dgraph.lm.moe`` with ``router``, ``routes``,
     ``dispatch``, ``experts``, ``combine`` and, with a shared expert,
     ``shared`` (a dense FFN on this shard's own tokens, added after
-    ``combine``)."""
+    ``combine``). One routing block for both places of the router: called
+    with no ``routes`` the layer routes from the tensor its experts multiply;
+    ``route_only`` routes from whatever it is handed and returns ``(gates
+    [T, k] float32, experts [T, k] int32)``, for a caller that hands them
+    back as ``routes`` with the experts' input later."""
 
     spec: HeldExperts
     comm: Any
     dtype: Any = None
 
     @nn.compact
-    def __call__(self, x32):
+    def __call__(self, x32, routes=None, route_only: bool = False):
         from dgraph_tpu.parallel.expert import held_experts_ffn, route_topk
 
         sp, d = self.spec, x32.shape[-1]
         with jax.named_scope("dgraph.lm.moe"):
-            with jax.named_scope("router"):
-                # float32 end to end: the k-th and (k+1)-th probabilities of
-                # a row can lie within a bf16 rounding of each other
-                logits = nn.Dense(
-                    sp.n_total, use_bias=False, dtype=jnp.float32,
-                    precision=jax.lax.Precision.HIGHEST, name="router")(x32)
-                bias = self.param(
-                    "select_bias", nn.initializers.zeros,
-                    (sp.n_total,)) if sp.select_bias else None
-                gates, experts = route_topk(
-                    logits, sp.k, score=sp.score, select_bias=bias,
-                    eps=sp.gate_eps, scale=sp.gate_scale)
-                # for a caller that asks (mutable=["intermediates"]): which
-                # experts each row chose, to set beside a reference's
-                self.sow("intermediates", "chosen", experts)
+            if routes is None:
+                with jax.named_scope("router"):
+                    # float32 end to end: the k-th and (k+1)-th probabilities
+                    # of a row can lie within a bf16 rounding of each other
+                    logits = nn.Dense(
+                        sp.n_total, use_bias=False, dtype=jnp.float32,
+                        precision=jax.lax.Precision.HIGHEST,
+                        name="router")(x32)
+                    bias = self.param(
+                        "select_bias", nn.initializers.zeros,
+                        (sp.n_total,)) if sp.select_bias else None
+                    routes = route_topk(
+                        logits, sp.k, score=sp.score, select_bias=bias,
+                        eps=sp.gate_eps, scale=sp.gate_scale)
+                    # for a caller that asks (mutable=["intermediates"]):
+                    # which experts each row chose, to set beside a
+                    # reference's
+                    self.sow("intermediates", "chosen", routes[1])
+            if route_only:
+                return routes
+            gates, experts = routes
             x = x32.astype(self.dtype)
-            gated = sp.form == "gated_silu"
+            gated = sp.form != "relu2"
             out, stats = held_experts_ffn(
                 x, gates, experts,
                 _Kernel((sp.n_held, d, sp.width), name="gate_proj")()
                 if gated else None,
                 _Kernel((sp.n_held, d, sp.width), name="up_proj")(),
                 _Kernel((sp.n_held, sp.width, d), name="down_proj")(),
-                n_total=sp.n_total, first_held=sp.first_held, rows=sp.rows,
+                n_total=sp.n_total if sp.ladder else None,
+                first_held=sp.first_held, rows=sp.rows,
                 axis_name=self.comm.graph_axis, form=sp.form)
             if sp.shared_width:
                 with jax.named_scope("shared"):
                     dense = functools.partial(
                         nn.Dense, use_bias=False, dtype=self.dtype)
                     u = dense(sp.shared_width, name="shared_up_proj")(x)
-                    mid = nn.silu(dense(sp.shared_width,
-                                        name="shared_gate_proj")(x)) * u \
-                        if gated else jnp.square(nn.relu(u))
+                    if sp.form == "relu2":
+                        mid = jnp.square(nn.relu(u))
+                    else:
+                        act = nn.relu if sp.form == "gated_relu" else nn.silu
+                        mid = act(dense(sp.shared_width,
+                                        name="shared_gate_proj")(x)) * u
                     out = out + dense(d, name="shared_down_proj")(mid)
             return out, stats
 
@@ -508,10 +562,11 @@ class GatedMemoryUnit(nn.Module):
 
 
 LAYER_MIXERS = ("attn", "conv", "ssm", "ssm_keep", "gmu", "diff_win",
-                "diff_keep", "cross", "ssd", "none")
+                "diff_keep", "cross", "ssd", "none", "attn_win")
 LAYER_FFNS = ("dense", "experts", "none")  # "none": the half is not there
 DIFF_MIXERS = ("diff_win", "diff_keep", "cross")  # differential attention
-ATTENDING = ("attn",) + DIFF_MIXERS
+ATTENDING = ("attn", "attn_win") + DIFF_MIXERS
+WINDOWED = ("diff_win", "attn_win")  # attend under ``window`` keys
 KEEPS = {"ssm_keep": "m", "diff_keep": "kv"}  # mixer -> what it keeps
 READS = {"gmu": "m", "cross": "kv"}  # mixer -> what it reads of the kept
 
@@ -559,11 +614,12 @@ class LoopLMLayer(nn.Module):
     norm: str = "rms"  # or "layer": LayerNorm with gain and bias
     attn_bias: bool = False  # a bias on attention's projections
     fused_mlp: bool = False  # one W_1 for the gate and the value
-    window: int = 0  # keys a query of a "diff_win" layer sees
+    window: int = 0  # keys a query of a "diff_win" / "attn_win" layer sees
     ssm: Optional[StateSpace] = None
     depth: int = 0  # the published index of the layer (differential attention)
     ssd: Optional[Mamba2Mixer] = None
     has_ffn: bool = True  # False: the mixer alone ("<mixer>+none")
+    full_attn_rope: bool = True  # False: an "attn" layer takes no positions
 
     @nn.compact
     def __call__(self, h, rope, kept=None):  # [T_loc, hidden], (cos, sin)
@@ -578,7 +634,14 @@ class LoopLMLayer(nn.Module):
                                      dtype=dt)
         post = (lambda name: norm(name=name)) if self.sandwich_norm \
             else (lambda name: lambda y: y.astype(h.dtype))
-        keep = None
+        keep = experts = routes = None
+        if self.experts is not None and self.has_ffn:
+            experts = HeldExpertsFFN(self.experts, self.comm, dt,
+                                     name="experts")
+            if self.experts.router_reads == "layer_input":
+                # the stream itself, un-normed, before the mixer: float32
+                # as the router is end to end
+                routes = experts(h.astype(jnp.float32), route_only=True)
         if self.mixer == "conv":
             a = GatedShortConv(self.conv_kernel, self.comm, dt, name="conv")(
                 norm(name="norm_conv_in")(h))
@@ -598,9 +661,11 @@ class LoopLMLayer(nn.Module):
         elif self.mixer in DIFF_MIXERS:
             h, keep = self.differ(h, kept, dt, dense, norm, post)
         elif self.mixer != "none":
+            if self.mixer == "attn" and not self.full_attn_rope:
+                rope = None
             h = self.attend(h, rope, dense, norm, post)
-        h, stats = self.ffn(h, dt, dense, norm, post) if self.has_ffn \
-            else (h, None)
+        h, stats = self.ffn(h, dense, norm, post, experts, routes) \
+            if self.has_ffn else (h, None)
         return h, ((stats, keep) if self.mixer in KEEPS else stats)
 
     def attend(self, h, rope, dense, norm, post):
@@ -623,6 +688,15 @@ class LoopLMLayer(nn.Module):
             a = self.comm.seq_attention(
                 q, k, v, impl=self.attn_impl,
                 mask=BlockDiffusionMask(n // 2, self.block_length))
+        elif self.mixer == "attn_win":
+            from dgraph_tpu.parallel.sequence import WindowMask
+
+            # a scope of its own around the call, so that a trace tells the
+            # windowed layers' attention from the full layers'
+            with jax.named_scope("dgraph.lm.attn_win"):
+                a = self.comm.seq_attention(
+                    q, k, v, impl=self.attn_impl,
+                    mask=WindowMask(n, self.window))
         else:
             a = self.comm.seq_attention(q, k, v, causal=True,
                                         impl=self.attn_impl)
@@ -675,12 +749,11 @@ class LoopLMLayer(nn.Module):
         return h + post("norm_attn_out")(a), (
             (k, v) if self.mixer == "diff_keep" else None)
 
-    def ffn(self, h, dt, dense, norm, post):
-        if self.experts is not None:
+    def ffn(self, h, dense, norm, post, experts, routes):
+        if experts is not None:
             u32 = RMSNorm(epsilon=self.rms_eps, dtype=jnp.float32,
                           name="norm_mlp_in")(h)
-            m, stats = HeldExpertsFFN(self.experts, self.comm, dt,
-                                      name="experts")(u32)
+            m, stats = experts(u32, routes)
             return h + post("norm_mlp_out")(m), stats
         u = norm(name="norm_mlp_in")(h)
         if self.fused_mlp:
@@ -833,6 +906,9 @@ class LoopLM(nn.Module):
     ssm: Optional[StateSpace] = None
     first_depth: int = 0  # the published index of the stack's first layer
     ssd: Optional[Mamba2Mixer] = None
+    # False: the full-attention ("attn") layers carry no positional encoding
+    # and only the windowed ("attn_win") ones take the rotary table
+    full_attn_rope: bool = True
 
     def layer_kinds(self) -> tuple:
         """The kind of each of the ``num_layers`` layers, in stack order."""
@@ -859,9 +935,16 @@ class LoopLM(nn.Module):
             if "ssd" in mixers and self.ssd is None:
                 raise ValueError("Mamba-2 layers in the pattern need `ssd`, "
                                  "their sizes")
-            if "diff_win" in mixers and self.window < 1:
+            if mixers & set(WINDOWED) and self.window < 1:
                 raise ValueError("windowed layers in the pattern need "
                                  "`window`")
+            if "attn_win" in mixers and self.block_length:
+                raise ValueError("a windowed layer under block diffusion: "
+                                 "one structured mask a layer")
+        if self.experts is not None \
+                and self.experts.router_reads not in ROUTER_READS:
+            raise ValueError(f"router_reads {self.experts.router_reads!r}: "
+                             f"one of {ROUTER_READS}")
         dt = _cfg.resolve_compute_dtype(self.dtype)
         self.embed = nn.Embed(self.vocab, self.hidden_size, dtype=dt)
         layer = dict(
@@ -873,7 +956,8 @@ class LoopLM(nn.Module):
             experts=self.experts, block_length=self.block_length,
             conv_kernel=self.conv_kernel, norm=self.norm,
             attn_bias=self.attn_bias, fused_mlp=self.fused_mlp,
-            window=self.window, ssm=self.ssm, ssd=self.ssd)
+            window=self.window, ssm=self.ssm, ssd=self.ssd,
+            full_attn_rope=self.full_attn_rope)
         # the same parameters every pass: broadcast, not split
         loop = nn.scan(
             LoopPass, variable_broadcast="params",
@@ -906,15 +990,16 @@ class LoopLM(nn.Module):
         return BlockDiffusionMask(seq_len, self.block_length)
 
     def attention_masks(self, seq_len: int):
-        """Of a stack with differential attention, the mask of each attending
-        layer in stack order (``WindowMask`` or ``CausalMask`` objects); None
-        for every other stack (one mask: :meth:`attention_mask`)."""
+        """Of a stack with differential attention or a windowed layer, the
+        mask of each attending layer in stack order (``WindowMask`` or
+        ``CausalMask`` objects); None for every other stack (one mask:
+        :meth:`attention_mask`)."""
         from dgraph_tpu.parallel.sequence import CausalMask, WindowMask
 
         mixers = [split_kind(kind)[0] for kind in self.layer_kinds()]
-        if not set(mixers) & set(DIFF_MIXERS):
+        if not set(mixers) & set(DIFF_MIXERS + WINDOWED):
             return None
-        return [WindowMask(seq_len, self.window) if m == "diff_win"
+        return [WindowMask(seq_len, self.window) if m in WINDOWED
                 else CausalMask(seq_len) for m in mixers if m in ATTENDING]
 
     def logits(self, h):

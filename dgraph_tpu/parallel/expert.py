@@ -20,11 +20,11 @@ choice and not the gate, ``g_e = scale * s_e / (sum of the chosen s + eps)``
 Two layers stand on that (:func:`held_experts_apply`):
 
 - :func:`held_experts_ffn`, the FFN of a sparse-expert model at published
-  sizes in either of two forms (gated SiLU, three products an expert; ungated
-  squared ReLU, two), the held experts' rows multiplied as groups
-  (:func:`grouped_matmul`). With no mesh axis its partial sum is the chip's
-  share of an expert-parallel deployment (what the absent experts would add
-  is left out); with an axis the shares of the ranks are summed.
+  sizes in any of three forms (gated SiLU or gated ReLU, three products an
+  expert; ungated squared ReLU, two), the held experts' rows multiplied as
+  groups (:func:`grouped_matmul`). With no mesh axis its partial sum is the
+  chip's share of an expert-parallel deployment (what the absent experts
+  would add is left out); with an axis the shares of the ranks are summed.
 - :func:`moe_apply`, one expert of any form a rank of an axis (k = 1 switch
   routing, k >= 2 GShard/Mixtral mixtures): the ``n_held = 1`` case.
 
@@ -338,6 +338,10 @@ def buffer_rows(tokens: int, k: int, n_held: int,
 # layer-steps falling through to the worst case. Two rungs below the last
 # cost the cells 5-6 % of a warm ``setup_s`` with the rungs' bodies traced
 # once (``_shared``), 10-17 % with every layer tracing its own.
+# smallthinker_21b_a3b.seq16k takes none (``HeldExperts.ladder`` False, the
+# one buffer): seeded routers over 8 of 64 experts send 1.0-1.6 x of 12 288
+# rows and up to 5 x by the end of a window, so a seed's layers pass any rung
+# at steps of their own and the step's mean follows the seed (PERF.md, PR 46).
 LADDER_FACTORS = (1.5, 3.0)
 
 
@@ -518,9 +522,10 @@ def held_experts_apply(
     return out, stats
 
 
-# The forms of an expert (and of the shared expert beside them):
-# W_down (silu(W_gate x) * W_up x), and W_down relu(W_up x)^2 with no gate.
-EXPERT_FORMS = ("gated_silu", "relu2")
+# The forms of an expert (and of the shared expert beside them): the gated
+# ones, W_down (act(W_gate x) * W_up x) with act silu or relu (ReGLU), and
+# W_down relu(W_up x)^2 with no gate.
+EXPERT_FORMS = ("gated_silu", "relu2", "gated_relu")
 
 
 def _ffn_rows(form, kernels, xs, routes):
@@ -536,9 +541,10 @@ def _ffn_rows(form, kernels, xs, routes):
     w_gate, w_up, w_down = kernels
     if form == "relu2":
         return product(jnp.square(jax.nn.relu(product(xs, w_up))), w_down)
+    act = jax.nn.relu if form == "gated_relu" else jax.nn.silu
     g = product(xs, w_gate)
     u = product(xs, w_up)
-    return product(jax.nn.silu(g) * u, w_down)
+    return product(act(g) * u, w_down)
 
 
 # One function object a form: the layers of a stack hand the ladder the SAME
@@ -563,7 +569,8 @@ def held_experts_ffn(
     """The held experts' part of a sparse FFN over the buffer
     (:func:`held_experts_apply`, :func:`grouped_matmul`), expert e's row for
     a token being, by ``form``: ``"gated_silu"``, ``W_down,e (silu(W_gate,e
-    x_t) * W_up,e x_t)``, three grouped products; ``"relu2"``, ``W_down,e
+    x_t) * W_up,e x_t)``, three grouped products; ``"gated_relu"`` (ReGLU),
+    the same with ``relu`` in ``silu``'s place; ``"relu2"``, ``W_down,e
     relu(W_up,e x_t)^2``, two, and no ``w_gate``. The gradient reaches the
     router through ``gates``. ``n_total``, the experts routed over, sizes the
     buffer's ladder (:func:`buffer_ladder`); None keeps the one buffer."""

@@ -440,11 +440,12 @@ def layers_by_kind(kinds) -> dict:
     """How many of a stack's layers are of which kind (``models.looplm``'s
     ``"<mixer>+<ffn>"``): ``conv``, ``attention`` (every attending mixer),
     ``dense_ffn``, ``expert_ffn``, and, where the stack has any, ``ssm``,
-    ``gmu``, ``window`` (attention under a window), ``cross`` (attention
+    ``gmu``, ``window`` (attention under a window, differential or plain),
+    ``attn_win`` (the plain ones of those), ``cross`` (attention
     on another layer's keys and values), ``ssd`` (Mamba-2 mixers) and the
     layers of one half: ``mixer_only`` (``"<mixer>+none"``) and
     ``experts_only`` (``"none+experts"``)."""
-    from dgraph_tpu.models.looplm import ATTENDING, split_kind
+    from dgraph_tpu.models.looplm import ATTENDING, WINDOWED, split_kind
 
     mixers = [split_kind(k)[0] for k in kinds]
     count = lambda *names: sum(m in names for m in mixers)
@@ -452,8 +453,8 @@ def layers_by_kind(kinds) -> dict:
            "dense_ffn": sum(k.endswith("+dense") for k in kinds),
            "expert_ffn": sum(k.endswith("+experts") for k in kinds)}
     more = {"ssm": count("ssm", "ssm_keep"), "gmu": count("gmu"),
-            "window": count("diff_win"), "cross": count("cross"),
-            "ssd": count("ssd"),
+            "window": count(*WINDOWED), "attn_win": count("attn_win"),
+            "cross": count("cross"), "ssd": count("ssd"),
             "mixer_only": sum(k.endswith("+none") for k in kinds),
             "experts_only": sum(k == "none+experts" for k in kinds)}
     out.update({k: n for k, n in more.items() if n})
@@ -549,11 +550,15 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     and ``setup.init_opt_state``; counters ``lm.*`` (the layers by kind:
     ``lm.layers.conv / .attention / .dense_ffn / .expert_ffn``, and where the
     stack has them ``.ssm / .gmu / .window / .cross`` with ``lm.ssm.state /
-    .inner / .chunk``, ``lm.attention.window / .v_head_dim``, ``.ssd /
-    .mixer_only / .experts_only`` with ``lm.ssd.heads / .groups / .state /
-    .chunk``; ``moe.shared_width`` beside the ``moe.*`` of a shared expert); a stack of
-    several masks (differential attention under a window and full) has each
-    self-checked, and ``attn.mask_pairs / .tile_pairs`` summed over them."""
+    .inner / .chunk``, ``lm.attention.window / .v_head_dim``, ``.attn_win``
+    with ``lm.attention.nope_layers`` (full-attention layers that take no
+    positions beside windowed ones that do), ``.ssd / .mixer_only /
+    .experts_only`` with ``lm.ssd.heads / .groups / .state / .chunk``;
+    ``moe.shared_width`` beside the ``moe.*`` of a shared expert,
+    ``moe.route_ahead_layers`` where the router reads its layer's input); a
+    stack of several masks (attention under a window and full, differential
+    or plain) has each self-checked, and ``attn.mask_pairs / .tile_pairs``
+    summed over them."""
     world = comm.get_world_size()
     if seq_len % world:
         raise ValueError(f"seq_len {seq_len} does not divide by world {world}")
@@ -566,16 +571,21 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     masks = model.attention_masks(seq_len) \
         if hasattr(model, "attention_masks") else None
     group = heads // (getattr(model, "num_kv_heads", None) or heads)
+    v_head_dim = None
     if by_kind is not None and not by_kind["attention"]:
         attention = "none"  # a stack of convolutions attends nowhere
-    elif masks:  # differential attention: a map's values are [v1 ; v2]
+    elif masks:  # a mask a layer: each distinct one is resolved, in order
+        from dgraph_tpu.models.looplm import DIFF_MIXERS, split_kind
         from dgraph_tpu.parallel.sequence import CausalMask
 
+        # differential attention: a map's values are [v1 ; v2]
+        v_head_dim = 2 * head_dim if any(
+            split_kind(k)[0] in DIFF_MIXERS for k in kinds) else None
         attention = "/".join(dict.fromkeys(
             resolve_attention(
                 comm, model.attn_impl, seq_len // world, heads, head_dim,
                 None if isinstance(m, CausalMask) else m, group,
-                v_head_dim=2 * head_dim)
+                v_head_dim=v_head_dim)
             for m in dict.fromkeys(masks)))
     else:
         attention = resolve_attention(
@@ -629,16 +639,23 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
                                      model.ssd.chunk or SSD_CHUNK)
         if by_kind.get("window"):
             default_registry.counter("lm.attention.window", model.window)
-        if masks:
-            default_registry.counter("lm.attention.v_head_dim", 2 * head_dim)
+        if v_head_dim:
+            default_registry.counter("lm.attention.v_head_dim", v_head_dim)
+        if by_kind.get("attn_win") and not model.full_attn_rope:
+            nope = sum(k.startswith("attn+") for k in kinds)
+            startup["nope_layers"] = nope
+            default_registry.counter("lm.attention.nope_layers", nope)
         expert_layers = by_kind["expert_ffn"]
     if mask is not None:
         masks = [mask]
     if masks:  # pairs the masks allow / pairs in the tiles visited
         from dgraph_tpu.parallel.sequence import flash_tile
 
-        rows = masks[0].rows
-        tile = flash_tile(rows) if attention == "splash" else rows
+        rows = masks[0].rows  # a kernel path skips whole tiles, the dense
+        # oracle none (a stack may take the flash kernel under its causal
+        # mask and the splash kernels under its window)
+        tile = flash_tile(rows) if set(attention.split("/")) <= {
+            "splash", "flash"} else rows
         startup.update(
             attention_mask="+".join(dict.fromkeys(m.name for m in masks)),
             mask_pairs=sum(m.pairs() for m in masks),
@@ -664,6 +681,9 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         if experts.shared_width:
             startup["moe_shared_width"] = experts.shared_width
             default_registry.counter("moe.shared_width", experts.shared_width)
+        if experts.router_reads == "layer_input":
+            startup["moe_route_ahead_layers"] = expert_layers
+            default_registry.counter("moe.route_ahead_layers", expert_layers)
     kw = dict(seq_len=seq_len, beta=beta, loss_block=loss_block,
               param_specs=specs)
     return LMTrainer(

@@ -4,6 +4,8 @@ layer takes, its output, every gradient and its counts are the one buffer's at
 the same bound; the ladder's own derivative holds nothing of a rung not taken;
 a ladder of one rung is the one buffer's program."""
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -19,7 +21,7 @@ from dgraph_tpu.parallel import expert as ex
 # sorted whatever the rung, so no shape below is 128 rows by accident).
 T, K, N, HELD, FIRST, D, F = 64, 4, 32, 2, 4, 16, 24
 LADDER = (24, 48, 128)
-FORMS = ("gated_silu", "relu2")
+FORMS = ("gated_silu", "relu2", "gated_relu")
 
 
 @pytest.fixture
@@ -115,6 +117,50 @@ def test_one_factor_is_two_rungs(monkeypatch):
     layer("relu2", chosen(10), N)  # traces: a two-way switch
 
 
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("rows_here,rows,rung", [
+    (10, None, 24), (100, None, 128), (70, 64, 64)])
+def test_a_model_without_the_ladder_runs_the_one_buffer(
+        small_tile, form, rows_here, rows, rung):
+    """``HeldExperts.ladder`` False (smallthinker_21b_a3b's cell: its layers'
+    rows lie astride any rung, by seed and by step): the experts' module
+    does not tell the layer the total, so the layer traces the one buffer's
+    program, no conditional, whatever was routed here; the laddered module
+    beside it takes a rung and computes the same."""
+    from dgraph_tpu.models import looplm
+    from dgraph_tpu.train import lm
+
+    x, gates, kernels, cot = inputs(form)
+    experts = chosen(rows_here)
+    names = ("up_proj", "down_proj") if form == "relu2" \
+        else ("gate_proj", "up_proj", "down_proj")
+
+    def f(x, gates, kernels, ladder):
+        mod = looplm.HeldExpertsFFN(looplm.HeldExperts(
+            N, HELD, K, F, first_held=FIRST, rows=rows, form=form,
+            ladder=ladder), lm.lm_comm(1), jnp.float32)
+        out, stats = mod.apply({"params": {
+            n: {"kernel": w} for n, w in zip(names, kernels[-len(names):])}},
+            x, (gates, experts))
+        return (out * cot).sum(), (out, stats)
+
+    def run(ladder):
+        g = jax.value_and_grad(functools.partial(f, ladder=ladder),
+                               argnums=(0, 1, 2), has_aux=True)
+        (_, (out, stats)), grads = jax.jit(g)(x, gates, kernels)
+        conds = [n for n, _, _ in _walk(jax.make_jaxpr(g)(
+            x, gates, kernels).jaxpr, False, []) if n == "cond"]
+        return out, np.asarray(stats), grads, conds
+
+    out, stats, grads, conds = run(False)
+    assert not conds and stats[5] == (rows or LADDER[-1])
+    want_out, want_stats, want_grads, want_conds = run(True)
+    assert want_conds and want_stats[5] == rung
+    same(stats[:5], want_stats[:5])
+    same(out, want_out)
+    same(grads, want_grads)
+
+
 # --- every rung is the one buffer ---------------------------------------------
 
 @pytest.mark.parametrize("form", FORMS)
@@ -128,6 +174,42 @@ def test_one_factor_is_two_rungs(monkeypatch):
 def test_rung_taken_is_the_one_buffer(small_tile, form, rows_here, rows, rung):
     experts = chosen(rows_here)
     out, stats, grads = layer(form, experts, N, rows)
+    _rung_is_the_one_buffer(experts, out, stats, grads, form, rows_here, rows,
+                            rung)
+
+
+@pytest.mark.parametrize("rows_here,rows,rung", [
+    (10, None, 24), (48, None, 48), (100, None, 128), (70, 64, 64)])
+def test_rung_taken_under_handed_routes_is_the_one_buffer(
+        small_tile, rows_here, rows, rung):
+    """The router's other place (``HeldExperts.router_reads =
+    "layer_input"``): the layer hands the experts' module the routes it took
+    before the mixer, from another tensor than the one the experts multiply,
+    and every rung is still the one buffer."""
+    from dgraph_tpu.models import looplm
+    from dgraph_tpu.train import lm
+
+    x, gates, kernels, cot = inputs("gated_relu")
+    experts = chosen(rows_here)
+    mod = looplm.HeldExpertsFFN(looplm.HeldExperts(
+        N, HELD, K, F, first_held=FIRST, rows=rows, form="gated_relu",
+        router_reads="layer_input"), lm.lm_comm(1), jnp.float32)
+    names = ("gate_proj", "up_proj", "down_proj")
+
+    def f(x, gates, kernels):
+        out, stats = mod.apply({"params": {
+            n: {"kernel": w} for n, w in zip(names, kernels)}},
+            x, (gates, experts))  # no router leaf: the routes are handed in
+        return (out * cot).sum(), (out, stats)
+
+    (_, (out, stats)), grads = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))(x, gates, kernels)
+    _rung_is_the_one_buffer(experts, out, np.asarray(stats), grads,
+                            "gated_relu", rows_here, rows, rung)
+
+
+def _rung_is_the_one_buffer(experts, out, stats, grads, form, rows_here, rows,
+                            rung):
     want_out, want_stats, want_grads = layer(form, experts, None, rows)
     assert stats[5] == rung and want_stats[5] == (rows or LADDER[-1])
     same(stats[:5], want_stats[:5])
