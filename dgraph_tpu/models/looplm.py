@@ -150,7 +150,7 @@ token-local, so those are the only communication. Parameters are float32;
 matmuls run in ``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
 logits are float32. The exit-distribution loss over the passes lives with the
 trainer (:mod:`dgraph_tpu.train.lm`), which applies :meth:`LoopLM.logits`
-in blocks of positions under recomputation.
+in blocks of positions.
 """
 
 from __future__ import annotations

@@ -25,8 +25,10 @@ exit gate ``lambda_t``,
 
 With one pass, or the gate off (then the last pass exits), it is the plain
 next-token cross-entropy. The head is applied per exit step in blocks of
-positions under recomputation, so no ``[R, T, vocab]`` tensor is ever live. A
-model that returns logits directly
+positions, so no ``[R, T, vocab]`` tensor is ever live, and where the loss is
+differentiated each block's pull-back is taken in the same loop, while its
+logits are there (:func:`weighted_cross_entropy`). A model that returns
+logits directly
 (:class:`~dgraph_tpu.models.transformer.SeqTransformerLM`, whose MoE blocks
 also sow an auxiliary loss) goes through the same step as one pass.
 
@@ -40,6 +42,7 @@ weighted per block by ``1 / t_b``, over ``L``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Iterable, Optional
 
 import jax
@@ -47,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax import lax
+from jax.extend.core import Var
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dgraph_tpu.comm.mesh import GRAPH_AXIS, make_graph_mesh, tree_size
@@ -159,14 +163,14 @@ def exit_distribution(gate_logits: jax.Array) -> jax.Array:
         [jax.nn.log_sigmoid(g) + before, log_stay[-1:]], axis=0)
 
 
-def exit_loss(ce: jax.Array, gate_logits: jax.Array,
-              beta: float) -> jax.Array:
-    """Per position ``[...]``: ``sum_t p_t ce_t - beta H(p)`` for ``ce``
-    ``[R, ...]`` and the gate logits of the first ``R - 1`` passes (``H`` the
-    entropy of the exit distribution)."""
+def exit_weights(gate_logits: jax.Array, beta: float):
+    """(``p`` ``[R, ...]``, ``-beta H(p)`` ``[...]``) from the gate logits of
+    the first ``R - 1`` passes: the weight each pass's cross-entropy carries
+    and the entropy term of the per-position loss ``sum_t p_t ce_t - beta
+    H(p)`` (``H`` the entropy of the exit distribution)."""
     logp = exit_distribution(gate_logits)
     p = jnp.exp(logp)
-    return (p * ce).sum(0) + beta * (p * logp).sum(0)
+    return p, beta * (p * logp).sum(0)
 
 
 def loss_block_size(t_local: int, vocab: int) -> int:
@@ -177,27 +181,91 @@ def loss_block_size(t_local: int, vocab: int) -> int:
                if t_local % b == 0 and b <= most)
 
 
-def blockwise_cross_entropy(logits_fn: Callable, hs: jax.Array,
-                            targets: jax.Array, block: int) -> jax.Array:
-    """``ce[t, i] = logsumexp(logits_fn(hs[t, i])) - logits_fn(hs[t, i])[
-    targets[i]]`` for ``hs`` ``[R, T, d]``, one ``[block, vocab]`` logits
-    tensor at a time (``lax.map`` over the R x T/block blocks), each block
-    recomputed in the backward pass. Equal to the direct computation."""
+def _block_cross_entropy(head_fn, head, h, tgt):
+    """``logsumexp(logits) - logits[tgt]`` ``[block]`` of one block's float32
+    logits ``head_fn(h, *head)`` ``[block, vocab]``."""
+    logits = head_fn(h, *head)
+    with jax.named_scope("dgraph.lm.cross_entropy"):
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        hit = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+        return lse - hit
+
+
+def _varying_like(x, like):
+    """``x`` varying over the manual axes ``like`` varies over (inside
+    ``shard_map``; nothing outside): where this cast of a parameter is
+    transposed, the shards' partial cotangents are summed."""
+    axes = jax.typeof(like).vma - jax.typeof(x).vma
+    return lax.pcast(x, tuple(axes), to="varying")
+
+
+def _loss_blocks(hs, targets, w, block):
+    """The R x T/block blocks of (``hs`` ``[R, T, d]``, ``targets`` ``[T]``,
+    ``w`` ``[R, T]``), the loops' leading axis."""
     R, T, d = hs.shape
     nb = T // block
+    return (hs.reshape(R * nb, block, d),
+            jnp.tile(targets.reshape(nb, block), (R, 1)),
+            w.reshape(R * nb, block))
 
-    @jax.checkpoint
-    def one(args):
-        h, tgt = args
-        logits = logits_fn(h)
-        with jax.named_scope("dgraph.lm.cross_entropy"):
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            hit = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
-            return lse - hit
 
-    ce = lax.map(one, (hs.reshape(R * nb, block, d),
-                       jnp.tile(targets.reshape(nb, block), (R, 1))))
-    return ce.reshape(R, T)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _weighted_ce(head_fn, block, head, hs, targets, w):
+    h, tgt, _ = _loss_blocks(hs, targets, w, block)
+    ce = lax.map(lambda a: _block_cross_entropy(head_fn, head, *a), (h, tgt))
+    return (w * ce.reshape(w.shape)).sum()
+
+
+def _weighted_ce_fwd(head_fn, block, head, hs, targets, w):
+    """One pass over the blocks: a block's logits give its cross-entropy and,
+    pulled back from its weights, its share of the head leaves' cotangent
+    (summed in float32, last block first as a scan's transpose sums it) and
+    the cotangent of its exit states. The loss is linear in all three, so
+    the backward pass only scales them."""
+
+    def one(acc, args):
+        h, tgt, wb = args
+        ce, pull = jax.vjp(
+            lambda head, h: _block_cross_entropy(head_fn, head, h, tgt),
+            head, h)
+        d_head, dh = pull(wb)
+        return [a + g.astype(a.dtype) for a, g in zip(acc, d_head)], (ce, dh)
+
+    d_head, (ce, dh) = lax.scan(
+        one, [_varying_like(jnp.zeros(a.shape, jnp.float32), a) for a in head],
+        _loss_blocks(hs, targets, w, block), reverse=True)
+    ce = ce.reshape(w.shape)
+    d_head = [g.astype(a.dtype) for g, a in zip(d_head, head)]
+    return (w * ce).sum(), (d_head, dh.reshape(hs.shape), ce)
+
+
+def _weighted_ce_bwd(head_fn, block, res, c):
+    d_head, dh, ce = res
+    return ([(c * g).astype(g.dtype) for g in d_head],
+            (c * dh).astype(dh.dtype), None, c * ce)
+
+
+_weighted_ce.defvjp(_weighted_ce_fwd, _weighted_ce_bwd)
+
+
+def weighted_cross_entropy(logits_fn: Callable, hs: jax.Array,
+                           targets: jax.Array, w: jax.Array,
+                           block: int) -> jax.Array:
+    """``sum(w * ce)`` with ``ce[t, i] = logsumexp(logits_fn(hs[t, i])) -
+    logits_fn(hs[t, i])[targets[i]]`` for ``hs`` ``[R, T, d]`` and weights
+    ``w`` ``[R, T]``, one ``[block, vocab]`` logits tensor at a time
+    (``lax.map`` over the R x T/block blocks). Equal to the direct
+    computation. Differentiated, the one loop also takes each block's
+    pull-back while its logits are there (three head products a block, no
+    recomputation); the array leaves ``logits_fn`` closes over (the head's
+    kernel, or the tied embedding) are found by ``jax.closure_convert`` and
+    are the only parameters that get a cotangent here."""
+    # traced on a block that varies over no axis, so that the converted
+    # function holds no cast of its own and takes the leaves cast here
+    head_fn, head = jax.closure_convert(
+        logits_fn, jnp.zeros((block, hs.shape[-1]), hs.dtype))
+    head = [_varying_like(a, hs) for a in head]
+    return _weighted_ce(head_fn, block, head, hs, targets, w)
 
 
 def _is_looped(model) -> bool:
@@ -236,34 +304,34 @@ def local_loss_sum(model, params, tokens, comm, *, seq_len: int,
     rank = 0 if comm.graph_axis is None else lax.axis_index(comm.graph_axis)
     positions = rank * t_loc + jnp.arange(t_loc, dtype=jnp.int32)
     targets, valid = next_token_targets(tokens, comm, seq_len)
-    aux, stats = 0.0, None
     if _is_looped(model):
         hs, stats = hidden_states(model, params, tokens, positions)
         gated = model.exit_gate and model.loop_steps > 1
         if not gated:
             hs = hs[-1:]  # the last pass exits with certainty
         with jax.named_scope("dgraph.lm.exit_loss"):
-            block = loss_block or loss_block_size(t_loc, model.vocab)
-            ce = blockwise_cross_entropy(
-                lambda h: model.apply(params, h, method="logits"),
-                hs, targets, block)
+            scored = valid.astype(jnp.float32)
+            w, total = scored[None], 0.0
             if gated:
                 gates = model.apply(params, hs[:-1], method="gate_logit")
-                per_pos = exit_loss(ce, gates, beta)
-            else:
-                per_pos = ce[0]
+                p, entropy = exit_weights(gates, beta)
+                w, total = p * scored, (scored * entropy).sum()
+            total += weighted_cross_entropy(
+                lambda h: model.apply(params, h, method="logits"),
+                hs, targets, w,
+                loss_block or loss_block_size(t_loc, model.vocab))
+        return total, 0.0, stats
+    aux = 0.0
+    if getattr(model, "moe_k", 0) > 0:
+        logits, mut = model.apply(params, tokens, positions,
+                                  mutable=["losses"])
+        aux = sum(jnp.sum(v) for v in jax.tree.leaves(mut))
     else:
-        if getattr(model, "moe_k", 0) > 0:
-            logits, mut = model.apply(params, tokens, positions,
-                                      mutable=["losses"])
-            aux = sum(jnp.sum(v) for v in jax.tree.leaves(mut))
-        else:
-            logits = model.apply(params, tokens, positions)
-        with jax.named_scope("dgraph.lm.exit_loss"):
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-            per_pos = -jnp.take_along_axis(
-                logp, targets[:, None], axis=1)[:, 0]
-    return jnp.where(valid, per_pos, 0.0).sum(), aux, stats
+        logits = model.apply(params, tokens, positions)
+    with jax.named_scope("dgraph.lm.exit_loss"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        per_pos = -jnp.take_along_axis(logp, targets[:, None], axis=1)[:, 0]
+    return jnp.where(valid, per_pos, 0.0).sum(), aux, None
 
 
 def _is_block_diffusion(model) -> bool:
@@ -280,8 +348,7 @@ def block_diffusion_loss_sum(model, params, batch, comm, *,
     of the token's block. The stack runs once over the ``2L`` rows
     ``[xt ; x0]`` (row i of either copy at position i) under
     ``BlockDiffusionMask(L, model.block_length)``; the head and the
-    cross-entropy (no shift) run over the ``L`` noised rows only, in blocks
-    under recomputation."""
+    cross-entropy (no shift) run over the ``L`` noised rows only, in blocks."""
     tokens, masked, weight = batch  # (a sharded sequence: seq_attention refuses)
     L = tokens.shape[0]
     rows = jnp.concatenate(
@@ -289,11 +356,11 @@ def block_diffusion_loss_sum(model, params, batch, comm, *,
     positions = jnp.tile(jnp.arange(L, dtype=jnp.int32), 2)
     hs, stats = hidden_states(model, params, rows, positions)
     with jax.named_scope("dgraph.lm.exit_loss"):
-        block = loss_block or loss_block_size(L, model.vocab)
-        ce = blockwise_cross_entropy(
+        w = jnp.where(masked, weight.astype(jnp.float32), 0.0)
+        total = weighted_cross_entropy(
             lambda h: model.apply(params, h, method="logits"),
-            hs[-1:, :L], tokens, block)[0]
-        total = jnp.where(masked, weight.astype(jnp.float32) * ce, 0.0).sum()
+            hs[-1:, :L], tokens, w[None],
+            loss_block or loss_block_size(L, model.vocab))
     return total, stats
 
 
@@ -353,6 +420,46 @@ def _leave_buffers_alone(updates):
         if getattr(path[-1], "key", None) in FROZEN_LEAVES else u, updates)
 
 
+def _probe_rows(model, tokens):
+    """(rows, positions) of a probe sequence ``tokens`` as the model takes
+    them; a block-diffusion model's rows are two copies."""
+    positions = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    if _is_block_diffusion(model):
+        tokens, positions = jnp.tile(tokens, 2), jnp.tile(positions, 2)
+    return tokens, positions
+
+
+def _outside_the_stack(model, params) -> list:
+    """Per leaf of ``params``, whether the stack (``hidden``) leaves it
+    unread: an untied head, the exit gate. Such a leaf's gradient is whole
+    once the exit loss is differentiated, before the layers' backward pass
+    starts. Read off a trace of ``hidden`` itself on a short probe sequence
+    (which leaves it reads depends on the length no more than a parameter's
+    shape does), not off a leaf's name."""
+    jaxpr = jax.make_jaxpr(lambda p: hidden_states(model, p, *_probe_rows(
+        model, jnp.zeros((INIT_PROBE_TOKENS,), jnp.int32)))[0])(params).jaxpr
+    read = {v for eqn in jaxpr.eqns for v in eqn.invars
+            if isinstance(v, Var)}
+    return [v not in read for v in jaxpr.invars]
+
+
+def _gradients_read(update: Callable, grads, *rest) -> list:
+    """For each output leaf of ``update(grads, *rest)``, the set of
+    ``grads``' leaves (by position) it is computed from; an equation that
+    holds a jaxpr of its own counts as reading all its inputs for all its
+    outputs."""
+    jaxpr = jax.make_jaxpr(update)(grads, *rest).jaxpr
+    reads = {v: {i} for i, v in enumerate(
+        jaxpr.invars[:len(jax.tree.leaves(grads))])}
+    none = frozenset()
+    for eqn in jaxpr.eqns:
+        got = none.union(*(reads.get(v, none) for v in eqn.invars
+                           if isinstance(v, Var)))
+        reads.update((v, got) for v in eqn.outvars)
+    return [reads.get(v, none) if isinstance(v, Var) else none
+            for v in jaxpr.outvars]
+
+
 def make_lm_train_step(model, optimizer: optax.GradientTransformation, mesh,
                        comm, *, seq_len: int, beta: float = 0.0,
                        loss_block: Optional[int] = None,
@@ -360,22 +467,60 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, mesh,
                        donate: bool = True, step_metrics: bool = False):
     """Jitted ``(params, opt_state, tokens [T]) -> (params, opt_state,
     StepMetrics)``. ``step_metrics`` (a build-time constant) adds the global
-    gradient norm."""
+    gradient norm.
+
+    The leaves the stack does not read (an untied head: its float32
+    gradient, the size of the head, comes out of the exit loss's loop) are
+    updated BEFORE the layers' backward pass, where the optimizer updates
+    them from their own gradients alone (AdamW does; a clip by the global
+    norm does not, and then nothing is done ahead): their gradients are
+    pulled back first, the updated leaves are tied to the seed of the
+    pull-back proper by one ``optimization_barrier``, and the compiler,
+    which otherwise schedules every update after the last gradient, has to
+    finish with those gradients before the layers' backward pass, whose
+    temporaries then take their place. The values are those of one update
+    of the whole tree."""
     loss_fn = make_lm_loss(
         model, mesh, comm, seq_len=seq_len, beta=beta, loss_block=loss_block,
         aux_weight=aux_weight, param_specs=param_specs)
 
     counted = _has_experts(model)
 
-    def lm_train_step(params, opt_state, tokens):
-        out, grads = jax.value_and_grad(loss_fn, has_aux=counted)(
-            params, tokens)
-        loss, counts = out if counted else (out, None)
-        gn = optax.global_norm(grads) if step_metrics else None
+    def update(grads, opt_state, params):
         with jax.named_scope("dgraph.lm.optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(
-                params, _leave_buffers_alone(updates))
+            return optax.apply_updates(
+                params, _leave_buffers_alone(updates)), opt_state
+
+    def updated_ahead(pull, seed, opt_state, params):
+        """(the seed tied to the leaves updated ahead, those leaves by their
+        position among ``update``'s outputs), or the seed and nothing."""
+        if comm.graph_axis is not None or not _is_looped(model):
+            return seed, {}
+        ahead = {i for i, out in enumerate(_outside_the_stack(model, params))
+                 if out}
+        reads = ahead and _gradients_read(update, params, opt_state, params)
+        if not ahead or any(r & ahead and not r <= ahead for r in reads):
+            return seed, {}
+        (first,) = pull(seed)  # all but the leaves ahead is dead code
+        flat, tree = jax.tree.flatten(first)
+        first = tree.unflatten([g if i in ahead else jnp.zeros_like(g)
+                                for i, g in enumerate(flat)])
+        new = jax.tree.leaves(update(first, opt_state, params))
+        return lax.optimization_barrier(
+            (seed, {i: a for i, (a, r) in enumerate(zip(new, reads))
+                    if r & ahead}))
+
+    def lm_train_step(params, opt_state, tokens):
+        out = jax.vjp(lambda p: loss_fn(p, tokens), params, has_aux=counted)
+        loss, pull, counts = out if counted else (*out, None)
+        seed, ahead = updated_ahead(
+            pull, jnp.ones_like(loss), opt_state, params)
+        (grads,) = pull(seed)
+        gn = optax.global_norm(grads) if step_metrics else None
+        new, tree = jax.tree.flatten(update(grads, opt_state, params))
+        params, opt_state = tree.unflatten(
+            [ahead.get(i, a) for i, a in enumerate(new)])
         return params, opt_state, StepMetrics(
             loss=loss, grad_norm=gn, moe_rows=counts)
 
@@ -408,11 +553,7 @@ def init_lm_params(model, mesh, comm, seed: int = 0, *,
     world = comm.get_world_size()
 
     def init(tokens):
-        t_loc = tokens.shape[0]
-        positions = jnp.arange(t_loc, dtype=jnp.int32)
-        if _is_block_diffusion(model):  # the rows are two copies
-            tokens, positions = jnp.tile(tokens, 2), jnp.tile(positions, 2)
-        return model.init(jax.random.key(seed), tokens, positions)
+        return model.init(jax.random.key(seed), *_probe_rows(model, tokens))
 
     probe = jnp.zeros((INIT_PROBE_TOKENS * world,), jnp.int32)
     specs = None

@@ -33,12 +33,12 @@ SIZE = {  # the reference's keys (the configuration's names)
 
 
 def build(comm, loop_steps=4, exit_gate=True, attn_impl="ring", dtype=None,
-          remat=True, kv_heads=4):
+          remat=True, kv_heads=4, **kw):
     return LoopLM(
         vocab=V, hidden_size=32, num_layers=3, num_heads=4, head_dim=8,
         intermediate=48, comm=comm, num_kv_heads=kv_heads,
         loop_steps=loop_steps, exit_gate=exit_gate, rms_eps=1e-6,
-        rope_theta=1e6, dtype=dtype, attn_impl=attn_impl, remat=remat)
+        rope_theta=1e6, dtype=dtype, attn_impl=attn_impl, remat=remat, **kw)
 
 
 @pytest.fixture(scope="module")
@@ -46,16 +46,19 @@ def tokens():
     return jnp.asarray(np.random.default_rng(0).integers(0, V, T), jnp.int32)
 
 
-@pytest.fixture(scope="module")
-def seeded(tokens):
+def seeded_for(model, tokens):
     """Seeded weights in the program's tree (no leaf inert: norm gains off 1,
     a gate bias), as the benchmark makes them."""
     from benchmark.builders.looplm import seeded_lm_params
 
-    model = build(lm.lm_comm(1))
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), tokens, jnp.arange(T)))
     return seeded_lm_params(shapes, 11, None)
+
+
+@pytest.fixture(scope="module")
+def seeded(tokens):
+    return seeded_for(build(lm.lm_comm(1)), tokens)
 
 
 @pytest.fixture(scope="module")
@@ -141,6 +144,76 @@ def test_reference_follows_adamw_like_the_trainer(seeded, tokens, reference):
         np.testing.assert_allclose(d, got["delta_norm"][name], rtol=1e-3)
 
 
+# --- the leaves the stack does not read are updated ahead -------------------------
+
+def _equations(jaxpr, primitive: str) -> list:
+    """Every equation of that primitive, in sub-jaxprs too."""
+    from dgraph_tpu.analysis.trace import walk_eqns
+
+    found = []
+    walk_eqns(jaxpr, lambda eqn: eqn.primitive.name.startswith(primitive)
+              and found.append(eqn))
+    return found
+
+
+def _adamw():
+    return optax.adamw(lambda c: 3e-4 * jnp.minimum(1.0, (c + 1) / 2000),
+                       b1=0.9, b2=0.95, weight_decay=0.1)
+
+
+# (the model, the optimizer, the shapes tied to the seed of the pull-back):
+# an untied head's kernel and the gate's two leaves with their two moments
+# each; under a tied head the gate's alone; under a clip by the global norm,
+# which reads every gradient for every update, nothing
+HEAD, GATE = [(32, V)] * 3, [(1,)] * 3 + [(32, 1)] * 3
+AHEAD_CASES = {
+    "untied": (dict(), _adamw, sorted(HEAD + GATE)),
+    "tied": (dict(tie_head=True), _adamw, sorted(GATE)),
+    "clipped": (dict(), lambda: optax.chain(
+        optax.clip_by_global_norm(1.0), _adamw()), None),
+}
+
+
+@pytest.mark.parametrize("case", list(AHEAD_CASES))
+def test_leaves_outside_the_stack_are_updated_ahead(tokens, case):
+    """The train step ties the updated leaves that the stack does not read
+    (and their moments) to the seed of the layers' pull-back with ONE barrier,
+    where the optimizer updates a leaf from its own gradient alone; what the
+    step returns is one update of the whole tree either way."""
+    kw, make_opt, tied = AHEAD_CASES[case]
+    model, opt = build(lm.lm_comm(1), **kw), make_opt()
+    params = seeded_for(model, tokens)
+    state = opt.init(params)
+    step = lm.make_lm_train_step(model, opt, None, model.comm, seq_len=T,
+                                 beta=0.1, loss_block=16, donate=False)
+    found = _equations(jax.make_jaxpr(step)(params, state, tokens).jaxpr,
+                       "optimization_barrier")
+    if tied is None:
+        assert not found
+    else:
+        (barrier,) = found
+        assert sorted(v.aval.shape for v in barrier.invars
+                      if v.aval.shape) == tied
+    outside = lm._outside_the_stack(model, params)
+    names = {"/".join(k.key for k in path[1:]) for (path, _), out in zip(
+        jax.tree_util.tree_flatten_with_path(params)[0], outside) if out}
+    assert names == {"gate/bias", "gate/kernel"} | (
+        set() if kw else {"head/kernel"})
+
+    loss, grads = jax.value_and_grad(lm.make_lm_loss(
+        model, None, model.comm, seq_len=T, beta=0.1, loss_block=16))(
+            params, tokens)
+    updates, want_state = opt.update(grads, state, params)
+    want = (optax.apply_updates(params, updates), want_state)
+    got_params, got_state, sm = step(params, state, tokens)
+    np.testing.assert_allclose(sm.loss, loss, rtol=1e-6)
+    flat = jax.tree_util.tree_flatten_with_path((got_params, got_state))[0]
+    for (path, a), b in zip(flat, jax.tree.leaves(want)):
+        # (jitted against eager: float32 rounding of the gradients)
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-8,
+                                   err_msg=str(path))
+
+
 # --- the loop is a parameter of one model ---------------------------------------
 
 @pytest.mark.parametrize("R", [1, 2, 4])
@@ -198,30 +271,133 @@ def test_gate_off_scores_the_last_pass_only(seeded, tokens):
 
 # --- the blockwise exit loss ------------------------------------------------------
 
-@pytest.mark.parametrize("block", [T, 16, 8])
-def test_blockwise_exit_loss_equals_direct(seeded, tokens, block):
-    """The head applied per exit step in blocks under recomputation gives the
-    loss and the gradients of the direct computation over [R, T, vocab]."""
-    model = build(lm.lm_comm(1))
+def direct_loss(model, p, batch, beta):
+    """The objective from the whole ``[R, T, vocab]`` logits at once, for
+    plain ``jax.grad``: the gated exit loss, the last pass's next-token
+    cross-entropy, or block diffusion's weighted masked-token one."""
+    log_softmax_at = lambda logits, tgt: -jnp.take_along_axis(
+        jax.nn.log_softmax(logits), tgt[..., None], -1)[..., 0]
+    if model.block_length:
+        tokens, masked, weight = batch
+        rows = jnp.concatenate([jnp.where(masked, model.mask_token, tokens),
+                                tokens])
+        hs = model.apply(p, rows, jnp.tile(jnp.arange(T), 2), method="hidden")
+        ce = log_softmax_at(model.apply(p, hs[-1, :T], method="logits"), tokens)
+        return jnp.where(masked, weight * ce, 0.0).sum() / T
+    logits, gates = model.apply(p, batch, jnp.arange(T))
+    ce = log_softmax_at(logits, jnp.concatenate([batch[1:], batch[:1]])[None])
+    if gates is None or model.loop_steps == 1:
+        return ce[-1, :-1].mean()
+    lam = jax.nn.sigmoid(gates[:-1])
+    stay = jnp.cumprod(1 - lam, 0)
+    prob = jnp.concatenate(
+        [lam * jnp.concatenate([jnp.ones_like(lam[:1]), stay[:-1]]), stay[-1:]])
+    per = (prob * ce).sum(0) + beta * (prob * jnp.log(prob)).sum(0)
+    return per[:-1].mean()
 
-    def direct(p):
-        logits, gates = model.apply(p, tokens, jnp.arange(T))
-        tgt = jnp.concatenate([tokens[1:], tokens[:1]])
-        ce = -jnp.take_along_axis(
-            jax.nn.log_softmax(logits), tgt[None, :, None], -1)[..., 0]
-        lam = jax.nn.sigmoid(gates[:-1])
-        stay = jnp.cumprod(1 - lam, 0)
-        prob = jnp.concatenate(
-            [lam * jnp.concatenate([jnp.ones_like(lam[:1]), stay[:-1]]), stay[-1:]])
-        per = (prob * ce).sum(0) + 0.1 * (prob * jnp.log(prob)).sum(0)
-        return per[:-1].mean()
 
-    want, want_g = jax.value_and_grad(direct)(seeded)
-    got, got_g = jax.value_and_grad(lambda p: single_loss(
-        model, p, tokens, beta=0.1, loss_block=block))(seeded)
+# (the model, the loss block, the traced scalar the loss is multiplied by):
+# gated with four exits / the last pass alone (gate off, or one pass), an
+# untied head / the embedding's transpose, next-token / block-diffusion
+# weights, one block / several
+GATED, TIED = dict(), dict(tie_head=True)
+LAST = dict(exit_gate=False)
+DIFFUSION = dict(loop_steps=1, exit_gate=False, block_length=8,
+                 mask_token=V - 1)
+EXIT_LOSS_CASES = {
+    "gated-untied-64": (GATED, T, 1.0),
+    "gated-untied-16": (GATED, 16, 1.0),
+    "gated-untied-8": (GATED, 8, 1.0),
+    "gated-tied-16": (TIED, 16, 1.0),
+    "gated-tied-8-scaled": (TIED, 8, 0.37),
+    "last-untied-16": (LAST, 16, 1.0),
+    "one_pass-tied-64": (dict(LAST, loop_steps=1, tie_head=True), T, 1.0),
+    "diffusion-untied-8-scaled": (DIFFUSION, 8, -2.5),
+    "diffusion-tied-64": (dict(DIFFUSION, tie_head=True), T, 1.0),
+}
+
+
+@pytest.mark.parametrize("case", list(EXIT_LOSS_CASES))
+def test_blockwise_exit_loss_equals_direct(tokens, case):
+    """The head applied in blocks, each block's pull-back taken in the loss's
+    own loop, gives the loss and EVERY gradient leaf (head or embedding, gate,
+    the stack's parameters through the exit states) of the direct computation
+    over ``[R, T, vocab]``; times a traced scalar, so that the backward rule's
+    incoming cotangent is not 1."""
+    kw, block, scale = EXIT_LOSS_CASES[case]
+    model = build(lm.lm_comm(1), **kw)
+    params = seeded_for(model, tokens)
+    if model.block_length:
+        from benchmark.builders.sdar import noised_batch
+
+        batch = tuple(jnp.asarray(a) for a in noised_batch(
+            np.random.default_rng(3), T, V - 1, 1.0, 8, 1e-3))
+    else:
+        batch = tokens
+    loss_fn = lm.make_lm_loss(model, None, model.comm, seq_len=T, beta=0.1,
+                              loss_block=block)
+    (want, want_g), (got, got_g) = (
+        jax.value_and_grad(lambda p, s: s * f(p))(params, jnp.float32(scale))
+        for f in (lambda p: direct_loss(model, p, batch, 0.1),
+                  lambda p: loss_fn(p, batch)))
     np.testing.assert_allclose(got, want, rtol=2e-6)
-    for a, b in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-7)
+    flat = jax.tree_util.tree_flatten_with_path(got_g)[0]
+    assert len(flat) == len(jax.tree.leaves(params))
+    for (path, a), b in zip(flat, jax.tree.leaves(want_g)):
+        assert float(jnp.abs(b).max()) > 0, path  # no leaf is inert
+        np.testing.assert_allclose(
+            a, b, rtol=2e-4, atol=2e-7 * abs(scale), err_msg=str(path))
+
+
+def _vocab_products(jaxpr, loops=()):
+    """(dot_general with the vocabulary as a dimension of an operand or of
+    its result, the scan / while equations it lies in) of a jaxpr and of
+    every jaxpr its equations hold."""
+    for eqn in jaxpr.eqns:
+        shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+        if eqn.primitive.name == "dot_general" and any(V in s for s in shapes):
+            yield eqn, loops
+        inner = loops + (eqn,) if eqn.primitive.name in ("scan", "while") \
+            else loops
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _vocab_products(sub, inner)
+
+
+@pytest.mark.parametrize("tie_head", [False, True], ids=["untied", "tied"])
+def test_one_loop_applies_the_head_three_times_a_block(tokens, tie_head):
+    """The counter that says the mechanism engaged. Differentiated, the head
+    (the only products with the vocabulary as a dimension) runs inside ONE
+    loop, three products a block (logits, their pull-back to the exit states
+    and to the head), and nowhere else; the eval step's loop holds the one
+    product and carries no cotangent; and of the parameters only the leaf
+    that ``logits`` reads crosses into the loss's own derivative rule."""
+    model = build(lm.lm_comm(1), tie_head=tie_head)
+    params = seeded_for(model, tokens)
+    kw = dict(seq_len=T, beta=0.1, loss_block=16)
+    loss = lm.make_lm_loss(model, None, model.comm, **kw)
+
+    found = list(_vocab_products(
+        jax.make_jaxpr(jax.value_and_grad(loss))(params, tokens).jaxpr))
+    assert len(found) == 3
+    assert all(len(loops) == 1 for _, loops in found)
+    (loop,) = {id(loops[0]): loops[0] for _, loops in found}.values()
+    assert loop.params["length"] == 4 * T // 16  # every block of every exit
+    head = (V, 32) if tie_head else (32, V)
+    carried = loop.invars[loop.params["num_consts"]:][:loop.params["num_carry"]]
+    assert [(v.aval.shape, v.aval.dtype) for v in carried] == [
+        (head, jnp.float32)]  # the head's cotangent, summed over the blocks
+
+    step = lm.make_lm_eval_step(model, None, model.comm, **kw)
+    ((_, (loop,)),) = _vocab_products(
+        jax.make_jaxpr(step)(params, tokens).jaxpr)
+    assert loop.params["num_carry"] == 0
+
+    (rule,) = [e for e in _equations(
+        jax.make_jaxpr(loss)(params, tokens).jaxpr, "custom_vjp_call")
+        if any(V in v.aval.shape for v in e.invars)]
+    # the head's leaf, the exit states [R, T, d], the targets, the weights
+    assert sorted(v.aval.shape for v in rule.invars) == sorted(
+        [head, (4, T, 32), (T,), (4, T)])
 
 
 def test_loss_block_size_divides_and_fits():
@@ -232,12 +408,12 @@ def test_loss_block_size_divides_and_fits():
 
 # --- sequence-sharded = single device -------------------------------------------
 
-def sharded(world, impl, seeded, tokens):
+def sharded(world, impl, seeded, tokens, **kw):
     devs = jax.devices()
     if len(devs) < world:
         pytest.skip(f"need {world} devices")
     mesh, comm = lm.lm_mesh(world, devs), lm.lm_comm(world)
-    model = build(comm, attn_impl=impl)
+    model = build(comm, attn_impl=impl, **kw)
 
     def hidden(p, tk):
         t_loc = tk.shape[0]
@@ -254,19 +430,26 @@ def sharded(world, impl, seeded, tokens):
     return logits, loss, grads
 
 
-@pytest.mark.parametrize("world,impl", [(4, "ring"), (8, "ring"), (4, "ulysses")])
+@pytest.mark.parametrize("world,impl,tie_head", [
+    (4, "ring", False), (8, "ring", False), (4, "ulysses", False),
+    (4, "ring", True)], ids=["4-ring", "8-ring", "4-ulysses", "4-ring-tied"])
 def test_sharded_model_matches_single_device(
-        world, impl, seeded, tokens, compiled_fresh):
+        world, impl, tie_head, seeded, tokens, compiled_fresh):
     """Logits of every pass (rotary positions from the shard's global offset),
     the loss over all T - 1 positions, and every gradient leaf (summed over
-    shards AND over the four uses of each weight). Compiled fresh: the
+    shards AND over the four uses of each weight; the head's, which the exit
+    loss takes a shard at a time in its own loop, and under a tied head the
+    embedding's, which sums the lookup's and the head's). Compiled fresh: the
     eight-device CPU executable loaded back from the persistent compilation
     cache aborts the process or gives a wrong loss."""
-    model = build(lm.lm_comm(1))
+    model = build(lm.lm_comm(1), tie_head=tie_head)
+    if tie_head:
+        seeded = seeded_for(model, tokens)
     want_logits, _ = model.apply(seeded, tokens, jnp.arange(T))
     want, want_g = jax.value_and_grad(
         lambda p: single_loss(model, p, tokens, beta=0.1))(seeded)
-    logits, loss, grads = sharded(world, impl, seeded, tokens)
+    logits, loss, grads = sharded(world, impl, seeded, tokens,
+                                  tie_head=tie_head)
     # online-softmax blocks sum in another order: float32 rounding
     np.testing.assert_allclose(logits, want_logits, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(loss, want, rtol=1e-5)

@@ -231,8 +231,9 @@ def test_channel_block_follows_the_budget(C, N, bt, itemsize, bc):
 
 # sha256[:16] of (the parameter tree's shapes, the train step's jaxpr) of
 # phi4_mini_flash's tiny preset at commit 1eb632a (PR 39), before the scan
-# had a second route: N = 4 keeps the lax form, so that program.
-PARENT_TINY = ("7a2acd26e2122411", "daef51665e9b5afa")
+# had a second route: N = 4 keeps the lax form, so that program; its exit
+# loss is one loop since PR 48 (daef51665e9b5afa until then).
+PARENT_TINY = ("7a2acd26e2122411", "3465530399e477c6")
 
 
 def test_tiny_preset_is_the_parents_program():
