@@ -145,6 +145,26 @@ untied head):
   causal prefix, while the windowed layers take the table (``rope_theta=None``
   keeps meaning that no layer has positions).
 
+Attention through a LATENT with a decoupled rotary key (multi-head latent
+attention, the ``deepseek_v3`` layer; kinds ``attn_mla+dense`` and
+``attn_mla+experts``; pre-norm RMSNorm, no bias, an untied head):
+
+- ``attn_mla`` (``mla``: :class:`LatentAttention`, which has the equations):
+  queries straight from the stream, keys and values up-projected a head from
+  a normed ``kv_rank``-wide latent, the rotary embedding over ``rope_dim`` of
+  a q.k head's ``nope_dim + rope_dim`` dimensions in the PUBLISHED pairs
+  ``(2i, 2i + 1)`` (:func:`apply_rotary_pairs`; the table is built for
+  ``rope_dim``, not ``head_dim``), ONE rotated key that every head reads,
+  exact causal softmax at ``1 / sqrt(nope_dim + rope_dim)`` through
+  ``seq_attention`` with value heads of ``v_head_dim`` (where one device
+  holds the sequence). Keys and values are materialised a head for the
+  kernel, the form the published code trains with: no absorbed products, no
+  cache;
+- its expert layers are what is there: the sigmoid router with a selection
+  bias and a gate scale, gated-SiLU experts, and beside them ONE shared
+  expert of the same gated form (the published ``n_shared_experts`` experts
+  are one MLP of their summed width).
+
 Everything but attention, the convolutions' halo and the scan's state is
 token-local, so those are the only communication. Parameters are float32;
 matmuls run in ``config.resolve_compute_dtype(dtype)``; norms, the rotary embedding and the
@@ -194,6 +214,22 @@ def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
         [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
 
 
+@jax.named_scope("dgraph.lm.rotary")
+def apply_rotary_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """The rotary embedding of ``x`` ``[T, H, D]`` over ADJACENT pairs ``(2i,
+    2i + 1)`` (the published ``rope_interleave`` form), ``cos``, ``sin`` ``[T,
+    D / 2]``. Each element takes its partner from the lane beside it (two
+    rolls and a select: no reshape of the lanes into ``[D / 2, 2]``, which a
+    TPU would tile at two of 128 lanes). Float32 arithmetic, result in
+    ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    c, s = (jnp.repeat(t, 2, axis=-1)[:, None, :] for t in (cos, sin))
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
+                        jnp.roll(xf, 1, axis=-1))
+    return (xf * c + partner * s).astype(x.dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class HeldExperts:
     """The chip's share of a sparse-expert FFN: ``n_held`` of ``n_total``
@@ -215,7 +251,9 @@ class HeldExperts:
     relu(W_up x)^2``: no ``gate_proj``); ``shared_width`` > 0: one shared
     expert of that width and the same form on every token, added to the held
     part (leaves ``shared_up_proj``, ``shared_down_proj`` and, gated,
-    ``shared_gate_proj``). ``router_reads``, the router's place
+    ``shared_gate_proj``); every router form goes with every expert form (a
+    gated-SiLU shared expert beside the sigmoid router with its bias and gate
+    scale is the ``deepseek_v3`` layer's). ``router_reads``, the router's place
     (``ROUTER_READS``): ``"ffn_input"``, what the experts multiply, the
     float32 output of the norm before them; or ``"layer_input"``, the
     residual stream as it reaches the layer, un-normed, before the mixer
@@ -478,6 +516,37 @@ class Mamba2Mixer:
     chunk: Optional[int] = None
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """The sizes of attention through a latent (the mixer kind ``attn_mla``;
+    kinds ``attn_mla+dense``, ``attn_mla+experts``): keys and values are
+    up-projected from a ``kv_rank``-wide normed latent; a q.k head is
+    ``nope_dim`` dimensions without positions and ``rope_dim`` rotated ones,
+    the rotated key ONE head shared by all; a value head is ``v_head_dim``.
+    Queries come straight from the stream: a query latent has no path yet.
+
+    With H heads, on the normed stream x (leaves ``q_proj``, ``kv_a_proj``,
+    ``kv_a_norm``, ``kv_b_proj``, ``o_proj``; no bias): ``q = W_q x`` as
+    ``[H, nope_dim + rope_dim]``; ``[c ; k_r] = W_kva x`` of sizes ``kv_rank |
+    rope_dim``; ``[k_nope,h ; v_h] = W_kvb RMSNorm(c)`` as ``[H, nope_dim +
+    v_head_dim]``; the rotary embedding over the ``rope_dim`` dimensions of
+    each query head and of ``k_r``, pairs ``(2i, 2i + 1)``; ``k_h = [k_nope,h
+    ; k_r]``; ``a_h = softmax(q_h k_h^T / sqrt(nope_dim + rope_dim)) v_h``
+    over the causal prefix; ``W_o [a_1 ... a_H]``. Scopes ``dgraph.lm.mla_down``
+    (``W_kva`` and the latent's norm) and ``dgraph.lm.mla_up`` (``W_kvb``, the
+    rotary key, its broadcast over the heads and the concatenation); the
+    attention core is ``seq_attention``'s own ``dgraph.comm.seq_attention``."""
+
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+
 def _a_log_heads_init(key, shape, dtype=jnp.float32, lo=1.0, hi=16.0):
     """``log U(lo, hi)`` a head: Mamba-2's own (``A_init_range``)."""
     return jnp.log(jax.random.uniform(key, shape, dtype, lo, hi))
@@ -562,10 +631,10 @@ class GatedMemoryUnit(nn.Module):
 
 
 LAYER_MIXERS = ("attn", "conv", "ssm", "ssm_keep", "gmu", "diff_win",
-                "diff_keep", "cross", "ssd", "none", "attn_win")
+                "diff_keep", "cross", "ssd", "none", "attn_win", "attn_mla")
 LAYER_FFNS = ("dense", "experts", "none")  # "none": the half is not there
 DIFF_MIXERS = ("diff_win", "diff_keep", "cross")  # differential attention
-ATTENDING = ("attn", "attn_win") + DIFF_MIXERS
+ATTENDING = ("attn", "attn_win", "attn_mla") + DIFF_MIXERS
 WINDOWED = ("diff_win", "attn_win")  # attend under ``window`` keys
 KEEPS = {"ssm_keep": "m", "diff_keep": "kv"}  # mixer -> what it keeps
 READS = {"gmu": "m", "cross": "kv"}  # mixer -> what it reads of the kept
@@ -620,6 +689,7 @@ class LoopLMLayer(nn.Module):
     ssd: Optional[Mamba2Mixer] = None
     has_ffn: bool = True  # False: the mixer alone ("<mixer>+none")
     full_attn_rope: bool = True  # False: an "attn" layer takes no positions
+    mla: Optional[LatentAttention] = None  # the sizes of an "attn_mla" layer
 
     @nn.compact
     def __call__(self, h, rope, kept=None):  # [T_loc, hidden], (cos, sin)
@@ -660,6 +730,8 @@ class LoopLMLayer(nn.Module):
             h = h + post("norm_ssd_out")(a)
         elif self.mixer in DIFF_MIXERS:
             h, keep = self.differ(h, kept, dt, dense, norm, post)
+        elif self.mixer == "attn_mla":
+            h = self.attend_latent(h, rope, dense, norm, post)
         elif self.mixer != "none":
             if self.mixer == "attn" and not self.full_attn_rope:
                 rope = None
@@ -701,6 +773,32 @@ class LoopLMLayer(nn.Module):
             a = self.comm.seq_attention(q, k, v, causal=True,
                                         impl=self.attn_impl)
         a = dense(self.hidden, name="o_proj")(a.reshape(n, H * D))
+        return h + post("norm_attn_out")(a)
+
+    def attend_latent(self, h, rope, dense, norm, post):
+        """Attention through a latent (:class:`LatentAttention`): ``h + W_o
+        a``. ``rope`` is the table for ``rope_dim``. The H keys and values
+        are written out a head for ``seq_attention``, the one rotated key
+        broadcast over the heads."""
+        sp, H, n = self.mla, self.num_heads, h.shape[0]
+        Dn, Dr, Dv = sp.nope_dim, sp.rope_dim, sp.v_head_dim
+        x = norm(name="norm_attn_in")(h)
+        q = dense(H * (Dn + Dr), name="q_proj")(x).reshape(n, H, Dn + Dr)
+        q = jnp.concatenate(
+            [q[..., :Dn], apply_rotary_pairs(q[..., Dn:], *rope)], axis=-1)
+        with jax.named_scope("dgraph.lm.mla_down"):
+            c, k_r = jnp.split(dense(sp.kv_rank + Dr, name="kv_a_proj")(x),
+                               [sp.kv_rank], axis=-1)
+            c = norm(name="kv_a_norm")(c)
+        with jax.named_scope("dgraph.lm.mla_up"):
+            k_nope, v = jnp.split(
+                dense(H * (Dn + Dv), name="kv_b_proj")(c).reshape(
+                    n, H, Dn + Dv), [Dn], axis=-1)
+            k_r = apply_rotary_pairs(k_r[:, None, :], *rope)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_r, (n, H, Dr))], axis=-1)
+        a = self.comm.seq_attention(q, k, v, causal=True, impl=self.attn_impl)
+        a = dense(self.hidden, name="o_proj")(a.reshape(n, H * Dv))
         return h + post("norm_attn_out")(a)
 
     def differ(self, h, kept, dt, dense, norm, post):
@@ -909,6 +1007,7 @@ class LoopLM(nn.Module):
     # False: the full-attention ("attn") layers carry no positional encoding
     # and only the windowed ("attn_win") ones take the rotary table
     full_attn_rope: bool = True
+    mla: Optional[LatentAttention] = None  # the "attn_mla" layers' sizes
 
     def layer_kinds(self) -> tuple:
         """The kind of each of the ``num_layers`` layers, in stack order."""
@@ -941,6 +1040,17 @@ class LoopLM(nn.Module):
             if "attn_win" in mixers and self.block_length:
                 raise ValueError("a windowed layer under block diffusion: "
                                  "one structured mask a layer")
+            if "attn_mla" in mixers:
+                if self.mla is None:
+                    raise ValueError("latent-attention layers in the pattern "
+                                     "need `mla`, their sizes")
+                if len(mixers & set(ATTENDING)) > 1 or self.block_length \
+                        or self.rope_theta is None:
+                    raise ValueError(
+                        "a stack attends at ONE pair of head sizes under one "
+                        "rotary table: latent attention beside another "
+                        "attending mixer, under block diffusion or without "
+                        "positions has no path")
         if self.experts is not None \
                 and self.experts.router_reads not in ROUTER_READS:
             raise ValueError(f"router_reads {self.experts.router_reads!r}: "
@@ -957,7 +1067,7 @@ class LoopLM(nn.Module):
             conv_kernel=self.conv_kernel, norm=self.norm,
             attn_bias=self.attn_bias, fused_mlp=self.fused_mlp,
             window=self.window, ssm=self.ssm, ssd=self.ssd,
-            full_attn_rope=self.full_attn_rope)
+            full_attn_rope=self.full_attn_rope, mla=self.mla)
         # the same parameters every pass: broadcast, not split
         loop = nn.scan(
             LoopPass, variable_broadcast="params",
@@ -974,11 +1084,22 @@ class LoopLM(nn.Module):
 
     def hidden(self, tokens, positions):  # [T_loc] int32 each
         rope = None if self.rope_theta is None else rotary_tables(
-            positions, self.head_dim, self.rope_theta)
+            positions, self.rotary_dim(), self.rope_theta)
         _, hs = self.stack(self.embed(tokens), rope)
         # [loop_steps, T_loc, hidden]; with expert layers also their counts,
         # [loop_steps, num_layers, 6] (parallel.expert.HELD_STATS)
         return hs
+
+    def latent(self) -> Optional[LatentAttention]:
+        """The latent-attention sizes, where the stack has such layers."""
+        attends = any(k.startswith("attn_mla+") for k in self.layer_kinds())
+        return self.mla if attends else None
+
+    def rotary_dim(self) -> int:
+        """The dimensions of a head the rotary table is built for: the
+        latent mixer's ``rope_dim``, else the whole ``head_dim``."""
+        sp = self.latent()
+        return self.head_dim if sp is None else sp.rope_dim
 
     def attention_mask(self, seq_len: int):
         """The structured mask the layers attend under for a sequence of

@@ -125,7 +125,8 @@ class CausalMask:
     """``causal`` as a mask object, for the kernels that take one: row q may
     attend row k iff ``k <= q``. The splash kernels skip the tiles above the
     diagonal and read grouped KV heads where they lie, which is how a head
-    narrower than the 128 lanes runs (:func:`_flash_dense`)."""
+    that is no multiple of the 128 lanes runs (:func:`_flash_dense`: 64, or
+    192 on values of 128)."""
 
     seq_len: int
     name: ClassVar[str] = "causal"
@@ -447,8 +448,8 @@ def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
 
     Trace-time decision: config tri-state (``DGRAPH_TPU_FLASH_ATTN``) +
     shape constraints of the TPU kernel (T a multiple of its 128 query
-    block; head_dim a multiple of the 128 lanes, or a narrower one whose
-    causal self-check at ``group`` passed). ``require_pinned=True`` (the
+    block; head_dim a multiple of the 128 lanes, or another whose causal
+    self-check at ``group`` and the values' head size passed). ``require_pinned=True`` (the
     single-comm ORACLE site) engages only on an explicit config True —
     never on auto — so an unverified Mosaic kernel can't silently replace
     the dense reference that parity harnesses compare against.
@@ -496,8 +497,8 @@ def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
         # splash kernels under a causal mask 17.7 / 83.7
         if not causal or kv_mask is not None:
             raise NotImplementedError(
-                f"head_dim {D}: the narrow-head kernel path is causal and "
-                f"takes no kv_mask")
+                f"head_dim {D}: the kernel path of a head off the lanes is "
+                f"causal and takes no kv_mask")
         return _splash_dense(qh, kh, vh, mask=CausalMask(T), scale=scale)
     kh, vh = repeat_kv(qh, kh, vh)
     if scale is None:
@@ -528,7 +529,12 @@ FLASH_BLOCK = 1024
 # Columns of a kv tile the splash kernels take through the softmax at a time:
 # what the chip measured fastest under the block-diffusion mask at 16384 rows,
 # 32 heads on 4 (forward 19.5 ms at 256, 23.8 at 512, 22.1 at 128; PERF.md,
-# section 6, PR 32).
+# section 6, PR 32), and for a q.k head of 192 on values of 128 (32 heads on
+# 32, causal, 16384 rows; forward / forward + backward 25.98 / 102.12 ms at
+# 256, 27.04 / 103.10 at 512, 30.77 / 107.03 at 128; q and k zero-padded to
+# 256, which is exact, are SLOWER at each: 26.72 / 103.01, 27.92 / 104.08,
+# 31.57 / 107.91, so such a head runs as it is; scripts/splash_head_sweep.py,
+# PERF.md, section 6, PR 49).
 SPLASH_KV_COMPUTE = 256
 # ... and for a head of 64 under the causal mask at 16384 rows, 32 heads on 8
 # (forward / forward + backward 17.68 / 83.70 ms at 512, 18.07 / 83.88 at 256,
@@ -600,9 +606,9 @@ def _splash_dense(qh, kh, vh, *, mask, scale, interpret: bool = False):
 def _splash_block_sizes(sk, T: int, D: int = 128):
     """Square tiles of ``flash_tile(T)`` for the three splash kernels, the
     softmax taken over SPLASH_KV_COMPUTE columns of a kv tile at a time
-    (SPLASH_KV_COMPUTE_NARROW for a head narrower than the lanes)."""
+    (SPLASH_KV_COMPUTE_NARROW for a q.k head ``D`` narrower than the lanes)."""
     b = flash_tile(T)
-    c = min(b, SPLASH_KV_COMPUTE if D % 128 == 0 else SPLASH_KV_COMPUTE_NARROW)
+    c = min(b, SPLASH_KV_COMPUTE_NARROW if D < 128 else SPLASH_KV_COMPUTE)
     return sk.BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=c,
         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=c,

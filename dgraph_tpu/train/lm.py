@@ -585,7 +585,8 @@ def layers_by_kind(kinds) -> dict:
     ``attn_win`` (the plain ones of those), ``cross`` (attention
     on another layer's keys and values), ``ssd`` (Mamba-2 mixers) and the
     layers of one half: ``mixer_only`` (``"<mixer>+none"``) and
-    ``experts_only`` (``"none+experts"``)."""
+    ``experts_only`` (``"none+experts"``), and ``attn_mla`` (attention
+    through a latent; among ``attention`` too)."""
     from dgraph_tpu.models.looplm import ATTENDING, WINDOWED, split_kind
 
     mixers = [split_kind(k)[0] for k in kinds]
@@ -596,6 +597,7 @@ def layers_by_kind(kinds) -> dict:
     more = {"ssm": count("ssm", "ssm_keep"), "gmu": count("gmu"),
             "window": count(*WINDOWED), "attn_win": count("attn_win"),
             "cross": count("cross"), "ssd": count("ssd"),
+            "attn_mla": count("attn_mla"),
             "mixer_only": sum(k.endswith("+none") for k in kinds),
             "experts_only": sum(k == "none+experts" for k in kinds)}
     out.update({k: n for k, n in more.items() if n})
@@ -696,7 +698,11 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     positions beside windowed ones that do), ``.ssd / .mixer_only /
     .experts_only`` with ``lm.ssd.heads / .groups / .state / .chunk``;
     ``moe.shared_width`` beside the ``moe.*`` of a shared expert,
-    ``moe.route_ahead_layers`` where the router reads its layer's input); a
+    ``moe.route_ahead_layers`` where the router reads its layer's input;
+    ``.attn_mla`` with ``lm.attention.qk_head_dim / .v_head_dim / .kv_rank /
+    .rope_dim``: the latent layers' q.k and value head sizes, which the
+    attention implementation is resolved and self-checked at, and the
+    latent's and the rotary key's widths); a
     stack of several masks (attention under a window and full, differential
     or plain) has each self-checked, and ``attn.mask_pairs / .tile_pairs``
     summed over them."""
@@ -713,6 +719,10 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         if hasattr(model, "attention_masks") else None
     group = heads // (getattr(model, "num_kv_heads", None) or heads)
     v_head_dim = None
+    latent = model.latent() if hasattr(model, "latent") else None
+    if latent is not None:  # one key and one value head a query head
+        head_dim, v_head_dim, group = (
+            latent.qk_head_dim, latent.v_head_dim, 1)
     if by_kind is not None and not by_kind["attention"]:
         attention = "none"  # a stack of convolutions attends nowhere
     elif masks:  # a mask a layer: each distinct one is resolved, in order
@@ -732,7 +742,7 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         attention = resolve_attention(
             comm, model.attn_impl,
             seq_len // world if mask is None else mask.rows, heads, head_dim,
-            mask, group)
+            mask, group, v_head_dim=v_head_dim)
     specs = None
     if params is None:
         params, specs = init_lm_params(
@@ -782,6 +792,12 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
             default_registry.counter("lm.attention.window", model.window)
         if v_head_dim:
             default_registry.counter("lm.attention.v_head_dim", v_head_dim)
+        if latent is not None:
+            startup["latent_attention"] = {
+                "qk_head_dim": head_dim, "kv_rank": latent.kv_rank,
+                "rope_dim": latent.rope_dim}
+            for name, v in startup["latent_attention"].items():
+                default_registry.counter(f"lm.attention.{name}", v)
         if by_kind.get("attn_win") and not model.full_attn_rope:
             nope = sum(k.startswith("attn+") for k in kinds)
             startup["nope_layers"] = nope

@@ -69,7 +69,8 @@ SECTIONS = [
     ("Models", "dgraph_tpu.models", None),
     ("Sequence-LM layers", "dgraph_tpu.models.looplm",
      ["HeldExperts", "HeldExpertsFFN", "StateSpace", "Mamba2Mixer",
-      "SSDMixer", "split_kind", "causal_taps", "previous_rows"]),
+      "LatentAttention", "SSDMixer", "split_kind", "causal_taps",
+      "previous_rows", "apply_rotary_pairs"]),
     ("GraphCast", "dgraph_tpu.models.graphcast", None),
     ("Tensor parallelism", "dgraph_tpu.parallel.tensor", None),
     ("Pipeline parallelism", "dgraph_tpu.parallel.pipeline", None),

@@ -181,3 +181,35 @@ def test_chunked_recurrence_kernels_compile_at_nemotron_shape(one_chip,
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert [(o.shape, o.dtype) for o in jax.eval_shape(fn, *args)] == want
+
+
+# kanana2_30b_a3b.seq16k (benchmark/configs/kanana2_30b_a3b.json): 16 384
+# causal rows, 32 query heads on 32 key heads of 192 and value heads of 128.
+MLA_T, MLA_H, MLA_QK, MLA_V = 16384, 32, 192, 128
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_splash_kernels_compile_at_a_qk_head_of_192_on_values_of_128(
+        one_chip, direction):
+    """The splash kernels (ISSUE 49) at the latent layers' shape: a q.k head
+    of a lane tile and a half, AS IT IS (no zero columns), on a value head of
+    another size, at ``SPLASH_KV_COMPUTE`` columns a softmax step."""
+    from dgraph_tpu.parallel import sequence as seq
+
+    def shape(d):
+        return jax.ShapeDtypeStruct((MLA_T, MLA_H, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    attend = lambda q, k, v: seq._splash_dense(
+        q, k, v, mask=seq.CausalMask(MLA_T), scale=None)
+    fn = attend if direction == "forward" else jax.grad(
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    args = [shape(MLA_QK), shape(MLA_QK), shape(MLA_V)]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "192" in text and "bf16[16384,32,256]" not in text  # not padded
+    out = jax.eval_shape(fn, *args)
+    want = [MLA_V] if direction == "forward" else [MLA_QK, MLA_QK, MLA_V]
+    assert [o.shape for o in jax.tree.leaves(out)] == [
+        (MLA_T, MLA_H, d) for d in want]
