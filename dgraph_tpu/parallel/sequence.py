@@ -577,38 +577,130 @@ def _splash_mask(mask, heads: int):
 def _splash_dense(qh, kh, vh, *, mask, scale, interpret: bool = False):
     """[T, H, D] queries on [T, Hkv, D] keys and values under a structured
     mask, via ``jax.experimental.pallas.ops.tpu.splash_attention``: tiles of
-    ``flash_tile(T)`` rows, skipped where the mask allows no pair, forward
-    and backward Mosaic kernels with their own VJP. Grouped-query heads are
-    native: one multi-query kernel over the ``H / Hkv`` query heads of a KV
-    head, mapped over the KV heads; K and V are read where they lie. The
-    values' head size may differ from D (the result has theirs)."""
+    ``flash_tile(T)`` rows, skipped where the mask allows no pair. Grouped-query
+    heads are native: one multi-query kernel over the ``H / Hkv`` query heads
+    of a KV head, mapped over the KV heads; K and V are read where they lie.
+    The values' head size may differ from D (the result has theirs).
+
+    The forward is the library's Mosaic kernel. The backward is ONE kernel of
+    the repo's own (:mod:`dgraph_tpu.ops.pallas_attention`: scores and
+    probabilities once a visited tile, ``dq``, ``dk``, ``dv`` summed in
+    float32 on chip) where :func:`_one_kernel_backward` says a KV head's
+    blocks fit, and the library's two (dkv, then dq) everywhere else. Counted
+    a traced backward: ``attn.bwd_calls``, ``attn.bwd_one_kernel``."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
 
     T, H, D = qh.shape
-    Hkv = kh.shape[1]
+    Hkv, Dv = kh.shape[1], vh.shape[-1]
     if H % Hkv or mask.rows != T:
         raise ValueError(f"heads {H} on {Hkv} kv heads, T={T} under a mask "
                          f"over {mask.rows} rows")
     if scale is None:
         scale = 1.0 / math.sqrt(D)
+    one = _one_kernel_backward(T, D, Dv, qh.dtype, interpret=interpret)
+    blocks = _splash_block_sizes(sk, T, D, backward=not one)
+    heads_mask = _splash_mask(mask, H // Hkv)
     kernel = sk.make_splash_mqa_single_device(
-        _splash_mask(mask, H // Hkv), interpret=interpret,
-        block_sizes=_splash_block_sizes(sk, T, D))
+        heads_mask, interpret=interpret, block_sizes=blocks)
     # kernel layout: [kv heads, query heads of one kv head, T, D]; the kernel
     # has no scale of its own
-    q4 = (qh * jnp.asarray(scale, qh.dtype)).transpose(1, 0, 2).reshape(
-        Hkv, H // Hkv, T, D)
-    out = jax.vmap(kernel)(q4, kh.transpose(1, 0, 2), vh.transpose(1, 0, 2))
-    return out.reshape(H, T, vh.shape[-1]).transpose(1, 0, 2).astype(qh.dtype)
+    operands = ((qh * jnp.asarray(scale, qh.dtype)).transpose(1, 0, 2).reshape(
+        Hkv, H // Hkv, T, D), kh.transpose(1, 0, 2), vh.transpose(1, 0, 2))
+    if one:
+        out = _one_kernel_attend(kernel, heads_mask, mask.allowed)(*operands)
+    else:
+        out = _counted_backward(jax.vmap(kernel)(*operands))
+    return out.reshape(H, T, Dv).transpose(1, 0, 2).astype(qh.dtype)
 
 
-def _splash_block_sizes(sk, T: int, D: int = 128):
-    """Square tiles of ``flash_tile(T)`` for the three splash kernels, the
-    softmax taken over SPLASH_KV_COMPUTE columns of a kv tile at a time
-    (SPLASH_KV_COMPUTE_NARROW for a q.k head ``D`` narrower than the lanes)."""
+@jax.custom_vjp
+def _counted_backward(out):
+    """The identity, whose pull-back counts a traced backward of a splash
+    call that kept the library's two kernels (``attn.bwd_calls``)."""
+    return out
+
+
+def _count_backward(_, do):
+    from dgraph_tpu.obs.metrics import default_registry
+
+    default_registry.counter("attn.bwd_calls")
+    return (do,)
+
+
+_counted_backward.defvjp(lambda out: (out, None), _count_backward)
+
+
+def _one_kernel_attend(kernel, heads_mask, allowed):
+    """``(q4, k3, v3) -> out`` in the kernels' layout: the library's forward
+    kernel (``kernel``'s, kept with its log-sum-exp where the call is
+    differentiated) under a ``jax.custom_vjp`` whose backward is
+    :func:`dgraph_tpu.ops.pallas_attention.backward` over the forward's own
+    tile list. The rules are traced after the trace that built ``kernel`` has
+    ended (under ``jax.checkpoint``), so they hold the tile list as numpy and
+    nothing of ``kernel``'s arrays."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask_info as mi)
+
+    from dgraph_tpu.obs.metrics import default_registry
+    from dgraph_tpu.ops import pallas_attention
+
+    tile = kernel.kwargs["block_sizes"].block_q
+    # the list `kernel` was built from (the library keeps the last dozen)
+    info, _ = mi.process_mask(
+        heads_mask, (tile, tile), downcast_smem_data=True, head_shards=1,
+        q_seq_shards=1)
+    tile_visits = pallas_attention.visits(info.data_next, info.block_mask)
+    forward_kernel = lambda **kw: jax.vmap(sk.SplashAttentionKernel(
+        info, None, None, **{**kernel.kwargs, **kw}))
+
+    @jax.custom_vjp
+    def attend(q4, k3, v3):
+        return forward_kernel()(q4, k3, v3)
+
+    def forward(q4, k3, v3):
+        o, (lse,) = forward_kernel(save_residuals=True)(q4, k3, v3)
+        return o, (q4, k3, v3, o, lse)
+
+    def backward(kept, do):
+        q4, k3, v3, o, lse = kept
+        default_registry.counter("attn.bwd_calls")
+        default_registry.counter("attn.bwd_one_kernel")
+        di = jnp.einsum("hgtd,hgtd->hgt", o.astype(jnp.float32),
+                        do.astype(jnp.float32))
+        return tuple(pallas_attention.backward(
+            q4, k3, v3, do, lse, di, tile_visits, allowed=allowed, tile=tile,
+            interpret=kernel.kwargs["interpret"]))
+
+    attend.defvjp(forward, backward)
+    return attend
+
+
+def _one_kernel_backward(T: int, D: int, Dv: int, dtype, *,
+                         interpret: bool = False) -> bool:
+    """Does a splash call at ``T`` rows and heads of ``D | Dv`` take the
+    one-kernel backward? On a TPU (or interpreted), where ``T`` is whole
+    tiles and a KV head's resident blocks and float32 accumulators, ``T x (D
+    + Dv)`` at whole lane tiles, fit ``pallas_attention.VMEM_BUDGET`` with
+    the rest of the kernel's blocks (``vmem_bytes``). Shapes decide: no flag,
+    no model's name."""
+    from dgraph_tpu.ops import pallas_attention
+
+    return (interpret or jax.default_backend() == "tpu") \
+        and pallas_attention.applies(
+            T, D, Dv, jnp.dtype(dtype).itemsize, flash_tile(T))
+
+
+def _splash_block_sizes(sk, T: int, D: int = 128, *, backward: bool = True):
+    """Square tiles of ``flash_tile(T)`` for the splash kernels (the forward
+    alone without ``backward``: the one-kernel backward takes its tile from
+    the forward's), the softmax taken over SPLASH_KV_COMPUTE columns of a kv
+    tile at a time (SPLASH_KV_COMPUTE_NARROW for a q.k head ``D`` narrower
+    than the lanes)."""
     b = flash_tile(T)
     c = min(b, SPLASH_KV_COMPUTE_NARROW if D < 128 else SPLASH_KV_COMPUTE)
+    if not backward:
+        return sk.BlockSizes(block_q=b, block_kv=b, block_kv_compute=c)
     return sk.BlockSizes(
         block_q=b, block_kv=b, block_kv_compute=c,
         block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=c,

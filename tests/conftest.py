@@ -69,6 +69,85 @@ def tpu_interpret(monkeypatch):
 
 
 @pytest.fixture
+def splash_backward(monkeypatch):
+    """``check(mask, group, head_dim, v_head_dim, dtype)``: the splash
+    attention's three gradients in interpret mode, tiles of 128 rows (pick a
+    mask under which whole, partial and skipped tiles all occur), through the
+    ONE backward kernel (``ops/pallas_attention.py``) against the dense
+    oracle's and against the library's two kernels', the route of a shape
+    past the VMEM budget; and the counters of both routes: one traced
+    backward each, ``attn.bwd_one_kernel`` under the one kernel alone."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask_info as mi)
+
+    from dgraph_tpu.obs.metrics import default_registry
+    from dgraph_tpu.ops import pallas_attention
+    from dgraph_tpu.parallel import sequence as seq
+
+    monkeypatch.setattr(seq, "FLASH_BLOCK", 128)
+
+    def counted():
+        c = default_registry.snapshot()["counters"]
+        return np.array([c.get("attn.bwd_calls", 0),
+                         c.get("attn.bwd_one_kernel", 0)])
+
+    def check(mask, group, head_dim, v_head_dim, dtype):
+        rows, kv_heads = mask.rows, 2
+        info, _ = mi.process_mask(
+            seq._splash_mask(mask, group), (128, 128),
+            downcast_smem_data=True, head_shards=1, q_seq_shards=1)
+        _, cut, counts = pallas_attention.visits(
+            info.data_next, info.block_mask)
+        live = np.arange(cut.shape[1])[None, :] < counts[:, None]
+        # the kernel visits the tiles the program counts (attn.tile_pairs):
+        # some skipped, some cut by the mask, some whole
+        assert counts.sum() * 128 * 128 == mask.tile_pairs(128)
+        assert counts.sum() < (rows // 128) ** 2
+        assert 0 < cut[live].sum() < live.sum()
+        rng = np.random.default_rng(1)
+        q, w = (jnp.asarray(rng.standard_normal((rows, kv_heads * group, d)),
+                            dtype) for d in (head_dim, v_head_dim))
+        k, v = (jnp.asarray(rng.standard_normal((rows, kv_heads, d)), dtype)
+                for d in (head_dim, v_head_dim))
+
+        def grads(attend):
+            # as a trainer differentiates a layer: jitted, under
+            # jax.checkpoint (the rules of a custom_vjp are then traced after
+            # the trace that made the call has ended)
+            loss = jax.checkpoint(
+                lambda *a: (attend(*a).astype(jnp.float32)
+                            * w.astype(jnp.float32)).sum())
+            return [np.asarray(g, np.float32) for g in jax.jit(
+                jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)]
+
+        splash = lambda *a: seq._splash_dense(
+            *a, mask=mask, scale=None, interpret=True)
+        before = counted()
+        assert seq._one_kernel_backward(rows, head_dim, v_head_dim, dtype,
+                                        interpret=True)
+        one = grads(splash)
+        assert (counted() - before == [1, 1]).all()
+        with monkeypatch.context() as m:
+            m.setattr(pallas_attention, "VMEM_BUDGET", 0)
+            two = grads(splash)
+        assert (counted() - before == [2, 1]).all()
+        want = grads(lambda *a: seq.dense_attention(*a, mask=mask))
+        exact = jnp.dtype(dtype) == jnp.float32
+        for mine, theirs, oracle in zip(one, two, want):
+            # float32: the same sums in another order; bfloat16: each
+            # rounded once from a float32 sum (a rounding apart at most),
+            # then dq once more by the softmax scale outside the kernels
+            np.testing.assert_allclose(mine, theirs, rtol=1e-5 if exact
+                                       else 2 ** -6, atol=1e-5)
+            np.testing.assert_allclose(mine, oracle, rtol=1e-5 if exact
+                                       else 5e-2, atol=1e-5 if exact else 5e-2)
+
+    return check
+
+
+@pytest.fixture
 def compiled_fresh():
     """A test whose programs are compiled here, none loaded from the
     persistent compilation cache (and none written to it)."""

@@ -413,6 +413,44 @@ def test_a_qk_head_off_the_lanes_on_another_value_head_in_interpret_mode():
         seq.SPLASH_KV_COMPUTE)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_one_kernel_backward_at_a_qk_head_of_24_on_values_of_32(splash_backward, dtype):
+    """ISSUE 50 at this configuration's grouping and head (tiny: one key head
+    a query head, 24 | 32 as 192 | 128 is, causal): dq, dk, dv of the one
+    backward kernel against the dense oracle's and the library's two
+    kernels' (``conftest.py::splash_backward``)."""
+    splash_backward(seq.CausalMask(512), 1, 24, 32, jnp.dtype(dtype))
+
+
+def test_the_one_kernel_backward_at_the_cells_head_tile_and_chunk():
+    """The one backward kernel as the cell runs it but for the rows: q.k
+    heads of 192 on value heads of 128, tiles of 1024 rows
+    (``FLASH_BLOCK``) taken 256 keys at a time (``pallas_attention.CHUNK``:
+    four chunks a visit, which the 128-row tiles of the other cases do not
+    reach), three tiles a side under the causal mask; float32, against the
+    dense oracle."""
+    from dgraph_tpu.ops import pallas_attention
+
+    T, D, Dv = 3072, 192, 128
+    assert (seq.flash_tile(T), pallas_attention.CHUNK) == (1024, 256)
+    rng = np.random.default_rng(3)
+    q, k = (jnp.asarray(rng.standard_normal((T, 1, D)), jnp.float32)
+            for _ in range(2))
+    v, w = (jnp.asarray(rng.standard_normal((T, 1, Dv)), jnp.float32)
+            for _ in range(2))
+    mask = seq.CausalMask(T)
+
+    def grads(attend):
+        return jax.grad(lambda *a: (attend(*a) * w).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda *a: seq._splash_dense(*a, mask=mask, scale=None,
+                                             interpret=True))
+    want = grads(lambda *a: seq.dense_attention(*a, mask=mask))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
 def test_zero_padded_q_and_k_give_the_same_attention():
     """What ``scripts/splash_head_sweep.py`` measured against the head as it
     is was the SAME attention: zero columns add nothing to a score while the

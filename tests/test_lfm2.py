@@ -548,6 +548,15 @@ def test_narrow_grouped_heads_match_the_oracle_in_interpret_mode():
             == np.tril(np.ones((6, 6), bool))).all()
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_one_kernel_backward_at_a_narrow_head(splash_backward, dtype):
+    """ISSUE 50 at this configuration's grouping and head (causal, 4 query
+    heads a KV head, heads of 64: half a lane tile): dq, dk, dv of the one
+    backward kernel against the dense oracle's and the library's two
+    kernels' (``conftest.py::splash_backward``)."""
+    splash_backward(seq.CausalMask(512), 4, 64, 64, jnp.dtype(dtype))
+
+
 def test_a_narrow_head_engages_only_after_its_own_selfcheck(monkeypatch):
     from dgraph_tpu import config as cfg
 

@@ -213,3 +213,50 @@ def test_splash_kernels_compile_at_a_qk_head_of_192_on_values_of_128(
     want = [MLA_V] if direction == "forward" else [MLA_QK, MLA_QK, MLA_V]
     assert [o.shape for o in jax.tree.leaves(out)] == [
         (MLA_T, MLA_H, d) for d in want]
+
+
+@pytest.mark.parametrize("cell", ["kanana2_30b_a3b.seq16k",
+                                  "sdar_30b_a3b.bd8k"])
+def test_the_one_kernel_splash_backward_compiles_at_a_cells_shape(
+        one_chip, monkeypatch, cell):
+    """ISSUE 50: the backward of a splash call as one kernel
+    (``ops/pallas_attention.py``) at the two claimed cells' attention shapes:
+    16 384 causal rows at 32 heads on 32 of 192 | 128 (a KV head's K, V, dk,
+    dv blocks and float32 accumulators resident: 83.6 MiB by ``vmem_bytes``,
+    the limit Mosaic is handed), and 16 384 block-diffusion rows at 32 heads
+    on 4 of 128 (a noised query tile's visits are two runs of key tiles).
+    On a TPU the route is taken by shape; the library's forward keeps its
+    log-sum-exp for it, and neither of the library's backward kernels is in
+    the program."""
+    from dgraph_tpu.obs.metrics import default_registry
+    from dgraph_tpu.ops import pallas_attention
+    from dgraph_tpu.parallel import sequence as seq
+
+    T, H, Hkv, D, Dv, mask = {
+        "kanana2_30b_a3b.seq16k": (MLA_T, MLA_H, MLA_H, MLA_QK, MLA_V,
+                                   seq.CausalMask(MLA_T)),
+        "sdar_30b_a3b.bd8k": (16384, 32, 4, 128, 128,
+                              seq.BlockDiffusionMask(8192, 4)),
+    }[cell]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_attention.vmem_bytes(T, D, Dv, 2, 1024) \
+        <= pallas_attention.VMEM_BUDGET
+
+    def shape(h, d):
+        return jax.ShapeDtypeStruct((T, h, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    fn = jax.grad(
+        lambda q, k, v: seq._splash_dense(
+            q, k, v, mask=mask, scale=None).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))
+    args = [shape(H, D), shape(Hkv, D), shape(Hkv, Dv)]
+    counters = lambda: default_registry.snapshot()["counters"]
+    before = counters().get("attn.bwd_one_kernel", 0)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert counters()["attn.bwd_one_kernel"] == before + 1
+    assert "splash_bwd_one_kernel" in text
+    assert "splash_mqa_dkv" not in text and "splash_mqa_dq" not in text
+    assert "bf16[16384,32,256]" not in text  # no head is padded in HBM
+    assert [o.shape for o in jax.eval_shape(fn, *args)] == [
+        (T, H, D), (T, Hkv, D), (T, Hkv, Dv)]

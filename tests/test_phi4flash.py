@@ -311,6 +311,20 @@ def test_splash_at_qk_64_v_128_matches_the_oracle_in_interpret_mode(kind):
     assert not seq.flash_attention_selfcheck(mask, 2, 64, 128)  # off-TPU
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["window", "causal"])
+def test_the_one_kernel_backward_at_qk_64_v_128(splash_backward, kind, dtype):
+    """ISSUE 50 under both of this stack's masks (2 query heads a key head,
+    q.k heads of 64 on value heads of 128): dq, dk, dv of the one backward
+    kernel against the dense oracle's and the library's two kernels'; a
+    window of two tiles, so that a query tile sees a whole tile, cut tiles on
+    both edges of the band and skipped ones on both sides
+    (``conftest.py::splash_backward``)."""
+    mask = seq.WindowMask(512, 256) if kind == "window" \
+        else seq.CausalMask(512)
+    splash_backward(mask, 2, 64, 128, jnp.dtype(dtype))
+
+
 def test_unequal_heads_engage_only_after_their_own_selfcheck(monkeypatch):
     from dgraph_tpu import config as cfg
 

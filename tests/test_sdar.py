@@ -341,6 +341,43 @@ def test_splash_kernels_match_the_oracle_in_interpret_mode():
     assert not seq.flash_attention_selfcheck(seq.BlockDiffusionMask(256, 4), 2)  # off-TPU
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_one_kernel_backward_under_the_block_diffusion_mask(splash_backward, dtype):
+    """ISSUE 50 under this configuration's mask: dq, dk, dv of the one
+    backward kernel against the dense oracle's and the library's two
+    kernels', 2 query heads a KV head at heads of 128; a noised query tile's
+    visits are not one run of key tiles (``conftest.py::splash_backward``)."""
+    splash_backward(seq.BlockDiffusionMask(256, 4), 2, 128, 128, jnp.dtype(dtype))
+
+
+@pytest.mark.parametrize("rows,head_dim,v_head_dim", [
+    (16384, 192, 128), (16384, 128, 128), (16384, 64, 64), (8192, 64, 128)],
+    ids=["kanana2_30b_a3b", "sdar_30b_a3b-smallthinker_21b_a3b",
+         "lfm2_8b_a1b", "phi4_mini_flash"])
+def test_the_one_kernel_backward_is_taken_by_shape(monkeypatch, rows,
+                                                   head_dim, v_head_dim):
+    """The route is a predicate on shapes (ISSUE 50): the five splash cells'
+    attention shapes take the one kernel on a TPU (or interpreted), a KV
+    head whose resident blocks and float32 accumulators pass the VMEM
+    budget does not, nor rows that are no whole lane-tile tiles, nor
+    anything off a TPU: those keep the library's two kernels."""
+    from dgraph_tpu.ops import pallas_attention
+
+    taken = lambda rows, **kw: seq._one_kernel_backward(
+        rows, head_dim, v_head_dim, jnp.bfloat16, **kw)
+    assert not taken(rows)  # the CPU
+    assert taken(rows, interpret=True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert taken(rows) and not taken(4 * rows) and not taken(rows + 64)
+    held = pallas_attention.vmem_bytes(rows, head_dim, v_head_dim, 2,
+                                       seq.flash_tile(rows))
+    # K, V, dk, dv (double-buffered blocks) and the two float32 accumulators
+    # of a head at whole lane tiles: 12 bytes a row and lane, and the rest
+    wide = -(-head_dim // 128) * 128 + -(-v_head_dim // 128) * 128
+    assert 12 * rows * wide < held <= pallas_attention.VMEM_BUDGET
+    assert held < 12 * rows * wide + (24 << 20)
+
+
 def test_grouped_query_heads_equal_repeated_keys_and_values():
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.standard_normal((32, 8, 8)), jnp.float32)

@@ -467,6 +467,17 @@ def test_seven_query_heads_a_kv_head_under_the_window_in_interpret_mode():
     np.testing.assert_array_equal(rk[0, :, 0], np.arange(28) // 7)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_one_kernel_backward_at_seven_query_heads_a_kv_head(
+        splash_backward, dtype):
+    """ISSUE 50 at this configuration's grouping (7 query heads a KV head,
+    heads of 128, under a window): dk and dv are summed over the seven heads
+    and every query tile inside the kernel, in float32; against the dense
+    oracle's and the library's two kernels' gradients
+    (``conftest.py::splash_backward``)."""
+    splash_backward(seq.WindowMask(512, 256), 7, 128, 128, jnp.dtype(dtype))
+
+
 def test_setup_resolves_a_mask_a_layer_and_counts_the_new_kind(size,
                                                                monkeypatch):
     from dgraph_tpu.obs import metrics
