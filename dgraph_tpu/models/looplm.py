@@ -192,6 +192,7 @@ _dot_f32_out = functools.partial(
     jax.lax.dot_general, preferred_element_type=jnp.float32)
 
 
+@jax.named_scope("dgraph.lm.rotary")
 def rotary_tables(positions: jax.Array, head_dim: int, theta: float):
     """(cos, sin), each ``[T, head_dim / 2]`` float32, of the angles
     ``position * theta^(-2i / head_dim)``. ``positions`` are GLOBAL token
@@ -228,6 +229,12 @@ def apply_rotary_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Arra
     partner = jnp.where(even, -jnp.roll(xf, -1, axis=-1),
                         jnp.roll(xf, 1, axis=-1))
     return (xf * c + partner * s).astype(x.dtype)
+
+
+def _under_norm_scope(module):
+    """``module``'s application under ``dgraph.lm.norm``, for a norm that
+    opens no scope of its own (``nn.LayerNorm``; :class:`RMSNorm` does)."""
+    return jax.named_scope("dgraph.lm.norm")(module.__call__)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -700,8 +707,8 @@ class LoopLMLayer(nn.Module):
         if self.norm == "rms":
             norm = functools.partial(RMSNorm, epsilon=self.rms_eps, dtype=dt)
         else:
-            norm = functools.partial(nn.LayerNorm, epsilon=self.rms_eps,
-                                     dtype=dt)
+            norm = lambda name: _under_norm_scope(nn.LayerNorm(
+                epsilon=self.rms_eps, dtype=dt, name=name))
         post = (lambda name: norm(name=name)) if self.sandwich_norm \
             else (lambda name: lambda y: y.astype(h.dtype))
         keep = experts = routes = None
@@ -784,8 +791,12 @@ class LoopLMLayer(nn.Module):
         Dn, Dr, Dv = sp.nope_dim, sp.rope_dim, sp.v_head_dim
         x = norm(name="norm_attn_in")(h)
         q = dense(H * (Dn + Dr), name="q_proj")(x).reshape(n, H, Dn + Dr)
-        q = jnp.concatenate(
-            [q[..., :Dn], apply_rotary_pairs(q[..., Dn:], *rope)], axis=-1)
+        # the slice of the rotary part and the concatenation that puts it
+        # back are what a rotary over ``Dr`` of a head's dimensions costs
+        # beyond the rolls
+        with jax.named_scope("dgraph.lm.rotary"):
+            q = jnp.concatenate(
+                [q[..., :Dn], apply_rotary_pairs(q[..., Dn:], *rope)], axis=-1)
         with jax.named_scope("dgraph.lm.mla_down"):
             c, k_r = jnp.split(dense(sp.kv_rank + Dr, name="kv_a_proj")(x),
                                [sp.kv_rank], axis=-1)
@@ -955,11 +966,12 @@ class LoopPass(nn.Module):
                 stats = jnp.concatenate(counted) if counted else None
             # rematerialised too: its float32 internals would otherwise be
             # saved once a pass
-            norm_cls = RMSNorm if self.layer.get("norm", "rms") == "rms" \
-                else nn.LayerNorm
+            rms = self.layer.get("norm", "rms") == "rms"
+            norm_cls = RMSNorm if rms else nn.LayerNorm
             norm_f = nn.remat(norm_cls) if self.remat else norm_cls
-            h = norm_f(epsilon=self.layer["rms_eps"], dtype=h.dtype,
-                       name="norm_f")(h)
+            norm_f = norm_f(epsilon=self.layer["rms_eps"], dtype=h.dtype,
+                            name="norm_f")
+            h = (norm_f if rms else _under_norm_scope(norm_f))(h)
         return h, (h if stats is None else (h, stats))
 
 
@@ -1085,7 +1097,13 @@ class LoopLM(nn.Module):
     def hidden(self, tokens, positions):  # [T_loc] int32 each
         rope = None if self.rope_theta is None else rotary_tables(
             positions, self.rotary_dim(), self.rope_theta)
-        _, hs = self.stack(self.embed(tokens), rope)
+        with jax.named_scope("dgraph.lm.embed"):
+            h0 = self.embed(tokens)
+        # the loop over the passes under the passes' own scope: what stacks
+        # their exit states (and what ``nn.scan`` computes from the loop's
+        # broadcast inputs alone, ahead of a run of layers) is the stream's
+        with jax.named_scope("dgraph.lm.loop_pass"):
+            _, hs = self.stack(h0, rope)
         # [loop_steps, T_loc, hidden]; with expert layers also their counts,
         # [loop_steps, num_layers, 6] (parallel.expert.HELD_STATS)
         return hs

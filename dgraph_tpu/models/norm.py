@@ -87,7 +87,7 @@ class RMSNorm(nn.Module):
     Sennrich 2019), no bias and no mean subtraction. Token-local, so it
     needs no communicator. The statistic and the scaling are computed in
     float32 whatever ``dtype`` is; the result is cast to ``dtype`` (None:
-    the input's)."""
+    the input's). Scope ``dgraph.lm.norm`` (only the sequence LM uses it)."""
 
     epsilon: float = 1e-6
     dtype: Any = None
@@ -95,7 +95,9 @@ class RMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        xf = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(var + self.epsilon) * scale.astype(jnp.float32)
-        return y.astype(self.dtype or x.dtype)
+        with jax.named_scope("dgraph.lm.norm"):
+            xf = x.astype(jnp.float32)
+            var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+            y = xf * jax.lax.rsqrt(var + self.epsilon) \
+                * scale.astype(jnp.float32)
+            return y.astype(self.dtype or x.dtype)

@@ -187,7 +187,8 @@ def _block_cross_entropy(head_fn, head, h, tgt):
     logits = head_fn(h, *head)
     with jax.named_scope("dgraph.lm.cross_entropy"):
         lse = jax.nn.logsumexp(logits, axis=-1)
-        hit = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+        with jax.named_scope("target_logit"):
+            hit = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
         return lse - hit
 
 
@@ -504,12 +505,13 @@ def make_lm_train_step(model, optimizer: optax.GradientTransformation, mesh,
             return seed, {}
         (first,) = pull(seed)  # all but the leaves ahead is dead code
         flat, tree = jax.tree.flatten(first)
-        first = tree.unflatten([g if i in ahead else jnp.zeros_like(g)
-                                for i, g in enumerate(flat)])
-        new = jax.tree.leaves(update(first, opt_state, params))
-        return lax.optimization_barrier(
-            (seed, {i: a for i, (a, r) in enumerate(zip(new, reads))
-                    if r & ahead}))
+        with jax.named_scope("dgraph.lm.optimizer"):
+            first = tree.unflatten([g if i in ahead else jnp.zeros_like(g)
+                                    for i, g in enumerate(flat)])
+            new = jax.tree.leaves(update(first, opt_state, params))
+            return lax.optimization_barrier(
+                (seed, {i: a for i, (a, r) in enumerate(zip(new, reads))
+                        if r & ahead}))
 
     def lm_train_step(params, opt_state, tokens):
         out = jax.vjp(lambda p: loss_fn(p, tokens), params, has_aux=counted)
