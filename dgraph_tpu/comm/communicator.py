@@ -198,8 +198,10 @@ class _BaseComm:
         ``single`` mode is the dense oracle. All three are exact, so model
         code is byte-identical under any choice. Wherever a device ends up
         holding a full-sequence view (single mode, or the Ulysses dense
-        stage), the Mosaic flash kernel takes over when enabled + the
-        shapes qualify (``config.use_flash_attention``).
+        stage), the Mosaic kernels take over when enabled + self-checked +
+        the shapes qualify (``config.use_flash_attention``): the splash
+        kernels for a plain causal call, the library's flash kernels for one
+        with a ``kv_mask`` or not causal (``sequence._flash_dense``).
 
         Args:
           q/k/v: [T_loc, H, D] per-shard (full [T, H, D] in single mode);
@@ -248,11 +250,10 @@ class _BaseComm:
             # flash here ONLY on an explicit pinned True (post-self-check):
             # single mode is the dense ORACLE parity harnesses compare
             # against — an unverified kernel must not replace it on auto
-            narrow = q.shape[-1] % 128 != 0  # its kernel path is causal only
-            if not (narrow and (not causal or kv_mask is not None)) \
-                    and _flash_applicable(q, require_pinned=True,
-                                          group=q.shape[1] // k.shape[1],
-                                          v_head_dim=v.shape[-1]):
+            if _flash_applicable(q, require_pinned=True,
+                                 group=q.shape[1] // k.shape[1],
+                                 v_head_dim=v.shape[-1], causal=causal,
+                                 kv_mask=kv_mask):
                 return _flash_dense(q, k, v, causal=causal, scale=None,
                                     kv_mask=kv_mask)
             return dense_attention(q, k, v, causal=causal, kv_mask=kv_mask)

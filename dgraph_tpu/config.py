@@ -73,11 +73,13 @@ def pallas_fused_bwd_enabled() -> bool:
         return use_pallas_fused_bwd
     return True
 
-# Mosaic flash-attention kernel for the Ulysses full-sequence per-head
-# attention (parallel/sequence.py). Tri-state like the scatter kernels:
-# None = auto (ON on TPU when shapes qualify), env DGRAPH_TPU_FLASH_ATTN
-# pins it; consumers should run flash_attention_selfcheck() on chip first
-# (same Mosaic-divergence rationale as the scatter self-checks).
+# Mosaic attention kernels (splash or the library's flash, by the call:
+# parallel/sequence.py::_flash_dense) wherever a device holds a full-sequence
+# view: the single-device LM and the Ulysses per-head stage. Tri-state like
+# the scatter kernels: None = auto (ON on TPU when shapes qualify), env
+# DGRAPH_TPU_FLASH_ATTN pins it; no value stands in for the kernels' chip
+# self-check, flash_attention_selfcheck(), which consumers run first (same
+# Mosaic-divergence rationale as the scatter self-checks).
 use_flash_attention: bool | None = _env_flag("DGRAPH_TPU_FLASH_ATTN", None)
 
 

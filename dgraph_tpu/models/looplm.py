@@ -17,7 +17,7 @@ Equations (d hidden, H heads of ``head_dim``, F intermediate, no bias):
   embedding on q and k at the token's GLOBAL position (rotate-half pairs
   ``(i, i + head_dim/2)``), exact causal softmax(``q k^T / sqrt(head_dim)``)
   ``v`` through the comm facade's ``seq_attention`` (dense or the Mosaic
-  flash kernel on one device, ring or Ulysses over the graph axis), ``W_o``;
+  kernels on one device, ring or Ulysses over the graph axis), ``W_o``;
 - the loop: ``h0 = E[tokens]``; for t = 1..R: ``h_t = RMSNorm_f(Stack(h_{t-1}))``;
   ``logits_t = W_head h_t``; exit gate ``lambda_t = sigmoid(w_g . h_t + b_g)``.
 

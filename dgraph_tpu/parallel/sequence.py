@@ -124,9 +124,10 @@ class BlockDiffusionMask:
 class CausalMask:
     """``causal`` as a mask object, for the kernels that take one: row q may
     attend row k iff ``k <= q``. The splash kernels skip the tiles above the
-    diagonal and read grouped KV heads where they lie, which is how a head
-    that is no multiple of the 128 lanes runs (:func:`_flash_dense`: 64, or
-    192 on values of 128)."""
+    diagonal and read grouped KV heads where they lie, which is how a plain
+    causal call runs (:func:`_causal_splash`: at a head of 128 where the one
+    backward kernel takes the shape, and at every head that is no multiple of
+    the 128 lanes: 64, or 192 on values of 128)."""
 
     seq_len: int
     name: ClassVar[str] = "causal"
@@ -421,7 +422,8 @@ def ulysses_attention(
     else:
         # every device needs the FULL-sequence mask once heads are sharded
         mask_full = lax.all_gather(kv_mask, axis_name, tiled=True)
-    if _flash_applicable(qh):
+    if _flash_applicable(qh, group=qh.shape[1] // kh.shape[1], causal=causal,
+                         kv_mask=mask_full):
         out = _flash_dense(qh, kh, vh, causal=causal, scale=scale,
                            kv_mask=mask_full)
         return head_to_seq(out)
@@ -439,20 +441,25 @@ def _head_key(head_dim: int, v_head_dim=None):
 
 
 def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
-                      mask=None, group: int = 1, v_head_dim=None) -> bool:
-    """Use the Mosaic flash-attention kernel for a full-sequence dense
-    attention site? With a structured ``mask`` the kernel is the splash one,
-    which additionally needs ITS self-check passed in this process for this
-    kind of mask and this many query heads a KV head (``group``): no flag
-    stands in for that.
+                      mask=None, group: int = 1, v_head_dim=None,
+                      causal: bool = False, kv_mask=None) -> bool:
+    """Run a full-sequence dense attention site through the Mosaic kernels?
+    Which kernels follows from the call itself. Under a structured ``mask``
+    they are the splash ones; a plain causal call (``causal``, no
+    ``kv_mask``) takes the splash ones too, under :class:`CausalMask`, where
+    :func:`_causal_splash` says so; every other call at a head of whole lanes
+    takes the library's flash kernels. Each family engages only after ITS
+    self-check passed in this process (the splash kernels' for this kind of
+    mask, this many query heads a KV head, ``group``, and these head sizes;
+    the flash kernels' ``_flash_verified``): no flag stands in for a check,
+    and neither check for the other.
 
     Trace-time decision: config tri-state (``DGRAPH_TPU_FLASH_ATTN``) +
-    shape constraints of the TPU kernel (T a multiple of its 128 query
-    block; head_dim a multiple of the 128 lanes, or another whose causal
-    self-check at ``group`` and the values' head size passed). ``require_pinned=True`` (the
-    single-comm ORACLE site) engages only on an explicit config True —
-    never on auto — so an unverified Mosaic kernel can't silently replace
-    the dense reference that parity harnesses compare against.
+    shape constraints of the TPU kernels (T a multiple of their 128 query
+    block). ``require_pinned=True`` (the single-comm ORACLE site) engages
+    only on an explicit config True — never on auto — so a Mosaic kernel
+    can't silently replace the dense reference that parity harnesses compare
+    against.
     """
     from dgraph_tpu import config as _cfg
 
@@ -460,46 +467,71 @@ def _flash_applicable(qh: jax.Array, *, require_pinned: bool = False,
         return False  # the kernel is Mosaic-only; a pinned flag on CPU
         # must not trace it (every other Pallas gate has this check)
     pinned = _cfg.use_flash_attention is True
-    if require_pinned and not pinned:
-        return False
-    if not pinned and not (
-        _cfg.flash_attention_enabled() and _flash_verified
-    ):
-        # auto engages only after a chip self-check latched success this
-        # process (the scatter kernels' central-veto discipline); an
-        # explicit pinned True is the operator's override
+    if (require_pinned or not _cfg.flash_attention_enabled()) and not pinned:
         return False
     T, _, D = qh.shape
-    # a structured mask, and causal attention at a head that is no multiple
-    # of the lanes, run through the splash kernels: once THEIR self-check
-    # passed for this kind of mask, grouping and head size
-    kind = CausalMask.name if mask is None else mask.name
-    if (mask is not None or D % 128) \
-            and (kind, group, _head_key(D, v_head_dim)) \
-            not in _splash_verified:
+    if T % 128:
         return False
-    return T % 128 == 0
+    Dv = v_head_dim or D
+    if mask is not None:
+        return (mask.name, group, _head_key(D, Dv)) in _splash_verified
+    if causal and kv_mask is None and _causal_splash(T, D, Dv, group,
+                                                     qh.dtype):
+        return True
+    return D % 128 == 0 and Dv == D and _flash_verified
+
+
+def _causal_splash(T: int, D: int, Dv: int, group: int, dtype) -> bool:
+    """Does a plain causal call (no ``kv_mask``) of ``T`` rows at ``group``
+    query heads a KV head and heads of ``D | Dv`` run through the splash
+    kernels under :class:`CausalMask`? Where the shape is theirs
+    (:func:`_causal_splash_shape`) and their self-check latched this grouping
+    and these head sizes. What the call shows decides: no flag."""
+    return _causal_splash_shape(T, D, Dv, dtype) and (
+        CausalMask.name, group, _head_key(D, Dv)) in _splash_verified
+
+
+def _causal_splash_shape(T: Optional[int], D: int, Dv: int, dtype) -> bool:
+    """The shapes of plain causal calls that are the splash kernels' (``T``
+    None: no such call is stated). A head off the lanes always: the flash
+    kernels take none but repeated and zero-padded (at D = 64, 32 heads on 8,
+    T = 16384: 20.2 ms forward / 100.7 forward + backward against the splash
+    kernels' 17.7 / 83.7; PERF.md section 6, PR 34). A head on them where the
+    backward is the one kernel (:func:`_one_kernel_backward`: 8.5 us a
+    visited 1024 x 1024 tile where the library's flash pair takes 13.1, with
+    K and V read where they lie; PERF.md section 6, PR 52)."""
+    return D % 128 != 0 or (
+        T is not None and _one_kernel_backward(T, D, Dv, dtype))
 
 
 def _flash_dense(qh, kh, vh, *, causal, scale, kv_mask):
-    """[T, H_loc, D] full-sequence attention via
-    ``jax.experimental.pallas.ops.tpu.flash_attention`` (forward AND
+    """[T, H_loc, D] full-sequence attention through the Mosaic kernels a
+    call's own arguments choose (the site passed :func:`_flash_applicable`):
+    a plain causal call the splash kernels under :class:`CausalMask` where
+    :func:`_causal_splash` holds (grouped K and V as they are), any other the
+    library's flash kernels (:func:`_flash_kernels`)."""
+    T, H, D = qh.shape
+    plain = causal and kv_mask is None
+    if D % 128 and not plain:
+        raise NotImplementedError(
+            f"head_dim {D}: the kernel path of a head off the lanes is "
+            f"causal and takes no kv_mask")
+    if plain and _causal_splash(T, D, vh.shape[-1], H // kh.shape[1],
+                                qh.dtype):
+        return _splash_dense(qh, kh, vh, mask=CausalMask(T), scale=scale)
+    return _flash_kernels(qh, kh, vh, causal=causal, scale=scale,
+                          kv_mask=kv_mask)
+
+
+def _flash_kernels(qh, kh, vh, *, causal, scale, kv_mask):
+    """``jax.experimental.pallas.ops.tpu.flash_attention`` (forward AND
     backward are Mosaic kernels with their own custom VJP — memory stays
     O(T * block) instead of the [T, H, T] logits tensor). Padded tail
-    positions are excluded by giving them a second segment id."""
+    positions are excluded by giving them a second segment id; grouped K and
+    V are repeated to the query heads."""
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
 
-    T, H, D = qh.shape
-    if D % 128:
-        # measured at D = 64, 32 heads on 8, T = 16384 (PERF.md section 6,
-        # PR 34): the flash kernel with K and V repeated 19.1 ms forward /
-        # 99.2 forward + backward (zero-padded to 128: 20.2 / 100.7), the
-        # splash kernels under a causal mask 17.7 / 83.7
-        if not causal or kv_mask is not None:
-            raise NotImplementedError(
-                f"head_dim {D}: the kernel path of a head off the lanes is "
-                f"causal and takes no kv_mask")
-        return _splash_dense(qh, kh, vh, mask=CausalMask(T), scale=scale)
+    T, _, D = qh.shape
     kh, vh = repeat_kv(qh, kh, vh)
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -716,29 +748,43 @@ def _flash_block_sizes(fa, T: int):
         block_q_dkv=b, block_k_major_dq=b, block_k_dq=b, block_q_dq=b)
 
 
-# Auto-mode flash engages only after flash_attention_selfcheck() passes
-# in this process (pinned config True bypasses — operator override).
+# Query heads the splash self-check's dense oracle takes at a time: its eager
+# programs hold several [rows, heads, rows] float32 arrays, 0.54 GB each at 8
+# heads and the check's 4096 rows, and the allocator's peaks are the process's:
+# at 16 heads at once (32 on 2) the check read 4.2 GB over the training step's
+# own peak (PERF.md section 6, PR 52).
+ORACLE_HEADS = 8
+# The library's flash kernels engage only after _flash_selfcheck() passed in
+# this process (what flash_attention_selfcheck() runs for a caller that reaches
+# them); a pinned config True does not stand in for it.
 _flash_verified = False
 # (mask kind, query heads a kv head, head_dim) whose splash self-check passed
 _splash_verified: set = set()
 
 
 def flash_attention_selfcheck(mask=None, group: int = 1,
-                              head_dim: int = 128, v_head_dim=None) -> bool:
+                              head_dim: int = 128, v_head_dim=None,
+                              rows: Optional[int] = None,
+                              dtype=jnp.float32) -> bool:
     """Chip-gated equivalence check vs :func:`dense_attention` (the same
     Mosaic-divergence rationale as bench.py's scatter self-checks: the
-    kernel class is invisible to CPU CI). Passing LATCHES auto-mode flash
-    on for this process; returns False off-TPU.
+    kernel class is invisible to CPU CI) of the kernels the caller's calls
+    will run, and of no others; returns False off-TPU.
 
-    With a structured ``mask`` (and ``group`` query heads a KV head) the
-    splash kernels are checked too, forward and backward, under a mask of the
+    As called with nothing, the library's flash kernels, forward and backward
+    (what a call with a ``kv_mask``, or not causal, runs); passing latches
+    ``_flash_verified``. With a structured ``mask`` (and ``group`` query heads
+    a KV head) the splash kernels, forward and backward, under a mask of the
     same kind over four tiles a side (whole, partial and skipped tiles all
-    occur) and two KV heads, at heads of ``head_dim``; passing latches
-    (kind, group, head_dim) in ``_splash_verified``. A ``head_dim`` that is no multiple of 128 runs
-    causal attention through the splash kernels too (:func:`_flash_dense`),
-    so they are checked under the causal mask at THAT head size and grouping
-    (and with values of ``v_head_dim``, where that differs: latched as
-    ``(head_dim, v_head_dim)``). True only if everything asked for passed.
+    occur) and two KV heads, at heads of ``head_dim`` (and values of
+    ``v_head_dim``, where that differs: latched as ``(head_dim,
+    v_head_dim)``); passing latches (kind, group, head_dim) in
+    ``_splash_verified``. Without a mask the caller's calls are plain causal
+    ones of ``rows`` rows and ``dtype`` streams, and where their shape is the
+    splash kernels' (:func:`_causal_splash_shape`: a ``head_dim`` that is no
+    multiple of 128, or one the one-kernel backward takes) those are checked,
+    under the causal mask at THAT head size and grouping, and the flash
+    kernels, which such a caller never reaches, are not.
 
     The call is the stage ``setup.attention_selfcheck`` (always on, one a
     latch asked for; off a TPU it ends at once with ``passed`` false).
@@ -748,11 +794,15 @@ def flash_attention_selfcheck(mask=None, group: int = 1,
     with spans.stage("setup.attention_selfcheck",
                      mask=CausalMask.name if mask is None else mask.name,
                      group=group, head_dim=head_dim) as st:
-        passed = jax.default_backend() == "tpu" and _flash_selfcheck()
-        if passed and (mask is not None or head_dim % 128):
+        if jax.default_backend() != "tpu":
+            passed = False
+        elif mask is not None or _causal_splash_shape(
+                rows, head_dim, v_head_dim or head_dim, dtype):
             passed = _splash_selfcheck(
                 mask or CausalMask(0), group, head_dim=head_dim,
                 v_head_dim=v_head_dim)
+        else:
+            passed = _flash_selfcheck()
         st.annotate(passed=passed)
     return passed
 
@@ -774,15 +824,16 @@ def _flash_selfcheck() -> bool:
         rtol=5e-2, atol=5e-2)
     try:
         for causal in (False, True):
-            got = _flash_dense(q, k, v, causal=causal, scale=None,
-                               kv_mask=mask)
+            got = _flash_kernels(q, k, v, causal=causal, scale=None,
+                                 kv_mask=mask)
             want = dense_attention(q, k, v, causal=causal, kv_mask=mask)
             real = np.asarray(mask) > 0
             if not close(np.asarray(got, np.float32)[real],
                          np.asarray(want, np.float32)[real]):
                 return False
-        # what a trainer runs: causal, no mask, T past one tile of
-        # FLASH_BLOCK, and the backward kernels (dq, dk, dv) as well
+        # causal, no mask (a shape past the one-kernel backward's budget),
+        # T past one tile of FLASH_BLOCK, and the backward kernels (dq, dk,
+        # dv) as well
         T = 2 * FLASH_BLOCK
         q, k, v, w = (
             jnp.asarray(rng.standard_normal((T, H, D)), jnp.bfloat16)
@@ -795,7 +846,7 @@ def _flash_selfcheck() -> bool:
                                     * w.astype(jnp.float32)).sum(),
                 argnums=(0, 1, 2))(q, k, v)
 
-        flash = lambda q_, k_, v_: _flash_dense(
+        flash = lambda q_, k_, v_: _flash_kernels(
             q_, k_, v_, causal=True, scale=None, kv_mask=None)
         dense = lambda q_, k_, v_: dense_attention(q_, k_, v_, causal=True)
         if not close(flash(q, k, v), dense(q, k, v)):
@@ -812,8 +863,9 @@ def _splash_selfcheck(mask, group: int, *, interpret: bool = False,
                       head_dim: int = 128, v_head_dim=None) -> bool:
     """Splash forward and backward against the dense oracle under a mask of
     ``mask``'s kind at heads of ``head_dim``; see
-    :func:`flash_attention_selfcheck`. The oracle runs one KV head at a time
-    (its ``[T, group, T]`` float32 logits)."""
+    :func:`flash_attention_selfcheck`. The oracle runs one KV head at a time,
+    ``ORACLE_HEADS`` of its query heads at a time (its ``[T, heads, T]``
+    float32 logits), the head's ``dk`` and ``dv`` summed over them."""
     rows = 4 * (128 if interpret else FLASH_BLOCK)  # four tiles a side
     small = mask.over(rows)
     rng = np.random.default_rng(5)
@@ -836,13 +888,19 @@ def _splash_selfcheck(mask, group: int, *, interpret: bool = False,
         got = both(lambda *a: _splash_dense(
             *a, mask=small, scale=None, interpret=interpret), q, k, v, w)
         for j in range(Hkv):
-            heads = slice(j * group, (j + 1) * group)
-            want = both(lambda *a: dense_attention(*a, mask=small),
-                        q[:, heads], k[:, j:j + 1], v[:, j:j + 1],
-                        w[:, heads])
-            mine = (got[0][:, heads], got[1][:, heads],
-                    got[2][:, j:j + 1], got[3][:, j:j + 1])
-            if not all(close(a, b) for a, b in zip(mine, want)):
+            dkv = [0.0, 0.0]
+            for h in range(j * group, (j + 1) * group, ORACLE_HEADS):
+                heads = slice(h, min(h + ORACLE_HEADS, (j + 1) * group))
+                want = both(lambda *a: dense_attention(*a, mask=small),
+                            q[:, heads], k[:, j:j + 1], v[:, j:j + 1],
+                            w[:, heads])
+                if not (close(got[0][:, heads], want[0])
+                        and close(got[1][:, heads], want[1])):
+                    return False
+                dkv = [a + b.astype(jnp.float32)
+                       for a, b in zip(dkv, want[2:])]
+            if not all(close(got[2 + i][:, j:j + 1], dkv[i])
+                       for i in range(2)):
                 return False
     except Exception:
         return False

@@ -68,7 +68,7 @@ INIT_PROBE_TOKENS = 128
 
 def lm_comm(world_size: int):
     """The communicator of a sequence LM: ``single`` on one device (its
-    ``seq_attention`` is the dense oracle or the flash kernel), ``tpu`` over
+    ``seq_attention`` is the dense oracle or the Mosaic kernels), ``tpu`` over
     the graph axis otherwise (ring or Ulysses)."""
     from dgraph_tpu.comm import Communicator
 
@@ -83,19 +83,24 @@ def lm_mesh(world_size: int, devices=None):
 
 def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
                       head_dim: int, mask=None, group: int = 1,
-                      v_head_dim: Optional[int] = None) -> str:
+                      v_head_dim: Optional[int] = None,
+                      dtype=jnp.float32) -> str:
     """Decide, before anything is traced, which attention implementation
-    ``comm.seq_attention`` will run, and say which: 'flash', 'dense', 'ring',
-    'ulysses+flash' or 'ulysses+dense'; under a structured ``mask`` (with
-    ``group`` query heads a KV head) 'splash' or 'dense', the self-check then
-    covering the splash kernels for that mask and grouping as well. A head
-    that is no multiple of 128 lanes runs causal attention through the splash
-    kernels too ('splash'), after their self-check at that head size and
-    grouping (and with values of ``v_head_dim``, where that is another size).
+    ``comm.seq_attention`` will run for a stack's calls (plain causal ones,
+    or under a structured ``mask``, with ``group`` query heads a KV head and
+    streams of ``dtype``), and say which: 'splash', 'flash', 'dense', 'ring',
+    'ulysses+splash', 'ulysses+flash' or 'ulysses+dense'. 'splash': the
+    splash kernels, which run every structured mask, and a plain causal call
+    under the causal mask at a head that is no multiple of 128 lanes (with
+    values of ``v_head_dim``, where that is another size) and at any head
+    wherever the one backward kernel takes the shape
+    (``sequence._causal_splash``); 'flash': the library's flash kernels, for
+    the causal shapes that are left.
 
-    Wherever a device holds a full-sequence view the Mosaic flash kernel is
-    engaged only after ``flash_attention_selfcheck()`` passed on this chip
-    (the flag is then pinned, which is what the single-comm site asks for).
+    Wherever a device holds a full-sequence view the Mosaic kernels are
+    engaged only after ``flash_attention_selfcheck`` passed on this chip for
+    the kernels the resolved route runs, and for no others (the flag is then
+    pinned, which is what the single-comm site asks for).
     A dense path whose ``[H, T, T]`` float32 logits would pass
     ``DENSE_LOGITS_LIMIT_BYTES`` raises: at such a size the oracle is not a
     fall-back."""
@@ -109,27 +114,31 @@ def resolve_attention(comm, attn_impl: str, t_local: int, num_heads: int,
             f"sequence; world size {world} shards it (ROADMAP R11)")
     if comm.graph_axis is not None and attn_impl == "ring":
         return "ring"
-    if cfg.flash_attention_enabled():
-        cfg.set_flags(use_flash_attention=seq.flash_attention_selfcheck(
-            mask, group, head_dim, v_head_dim))
     if comm.graph_axis is None:
         t_full, heads, prefix = t_local, num_heads, ""
-    else:  # ulysses: the full sequence, a share of the heads
-        t_full, heads, prefix = t_local * world, num_heads // world, "ulysses+"
-    view = jax.ShapeDtypeStruct((t_full, heads, head_dim), jnp.float32)
+    else:  # ulysses: the full sequence, a share of the heads, K and V
+        # repeated to them
+        t_full, heads, prefix, group = (
+            t_local * world, num_heads // world, "ulysses+", 1)
+    if cfg.flash_attention_enabled():
+        cfg.set_flags(use_flash_attention=seq.flash_attention_selfcheck(
+            mask, group, head_dim, v_head_dim, rows=t_full, dtype=dtype))
+    view = jax.ShapeDtypeStruct((t_full, heads, head_dim), dtype)
     if seq._flash_applicable(view, require_pinned=comm.graph_axis is None,
-                             mask=mask, group=group, v_head_dim=v_head_dim):
-        return prefix + ("flash" if mask is None and head_dim % 128 == 0
-                         else "splash")
+                             mask=mask, group=group, v_head_dim=v_head_dim,
+                             causal=mask is None):
+        return prefix + ("splash" if mask is not None or seq._causal_splash(
+            t_full, head_dim, v_head_dim or head_dim, group, dtype)
+            else "flash")
     if heads * t_full * t_full * 4 > DENSE_LOGITS_LIMIT_BYTES:
         raise RuntimeError(
             f"attention over T={t_full} with {heads} heads would materialise "
             f"{heads * t_full * t_full * 4 / 1e9:.1f} GB of logits in the dense "
-            f"oracle, and the flash kernel is not engaged (backend "
+            f"oracle, and no Mosaic kernel is engaged (backend "
             f"{jax.default_backend()!r}, use_flash_attention="
-            f"{cfg.use_flash_attention!r}, self-check latched="
-            f"{seq._flash_verified}); shard the sequence (ring) or repair the "
-            f"kernel")
+            f"{cfg.use_flash_attention!r}, self-checks latched: flash "
+            f"{seq._flash_verified}, splash {sorted(seq._splash_verified, key=str)}); "
+            f"shard the sequence (ring) or repair the kernel")
     return prefix + "dense"
 
 
@@ -720,6 +729,11 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
     masks = model.attention_masks(seq_len) \
         if hasattr(model, "attention_masks") else None
     group = heads // (getattr(model, "num_kv_heads", None) or heads)
+    from dgraph_tpu import config as cfg
+
+    # what the layers' q, k, v stream in (the kernels' VMEM is counted in it)
+    dtype = jnp.dtype(cfg.resolve_compute_dtype(
+        getattr(model, "dtype", None)) or jnp.float32)
     v_head_dim = None
     latent = model.latent() if hasattr(model, "latent") else None
     if latent is not None:  # one key and one value head a query head
@@ -738,13 +752,13 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
             resolve_attention(
                 comm, model.attn_impl, seq_len // world, heads, head_dim,
                 None if isinstance(m, CausalMask) else m, group,
-                v_head_dim=v_head_dim)
+                v_head_dim=v_head_dim, dtype=dtype)
             for m in dict.fromkeys(masks)))
     else:
         attention = resolve_attention(
             comm, model.attn_impl,
             seq_len // world if mask is None else mask.rows, heads, head_dim,
-            mask, group, v_head_dim=v_head_dim)
+            mask, group, v_head_dim=v_head_dim, dtype=dtype)
     specs = None
     if params is None:
         params, specs = init_lm_params(
@@ -811,8 +825,9 @@ def lm_setup(model, optimizer: optax.GradientTransformation, mesh, comm, *,
         from dgraph_tpu.parallel.sequence import flash_tile
 
         rows = masks[0].rows  # a kernel path skips whole tiles, the dense
-        # oracle none (a stack may take the flash kernel under its causal
-        # mask and the splash kernels under its window)
+        # oracle none (a stack may take the flash kernels under its causal
+        # mask, at a shape past the one backward kernel's budget, and the
+        # splash kernels under its window)
         tile = flash_tile(rows) if set(attention.split("/")) <= {
             "splash", "flash"} else rows
         startup.update(

@@ -37,8 +37,9 @@ class Config:
     lr: float = 3e-3
     world_size: Optional[int] = None  # None = all devices
     # 'ring' (O(T/W) memory) or 'ulysses' (all-to-all head sharding; its
-    # full-sequence dense stage uses the Mosaic flash kernel on TPU when
-    # config.use_flash_attention allows AND the chip self-check passes)
+    # full-sequence dense stage uses the Mosaic kernels on TPU, splash for a
+    # plain causal call and the library's flash otherwise, when
+    # config.use_flash_attention allows AND their chip self-check passed)
     attn_impl: str = "ring"
     # >0: expert-parallel MoE FFN over the same axis (one expert per rank,
     # DeepSpeed-MoE axis fusion); k = experts per token

@@ -1,7 +1,7 @@
-"""The splash attention's backward on the chip, stand-alone, at the five
-splash cells' attention shapes and masks: the library's two kernels (dkv,
-then dq: the parent's backward, and still the route of a shape whose blocks
-do not fit) against the one kernel of ``dgraph_tpu/ops/pallas_attention.py``:
+"""The splash attention's backward on the chip, stand-alone, at the seven
+sequence cells' attention shapes and masks: the library's two kernels (dkv,
+then dq: still the route of a shape whose blocks do not fit) against the one
+kernel of ``dgraph_tpu/ops/pallas_attention.py``:
 
     chiprun --chips 1 -- python scripts/splash_bwd_sweep.py
 
@@ -11,8 +11,13 @@ compiles), the backward alone as their difference, and the largest
 difference between the two backwards' gradients (both round once from
 float32 sums, so they differ by roundings of single products). The two-kernel
 side is reached the way a shape past the budget reaches it: with
-``pallas_attention.VMEM_BUDGET`` at 0. A CPU run is refused: times come from
-the chip only.
+``pallas_attention.VMEM_BUDGET`` at 0. A plain causal shape at a head of whole
+lanes (Ouro's, SmallThinker's full layer's, Nemotron's: the route
+``_flash_dense`` gave the splash kernels at ISSUE 52) has two columns more:
+the forward and forward + backward of the library's FLASH kernels with K and
+V repeated to the query heads, which is what ran there before and what a
+``kv_mask`` or a shape past the budget still runs. A CPU run is refused: times
+come from the chip only.
 
     JAX_PLATFORMS=cpu python scripts/splash_bwd_sweep.py --memory
 
@@ -41,6 +46,9 @@ SHAPES = {
     "lfm2_8b_a1b.seq16k": ("causal", 16384, None, 32, 8, 64, 64),
     "phi4_mini_flash.seq8k/window": ("window", 8192, 512, 40, 20, 64, 128),
     "phi4_mini_flash.seq8k/full": ("causal", 8192, None, 40, 20, 64, 128),
+    "ouro_2p6b.seq8k": ("causal", 8192, None, 16, 16, 128, 128),
+    "smallthinker_21b_a3b.seq16k/full": ("causal", 16384, None, 28, 4, 128, 128),
+    "nemotron3_nano_30b_a3b.seq8k": ("causal", 8192, None, 32, 2, 128, 128),
 }
 
 
@@ -80,24 +88,34 @@ def timings() -> int:
 
         attend = lambda q, k, v: seq._splash_dense(
             q, k, v, mask=mask, scale=None)
-        both = lambda: jax.jit(jax.grad(
+        both = lambda attend: jax.jit(jax.grad(
             lambda q, k, v: (attend(q, k, v).astype(jnp.float32)
                              * w.astype(jnp.float32)).sum(),
             argnums=(0, 1, 2)))
+        gap = lambda g, h: max(
+            float(jnp.abs(a.astype(jnp.float32)
+                          - b.astype(jnp.float32)).max())
+            for a, b in zip(g, h))
         fwd, _ = timed(jax.jit(attend))
         pa.VMEM_BUDGET = 0
-        two, g2 = timed(both())
+        two, g2 = timed(both(attend))
         pa.VMEM_BUDGET = budget
         assert seq._one_kernel_backward(T, D, Dv, q.dtype), cell
-        one, g1 = timed(both())
-        gap = max(float(jnp.abs(a.astype(jnp.float32)
-                                - b.astype(jnp.float32)).max())
-                  for a, b in zip(g1, g2))
-        print(f"{cell} {kind} T={T} heads={H}on{Hkv} head={D}|{Dv} "
-              f"forward_ms={fwd:.2f} two_kernels_fb_ms={two:.2f} "
-              f"one_kernel_fb_ms={one:.2f} backward_ms={two - fwd:.2f}->"
-              f"{one - fwd:.2f} ratio={(one - fwd) / (two - fwd):.3f} "
-              f"max_grad_gap={gap:.4f}", flush=True)
+        one, g1 = timed(both(attend))
+        line = (f"{cell} {kind} T={T} heads={H}on{Hkv} head={D}|{Dv} "
+                f"forward_ms={fwd:.2f} two_kernels_fb_ms={two:.2f} "
+                f"one_kernel_fb_ms={one:.2f} backward_ms={two - fwd:.2f}->"
+                f"{one - fwd:.2f} ratio={(one - fwd) / (two - fwd):.3f} "
+                f"max_grad_gap={gap(g1, g2):.4f}")
+        if kind == "causal" and D % 128 == 0 and Dv == D:
+            flash = lambda q, k, v: seq._flash_kernels(
+                q, k, v, causal=True, scale=None, kv_mask=None)
+            f_fwd, _ = timed(jax.jit(flash))
+            f_both, gf = timed(both(flash))
+            line += (f" flash_forward_ms={f_fwd:.2f} flash_fb_ms={f_both:.2f}"
+                     f" flash_backward_ms={f_both - f_fwd:.2f}"
+                     f" max_grad_gap_to_flash={gap(g1, gf):.4f}")
+        print(line, flush=True)
     return 0
 
 
@@ -159,7 +177,6 @@ def memory() -> int:
                                         topology_name="v5e:2x2")
     jax.default_backend = lambda: "tpu"  # the program's TPU branches
     cfg.use_flash_attention = True
-    seq._flash_verified = True
     mesh, comm = lm.lm_mesh(1, topo.devices[:1]), lm.lm_comm(1)
     budget = pa.VMEM_BUDGET
     for name, size, model, batch, latch in models(comm):

@@ -488,7 +488,9 @@ def test_setup_resolves_at_the_mixers_head_sizes_and_counts_them(size,
     lm.lm_setup(model, optax.sgd(0.1), lm.lm_mesh(1), model.comm, seq_len=T)
     # 4 heads at the mixer's q.k head of 24 (NOT model.head_dim's), one key
     # head a query head, values of 32
-    assert asked == [((4, 24, None, 1), {"v_head_dim": 32})]
+    # ... and streams of the model's type (what the kernels' VMEM is counted in)
+    assert asked == [((4, 24, None, 1), {"v_head_dim": 32,
+                                         "dtype": jnp.dtype("float32")})]
     c = reg.snapshot()["counters"]
     assert (c["lm.layers.attention"], c["lm.layers.attn_mla"],
             c["lm.layers.dense_ffn"], c["lm.layers.expert_ffn"]) == (3, 3, 1, 2)

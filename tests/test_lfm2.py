@@ -564,22 +564,27 @@ def test_a_narrow_head_engages_only_after_its_own_selfcheck(monkeypatch):
     monkeypatch.setattr(cfg, "use_flash_attention", True)
     monkeypatch.setattr(seq, "_splash_verified", set())
     q = jnp.zeros((256, 8, 64))
-    assert seq._flash_applicable(q, require_pinned=True, group=4) is False
+    ok = lambda x, **kw: seq._flash_applicable(
+        x, require_pinned=True, **{"group": 4, "causal": True, **kw})
+    assert ok(q) is False
     seq._splash_verified.add(("causal", 4, 64))
-    assert seq._flash_applicable(q, require_pinned=True, group=4) is True
-    assert seq._flash_applicable(q, require_pinned=True, group=2) is False
-    assert seq._flash_applicable(jnp.zeros((250, 8, 64)), require_pinned=True,
-                                 group=4) is False
+    assert ok(q) is True
+    assert ok(q, group=2) is False
+    assert ok(jnp.zeros((250, 8, 64))) is False
+    # its kernel path is causal and takes no kv_mask
+    assert ok(q, causal=False) is False
+    assert ok(q, kv_mask=jnp.ones(256)) is False
     # a structured mask has its own check, at its own head size
     bd = seq.BlockDiffusionMask(128, 4)
-    assert seq._flash_applicable(q, require_pinned=True, group=4, mask=bd) is False
+    assert ok(q, causal=False, mask=bd) is False
     seq._splash_verified.add(("block_diffusion", 4, 128))
-    assert seq._flash_applicable(q, require_pinned=True, group=4, mask=bd) is False
-    assert seq._flash_applicable(jnp.zeros((256, 8, 128)), require_pinned=True,
-                                 group=4, mask=bd) is True
-    # causal heads of 128 keep the flash kernel, whatever the grouping
-    assert seq._flash_applicable(jnp.zeros((256, 8, 128)), require_pinned=True,
-                                 group=4) is True
+    assert ok(q, causal=False, mask=bd) is False
+    assert ok(jnp.zeros((256, 8, 128)), causal=False, mask=bd) is True
+    # causal heads of 128 have a check of their own too (ISSUE 52), and the
+    # library's flash kernels theirs
+    assert ok(jnp.zeros((256, 8, 128))) is False
+    monkeypatch.setattr(seq, "_flash_verified", True)
+    assert ok(jnp.zeros((256, 8, 128))) is True
     with pytest.raises(NotImplementedError, match="causal"):
         seq._flash_dense(q, q[:, :2], q[:, :2], causal=False, scale=None,
                          kv_mask=None)

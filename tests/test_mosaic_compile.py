@@ -18,7 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -33,9 +33,14 @@ def one_chip():
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 # gcn_arxiv.w1 (benchmark/configs/gcn_arxiv.json): e_pad, n_owner_pad, one
@@ -215,8 +220,20 @@ def test_splash_kernels_compile_at_a_qk_head_of_192_on_values_of_128(
         (MLA_T, MLA_H, d) for d in want]
 
 
-@pytest.mark.parametrize("cell", ["kanana2_30b_a3b.seq16k",
-                                  "sdar_30b_a3b.bd8k"])
+# (rows, query heads, KV heads, q.k head, value head, mask, vmem_bytes in
+# MiB) of a cell's attention; a plain causal call (ISSUE 52: no mask object,
+# the route is ``_flash_dense``'s) has None for its mask
+ONE_KERNEL_CELLS = {
+    "kanana2_30b_a3b.seq16k": (MLA_T, MLA_H, MLA_H, MLA_QK, MLA_V,
+                               "causal mask", 83.75),
+    "sdar_30b_a3b.bd8k": (16384, 32, 4, 128, 128, "block diffusion", 58.25),
+    "ouro_2p6b.seq8k": (8192, 16, 16, 128, 128, None, 34.125),
+    "smallthinker_21b_a3b.seq16k/full": (16384, 28, 4, 128, 128, None, 58.25),
+    "nemotron3_nano_30b_a3b.seq8k": (8192, 32, 2, 128, 128, None, 34.125),
+}
+
+
+@pytest.mark.parametrize("cell", list(ONE_KERNEL_CELLS))
 def test_the_one_kernel_splash_backward_compiles_at_a_cells_shape(
         one_chip, monkeypatch, cell):
     """ISSUE 50: the backward of a splash call as one kernel
@@ -227,36 +244,87 @@ def test_the_one_kernel_splash_backward_compiles_at_a_cells_shape(
     on 4 of 128 (a noised query tile's visits are two runs of key tiles).
     On a TPU the route is taken by shape; the library's forward keeps its
     log-sum-exp for it, and neither of the library's backward kernels is in
-    the program."""
+    the program. ISSUE 52: and at the three plain causal shapes at a head of
+    128 that ``_flash_dense`` sends the same way once ``("causal", group,
+    128)`` is latched (16 heads on 16 and 32 on 2 at 8 192 rows, 28 on 4 at
+    16 384): the splash forward with K and V of ``Hkv`` heads as they are, the
+    one backward kernel, and none of the library's flash kernels."""
     from dgraph_tpu.obs.metrics import default_registry
     from dgraph_tpu.ops import pallas_attention
     from dgraph_tpu.parallel import sequence as seq
 
-    T, H, Hkv, D, Dv, mask = {
-        "kanana2_30b_a3b.seq16k": (MLA_T, MLA_H, MLA_H, MLA_QK, MLA_V,
-                                   seq.CausalMask(MLA_T)),
-        "sdar_30b_a3b.bd8k": (16384, 32, 4, 128, 128,
-                              seq.BlockDiffusionMask(8192, 4)),
-    }[cell]
+    T, H, Hkv, D, Dv, mask, mib = ONE_KERNEL_CELLS[cell]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert pallas_attention.vmem_bytes(T, D, Dv, 2, 1024) \
+    assert pallas_attention.vmem_bytes(T, D, Dv, 2, 1024) == mib * 2 ** 20 \
         <= pallas_attention.VMEM_BUDGET
+    if mask is None:
+        monkeypatch.setattr(seq, "_splash_verified", {("causal", H // Hkv, D)})
+        attend = lambda q, k, v: seq._flash_dense(
+            q, k, v, causal=True, scale=None, kv_mask=None)
+    else:
+        mask = seq.CausalMask(T) if mask == "causal mask" \
+            else seq.BlockDiffusionMask(T // 2, 4)
+        attend = lambda q, k, v: seq._splash_dense(
+            q, k, v, mask=mask, scale=None)
 
     def shape(h, d):
         return jax.ShapeDtypeStruct((T, h, d), jnp.bfloat16,
                                     sharding=one_chip)
 
     fn = jax.grad(
-        lambda q, k, v: seq._splash_dense(
-            q, k, v, mask=mask, scale=None).astype(jnp.float32).sum(),
+        lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
         argnums=(0, 1, 2))
     args = [shape(H, D), shape(Hkv, D), shape(Hkv, Dv)]
     counters = lambda: default_registry.snapshot()["counters"]
     before = counters().get("attn.bwd_one_kernel", 0)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert counters()["attn.bwd_one_kernel"] == before + 1
-    assert "splash_bwd_one_kernel" in text
+    assert "splash_bwd_one_kernel" in text and "splash_mqa_fwd" in text
     assert "splash_mqa_dkv" not in text and "splash_mqa_dq" not in text
+    assert "flash_mha" not in text and "flash_attention" not in text
     assert "bf16[16384,32,256]" not in text  # no head is padded in HBM
     assert [o.shape for o in jax.eval_shape(fn, *args)] == [
         (T, H, D), (T, Hkv, D), (T, Hkv, Dv)]
+
+
+@pytest.mark.parametrize("latched", ["splash", "flash"])
+def test_ulysses_attention_compiles_across_four_chips_on_either_route(
+        topo, monkeypatch, latched):
+    """No cell runs the Ulysses stage (ROADMAP R10), so its kernels are seen
+    here alone: ``ulysses_attention`` under ``shard_map`` over the four chips
+    of a described host, causal, 16 heads of 128 at 8 192 rows, forward and
+    backward. With ``("causal", 1, 128)`` latched its per-head stage is the
+    splash forward and the one backward kernel (ISSUE 52: K and V come
+    repeated, so one key head a query head); with the flash kernels' latch
+    alone it keeps the library's flash kernels."""
+    import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dgraph_tpu import config as cfg
+    from dgraph_tpu.parallel import sequence as seq
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(cfg, "use_flash_attention", None)  # auto
+    monkeypatch.setattr(seq, "_flash_verified", latched == "flash")
+    monkeypatch.setattr(seq, "_splash_verified",
+                        {("causal", 1, 128)} if latched == "splash" else set())
+    mesh = Mesh(np.array(topo.devices), ("seq",))
+    rows = jax.ShapeDtypeStruct((8192, 16, 128), jnp.bfloat16,
+                                sharding=NamedSharding(mesh, P("seq")))
+    attend = shard_map(
+        lambda q, k, v: seq.ulysses_attention(q, k, v, "seq", causal=True),
+        mesh=mesh, in_specs=(P("seq"),) * 3, out_specs=P("seq"),
+        # the library's kernels (flash and splash forward alike) declare no
+        # vma on their out_shape, so under the checker, which every call
+        # site of the program keeps on, NEITHER route traces (PERF.md
+        # section 7, #7b); what is compiled here is the kernels' lowering
+        check_vma=False)
+    fn = jax.grad(lambda *a: attend(*a).astype(jnp.float32).sum(),
+                  argnums=(0, 1, 2))
+    with jax.set_mesh(mesh):
+        text = jax.jit(fn).lower(rows, rows, rows).compile().as_text()
+    assert "all-to-all" in text
+    assert ("splash_bwd_one_kernel" in text, "splash_mqa_fwd" in text,
+            "flash_mha" in text) == (
+        (True, True, False) if latched == "splash" else (False, False, True))

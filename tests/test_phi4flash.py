@@ -333,7 +333,8 @@ def test_unequal_heads_engage_only_after_their_own_selfcheck(monkeypatch):
     monkeypatch.setattr(seq, "_splash_verified", {("causal", 2, 64)})
     q = jnp.zeros((256, 8, 64))
     win = seq.WindowMask(256, 32)
-    ok = lambda **kw: seq._flash_applicable(q, require_pinned=True, group=2, **kw)
+    ok = lambda mask=None, **kw: seq._flash_applicable(
+        q, require_pinned=True, group=2, mask=mask, causal=mask is None, **kw)
     assert ok() is True and ok(v_head_dim=64) is True
     assert ok(v_head_dim=128) is False and ok(mask=win, v_head_dim=128) is False
     seq._splash_verified.add(("window", 2, (64, 128)))

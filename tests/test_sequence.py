@@ -271,14 +271,19 @@ def test_flash_shape_gate(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     old = cfg.use_flash_attention
     try:
-        cfg.set_flags(use_flash_attention=True)  # pinned: operator override
+        cfg.set_flags(use_flash_attention=True)  # pinned
+        # the pin stands in for no self-check: the flash kernels' own latch
+        assert seq._flash_applicable(jnp.zeros((256, 2, 128))) is False
+        monkeypatch.setattr(seq, "_flash_verified", True)
         assert seq._flash_applicable(jnp.zeros((256, 2, 128))) is True
         assert seq._flash_applicable(
             jnp.zeros((256, 2, 128)), require_pinned=True) is True
         assert seq._flash_applicable(jnp.zeros((250, 2, 128))) is False
         assert seq._flash_applicable(jnp.zeros((256, 2, 64))) is False
-        # auto (None) needs the self-check latch even on "tpu"
+        # auto (None) needs the self-check latch as well
         cfg.set_flags(use_flash_attention=None)
+        assert seq._flash_applicable(jnp.zeros((256, 2, 128))) is True
+        monkeypatch.setattr(seq, "_flash_verified", False)
         assert seq._flash_applicable(jnp.zeros((256, 2, 128))) is False
     finally:
         cfg.set_flags(use_flash_attention=old)

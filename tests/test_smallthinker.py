@@ -496,8 +496,8 @@ def test_setup_resolves_a_mask_a_layer_and_counts_the_new_kind(size,
     lm.lm_setup(model, optax.sgd(0.1), lm.lm_mesh(1), model.comm, seq_len=T)
     # the full mask (as causal) and the window, once each, at this grouping
     # and with the values' head the keys' (no differential attention here)
-    assert asked == [(None, 2, {"v_head_dim": None}),
-                     (masks[1], 2, {"v_head_dim": None})]
+    kw = {"v_head_dim": None, "dtype": jnp.dtype("float32")}
+    assert asked == [(None, 2, kw), (masks[1], 2, kw)]
     c = reg.snapshot()["counters"]
     assert (c["lm.layers.attention"], c["lm.layers.attn_win"],
             c["lm.layers.window"], c["lm.layers.expert_ffn"]) == (4, 3, 3, 4)
